@@ -1,7 +1,7 @@
 # Test/bench entry points (the reference pins quality with Makefile:3-7 —
 # fmt + clippy + `cargo test` under a quickcheck budget; here the suite +
 # dryrun + bench are the equivalent gates).
-.PHONY: test test-fast test-chaos test-recovery test-restart test-overload test-fuzz test-devicefault test-device-stripped dryrun bench bench-smoke trace-smoke critpath-smoke overload-smoke fuzz-smoke failover-smoke telemetry-smoke pallas-smoke scenario-smoke
+.PHONY: test test-fast test-chaos test-recovery test-restart test-overload test-fuzz test-devicefault test-device-stripped dryrun bench chip-smoke bench-smoke trace-smoke critpath-smoke overload-smoke fuzz-smoke failover-smoke telemetry-smoke pallas-smoke scenario-smoke
 
 test:
 	python -m pytest tests/ -x -q
@@ -58,8 +58,15 @@ test-device-stripped:
 dryrun:
 	python -c "import __graft_entry__; __graft_entry__.dryrun_multichip(8)"
 
+# full mode needs the chip: it exits non-zero unless jax finds the TPU
+# (hostenv.py's platform rule) or when any row raised.  `make chip-smoke`
+# is the quick proof that the served path starts there; both run through
+# the chip tool, one process per chip
 bench:
 	python bench.py
+
+chip-smoke:
+	python chip_smoke.py
 
 # tiny CPU-sized bench rows (table + Newt serving), in-process: catches
 # import breaks and order-of-magnitude regressions in the bench seams

@@ -17,7 +17,6 @@ import json
 import pickle
 
 from fantoch_tpu.bin.common import (
-    force_platform_from_env,
     maybe_log_file,
     parse_id_range,
     parse_shard_addr,
@@ -179,7 +178,8 @@ async def drive(args: argparse.Namespace) -> None:
 
 
 def main(argv=None) -> None:
-    force_platform_from_env()
+    # the client plane is asyncio + pickle: it never imports jax, so it
+    # can share a host with the process that owns the chip
     args = build_parser().parse_args(argv)
     maybe_log_file(args.log_file)
     asyncio.run(drive(args))

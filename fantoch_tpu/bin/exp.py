@@ -25,9 +25,6 @@ import json
 
 
 def main(argv=None) -> None:
-    from fantoch_tpu.bin.common import force_platform_from_env
-
-    force_platform_from_env(touches_default_backend=False)
     parser = argparse.ArgumentParser(
         prog="fantoch_tpu.bin.exp", description=__doc__
     )

@@ -24,9 +24,6 @@ def main() -> None:
     parser.add_argument("--conflict", type=float, default=0.5)
     args = parser.parse_args()
 
-    from fantoch_tpu.bin.common import force_platform_from_env
-
-    force_platform_from_env()
     import multiprocessing as mp
 
     import numpy as np

@@ -15,13 +15,12 @@ import json
 from fantoch_tpu.bin.common import (
     add_config_flags,
     config_from_args,
-    force_platform_from_env,
+    start_device_entry,
     protocol_by_name,
 )
 
 
 def main(argv=None) -> None:
-    force_platform_from_env()
     parser = argparse.ArgumentParser(prog="fantoch_tpu.bin.replay", description=__doc__)
     parser.add_argument("--log", required=True)
     parser.add_argument("--protocol", required=True)
@@ -29,6 +28,9 @@ def main(argv=None) -> None:
     parser.add_argument("--shard-id", type=int, default=0)
     add_config_flags(parser)
     args = parser.parse_args(argv)
+    config = config_from_args(args)
+    if config.dispatches_to_device():
+        start_device_entry("bin/replay")
 
     from fantoch_tpu.run.observe import replay_execution_log
 
@@ -37,7 +39,7 @@ def main(argv=None) -> None:
         protocol_by_name(args.protocol),
         args.id,
         args.shard_id,
-        config_from_args(args),
+        config,
     )
     print(
         json.dumps(

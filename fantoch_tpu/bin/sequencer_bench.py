@@ -18,9 +18,6 @@ import time
 
 
 def main(argv=None) -> None:
-    from fantoch_tpu.bin.common import force_platform_from_env
-
-    force_platform_from_env()
     parser = argparse.ArgumentParser(
         prog="fantoch_tpu.bin.sequencer_bench", description=__doc__
     )
@@ -31,6 +28,10 @@ def main(argv=None) -> None:
                         help="commands for the host measurement "
                         "(default: min(batch, 50000))")
     args = parser.parse_args(argv)
+
+    from fantoch_tpu.bin.common import start_device_entry
+
+    device = start_device_entry("bin/sequencer_bench")
 
     import jax
     import jax.numpy as jnp
@@ -75,6 +76,7 @@ def main(argv=None) -> None:
     print(
         json.dumps(
             {
+                **device,
                 "keys": args.keys,
                 "batch": args.batch,
                 "device_cmds_per_s": int(args.batch / device_s),

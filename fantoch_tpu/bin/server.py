@@ -15,15 +15,16 @@ from __future__ import annotations
 
 import argparse
 import asyncio
+import signal
 
 from fantoch_tpu.bin.common import (
     add_config_flags,
     config_from_args,
-    force_platform_from_env,
     maybe_log_file,
     parse_peer,
     parse_sorted,
     protocol_by_name,
+    start_device_entry,
 )
 
 
@@ -58,8 +59,8 @@ def build_parser() -> argparse.ArgumentParser:
                         help="max conflict-key buckets per command")
     parser.add_argument(
         "--device-pipeline", choices=["auto", "on", "off"], default="auto",
-        help="dispatch/drain overlap for saturated serving (auto = on for "
-        "non-CPU backends, or whenever a pipeline depth was requested; "
+        help="dispatch/drain overlap for saturated serving (auto = on off "
+        "the CPU, or whenever a pipeline depth was requested; "
         "overlap needs a compute resource besides the host cores).  The "
         "in-flight depth is the --serving-pipeline-depth config flag "
         "(one knob: flag > FANTOCH_SERVING_PIPELINE_DEPTH env > 1)")
@@ -139,12 +140,32 @@ def build_parser() -> argparse.ArgumentParser:
     return parser
 
 
+def _backend_banner(backend: dict) -> str:
+    """The banner clause that names what serves (a parent script reads
+    it to tell a chip run from a CPU run)."""
+    mesh = backend.get("mesh_shape")
+    return (
+        f" [platform={backend['platform']} "
+        f"device_kind={backend['device_kind']!r} "
+        f"devices={backend['device_count']}"
+        + (
+            " mesh=" + "x".join(f"{a}:{n}" for a, n in mesh.items())
+            if mesh
+            else ""
+        )
+        + f" compile_cache={backend['compile_cache_dir']}]"
+    )
+
+
 async def serve_device_step(args: argparse.Namespace) -> None:
     """The TPU serving path: one server, the protocol round on-device."""
-    from fantoch_tpu.run.device_runner import DeviceRuntime
-
     protocol_by_name(args.protocol)  # validate the label even when unused
     config = config_from_args(args)
+    # the platform rule, before a mesh is built or a port is bound
+    backend = start_device_entry("bin/server --device-step")
+
+    from fantoch_tpu.run.device_runner import DeviceRuntime
+
     process_id = args.id if args.id is not None else 1
     mesh = None
     if args.multihost:
@@ -184,6 +205,11 @@ async def serve_device_step(args: argparse.Namespace) -> None:
     await runtime.start()
     _arm_profile_signal(args)
     _arm_flight_signal(runtime)
+    # SIGTERM stops the server the way Ctrl-C does: the serve task is
+    # cancelled and the finally below leaves the final snapshot
+    asyncio.get_running_loop().add_signal_handler(
+        signal.SIGTERM, asyncio.current_task().cancel
+    )
     print(
         f"p{process_id} (device-step, n={config.n}) serving clients on "
         f"{args.ip}:{args.client_port}"
@@ -191,15 +217,17 @@ async def serve_device_step(args: argparse.Namespace) -> None:
             f"; /metrics on :{runtime.metrics_port}"
             if runtime.metrics_port is not None
             else ""
-        ),
+        )
+        + _backend_banner({**backend, **runtime.backend_report()}),
         flush=True,
     )
     try:
         await runtime.failed.wait()
         raise SystemExit(f"p{process_id} failed: {runtime.failure!r}")
     finally:
-        # runs under task cancellation too (Ctrl-C through asyncio.run):
-        # short serves must still leave a final metrics snapshot
+        # runs under task cancellation too (Ctrl-C or SIGTERM through
+        # asyncio.run): short serves must still leave a final metrics
+        # snapshot
         if runtime.metrics_file is not None or runtime.telemetry is not None:
             runtime._emit_telemetry()
 
@@ -239,6 +267,15 @@ async def serve(args: argparse.Namespace) -> None:
         )
     protocol_cls = protocol_by_name(args.protocol)
     config = config_from_args(args)
+    # a batched executor or a device plane dispatches to the device: the
+    # platform rule applies before a port is bound (on a one-chip host
+    # only ONE such server can own the chip; its peers run under
+    # JAX_PLATFORMS=cpu)
+    backend = (
+        start_device_entry(f"bin/server --protocol {args.protocol}")
+        if config.dispatches_to_device()
+        else None
+    )
 
     peers = {}
     delays = {}
@@ -302,7 +339,8 @@ async def serve(args: argparse.Namespace) -> None:
             f"; /metrics on :{runtime.metrics_port}"
             if runtime.metrics_port is not None
             else ""
-        ),
+        )
+        + (_backend_banner(backend) if backend is not None else ""),
         flush=True,
     )
     await runtime.failed.wait()
@@ -310,12 +348,11 @@ async def serve(args: argparse.Namespace) -> None:
 
 
 def main(argv=None) -> None:
-    force_platform_from_env()
     args = build_parser().parse_args(argv)
     maybe_log_file(args.log_file)
     try:
         asyncio.run(serve(args))
-    except KeyboardInterrupt:
+    except (KeyboardInterrupt, asyncio.CancelledError):
         pass
 
 

@@ -5,13 +5,14 @@ program costs ~50s on the dev rig, and a scenario sweep that perturbs
 any shape axis pays it per point.  The defense is two-sided and this
 module is the seam for both:
 
-* **Persistent cache** — :func:`ensure_compile_cache` resolves the cache
-  directory (``Config.compile_cache_dir`` > ``FANTOCH_COMPILE_CACHE_DIR``
-  env > under the obs dir when the caller has one > the repo-adjacent
-  ``.jax_cache`` default) and delegates the jax.config flag-setting to
-  :func:`fantoch_tpu.hostenv.enable_compile_cache`.  With the cache warm,
-  a "compile" is a disk load: ``observability.device`` pairs the cache
-  hit/miss monitoring events with the backend-compile duration events so
+* **Persistent cache** — :func:`ensure_compile_cache` is the one
+  idempotent enable, and the directory is placed from OUTSIDE: where
+  ``JAX_COMPILATION_CACHE_DIR`` is set, jax already reads it and this
+  module sets no directory in code; where it is not, the cache is
+  ``<checkout>/.jax_cache`` and nowhere else (a cache under a per-run
+  directory starts cold every run).  With the cache warm, a "compile"
+  is a disk load: ``observability.device`` pairs the cache hit/miss
+  monitoring events with the backend-compile duration events so
   ``jax_recompiles`` counts only TRUE compiles (a warm sweep reports 0)
   while ``jax_cache_hits``/``jax_cache_misses`` expose the retrievals.
 
@@ -71,39 +72,29 @@ def clear_program_registry() -> None:
     _programs.clear()
 
 
-def resolve_cache_dir(config=None, obs_dir: Optional[str] = None) -> Optional[str]:
-    """The cache-dir precedence: explicit config > env > obs-dir default
-    > ``None`` (meaning: let hostenv fall back to the repo-adjacent
-    ``.jax_cache``)."""
-    value = getattr(config, "compile_cache_dir", None) if config else None
-    if value:
-        return str(value)
-    env = os.environ.get("FANTOCH_COMPILE_CACHE_DIR")
-    if env:
-        return env
-    if obs_dir:
-        return os.path.join(obs_dir, ".jax_cache")
-    return None
-
-
-def ensure_compile_cache(config=None, obs_dir: Optional[str] = None) -> str:
-    """Idempotent persistent-cache enable at the resolved directory;
-    returns the directory in effect.  Safe to call from every runner
-    seam (device_runner, process_runner, bench, conftest) — only the
-    first distinct directory actually flips the jax.config flags."""
+def ensure_compile_cache() -> str:
+    """Idempotent persistent-cache enable; returns the directory in
+    effect.  Safe to call from every start-up seam (CLI binaries, both
+    runners, bench, conftest) — only the first call flips jax.config.
+    Nothing is swallowed: a cache that fails to enable is an error."""
     global _enabled_dir
-    from fantoch_tpu.hostenv import enable_compile_cache
+    if _enabled_dir is None:
+        import jax
 
-    cache_dir = resolve_cache_dir(config, obs_dir)
-    if cache_dir is None:
-        import fantoch_tpu
+        cache_dir = os.environ.get("JAX_COMPILATION_CACHE_DIR")
+        if not cache_dir:
+            import fantoch_tpu
 
-        cache_dir = os.path.join(
-            os.path.dirname(os.path.dirname(os.path.abspath(fantoch_tpu.__file__))),
-            ".jax_cache",
-        )
-    if _enabled_dir == cache_dir:
-        return cache_dir
-    enable_compile_cache(cache_dir)
-    _enabled_dir = cache_dir
-    return cache_dir
+            cache_dir = os.path.join(
+                os.path.dirname(
+                    os.path.dirname(os.path.abspath(fantoch_tpu.__file__))
+                ),
+                ".jax_cache",
+            )
+            jax.config.update("jax_compilation_cache_dir", cache_dir)
+        # cache every program, however small or quick to compile: a
+        # serving loop stalls on each one it has to rebuild
+        jax.config.update("jax_persistent_cache_min_compile_time_secs", 0.0)
+        jax.config.update("jax_persistent_cache_min_entry_size_bytes", 0)
+        _enabled_dir = cache_dir
+    return _enabled_dir

@@ -134,17 +134,13 @@ class Config:
     # hot plane dispatches (graph/pred plane step, fused table round,
     # votes commit) through hand-fused Pallas kernels instead of the
     # XLA-composed programs.  None = the FANTOCH_PALLAS env var, else
-    # the backend default (on for TPU, off elsewhere — on CPU the
-    # kernels run in interpret mode, a parity instrument not a perf
-    # win).  Bit-for-bit either way; unsupported backends fall back to
-    # the composed programs automatically.  Process-global (the routers
-    # are module-level): co-hosted executors share one route
+    # off: the composed programs are the route that compiles on every
+    # backend.  An explicit opt-in runs the kernels in interpret mode on
+    # the CPU (the parity instrument) and RAISES on a TPU, whose Pallas
+    # lowering refuses them (scatter, sort) — there is no automatic
+    # fallback.  Process-global (the routers are module-level):
+    # co-hosted executors share one route
     pallas_kernels: Optional[bool] = None
-    # persistent XLA compilation-cache directory
-    # (core/compile_cache.py): an explicit path here beats the
-    # FANTOCH_COMPILE_CACHE_DIR env var, which beats the obs-dir /
-    # repo-adjacent defaults.  None = resolve through env/defaults
-    compile_cache_dir: Optional[str] = None
     # sampled shadow-check rate in [0, 1]: with probability p per
     # dispatch (seeded, deterministic) the plane replays the dispatch's
     # inputs through the same kernel on host-owned twin state and
@@ -368,6 +364,19 @@ class Config:
                 "newt_clock_bump_interval_ms (real-time micros clocks "
                 "exceed the 31-bit device window)"
             )
+
+    def dispatches_to_device(self) -> bool:
+        """Does this config select an executor or plane that dispatches
+        jitted programs?  (The graph plane needs the batched graph
+        executor, so its own field adds nothing here.)  Such a process
+        falls under the platform rule (fantoch_tpu/hostenv.py)."""
+        return (
+            self.batched_graph_executor
+            or self.batched_table_executor
+            or self.batched_pred_executor
+            or self.device_table_plane
+            or self.device_pred_plane
+        )
 
     # --- quorum sizes (protocol facts; fantoch/src/config.rs:252-317) ---
 

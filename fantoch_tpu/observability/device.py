@@ -45,12 +45,11 @@ def subscribe_recompiles() -> bool:
     global _subscribed
     if _subscribed:
         return True
-    try:
-        from jax import monitoring
-    except Exception:  # jax absent or too old: counters just stay 0
-        return False
+    from jax import monitoring
 
-    def _on_event(key: str) -> None:
+    # jax calls a listener as callback(event, [duration,] **kwargs); the
+    # keywords (fun_name=..., ...) are not used here
+    def _on_event(key: str, **_kwargs) -> None:
         global _cache_hits, _cache_misses, _pending_hits
         # the persistent-cache outcome events fire BEFORE the duration
         # event of the compile-or-retrieve they describe (verified on the
@@ -62,7 +61,7 @@ def subscribe_recompiles() -> bool:
         elif key.endswith("compilation_cache/cache_misses"):
             _cache_misses += 1
 
-    def _on_duration(key: str, secs: float) -> None:
+    def _on_duration(key: str, secs: float, **_kwargs) -> None:
         global _recompiles, _compile_ms, _pending_hits
         if key.endswith("backend_compile_duration"):
             if _pending_hits > 0:
@@ -76,10 +75,7 @@ def subscribe_recompiles() -> bool:
             # included: a warm run's compile_ms is the disk-load cost.
             _compile_ms += secs * 1000.0
 
-    try:
-        monitoring.register_event_listener(_on_event)
-    except Exception:  # noqa: BLE001 — older jax: hits/misses stay 0 and
-        pass  # every duration event counts as a compile (pre-cache rule)
+    monitoring.register_event_listener(_on_event)
     monitoring.register_event_duration_secs_listener(_on_duration)
     _subscribed = True
     return True

@@ -1,18 +1,25 @@
 """Pallas-fused resolve kernels for the hot device-plane dispatches.
 
 The BASELINE north star names a *Pallas kernel* for conflict detection +
-order resolution; until this module every resolve path was XLA-composed
-(`lax.scan` chains, peel-and-compact, scatter pipelines).  XLA fuses
-elementwise work but materializes every scatter/gather boundary to HBM —
-on a plane dispatch that is the install scatter, the waiter-index patch,
-*and every iteration* of the dependency fixpoint.  A Pallas kernel
-compiles the whole dispatch body as ONE Mosaic program whose
-intermediates (the ``int32[C, W]`` dep-slot matrix, the dot's
-clock/src columns, the fixpoint's executable mask) stay VMEM-resident
-from the install through the last fixpoint sweep — the "explicit VMEM
-blocking" the ROADMAP item asks for is exactly this residency, guarded
-by :func:`_fits_vmem` so an oversized window routes back to the
-composed program instead of faulting the chip.
+order resolution.  XLA fuses elementwise work but materializes every
+scatter/gather boundary to HBM — on a plane dispatch that is the install
+scatter, the waiter-index patch, *and every iteration* of the dependency
+fixpoint.  These kernels express each dispatch body as ONE gridless
+whole-array ``pallas_call`` whose intermediates (the ``int32[C, W]``
+dep-slot matrix, the dot's clock/src columns, the fixpoint's executable
+mask) would stay VMEM-resident from the install through the last
+fixpoint sweep.
+
+**What the chip says** (TPU v5 lite, jax 0.9.0, ``interpret=False``,
+C=4096 W=4, PR 21's probe): none of the four families lowers.
+``pred_plane_step`` and ``graph_plane_step`` (keyed, general and
+general_resident alike) are refused with *"Unimplemented primitive in
+Pallas TPU lowering for KernelType.TC: scatter"*; ``votes_commit`` and
+``table_round`` with *"...: sort"*.  They never reach Mosaic.  So the
+composed XLA programs are the route on every backend, and this module
+is an explicit opt-in: interpret mode on the CPU (the parity
+instrument), a loud failure on the TPU.  Rewriting a kernel so that it
+lowers, or deleting the module, is ROADMAP S4/D1.
 
 Three kernel families, matching the three plane dispatches:
 
@@ -23,11 +30,7 @@ Three kernel families, matching the three plane dispatches:
   (install + waiter-index patch + executed fold + mode-routed resolve).
   The resolve core is shared *by construction* with the composed path
   (``ops.graph_resolve.graph_plane_step_core``): the kernel body traces
-  the identical program, so resolved/stuck/rank/order parity is exact,
-  and on TPU the whole step lowers as one fused program where Mosaic
-  supports the traced ops (the sort-based keyed core may refuse to
-  lower — the router's first-dispatch probe then falls back to the
-  composed program for the life of the process).
+  the identical program, so resolved/stuck/rank/order parity is exact.
 * :func:`votes_commit_pallas` / :func:`table_round_pallas` — the fused
   table round (vote-range coalesce + frontier advance + stability order
   statistic as one kernel), sharing ``ops.table_ops`` cores the same
@@ -40,25 +43,18 @@ resident state aliases in-place through ``input_output_aliases`` under
 the same ``donate_argnums`` the composed programs use, so
 ``resident_uploads == 1`` holds whichever route serves.
 
-**Routing** (``Config.pallas_kernels`` > ``FANTOCH_PALLAS`` env > the
-backend default): the public ops symbols (``resolve_pred_plane_step``,
+**Routing** (``Config.pallas_kernels`` > ``FANTOCH_PALLAS`` env > off):
+the public ops symbols (``resolve_pred_plane_step``,
 ``resolve_graph_plane_step``, ``fused_votes_commit``,
 ``fused_table_round``) are routers that consult :func:`pallas_enabled`
-per dispatch.  The default is ON for TPU backends (where the fusion
-pays) and OFF elsewhere: on the CPU dev pin the kernels execute in
-Pallas *interpret mode* — the kernel body discharges to the same XLA
-ops, so parity is testable on every push (the parity suite and
-``make pallas-smoke`` force the route on), but interpret dispatch adds
-pure overhead to a serving loop, so CPU serving keeps the composed
-programs unless ``FANTOCH_PALLAS=1`` opts in.  ``FANTOCH_PALLAS=0`` is
-the escape hatch that forces the composed path everywhere, including
-TPU.
+per dispatch.  There is no fallback: a route that is on and does not
+compile raises, and :func:`pallas_status` reports the route each family
+was actually served by.
 """
 
 from __future__ import annotations
 
 import functools
-import logging
 import os
 from typing import Dict, Optional
 
@@ -73,31 +69,21 @@ from fantoch_tpu.ops.graph_resolve import (
 from fantoch_tpu.ops.pred_resolve import PredPlaneStep
 from fantoch_tpu.ops.table_ops import _fused_round_core, _votes_commit_core
 
-logger = logging.getLogger(__name__)
-
-# conservative per-dispatch VMEM budget for the fused kernels: the whole
-# resident window plus the feed columns must fit on-core or the dispatch
-# routes to the composed program (which tiles through HBM instead of
-# faulting).  v4 cores have 16 MiB of VMEM per core; half is headroom
-# for Mosaic's own temporaries.
-_VMEM_BUDGET_BYTES = 8 * (1 << 20)
-
 # ---------------------------------------------------------------------------
-# routing: Config.pallas_kernels > FANTOCH_PALLAS env > backend default
+# routing: Config.pallas_kernels > FANTOCH_PALLAS env > off
 # ---------------------------------------------------------------------------
 
 _override: Optional[bool] = None
-# first-dispatch probe verdict per kernel family: None = untried,
-# True = compiled+ran, False = refused to lower (composed fallback for
-# the life of the process — lowering failures are deterministic)
-_supported: Dict[str, Optional[bool]] = {}
+# dispatches served per family and route, counted by the router itself:
+# what pallas_status() reports is what ran, not what was asked for
+_served: Dict[str, Dict[str, int]] = {}
 
 
 def set_pallas_kernels(enabled: Optional[bool]) -> None:
     """Process-global route override: ``True``/``False`` pin the route,
-    ``None`` returns to env/backend resolution.  Like the recompile
-    counters this is process-global — co-hosted executors with
-    conflicting configs share one route (last writer wins)."""
+    ``None`` returns to env resolution.  Like the recompile counters
+    this is process-global — co-hosted executors with conflicting
+    configs share one route (last writer wins)."""
     global _override
     _override = enabled
 
@@ -105,8 +91,8 @@ def set_pallas_kernels(enabled: Optional[bool]) -> None:
 def apply_pallas_config(config) -> None:
     """Executor-construction seam: fold ``Config.pallas_kernels`` into
     the route (an explicit config value beats the env var; ``None``
-    leaves env/backend resolution in place — the
-    ``Config.device_graph_plane`` precedence convention)."""
+    leaves env resolution in place — the ``Config.device_graph_plane``
+    precedence convention)."""
     value = getattr(config, "pallas_kernels", None)
     if value is not None:
         set_pallas_kernels(bool(value))
@@ -114,82 +100,45 @@ def apply_pallas_config(config) -> None:
 
 def pallas_enabled() -> bool:
     """Resolve the route for the next dispatch: explicit override
-    (config) > ``FANTOCH_PALLAS`` env > default (on for TPU backends,
-    off elsewhere — interpret mode is a parity instrument, not a CPU
-    win; see the module docstring)."""
+    (config) > ``FANTOCH_PALLAS`` env > off.  Off is the default on
+    every backend because the composed programs are the route that
+    compiles on every backend (module docstring)."""
     if _override is not None:
         return _override
     env = os.environ.get("FANTOCH_PALLAS")
     if env is not None and env != "":
         return env not in ("0", "false", "False", "off")
-    try:
-        return jax.default_backend() == "tpu"
-    except Exception:  # backend init failure: the composed path works
-        return False
+    return False
 
 
 def _interpret() -> bool:
     """Interpret-mode switch: anything that is not a real TPU backend
     runs the kernel body through the Pallas interpreter (bit-for-bit
     the same ops, no Mosaic lowering)."""
-    try:
-        return jax.default_backend() != "tpu"
-    except Exception:
-        return True
-
-
-def _fits_vmem(*arrays) -> bool:
-    """Whole-state VMEM residency gate (compiled mode only): the fused
-    kernel keeps every operand on-core, so the operand total must fit
-    the budget.  Interpret mode has no VMEM and always fits."""
-    if _interpret():
-        return True
-    total = 0
-    for a in arrays:
-        size = 1
-        for dim in getattr(a, "shape", ()):
-            size *= int(dim)
-        total += size * jnp.dtype(getattr(a, "dtype", jnp.int32)).itemsize
-    return total <= _VMEM_BUDGET_BYTES
+    return jax.default_backend() != "tpu"
 
 
 def pallas_status() -> Dict[str, object]:
-    """Routing introspection for bench rows and the smoke: the resolved
-    route plus each family's probe verdict."""
+    """Routing introspection for bench rows and the smokes: the route
+    the next dispatch would take, and per family the dispatches each
+    route has actually served in this process."""
     return {
         "enabled": pallas_enabled(),
         "interpret": _interpret(),
-        "families": dict(_supported),
+        "served": {family: dict(routes) for family, routes in _served.items()},
     }
 
 
 def route_dispatch(family: str, pallas_fn, composed_fn, args, kwargs):
-    """The per-dispatch router: composed path when the route is off or
-    the family's probe failed; otherwise the Pallas kernel, with the
-    FIRST dispatch per family probing lowering support.  A probe
-    failure (Mosaic refusing an op on a real TPU) is caught at compile
-    time — before any donated buffer is consumed — so retrying the
-    composed program on the same arguments is safe; the family then
-    stays on the composed path for the life of the process (lowering
-    failures are deterministic, no point re-probing)."""
-    if not pallas_enabled():
-        return composed_fn(*args, **kwargs)
-    verdict = _supported.get(family)
-    if verdict is False:
-        return composed_fn(*args, **kwargs)
-    if verdict:
-        return pallas_fn(*args, **kwargs)
-    try:
-        out = pallas_fn(*args, **kwargs)
-    except Exception as exc:  # noqa: BLE001 — unsupported backend/op
-        _supported[family] = False
-        logger.warning(
-            "pallas kernel family %r unsupported on backend %r (%s); "
-            "falling back to the composed XLA program for this process",
-            family, jax.default_backend(), exc,
-        )
-        return composed_fn(*args, **kwargs)
-    _supported[family] = True
+    """The per-dispatch router: the composed program when the route is
+    off, the Pallas kernel when it is on.  Nothing is caught — a kernel
+    that does not compile on this backend raises out of the dispatch."""
+    route, fn = (
+        ("pallas", pallas_fn) if pallas_enabled() else ("xla", composed_fn)
+    )
+    out = fn(*args, **kwargs)
+    served = _served.setdefault(family, {})
+    served[route] = served.get(route, 0) + 1
     return out
 
 

@@ -204,9 +204,8 @@ def resolve_pred_plane_step(
     u_row, u_deps, u_clock, u_src, p_row, p_col, p_val,
 ) -> PredPlaneStep:
     """Route one resident pred-plane dispatch: the Pallas-fused kernel
-    when :func:`fantoch_tpu.ops.pallas_resolve.pallas_enabled` says so
-    (and the window fits VMEM), else the composed
-    :func:`resolve_pred_plane_step_xla`.  Same signature, donation set,
+    when :func:`fantoch_tpu.ops.pallas_resolve.pallas_enabled` says so,
+    else the composed :func:`resolve_pred_plane_step_xla`.  Same signature, donation set,
     and bit-for-bit output either way — executors, twin replay, and
     shadow checks all call through here, so every consumer follows one
     route."""
@@ -214,9 +213,7 @@ def resolve_pred_plane_step(
 
     args = (deps, clock, src, occ, executed,
             u_row, u_deps, u_clock, u_src, p_row, p_col, p_val)
-    if pr.pallas_enabled() and pr._fits_vmem(deps, clock, src, u_deps):
-        return pr.route_dispatch(
-            "pred_plane_step", pr.pred_plane_step_pallas,
-            resolve_pred_plane_step_xla, args, {},
-        )
-    return resolve_pred_plane_step_xla(*args)
+    return pr.route_dispatch(
+        "pred_plane_step", pr.pred_plane_step_pallas,
+        resolve_pred_plane_step_xla, args, {},
+    )

@@ -350,20 +350,17 @@ register_program("votes_commit_xla", fused_votes_commit_xla)
 
 def fused_votes_commit(frontier, vkey, vby, vstart, vend, valid, *, threshold):
     """Route one table-plane commit dispatch: the Pallas-fused kernel
-    when :func:`fantoch_tpu.ops.pallas_resolve.pallas_enabled` says so
-    (and the window fits VMEM), else the composed
-    :func:`fused_votes_commit_xla`.  Same signature, donation, and
+    when :func:`fantoch_tpu.ops.pallas_resolve.pallas_enabled` says so,
+    else the composed :func:`fused_votes_commit_xla`.  Same signature, donation, and
     bit-for-bit 7-tuple either way (the residual-column protocol is
     part of the contract)."""
     from fantoch_tpu.ops import pallas_resolve as pr
 
     args = (frontier, vkey, vby, vstart, vend, valid)
-    if pr.pallas_enabled() and pr._fits_vmem(frontier, vkey, vstart, vend):
-        return pr.route_dispatch(
-            "votes_commit", pr.votes_commit_pallas, fused_votes_commit_xla,
-            args, {"threshold": threshold},
-        )
-    return fused_votes_commit_xla(*args, threshold=threshold)
+    return pr.route_dispatch(
+        "votes_commit", pr.votes_commit_pallas, fused_votes_commit_xla,
+        args, {"threshold": threshold},
+    )
 
 
 def _fused_round_core(prior, frontier, key, min_clock, threshold, voters):
@@ -417,19 +414,16 @@ register_program("table_round_xla", fused_table_round_xla)
 
 def fused_table_round(prior, frontier, key, min_clock, *, threshold, voters):
     """Route one dense table round: the Pallas-fused kernel when
-    :func:`fantoch_tpu.ops.pallas_resolve.pallas_enabled` says so (and
-    the tables fit VMEM), else the composed
-    :func:`fused_table_round_xla`.  Bit-for-bit either way."""
+    :func:`fantoch_tpu.ops.pallas_resolve.pallas_enabled` says so, else
+    the composed :func:`fused_table_round_xla`.  Bit-for-bit either way."""
     from fantoch_tpu.ops import pallas_resolve as pr
 
     args = (prior, frontier, key, min_clock)
     kwargs = {"threshold": threshold, "voters": voters}
-    if pr.pallas_enabled() and pr._fits_vmem(prior, frontier, key):
-        return pr.route_dispatch(
-            "table_round", pr.table_round_pallas, fused_table_round_xla,
-            args, kwargs,
-        )
-    return fused_table_round_xla(*args, **kwargs)
+    return pr.route_dispatch(
+        "table_round", pr.table_round_pallas, fused_table_round_xla,
+        args, kwargs,
+    )
 
 
 @functools.partial(

@@ -54,18 +54,8 @@ from typing import NamedTuple, Tuple
 
 import jax
 import jax.numpy as jnp
+from jax import shard_map
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
-
-# jax >= 0.5 exposes shard_map at top level; 0.4.x keeps it experimental
-# and spells the replication-check kwarg check_rep instead of check_vma
-shard_map = getattr(jax, "shard_map", None)
-if shard_map is None:
-    from jax.experimental.shard_map import shard_map as _shard_map_experimental
-
-    def shard_map(f, *, check_vma=None, **kwargs):
-        if check_vma is not None:
-            kwargs["check_rep"] = check_vma
-        return _shard_map_experimental(f, **kwargs)
 
 from fantoch_tpu.ops.graph_resolve import (
     MISSING,

@@ -48,6 +48,12 @@ class ProcessMetrics:
     # before the fields existed (dataclass defaults cover old pickles)
     queues: Optional[Dict[str, Dict[str, float]]] = None
     overload: Optional[Dict[str, float]] = None
+    # what produced the ``device`` counters: platform, device_kind and
+    # device_count as jax reports them, and the mesh shape (None here —
+    # the executor planes run on the default device; the device-step
+    # runtime's JSON snapshot carries its (replica x batch) mesh).  None
+    # whenever ``device`` is None
+    backend: Optional[Dict[str, Any]] = None
 
 
 def write_metrics_snapshot(path: str, metrics: ProcessMetrics) -> None:

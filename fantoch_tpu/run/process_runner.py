@@ -851,14 +851,7 @@ class ProcessRuntime:
         prof.set_registry(self.prof_registry)
         # count XLA recompiles for the metrics snapshot when any device
         # plane can compile (the hook is process-global and idempotent)
-        if (
-            self.config.device_table_plane
-            or self.config.device_pred_plane
-            or self.config.device_graph_plane
-            or self.config.batched_graph_executor
-            or self.config.batched_table_executor
-            or self.config.batched_pred_executor
-        ):
+        if self.config.dispatches_to_device():
             from fantoch_tpu.core.compile_cache import ensure_compile_cache
             from fantoch_tpu.observability.device import subscribe_recompiles
 
@@ -866,7 +859,7 @@ class ProcessRuntime:
             # persistent compile cache before the first dispatch:
             # restarted processes reload programs from disk instead of
             # re-paying the compile wall
-            ensure_compile_cache(self.config, obs_dir=self._obs_dir())
+            ensure_compile_cache()
         peer_server = await asyncio.start_server(self._on_peer, *self.listen_addr)
         client_server = await asyncio.start_server(self._on_client, *self.client_addr)
         self._servers = [peer_server, client_server]
@@ -1933,6 +1926,11 @@ class ProcessRuntime:
             # the latency breakdown it explains
             for name, value in sorted(overload.items()):
                 self.tracer.counter(name, value, pid=self.process.id)
+        backend = None
+        if device is not None:
+            from fantoch_tpu.hostenv import device_report
+
+            backend = {**device_report(), "mesh_shape": None}
         write_metrics_snapshot(
             self.metrics_file,
             ProcessMetrics(
@@ -1941,6 +1939,7 @@ class ProcessRuntime:
                 device,
                 queues,
                 overload,
+                backend,
             ),
         )
 

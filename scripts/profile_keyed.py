@@ -10,19 +10,19 @@ import sys
 import time
 
 import jax
-
-jax.config.update("jax_compilation_cache_dir", "/tmp/jaxcache")
-jax.config.update("jax_persistent_cache_min_compile_time_secs", 1.0)
 import jax.numpy as jnp
 import numpy as np
 
 sys.path.insert(0, ".")
 from bench import BATCH, CONFLICT, build_workload  # noqa: E402
+from fantoch_tpu.core.compile_cache import ensure_compile_cache  # noqa: E402
 from fantoch_tpu.ops.graph_resolve import (  # noqa: E402
     TERMINAL,
     _doubling_core,
     _residual_size_for,
 )
+
+ensure_compile_cache()
 
 RES = _residual_size_for(BATCH)
 

@@ -25,9 +25,10 @@ A second leg re-runs the Pallas parity suite
 (tests/test_pallas_resolve.py) in its own pytest process with
 ``FANTOCH_PALLAS=1`` forced through the environment: tier-1 already runs
 the suite with routes forced per-test, but this leg additionally proves
-the ENV escape-hatch path — the route every executor takes when the flag
-is set rig-wide — end to end on whatever backend is attached (interpret
-mode on the CPU pin, Mosaic-lowered kernels on a TPU rig).
+the ENV opt-in path — the route every executor takes when the flag
+is set process-wide — end to end in interpret mode on the CPU pin (on a
+TPU the kernels do not lower and the suite would raise,
+ops/pallas_resolve.py).
 
 Usage: make test-device-stripped  (or: python scripts/run_device_stripped.py)
 """

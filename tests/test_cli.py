@@ -6,6 +6,7 @@ Reference: fantoch_ps/src/bin/{common/protocol.rs,client.rs,simulation.rs,
 shard_distribution.rs,graph_executor_replay.rs} and the reference's own
 3-process localhost smoke scripts (bin/{proc,client,bench})."""
 
+import glob
 import json
 import os
 import signal
@@ -22,10 +23,67 @@ REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 
 def cli_env():
     env = dict(os.environ)
-    env["FANTOCH_PLATFORM"] = "cpu"
+    env["JAX_PLATFORMS"] = "cpu"
     env["PYTHONPATH"] = REPO
-    env.pop("JAX_PLATFORMS", None)
     return env
+
+
+HAS_TPU_NODE = bool(
+    glob.glob("/dev/accel*") or glob.glob("/dev/vfio/[0-9]*")
+)
+
+
+@pytest.mark.skipif(HAS_TPU_NODE, reason="this machine has a TPU")
+@pytest.mark.parametrize(
+    "argv",
+    [
+        ["-m", "fantoch_tpu.bin.server", "--device-step", "--protocol",
+         "epaxos", "-n", "3", "-f", "1", "--client-port", "1"],
+        ["-m", "fantoch_tpu.bin.server", "--protocol", "epaxos", "--id", "1",
+         "--port", "1", "--client-port", "2", "--addresses", "2=127.0.0.1:3",
+         "--sorted", "1:0,2:0", "-n", "3", "-f", "1",
+         "--batched-graph-executor"],
+        ["bench.py"],
+    ],
+    ids=["device-step", "batched-executor", "bench-full-mode"],
+)
+def test_device_entry_points_refuse_a_silent_cpu(argv):
+    """The platform rule: with JAX_PLATFORMS unset and no chip, jax
+    falls back to the CPU without a word — every entry point that
+    dispatches to a device must exit non-zero within seconds, before it
+    binds a port, naming what it found.  (With JAX_PLATFORMS=cpu the
+    same binaries serve: every other row of this file, and
+    tests/test_chip_smoke.py's served leg.)"""
+    env = cli_env()
+    del env["JAX_PLATFORMS"]
+    t0 = time.monotonic()
+    out = subprocess.run(
+        [sys.executable, *argv], capture_output=True, text=True, timeout=60,
+        env=env, cwd=REPO,
+    )
+    assert out.returncode != 0, out.stdout
+    assert "needs the TPU but jax fell back to 'cpu'" in out.stderr
+    assert out.stdout == "" and time.monotonic() - t0 < 60
+
+
+def test_ci_pins_the_supported_jax():
+    from fantoch_tpu.hostenv import SUPPORTED_JAX
+
+    with open(os.path.join(REPO, ".github", "workflows", "ci.yml")) as fh:
+        ci = fh.read()
+    assert ci.count(f'"jax[cpu]=={SUPPORTED_JAX}"') == 2  # both jobs
+
+
+def test_client_never_imports_jax():
+    """bin/client shares the host with the process that owns the chip:
+    it must not initialise (or even import) a backend."""
+    code = (
+        "import sys; import fantoch_tpu.bin.client, "
+        "fantoch_tpu.run.client_runner; "
+        "assert 'jax' not in sys.modules and 'jaxlib' not in sys.modules"
+    )
+    subprocess.run([sys.executable, "-c", code], check=True, env=cli_env(),
+                   cwd=REPO, timeout=60)
 
 
 def run_tool(module, args, timeout=120):

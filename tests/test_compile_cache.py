@@ -1,6 +1,10 @@
 """Compile-wall regression suite (core/compile_cache.py).
 
-Two proofs the ISSUE demands:
+Three proofs:
+
+* **Placement from outside** — ``JAX_COMPILATION_CACHE_DIR`` set puts
+  the entries there and the code sets no directory; unset, the cache is
+  ``<checkout>/.jax_cache``.
 
 * **Compiled-identity discipline** — a multi-point sweep over batch
   sizes routed through the canonicalized (pow2-padded) shapes compiles
@@ -48,19 +52,91 @@ def test_registry_counts_and_identities():
         compile_cache._programs.pop("_toy", None)
 
 
-def test_resolve_cache_dir_precedence(monkeypatch, tmp_path):
-    """config > FANTOCH_COMPILE_CACHE_DIR > obs-dir default > None."""
-    from fantoch_tpu.core.config import Config
+def test_listeners_accept_the_keywords_jax_passes():
+    """jax 0.9.0 calls monitoring listeners as callback(event,
+    [duration,] **kwargs): a compile event carrying ``fun_name=`` must
+    be counted, not kill the process at its first compile (the one
+    cause of the 194 seed failures)."""
+    from jax import monitoring
 
-    monkeypatch.delenv("FANTOCH_COMPILE_CACHE_DIR", raising=False)
-    assert compile_cache.resolve_cache_dir(None) is None
-    assert compile_cache.resolve_cache_dir(
-        None, obs_dir=str(tmp_path)
-    ) == os.path.join(str(tmp_path), ".jax_cache")
-    monkeypatch.setenv("FANTOCH_COMPILE_CACHE_DIR", "/env/dir")
-    assert compile_cache.resolve_cache_dir(None, obs_dir=str(tmp_path)) == "/env/dir"
-    cfg = Config(3, 1, compile_cache_dir="/cfg/dir")
-    assert compile_cache.resolve_cache_dir(cfg, obs_dir=str(tmp_path)) == "/cfg/dir"
+    from fantoch_tpu.observability import device
+
+    assert device.subscribe_recompiles()
+    before_n, before_ms = device.recompile_count(), device.compile_ms()
+    before_hits = device.cache_hit_count()
+    monitoring.record_event_duration_secs(
+        "/jax/core/compile/backend_compile_duration", 0.25, fun_name="toy"
+    )
+    assert device.recompile_count() == before_n + 1
+    assert device.compile_ms() >= before_ms + 250.0
+    # a cache hit reclassifies the duration event after it as a retrieval
+    monitoring.record_event("/jax/compilation_cache/cache_hits", fun_name="toy")
+    monitoring.record_event_duration_secs(
+        "/jax/core/compile/backend_compile_duration", 0.01, fun_name="toy"
+    )
+    assert device.cache_hit_count() == before_hits + 1
+    assert device.recompile_count() == before_n + 1
+
+
+_PLACEMENT = textwrap.dedent(
+    """
+    import json, os, sys
+    from fantoch_tpu.hostenv import force_cpu_platform
+    force_cpu_platform()
+    import jax
+
+    updates = []
+    real_update = jax.config.update
+    def spy(name, value):
+        updates.append(name)
+        return real_update(name, value)
+    jax.config.update = spy
+
+    from fantoch_tpu.core.compile_cache import ensure_compile_cache
+    in_effect = ensure_compile_cache()
+    assert ensure_compile_cache() == in_effect  # idempotent
+
+    import jax.numpy as jnp
+    jax.jit(lambda x: x * 3 + 1)(jnp.arange(8)).block_until_ready()
+    print(json.dumps({
+        "in_effect": in_effect,
+        "config_dir": jax.config.jax_compilation_cache_dir,
+        "set_dir_in_code": "jax_compilation_cache_dir" in updates,
+    }))
+    """
+)
+
+
+def _run_placement(env_dir):
+    repo = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    env = {k: v for k, v in os.environ.items()
+           if k != "JAX_COMPILATION_CACHE_DIR"}
+    if env_dir is not None:
+        env["JAX_COMPILATION_CACHE_DIR"] = env_dir
+    out = subprocess.run(
+        [sys.executable, "-c", _PLACEMENT],
+        capture_output=True, text=True, timeout=300, cwd=repo, env=env,
+    )
+    assert out.returncode == 0, out.stderr[-2000:]
+    return repo, json.loads(out.stdout.strip().splitlines()[-1])
+
+
+def test_cache_dir_from_environment(tmp_path):
+    """JAX_COMPILATION_CACHE_DIR set => the entries land there and the
+    code sets no directory of its own."""
+    env_dir = str(tmp_path / "placed")
+    _repo, got = _run_placement(env_dir)
+    assert got["in_effect"] == got["config_dir"] == env_dir
+    assert got["set_dir_in_code"] is False
+    assert os.listdir(env_dir), "no cache entry under JAX_COMPILATION_CACHE_DIR"
+
+
+def test_cache_dir_default_is_the_checkout():
+    """JAX_COMPILATION_CACHE_DIR unset => <checkout>/.jax_cache and
+    nowhere else."""
+    repo, got = _run_placement(None)
+    assert got["in_effect"] == got["config_dir"] == os.path.join(repo, ".jax_cache")
+    assert got["set_dir_in_code"] is True
 
 
 def test_plane_sweep_compiles_each_program_once():
@@ -144,11 +220,8 @@ _SUBPROC = textwrap.dedent(
         subscribe_recompiles,
     )
 
-    class Cfg:
-        compile_cache_dir = sys.argv[1]
-
     subscribe_recompiles()
-    ensure_compile_cache(Cfg())
+    ensure_compile_cache()
 
     import numpy as np
     from fantoch_tpu.ops.table_ops import fused_votes_commit_xla
@@ -179,9 +252,11 @@ def test_cold_vs_warm_persistent_cache(tmp_path):
 
     def run():
         out = subprocess.run(
-            [sys.executable, "-c", _SUBPROC, str(tmp_path / "cache")],
+            [sys.executable, "-c", _SUBPROC],
             capture_output=True, text=True, timeout=300,
             cwd=os.path.dirname(os.path.dirname(os.path.abspath(__file__))),
+            env={**os.environ,
+                 "JAX_COMPILATION_CACHE_DIR": str(tmp_path / "cache")},
         )
         assert out.returncode == 0, out.stderr[-2000:]
         return json.loads(out.stdout.strip().splitlines()[-1])

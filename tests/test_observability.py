@@ -645,3 +645,42 @@ def test_localhost_trace_covers_lifecycle(tmp_path):
     for span in spans.values():
         assert set(span["stages"]) == set(STAGES), span
     assert monotonic_violations(spans) == []
+
+
+def test_snapshot_names_the_backend_that_served(tmp_path):
+    """A process whose executors dispatch to a device says which device
+    in every snapshot (``backend``: platform, device_kind, device_count,
+    mesh_shape) — a host-only process carries neither ``device`` nor
+    ``backend``."""
+    workload = Workload(
+        shard_count=1, key_gen=ConflictRateKeyGen(50), keys_per_command=1,
+        commands_per_client=5, payload_size=1,
+    )
+    for device_pred_plane in (True, False):
+        config = Config(
+            n=3, f=1, gc_interval_ms=50,
+            executor_executed_notification_interval_ms=50,
+            device_pred_plane=device_pred_plane,
+        )
+        out_dir = tmp_path / f"plane_{device_pred_plane}"
+        out_dir.mkdir()
+        from fantoch_tpu.protocol import Caesar
+
+        asyncio.run(
+            run_localhost_cluster(
+                Caesar, config, workload, clients_per_process=1,
+                extra_run_time_ms=400, observe_dir=str(out_dir),
+            )
+        )
+        snaps = sorted(glob.glob(str(out_dir / "metrics_p*.gz")))
+        assert len(snaps) == 3
+        for path in snaps:
+            snap = read_metrics_snapshot(path)
+            if device_pred_plane:
+                assert snap.device["pred_plane_dispatches"] > 0
+                assert snap.backend["platform"] == "cpu"
+                assert set(snap.backend) == {
+                    "platform", "device_kind", "device_count", "mesh_shape"
+                }
+            else:
+                assert snap.device is None and snap.backend is None

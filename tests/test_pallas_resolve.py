@@ -6,12 +6,12 @@ return bit-for-bit the composed route's outputs (same resolved/stuck/
 rank/order, same residual-column protocol), under the same donation
 discipline (``resident_uploads == 1`` at the executor level).  On the
 CPU pin the kernels run in Pallas interpret mode, so this suite proves
-the contract on every push; on a TPU backend the same tests exercise the
-Mosaic-lowered kernels (scripts/run_device_stripped.py re-runs the suite
-with ``FANTOCH_PALLAS=1`` forced through the executor stack).
+the contract on every push (scripts/run_device_stripped.py re-runs the
+suite with ``FANTOCH_PALLAS=1`` forced through the executor stack).  On
+the TPU the kernels do not lower (ops/pallas_resolve.py).
 
 Every test forces the route explicitly (``set_pallas_kernels``) so the
-suite is independent of the backend default (off on CPU).
+suite is independent of the default (off).
 """
 
 import contextlib
@@ -277,11 +277,12 @@ def test_table_round_parity_chain():
 
 
 def test_route_resolution_precedence(monkeypatch):
-    """Config override beats the env var beats the backend default (off
-    on the CPU pin), and FANTOCH_PALLAS=0 is the escape hatch."""
+    """Config override beats the env var beats the default, which is
+    off on every backend (the composed programs are the route that
+    compiles everywhere)."""
     monkeypatch.delenv("FANTOCH_PALLAS", raising=False)
     pallas_resolve.set_pallas_kernels(None)
-    assert pallas_resolve.pallas_enabled() is False  # CPU default
+    assert pallas_resolve.pallas_enabled() is False  # the default
     monkeypatch.setenv("FANTOCH_PALLAS", "1")
     assert pallas_resolve.pallas_enabled() is True
     monkeypatch.setenv("FANTOCH_PALLAS", "0")
@@ -309,48 +310,40 @@ def test_apply_pallas_config():
         pallas_resolve.set_pallas_kernels(None)
 
 
-def test_unsupported_family_falls_back_for_process_life():
-    """A kernel that fails to lower routes that dispatch to the composed
-    program (the args are intact: lowering fails before donation
-    consumes buffers) and pins the family to the composed path."""
+def test_route_on_and_kernel_raising_propagates():
+    """There is no fallback: with the route on, a kernel that fails to
+    compile raises out of the dispatch and the composed program is not
+    tried in its place; pallas_status counts only what was served."""
     calls = {"pallas": 0, "composed": 0}
 
     def bad_kernel(x):
         calls["pallas"] += 1
-        raise RuntimeError("mosaic lowering refused")
+        raise NotImplementedError(
+            "Unimplemented primitive in Pallas TPU lowering: scatter"
+        )
 
     def composed(x):
         calls["composed"] += 1
         return x + 1
 
-    pallas_resolve._supported.pop("_test_family", None)
-    with forced_pallas(True):
-        out = pallas_resolve.route_dispatch(
-            "_test_family", bad_kernel, composed, (1,), {}
-        )
-        assert out == 2
-        assert pallas_resolve._supported["_test_family"] is False
-        # second dispatch: straight to composed, no re-probe
-        out = pallas_resolve.route_dispatch(
-            "_test_family", bad_kernel, composed, (2,), {}
-        )
-        assert out == 3
-    assert calls == {"pallas": 1, "composed": 2}
-    pallas_resolve._supported.pop("_test_family", None)
-
-
-def test_vmem_gate_routes_oversized_to_composed():
-    """In compiled (non-interpret) mode an operand set past the VMEM
-    budget must route composed; interpret mode always fits."""
-    big = np.zeros((4096, 4096), np.int32)  # 64 MiB > the 8 MiB budget
-    assert pallas_resolve._fits_vmem(big) is True  # interpret on CPU
-    # emulate a compiled backend by bypassing the interpret short-circuit
-    import unittest.mock as mock
-
-    with mock.patch.object(pallas_resolve, "_interpret", return_value=False):
-        assert pallas_resolve._fits_vmem(big) is False
-        small = np.zeros((64, 64), np.int32)
-        assert pallas_resolve._fits_vmem(small) is True
+    pallas_resolve._served.pop("_test_family", None)
+    try:
+        with forced_pallas(True):
+            with pytest.raises(NotImplementedError, match="scatter"):
+                pallas_resolve.route_dispatch(
+                    "_test_family", bad_kernel, composed, (1,), {}
+                )
+        assert calls == {"pallas": 1, "composed": 0}
+        assert "_test_family" not in pallas_resolve.pallas_status()["served"]
+        with forced_pallas(False):
+            assert pallas_resolve.route_dispatch(
+                "_test_family", bad_kernel, composed, (1,), {}
+            ) == 2
+        assert pallas_resolve.pallas_status()["served"]["_test_family"] == {
+            "xla": 1
+        }
+    finally:
+        pallas_resolve._served.pop("_test_family", None)
 
 
 # ---------------------------------------------------------------------------
