@@ -227,9 +227,8 @@ async def serve_device_step(args: argparse.Namespace) -> None:
     finally:
         # runs under task cancellation too (Ctrl-C or SIGTERM through
         # asyncio.run): short serves must still leave a final metrics
-        # snapshot
-        if runtime.metrics_file is not None or runtime.telemetry is not None:
-            runtime._emit_telemetry()
+        # snapshot (and the round-stage spans beside it)
+        runtime.emit_final()
 
 
 def _arm_flight_signal(runtime) -> None:

@@ -23,11 +23,21 @@ by a cache hit is a retrieval (counted in :func:`cache_hit_count`, its
 wall in :func:`compile_ms` — retrieval stalls serving just like a
 compile, only shorter), everything else is a TRUE compile.  That makes
 ``jax_recompiles == 0`` the proof a warm-cache sweep never paid XLA.
+
+The served path's host time is split by :class:`StageRecorder`: one span
+per stage of a round (never per command), summed into ``stage_<name>_ms``
+/ ``stage_<name>_n`` beside the tallies above, annotated on the
+profiler's clock, and kept in a bounded ring that the runtime writes out
+when it stops.
 """
 
 from __future__ import annotations
 
-from typing import Callable, Dict, Optional
+import json
+import threading
+import time
+from collections import deque
+from typing import Deque, Dict, List, Optional, Tuple
 
 _recompiles = 0
 _compile_ms = 0.0
@@ -161,3 +171,139 @@ def derive_idle_frac(counters: Dict[str, float]) -> Dict[str, float]:
             max(0.0, 1.0 - busy / span), 4
         )
     return counters
+
+
+# --- round-stage spans (the served path's host time, per round) ---
+
+# the stages of a served round (run/device_runner.py ``_driver_task`` and
+# run/pipeline.py name the sites); declared up front so every counter is
+# in the first snapshot and a reader can take deltas of all of them
+ROUND_STAGES = (
+    "idle_wait", "gate_wait", "collect", "handoff", "step", "assemble",
+    "enqueue", "fetch", "execute", "resume", "deliver", "publish", "round",
+    # on the loop beside the rounds: the telemetry tick's snapshot write,
+    # the probe's late wake-ups, the interpreter's full collections
+    "snapshot", "loop_stall", "gc",
+)
+SPAN_RING = 65536  # closed spans kept for the dump (~40 min of open-loop rounds)
+
+
+class _Span:
+    """One open stage: ``with recorder.span(name, round)``.  ``t0`` and
+    ``t1`` (``time.monotonic_ns``) stay readable after the block."""
+
+    __slots__ = ("_rec", "name", "round", "parent", "_cpu0", "_note", "t0", "t1")
+
+    def __init__(self, rec, name, round_id, parent, cpu):
+        self._rec = rec
+        self.name = name
+        self.round = round_id
+        self.parent = parent
+        self._cpu0 = 0 if cpu else None  # thread CPU time at entry, where asked for
+        self._note = rec._annotation("fantoch/" + name, round=round_id)
+        self.t0 = self.t1 = 0
+
+    def __enter__(self):
+        stack = self._rec._stack()
+        if self.parent is None and stack:
+            self.parent = stack[-1]
+        stack.append(self.name)
+        self._note.__enter__()
+        if self._cpu0 is not None:
+            self._cpu0 = time.thread_time_ns()
+        self.t0 = time.monotonic_ns()
+        return self
+
+    def __exit__(self, *exc):
+        self.t1 = time.monotonic_ns()
+        rec = self._rec
+        if self._cpu0 is not None:
+            rec.cpu_ns[self.name] = (
+                rec.cpu_ns.get(self.name, 0) + time.thread_time_ns() - self._cpu0
+            )
+        self._note.__exit__(*exc)
+        rec._stack().pop()
+        rec.record(self.name, self.t0, self.t1, self.round, self.parent)
+        return False
+
+
+class StageRecorder:
+    """Where a served round's host time goes.  One recorder per driver
+    (``PipelineCore._init_pipeline`` creates it, ``DeviceRuntime`` shares
+    it), three sinks per span: cumulative wall time and count per stage
+    (:meth:`counters`, folded into the metrics snapshot), a
+    ``jax.profiler.TraceAnnotation`` named ``fantoch/<stage>`` so the
+    span lands in a profiler capture on the clock of the device planes
+    (near free while no capture runs), and a bounded ring of closed spans
+    ``(name, t0_ns, t1_ns, round, thread, parent)`` for :meth:`dump`.
+
+    The clock is ``time.monotonic_ns``, the one load generators stamp
+    ``due`` / ``sent`` / ``acked`` with.  Spans open on the event loop
+    and on the pool thread that runs the step; each stage is written by
+    one thread at a time, so no lock is taken."""
+
+    clock = staticmethod(time.monotonic_ns)
+
+    def __init__(self, ring: int = SPAN_RING):
+        from jax.profiler import TraceAnnotation
+
+        self._annotation = TraceAnnotation
+        self.ns: Dict[str, int] = dict.fromkeys(ROUND_STAGES, 0)
+        self.n: Dict[str, int] = dict.fromkeys(ROUND_STAGES, 0)
+        self.cpu_ns: Dict[str, int] = {"step": 0}
+        self.ring: Deque[Tuple[str, int, int, int, int, Optional[str]]] = deque(
+            maxlen=ring
+        )
+        self._local = threading.local()
+
+    def _stack(self) -> List[str]:
+        try:
+            return self._local.stack
+        except AttributeError:
+            stack = self._local.stack = []
+            return stack
+
+    def span(self, name: str, round_id: int = 0, parent: Optional[str] = None,
+             cpu: bool = False) -> _Span:
+        """Context manager for one stage of round ``round_id``.  The
+        parent is the innermost span open on this thread unless named
+        (a span whose parent runs on the other thread names it);
+        ``cpu`` also sums the thread's CPU time (``stage_<name>_cpu_ms``)."""
+        return _Span(self, name, round_id, parent, cpu)
+
+    def record(self, name: str, t0_ns: int, t1_ns: int, round_id: int = 0,
+               parent: Optional[str] = None) -> None:
+        """A closed interval whose ends were read elsewhere (a hand-off
+        between threads, a late wake-up): counters and ring, no
+        annotation."""
+        self.ns[name] = self.ns.get(name, 0) + t1_ns - t0_ns
+        self.n[name] = self.n.get(name, 0) + 1
+        self.ring.append(
+            (name, t0_ns, t1_ns, round_id, threading.get_ident(), parent)
+        )
+
+    def ms(self, *names: str) -> float:
+        """Cumulative wall milliseconds of the named stages."""
+        return sum(self.ns.get(name, 0) for name in names) / 1e6
+
+    def counters(self) -> Dict[str, float]:
+        out: Dict[str, float] = {}
+        for name, total in self.ns.items():
+            out[f"stage_{name}_ms"] = round(total / 1e6, 3)
+            out[f"stage_{name}_n"] = self.n[name]
+        for name, total in self.cpu_ns.items():
+            out[f"stage_{name}_cpu_ms"] = round(total / 1e6, 3)
+        return out
+
+    def dump(self, path: str) -> None:
+        """The ring as JSON: ``spans`` rows in closing order, times in
+        ns of ``time.monotonic_ns``."""
+        with open(path, "w") as fh:
+            json.dump(
+                {
+                    "clock": "monotonic_ns",
+                    "columns": ["name", "t0_ns", "t1_ns", "round", "thread", "parent"],
+                    "spans": list(self.ring),
+                },
+                fh,
+            )

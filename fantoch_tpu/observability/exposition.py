@@ -195,7 +195,12 @@ async def capture_device_profile(out_dir: str, ms: int) -> Dict[str, Any]:
     """One jax.profiler capture of ``ms`` milliseconds, saved under
     ``out_dir/device_trace_<epoch_ms>``.  Serialized (one capture at a
     time) and cooperative: the sleep yields, so serving continues while
-    the profiler records it."""
+    the profiler records it.  The python tracer stays off (it slows the
+    host it measures several times over); the host tracer stays on, so
+    the capture keeps the runtime's own events and the ``fantoch/*``
+    stage annotations (observability/device.py) beside the device
+    planes.  ``stop_trace`` serialises and writes the capture: it runs
+    on a pool thread, not on the loop."""
     global _capture_active
     try:
         from jax import profiler
@@ -207,9 +212,13 @@ async def capture_device_profile(out_dir: str, ms: int) -> Dict[str, Any]:
     path = f"{out_dir}/device_trace_{_time.time_ns() // 1_000_000}"
     _capture_active = True
     try:
-        profiler.start_trace(path)
+        options = profiler.ProfileOptions()
+        options.python_tracer_level = 0
+        profiler.start_trace(path, profiler_options=options)
         await asyncio.sleep(ms / 1000)
-        profiler.stop_trace()
+        await asyncio.get_running_loop().run_in_executor(
+            None, profiler.stop_trace
+        )
     except Exception as exc:  # noqa: BLE001 — a failed capture must not kill serving
         return {"error": f"profiler capture failed: {exc!r}"}
     finally:

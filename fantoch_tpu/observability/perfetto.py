@@ -11,6 +11,9 @@ Emits the (legacy, universally-supported) Trace Event Format that both
   crossed the network (the critpath stitching, rendered);
 - counter (``ph: "C"``) events for the device-plane tallies, one track
   per counter name;
+- one complete event per round-stage span (``k == "rs"``) on the serving
+  process's ``rounds`` track, so a command's segments read against the
+  round that carried it;
 - metadata (``ph: "M"``) events naming process tracks.
 
 Timestamps are microseconds, exactly as recorded (virtual in sim
@@ -28,6 +31,7 @@ from fantoch_tpu.observability.report import assemble_spans, span_segments
 # pid, e.g. jax_recompiles) get their own track rather than polluting it
 CLIENT_PID = 0
 GLOBAL_PID = -1
+ROUNDS_TID = "rounds"  # the row of a process track that holds its rounds
 
 
 def to_perfetto(events: List[Dict[str, Any]]) -> Dict[str, Any]:
@@ -101,6 +105,25 @@ def to_perfetto(events: List[Dict[str, Any]]) -> Dict[str, Any]:
                 "id": flow_id, "ts": hop["tr"], "pid": hop["dst"],
                 "tid": tid,
             })
+    for ev in events:
+        if ev.get("k") != "rs":
+            continue
+        pid = ev.get("pid")
+        if pid is None:
+            pid = GLOBAL_PID
+        pids.add(pid)
+        trace.append(
+            {
+                "name": ev["name"],
+                "cat": "round",
+                "ph": "X",
+                "ts": ev["t0"],
+                "dur": ev["t1"] - ev["t0"],
+                "pid": pid,
+                "tid": ROUNDS_TID,
+                "args": {"round": ev["round"]},
+            }
+        )
     for ev in events:
         if ev.get("k") != "ctr":
             continue

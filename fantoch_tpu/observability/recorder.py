@@ -39,6 +39,7 @@ from fantoch_tpu.observability.tracer import (
     counter_event,
     edge_event,
     offset_event,
+    round_span_event,
     span_event,
 )
 
@@ -123,6 +124,15 @@ class FlightRecorder:
             offset_event(self._time.micros(), pid, peer, offset_us, rtt_us)
         )
         self.inner.offset(pid, peer, offset_us, rtt_us)
+
+    def round_span(self, name, t0_ns, t1_ns, round_id, now_ns, pid=None) -> None:
+        self._ring.append(
+            round_span_event(
+                self._time.micros(), name, t0_ns, t1_ns, round_id, now_ns,
+                pid=pid,
+            )
+        )
+        self.inner.round_span(name, t0_ns, t1_ns, round_id, now_ns, pid=pid)
 
     def flush(self) -> None:
         self.inner.flush()

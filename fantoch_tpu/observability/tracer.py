@@ -46,6 +46,15 @@ added for cross-process critical-path attribution
   round-trip that bounds its error), emitted by the run layer whenever
   a heartbeat RTT sample improves the estimate (run/links.py).
 
+One more kind belongs to the device-step serving path alone:
+
+- ``k == "rs"``: a finished *round-stage span* (``name``, ``t0``,
+  ``t1`` in the log's own microseconds, ``round`` = the dispatch
+  number) from the round-stage recorder (observability/device.py), so
+  a command's stages can be read against the round that carried it
+  (its ``ingest`` stamp carries the same ``round`` in its meta).
+  Unsampled: there are a few per round, not per command.
+
 Sampling is a deterministic hash of the span id (:func:`span_hash` over
 ``(rifl.source, rifl.sequence)``) against ``Config.trace_sample_rate``:
 the same seed yields the same sampled dot set, with no RNG state touched
@@ -147,6 +156,19 @@ def offset_event(t_us, pid, peer, offset_us, rtt_us):
     }
 
 
+def round_span_event(t_us, name, t0_ns, t1_ns, round_id, now_ns, pid=None):
+    """A round-stage span timed in ns of the recorder's clock, which reads
+    ``now_ns`` at ``t_us`` of the log's: the ends go into the log's own
+    microseconds."""
+    ev: Dict[str, Any] = {
+        "k": "rs", "name": name, "t0": t_us - (now_ns - t0_ns) // 1000,
+        "t1": t_us - (now_ns - t1_ns) // 1000, "round": round_id, "t": t_us,
+    }
+    if pid is not None:
+        ev["pid"] = pid
+    return ev
+
+
 def edge_dot(msg: Any):
     """The dot a protocol message's trace edges key on: a single
     ``.dot`` field (MCollect/MCollectAck/MCommit/... across the
@@ -185,6 +207,9 @@ class _NoopTracer:
         pass
 
     def offset(self, pid, peer, offset_us, rtt_us) -> None:
+        pass
+
+    def round_span(self, name, t0_ns, t1_ns, round_id, now_ns, pid=None) -> None:
         pass
 
     def flush(self) -> None:
@@ -298,6 +323,16 @@ class Tracer:
         whenever a better (lower-RTT) heartbeat sample lands."""
         self._write(
             offset_event(self._time.micros(), pid, peer, offset_us, rtt_us)
+        )
+
+    def round_span(self, name, t0_ns, t1_ns, round_id, now_ns, pid=None) -> None:
+        """A finished round-stage span (ends in ns of the recorder's
+        clock, which reads ``now_ns`` now)."""
+        self._write(
+            round_span_event(
+                self._time.micros(), name, t0_ns, t1_ns, round_id, now_ns,
+                pid=pid,
+            )
         )
 
     def _write(self, ev: Dict[str, Any]) -> None:

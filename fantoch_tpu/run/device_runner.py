@@ -45,7 +45,10 @@ CommandResult over that same connection.
 from __future__ import annotations
 
 import asyncio
+import gc
+import os
 from collections import deque
+from time import monotonic, monotonic_ns
 from typing import Any, Deque, Dict, List, NamedTuple, Optional, Tuple
 
 import numpy as np
@@ -199,8 +202,8 @@ class _DriverCore(PipelineCore):
         self.stable_watermark = 0
         # the depth-K dispatch/drain pipeline + staging ingest ring +
         # per-dispatch counters (step/step_pipelined/flush_pipeline and
-        # _staging come from PipelineCore; drivers implement the
-        # dispatch()/drain() split)
+        # _staging come from PipelineCore; drivers implement the halves
+        # _assemble / _enqueue of a dispatch and _execute of a drain)
         self._init_pipeline()
 
     @property
@@ -245,13 +248,10 @@ class _DriverCore(PipelineCore):
             key_width=key_width,
         )
 
-    def _dispatch_dot_keyed(self, batch: List[Tuple[Dot, Command]]):
-        """Shared dispatch body for the dot-keyed drivers (Newt/Caesar):
-        assemble the fixed-size key/src/seq columns, register commands
-        under packed (source, window sequence), and submit one device
-        round; returns the round token for ``drain``."""
-        import jax.numpy as jnp
-
+    def _assemble(self, batch: List[Tuple[Dot, Command]]):
+        """The dot-keyed drivers' assembly (Newt/Caesar): fill the
+        fixed-size key/src/seq columns and register commands under
+        packed (source, window sequence)."""
         from fantoch_tpu.parallel.mesh_step import KEY_PAD
 
         assert len(batch) <= self.batch_size
@@ -263,9 +263,15 @@ class _DriverCore(PipelineCore):
             ("seq", (b,), np.int32, 0),
         )
         self._assemble_rows(batch, key, src, seq)
+        return key, src, seq
+
+    def _enqueue(self, columns):
+        """Submit one device round over the assembled columns; returns
+        the round token for ``drain``."""
+        import jax.numpy as jnp
 
         self._state, out = self._step(
-            self._state, jnp.asarray(key), jnp.asarray(src), jnp.asarray(seq)
+            self._state, *(jnp.asarray(column) for column in columns)
         )
         self.rounds += 1
         return out
@@ -629,11 +635,9 @@ class DeviceDriver(_DriverCore):
             or super()._pipeline_flush_needed(batch)
         )
 
-    def dispatch(self, batch: List[Tuple[Dot, Command]]):
-        """Assemble + dispatch one device round (async — does not block
-        on device completion); returns the round token for ``drain``."""
-        import jax.numpy as jnp
-
+    def _assemble(self, batch: List[Tuple[Dot, Command]]):
+        """One round's key/src/seq columns, each command registered
+        under the gid its row will get."""
         assert len(batch) <= self.batch_size, (
             f"batch {len(batch)} exceeds the compiled batch size "
             f"{self.batch_size}; chunk at the caller"
@@ -664,21 +668,16 @@ class DeviceDriver(_DriverCore):
             src[i] = dot.source
             seq[i] = self._device_seq(dot)
             self._cmds[self._next_gid + i] = (dot, cmd)
+        return key, src, seq
 
-        self._state, out = self._step(
-            self._state, jnp.asarray(key), jnp.asarray(src), jnp.asarray(seq)
-        )
-        self._next_gid += b
-        self.rounds += 1
+    def _enqueue(self, columns):
+        out = super()._enqueue(columns)
+        self._next_gid += self.batch_size
         return out
 
-    def drain(self, out) -> List[ExecutorResult]:
-        """Fetch one round's outputs and execute its resolved commands
-        in device order against the KVStore."""
-        # one pytree fetch, one device->host round trip, and the
-        # busy/idle bookkeeping point (PipelineCore._fetch)
-        out = self._fetch(out)
-
+    def _execute(self, _tok, out) -> List[ExecutorResult]:
+        """Execute one fetched round's resolved commands in device
+        order against the KVStore."""
         order = np.asarray(out.order)
         resolved = np.asarray(out.resolved)
         gids = np.asarray(out.gids)
@@ -831,11 +830,6 @@ class NewtDeviceDriver(_DriverCore):
             or super()._pipeline_flush_needed(batch)
         )
 
-    def dispatch(self, batch: List[Tuple[Dot, Command]]):
-        """Assemble + dispatch one Newt round (async); returns the round
-        token for ``drain``."""
-        return self._dispatch_dot_keyed(batch)
-
     def _chain_windows_blocked(
         self, batches: List[List[Tuple[Dot, Command]]]
     ) -> bool:
@@ -860,9 +854,11 @@ class NewtDeviceDriver(_DriverCore):
         (parallel/mesh_step.jit_newt_multi_step, compiled per chain
         length on first use); returns the chain token for ``drain``.
         The caller checked ``_chain_windows_blocked`` first."""
-        import jax.numpy as jnp
+        return self._dispatch_halves(
+            self._assemble_chain, self._enqueue_chain, batches
+        )
 
-        from fantoch_tpu.parallel import mesh_step
+    def _assemble_chain(self, batches: List[List[Tuple[Dot, Command]]]):
         from fantoch_tpu.parallel.mesh_step import KEY_PAD
 
         S = len(batches)
@@ -876,6 +872,14 @@ class NewtDeviceDriver(_DriverCore):
         for r, batch in enumerate(batches):
             assert len(batch) <= b
             self._assemble_rows(batch, keys[r], srcs[r], seqs[r])
+        return keys, srcs, seqs
+
+    def _enqueue_chain(self, columns):
+        import jax.numpy as jnp
+
+        from fantoch_tpu.parallel import mesh_step
+
+        S = len(columns[0])
         multi = self._multi_step.get(S)
         if multi is None:
             multi = mesh_step.jit_newt_multi_step(
@@ -883,8 +887,7 @@ class NewtDeviceDriver(_DriverCore):
             )
             self._multi_step[S] = multi
         self._state, outs = multi(
-            self._state, jnp.asarray(keys), jnp.asarray(srcs),
-            jnp.asarray(seqs),
+            self._state, *(jnp.asarray(column) for column in columns)
         )
         self.rounds += S
         return _ChainToken(outs, S)
@@ -944,14 +947,16 @@ class NewtDeviceDriver(_DriverCore):
             S,
         )
 
-    def drain(self, tok) -> List[ExecutorResult]:
-        """Fetch one round token's outputs (a single round or a whole
-        chain — ONE device->host round trip either way) and execute its
-        stable commands in (clock, dot) order."""
+    def _token_outputs(self, tok):
+        return tok.outs if isinstance(tok, _ChainToken) else tok
+
+    def _execute(self, tok, outs) -> List[ExecutorResult]:
+        """Execute one fetched token's stable commands in (clock, dot)
+        order: a single round, or a whole chain's rounds (ONE
+        device->host round trip either way)."""
         from fantoch_tpu.parallel.mesh_step import NewtStepOutput
 
         if isinstance(tok, _ChainToken):
-            outs = self._fetch(tok.outs)
             results: List[ExecutorResult] = []
             for r in range(tok.rounds):
                 results.extend(
@@ -960,7 +965,7 @@ class NewtDeviceDriver(_DriverCore):
                     )
                 )
             return results
-        return self._drain_round(self._fetch(tok))
+        return self._drain_round(outs)
 
     def _drain_round(self, out) -> List[ExecutorResult]:
         """One (already fetched) round's drain: advance watermark /
@@ -1062,17 +1067,9 @@ class CaesarDeviceDriver(_DriverCore):
         )
         self._pend_cap = pending_capacity
 
-    def dispatch(self, batch: List[Tuple[Dot, Command]]):
-        """Assemble + dispatch one Caesar round (async); returns the
-        round token for ``drain``."""
-        return self._dispatch_dot_keyed(batch)
-
-    def drain(self, out) -> List[ExecutorResult]:
-        """Fetch one round's outputs and execute its wait-cleared
-        commands in (clock, dot) order."""
-        # one pytree fetch, one device->host round trip (PipelineCore)
-        out = self._fetch(out)
-
+    def _execute(self, _tok, out) -> List[ExecutorResult]:
+        """Execute one fetched round's wait-cleared commands in
+        (clock, dot) order."""
         wm = int(out.watermark)
         if wm >= self.CLOCK_GUARD:
             raise RuntimeError(
@@ -1198,11 +1195,9 @@ class PaxosDeviceDriver(_DriverCore):
             or super()._pipeline_flush_needed(batch)
         )
 
-    def dispatch(self, batch: List[Tuple[Dot, Command]]):
-        """Assemble + dispatch one slot round (async); the token carries
-        the batch length for drain's slot-counter accounting."""
-        import jax.numpy as jnp
-
+    def _assemble(self, batch: List[Tuple[Dot, Command]]):
+        """One slot round's valid/src/seq columns, and the batch length
+        for drain's slot-counter accounting."""
         assert len(batch) <= self.batch_size
         if self._next_slot + self.batch_size >= self.SLOT_RESET_THRESHOLD:
             assert self._undrained == 0, (
@@ -1227,22 +1222,21 @@ class PaxosDeviceDriver(_DriverCore):
             src[i] = dot.source
             seq[i] = self._device_seq(dot)
             self._cmds[self._packed(dot.source, seq[i])] = (dot, cmd)
+        return (valid, src, seq), len(batch)
 
-        self._state, out = self._step(
-            self._state, jnp.asarray(valid), jnp.asarray(src), jnp.asarray(seq)
-        )
-        self.rounds += 1
-        return (out, len(batch))
+    def _enqueue(self, staged):
+        columns, n_batch = staged
+        return super()._enqueue(columns), n_batch
 
-    def drain(self, tok) -> List[ExecutorResult]:
-        """Fetch one round's outputs and execute its contiguous slot
-        prefix against the KVStore."""
-        out, n_batch = tok
-        # one pytree fetch, one device->host round trip (PipelineCore);
-        # the round's own exec_frontier rides in the output, so a later
-        # dispatched round cannot leak its frontier into this one
-        out = self._fetch(out)
+    def _token_outputs(self, tok):
+        return tok[0]
 
+    def _execute(self, tok, out) -> List[ExecutorResult]:
+        """Execute one fetched round's contiguous slot prefix against
+        the KVStore.  The round's own exec_frontier rides in the output,
+        so a later dispatched round cannot leak its frontier into this
+        one."""
+        n_batch = tok[1]
         order = np.asarray(out.order)
         executed = np.asarray(out.executed)
         slot = np.asarray(out.slot)
@@ -1337,10 +1331,14 @@ class _DeviceClientSession:
         return False
 
     async def _flush_loop(self) -> None:
+        runtime = self.runtime
         while True:
             await self._flush_needed.wait()
             self._flush_needed.clear()
+            t0 = monotonic_ns()
             await self.rw.flush()
+            runtime._flush_ns += monotonic_ns() - t0
+            runtime._flushes += 1
 
     def _reject(self, cmd: Command, why: str) -> None:
         """Reply with an empty (zero-key) CommandResult — the client's
@@ -1443,6 +1441,7 @@ class _DeviceClientSession:
                         )
                     if not isinstance(msg, Submit):
                         raise ProtocolError(f"unexpected message {msg!r}")
+                    admit_t0 = monotonic_ns()
                     cmd = msg.cmd
                     tracer = self.runtime.tracer
                     if tracer.enabled:
@@ -1472,6 +1471,7 @@ class _DeviceClientSession:
                             pid=self.runtime.process_id,
                         )
                     self.runtime.submit(dot, cmd)
+                    self.runtime._admit_ns += monotonic_ns() - admit_t0
             finally:
                 flusher.cancel()
         finally:
@@ -1694,6 +1694,22 @@ class DeviceRuntime:
         self._submit_queue: BoundedSubmitRing = BoundedSubmitRing(
             capacity=config.admission_limit
         )
+        # where a round's host time goes (observability/device.py
+        # StageRecorder): the driver's recorder, shared, so the loop's
+        # stages and the step's land in one ring on one clock
+        self.stages = self.driver.stages
+        # the per-command boundaries: two clock reads each, no span
+        self._decode_tally = [0, 0]  # [ns, frames] of pickle.loads, shared with every Rw
+        self._flush_ns = 0  # awaits of rw.flush() in the sessions
+        self._flushes = 0
+        self._admit_ns = 0  # a Submit received -> runtime.submit returned
+        self._queue_wait_ms = 0.0  # sum over released commands, ring time
+        self._queue_released = 0
+        # the loop's own lateness (_lag_task)
+        self._loop_lag_hwm_ms = 0.0
+        self._loop_stall_ms = 0.0
+        self._loop_stalls = 0
+        self._gc_started = 0  # monotonic_ns of the full collection under way
         self._tallies: Dict[str, int] = {}
         self._publish_tallies()
         self._work = asyncio.Event()
@@ -1801,21 +1817,18 @@ class DeviceRuntime:
         server = await asyncio.start_server(self._on_client, *self.client_addr)
         self._servers = [server]
         self.spawn(self._driver_task())
+        self.spawn(self._lag_task())
+        gc.callbacks.append(self._on_gc)
         if self.metrics_file is not None or self.telemetry is not None:
             self.spawn(self._telemetry_task())
         if self.metrics_port is not None:
-            from fantoch_tpu.observability.exposition import (
-                MetricsServer,
-                profile_output_dir,
-            )
+            from fantoch_tpu.observability.exposition import MetricsServer
 
             self.metrics_server = MetricsServer(
                 self.telemetry_sample,
                 self.metrics_port,
                 labels={"pid": str(self.process_id)},
-                profile_dir=profile_output_dir(
-                    self.telemetry and self.telemetry.path, self.metrics_file
-                ),
+                profile_dir=self._profile_dir(),
             )
             await self.metrics_server.start()
             self.metrics_port = self.metrics_server.port
@@ -1850,6 +1863,21 @@ class DeviceRuntime:
             "shed_submissions": self._submit_queue.sheds,
             # per-dispatch device counters (observability/device.py)
             **d.device_counters(),
+            # a round's host time by stage: stage_<name>_ms / _n
+            **self.stages.counters(),
+            # the per-command boundaries around the rounds
+            "session_decode_ms": round(self._decode_tally[0] / 1e6, 3),
+            "session_decoded": self._decode_tally[1],
+            "session_admit_ms": round(self._admit_ns / 1e6, 3),
+            "queue_wait_ms": round(self._queue_wait_ms, 3),
+            "queue_released": self._queue_released,
+            "reply_flush_ms": round(self._flush_ns / 1e6, 3),
+            "reply_flushes": self._flushes,
+            # the event loop's lateness: worst wake-up, and the sum and
+            # count of wake-ups later than LOOP_STALL_MS
+            "loop_lag_hwm_ms": round(self._loop_lag_hwm_ms, 3),
+            "loop_stall_ms": round(self._loop_stall_ms, 3),
+            "loop_stalls": self._loop_stalls,
             # adaptive ingest batcher tallies (run/ingest.py)
             **self._batcher.counters(),
             "jax_recompiles": recompile_count(),
@@ -1882,7 +1910,19 @@ class DeviceRuntime:
 
         write_json_snapshot(
             self.metrics_file,
-            {**self._tallies, "backend": self.backend_report()},
+            {
+                **self._tallies,
+                "backend": self.backend_report(),
+                # where captures and round_spans.json land
+                "profile_dir": self._profile_dir(),
+            },
+        )
+
+    def _profile_dir(self) -> str:
+        from fantoch_tpu.observability.exposition import profile_output_dir
+
+        return profile_output_dir(
+            self.telemetry and self.telemetry.path, self.metrics_file
         )
 
     # gauge-natured tally keys: instantaneous values, not monotone
@@ -1891,7 +1931,7 @@ class DeviceRuntime:
         "in_flight", "stable_watermark", "queued", "queued_hwm",
         "queue_capacity", "device_idle_frac", "device_pipeline_depth",
         "dispatch_fill_frac", "serving_chain_len", "ingest_target",
-        "ingest_rate_per_s",
+        "ingest_rate_per_s", "loop_lag_hwm_ms",
     })
 
     def telemetry_sample(self):
@@ -1914,11 +1954,38 @@ class DeviceRuntime:
         if self.metrics_file is not None:
             self._write_metrics_snapshot()
 
+    def emit_final(self) -> None:
+        """What a stopping server leaves behind (``stop()``, and the
+        SIGTERM / Ctrl-C path of ``bin/server``): the last tallies as a
+        snapshot, and the ring of round-stage spans as
+        ``round_spans.json`` beside the captures.  Nothing without a
+        metrics or telemetry file."""
+        if self.metrics_file is None and self.telemetry is None:
+            return
+        self._publish_tallies()
+        self._emit_telemetry()
+        self.stages.dump(os.path.join(self._profile_dir(), "round_spans.json"))
+
     async def _telemetry_task(self) -> None:
         while True:
             await asyncio.sleep(self.telemetry_interval_ms / 1000)
-            self._emit_telemetry()
-            self.tracer.flush()
+            # a file written on the loop: under a span, so a stall of the
+            # loop that falls here has a name
+            with self.stages.span("snapshot"):
+                self._emit_telemetry()
+                self.tracer.flush()
+
+    def _on_gc(self, phase: str, info: Dict[str, int]) -> None:
+        """``gc.callbacks`` hook: a full (generation 2) collection stops
+        whichever thread allocates, under the GIL; it goes into the ring
+        as a ``gc`` entry so a late loop can be laid beside it."""
+        if info["generation"] < 2:
+            return
+        if phase == "start":
+            self._gc_started = monotonic_ns()
+        elif self._gc_started:
+            self.stages.record("gc", self._gc_started, monotonic_ns())
+            self._gc_started = 0
 
     async def stop(self) -> None:
         if self.metrics_server is not None:
@@ -1926,8 +1993,9 @@ class DeviceRuntime:
         tasks = list(self._tasks)
         self._teardown()
         await asyncio.gather(*tasks, return_exceptions=True)
-        if self.metrics_file is not None or self.telemetry is not None:
-            self._emit_telemetry()
+        if self._on_gc in gc.callbacks:
+            gc.callbacks.remove(self._on_gc)
+        self.emit_final()
         if self.telemetry is not None:
             self.telemetry.close()
         self.tracer.close()
@@ -1935,7 +2003,9 @@ class DeviceRuntime:
     # --- client plane ---
 
     async def _on_client(self, reader, writer) -> None:
-        session = _DeviceClientSession(self, Rw(reader, writer))
+        session = _DeviceClientSession(
+            self, Rw(reader, writer, decode_tally=self._decode_tally)
+        )
         self.spawn(session.run(), fatal=False)
 
     def has_capacity(self) -> bool:
@@ -1957,7 +2027,10 @@ class DeviceRuntime:
 
     def submit(self, dot: Dot, cmd: Command) -> None:
         self.submitted += 1
-        if not self._submit_queue.try_push((dot, cmd)):
+        now_ms = monotonic() * 1000.0
+        # the arrival time rides beside the item: the ring wait of each
+        # command is read off it at release (queue_wait_ms)
+        if not self._submit_queue.try_push((dot, cmd, now_ms)):
             # unreachable via sessions (has_capacity() is checked on the
             # same cooperative tick, with no await between check and
             # submit) — a real exception, not an assert, so a future
@@ -1971,9 +2044,7 @@ class DeviceRuntime:
                 self._submit_queue.capacity or 0,
                 self.retry_after_ms(),
             )
-        from time import monotonic
-
-        self._batcher.note_arrivals(monotonic() * 1000.0, 1)
+        self._batcher.note_arrivals(now_ms, 1)
         self._work.set()
 
     def drop_session(self, session: "_DeviceClientSession") -> None:
@@ -2006,19 +2077,142 @@ class DeviceRuntime:
 
     # --- the serving loop ---
 
-    async def _driver_task(self) -> None:
-        from time import monotonic
+    # a wake-up later than this is a stall of the event loop: counted,
+    # summed, and put into the span ring (what a round's spans say was
+    # open on either thread at that time is the finding)
+    LOOP_LAG_PROBE_MS = 10.0
+    LOOP_STALL_MS = 20.0
 
-        loop = asyncio.get_running_loop()
+    async def _lag_task(self) -> None:
+        """The loop's own lateness: sleep ``LOOP_LAG_PROBE_MS``, measure
+        how late the wake-up came."""
+        probe_ns = int(self.LOOP_LAG_PROBE_MS * 1e6)
+        while True:
+            due = monotonic_ns() + probe_ns
+            await asyncio.sleep(self.LOOP_LAG_PROBE_MS / 1000.0)
+            now = monotonic_ns()
+            late_ms = (now - due) / 1e6
+            if late_ms > self._loop_lag_hwm_ms:
+                self._loop_lag_hwm_ms = late_ms
+            if late_ms > self.LOOP_STALL_MS:
+                self._loop_stall_ms += late_ms
+                self._loop_stalls += 1
+                self.stages.record("loop_stall", due, now)
+
+    async def _step_on_pool(self, round_id: int, step, *args):
+        """One blocking driver call off the event loop (connections and
+        result flushes stay live during the round), between its two
+        hand-offs: ``handoff`` from here to the first line on the pool
+        thread, ``resume`` from the call's return there to this task
+        running again.  Both share the GIL with whatever the loop does
+        meanwhile."""
+        stages = self.stages
+        called = stages.clock()
+
+        def on_pool():
+            stages.record("handoff", called, stages.clock(), round_id, "round")
+            with stages.span("step", round_id, parent="round", cpu=True):
+                results = step(*args)
+            return results, stages.clock()
+
+        results, returned = await asyncio.get_running_loop().run_in_executor(
+            None, on_pool
+        )
+        stages.record("resume", returned, stages.clock(), round_id, "round")
+        return results
+
+    def _collect(self, round_id: int, chain: int) -> List[List[Tuple[Dot, Command]]]:
+        """Up to ``chain`` rounds (the auto-tuned chain length) from the
+        requeue and the released queue, canonicalised to the pow2 ladder
+        of chain lengths."""
         driver = self.driver
+        tracer = self.tracer
+        batches: List[List[Tuple[Dot, Command]]] = []
+        pending = driver.take_requeue()
+        released = 0
+        arrived_ms = 0.0
+        while (pending or self._submit_queue) and len(batches) < chain:
+            batch: List[Tuple[Dot, Command]] = []
+            while pending and len(batch) < driver.batch_size:
+                batch.append(pending.pop(0))
+            while self._submit_queue and len(batch) < driver.batch_size:
+                dot, cmd, at_ms = self._submit_queue.popleft()
+                if tracer.enabled:
+                    # batch release: payload->ingest is the queue +
+                    # batching wait (critpath's ingest-batching
+                    # bucket); the round says which `rs` slice it rode
+                    tracer.span(
+                        "ingest", cmd.rifl, dot=dot, pid=self.process_id,
+                        meta={"round": round_id},
+                    )
+                batch.append((dot, cmd))
+                released += 1
+                arrived_ms += at_ms
+            batches.append(batch)
+        if len(batches) > 1:
+            # canonicalize the dispatched chain length to the pow2
+            # ladder: the chained step programs compile per chain
+            # length, so dispatching whatever 1..S rounds the queue
+            # happened to fill would mint a compiled program per
+            # value — truncate to the pow2 floor and requeue the
+            # remainder (it leads the next chain)
+            keep = 1
+            while keep * 2 <= len(batches):
+                keep *= 2
+            for batch in reversed(batches[keep:]):
+                pending[:0] = batch
+            del batches[keep:]
+        if pending:
+            # overflow past S full rounds goes back to the requeue
+            # (next iteration dispatches it first)
+            driver._requeue[:0] = pending
+        if released:
+            now_ms = monotonic() * 1000.0
+            self._batcher.note_release(now_ms, released)
+            self._queue_wait_ms += released * now_ms - arrived_ms
+            self._queue_released += released
+        return batches or [[]]  # nothing queued: a pending-buffer progress round
+
+    async def _serve_round(self, round_id: int, step, *args) -> List[ExecutorResult]:
+        """The tail every round shares: the step on the pool thread,
+        delivery of what it executed, the published tallies."""
+        stages = self.stages
+        results = await self._step_on_pool(round_id, step, *args)
+        with stages.span("deliver", round_id):
+            self._deliver(results)
+        with stages.span("publish", round_id):
+            # feed the chain auto-tuner the cumulative overlap counters
+            # (it rate-limits itself by dispatch count)
+            driver = self.driver
+            self._chain_tuner.observe(
+                driver.dispatches,
+                driver.dispatch_wall_ms,
+                driver.device_counters()["device_busy_ms"],
+                driver.rounds,
+            )
+            self._publish_tallies()
+        return results
+
+    def _trace_round(self, whole) -> None:
+        """The finished round, for the operator's per-command trace."""
+        if self.tracer.enabled:
+            self.tracer.round_span(
+                whole.name, whole.t0, whole.t1, whole.round, self.stages.clock(),
+                pid=self.process_id,
+            )
+
+    async def _driver_task(self) -> None:
+        driver = self.driver
+        stages = self.stages
         # dispatch/drain pipelining (DeviceDriver only): under saturation
         # round k+1's device dispatch overlaps round k's host emit loop
         can_pipeline = self.pipeline
         batcher = self._batcher
         tuner = self._chain_tuner
-        tracer = self.tracer
         idle_rounds = 0  # empty-input rounds yielding no results
         while True:
+            # a round is named by the number of the dispatch it makes
+            round_id = driver.dispatches + 1
             if not self._submit_queue and can_pipeline and driver.has_outstanding:
                 # the queue went quiet with a round still in flight:
                 # retire it directly — its results must not strand, and
@@ -2030,15 +2224,14 @@ class DeviceRuntime:
                 # iteration re-evaluates the queue from scratch — the
                 # arrival simply waits one flush, it can never interleave
                 # a dispatch into the flushing pipeline
-                results = await loop.run_in_executor(
-                    None, driver.flush_pipeline
-                )
-                self._deliver(results)
-                self._publish_tallies()
+                with stages.span("round", round_id) as whole:
+                    await self._serve_round(round_id, driver.flush_pipeline)
+                self._trace_round(whole)
                 continue
             if not self._submit_queue and driver.in_flight == 0:
                 self._work.clear()
-                await self._work.wait()
+                with stages.span("idle_wait"):
+                    await self._work.wait()
             # adaptive ingest gate (run/ingest.py): hold a part-empty
             # round while arrivals fill it toward the EWMA size target,
             # for at most the deadline budget.  Requeued overflow is
@@ -2062,89 +2255,42 @@ class DeviceRuntime:
                     self._work.clear()
                     # a submit that landed since the poll set _work
                     # before the clear — the wait returns immediately
-                    try:
-                        await asyncio.wait_for(
-                            self._work.wait(), timeout=wait_ms / 1000.0
-                        )
-                    except asyncio.TimeoutError:
-                        pass
+                    with stages.span("gate_wait", round_id, parent="round"):
+                        try:
+                            await asyncio.wait_for(
+                                self._work.wait(), timeout=wait_ms / 1000.0
+                            )
+                        except asyncio.TimeoutError:
+                            pass
                     continue
-            # chained-by-default: assemble up to S rounds (the
-            # auto-tuned chain length) from requeue + the released queue
-            batches: List[List[Tuple[Dot, Command]]] = []
-            pending = driver.take_requeue()
-            released = 0
-            while (pending or self._submit_queue) and len(batches) < tuner.chain:
-                batch: List[Tuple[Dot, Command]] = []
-                while pending and len(batch) < driver.batch_size:
-                    batch.append(pending.pop(0))
-                while self._submit_queue and len(batch) < driver.batch_size:
-                    dot, cmd = self._submit_queue.popleft()
-                    if tracer.enabled:
-                        # batch release: payload->ingest is the queue +
-                        # batching wait (critpath's ingest-batching
-                        # bucket)
-                        tracer.span(
-                            "ingest", cmd.rifl, dot=dot, pid=self.process_id
-                        )
-                    batch.append((dot, cmd))
-                    released += 1
-                batches.append(batch)
-            if len(batches) > 1:
-                # canonicalize the dispatched chain length to the pow2
-                # ladder: the chained step programs compile per chain
-                # length, so dispatching whatever 1..S rounds the queue
-                # happened to fill would mint a compiled program per
-                # value — truncate to the pow2 floor and requeue the
-                # remainder (it leads the next chain)
-                keep = 1
-                while keep * 2 <= len(batches):
-                    keep *= 2
-                for batch in reversed(batches[keep:]):
-                    pending[:0] = batch
-                del batches[keep:]
-            if pending:
-                # overflow past S full rounds goes back to the requeue
-                # (next iteration dispatches it first)
-                driver._requeue[:0] = pending
-            if released:
-                batcher.note_release(monotonic() * 1000.0, released)
-            if not batches:
-                batches = [[]]  # pending-buffer progress round
-            # pipelining pays one round of delivery lag, so engage it only
-            # when another batch is already waiting (throughput regime);
-            # a lone closed-loop command keeps the immediate sync round.
-            # An outstanding round forces the pipelined path regardless:
-            # its results must come back in order ahead of this round's.
-            pipeline = can_pipeline and (
-                driver.has_outstanding or len(self._submit_queue) > 0
-            )
-            # blocking device dispatch off the event loop: connections and
-            # result flushes stay live during the round.  Chains route
-            # through the shared chained surface (one fused device
-            # program on Newt, S plain rounds elsewhere)
-            if len(batches) > 1:
-                step = (
-                    driver.step_chained_pipelined
-                    if pipeline else driver.step_chained
+            with stages.span("round", round_id) as whole:
+                with stages.span("collect", round_id):
+                    batches = self._collect(round_id, tuner.chain)
+                # pipelining pays one round of delivery lag, so engage it
+                # only when another batch is already waiting (throughput
+                # regime); a lone closed-loop command keeps the immediate
+                # sync round.  An outstanding round forces the pipelined
+                # path regardless: its results must come back in order
+                # ahead of this round's.
+                pipeline = can_pipeline and (
+                    driver.has_outstanding or len(self._submit_queue) > 0
                 )
-                results = await loop.run_in_executor(None, step, batches)
-            else:
-                results = await loop.run_in_executor(
-                    None,
-                    driver.step_pipelined if pipeline else driver.step,
-                    batches[0],
-                )
-            # feed the chain auto-tuner the cumulative overlap counters
-            # (it rate-limits itself by dispatch count)
-            tuner.observe(
-                driver.dispatches,
-                driver.dispatch_wall_ms,
-                driver.device_counters()["device_busy_ms"],
-                driver.rounds,
-            )
-            self._deliver(results)
-            self._publish_tallies()
+                # chains route through the shared chained surface (one
+                # fused device program on Newt, S plain rounds elsewhere)
+                if len(batches) > 1:
+                    results = await self._serve_round(
+                        round_id,
+                        driver.step_chained_pipelined
+                        if pipeline else driver.step_chained,
+                        batches,
+                    )
+                else:
+                    results = await self._serve_round(
+                        round_id,
+                        driver.step_pipelined if pipeline else driver.step,
+                        batches[0],
+                    )
+            self._trace_round(whole)
             # commands stuck in the device pending buffer (degraded quorum)
             # with no new submissions would otherwise hot-spin device
             # rounds — including overflow-requeue cycles, whose batches are
@@ -2163,11 +2309,12 @@ class DeviceRuntime:
                 # a submit that landed while driver.step ran set _work
                 # before the clear — check the queue itself, not the event
                 if not self._submit_queue:
-                    try:
-                        await asyncio.wait_for(
-                            self._work.wait(), timeout=backoff
-                        )
-                    except asyncio.TimeoutError:
-                        pass
+                    with stages.span("idle_wait"):
+                        try:
+                            await asyncio.wait_for(
+                                self._work.wait(), timeout=backoff
+                            )
+                        except asyncio.TimeoutError:
+                            pass
             else:
                 idle_rounds = 0

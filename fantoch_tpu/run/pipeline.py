@@ -15,9 +15,11 @@ refactor seam): drivers implement a ``dispatch(batch) -> token`` /
 ``drain(token) -> results`` split and inherit
 
   * :class:`PipelineCore` — a depth-K in-flight ring of round tokens
-    (``step`` / ``step_pipelined`` / ``flush_pipeline``), per-dispatch
-    wall-split counters, and the device busy/idle instrument
-    (``device_idle_frac``);
+    (``step`` / ``step_pipelined`` / ``flush_pipeline``), the stage
+    recorder whose spans split a dispatch into ``assemble`` / ``enqueue``
+    and a drain into ``fetch`` / ``execute``
+    (observability/device.py ``StageRecorder``), and the device
+    busy/idle instrument (``device_idle_frac``);
   * :class:`IngestRing` — K+1 pre-staged host staging buffer sets for
     batch assembly, cycled round-robin so the columns a still-in-flight
     round reads (``jnp.asarray`` zero-copy aliases host numpy on the CPU
@@ -40,7 +42,6 @@ only aliasing hazard, and the ring's size (depth + 1) closes it.
 from __future__ import annotations
 
 import os
-import time
 from collections import deque
 from typing import Any, Deque, Dict, List, Optional, Sequence, Tuple
 
@@ -175,10 +176,15 @@ class PipelineCore:
     """Depth-K dispatch/drain pipelining plus the per-dispatch counters
     every device serving driver shares.
 
-    Subclasses implement ``dispatch(batch) -> token`` (async: must not
-    block on device completion) and ``drain(token) -> results`` (fetches
-    outputs via :meth:`_fetch` and emits).  ``_pipeline_flush_needed``
-    gates dispatches that would rebase state an in-flight round still
+    Subclasses implement the halves of a dispatch, ``_assemble(batch) ->
+    staged`` (host columns, registry) and ``_enqueue(staged) -> token``
+    (async: must not block on device completion), and the tail of a
+    drain, ``_execute(token, fetched) -> results``; :meth:`dispatch` and
+    :meth:`drain` put the stage spans around them once for every driver.
+    (A subclass may still override ``dispatch`` / ``drain`` whole, as the
+    host-only test doubles do; it then records no ``assemble`` /
+    ``enqueue`` / ``execute`` time.)  ``_pipeline_flush_needed`` gates
+    dispatches that would rebase state an in-flight round still
     references (sequence/clock/gid windows) — the pipeline retires every
     outstanding round first.
 
@@ -194,20 +200,27 @@ class PipelineCore:
             "PipelineCore subclasses must set batch_size before "
             "_init_pipeline"
         )
+        from fantoch_tpu.observability.device import StageRecorder
+
         self._ring: Optional[IngestRing] = None  # lazy staging ring
         # per-dispatch observability (observability/device.py):
         # dispatched_rows vs dispatched_capacity is the batch occupancy;
-        # dispatch/drain wall-ms split host assembly from device wait
+        # the stage recorder splits a round's host time (assemble /
+        # enqueue / fetch / execute here, the loop's stages in
+        # DeviceRuntime) — dispatch/drain/fetch wall-ms are reads of it
+        self.stages = StageRecorder()
         self.dispatches = 0
         self.dispatched_rows = 0
         self.dispatched_capacity = 0
-        self.dispatch_wall_ms = 0.0
-        self.drain_wall_ms = 0.0
-        self.fetch_wall_ms = 0.0  # blocking device->host wait inside drains
         self.pipelined_rounds = 0  # rounds dispatched over an in-flight one
         self.chain_len = 1  # rounds the latest dispatch carried (gauge)
-        # the in-flight ring: dispatched-but-undrained round tokens, FIFO
-        self._inflight: Deque[Any] = deque()
+        # the round the spans on the stepping thread belong to: the
+        # dispatch number while dispatching, the retired round's number
+        # while draining (a pipelined drain is not the round dispatched)
+        self._span_round = 0
+        # the in-flight ring: dispatched-but-undrained (round id, token)
+        # pairs, FIFO
+        self._inflight: Deque[Tuple[int, Any]] = deque()
         # rounds dispatched and not yet entered drain — during a drain
         # this counts OTHER in-flight rounds (unlike has_outstanding,
         # which is False mid-flush even with round k+1 dispatched), so
@@ -221,10 +234,28 @@ class PipelineCore:
         # retires the LAST in-flight round; span is first dispatch ->
         # last fetch.  idle = span - busy = wall the device sat waiting
         # on host assembly/emit — the number the pipeline exists to kill.
-        self._busy_t0: Optional[float] = None
-        self._busy_ms = 0.0
-        self._span_t0: Optional[float] = None
-        self._span_end: Optional[float] = None
+        # (the recorder's clock, ns)
+        self._busy_t0: Optional[int] = None
+        self._busy_ns = 0
+        self._span_t0: Optional[int] = None
+        self._span_end: Optional[int] = None
+
+    @property
+    def dispatch_wall_ms(self) -> float:
+        """Host wall time inside dispatches (assembly + the jitted call
+        returning)."""
+        return self.stages.ms("assemble", "enqueue")
+
+    @property
+    def drain_wall_ms(self) -> float:
+        """Host wall time inside drains: the blocking fetch, then the
+        execution of what it brought."""
+        return self.stages.ms("fetch", "execute")
+
+    @property
+    def fetch_wall_ms(self) -> float:
+        """The blocking device->host wait inside drains."""
+        return self.stages.ms("fetch")
 
     def _staging(self, *specs) -> Tuple[np.ndarray, ...]:
         """The next pre-staged host staging slot for batch assembly:
@@ -244,7 +275,7 @@ class PipelineCore:
             "overlap-instrument reset with rounds in flight"
         )
         self._busy_t0 = self._span_t0 = self._span_end = None
-        self._busy_ms = 0.0
+        self._busy_ns = 0
 
     # --- the serving surface ---
 
@@ -300,6 +331,34 @@ class PipelineCore:
             lambda: self.dispatch(batch), len(batch), self.batch_size, 1
         )
 
+    # --- the halves of a round, under their stage spans ---
+
+    def dispatch(self, batch):
+        """Assemble + enqueue one device round (async — does not block
+        on device completion); returns the round token for ``drain``."""
+        return self._dispatch_halves(self._assemble, self._enqueue, batch)
+
+    def _dispatch_halves(self, assemble, enqueue, work):
+        """``assemble``: staging slot, window checks, the row loop, the
+        registry.  ``enqueue``: the columns handed to jax and the jitted
+        call returning."""
+        with self.stages.span("assemble", self._span_round):
+            staged = assemble(work)
+        with self.stages.span("enqueue", self._span_round):
+            return enqueue(staged)
+
+    def drain(self, tok):
+        """Fetch one token's outputs (ONE blocking device->host round
+        trip) and execute what they resolved."""
+        fetched = self._fetch(self._token_outputs(tok))
+        with self.stages.span("execute", self._span_round):
+            return self._execute(tok, fetched)
+
+    def _token_outputs(self, tok):
+        """The device arrays of a token (drivers whose token carries
+        more than the step's outputs override)."""
+        return tok
+
     def _pipeline_dispatch(
         self, fn, rows: int, capacity: int, rounds: int
     ) -> List[Any]:
@@ -332,12 +391,13 @@ class PipelineCore:
         )
 
     def _track_dispatch(self, fn, rows: int, capacity: int, rounds: int):
-        t0 = time.perf_counter()
+        """Run one dispatch thunk; returns ``(round id, token)``, the
+        round id being the dispatch's number (1-based)."""
+        round_id = self._span_round = self.dispatches + 1
         if self._span_t0 is None:
-            self._span_t0 = t0
+            self._span_t0 = self.stages.clock()
         tok = fn()
-        t1 = time.perf_counter()
-        self.dispatch_wall_ms += (t1 - t0) * 1000.0
+        t1 = self.stages.clock()
         self.dispatches += 1
         self.dispatched_rows += rows
         self.dispatched_capacity += capacity
@@ -349,16 +409,15 @@ class PipelineCore:
             # returns (the submit is async); host assembly before it
             # counts as idle, which is the point of the instrument
             self._busy_t0 = t1
-        return tok
+        return round_id, tok
 
-    def _drain_tracked(self, tok):
+    def _drain_tracked(self, tracked):
+        round_id, tok = tracked
         # inside drain, _undrained counts OTHER in-flight rounds
         self._undrained -= 1
         self._undrained_rounds -= self._token_rounds(tok)
-        t0 = time.perf_counter()
-        out = self.drain(tok)
-        self.drain_wall_ms += (time.perf_counter() - t0) * 1000.0
-        return out
+        self._span_round = round_id  # the round retired, not the one dispatching
+        return self.drain(tok)
 
     def _token_rounds(self, tok) -> int:
         """Protocol rounds one dispatch token carries (chained drivers
@@ -374,12 +433,11 @@ class PipelineCore:
         round, the device goes idle until the next dispatch."""
         import jax
 
-        t0 = time.perf_counter()
-        out = jax.device_get(out)
-        t1 = time.perf_counter()
-        self.fetch_wall_ms += (t1 - t0) * 1000.0
+        with self.stages.span("fetch", self._span_round) as span:
+            out = jax.device_get(out)
+        t1 = span.t1
         if self._undrained == 0 and self._busy_t0 is not None:
-            self._busy_ms += (t1 - self._busy_t0) * 1000.0
+            self._busy_ns += t1 - self._busy_t0
             self._busy_t0 = None
         self._span_end = t1
         return out
@@ -398,18 +456,18 @@ class PipelineCore:
         ``device_idle_frac`` — the fraction of the serving span the
         device sat idle waiting on the host (the pipelined loop's whole
         job is driving it toward 0)."""
-        now = time.perf_counter()
-        busy_ms = self._busy_ms
+        now = self.stages.clock()
+        busy_ms = self._busy_ns / 1e6
         span_ms = 0.0
         if self._span_t0 is not None:
             span_end = self._span_end
             if self._busy_t0 is not None:
                 # rounds still in flight: close the open windows at `now`
                 # for a consistent mid-run snapshot
-                busy_ms += (now - self._busy_t0) * 1000.0
+                busy_ms += (now - self._busy_t0) / 1e6
                 span_end = now
             if span_end is not None:
-                span_ms = (span_end - self._span_t0) * 1000.0
+                span_ms = (span_end - self._span_t0) / 1e6
         idle_frac = (
             max(0.0, 1.0 - busy_ms / span_ms) if span_ms > 0 else 0.0
         )

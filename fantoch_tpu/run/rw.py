@@ -14,7 +14,8 @@ import asyncio
 import pickle
 import socket
 import struct
-from typing import Any, Optional
+from time import monotonic_ns
+from typing import Any, List, Optional
 
 _LEN = struct.Struct(">I")
 # link frames (peer connections after the handshake): u8 kind + u64 seq
@@ -57,9 +58,18 @@ async def connect_with_retry(
 class Rw:
     """Framed reader/writer over one TCP connection."""
 
-    def __init__(self, reader: asyncio.StreamReader, writer: asyncio.StreamWriter):
+    def __init__(
+        self,
+        reader: asyncio.StreamReader,
+        writer: asyncio.StreamWriter,
+        decode_tally: Optional[List[int]] = None,
+    ):
+        """``decode_tally``: a ``[ns, frames]`` pair the owner shares
+        among its connections; ``recv`` adds each frame's unpickle time
+        to it (the device runtime's ``session_decode_ms``)."""
         self._reader = reader
         self._writer = writer
+        self._decode_tally = decode_tally
         sock = writer.get_extra_info("socket")
         if sock is not None:
             # TCP_NODELAY, as the reference's Connection (connection.rs:46-51)
@@ -73,7 +83,14 @@ class Rw:
             return None
         (length,) = _LEN.unpack(header)
         payload = await self._reader.readexactly(length)
-        return pickle.loads(payload)
+        tally = self._decode_tally
+        if tally is None:
+            return pickle.loads(payload)
+        t0 = monotonic_ns()
+        value = pickle.loads(payload)
+        tally[0] += monotonic_ns() - t0
+        tally[1] += 1
+        return value
 
     def write(self, value: Any) -> None:
         """Queue one frame without flushing."""

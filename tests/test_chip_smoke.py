@@ -58,6 +58,11 @@ def test_leg_kernel_tiny():
 
 
 def test_leg_planes_tiny():
+    from fantoch_tpu.ops.pallas_resolve import pallas_status
+
+    # the tally of routes is the process's: an earlier test file of the
+    # same worker may have served through the kernels
+    earlier = pallas_status()["served"]
     leg = chip_smoke.leg_planes(
         table=dict(batch=2000, keys=256, n=3, rounds=2),
         pred=dict(batch=1024, keys=128, rounds=2),
@@ -70,7 +75,13 @@ def test_leg_planes_tiny():
     # the route that served is the composed one, and the status says so
     status = leg["pallas_status"]
     assert status["enabled"] is False
-    assert all(set(routes) == {"xla"} for routes in status["served"].values())
+    served_here = {
+        family: {route for route, count in routes.items()
+                 if count > earlier.get(family, {}).get(route, 0)}
+        for family, routes in status["served"].items()
+    }
+    assert any(served_here.values())
+    assert all(routes <= {"xla"} for routes in served_here.values())
 
 
 def test_last_line_is_the_verdict_and_nothing_else():

@@ -141,7 +141,10 @@ def test_cache_dir_default_is_the_checkout():
 
 def test_plane_sweep_compiles_each_program_once():
     """5-point batch-size sweep through the canonicalized shapes: every
-    plane program ends the sweep with exactly ONE compiled signature.
+    plane program ends the sweep with exactly ONE compiled signature
+    (none new where an earlier test file of the same worker process has
+    already compiled that signature: the jit cache counted is the
+    process's).
 
     The sweep drives the real call paths (the table plane's pow2 vote
     padding, the pred/graph planes' pow2 feed chopping) with batch sizes
@@ -167,7 +170,7 @@ def test_plane_sweep_compiles_each_program_once():
         ve = np.array([r.randrange(1, 10) for _ in range(batch)], np.int64)
         plane.commit_votes(vk, vb, vs, ve)
     after = compile_cache.program_compile_counts()["votes_commit_xla"]
-    assert after - before == 1, (
+    assert after - before <= 1 <= after, (
         "table-plane sweep minted extra compiled signatures: a batch "
         "axis leaked past the pow2 pad"
     )
@@ -203,7 +206,7 @@ def test_plane_sweep_compiles_each_program_once():
         ex.handle_batch(infos[at : at + size], None)
         at += size
     counts1 = compile_cache.program_compile_counts()["pred_plane_step_xla"]
-    assert counts1 - counts0 == 1, (
+    assert counts1 - counts0 <= 1 <= counts1, (
         "pred-plane sweep minted extra compiled signatures: a feed axis "
         "leaked past the pow2 chop"
     )

@@ -1704,3 +1704,218 @@ def test_lone_command_fast_path_releases_immediately():
     assert runtime.driver.executed == 1
     assert runtime._batcher.releases_fast >= 1
     assert runtime._batcher.releases_deadline == 0
+
+
+# --- round-stage spans on the served path (observability/device.py) ---
+
+
+def _served(tmp_path=None, **kw):
+    config = Config(3, 1, shard_count=1, **kw.pop("config", {}))
+    workload = Workload(
+        shard_count=1, key_gen=ConflictRateKeyGen(50), keys_per_command=1,
+        commands_per_client=COMMANDS_PER_CLIENT, payload_size=1,
+    )
+    return asyncio.run(
+        run_device_server(
+            config, workload, client_count=4, batch_size=8,
+            open_loop_interval_ms=1, pipeline=True, **kw,
+        )
+    )
+
+
+@pytest.mark.parametrize("protocol", ["epaxos", "newt", "caesar", "fpaxos"])
+def test_served_round_self_times_telescope(protocol, tmp_path):
+    """Every driver's round splits the same way: the step holds its
+    four children, the round holds the loop's stages and the step with
+    its two hand-offs, and a drain is its fetch plus its execute (so
+    dispatch + fetch + drain holds the fetch twice)."""
+    import json
+
+    runtime, _clients = _served(
+        protocol=protocol, telemetry_file=str(tmp_path / "series.jsonl"))
+    t = runtime._tallies
+    assert t["executed"] == t["replied"] == 4 * COMMANDS_PER_CLIENT
+    inside = sum(t[f"stage_{name}_ms"] for name in ("assemble", "enqueue", "fetch", "execute"))
+    assert t["stage_step_ms"] >= inside - 0.01 and inside > 0
+    assert t["stage_step_cpu_ms"] > 0
+    around = sum(t[f"stage_{name}_ms"] for name in
+                 ("collect", "handoff", "step", "resume", "deliver", "publish"))
+    # the round's own publish is still open when its tallies are taken
+    assert t["stage_round_ms"] + t["stage_publish_ms"] >= around - 0.05 * t["stage_round_n"]
+    assert t["device_drain_ms"] == pytest.approx(
+        t["stage_fetch_ms"] + t["stage_execute_ms"], abs=0.002)
+    assert t["stage_step_n"] == t["stage_handoff_n"] == t["stage_resume_n"]
+    assert t["stage_fetch_n"] == t["device_dispatches"]
+    # the per-command boundaries
+    assert t["session_decoded"] >= t["submitted"] == 4 * COMMANDS_PER_CLIENT
+    assert t["queue_released"] == t["submitted"] and t["queue_wait_ms"] > 0
+    assert t["session_decode_ms"] > 0 and t["session_admit_ms"] > 0
+    assert t["reply_flushes"] > 0 and t["reply_flush_ms"] > 0
+    # the ring, written on stop beside the series; spans name their thread
+    with open(tmp_path / "round_spans.json") as fh:
+        ring = json.load(fh)
+    rows = [dict(zip(ring["columns"], row)) for row in ring["spans"]]
+    by_name = {row["name"]: row for row in rows}
+    assert {"round", "collect", "handoff", "step", "assemble", "enqueue", "fetch",
+            "execute", "resume", "deliver", "publish", "idle_wait"} <= set(by_name)
+    assert by_name["step"]["parent"] == by_name["deliver"]["parent"] == "round"
+    assert by_name["fetch"]["parent"] == by_name["assemble"]["parent"] == "step"
+    assert by_name["step"]["thread"] != by_name["deliver"]["thread"]  # pool vs loop
+    assert by_name["handoff"]["thread"] == by_name["step"]["thread"]
+    for row in rows:  # a step lies inside the round of its number
+        if row["name"] == "step":
+            whole = next(r for r in rows if r["name"] == "round" and r["round"] == row["round"])
+            assert whole["t0_ns"] <= row["t0_ns"] <= row["t1_ns"] <= whole["t1_ns"]
+
+
+def test_round_spans_are_written_only_beside_a_metrics_or_telemetry_file(tmp_path, monkeypatch):
+    monkeypatch.chdir(tmp_path)
+    runtime, _clients = _served()
+    assert runtime.stages.ring and not list(tmp_path.iterdir())
+    assert "profile_dir" not in runtime._tallies  # a string: not among the numbers
+
+
+def test_stage_counters_are_monotone_across_snapshots_and_name_the_profile_dir(tmp_path):
+    """What ``snapshot_delta`` needs of every new key: in the first
+    snapshot already, numeric, never falling."""
+    import json
+
+    from fantoch_tpu.run.device_runner import DeviceRuntime
+    from fantoch_tpu.run.harness import free_port
+
+    async def go():
+        runtime = DeviceRuntime(
+            Config(3, 1, shard_count=1), ("127.0.0.1", free_port()),
+            batch_size=8, key_buckets=64, metrics_file=str(tmp_path / "snap.json"),
+        )
+        runtime._write_metrics_snapshot()
+        with open(tmp_path / "snap.json") as fh:
+            first = json.load(fh)
+        await runtime.start()
+        for i in range(3):
+            cmd = Command.from_single(Rifl(9, i + 1), 0, f"k{i}", KVOp.put("v"))
+            runtime.submit(runtime.dot_gen.next_id(), cmd)
+        for _ in range(1500):
+            if runtime.failure is not None:
+                raise runtime.failure
+            # (a stop while the step still runs on its pool thread would
+            # close the cancelled round ahead of its step)
+            if runtime.driver.executed >= 3 and runtime.stages.n["round"] >= 1:
+                break
+            await asyncio.sleep(0.02)
+        await runtime.stop()
+        with open(tmp_path / "snap.json") as fh:
+            return first, json.load(fh)
+
+    first, last = asyncio.run(go())
+    assert first["profile_dir"] == last["profile_dir"] == str(tmp_path)
+    new_keys = [key for key in last if key.startswith(("stage_", "session_", "queue_wait",
+                                                       "queue_released", "reply_flush", "loop_"))]
+    assert len(new_keys) >= 2 * 14 + 1 + 10
+    for key in new_keys:
+        assert key in first, key
+        assert isinstance(first[key], (int, float)) and isinstance(last[key], (int, float))
+        assert last[key] >= first[key], key
+    assert last["stage_round_n"] >= 1 and last["stage_step_ms"] > 0
+    assert (tmp_path / "round_spans.json").exists()
+
+
+def test_the_lag_task_records_a_stall_of_the_loop(tmp_path):
+    import json
+    import time
+
+    from fantoch_tpu.run.device_runner import DeviceRuntime
+    from fantoch_tpu.run.harness import free_port
+
+    async def go():
+        runtime = DeviceRuntime(
+            Config(3, 1, shard_count=1), ("127.0.0.1", free_port()),
+            batch_size=8, key_buckets=64, metrics_file=str(tmp_path / "snap.json"),
+        )
+        await runtime.start()
+        await asyncio.sleep(0.05)  # the probe is asleep in its 10 ms
+        time.sleep(0.06)           # the loop does not run for 60 ms
+        await asyncio.sleep(0.05)
+        await runtime.stop()
+        return runtime
+
+    runtime = asyncio.run(go())
+    t = runtime._tallies
+    assert t["loop_stalls"] >= 1 and t["loop_stall_ms"] >= 40.0
+    assert t["loop_lag_hwm_ms"] >= 40.0
+    with open(tmp_path / "round_spans.json") as fh:
+        ring = json.load(fh)
+    stalls = [row for row in ring["spans"] if row[0] == "loop_stall"]
+    assert stalls and (stalls[0][2] - stalls[0][1]) / 1e6 >= 40.0
+    assert t["stage_loop_stall_n"] == t["loop_stalls"]
+
+
+def test_full_collections_and_snapshot_writes_are_in_the_ring(tmp_path):
+    """The two things that run on the loop beside the rounds and can stop
+    it: the telemetry tick's file write (a ``snapshot`` span) and a full
+    collection of the interpreter (a ``gc`` entry, whatever thread ran
+    it).  The hook is gone again when the runtime stops."""
+    import gc
+
+    from fantoch_tpu.run.device_runner import DeviceRuntime
+    from fantoch_tpu.run.harness import free_port
+
+    async def go():
+        runtime = DeviceRuntime(
+            Config(3, 1, shard_count=1), ("127.0.0.1", free_port()),
+            batch_size=8, key_buckets=64, metrics_file=str(tmp_path / "snap.json"),
+            metrics_interval_ms=20,
+        )
+        await runtime.start()
+        assert runtime._on_gc in gc.callbacks
+        gc.collect()   # generation 2
+        gc.collect(0)  # a young collection is not recorded
+        await asyncio.sleep(0.1)
+        await runtime.stop()
+        return runtime
+
+    runtime = asyncio.run(go())
+    assert runtime._on_gc not in gc.callbacks
+    t = runtime._tallies
+    assert t["stage_gc_n"] >= 1 and t["stage_gc_ms"] > 0
+    assert t["stage_snapshot_n"] >= 2 and t["stage_snapshot_ms"] > 0
+    names = [row[0] for row in runtime.stages.ring]
+    assert "gc" in names and "snapshot" in names
+
+
+def test_round_spans_reach_the_operators_trace(tmp_path):
+    """With the per-command tracer on, each finished round is one ``rs``
+    event, the ``ingest`` stamp names the round that released the
+    command, ``to-perfetto`` draws the rounds, and the tools that do not
+    know the kind skip it."""
+    from fantoch_tpu.observability import read_trace
+    from fantoch_tpu.observability.critpath import critpath_report
+    from fantoch_tpu.observability.perfetto import (
+        ROUNDS_TID, to_perfetto, validate_perfetto,
+    )
+    from fantoch_tpu.observability.report import diff_stages, summarize
+
+    trace = str(tmp_path / "trace.jsonl")
+    runtime, _clients = _served(trace_file=trace, config={"trace_sample_rate": 1.0})
+    events = read_trace(trace)
+    rounds = [ev for ev in events if ev["k"] == "rs"]
+    assert rounds and len(rounds) == runtime.stages.n["round"]
+    assert all(ev["name"] == "round" and ev["t1"] >= ev["t0"] and ev["pid"] == 1
+               for ev in rounds)
+    assert all(ev["t1"] <= ev["t"] for ev in rounds)  # on the log's own clock
+    ingests = [ev for ev in events if ev["k"] == "span" and ev["stage"] == "ingest"]
+    assert len(ingests) == 4 * COMMANDS_PER_CLIENT
+    assert {ev["m"]["round"] for ev in ingests} <= {ev["round"] for ev in rounds}
+    drawn = to_perfetto(events)
+    validate_perfetto(drawn)
+    slices = [ev for ev in drawn["traceEvents"] if ev.get("cat") == "round"]
+    assert len(slices) == len(rounds) and {ev["tid"] for ev in slices} == {ROUNDS_TID}
+    # report, critpath and diff see the same spans with and without the kind
+    plain = [ev for ev in events if ev["k"] != "rs"]
+    with_kind, without = summarize(events), summarize(plain)
+    assert with_kind.pop("events") == without.pop("events") + len(rounds)
+    assert with_kind == without
+    differ = diff_stages(events, plain, tol_frac=0.0, tol_abs_us=0)
+    assert differ["matched"] == 4 * COMMANDS_PER_CLIENT
+    assert not (differ["only_a"] or differ["only_b"] or differ["mismatches"])
+    assert critpath_report(events) == critpath_report(plain)

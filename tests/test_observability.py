@@ -684,3 +684,50 @@ def test_snapshot_names_the_backend_that_served(tmp_path):
                 }
             else:
                 assert snap.device is None and snap.backend is None
+
+
+def test_a_capture_holds_the_round_stages_and_no_python_frames(tmp_path):
+    """``capture_device_profile`` runs without the python tracer (which
+    slows the host it measures several times over) and with the host
+    tracer, so the served round's ``fantoch/*`` stage annotations land in
+    the capture beside the runtime's own events."""
+    from jax.profiler import ProfileData
+
+    from fantoch_tpu.observability.exposition import capture_device_profile
+    from fantoch_tpu.run.harness import run_device_server
+
+    config = Config(3, 1, shard_count=1)
+    workload = Workload(
+        shard_count=1, key_gen=ConflictRateKeyGen(50), keys_per_command=1,
+        commands_per_client=10, payload_size=1,
+    )
+
+    async def serve():
+        return await run_device_server(
+            config, workload, client_count=4, batch_size=8,
+            open_loop_interval_ms=1, pipeline=True,
+        )
+
+    async def go():
+        await serve()  # the first run compiles (or loads) the round
+        capture = asyncio.ensure_future(capture_device_profile(str(tmp_path), 1500))
+        await asyncio.sleep(0.05)
+        await serve()
+        return await capture
+
+    reply = asyncio.run(go())
+    assert reply.get("ms") == 1500 and reply["path"].startswith(str(tmp_path)), reply
+    found = glob.glob(reply["path"] + "/**/*.xplane.pb", recursive=True)
+    assert len(found) == 1
+    names = [
+        event.name
+        for plane in ProfileData.from_file(found[0]).planes
+        for line in plane.lines
+        for event in line.events
+    ]
+    stages = {name.split("#", 1)[0] for name in names if name.startswith("fantoch/")}
+    assert {"fantoch/step", "fantoch/deliver", "fantoch/round", "fantoch/fetch",
+            "fantoch/execute", "fantoch/assemble", "fantoch/enqueue"} <= stages
+    # the python tracer names its frames "$file:line function"
+    assert not [name for name in names if name.startswith("$")]
+    assert len(names) > len([n for n in names if n.startswith("fantoch/")])  # the runtime's own

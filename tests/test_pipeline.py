@@ -512,3 +512,100 @@ def test_ingest_ring_slot_never_reused_while_in_flight():
         assert [v for _r, v in outs] == [
             10 * i + j for i in range(8) for j in (1, 2)
         ]
+
+
+# --- round-stage spans (observability/device.py StageRecorder) ---
+
+
+class _HalvesDriver(PipelineCore):
+    """A driver that implements the halves, as the device drivers do:
+    ``dispatch`` / ``drain`` are PipelineCore's own, under its spans."""
+
+    def __init__(self):
+        self.batch_size = 8
+        self._init_pipeline()
+        self._round = 0
+
+    def _assemble(self, batch):
+        return list(batch)
+
+    def _enqueue(self, staged):
+        self._round += 1
+        return (self._round, staged)
+
+    def _execute(self, tok, fetched):
+        assert fetched == tok  # device_get of host values is the identity
+        r, batch = tok
+        return [(r, item) for item in batch]
+
+
+def test_drain_wall_is_fetch_plus_execute_and_dispatch_is_its_halves():
+    """``device_drain_ms`` is the whole of a drain, so it already holds
+    the blocking fetch that ``device_fetch_ms`` reports again: the
+    witness of the double count in dispatch + fetch + drain sums.  The
+    three keys are reads of the stage recorder."""
+    d = _HalvesDriver()
+    for i in range(5):
+        assert d.step([i, i + 10]) == [(i + 1, i), (i + 1, i + 10)]
+    c, s = d.device_counters(), d.stages.counters()
+    assert s["stage_fetch_n"] == s["stage_execute_n"] == 5
+    assert s["stage_assemble_n"] == s["stage_enqueue_n"] == c["device_dispatches"] == 5
+    assert c["device_fetch_ms"] == s["stage_fetch_ms"] > 0
+    assert c["device_drain_ms"] == pytest.approx(
+        s["stage_fetch_ms"] + s["stage_execute_ms"], abs=0.002)
+    assert c["device_dispatch_ms"] == pytest.approx(
+        s["stage_assemble_ms"] + s["stage_enqueue_ms"], abs=0.002)
+    assert c["device_drain_ms"] > c["device_fetch_ms"]
+
+
+def test_a_pipelined_drains_spans_carry_the_retired_rounds_id():
+    d = _HalvesDriver()
+    d.pipeline_depth = 1
+    assert d.step_pipelined(["a"]) == []          # dispatch 1 stays in flight
+    assert d.step_pipelined(["b"]) == [(1, "a")]  # dispatch 2 retires round 1
+    assert d.flush_pipeline() == [(2, "b")]
+    rounds = [(name, round_id) for name, _t0, _t1, round_id, _thread, _parent in d.stages.ring]
+    assert rounds == [
+        ("assemble", 1), ("enqueue", 1),
+        ("assemble", 2), ("enqueue", 2), ("fetch", 1), ("execute", 1),
+        ("fetch", 2), ("execute", 2),
+    ]
+    for _name, t0, t1, _round, _thread, parent in d.stages.ring:
+        assert t1 >= t0 and parent is None  # no step span above a bare driver
+
+
+def test_stage_counters_are_numeric_and_monotone_and_the_ring_is_bounded():
+    from fantoch_tpu.observability.device import ROUND_STAGES, StageRecorder
+
+    rec = StageRecorder(ring=8)
+    first = rec.counters()
+    assert all(f"stage_{name}_ms" in first and f"stage_{name}_n" in first
+               for name in ROUND_STAGES)  # every stage is in the first snapshot
+    assert set(first.values()) == {0}
+    for i in range(20):
+        with rec.span("step", i, cpu=True):
+            with rec.span("assemble", i) as inner:
+                pass
+        rec.record("handoff", 5, 9, i, "round")
+    assert len(rec.ring) == 8 and inner.parent == "step" and inner.t1 >= inner.t0 > 0
+    assert rec.ring[-1] == ("handoff", 5, 9, 19, rec.ring[-1][4], "round")
+    second = rec.counters()
+    assert all(isinstance(value, (int, float)) for value in second.values())
+    assert all(second[key] >= first[key] for key in first)
+    assert second["stage_step_n"] == second["stage_assemble_n"] == second["stage_handoff_n"] == 20
+    assert second["stage_handoff_ms"] == pytest.approx(20 * 4e-6, abs=1e-3)
+    assert second["stage_step_ms"] >= second["stage_assemble_ms"] > 0
+    assert second["stage_step_cpu_ms"] >= 0
+
+
+def test_the_ring_dumps_as_json(tmp_path):
+    import json
+
+    d = _HalvesDriver()
+    d.step(["x"])
+    path = tmp_path / "round_spans.json"
+    d.stages.dump(str(path))
+    blob = json.loads(path.read_text())
+    assert blob["clock"] == "monotonic_ns"
+    assert blob["columns"] == ["name", "t0_ns", "t1_ns", "round", "thread", "parent"]
+    assert [row[0] for row in blob["spans"]] == ["assemble", "enqueue", "fetch", "execute"]
