@@ -193,5 +193,20 @@ class CommandResult:
             assert key not in self._results
             self._results[key] = res
 
+    def __reduce__(self):
+        # plain values on the wire: no class path per field, no BUILD
+        rifl = self._rifl
+        return _restore_result, (rifl[0], rifl[1], self._key_count, self._results)
+
     def __repr__(self) -> str:
         return f"CommandResult({self._rifl}, {len(self._results)}/{self._key_count})"
+
+
+def _restore_result(
+    source: int, sequence: int, key_count: int, results: Dict[Key, Tuple[KVOpResult, ...]]
+) -> CommandResult:
+    """Unpickle a :class:`CommandResult` from the four values its
+    ``__reduce__`` carries."""
+    result = CommandResult(Rifl(source, sequence), key_count)
+    result._results = results
+    return result

@@ -47,7 +47,7 @@ from __future__ import annotations
 import asyncio
 import gc
 import os
-from collections import deque
+from collections import defaultdict, deque
 from time import monotonic, monotonic_ns
 from typing import Any, Deque, Dict, List, NamedTuple, Optional, Tuple
 
@@ -80,7 +80,7 @@ from fantoch_tpu.run.prelude import (
     Submit,
     ToClient,
 )
-from fantoch_tpu.run.rw import Rw
+from fantoch_tpu.run.rw import Rw, frame
 from fantoch_tpu.utils import key_hash, logger
 
 Address = Tuple[str, int]
@@ -1303,32 +1303,52 @@ class _DeviceClientSession:
         }
         self._shards_left[cmd.rifl] = cmd.shard_count
 
-    def deliver(self, result: ExecutorResult) -> bool:
-        """Route one per-key partial; returns True when the rifl is fully
-        answered (all shards' CommandResults written)."""
-        shards = self._key_shard.get(result.rifl)
-        if shards is None:
-            return True  # stale (session re-registered the rifl, or bug)
-        sid = shards[result.key]
-        done = self.pending_by_shard[sid].add_executor_result(result)
-        if done is not None:
-            tracer = self.runtime.tracer
-            if tracer.enabled:
-                tracer.span(
-                    "executed", done.rifl, pid=self.runtime.process_id
-                )
-                tracer.edge(
-                    "s", "Reply", self.runtime.process_id, 0, 0,
-                    rifl=done.rifl,
-                )
-            self.rw.write(ToClient(done))
+    def deliver(self, results: List[ExecutorResult]) -> int:
+        """Route one round's per-key partials of this session's commands,
+        in the order the round executed them: aggregate each, encode a
+        frame for every ``CommandResult`` that completes (one per shard
+        of the command), and hand the connection all of them in one
+        write.  Returns how many rifls are now fully answered; those are
+        gone from ``runtime.rifl_sessions`` whether or not the write went
+        through."""
+        runtime = self.runtime
+        rifl_sessions = runtime.rifl_sessions
+        key_shard = self._key_shard
+        shards_left = self._shards_left
+        pending_by_shard = self.pending_by_shard
+        tracer = runtime.tracer
+        tracing = tracer.enabled
+        frames: List[bytes] = []
+        answered = 0
+        for result in results:
+            rifl = result.rifl
+            shards = key_shard.get(rifl)
+            if shards is None:
+                # stale (the session re-registered the rifl, or a bug):
+                # counts as answered, once
+                if rifl_sessions.pop(rifl, None) is not None:
+                    answered += 1
+                continue
+            done = pending_by_shard[shards[result.key]].add_executor_result(result)
+            if done is None:
+                continue
+            if tracing:
+                tracer.span("executed", rifl, pid=runtime.process_id)
+                tracer.edge("s", "Reply", runtime.process_id, 0, 0, rifl=rifl)
+            frames.append(frame(ToClient(done)))
+            left = shards_left[rifl] - 1
+            if left:
+                shards_left[rifl] = left
+            else:
+                del key_shard[rifl], shards_left[rifl], rifl_sessions[rifl]
+                answered += 1
+        if frames:
+            data = b"".join(frames)
+            self.rw.write_frames(data)
+            runtime._reply_writes += 1
+            runtime._reply_bytes += len(data)
             self._flush_needed.set()
-            self._shards_left[result.rifl] -= 1
-            if self._shards_left[result.rifl] == 0:
-                del self._key_shard[result.rifl]
-                del self._shards_left[result.rifl]
-                return True
-        return False
+        return answered
 
     async def _flush_loop(self) -> None:
         runtime = self.runtime
@@ -1702,6 +1722,8 @@ class DeviceRuntime:
         self._decode_tally = [0, 0]  # [ns, frames] of pickle.loads, shared with every Rw
         self._flush_ns = 0  # awaits of rw.flush() in the sessions
         self._flushes = 0
+        self._reply_writes = 0  # writes of a round's frames to a connection
+        self._reply_bytes = 0
         self._admit_ns = 0  # a Submit received -> runtime.submit returned
         self._queue_wait_ms = 0.0  # sum over released commands, ring time
         self._queue_released = 0
@@ -1873,6 +1895,8 @@ class DeviceRuntime:
             "queue_released": self._queue_released,
             "reply_flush_ms": round(self._flush_ns / 1e6, 3),
             "reply_flushes": self._flushes,
+            "reply_writes": self._reply_writes,
+            "reply_bytes": self._reply_bytes,
             # the event loop's lateness: worst wake-up, and the sum and
             # count of wake-ups later than LOOP_STALL_MS
             "loop_lag_hwm_ms": round(self._loop_lag_hwm_ms, 3),
@@ -2057,22 +2081,28 @@ class DeviceRuntime:
             del self.rifl_sessions[rifl]
 
     def _deliver(self, results: List[ExecutorResult]) -> None:
+        """The reply stage of a round: its results grouped by the
+        session that submitted them, then one aggregate-encode-write
+        pass per session."""
+        rifl_sessions = self.rifl_sessions
+        by_session: Dict[_DeviceClientSession, List[ExecutorResult]] = defaultdict(list)
         for result in results:
-            session = self.rifl_sessions.get(result.rifl)
+            session = rifl_sessions.get(result.rifl)
             if session is None:
                 continue  # session closed mid-flight
+            by_session[session].append(result)
+        for session, batch in by_session.items():
             try:
-                if session.deliver(result):
-                    self.replied += 1
-                    del self.rifl_sessions[result.rifl]
+                self.replied += session.deliver(batch)
             except (ConnectionError, OSError) as exc:
                 # runs on the (fatal) driver task: a half-closed client
-                # connection must cost only its own results — but only
+                # connection must cost only its own replies — but only
                 # transport faults are session-scoped; logic errors
                 # (aggregation invariants) still fail the runtime loudly
                 logger.warning(
-                    "dropping result for client %s (dead session): %r",
-                    result.rifl.source, exc,
+                    "dropping %d results of the round for clients %s "
+                    "(dead session): %r",
+                    len(batch), session.client_ids, exc,
                 )
 
     # --- the serving loop ---

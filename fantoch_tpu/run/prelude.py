@@ -14,7 +14,7 @@ import asyncio
 from dataclasses import dataclass
 from typing import Any, Dict, List, Optional
 
-from fantoch_tpu.core.command import Command, CommandResult
+from fantoch_tpu.core.command import Command, CommandResult, _restore_result
 from fantoch_tpu.core.ids import ClientId, ProcessId, Rifl, ShardId
 from fantoch_tpu.run.backpressure import BoundedQueue
 from fantoch_tpu.run.routing import WorkerIndex, resolve_index
@@ -104,6 +104,17 @@ class Submit:
 @dataclass
 class ToClient:
     cmd_result: CommandResult
+
+    def __reduce__(self):
+        # the reply is the hot frame of the client plane: the result's
+        # plain values under one callable, instead of two class paths,
+        # the attribute names and a BUILD each
+        return _to_client, self.cmd_result.__reduce__()[1]
+
+
+def _to_client(*values) -> ToClient:
+    """Unpickle a :class:`ToClient` from its ``CommandResult``'s values."""
+    return ToClient(_restore_result(*values))
 
 
 @dataclass

@@ -32,6 +32,13 @@ def deserialize(payload: bytes) -> Any:
     return pickle.loads(payload)
 
 
+def frame(value: Any) -> bytes:
+    """One frame as it goes on the wire: u32 big-endian length, then the
+    pickle."""
+    payload = serialize(value)
+    return _LEN.pack(len(payload)) + payload
+
+
 async def connect_with_retry(
     addr: tuple, attempts: int = 120, backoff_s: float = 0.05
 ) -> "Rw":
@@ -94,11 +101,12 @@ class Rw:
 
     def write(self, value: Any) -> None:
         """Queue one frame without flushing."""
-        self.write_frame(serialize(value))
+        self._writer.write(frame(value))
 
-    def write_frame(self, payload: bytes) -> None:
-        """Queue one pre-serialized frame without flushing."""
-        self._writer.write(_LEN.pack(len(payload)) + payload)
+    def write_frames(self, frames: bytes) -> None:
+        """Queue a run of frames (each as :func:`frame` makes it, joined)
+        with one call of the transport, without flushing."""
+        self._writer.write(frames)
 
     async def send(self, value: Any) -> None:
         """Queue one frame and flush."""

@@ -1919,3 +1919,260 @@ def test_round_spans_reach_the_operators_trace(tmp_path):
     assert differ["matched"] == 4 * COMMANDS_PER_CLIENT
     assert not (differ["only_a"] or differ["only_b"] or differ["mismatches"])
     assert critpath_report(events) == critpath_report(plain)
+
+
+# --- the reply stage works on a round (DeviceRuntime._deliver) ---
+
+
+class _CountingWriter:
+    """What ``Rw`` needs of a ``StreamWriter``: every ``write`` is one
+    entry of ``writes``; ``fail`` is raised by each of them instead."""
+
+    def __init__(self, fail=None):
+        self.writes = []
+        self.fail = fail
+
+    def get_extra_info(self, name):
+        return None
+
+    def write(self, data):
+        if self.fail is not None:
+            raise self.fail
+        self.writes.append(bytes(data))
+
+
+def _reply_stage(shard_count=1, connections=3, failing=None):
+    """An unstarted runtime with one session per connection, each over
+    a counting writer; ``failing`` maps a connection to what its writes
+    raise."""
+    from fantoch_tpu.run.device_runner import DeviceRuntime, _DeviceClientSession
+    from fantoch_tpu.run.rw import Rw
+
+    runtime = DeviceRuntime(
+        Config(3, 1, shard_count=shard_count), ("127.0.0.1", 0),
+        batch_size=8, key_buckets=64, key_width=2,
+    )
+    writers = [_CountingWriter((failing or {}).get(i)) for i in range(connections)]
+    sessions = [_DeviceClientSession(runtime, Rw(None, writer)) for writer in writers]
+    return runtime, sessions, writers
+
+
+def _submitted(runtime, session, cmd):
+    """What ``_DeviceClientSession.run`` does for an admitted Submit,
+    short of the ring."""
+    session.track(cmd)
+    runtime.rifl_sessions[cmd.rifl] = session
+
+
+def _frames(data):
+    """The ``ToClient``s of a connection's bytes, through the client
+    plane's own decoder."""
+    from fantoch_tpu.run import rw
+
+    out, at = [], 0
+    while at < len(data):
+        (length,) = rw._LEN.unpack_from(data, at)
+        out.append(rw.deserialize(data[at + 4:at + 4 + length]))
+        at += 4 + length
+    assert at == len(data)
+    return out
+
+
+def _fields(to_client):
+    result = to_client.cmd_result
+    return result.rifl, result._key_count, result.results, result.ready
+
+
+def _one_at_a_time(tracked, rounds):
+    """The reference: a result at a time, a ``ToClient`` at a time, as
+    the stage worked before it took a round.  ``tracked`` maps a
+    connection to its live commands; returns connection -> replies."""
+    from fantoch_tpu.core.command import CommandResult
+    from fantoch_tpu.run.prelude import ToClient
+
+    owner, parts, left, out = {}, {}, {}, {conn: [] for conn in tracked}
+    for conn, cmds in tracked.items():
+        for cmd in cmds:
+            owner[cmd.rifl], left[cmd.rifl] = conn, cmd.shard_count
+            for sid in cmd.shards():
+                part = CommandResult(cmd.rifl, cmd.key_count(sid))
+                for key in cmd.keys(sid):
+                    parts[cmd.rifl, key] = part
+    for results in rounds:
+        for result in results:
+            if result.rifl not in owner:
+                continue
+            part = parts[result.rifl, result.key]
+            if part.add_partial(result.key, result.op_results):
+                out[owner[result.rifl]].append(ToClient(part))
+                left[result.rifl] -= 1
+                if left[result.rifl] == 0:
+                    del owner[result.rifl]
+    return out
+
+
+def _mixed_rounds(shard_count):
+    """Two rounds of results over three live connections and a dropped
+    one: single-key commands, a two-key command whose partials arrive
+    in different rounds, a two-shard command (where the server has two
+    shards), a stale rifl and the dropped session's rifl."""
+    from fantoch_tpu.executor.base import ExecutorResult
+
+    put = KVOp.put("v" * 100)
+    singles = {
+        conn: [Command.from_single(Rifl(10 + conn, seq), 0, f"k{conn}.{seq}", put)
+               for seq in (1, 2, 3)]
+        for conn in (0, 1, 2)
+    }
+    two_key = Command(Rifl(10, 9), {0: {"a": (put,), "b": (put,)}})
+    tracked = {0: singles[0] + [two_key], 1: singles[1], 2: singles[2]}
+    if shard_count == 2:
+        tracked[1] = tracked[1] + [Command(Rifl(11, 9), {0: {"c": (put,)}, 1: {"d": (put, put)}})]
+    stale, dropped = Rifl(12, 77), Command.from_single(Rifl(13, 1), 0, "gone", put)
+
+    def res(rifl, key, *values):
+        return ExecutorResult(rifl, key, values or ("prev-" + key,))
+
+    first = [res(Rifl(10, 1), "k0.1"), res(Rifl(11, 1), "k1.1"), res(Rifl(10, 9), "b"),
+             res(stale, "x"), res(Rifl(12, 1), "k2.1", None), res(Rifl(13, 1), "gone"),
+             res(Rifl(10, 2), "k0.2"), res(Rifl(11, 9), "d", None, "d1")]
+    second = [res(Rifl(11, 2), "k1.2"), res(Rifl(10, 9), "a"), res(Rifl(11, 9), "c"),
+              res(Rifl(10, 3), "k0.3"), res(Rifl(12, 2), "k2.2"), res(stale, "x")]
+    live = {cmd.rifl for cmds in tracked.values() for cmd in cmds} | {stale, dropped.rifl}
+    rounds = [[r for r in results if r.rifl in live] for results in (first, second)]
+    return tracked, stale, dropped, rounds
+
+
+@pytest.mark.parametrize("shard_count", [1, 2])
+def test_a_rounds_replies_equal_the_one_at_a_time_reference(shard_count):
+    tracked, stale, dropped, rounds = _mixed_rounds(shard_count)
+    runtime, sessions, writers = _reply_stage(shard_count, connections=4)
+    for conn, cmds in tracked.items():
+        for cmd in cmds:
+            _submitted(runtime, sessions[conn], cmd)
+    runtime.rifl_sessions[stale] = sessions[2]  # routed, never tracked
+    _submitted(runtime, sessions[3], dropped)
+    runtime.drop_session(sessions[3])
+    for results in rounds:
+        runtime._deliver(results)
+    expected = _one_at_a_time(tracked, rounds)
+    for conn in tracked:
+        got = _frames(b"".join(writers[conn].writes))
+        assert [_fields(t) for t in got] == [_fields(t) for t in expected[conn]], conn
+        assert got and all(t.cmd_result.ready for t in got)
+    assert writers[3].writes == []
+    answered = sum(len(cmds) for cmds in tracked.values())
+    # the stale rifl counts as answered, once; only the unexecuted commands stay routed
+    assert runtime.replied == answered - 2 + 1
+    assert set(runtime.rifl_sessions) == {Rifl(11, 3), Rifl(12, 3)}
+    assert not sessions[0]._key_shard and not sessions[0]._shards_left
+
+
+def test_a_round_is_one_write_per_session_and_the_snapshot_counts_it():
+    tracked, stale, dropped, rounds = _mixed_rounds(1)
+    runtime, sessions, writers = _reply_stage(connections=4)
+    for conn, cmds in tracked.items():
+        for cmd in cmds:
+            _submitted(runtime, sessions[conn], cmd)
+    runtime._deliver(rounds[0])
+    # every live connection has a reply in the first round; the fourth has none
+    assert [len(w.writes) for w in writers] == [1, 1, 1, 0]
+    assert all(s._flush_needed.is_set() for s in sessions[:3])
+    assert not sessions[3]._flush_needed.is_set()
+    runtime._deliver(rounds[1])
+    runtime._deliver([])  # a progress round with nothing executed writes nothing
+    assert [len(w.writes) for w in writers] == [2, 2, 2, 0]
+    runtime._publish_tallies()
+    t = runtime._tallies
+    assert t["reply_writes"] == sum(len(w.writes) for w in writers) == 6
+    assert t["reply_bytes"] == sum(len(data) for w in writers for data in w.writes)
+    assert t["replied"] == sum(len(_frames(data)) for w in writers for data in w.writes) == 8
+
+
+def test_a_dead_connection_costs_only_its_own_replies(caplog):
+    tracked, stale, dropped, rounds = _mixed_rounds(1)
+    runtime, sessions, writers = _reply_stage(
+        connections=3, failing={1: ConnectionResetError("peer went away")})
+    for conn, cmds in tracked.items():
+        for cmd in cmds:
+            _submitted(runtime, sessions[conn], cmd)
+    with caplog.at_level("WARNING"):
+        for results in rounds:
+            runtime._deliver(results)
+    expected = _one_at_a_time(tracked, rounds)
+    for conn in (0, 2):
+        got = _frames(b"".join(writers[conn].writes))
+        assert [_fields(t) for t in got] == [_fields(t) for t in expected[conn]]
+    assert writers[1].writes == []
+    # one warning per session and round, not one per result
+    assert len([r for r in caplog.records if "dead session" in r.getMessage()]) == 2
+    # the answered rifls of the dead connection are not left routed
+    assert set(runtime.rifl_sessions) == {Rifl(11, 3), Rifl(12, 3)}
+    assert runtime.replied == len(expected[0]) + len(expected[2])
+    # anything but a transport fault still fails the stage loudly
+    writers[0].fail = RuntimeError("not a transport fault")
+    _submitted(runtime, sessions[0], Command.from_single(Rifl(10, 50), 0, "z", KVOp.put("v")))
+    with pytest.raises(RuntimeError):
+        runtime._deliver([rounds[0][0]._replace(rifl=Rifl(10, 50), key="z")])
+
+
+def test_every_reply_is_one_executed_span_and_one_reply_edge():
+    class Recorder:
+        enabled = True
+
+        def __init__(self):
+            self.spans, self.edges = [], []
+
+        def span(self, stage, rifl, **kw):
+            self.spans.append((stage, rifl, kw["pid"]))
+
+        def edge(self, direction, kind, *where, rifl):
+            self.edges.append((direction, kind, rifl))
+
+    tracked, stale, dropped, rounds = _mixed_rounds(2)
+    runtime, sessions, writers = _reply_stage(shard_count=2, connections=3)
+    runtime.tracer = Recorder()
+    for conn, cmds in tracked.items():
+        for cmd in cmds:
+            _submitted(runtime, sessions[conn], cmd)
+    for results in rounds:
+        runtime._deliver(results)
+    replies = [t.cmd_result.rifl for w in writers for t in _frames(b"".join(w.writes))]
+    assert len(replies) == 10  # 7 single-key, the two-key one, two shards of Rifl(11, 9)
+    assert sorted(rifl for _stage, rifl, _pid in runtime.tracer.spans) == sorted(replies)
+    assert {(stage, pid) for stage, _rifl, pid in runtime.tracer.spans} == {
+        ("executed", runtime.process_id)}
+    assert sorted(rifl for _d, _k, rifl in runtime.tracer.edges) == sorted(replies)
+    assert {(d, k) for d, k, _rifl in runtime.tracer.edges} == {("s", "Reply")}
+
+
+@pytest.mark.parametrize("results, key_count", [
+    ({}, 0),  # _reject's CommandResult(rifl, 0)
+    ({"k": ("previous",)}, 1),
+    ({"a": ("x" * 100,), "b": ("y", "z"), "c": (None,)}, 3),
+    ({"k": (None,)}, 1),
+    ({"a": ("x",)}, 2),  # a partial that is not ready stays not ready
+], ids=["empty", "one-key", "three-keys", "none-previous", "not-ready"])
+def test_the_compact_reply_pickle_round_trips(results, key_count):
+    import pickle
+
+    from fantoch_tpu.core.command import CommandResult
+    from fantoch_tpu.run import rw
+    from fantoch_tpu.run.prelude import ToClient
+
+    result = CommandResult(Rifl(2**40 + 7, 2**33), key_count)
+    for key, values in results.items():
+        result.add_partial(key, values)
+    payload = rw.serialize(ToClient(result))
+    back = rw.deserialize(payload)
+    assert type(back) is ToClient and type(back.cmd_result) is CommandResult
+    assert type(back.cmd_result.rifl) is Rifl
+    assert _fields(back) == (Rifl(2**40 + 7, 2**33), key_count, results, len(results) == key_count)
+    assert list(back.cmd_result.results) == list(results)  # key order too
+    # a CommandResult on its own (the simulator's recorders) takes the same form
+    alone = pickle.loads(pickle.dumps(result))
+    assert _fields(ToClient(alone)) == _fields(back)
+    # the frame carries values, not the classes' paths and attribute names
+    for word in (b"CommandResult", b"_key_count", b"_results", b"fantoch_tpu.core.ids"):
+        assert word not in payload
+    assert rw.frame(ToClient(result)) == rw._LEN.pack(len(payload)) + payload
