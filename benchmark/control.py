@@ -20,11 +20,23 @@ every seed, is rejected.  The mutations:
   acknowledged write (a reply only after execution; nothing acknowledged is
   lost).
 * ``foreign_value``: a write returns a value no client of the run wrote.
+
+Where the history holds commands over several keys, three more break the
+guarantee those add, one order of commands across keys and shards:
+
+* ``cross_key_swap``: two commands that share two keys keep their order on
+  one and swap it on the other.
+* ``torn_command``: a command's write on one of its keys is gone from that
+  key's chain (the next write there, or the read-back, returns what the
+  command itself returned); its other key is intact.
+* ``cross_key_inversion``: a command is stamped as acknowledged before one
+  it follows through a path over two keys was sent.
 """
 
 from __future__ import annotations
 
 import argparse
+import itertools
 import json
 import os
 import sys
@@ -35,7 +47,7 @@ if ROOT not in sys.path:
 
 import numpy as np  # noqa: E402
 
-from benchmark.check import check_history  # noqa: E402
+from benchmark.check import History, check_history  # noqa: E402
 from benchmark.generators.kv_loop import BAD_VALUE, GET, OK, PUT, READBACK  # noqa: E402
 
 
@@ -95,6 +107,102 @@ def mutations(records: dict, strays, seed: int):
     rec = fresh()
     rec["ret_client"][rows[0]] = rec["ret_seq"][rows[0]] = BAD_VALUE
     yield "foreign_value", rec, strays
+
+    if len(records.get("more_key", ())):
+        for name, rec in _Order(records).mutations(rng, fresh):
+            yield name, rec, strays
+
+
+class _Order:
+    """Every key's chain of acknowledged writes, first and further keys
+    alike, over the entries of ``check.History``: entry ``e < n`` is record
+    ``e`` with its first key, ``e >= n`` is row ``e - n`` of the ``more_*``
+    columns (the history is a sound one: every such row has its command)."""
+
+    def __init__(self, rec: dict):
+        hist = History(rec)
+        cols = hist.cols
+        self.rec, self.n, self.record, self.key = rec, hist.n, hist.row, cols["key"]
+        self.ident = [(c << 32) | s for c, s in zip(cols["client"], cols["seq"])]
+        self.ret = [(c << 32) | s if c >= 0 else -1 for c, s in zip(cols["ret_client"], cols["ret_seq"])]
+        self.returned_by: dict[tuple[int, int], list[int]] = {}  # (key, value's id) -> entries
+        for entry in range(hist.entries):
+            if cols["status"][entry] == OK:
+                self.returned_by.setdefault((self.key[entry], self.ret[entry]), []).append(entry)
+        writes = [e for e in range(hist.entries) if cols["op"][e] == PUT and cols["status"][e] == OK]
+        self.on = {(self.ident[e], self.key[e]): e for e in writes}  # who wrote what where
+        # acknowledged writes of several keys: record -> its entries, the first key's first
+        self.entries_of = {record: [record] + more for record, more in hist.more_of.items()
+                           if cols["op"][record] == PUT and cols["status"][record] == OK}
+
+    def set_returned(self, rec: dict, entry: int, value: int) -> None:
+        """``entry`` now returned the value of the write with id ``value``."""
+        client, seq = (-1, -1) if value < 0 else (value >> 32, value & 0xFFFFFFFF)
+        if entry < self.n:
+            rec["ret_client"][entry], rec["ret_seq"][entry] = client, seq
+        else:
+            rec["more_ret_client"][entry - self.n], rec["more_ret_seq"][entry - self.n] = client, seq
+
+    def after(self, entry: int) -> list[int]:
+        """The entries that returned ``entry``'s value: the next write, reads."""
+        return self.returned_by.get((self.key[entry], self.ident[entry]), [])
+
+    def follows(self, first: int, second: int) -> bool:
+        """Whether ``second`` comes after ``first`` in their key's chain."""
+        at = first
+        while at is not None and at != second:
+            at = next((e for e in self.after(at) if self.rec["op"][self.record[e]] == PUT), None)
+        return at == second
+
+    def mutations(self, rng, fresh):
+        op, sent = self.rec["op"], self.rec["sent"]
+
+        def pick(found: list, what: str):
+            if not found:
+                raise ValueError(f"nothing to mutate: no {what}")
+            return found[int(rng.integers(len(found)))]
+
+        # two commands over the same two keys: on one of them the later moves
+        # to just before the earlier, ... p -> a -> x.. -> b -> n ... becomes p -> b -> a -> x.. -> n
+        by_pair: dict[tuple[int, int], list[int]] = {}
+        for record, entries in self.entries_of.items():
+            for pair in itertools.combinations(sorted(self.key[e] for e in entries), 2):
+                by_pair.setdefault(pair, []).append(record)
+        pair, records = pick([item for item in by_pair.items() if len(item[1]) >= 2],
+                             "two acknowledged commands that share two keys")
+        a, b = (self.on[(self.ident[int(r)], pair[1])] for r in rng.choice(records, 2, replace=False))
+        if not self.follows(a, b):
+            a, b = b, a
+        new = fresh()
+        for entry in self.after(b):
+            self.set_returned(new, entry, self.ret[b])
+        self.set_returned(new, b, self.ret[a])
+        self.set_returned(new, a, self.ident[b])
+        yield "cross_key_swap", new
+
+        # a command's write on a further key is gone: what follows it there
+        # returns what the command itself returned
+        entry = pick([e for entries in self.entries_of.values() for e in entries[1:] if self.after(e)],
+                     "further key of a command that anything follows")
+        new = fresh()
+        for later in self.after(entry):
+            self.set_returned(new, later, self.ret[entry])
+        yield "torn_command", new
+
+        # x -> c on one key, c -> d on another: d acknowledged before x was sent
+        paths = []
+        for record, entries in self.entries_of.items():
+            x = self.on.get((self.ret[entries[1]], self.key[entries[1]]))
+            d = next((e for e in self.after(record) if op[self.record[e]] == PUT), None)
+            if x is not None and d is not None and self.record[x] != self.record[d]:
+                paths.append((bool(sent[record] < sent[self.record[x]]), self.record[x], self.record[d]))
+        # best where c was sent before x: then no key's own chain shows it, only the path
+        _, early, late = pick([p for p in paths if p[0]] or paths,
+                              "command that follows one on a key and precedes one on another")
+        new = fresh()
+        new["acked"][late] = new["sent"][early] - 0.002
+        new["sent"][late] = new["due"][late] = new["acked"][late] - 0.001
+        yield "cross_key_inversion", new
 
 
 def main(argv=None) -> int:

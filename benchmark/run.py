@@ -11,7 +11,9 @@ the path with the cell's own traffic, then measure for ``--seconds``, stop
 offering, drain; the parent reads a sample of keys back, stops the server,
 checks the answers (``benchmark/check.py``) and prints the result as the
 last line of stdout.  Set-up time runs from process start to the first
-measured send.
+measured send.  A record of the history is one command; the further keys
+of a command over several are rows of a side table (the ``more_*``
+columns, ``benchmark/check.py``), carried beside the records.
 
 Everything that belongs to one cell is data found by name from
 ``BENCHMARK.json``: the configuration (``benchmark/configs``), the mix
@@ -32,6 +34,7 @@ import glob  # noqa: E402
 import importlib.util  # noqa: E402
 import json  # noqa: E402
 import os  # noqa: E402
+import re  # noqa: E402
 import shutil  # noqa: E402
 import signal  # noqa: E402
 import socket  # noqa: E402
@@ -44,7 +47,7 @@ if ROOT not in sys.path:
 
 import numpy as np  # noqa: E402
 
-from benchmark.check import check_history  # noqa: E402
+from benchmark.check import MORE, check_history, command_rows  # noqa: E402
 
 BANNER_LIMIT_S = 600.0   # the server's start, the first import of jax included
 COMPILE_LIMIT_S = 900.0  # the first commands of a cold checkout compile the round
@@ -255,6 +258,9 @@ def run_cell(
             time.sleep(0.1)
         if f"platform={platform} " not in _tail(server_log):
             raise RunFailed(f"the server does not serve from {platform}: {_tail(server_log)}")
+        devices = re.search(r" devices=(\d+) ", _tail(server_log))
+        if devices is None or int(devices.group(1)) < cell["chips"]:
+            raise RunFailed(f"the cell asks for {cell['chips']} chips: {_tail(server_log)}")
         log(f"# server up after {time.monotonic() - started:.1f} s: {_tail(server_log).strip()}")
 
         gen_env = {**env, "JAX_PLATFORMS": "cpu"}
@@ -307,10 +313,13 @@ def run_cell(
         parts = [dict(np.load(os.path.join(out_dir, f"records_{proc}.npz")))
                  for proc in range(n_procs)]
         records = {name: np.concatenate([part[name] for part in parts]) for name in parts[0]}
-        readback = generator.read_back(
-            "127.0.0.1", port, seed, int(mix["clients"]), config["payload_bytes"],
-            _readback_keys(records, int(mix["readback_keys"]), seed), float(mix["drain_limit_s"]),
-        )
+        keys = _readback_keys(records, int(mix["readback_keys"]), seed)
+        if hasattr(generator, "read_back_mix"):  # it has to know the mix: which shard holds a key
+            readback = generator.read_back_mix("127.0.0.1", port, seed, mix, config["payload_bytes"],
+                                               keys, float(mix["drain_limit_s"]))
+        else:
+            readback = generator.read_back("127.0.0.1", port, seed, int(mix["clients"]),
+                                           config["payload_bytes"], keys, float(mix["drain_limit_s"]))
         records = {name: np.concatenate([records[name], readback[name]]) for name in records}
 
         rc = _stop(server)
@@ -337,7 +346,12 @@ def run_cell(
                      "device_dispatches_in_window": delta.get("device_dispatches")}]})
     correct = verdict["correct"] and served
 
-    measured = {name: col[records["phase"] == 1] for name, col in records.items()}
+    # a record is a command; a command's further keys (the more_* columns)
+    # are in the window where their command is
+    in_window = records["phase"] == 1
+    more_in_window = in_window[command_rows(records)]
+    measured = {name: col[more_in_window if name.startswith(MORE) else in_window]
+                for name, col in records.items()}
     failed = int(np.count_nonzero(measured["status"] != 0))
     ctx = {
         "measured": measured, "t0": t0, "seconds": seconds, "drain_end": drain_end,
@@ -363,14 +377,23 @@ def run_cell(
     metrics = {kind: read_metrics(cell[kind], ctx) for kind in ("end_to_end", "per_layer")}
     result["metrics"] = metrics["per_layer" if trace else "end_to_end"]
     result["device"] = device
+    # every number the verdict compared, beside its limit; last in the line
+    result["compared"] = {
+        "violations": {"value": verdict["stats"]["violations"], "limit": 0},
+        "device_dispatches_in_window": {"value": delta.get("device_dispatches", 0), "limit": ">=1"},
+    }
     _report(ctx, reports, verdict, result, {**metrics["end_to_end"], **metrics["per_layer"]},
             server_rc=rc, out_dir=out_dir)
     return result
 
 
 def _readback_keys(records: dict, count: int, seed: int) -> list[int]:
-    """A seeded sample of the keys written, the hottest among them."""
-    written, times = np.unique(records["key"][records["op"] == 0], return_counts=True)
+    """A seeded sample of the keys written, whether first or further keys of
+    their commands, the hottest among them."""
+    further = records.get("more_key", np.zeros(0, np.int32))
+    written, times = np.unique(np.concatenate([
+        records["key"][records["op"] == 0], further[records["op"][command_rows(records)] == 0],
+    ]), return_counts=True)
     hottest = written[np.argsort(-times, kind="stable")[: count // 8]]
     rest = np.setdiff1d(written, hottest)
     rng = np.random.default_rng([int(seed), 23])
@@ -440,6 +463,8 @@ def main(argv=None) -> int:
         print(f"benchmark: {exc}", file=sys.stderr)
         return 1
     print(json.dumps(result), flush=True)
+    for name, pair in result["compared"].items():
+        print(f"compared {name}: {pair['value']} (limit {pair['limit']})", file=sys.stderr)
     return 0
 
 

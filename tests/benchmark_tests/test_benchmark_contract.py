@@ -1,131 +1,126 @@
-"""BENCHMARK.json against the contract's form, and against the files it names."""
+"""BENCHMARK.json against the contract's form, and against the files it
+names.  The rules are functions of a tree (``contract_rules.py``): here they
+are applied to the tree itself, and to a copy of it to which the next PR's
+cell was added as new files and list entries only."""
 
 import json
 import os
-import re
 
 import pytest
 
 from benchmark import run
+from tests.benchmark_tests import contract_rules as rules
+from tests.benchmark_tests import next_cell
 
 ROOT = run.ROOT
-NAME = re.compile(r"^[A-Za-z0-9_][A-Za-z0-9_.\-]{0,63}$")
-UNIT = re.compile(r"^[A-Za-z0-9_/%.\-]{1,16}$")
-SOURCES = {"device_trace", "program_span", "program_counter", "host_clock"}
-
-
-def bench():
-    with open(os.path.join(ROOT, "BENCHMARK.json")) as fh:
-        return json.load(fh)
-
-
-def cells():
-    return [cell["name"] for cell in bench()["workloads"]]
 
 
 def test_the_file_has_the_contracts_keys_and_forms():
-    spec = bench()
-    assert set(spec) == {"command", "paths", "run_seconds", "configs", "workloads",
-                         "end_to_end", "per_layer"}
-    assert os.path.getsize(os.path.join(ROOT, "BENCHMARK.json")) <= 64 * 1024
-    assert 1 <= spec["run_seconds"] <= 51 and isinstance(spec["run_seconds"], int)
-    assert all(os.path.isdir(os.path.join(ROOT, path)) for path in spec["paths"])
-    assert any(word.startswith(spec["paths"][0] + "/") for word in spec["command"])
-    for entry in spec["configs"]:
-        assert set(entry) == {"name", "source", "file", "reduced", "why"}
-        assert NAME.match(entry["name"]) and 1 <= len(entry["source"]) <= 200
-        assert entry["file"].startswith(spec["paths"][0] + "/")
-        with open(os.path.join(ROOT, entry["file"])) as fh:
-            config = json.load(fh)
-        assert config["source"] == entry["source"] and config["reduced"] == entry["reduced"]
-        assert config["guarantees"] and config["assumed"]
-    assert len({entry["file"] for entry in spec["configs"]}) == len(spec["configs"])
-    for cell in spec["workloads"]:
-        assert set(cell) == {"name", "config", "traffic", "chips", "why"}
-        assert NAME.match(cell["name"]) and NAME.match(cell["traffic"])
-        assert cell["chips"] in (1, 4) and 1 <= len(cell["why"]) <= 200
-        assert cell["config"] in {entry["name"] for entry in spec["configs"]}
-    pairs = [(cell["config"], cell["traffic"]) for cell in spec["workloads"]]
-    assert len(set(pairs)) == len(pairs)
-    assert sum(cell["chips"] == 4 for cell in spec["workloads"]) <= max(1, len(pairs) // 2)
-    names = [m["name"] for m in spec["end_to_end"] + spec["per_layer"]]
-    assert len(set(names)) == len(names)
-    for metric in spec["end_to_end"]:
-        assert set(metric) - {"workloads"} == {"name", "unit", "better", "bound", "source"}
-        assert metric["source"] in {"host_clock", "device_trace"}
-        assert 0.01 <= metric["bound"] <= 0.25
-    assert any(m["name"] == "setup_s" and "workloads" not in m for m in spec["end_to_end"])
-    for metric in spec["per_layer"]:
-        assert set(metric) - {"workloads"} == {"name", "unit", "better", "source", "layer", "moves"}
-        assert metric["source"] in SOURCES and 1 <= len(metric["layer"]) <= 200
-        moved = next(m for m in spec["end_to_end"] if m["name"] == metric["moves"])
-        for cell in metric.get("workloads", []):
-            assert cell in moved.get("workloads", cells())
-    for metric in spec["end_to_end"] + spec["per_layer"]:
-        assert NAME.match(metric["name"]) and UNIT.match(metric["unit"])
-        assert metric["better"] in ("lower", "higher")
-        assert set(metric.get("workloads", [])) <= set(cells())
+    rules.the_file_has_the_contracts_keys_and_forms(ROOT)
 
 
-@pytest.mark.parametrize("workload", cells())
+@pytest.mark.parametrize("workload", rules.cells(ROOT))
 def test_every_cell_finds_its_files_by_name(workload):
-    cell = run.load_cell(ROOT, workload)
-    assert cell["chips"] == 1
-    assert cell["config"]["device_batch"] == 4096
-    assert "--device-step" not in cell["config"]["server_flags"]  # the harness adds it
-    assert cell["mix"]["loop"] in ("open", "closed")
-    if cell["mix"]["loop"] == "open":
-        assert cell["mix"]["rate_per_s"] > 0  # the cell's own number, 0.8 of its knee
-        assert cell["mix"]["rate_per_s"] == pytest.approx(0.8 * cell["mix"]["knee_per_s"])
-    end_to_end = {m["name"] for m in cell["end_to_end"]}
-    assert "setup_s" in end_to_end and len(end_to_end) >= 2 and cell["per_layer"]
-    for metric in cell["end_to_end"] + cell["per_layer"]:
-        assert os.path.exists(os.path.join(cell["base"], "readers", metric["reader"] + ".py"))
-    assert {m["moves"] for m in cell["per_layer"]} <= end_to_end
-
-
-SHAPE_FLAGS = {"--protocol", "-n", "-f", "--device-key-buckets", "--device-batch",
-               "--device-pending"}
+    rules.a_cell_finds_its_files_by_name(ROOT, workload)
 
 
 def test_configurations_run_at_the_programs_defaults_and_mark_what_they_assume():
-    """A configuration passes the deployment's shape and nothing else (no
-    tuning flag pinned around a fault of the program), and whatever the cited
-    source does not bear out is named under ``assumed``, in the mix too."""
-    spec = bench()
-    for entry in spec["configs"]:
-        config = run._load(os.path.join(ROOT, entry["file"]))
-        flags = {word for word in config["server_flags"] if word.startswith("-")}
-        assert flags == SHAPE_FLAGS, flags
-        assert {"f", "keys_per_command", "payload_bytes", "write_share"} <= set(config["assumed"])
-        assert "f=1" not in entry["source"] and "1 key" not in entry["source"]
-    for traffic in {cell["traffic"] for cell in spec["workloads"]}:
-        mix = run._load(os.path.join(ROOT, spec["paths"][0], "traffic", traffic + ".json"))
-        assert {"key_gen.coefficient", "clients", "read_share"} <= set(mix["assumed"])
-        if mix["loop"] == "open":
-            assert "arrivals" in mix["assumed"]
+    rules.configurations_pass_their_shape_and_mark_what_they_assume(ROOT)
 
 
 def test_per_layer_metrics_follow_what_they_move_and_list_no_cells():
-    """A later cell is traced without an edit to any list: a per-layer metric
-    is reported wherever the end-to-end metric it moves is."""
-    spec = bench()
-    assert all("workloads" not in metric for metric in spec["per_layer"])
-    for cell in cells():
-        reported = {m["name"] for m in spec["end_to_end"] if cell in m.get("workloads", [cell])}
-        expected = {m["name"] for m in spec["per_layer"] if m["moves"] in reported}
-        assert {m["name"] for m in run.load_cell(ROOT, cell)["per_layer"]} == expected
-        assert expected  # every cell reports at least one per-layer metric
-    for metric in spec["per_layer"]:  # the metric's own file says the same as its entry
-        own = run._load(os.path.join(ROOT, spec["paths"][0], "layer_metrics", metric["name"] + ".json"))
-        assert {key: own[key] for key in metric} == metric, metric["name"]
-        assert own["reads"] and own["reader"]
+    """(The name is the test's first: a metric may list cells since PR 26,
+    where its mechanism lives in some cells only, and then follows its list.)"""
+    rules.per_layer_metrics_follow_what_they_move(ROOT)
 
 
 def test_the_harness_holds_no_cell_protocol_or_metric_name():
-    with open(os.path.join(ROOT, "benchmark", "run.py")) as fh:
-        source = fh.read()
-    spec = bench()
-    for word in [m["name"] for m in spec["end_to_end"] + spec["per_layer"]] + cells() + \
-            [c["name"] for c in spec["configs"]] + ["epaxos", "newt", "tempo", "zipf"]:
-        assert word not in source, word
+    rules.the_harness_holds_no_cell_protocol_or_metric_name(ROOT)
+
+
+def _files(root):
+    return {path: open(path, "rb").read()
+            for folder, _, files in os.walk(root) if "__pycache__" not in folder
+            for path in (os.path.join(folder, name) for name in files)
+            if not os.path.islink(path)}
+
+
+def test_the_rules_take_the_next_prs_four_chip_sharded_two_key_cell(tmp_path):
+    """The cell `PERF.md` s7 keeps first in line, as the PR that adds it will:
+    four chips, `--shard-count 4 --device-key-width 2`, a `kv_multi` mix, its
+    name on `goodput_cmds_s`, a per-layer metric of its own.  Every rule holds
+    for the tree with it, and no file that was there is changed."""
+    root = next_cell.copy_tree(str(tmp_path))
+    before = _files(root)
+    spec_before = rules.bench(root)
+    cell = next_cell.add_next_cell(root)
+    for rule in rules.RULES:
+        rule(root)
+    for workload in rules.cells(root):
+        rules.a_cell_finds_its_files_by_name(root, workload)
+    assert cell in rules.cells(root) and len(rules.cells(root)) == len(rules.cells(ROOT)) + 1
+    loaded = run.load_cell(root, cell)
+    assert loaded["chips"] == 4 and loaded["mix"]["generator"] == "kv_multi"
+    flags = rules.flags_of(loaded["config"])
+    assert flags["--shard-count"] == "4" and flags["--device-key-width"] == "2"
+    assert next_cell.METRIC in {m["name"] for m in loaded["per_layer"]}
+    assert "goodput_cmds_s" in {m["name"] for m in loaded["end_to_end"]}
+    # the metric of its own is reported in that cell alone
+    for other in rules.cells(ROOT):
+        assert next_cell.METRIC not in {m["name"] for m in run.load_cell(root, other)["per_layer"]}
+    # new files and appended entries only
+    after = _files(root)
+    changed = {path for path, content in before.items() if after.get(path) != content}
+    assert changed == {os.path.join(root, "BENCHMARK.json")}
+    assert len(after) == len(before) + 3  # a configuration, a mix, a layer metric
+    spec = rules.bench(root)
+    for section in ("configs", "workloads", "per_layer"):
+        assert spec[section][:-1] == spec_before[section]
+    for now, then in zip(spec["end_to_end"], spec_before["end_to_end"]):
+        assert {**now, "workloads": None} == {**then, "workloads": None}
+        assert now.get("workloads", [])[: len(then.get("workloads", []))] == then.get("workloads", [])
+
+
+@pytest.mark.parametrize("breach,rule", [
+    # a tuning flag pinned in a configuration
+    (lambda config, mix, spec: config["server_flags"].extend(["--serving-chain-max", "1"]),
+     rules.configurations_pass_their_shape_and_mark_what_they_assume),
+    # a shape flag that says another thing than the deployment
+    (lambda config, mix, spec: config["deployment"].update(shards=2),
+     rules.configurations_pass_their_shape_and_mark_what_they_assume),
+    # a deployment over shards whose flags leave the shards out
+    (lambda config, mix, spec: config.update(server_flags=[
+        w for w in config["server_flags"] if w not in ("--shard-count", "4")]),
+     rules.configurations_pass_their_shape_and_mark_what_they_assume),
+    # a mix of one key a command under a deployment of two
+    (lambda config, mix, spec: mix.update(keys_per_command=1),
+     rules.configurations_pass_their_shape_and_mark_what_they_assume),
+    # four chips in the cell, one in the deployment
+    (lambda config, mix, spec: config["deployment"].update(chips=1),
+     lambda root: rules.a_cell_finds_its_files_by_name(root, next_cell.CELL)),
+    # the batch the metrics divide by is not the batch the flags pass
+    (lambda config, mix, spec: config.update(device_batch=2048),
+     lambda root: rules.a_cell_finds_its_files_by_name(root, next_cell.CELL)),
+    # a per-layer metric that lists a cell which does not report what it moves
+    (lambda config, mix, spec: spec["per_layer"][-1]["workloads"].append(spec["workloads"][0]["name"]),
+     rules.the_file_has_the_contracts_keys_and_forms),
+    # three cells of five on four chips
+    (lambda config, mix, spec: [cell.update(chips=4) for cell in spec["workloads"][:2]],
+     rules.the_file_has_the_contracts_keys_and_forms),
+], ids=["tuning_flag", "flag_against_deployment", "shards_without_their_flag", "mix_against_deployment",
+        "chips_against_deployment", "batch_against_flag", "metric_lists_a_cell_it_cannot_move",
+        "too_many_four_chip_cells"])
+def test_the_rules_refuse_what_they_are_there_to_refuse(tmp_path, breach, rule):
+    root = next_cell.copy_tree(str(tmp_path))
+    next_cell.add_next_cell(root)
+    paths = {"config": os.path.join(root, "benchmark", "configs", next_cell.CONFIG + ".json"),
+             "mix": os.path.join(root, "benchmark", "traffic", next_cell.TRAFFIC + ".json"),
+             "spec": os.path.join(root, "BENCHMARK.json")}
+    parts = {name: run._load(path) for name, path in paths.items()}
+    rule(root)  # holds before the breach
+    breach(parts["config"], parts["mix"], parts["spec"])
+    for name, path in paths.items():
+        with open(path, "w") as fh:
+            json.dump(parts[name], fh)
+    with pytest.raises(AssertionError):
+        rule(root)

@@ -156,8 +156,7 @@ def test_a_configuration_mix_metric_reader_and_cell_are_added_as_new_files_only(
     # the new cell reports its own metric and, with no edit to any list, every
     # metric there is that moves the end-to-end metric it reports
     assert set(result["metrics"]) == names("per_layer", "atlas_n3.conflict50_small", spec)
-    assert len(result["metrics"]) > 1 and all(
-        "workloads" not in m for m in bench()["per_layer"])
+    assert len(result["metrics"]) > 1
     plain = run.run_cell("atlas_n3.conflict50_small", 11, 2.0, False, root=root, platform="cpu",
                          started=time.monotonic())
     assert set(plain["metrics"]) == {"goodput_cmds_s", "setup_s"}

@@ -187,6 +187,11 @@ def test_each_round_stage_metrics_file_says_what_its_entry_says(name):
 
 
 def test_the_new_entries_come_after_those_the_benchmark_had():
+    """Entries are appended: what PR 23 had comes before what PR 24 added,
+    and that before anything later (a later PR adds at the end; retiring
+    an entry, as PR 26 did, moves none of the others)."""
     names = [m["name"] for m in _bench()["per_layer"]]
-    assert names.index("slow_path_share.sat") == 15 < names.index("session_us_per_cmd.open")
-    assert len(names) == 16 + 25 and "gate_wait_ms.sat" not in names
+    first, second = names.index("slow_path_share.sat"), names.index("session_us_per_cmd.open")
+    assert first + 1 == second and names[second:second + 25] == [
+        base + kind for base in NEW for kind in (".open", ".sat") if base + kind != "gate_wait_ms.sat"]
+    assert "gate_wait_ms.sat" not in names
