@@ -1,0 +1,33 @@
+"""The device round's share of the memory roofline, in %: the bytes a
+dispatch must read and write on the fullest device
+(``benchmark/round_bytes.py``, from the configuration's shape and the mesh
+the server reports, times the rounds a dispatch chains: snapshot delta
+``rounds`` / ``device_dispatches``) over the device time of a dispatch
+(``busy_per_round_s`` of the trace reduction: busy time over runs of the
+program that took most device time, a chained dispatch counting as one run)
+times the device's published memory bandwidth (``benchmark/peaks.json``).
+
+An untraced run, a capture without a device program, a server that names no
+TPU and a protocol without a byte count all read as nothing; a TPU that is
+not in the table of peaks is an error."""
+
+from benchmark.round_bytes import round_min_bytes
+from benchmark.trace_reduce import peaks_for
+
+
+def read(ctx):
+    trace = ctx.get("trace") or {}
+    backend = ctx["snapshot_end"].get("backend") or {}
+    delta = ctx["snapshot_delta"]
+    per_dispatch_s = trace.get("busy_per_round_s")
+    if not per_dispatch_s or backend.get("platform") != "tpu":
+        return None
+    if delta.get("device_dispatches", 0) <= 0 or delta.get("rounds", 0) <= 0:
+        return None
+    replica_axis = (backend.get("mesh_shape") or {}).get("replica", 1)
+    per_round = round_min_bytes(ctx["config"], replica_axis)
+    if per_round is None:
+        return None
+    rounds_per_dispatch = delta["rounds"] / delta["device_dispatches"]
+    peak = peaks_for(backend.get("device_kind") or "")["hbm_bytes_per_s"]
+    return float(100.0 * per_round * rounds_per_dispatch / (per_dispatch_s * peak))

@@ -144,6 +144,7 @@ def _backend_banner(backend: dict) -> str:
     """The banner clause that names what serves (a parent script reads
     it to tell a chip run from a CPU run)."""
     mesh = backend.get("mesh_shape")
+    shards = backend.get("shards_on_device")
     return (
         f" [platform={backend['platform']} "
         f"device_kind={backend['device_kind']!r} "
@@ -151,6 +152,14 @@ def _backend_banner(backend: dict) -> str:
         + (
             " mesh=" + "x".join(f"{a}:{n}" for a, n in mesh.items())
             if mesh
+            else ""
+        )
+        # the shards on each device, devices apart by "|": 0|1|2|3 is
+        # one shard a device
+        + (
+            " shards_on_device="
+            + "|".join(",".join(map(str, held)) for held in shards)
+            if shards
             else ""
         )
         + f" compile_cache={backend['compile_cache_dir']}]"

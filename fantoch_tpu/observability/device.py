@@ -184,6 +184,9 @@ ROUND_STAGES = (
     # on the loop beside the rounds: the telemetry tick's snapshot write,
     # the probe's late wake-ups, the interpreter's full collections
     "snapshot", "loop_stall", "gc",
+    # before the first round: a chain program compiled or loaded
+    # (``precompile_chains``; the span's round is the chain length)
+    "precompile",
 )
 SPAN_RING = 65536  # closed spans kept for the dump (~40 min of open-loop rounds)
 

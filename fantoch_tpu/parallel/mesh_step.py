@@ -141,7 +141,9 @@ def shard_of_row(row: int, num_replicas_total: int, shard_count: int) -> int:
 
 
 def make_mesh(
-    n_devices: int | None = None, num_replicas: int | None = None
+    n_devices: int | None = None,
+    num_replicas: int | None = None,
+    shard_count: int = 1,
 ) -> Mesh:
     """Factor the device list into a (replica, batch) mesh.
 
@@ -149,24 +151,66 @@ def make_mesh(
     replicas; batches are wide).  When ``num_replicas`` is given, the
     replica axis must divide it (each device slice holds a whole number of
     replica blocks — init_state's sharding contract).
+
+    ``shard_count`` > 1 (partial replication; ``num_replicas`` then counts
+    every shard's rows): where the devices can hold whole shards, the
+    replica axis is the largest factor of the device count that divides
+    the shard count, and may then exceed the batch axis.  Rows are
+    shard-major (:func:`shard_of_row`), so each slice along ``replica``
+    holds ``shard_count // replica`` whole shards: a shard's quorum
+    ``pmax`` / ``psum`` reduce over rows of one device, and only the
+    cross-shard max and the stability gather cross devices.  4 devices x
+    4 shards gives ``replica:4 x batch:1``, one shard a device.  One
+    device, one shard, and a shard count no factor of the device count
+    divides get the mesh of the rule above.
     """
     devices = jax.devices()
     if n_devices is not None:
         devices = devices[:n_devices]
     n = len(devices)
-    replica = 1
-    for cand in range(min(n, 8), 0, -1):
-        if (
-            n % cand == 0
-            and cand <= n // cand
-            and (num_replicas is None or num_replicas % cand == 0)
-        ):
-            replica = cand
-            break
+    replica = max(
+        (
+            cand
+            for cand in range(2, min(n, shard_count) + 1)
+            if n % cand == 0 and shard_count % cand == 0
+        ),
+        default=0,
+    )
+    if not replica:
+        replica = 1
+        for cand in range(min(n, 8), 0, -1):
+            if (
+                n % cand == 0
+                and cand <= n // cand
+                and (num_replicas is None or num_replicas % cand == 0)
+            ):
+                replica = cand
+                break
     import numpy as np
 
     dev_array = np.array(devices).reshape(replica, n // replica)
     return Mesh(dev_array, (REPLICA_AXIS, BATCH_AXIS))
+
+
+def shards_on_devices(
+    mesh: Mesh, num_replicas_total: int, shard_count: int
+) -> list[list[int]]:
+    """The shards whose replica rows each device of ``mesh`` holds, in the
+    mesh's device order (row-major over replica x batch): what the
+    "serving clients" banner and the snapshot's ``backend`` say of the
+    layout.  One shard a device reads ``[[0], [1], [2], [3]]``; the
+    ``replica:2 x batch:2`` mesh of four shards ``[[0, 1], [0, 1],
+    [2, 3], [2, 3]]`` (the batch axis repeats a replica slice)."""
+    slices, batch = mesh.shape[REPLICA_AXIS], mesh.shape[BATCH_AXIS]
+    rows = num_replicas_total // slices
+    out = []
+    for at in range(slices):
+        held = sorted({
+            shard_of_row(row, num_replicas_total, shard_count)
+            for row in range(at * rows, (at + 1) * rows)
+        })
+        out.extend([held] * batch)
+    return out
 
 
 def init_state(
@@ -1033,8 +1077,11 @@ def jit_newt_multi_step(
     live_replicas: int | None = None,
     shard_count: int = 1,
 ):
-    """jit-compiled multi-round Newt chain with donated state (one
-    compile per S shape; S rides the input's leading axis)."""
+    """The multi-round Newt chain with donated state, jitted: S rides the
+    inputs' leading axis, so each chain length is a program of its own.
+    A server lowers and compiles (or loads) the lengths its tuner may
+    pick on the real shapes before it serves
+    (run/device_runner.py ``NewtDeviceDriver.precompile_chains``)."""
     import functools
 
     return jax.jit(

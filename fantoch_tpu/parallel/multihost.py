@@ -163,7 +163,7 @@ def make_multihost_mesh(
     devices = jax.devices()
     groups = group_by_process(devices)
     if len(groups) == 1:
-        return make_mesh(num_replicas=num_replicas)
+        return make_mesh(num_replicas=num_replicas, shard_count=shard_count)
     hosts = len(groups)
     if num_replicas is not None:
         if num_replicas % hosts != 0:

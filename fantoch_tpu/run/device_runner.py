@@ -49,7 +49,7 @@ import gc
 import os
 from collections import defaultdict, deque
 from time import monotonic, monotonic_ns
-from typing import Any, Deque, Dict, List, NamedTuple, Optional, Tuple
+from typing import Any, Deque, Dict, List, NamedTuple, Optional, Sequence, Tuple
 
 import numpy as np
 
@@ -238,7 +238,9 @@ class _DriverCore(PipelineCore):
         self._mesh = (
             mesh
             if mesh is not None
-            else mesh_step.make_mesh(num_replicas=total_rows)
+            else mesh_step.make_mesh(
+                num_replicas=total_rows, shard_count=shard_count
+            )
         )
         self._state = init_state_fn(
             self._mesh,
@@ -265,13 +267,18 @@ class _DriverCore(PipelineCore):
         self._assemble_rows(batch, key, src, seq)
         return key, src, seq
 
+    def _columns_to_device(self, columns, S: int = 1):
+        """The assembled columns of a dispatch of ``S`` rounds, handed
+        to jax."""
+        import jax.numpy as jnp
+
+        return tuple(jnp.asarray(column) for column in columns)
+
     def _enqueue(self, columns):
         """Submit one device round over the assembled columns; returns
         the round token for ``drain``."""
-        import jax.numpy as jnp
-
         self._state, out = self._step(
-            self._state, *(jnp.asarray(column) for column in columns)
+            self._state, *self._columns_to_device(columns)
         )
         self.rounds += 1
         return out
@@ -764,13 +771,17 @@ class NewtDeviceDriver(_DriverCore):
             self._mesh, f=f, tiny_quorums=tiny_quorums,
             live_replicas=live_replicas, shard_count=shard_count,
         )
-        # chained multi-round programs (step_chained), compiled per chain
-        # length on first use
+        # chained multi-round programs (step_chained), one per chain
+        # length: a server makes every length its tuner may pick ready
+        # before it serves (``precompile_chains``); a driver used without
+        # that start-up jits a length when it first meets it
         self._step_kwargs = dict(
             f=f, tiny_quorums=tiny_quorums,
             live_replicas=live_replicas, shard_count=shard_count,
         )
         self._multi_step: Dict[int, object] = {}
+        # where a precompiled length's columns live on the mesh
+        self._column_shardings: Dict[int, object] = {}
         # no host identity mirror: the step outputs carry the working
         # rows' (src, seq) columns (NewtStepOutput.work_src/work_seq)
         self._pend_cap = pending_capacity
@@ -800,9 +811,15 @@ class NewtDeviceDriver(_DriverCore):
             "carried committed clock at/below the stable floor"
         )
         pend_clock = np.where(live, pend_clock - floor, -1)
+        # (the shifted tables go back where the old ones lived: a
+        # precompiled program takes its state at that sharding only)
         self._state = st._replace(
-            key_clock=shift_table(st.key_clock, floor),
-            vote_frontier=shift_table(st.vote_frontier, floor),
+            key_clock=jax.device_put(
+                shift_table(st.key_clock, floor), st.key_clock.sharding
+            ),
+            vote_frontier=jax.device_put(
+                shift_table(st.vote_frontier, floor), st.vote_frontier.sharding
+            ),
             pend_clock=jax.device_put(
                 jnp.array(pend_clock.astype(np.int32)),
                 st.pend_clock.sharding,
@@ -851,8 +868,9 @@ class NewtDeviceDriver(_DriverCore):
 
     def _dispatch_chain(self, batches: List[List[Tuple[Dot, Command]]]):
         """Assemble + dispatch S rounds as ONE device program
-        (parallel/mesh_step.jit_newt_multi_step, compiled per chain
-        length on first use); returns the chain token for ``drain``.
+        (parallel/mesh_step.jit_newt_multi_step; a server has loaded the
+        program of every length it dispatches before its first client,
+        ``precompile_chains``); returns the chain token for ``drain``.
         The caller checked ``_chain_windows_blocked`` first."""
         return self._dispatch_halves(
             self._assemble_chain, self._enqueue_chain, batches
@@ -874,9 +892,65 @@ class NewtDeviceDriver(_DriverCore):
             self._assemble_rows(batch, keys[r], srcs[r], seqs[r])
         return keys, srcs, seqs
 
-    def _enqueue_chain(self, columns):
-        import jax.numpy as jnp
+    def precompile_chains(self, lengths: Sequence[int]) -> List[int]:
+        """Make the program of every chain length in ``lengths`` ready
+        before serving: length 1 is the round itself
+        (``jit_newt_step``), a longer one the ``lax.scan`` of that many
+        rounds, each lowered on the real state's and columns' shapes and
+        compiled, or loaded, through the persistent compile cache.  The
+        dispatch of a ready length then runs the loaded executable and
+        compiles nothing.  One ``precompile`` span a program.  Returns
+        the lengths now ready, in order; it stops at the first that
+        cannot be made ready (the longer ones need more of whatever it
+        lacked), and the caller keeps its tuner off the rest."""
+        import jax
 
+        from fantoch_tpu.parallel import mesh_step
+
+        b, kw = self.batch_size, self.key_width
+        ready: List[int] = []
+        for S in lengths:
+            if S not in self._column_shardings:
+                lead = () if S == 1 else (S,)
+                columns = tuple(
+                    jax.ShapeDtypeStruct(lead + shape, np.int32)
+                    for shape in ((b, kw), (b,), (b,))
+                )
+                jitted = self._step if S == 1 else mesh_step.jit_newt_multi_step(
+                    self._mesh, **self._step_kwargs
+                )
+                try:
+                    with self.stages.span("precompile", S):
+                        program = jitted.lower(self._state, *columns).compile()
+                except Exception as exc:  # the compiler's own errors are many
+                    logger.warning(
+                        "chain length %d cannot be made ready (%r): serving "
+                        "with chains of at most %d", S, exc, max(ready, default=1),
+                    )
+                    break
+                self._column_shardings[S] = tuple(program.input_shardings[0][1:])
+                if S == 1:
+                    self._step = program
+                else:
+                    self._multi_step[S] = program
+            ready.append(S)
+        return ready
+
+    @property
+    def precompiled_programs(self) -> int:
+        return len(self._column_shardings)
+
+    def _columns_to_device(self, columns, S: int = 1):
+        """Where the length's program was precompiled, straight to where
+        it takes its columns."""
+        import jax
+
+        shardings = self._column_shardings.get(S)
+        if shardings is None:
+            return super()._columns_to_device(columns, S)
+        return jax.device_put(tuple(columns), shardings)
+
+    def _enqueue_chain(self, columns):
         from fantoch_tpu.parallel import mesh_step
 
         S = len(columns[0])
@@ -887,7 +961,7 @@ class NewtDeviceDriver(_DriverCore):
             )
             self._multi_step[S] = multi
         self._state, outs = multi(
-            self._state, *(jnp.asarray(column) for column in columns)
+            self._state, *self._columns_to_device(columns, S)
         )
         self.rounds += S
         return _ChainToken(outs, S)
@@ -1319,7 +1393,7 @@ class _DeviceClientSession:
         tracer = runtime.tracer
         tracing = tracer.enabled
         frames: List[bytes] = []
-        answered = 0
+        answered = completed = multi_shard = 0
         for result in results:
             rifl = result.rifl
             shards = key_shard.get(rifl)
@@ -1342,11 +1416,17 @@ class _DeviceClientSession:
             else:
                 del key_shard[rifl], shards_left[rifl], rifl_sessions[rifl]
                 answered += 1
+                completed += 1
+                if len(shards) > 1 and len(set(shards.values())) > 1:
+                    multi_shard += 1
         if frames:
             data = b"".join(frames)
             self.rw.write_frames(data)
             runtime._reply_writes += 1
             runtime._reply_bytes += len(data)
+            runtime._shard_replies += len(frames)
+            runtime._commands_completed += completed
+            runtime._multi_shard_completed += multi_shard
             self._flush_needed.set()
         return answered
 
@@ -1724,6 +1804,9 @@ class DeviceRuntime:
         self._flushes = 0
         self._reply_writes = 0  # writes of a round's frames to a connection
         self._reply_bytes = 0
+        self._shard_replies = 0  # CommandResult frames: one per touched shard
+        self._commands_completed = 0  # a command's last shard replied
+        self._multi_shard_completed = 0  # ... of a command over several shards
         self._admit_ns = 0  # a Submit received -> runtime.submit returned
         self._queue_wait_ms = 0.0  # sum over released commands, ring time
         self._queue_released = 0
@@ -1732,6 +1815,13 @@ class DeviceRuntime:
         self._loop_stall_ms = 0.0
         self._loop_stalls = 0
         self._gc_started = 0  # monotonic_ns of the full collection under way
+        from fantoch_tpu.parallel.mesh_step import shards_on_devices
+
+        self._shards_on_device = shards_on_devices(
+            self.driver._mesh,
+            config.n * self.driver.shard_count,
+            self.driver.shard_count,
+        )
         self._tallies: Dict[str, int] = {}
         self._publish_tallies()
         self._work = asyncio.Event()
@@ -1835,6 +1925,11 @@ class DeviceRuntime:
         # restarted/rebuilt runners reload their programs from disk
         # instead of re-paying the compile wall
         ensure_compile_cache()
+        # every chain length the tuner may pick, compiled or loaded here,
+        # before a client can connect: the serving loop compiles nothing
+        self._chain_tuner.limit_to(
+            self.driver.precompile_chains(self._chain_tuner.ladder())
+        )
         self._arm_device_faults()
         server = await asyncio.start_server(self._on_client, *self.client_addr)
         self._servers = [server]
@@ -1897,6 +1992,12 @@ class DeviceRuntime:
             "reply_flushes": self._flushes,
             "reply_writes": self._reply_writes,
             "reply_bytes": self._reply_bytes,
+            # a command over several shards answers once per shard:
+            # frames written, commands whose last shard replied, and
+            # those of them that touched more than one shard
+            "shard_replies": self._shard_replies,
+            "commands_completed": self._commands_completed,
+            "multi_shard_completed": self._multi_shard_completed,
             # the event loop's lateness: worst wake-up, and the sum and
             # count of wake-ups later than LOOP_STALL_MS
             "loop_lag_hwm_ms": round(self._loop_lag_hwm_ms, 3),
@@ -1904,6 +2005,7 @@ class DeviceRuntime:
             "loop_stalls": self._loop_stalls,
             # adaptive ingest batcher tallies (run/ingest.py)
             **self._batcher.counters(),
+            "precompiled_programs": d.precompiled_programs,
             "jax_recompiles": recompile_count(),
             "jax_compile_ms": compile_ms(),
             "jax_cache_hits": cache_hit_count(),
@@ -1922,6 +2024,9 @@ class DeviceRuntime:
             "mesh_shape": {
                 axis: int(size) for axis, size in self.driver._mesh.shape.items()
             },
+            # the shards whose replica rows each device holds, in the
+            # mesh's device order
+            "shards_on_device": self._shards_on_device,
         }
 
     def _write_metrics_snapshot(self) -> None:
@@ -1955,7 +2060,7 @@ class DeviceRuntime:
         "in_flight", "stable_watermark", "queued", "queued_hwm",
         "queue_capacity", "device_idle_frac", "device_pipeline_depth",
         "dispatch_fill_frac", "serving_chain_len", "ingest_target",
-        "ingest_rate_per_s", "loop_lag_hwm_ms",
+        "ingest_rate_per_s", "loop_lag_hwm_ms", "precompiled_programs",
     })
 
     def telemetry_sample(self):
@@ -2181,11 +2286,12 @@ class DeviceRuntime:
             batches.append(batch)
         if len(batches) > 1:
             # canonicalize the dispatched chain length to the pow2
-            # ladder: the chained step programs compile per chain
-            # length, so dispatching whatever 1..S rounds the queue
-            # happened to fill would mint a compiled program per
-            # value — truncate to the pow2 floor and requeue the
-            # remainder (it leads the next chain)
+            # ladder: each chain length is a program of its own, and
+            # the ladder's are the ones start-up made ready, so
+            # dispatching whatever 1..S rounds the queue happened to
+            # fill would compile inside the serving loop — truncate to
+            # the pow2 floor and requeue the remainder (it leads the
+            # next chain)
             keep = 1
             while keep * 2 <= len(batches):
                 keep *= 2

@@ -301,10 +301,13 @@ class ChainAutoTuner:
     Once overhead falls under ``shrink_frac`` the chain HALVES
     (hysteresis between the two bands keeps S stable).  S moves on a
     strict pow2 schedule — double up, halve down, ceiling at the pow2
-    floor of ``chain_max`` — because the chained step programs compile
-    per chain length: a decrement schedule would bake every value in
+    floor of ``chain_max`` — because each chain length is a program of
+    its own: a decrement schedule would bake every value in
     ``[1, chain_max]`` into a distinct compiled signature (the compile
-    wall), while pow2 bounds the set at O(log chain_max) programs.
+    wall), while pow2 bounds the set at O(log chain_max) programs, few
+    enough that a server loads every one of them before it serves
+    (:meth:`ladder`; :meth:`limit_to` keeps the tuner off a length that
+    could not be made ready).
     Observations under ``min_dispatches`` new dispatches are deferred so
     one jittery round cannot thrash S.
     """
@@ -334,6 +337,25 @@ class ChainAutoTuner:
         self.min_dispatches = int(min_dispatches)
         self.adjustments = 0
         self._last: Optional[Tuple[float, float, float, float]] = None
+
+    def ladder(self) -> List[int]:
+        """Every chain length the tuner may emit: the powers of two up
+        to ``chain_max``."""
+        lengths = [1]
+        while lengths[-1] * 2 <= self.chain_max:
+            lengths.append(lengths[-1] * 2)
+        return lengths
+
+    def limit_to(self, ready: Sequence[int]) -> None:
+        """Keep the tuner on the lengths of ``ready``: the ceiling drops
+        to the top of the ladder's unbroken run of ready lengths."""
+        top = 1
+        for length in self.ladder()[1:]:
+            if length not in ready:
+                break
+            top = length
+        self.chain_max = top
+        self.chain = min(self.chain, top)
 
     def observe(
         self,

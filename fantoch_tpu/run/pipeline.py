@@ -304,6 +304,18 @@ class PipelineCore:
             results.extend(self.step(batch))
         return results
 
+    def precompile_chains(self, lengths) -> List[int]:
+        """The chain lengths of ``lengths`` whose programs are ready
+        before serving.  Here a chain is S plain rounds and has no
+        program of its own, so every length is; a driver with a fused
+        program per length (NewtDeviceDriver) compiles or loads them."""
+        return list(lengths)
+
+    @property
+    def precompiled_programs(self) -> int:
+        """Chain programs loaded ahead of serving (gauge)."""
+        return 0
+
     def step_chained_pipelined(self, batches) -> List[Any]:
         """S rounds per call composed with the depth-K pipeline.  Base
         implementation: S consecutive ``step_pipelined`` rounds (the

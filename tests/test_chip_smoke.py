@@ -34,9 +34,10 @@ def test_leg_served_tiny(tmp_path):
     assert leg["device_dispatches"] > 0
     assert leg["recompiles_warm_burst"] == 0
     assert set(leg["backend"]) == {
-        "platform", "device_kind", "device_count", "mesh_shape"
+        "platform", "device_kind", "device_count", "mesh_shape", "shards_on_device"
     }
     assert "platform=cpu" in leg["banner"] and "mesh=" in leg["banner"]
+    assert " shards_on_device=0" in leg["banner"]
 
 
 def test_leg_served_reports_a_dead_server(tmp_path):
