@@ -7,10 +7,10 @@ results aggregate per-key op results until all keys have reported.
 
 from __future__ import annotations
 
-from typing import Dict, Iterator, Tuple, TYPE_CHECKING
+from typing import Dict, Iterator, List, Optional, Tuple, TYPE_CHECKING
 
 from fantoch_tpu.core.ids import Rifl, ShardId
-from fantoch_tpu.core.kvs import KVOp, KVOpResult, Key, KVStore
+from fantoch_tpu.core.kvs import KINDS, KVOp, KVOpResult, Key, KVStore
 
 if TYPE_CHECKING:
     from fantoch_tpu.executor.base import ExecutorResult
@@ -106,6 +106,16 @@ class Command:
             for key in ops:
                 yield shard_id, key
 
+    def single_key(self) -> Optional[Tuple[ShardId, Key]]:
+        """``(shard, key)`` of a command of one key on one shard (the
+        dominant shape: its callers skip their general scans), else
+        None."""
+        if self._total_key_count == 1 and len(self._shard_to_ops) == 1:
+            for shard_id, ops in self._shard_to_ops.items():
+                for key in ops:
+                    return shard_id, key
+        return None
+
     def conflicts(self, other: "Command") -> bool:
         """Key-intersection conflict check (fantoch/src/command.rs:141-147)."""
         for shard_id, ops in self._shard_to_ops.items():
@@ -144,9 +154,77 @@ class Command:
     def __hash__(self) -> int:
         return hash(self._rifl)
 
+    def __reduce__(self):
+        # plain values on the wire: the rifl's two numbers, then the
+        # command's shape, kinds as their index in ``KINDS``.  One
+        # shard, one key, one op (what the command itself shows) goes
+        # flat; every other shape as nested tuples, shard -> key -> ops
+        # in the dicts' order.
+        rifl = self._rifl
+        shard_to_ops = self._shard_to_ops
+        single = self.single_key()
+        if single is not None:
+            shard_id, key = single
+            key_ops = shard_to_ops[shard_id][key]
+            if len(key_ops) == 1:
+                op = key_ops[0]
+                return _restore_command, (
+                    rifl[0], rifl[1], shard_id, key, _KIND_CODE[op.kind], op.value,
+                )
+        return _restore_command, (
+            rifl[0],
+            rifl[1],
+            tuple(
+                (
+                    shard_id,
+                    tuple(
+                        (key, tuple((_KIND_CODE[op.kind], op.value) for op in key_ops))
+                        for key, key_ops in ops.items()
+                    ),
+                )
+                for shard_id, ops in shard_to_ops.items()
+            ),
+        )
+
     def __repr__(self) -> str:
         keys = {s: sorted(ops) for s, ops in self._shard_to_ops.items()}
         return f"Command({self._rifl}, {keys})"
+
+
+_KIND_CODE = {kind: code for code, kind in enumerate(KINDS)}
+
+
+def _restore_command(source: int, sequence: int, shard, key=None, kind=0, value=None) -> Command:
+    """Unpickle a :class:`Command` from the values its ``__reduce__``
+    carries: ``shard, key, kind, value`` of the flat form, or in
+    ``shard``'s place the general form's ``((shard, ((key, ((kind,
+    value), ...)), ...)), ...)``."""
+    rifl = Rifl(source, sequence)
+    if key is not None:
+        return Command.from_single(rifl, shard, key, KVOp(KINDS[kind], value))
+    # the constructor's scan, folded into the pass that builds the ops
+    shard_to_ops: Dict[ShardId, Dict[Key, Tuple[KVOp, ...]]] = {}
+    reads = writes = total = 0
+    for shard_id, keys in shard:
+        ops = shard_to_ops[shard_id] = {}
+        for k, key_ops in keys:
+            ops[k] = tuple([KVOp(KINDS[code], v) for code, v in key_ops])
+            for code, _ in key_ops:
+                if code:
+                    writes += 1
+                else:
+                    reads += 1
+        total += len(ops)
+    assert shard_to_ops, "commands must have at least one shard"
+    assert reads == 0 or writes == 0, (
+        "non-read-only commands cannot contain Get operations"
+    )
+    cmd = Command.__new__(Command)
+    cmd._rifl = rifl
+    cmd._shard_to_ops = shard_to_ops
+    cmd._read_only = writes == 0
+    cmd._total_key_count = total
+    return cmd
 
 
 class CommandResult:

@@ -29,6 +29,11 @@ class KVOpKind(Enum):
     DELETE = "Delete"
 
 
+# a kind's code on the wire (core/command.py, Command.__reduce__): its
+# index here, so that no frame carries an Enum by class path
+KINDS = (KVOpKind.GET, KVOpKind.PUT, KVOpKind.DELETE)
+
+
 @dataclass(frozen=True)
 class KVOp:
     """A single-key operation (fantoch/src/kvs.rs:12-16)."""

@@ -113,28 +113,37 @@ def _buckets(
     command."""
     if cache is not None and len(cache) > _BUCKET_CACHE_MAX:
         cache.clear()
+    single = cmd.single_key()
+    if single is not None:
+        # one key: nothing to dedup or sort
+        sid, k = single
+        if shard_count == 1 and sid != shard_id:
+            return []
+        return [_bucket(sid, k, key_buckets, shard_count, cache)]
     if shard_count == 1:
-        if cache is None:
-            return sorted({key_hash(k) % key_buckets for k in cmd.keys(shard_id)})
-        bs = set()
-        for k in cmd.keys(shard_id):
-            b = cache.get(k)
-            if b is None:
-                cache[k] = b = key_hash(k) % key_buckets
-            bs.add(b)
-        return sorted(bs)
-    per_shard = key_buckets // shard_count
-    bs = set()
-    for sid in cmd.shards():
-        for k in cmd.keys(sid):
-            ck = (sid, k)
-            b = None if cache is None else cache.get(ck)
-            if b is None:
-                b = sid + shard_count * (key_hash(k) % per_shard)
-                if cache is not None:
-                    cache[ck] = b
-            bs.add(b)
-    return sorted(bs)
+        return sorted(
+            {_bucket(shard_id, k, key_buckets, 1, cache) for k in cmd.keys(shard_id)}
+        )
+    return sorted(
+        {_bucket(sid, k, key_buckets, shard_count, cache) for sid, k in cmd.all_keys()}
+    )
+
+
+def _bucket(
+    sid: ShardId, k: str, key_buckets: int, shard_count: int, cache: Optional[Dict]
+) -> int:
+    """One key's bucket, through the memo where there is one (keyed by
+    the key alone on one shard, by ``(shard, key)`` on several)."""
+    ck = k if shard_count == 1 else (sid, k)
+    b = None if cache is None else cache.get(ck)
+    if b is None:
+        if shard_count == 1:
+            b = key_hash(k) % key_buckets
+        else:
+            b = sid + shard_count * (key_hash(k) % (key_buckets // shard_count))
+        if cache is not None:
+            cache[ck] = b
+    return b
 
 
 def _bucket_row(
@@ -1370,6 +1379,13 @@ class _DeviceClientSession:
 
     def track(self, cmd: Command) -> None:
         """Register a submitted command for result aggregation."""
+        single = cmd.single_key()
+        if single is not None:
+            sid, key = single
+            self.pending_by_shard[sid].wait_for(cmd)
+            self._key_shard[cmd.rifl] = {key: sid}
+            self._shards_left[cmd.rifl] = 1
+            return
         for sid in cmd.shards():
             self.pending_by_shard[sid].wait_for(cmd)
         self._key_shard[cmd.rifl] = {
@@ -1512,6 +1528,79 @@ class _DeviceClientSession:
             )
         return None
 
+    def _admit(self, msgs: List[Any]) -> None:
+        """One pass over the messages of a socket read, in frame order.
+        A ``Submit`` is validated, checked against the ring's bound
+        (shed with a typed Overloaded BEFORE tracking, so the retry
+        re-runs the full path with no leftover aggregation state),
+        tracked and given its dot; the read's admitted commands then
+        enter the ring together.  Any other message is taken where it
+        stands: what was admitted before it is pushed whatever it
+        raises."""
+        t0 = monotonic_ns()
+        # the read's one arrival time: it rides beside each of its
+        # commands in the ring
+        now_ms = t0 / 1e6
+        runtime = self.runtime
+        room = runtime.room()
+        tracer = runtime.tracer
+        tracing = tracer.enabled
+        rifl_sessions = runtime.rifl_sessions
+        next_dot = runtime.dot_gen.next_id
+        validate, track = self._validate, self.track
+        admitted: List[Tuple[Dot, Command, float]] = []
+        try:
+            for msg in msgs:
+                if not isinstance(msg, Submit):
+                    self._not_a_submit(msg)
+                    continue
+                cmd = msg.cmd
+                if tracing:
+                    # ingress edge: client->server network vs queue
+                    # split in the critpath report
+                    tracer.edge(
+                        "r", "Submit", 0, runtime.process_id, 0, rifl=cmd.rifl,
+                    )
+                why = validate(cmd)
+                if why is not None:
+                    self._reject(cmd, why)
+                    continue
+                if room is not None and len(admitted) >= room:
+                    # the ring fills inside this read: push what the
+                    # read has admitted, so that the shed's reply reads
+                    # the ring at its bound
+                    if admitted:
+                        runtime.submit_all(admitted, now_ms)
+                        admitted = []
+                        room = 0
+                    self._shed(cmd)
+                    continue
+                track(cmd)
+                rifl_sessions[cmd.rifl] = self
+                dot = next_dot()
+                if tracing:
+                    tracer.span(
+                        "payload", cmd.rifl, dot=dot, pid=runtime.process_id,
+                    )
+                admitted.append((dot, cmd, now_ms))
+        finally:
+            if admitted:
+                runtime.submit_all(admitted, now_ms)
+            runtime._admit_ns += monotonic_ns() - t0
+
+    def _not_a_submit(self, msg: Any) -> None:
+        if not isinstance(msg, Register):
+            raise ProtocolError(f"unexpected message {msg!r}")
+        # sharded: the unified mesh executes every shard's portion
+        # behind the submit session; per-shard registration has nothing
+        # to set up
+        if self.runtime.driver.shard_count == 1:
+            raise ProtocolError(
+                "device-step serving is single-shard; Register "
+                "(multi-shard partial registration) has no "
+                "meaning here"
+            )
+
     async def run(self) -> None:
         try:
             hi = await self.rw.recv()
@@ -1522,56 +1611,13 @@ class _DeviceClientSession:
             self.client_ids = hi.client_ids
             await self.rw.send(ClientHiAck())
             flusher = self.runtime.spawn(self._flush_loop(), fatal=False)
-            sharded = self.runtime.driver.shard_count > 1
             try:
                 while True:
-                    msg = await self.rw.recv()
-                    if msg is None:
+                    # what one socket read brought, every whole frame of it
+                    msgs = await self.rw.recv_all()
+                    if msgs is None:
                         break
-                    if isinstance(msg, Register):
-                        if sharded:
-                            # the unified mesh executes every shard's
-                            # portion behind the submit session; per-shard
-                            # registration has nothing to set up
-                            continue
-                        raise ProtocolError(
-                            "device-step serving is single-shard; Register "
-                            "(multi-shard partial registration) has no "
-                            "meaning here"
-                        )
-                    if not isinstance(msg, Submit):
-                        raise ProtocolError(f"unexpected message {msg!r}")
-                    admit_t0 = monotonic_ns()
-                    cmd = msg.cmd
-                    tracer = self.runtime.tracer
-                    if tracer.enabled:
-                        # ingress edge: client->server network vs queue
-                        # split in the critpath report
-                        tracer.edge(
-                            "r", "Submit", 0, self.runtime.process_id, 0,
-                            rifl=cmd.rifl,
-                        )
-                    why = self._validate(cmd)
-                    if why is not None:
-                        self._reject(cmd, why)
-                        continue
-                    if not self.runtime.has_capacity():
-                        # admission control: the submit ring is at its
-                        # bound — shed with a typed Overloaded + hint
-                        # BEFORE tracking, so the retry re-runs the
-                        # full path with no leftover aggregation state
-                        self._shed(cmd)
-                        continue
-                    self.track(cmd)
-                    self.runtime.rifl_sessions[cmd.rifl] = self
-                    dot = self.runtime.dot_gen.next_id()
-                    if tracer.enabled:
-                        tracer.span(
-                            "payload", cmd.rifl, dot=dot,
-                            pid=self.runtime.process_id,
-                        )
-                    self.runtime.submit(dot, cmd)
-                    self.runtime._admit_ns += monotonic_ns() - admit_t0
+                    self._admit(msgs)
             finally:
                 flusher.cancel()
         finally:
@@ -1799,7 +1845,9 @@ class DeviceRuntime:
         # stages and the step's land in one ring on one clock
         self.stages = self.driver.stages
         # the per-command boundaries: two clock reads each, no span
-        self._decode_tally = [0, 0]  # [ns, frames] of pickle.loads, shared with every Rw
+        # [ns, frames, reads] of turning socket reads into messages,
+        # shared with every Rw
+        self._decode_tally = [0, 0, 0]
         self._flush_ns = 0  # awaits of rw.flush() in the sessions
         self._flushes = 0
         self._reply_writes = 0  # writes of a round's frames to a connection
@@ -1807,7 +1855,7 @@ class DeviceRuntime:
         self._shard_replies = 0  # CommandResult frames: one per touched shard
         self._commands_completed = 0  # a command's last shard replied
         self._multi_shard_completed = 0  # ... of a command over several shards
-        self._admit_ns = 0  # a Submit received -> runtime.submit returned
+        self._admit_ns = 0  # a read's messages decoded -> its commands pushed
         self._queue_wait_ms = 0.0  # sum over released commands, ring time
         self._queue_released = 0
         # the loop's own lateness (_lag_task)
@@ -1985,6 +2033,7 @@ class DeviceRuntime:
             # the per-command boundaries around the rounds
             "session_decode_ms": round(self._decode_tally[0] / 1e6, 3),
             "session_decoded": self._decode_tally[1],
+            "session_reads": self._decode_tally[2],
             "session_admit_ms": round(self._admit_ns / 1e6, 3),
             "queue_wait_ms": round(self._queue_wait_ms, 3),
             "queue_released": self._queue_released,
@@ -2137,13 +2186,14 @@ class DeviceRuntime:
         )
         self.spawn(session.run(), fatal=False)
 
-    def has_capacity(self) -> bool:
-        """Admission check for sessions: False once the submit ring sits
-        at its bound (the session sheds with a typed Overloaded reply
-        instead of queueing).  Check-then-submit is race-free: sessions
+    def room(self) -> Optional[int]:
+        """Admission check for sessions: how many more commands the
+        submit ring takes before it sits at its bound (None: unbounded);
+        past that the session sheds with a typed Overloaded reply
+        instead of queueing.  Count-then-submit is race-free: sessions
         and the driver share one cooperative loop."""
         ring = self._submit_queue
-        return ring.capacity is None or len(ring) < ring.capacity
+        return None if ring.capacity is None else ring.capacity - len(ring)
 
     def retry_after_ms(self) -> int:
         """The shed reply's retry-after hint, scaled by how many rounds
@@ -2155,13 +2205,20 @@ class DeviceRuntime:
         return base * max(1, len(ring) // max(1, self.driver.batch_size))
 
     def submit(self, dot: Dot, cmd: Command) -> None:
-        self.submitted += 1
         now_ms = monotonic() * 1000.0
-        # the arrival time rides beside the item: the ring wait of each
-        # command is read off it at release (queue_wait_ms)
-        if not self._submit_queue.try_push((dot, cmd, now_ms)):
-            # unreachable via sessions (has_capacity() is checked on the
-            # same cooperative tick, with no await between check and
+        self.submit_all([(dot, cmd, now_ms)], now_ms)
+
+    def submit_all(
+        self, admitted: List[Tuple[Dot, Command, float]], now_ms: float
+    ) -> None:
+        """The commands a session admitted from one socket read, in
+        their order, each with the read's arrival time ``now_ms`` beside
+        it (the ring wait of a command is read off it at release,
+        ``queue_wait_ms``): the pushes, one note to the ingest batcher
+        and one wake-up of the driver task for all of them."""
+        if not self._submit_queue.try_extend(admitted):
+            # unreachable via sessions (room() is counted on the same
+            # cooperative tick, with no await between count and
             # submit) — a real exception, not an assert, so a future
             # caller that skips the check fails LOUDLY (the driver task
             # tears the runtime down) instead of silently dropping the
@@ -2173,7 +2230,8 @@ class DeviceRuntime:
                 self._submit_queue.capacity or 0,
                 self.retry_after_ms(),
             )
-        self._batcher.note_arrivals(now_ms, 1)
+        self.submitted += len(admitted)
+        self._batcher.note_arrivals(now_ms, len(admitted))
         self._work.set()
 
     def drop_session(self, session: "_DeviceClientSession") -> None:

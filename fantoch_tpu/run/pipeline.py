@@ -154,6 +154,17 @@ class BoundedSubmitRing:
             self.depth_hwm = len(self._items)
         return True
 
+    def try_extend(self, items: List[Any]) -> bool:
+        """All of ``items`` in their order, or none of them where they
+        would pass ``capacity``."""
+        depth = len(self._items) + len(items)
+        if self.capacity is not None and depth > self.capacity:
+            return False
+        self._items.extend(items)
+        if depth > self.depth_hwm:
+            self.depth_hwm = depth
+        return True
+
     def popleft(self) -> Any:
         return self._items.popleft()
 

@@ -14,7 +14,12 @@ import asyncio
 from dataclasses import dataclass
 from typing import Any, Dict, List, Optional
 
-from fantoch_tpu.core.command import Command, CommandResult, _restore_result
+from fantoch_tpu.core.command import (
+    Command,
+    CommandResult,
+    _restore_command,
+    _restore_result,
+)
 from fantoch_tpu.core.ids import ClientId, ProcessId, Rifl, ShardId
 from fantoch_tpu.run.backpressure import BoundedQueue
 from fantoch_tpu.run.routing import WorkerIndex, resolve_index
@@ -98,7 +103,22 @@ class Unregister:
 
 @dataclass
 class Submit:
+    """Client -> server: one command to order and execute.  The hot
+    frame of the way in, so it goes as the command's plain values under
+    one callable (``Command.__reduce__``: rifl source and sequence, then
+    shard, key, kind code and value of a one-key one-op command, 160-odd
+    bytes at a 100-byte value; or the nested ``shard -> key -> ops``
+    tuples of any other shape), with no class path or attribute name."""
+
     cmd: Command
+
+    def __reduce__(self):
+        return _submit, self.cmd.__reduce__()[1]
+
+
+def _submit(*values) -> Submit:
+    """Unpickle a :class:`Submit` from its ``Command``'s values."""
+    return Submit(_restore_command(*values))
 
 
 @dataclass

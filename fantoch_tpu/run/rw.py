@@ -2,10 +2,16 @@
 
 Reference: fantoch/src/run/rw/{mod,connection}.rs — the reference frames
 with tokio's LengthDelimitedCodec + bincode; here frames are a u32
-big-endian length prefix + pickled payload.  ``write`` queues without
-flushing, ``send`` queues and flushes, mirroring the reference's explicit
-flush control (rw/mod.rs:55-84) that lets writers batch small protocol
-messages into one syscall.
+big-endian length prefix + pickled payload.  The two hot messages of the
+client plane pickle as plain values under one callable each, with no
+class path or attribute name per field: the reply (``ToClient``, run/
+prelude.py) and the ``Submit`` (a command's rifl numbers, then ``shard,
+key, kind code, value`` where it has one key and one op, nested ``shard
+-> key -> ops`` tuples otherwise; core/command.py).  ``write`` queues
+without flushing, ``send`` queues and flushes, mirroring the reference's
+explicit flush control (rw/mod.rs:55-84) that lets writers batch small
+protocol messages into one syscall; ``recv`` reads one frame, ``recv_all``
+every whole frame a socket read brought.
 """
 
 from __future__ import annotations
@@ -18,6 +24,10 @@ from time import monotonic_ns
 from typing import Any, List, Optional
 
 _LEN = struct.Struct(">I")
+# what one ``recv_all`` asks the stream for: more than a ``StreamReader``
+# holds (it pauses its transport above twice its ``limit``), so a read
+# takes all that is buffered and the reader's limit is the bound
+_READ_ALL = 1 << 24
 # link frames (peer connections after the handshake): u8 kind + u64 seq
 # header inside the length-delimited frame; see run/links.py for the
 # reliability protocol built on top
@@ -71,12 +81,15 @@ class Rw:
         writer: asyncio.StreamWriter,
         decode_tally: Optional[List[int]] = None,
     ):
-        """``decode_tally``: a ``[ns, frames]`` pair the owner shares
-        among its connections; ``recv`` adds each frame's unpickle time
-        to it (the device runtime's ``session_decode_ms``)."""
+        """``decode_tally``: ``[ns, frames, reads]``, which the owner
+        shares among its connections; ``recv`` adds each frame's
+        unpickle time to it, ``recv_all`` a read's walk and unpickles
+        and the read itself (the device runtime's ``session_decode_ms``,
+        ``session_decoded``, ``session_reads``)."""
         self._reader = reader
         self._writer = writer
         self._decode_tally = decode_tally
+        self._tail = b""  # recv_all: the bytes of a frame not yet whole
         sock = writer.get_extra_info("socket")
         if sock is not None:
             # TCP_NODELAY, as the reference's Connection (connection.rs:46-51)
@@ -98,6 +111,45 @@ class Rw:
         tally[0] += monotonic_ns() - t0
         tally[1] += 1
         return value
+
+    async def recv_all(self) -> Optional[List[Any]]:
+        """Every whole frame the connection holds, decoded, in order:
+        one read of the stream when no frame is whole yet, then a walk
+        over the bytes; the incomplete tail waits for the next read.
+        None on EOF.  A connection that has called this stays with it
+        (``recv`` does not see the tail)."""
+        read, unpack_from, loads, size = (
+            self._reader.read, _LEN.unpack_from, pickle.loads, _LEN.size,
+        )
+        while True:
+            try:
+                data = await read(_READ_ALL)
+            except ConnectionResetError:
+                return None
+            if not data:
+                if len(self._tail) >= size:
+                    # EOF inside a payload: what readexactly raises
+                    raise asyncio.IncompleteReadError(self._tail[size:], None)
+                return None
+            t0 = monotonic_ns()
+            if self._tail:
+                data = self._tail + data
+            values: List[Any] = []
+            at, end = 0, len(data)
+            while end - at >= size:
+                stop = at + size + unpack_from(data, at)[0]
+                if stop > end:
+                    break
+                values.append(loads(data[at + size : stop]))
+                at = stop
+            self._tail = data[at:]
+            tally = self._decode_tally
+            if tally is not None:
+                tally[0] += monotonic_ns() - t0
+                tally[1] += len(values)
+                tally[2] += bool(values)
+            if values:
+                return values
 
     def write(self, value: Any) -> None:
         """Queue one frame without flushing."""
