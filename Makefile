@@ -1,7 +1,7 @@
 # Test/bench entry points (the reference pins quality with Makefile:3-7 —
 # fmt + clippy + `cargo test` under a quickcheck budget; here the suite +
 # dryrun + bench are the equivalent gates).
-.PHONY: test test-fast test-chaos test-recovery test-restart test-overload test-fuzz test-devicefault test-device-stripped dryrun bench chip-smoke bench-smoke trace-smoke critpath-smoke overload-smoke fuzz-smoke failover-smoke telemetry-smoke pallas-smoke scenario-smoke
+.PHONY: test test-fast test-chaos test-recovery test-restart test-overload test-fuzz test-devicefault test-device-stripped dryrun bench chip-smoke bench-smoke trace-smoke critpath-smoke overload-smoke fuzz-smoke failover-smoke telemetry-smoke scenario-smoke
 
 test:
 	python -m pytest tests/ -x -q
@@ -131,15 +131,6 @@ test-devicefault:
 # the per-push CI slice runs this next to fuzz-smoke
 failover-smoke:
 	python scripts/failover_smoke.py
-
-# Pallas-kernel gate: interpret-mode route-vs-route parity across all
-# four fused resolve families (pred/graph step, votes commit, fused
-# round), probe verdicts, the executor donation seam, and the
-# compile-cache discipline (bounded signatures; zero misses => zero
-# true recompiles) — the per-push CI slice runs this next to
-# failover-smoke
-pallas-smoke:
-	python scripts/pallas_smoke.py
 
 # scenario-observatory gate (r20): a declarative spec expands
 # byte-identically, a 3-point offered-rate ladder (sim timeline, EPaxos

@@ -316,9 +316,6 @@ def full_main() -> int:
         # accelerator failover drill (fault plane, r17)
         ("failover", bench_failover),
     ]
-    # (the r19 Pallas route-vs-route rows are interpret-mode parity rows:
-    # they run in --smoke on the CPU; the TPU's Pallas lowering refuses
-    # all four kernel families, see ops/pallas_resolve.py)
     if platform != "cpu":
         # scaling row last and chip only: CPU sorts at 4M would eat the
         # run's budget, and a cold 4M compile must not crowd out the
@@ -1311,7 +1308,7 @@ def bench_device_serving(
     total: int = 32_768, batch: int = 4096, conflict: float = 0.5, n: int = 3,
     families: Tuple[str, ...] = ("newt", "caesar", "paxos"),
     sweep: bool = True,
-    pipeline_depth: int = None,
+    pipeline_depth: int = 2,
 ):
     """The served TPU path (run/device_runner.DeviceDriver): real Command
     objects through the device protocol round — batch assembly, the
@@ -1337,14 +1334,9 @@ def bench_device_serving(
     from fantoch_tpu.core import Command, Dot, KVOp, Rifl
     from fantoch_tpu.run.device_runner import DeviceDriver
 
-    from fantoch_tpu.run.pipeline import requested_pipeline_depth
-
-    # one-knob resolution shared with the serving loop (arg > env), with
-    # the bench's own default of 2 on top (transfer of round k+1 + emit
-    # of round k-1 overlap compute of round k)
-    depth = requested_pipeline_depth(pipeline_depth)
-    if depth is None:
-        depth = 2
+    # the bench's own depth of 2: transfer of round k+1 and emit of
+    # round k-1 overlap compute of round k
+    depth = pipeline_depth
     assert depth >= 1, f"pipeline depth must be >= 1, got {depth}"
 
     rng = np.random.default_rng(21)
@@ -1975,278 +1967,6 @@ def bench_failover(
     }
 
 
-def bench_pallas_resolve(
-    cap: int = 512, width: int = 8, rounds: int = 8,
-) -> dict:
-    """Pallas-vs-composed resolve dispatch (round 19): the hand-fused
-    pred/graph plane-step kernels (ops/pallas_resolve.py) raced against
-    the composed-XLA originals on IDENTICAL multi-dispatch feeds, each
-    route threading its own donated resident state.  Self-checking: the
-    final step outputs must be bit-for-bit equal across routes before
-    any wall is reported.  A ``--smoke`` row only: on the CPU the Pallas
-    route runs in interpret mode (the parity vehicle — it discharges to
-    the same XLA ops, so the CPU walls race plumbing, not Mosaic); on
-    the TPU the kernels do not lower (ops/pallas_resolve.py) and this
-    row would raise."""
-    import random
-
-    import jax
-    import numpy as np
-    import jax.numpy as jnp
-
-    from fantoch_tpu.ops import pallas_resolve as pr
-    from fantoch_tpu.ops.graph_resolve import (
-        MISSING,
-        TERMINAL,
-        resolve_graph_plane_step,
-    )
-    from fantoch_tpu.ops.pred_resolve import resolve_pred_plane_step
-
-    U, P, E = 64, 16, 4
-    rng = random.Random(19)
-
-    def pred_feed(installed):
-        u_row = np.full((U,), cap, np.int32)
-        u_deps = np.full((U, width), TERMINAL, np.int32)
-        u_clock = np.zeros((U,), np.int32)
-        u_src = np.zeros((U,), np.int32)
-        installs = min(U, cap - installed)
-        for i in range(installs):
-            row = installed + i
-            u_row[i] = row
-            u_clock[i] = rng.randrange(1, 1 << 20)
-            u_src[i] = rng.randrange(1, 4)
-            for w in range(rng.randrange(0, width + 1)):
-                u_deps[i, w] = rng.choice(
-                    [TERMINAL, MISSING, rng.randrange(0, max(row, 1))]
-                )
-        p_row = np.full((P,), cap, np.int32)
-        p_col = np.zeros((P,), np.int32)
-        p_val = np.full((P,), TERMINAL, np.int32)
-        for j in range(rng.randrange(0, P)):
-            if installed == 0:
-                break
-            p_row[j] = rng.randrange(0, installed)
-            p_col[j] = rng.randrange(0, width)
-            p_val[j] = rng.choice([TERMINAL, rng.randrange(0, installed)])
-        feed = (u_row, u_deps, u_clock, u_src, p_row, p_col, p_val)
-        return tuple(jnp.asarray(a) for a in feed), installed + installs
-
-    def graph_feed(installed):
-        u_row = np.full((U,), cap, np.int32)
-        u_deps = np.full((U, width), TERMINAL, np.int32)
-        u_key = np.zeros((U,), np.int32)
-        u_src = np.zeros((U,), np.int32)
-        u_seq = np.zeros((U,), np.int32)
-        installs = min(U, cap - installed)
-        for i in range(installs):
-            row = installed + i
-            u_row[i] = row
-            u_key[i] = rng.randrange(0, 16)
-            u_src[i] = rng.randrange(1, 4)
-            u_seq[i] = row + 1
-            for w in range(rng.randrange(0, width + 1)):
-                u_deps[i, w] = rng.choice(
-                    [TERMINAL, MISSING, rng.randrange(0, max(row, 1))]
-                )
-        p_row = np.full((P,), cap, np.int32)
-        p_col = np.zeros((P,), np.int32)
-        p_val = np.full((P,), TERMINAL, np.int32)
-        for j in range(rng.randrange(0, P)):
-            if installed == 0:
-                break
-            p_row[j] = rng.randrange(0, installed)
-            p_col[j] = rng.randrange(0, width)
-            p_val[j] = rng.choice([TERMINAL, rng.randrange(0, installed)])
-        e_row = np.full((E,), cap, np.int32)
-        feed = (u_row, u_deps, u_key, u_src, u_seq, p_row, p_col, p_val, e_row)
-        return tuple(jnp.asarray(a) for a in feed), installed + installs
-
-    # identical feed sequences for both routes, built once up front
-    pred_feeds, graph_feeds = [], []
-    installed = 0
-    for _ in range(rounds):
-        feed, installed = pred_feed(installed)
-        pred_feeds.append(feed)
-    installed = 0
-    for _ in range(rounds):
-        feed, installed = graph_feed(installed)
-        graph_feeds.append(feed)
-
-    def pred_state():
-        return (
-            jnp.full((cap, width), TERMINAL, jnp.int32),
-            jnp.zeros((cap,), jnp.int32),
-            jnp.zeros((cap,), jnp.int32),
-            jnp.zeros((cap,), jnp.bool_),
-            jnp.zeros((cap,), jnp.bool_),
-        )
-
-    def graph_state():
-        return (
-            jnp.full((cap, width), TERMINAL, jnp.int32),
-            jnp.zeros((cap,), jnp.int32),
-            jnp.zeros((cap,), jnp.int32),
-            jnp.zeros((cap,), jnp.int32),
-            jnp.zeros((cap,), jnp.bool_),
-            jnp.zeros((cap,), jnp.bool_),
-        )
-
-    def run(enabled, step, fresh, feeds, carry):
-        """Thread one route through all feeds; the first dispatch warms
-        the compile, the rest are timed.  Returns (final output as np,
-        mean timed wall ms)."""
-        pr.set_pallas_kernels(enabled)
-        try:
-            state = fresh()
-            walls = []
-            out = None
-            for index, feed in enumerate(feeds):
-                t0 = time.perf_counter()
-                out = step(*state, *feed)
-                jax.block_until_ready(tuple(out))
-                if index > 0:
-                    walls.append((time.perf_counter() - t0) * 1000.0)
-                state = tuple(out[:carry])
-            final = tuple(np.asarray(o) for o in tuple(out))
-            return final, sum(walls) / max(1, len(walls))
-        finally:
-            pr.set_pallas_kernels(None)
-
-    pred_p, pred_p_ms = run(True, resolve_pred_plane_step, pred_state,
-                            pred_feeds, 5)
-    pred_x, pred_x_ms = run(False, resolve_pred_plane_step, pred_state,
-                            pred_feeds, 5)
-    graph_step = lambda *a: resolve_graph_plane_step(*a, mode="keyed")  # noqa: E731
-    graph_p, graph_p_ms = run(True, graph_step, graph_state, graph_feeds, 6)
-    graph_x, graph_x_ms = run(False, graph_step, graph_state, graph_feeds, 6)
-    for name, got, want in (("pred", pred_p, pred_x), ("graph", graph_p, graph_x)):
-        for i, (g, w) in enumerate(zip(got, want)):
-            assert np.array_equal(g, w), (
-                f"pallas route diverged from composed on {name} field {i}"
-            )
-    status = pr.pallas_status()
-    return {
-        "pallas_resolve_definition": (
-            f"pred+graph plane step, cap {cap} width {width}, {U} "
-            f"installs x {rounds} dispatches, both routes on identical "
-            "feeds, final-state parity asserted (r19)"
-        ),
-        "pallas_resolve_pred_ms": round(pred_p_ms, 3),
-        "pallas_resolve_pred_composed_ms": round(pred_x_ms, 3),
-        "pallas_resolve_graph_ms": round(graph_p_ms, 3),
-        "pallas_resolve_graph_composed_ms": round(graph_x_ms, 3),
-        "pallas_resolve_interpret": bool(status["interpret"]),
-    }
-
-
-def bench_table_pallas(keys: int = 256, batch: int = 2048, rounds: int = 8) -> dict:
-    """Pallas-vs-composed fused table round (round 19): the one-kernel
-    vote-coalesce + frontier + stability commit and the dense fused
-    round, raced route-vs-route on identical vote batches, each route
-    threading its own donated frontier.  Self-checking: every round's
-    full output tuple (stable mask, run/residual columns, frontier)
-    must agree bit-for-bit before walls are reported.  Same caveat as
-    ``bench_pallas_resolve``: a ``--smoke`` row, CPU walls race
-    plumbing, and the kernels do not lower on the TPU."""
-    import random
-
-    import jax
-    import numpy as np
-    import jax.numpy as jnp
-
-    from fantoch_tpu.ops import pallas_resolve as pr
-    from fantoch_tpu.ops.table_ops import fused_table_round, fused_votes_commit
-
-    rng = random.Random(23)
-    n = 3
-    feeds = []
-    for _ in range(rounds):
-        vkey = np.array([rng.randrange(0, keys) for _ in range(batch)], np.int32)
-        vby = np.array([rng.randrange(0, n) for _ in range(batch)], np.int32)
-        vstart = np.array(
-            [rng.randrange(1, 64) for _ in range(batch)], np.int32
-        )
-        vend = vstart + np.array(
-            [rng.randrange(0, 8) for _ in range(batch)], np.int32
-        )
-        valid = np.array([rng.random() < 0.9 for _ in range(batch)], bool)
-        feeds.append(
-            tuple(jnp.asarray(a) for a in (vkey, vby, vstart, vend, valid))
-        )
-    round_feeds = []
-    for _ in range(rounds):
-        rkey = np.array(
-            [rng.randrange(0, keys - 1) for _ in range(batch)], np.int32
-        )
-        rmc = np.array([rng.randrange(0, 16) for _ in range(batch)], np.int32)
-        round_feeds.append((jnp.asarray(rkey), jnp.asarray(rmc)))
-
-    def run_commit(enabled):
-        pr.set_pallas_kernels(enabled)
-        try:
-            frontier = jnp.zeros((keys, n), jnp.int32)
-            walls, outs = [], []
-            for index, feed in enumerate(feeds):
-                t0 = time.perf_counter()
-                out = fused_votes_commit(frontier, *feed, threshold=2)
-                jax.block_until_ready(tuple(out))
-                if index > 0:
-                    walls.append((time.perf_counter() - t0) * 1000.0)
-                outs.append(tuple(np.asarray(o) for o in out[1:]))
-                frontier = out[0]
-            outs.append((np.asarray(frontier),))
-            return outs, sum(walls) / max(1, len(walls))
-        finally:
-            pr.set_pallas_kernels(None)
-
-    def run_round(enabled):
-        pr.set_pallas_kernels(enabled)
-        try:
-            prior = jnp.zeros((keys,), jnp.int32)
-            frontier = jnp.zeros((keys, n), jnp.int32)
-            walls, outs = [], []
-            for index, feed in enumerate(round_feeds):
-                t0 = time.perf_counter()
-                out = fused_table_round(
-                    prior, frontier, *feed, threshold=2, voters=2
-                )
-                jax.block_until_ready(tuple(out))
-                if index > 0:
-                    walls.append((time.perf_counter() - t0) * 1000.0)
-                outs.append(tuple(np.asarray(o) for o in out[2:]))
-                prior, frontier = out[0], out[1]
-            outs.append((np.asarray(prior), np.asarray(frontier)))
-            return outs, sum(walls) / max(1, len(walls))
-        finally:
-            pr.set_pallas_kernels(None)
-
-    commit_p, commit_p_ms = run_commit(True)
-    commit_x, commit_x_ms = run_commit(False)
-    round_p, round_p_ms = run_round(True)
-    round_x, round_x_ms = run_round(False)
-    for name, got, want in (
-        ("votes_commit", commit_p, commit_x),
-        ("table_round", round_p, round_x),
-    ):
-        for r, (g, w) in enumerate(zip(got, want)):
-            for i, (a, b) in enumerate(zip(g, w)):
-                assert np.array_equal(a, b), (
-                    f"pallas route diverged on {name} round {r} field {i}"
-                )
-    return {
-        "table_pallas_definition": (
-            f"fused votes-commit + dense round, {batch} votes x "
-            f"{rounds} rounds over {keys} keys, both routes on identical "
-            "feeds, per-round output parity asserted (r19)"
-        ),
-        "table_pallas_commit_ms": round(commit_p_ms, 3),
-        "table_pallas_commit_composed_ms": round(commit_x_ms, 3),
-        "table_pallas_round_ms": round(round_p_ms, 3),
-        "table_pallas_round_composed_ms": round(round_x_ms, 3),
-    }
-
-
 # --- perf-regression gate (bench.py --regress) ---
 #
 # Compare a fresh bench row against an earlier one with per-key
@@ -2281,11 +2001,6 @@ REGRESS_BANDS = (
     # single dispatch each) on shared CI cores — scheduling noise, not
     # the plane, dominates the spread
     ("failover_", 3.0),
-    # route-vs-route kernel races (r19): per-dispatch walls of small
-    # kernels on shared CI cores — scheduler noise swings a sub-ms wall
-    # harder than any plumbing change; the chip rows carry the claim
-    ("pallas_resolve_", 2.5),
-    ("table_pallas_", 2.5),
     # scenario-curve rows (r20) ride the deterministic sim, but the knee
     # snaps between ladder points when detect_knee thresholds or the
     # serving path move — same coarse-grained band as overload_
@@ -2297,10 +2012,6 @@ REGRESS_BANDS = (
 # records must agree on it before any key of the family is compared
 DEFINITION_STAMPS = (
     ("serving_", "serving_newt_definition"),
-    # r19 kernel-race rows: table_pallas_ MUST precede table_ (first
-    # match wins) or its keys would be gated on the r06 table stamp
-    ("table_pallas_", "table_pallas_definition"),
-    ("pallas_resolve_", "pallas_resolve_definition"),
     ("table_", "table_arrays_definition"),
     ("overload_", "overload_definition"),
     ("pred_plane_serving_", "pred_plane_serving_definition"),
@@ -2490,11 +2201,6 @@ def smoke_main() -> None:
         bench_failover(keys=64, rounds=16, votes_per_round=256,
                        fault_at=5, down=4)
     )
-    # r19 route-vs-route rows, CPU-sized: every round's outputs are
-    # parity-asserted inside the bench — a diverging Pallas kernel fails
-    # the smoke here, not on the rig
-    out.update(bench_pallas_resolve(cap=128, width=4, rounds=4))
-    out.update(bench_table_pallas(keys=64, batch=256, rounds=4))
     # r20 scenario-curve row: deterministic sim sweep — asserts in-row
     # that the ladder saturates (a missing knee is a pipeline break)
     out.update(bench_curve())
@@ -2581,12 +2287,6 @@ def smoke_main() -> None:
         >= out["serving_ingest_unbatched_cmds_per_s"]
     ), out
     assert out["serving_ingest_recompiles_timed"] == 0, out
-    # the r19 kernel-route rows ran their own bit-for-bit parity asserts
-    # in-row; gate that both routes actually dispatched and were timed
-    assert out["pallas_resolve_pred_ms"] > 0, out
-    assert out["pallas_resolve_graph_ms"] > 0, out
-    assert out["table_pallas_commit_ms"] > 0, out
-    assert out["pallas_resolve_interpret"] is True, out  # cpu smoke
     # the r20 curve row: all three ladder points measured, knee detected
     # past the first point (the 50/s point must serve comfortably), and
     # the knee's goodput nonzero

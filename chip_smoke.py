@@ -23,8 +23,7 @@ Three legs, one after the other, each with its own device-owning process
 3. **planes** — the table, pred and graph plane programs at
    ``bench.py``'s default sizes; each row asserts per-key order parity
    with its sequential host twin in-row; on top: dispatches > 0, the
-   residency invariant on ``resident_uploads``, zero failovers, and the
-   ``pallas_status()`` that served.
+   residency invariant on ``resident_uploads`` and zero failovers.
 
 Exit 0 only if every leg passed on ``platform == "tpu"``.  Stdout is
 then two JSON lines: the report (every leg's result, mesh shape, cache
@@ -327,7 +326,6 @@ def leg_planes(table: dict | None = None, pred: dict | None = None,
     default sizes unless a test passes smaller ones."""
     device = _own_device("chip_smoke planes leg")
     import bench
-    from fantoch_tpu.ops.pallas_resolve import pallas_status
 
     rows = {}
     rows.update(bench.bench_table_path(**(table or {})))
@@ -350,7 +348,6 @@ def leg_planes(table: dict | None = None, pred: dict | None = None,
         )
         _check(got["failovers"] == 0, f"{plane} plane failed over", got)
         counters[plane] = got
-    status = pallas_status()
     return {
         "ok": True, **device, "host_twin_parity": True, "planes": counters,
         "sizes": {
@@ -358,7 +355,7 @@ def leg_planes(table: dict | None = None, pred: dict | None = None,
             "pred_batch": rows["pred_plane_batch"],
             "graph_batch": rows["graph_plane_batch"],
         },
-        "pallas_status": status, **_compile_tally(),
+        **_compile_tally(),
     }
 
 

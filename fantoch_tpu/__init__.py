@@ -7,7 +7,7 @@ pure state machines over a shared ``Protocol`` interface, pluggable
 ``Executor`` ordering engines, a deterministic discrete-event simulator, and
 an asyncio TCP runner — with the hot execution data plane (dependency-graph
 SCC/topological resolution, key-clock proposals, vote-range stability)
-re-designed as batched JAX/Pallas computations instead of serial pointer
+re-designed as batched JAX computations instead of serial pointer
 walks, and multi-chip scaling expressed as jax.sharding over a device Mesh.
 """
 

@@ -79,36 +79,33 @@ def add_config_flags(parser: argparse.ArgumentParser) -> None:
                         "(executor/graph/graph_plane.py): the dependency "
                         "backlog stays on device across feeds; requires "
                         "--batched-graph-executor and shard-count 1; "
-                        "default FANTOCH_GRAPH_PLANE env, else off")
+                        "default off")
     parser.add_argument("--graph-kernel-threshold", type=int, default=None,
                         metavar="N",
                         help="backlog size gating exact structure metrics "
                         "and the resident general path in the batched "
-                        "graph executor; default "
-                        "FANTOCH_GRAPH_KERNEL_THRESHOLD env, else 4096")
+                        "graph executor; default 4096")
     parser.add_argument("--serving-pipeline-depth", type=int, default=None,
                         metavar="K",
                         help="device serving pipeline depth (run/pipeline.py): "
                         "dispatched-but-undrained rounds kept in flight; "
-                        "default FANTOCH_SERVING_PIPELINE_DEPTH env, else 1")
+                        "default 1")
     parser.add_argument("--ingest-deadline", type=float, default=None,
                         metavar="MS", dest="ingest_deadline_ms",
                         help="adaptive ingest batching deadline budget "
                         "(run/ingest.py): a queued submission waits at most "
-                        "this long for its round to fill; default "
-                        "FANTOCH_INGEST_DEADLINE_MS env, else 2.0; "
+                        "this long for its round to fill; default 2.0; "
                         "0 disables batching")
     parser.add_argument("--ingest-target", type=int, default=None,
                         metavar="N", dest="ingest_target",
                         help="fixed ingest size target (rows that release "
                         "a round), overriding the EWMA-adaptive target; "
-                        "default FANTOCH_INGEST_TARGET env, else adaptive")
+                        "default adaptive")
     parser.add_argument("--serving-chain-max", type=int, default=None,
                         metavar="S", dest="serving_chain_max",
                         help="ceiling on the auto-tuned serving chain "
                         "length (rounds fused per device dispatch); "
-                        "default FANTOCH_SERVING_CHAIN_MAX env, else 8; "
-                        "1 disables chaining")
+                        "default 8; 1 disables chaining")
     parser.add_argument("--wal-sync", default=None,
                         choices=("always", "interval", "never"),
                         help="durable command-log fsync policy (run/wal.py); "

@@ -63,7 +63,7 @@ def build_parser() -> argparse.ArgumentParser:
         "the CPU, or whenever a pipeline depth was requested; "
         "overlap needs a compute resource besides the host cores).  The "
         "in-flight depth is the --serving-pipeline-depth config flag "
-        "(one knob: flag > FANTOCH_SERVING_PIPELINE_DEPTH env > 1)")
+        "(default 1)")
     parser.add_argument("--device-pending", type=int, default=256,
                         help="device pending-buffer capacity")
     parser.add_argument(

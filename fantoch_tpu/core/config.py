@@ -76,9 +76,7 @@ class Config:
     device_table_plane: bool = False
     # frontier-matrix element count (keys x n) at which the TableExecutor
     # host path routes stability to the device kernel instead of the
-    # numpy partition.  None = the built-in default (1 << 20), overridable
-    # via the FANTOCH_TABLE_KERNEL_THRESHOLD env var; an explicit value
-    # here beats both
+    # numpy partition.  None = the built-in default (1 << 20)
     table_kernel_threshold: Optional[int] = None
     # batch Caesar's predecessor executor: two-phase countdown resolution
     # as one device kernel per batch (fantoch_tpu/ops/pred_resolve.py at
@@ -102,19 +100,15 @@ class Config:
     # feeds install new rows and patch MISSING cells in place, resolves
     # run as donated in-place dispatches with only the emitted order
     # fetched back, and missing-blocked rows stay resident instead of
-    # round-tripping through host columns.  None = the
-    # FANTOCH_GRAPH_PLANE env var, else off (the host-column path stays
-    # the default oracle twin).  Single-shard only (shard sets must
-    # survive on host for cross-shard requests); requires
+    # round-tripping through host columns.  None = off (the host-column
+    # path stays the default oracle twin).  Single-shard only (shard sets
+    # must survive on host for cross-shard requests); requires
     # batched_graph_executor
     device_graph_plane: Optional[bool] = None
     # backlog size at which the batched graph executor stops collecting
     # exact per-SCC structure metrics (CHAIN_SIZE) and switches the
     # multi-key path to the resident peeler / the host path to the
-    # arrival-order shortcut.  None = the FANTOCH_GRAPH_KERNEL_THRESHOLD
-    # env var, else the built-in 4096; an explicit value here beats both
-    # (the Config.table_kernel_threshold precedence, resolved through
-    # executor/device_plane.resolve_threshold)
+    # arrival-order shortcut.  None = the built-in 4096
     graph_kernel_threshold: Optional[int] = None
     # resolver choice for the batched graph executor on *CPU* backends:
     # None = auto (the native C++ SCC resolver, fantoch_tpu/native, when
@@ -130,17 +124,6 @@ class Config:
     # dispatch log failover replays from.  None (default) = unarmed, the
     # plane trusts the device unconditionally (zero overhead)
     device_dispatch_timeout_ms: Optional[float] = None
-    # Pallas-fused resolve kernels (ops/pallas_resolve.py): route the
-    # hot plane dispatches (graph/pred plane step, fused table round,
-    # votes commit) through hand-fused Pallas kernels instead of the
-    # XLA-composed programs.  None = the FANTOCH_PALLAS env var, else
-    # off: the composed programs are the route that compiles on every
-    # backend.  An explicit opt-in runs the kernels in interpret mode on
-    # the CPU (the parity instrument) and RAISES on a TPU, whose Pallas
-    # lowering refuses them (scatter, sort) — there is no automatic
-    # fallback.  Process-global (the routers are module-level):
-    # co-hosted executors share one route
-    pallas_kernels: Optional[bool] = None
     # sampled shadow-check rate in [0, 1]: with probability p per
     # dispatch (seeded, deterministic) the plane replays the dispatch's
     # inputs through the same kernel on host-owned twin state and
@@ -169,40 +152,40 @@ class Config:
     # device serving pipeline depth (run/pipeline.py): how many
     # dispatched-but-undrained device rounds the serving loop keeps in
     # flight, overlapping host<->device transfer and result emit with
-    # device compute (depth K = K rounds of delivery lag).  None = the
-    # FANTOCH_SERVING_PIPELINE_DEPTH env var, else 1 (the classic
-    # double-buffered overlap); an explicit value also opts the
-    # DeviceRuntime into pipelining on CPU backends (new knob; no
-    # reference counterpart — the reference's runner is message-at-a-time)
+    # device compute (depth K = K rounds of delivery lag).  Set by
+    # --serving-pipeline-depth; None = 1 (the classic double-buffered
+    # overlap); an explicit value also opts the DeviceRuntime into
+    # pipelining on CPU backends (new knob; no reference counterpart —
+    # the reference's runner is message-at-a-time)
     serving_pipeline_depth: Optional[int] = None
     # adaptive ingest batching at the serving edge (run/ingest.py): the
     # deadline budget (ms) a queued submission may wait for its round to
-    # fill before it is released anyway.  One knob like
-    # serving_pipeline_depth: None = the FANTOCH_INGEST_DEADLINE_MS env
-    # var, else 2.0; an explicit 0 disables batching (legacy
-    # dispatch-on-anything).  The size target adapts from the EWMA
+    # fill before it is released anyway.  Set by --ingest-deadline;
+    # None = 2.0 on the served path (the sim and ProcessRuntime's pools
+    # batch only when it is set); an explicit 0 disables batching
+    # (dispatch on anything).  The size target adapts from the EWMA
     # arrival rate unless ingest_target pins it; a lone command in an
     # otherwise idle system always dispatches immediately (the sync-
     # latency fast path), whatever these knobs say
     ingest_deadline_ms: Optional[float] = None
     # fixed ingest size target (rows that release a round) overriding
-    # the EWMA-adaptive target.  None = the FANTOCH_INGEST_TARGET env
-    # var, else adaptive
+    # the EWMA-adaptive target.  Set by --ingest-target; None = adaptive
     ingest_target: Optional[int] = None
     # ceiling on the auto-tuned serving chain length S (rounds fused per
     # device dispatch, NewtDeviceDriver.step_chained_pipelined): the
     # tuner grows S while per-round dispatch overhead dominates device
-    # time and never past this.  None = the FANTOCH_SERVING_CHAIN_MAX
-    # env var, else 8; 1 disables chaining
+    # time and never past this.  Set by --serving-chain-max; None = 8;
+    # 1 disables chaining
     serving_chain_max: Optional[int] = None
     # durable command-log fsync policy (run/wal.py): "always" fsyncs
     # every append (commit-durable before anything acks it), "interval"
     # fsyncs on the runtime's periodic WAL tick (bounded loss window),
-    # "never" leaves durability to the OS.  One knob like
-    # serving_pipeline_depth: None = the FANTOCH_WAL_SYNC env var, else
-    # "interval"; an explicit value here beats both.  Only consulted when
-    # a runtime is given a wal_dir (new knob; no reference counterpart —
-    # the reference's runner has no durability story)
+    # "never" leaves durability to the OS.  None = the FANTOCH_WAL_SYNC
+    # env var (a deployment's durability policy, so the one variable a
+    # field still defers to), else "interval"; an explicit value here
+    # beats both.  Only consulted when a runtime is given a wal_dir (new
+    # knob; no reference counterpart — the reference's runner has no
+    # durability story)
     wal_sync: Optional[str] = None
     # overload-control plane (run/backpressure.py).  queue_capacity is
     # the high watermark of every run-layer bounded queue (worker /

@@ -28,8 +28,8 @@ and before this base each hand-rolled its own copy:
 * **per-dispatch counters** — dispatches / occupancy / residual work /
   kernel wall-ms, surfaced through ``Executor.device_counters()`` into
   the metrics snapshot, the tracer, and the bench rows.
-* **kernel-threshold switches** — config > env > built-in default
-  resolution for the thresholds that route host-vs-kernel work
+* **kernel-threshold switches** — the ``Config`` field, else the
+  built-in default, for the thresholds that route host-vs-kernel work
   (:func:`resolve_threshold`).
 
 Capacity follows a pow2 schedule (``_grow`` doubles) so XLA compiles
@@ -71,7 +71,6 @@ anything: no log, no twin, dispatch paths unchanged.
 
 from __future__ import annotations
 
-import os
 import time
 from typing import Dict, List, Optional, Tuple
 
@@ -101,19 +100,10 @@ HEALTH_NAMES = {
 TWIN_FOLD_LIMIT = 64
 
 
-def resolve_threshold(
-    explicit: Optional[int], env_var: str, default: int
-) -> int:
-    """The shared threshold-knob resolution: an explicit config value
-    beats the environment variable beats the built-in default (the
-    ``Config.table_kernel_threshold`` precedence, extracted so every
-    plane's switches resolve the same way)."""
-    if explicit is not None:
-        return int(explicit)
-    env = os.environ.get(env_var)
-    if env:
-        return int(env)
-    return default
+def resolve_threshold(explicit: Optional[int], default: int) -> int:
+    """A plane's kernel-size threshold: the ``Config`` field where it is
+    set, else the plane's built-in default."""
+    return default if explicit is None else int(explicit)
 
 
 class DevicePlane:

@@ -67,22 +67,9 @@ from fantoch_tpu.ops.graph_resolve import (
     TERMINAL,
     resolve_general,
     resolve_general_resident,
-    resolve_general_staged,
     resolve_keyed_auto,
 )
 from fantoch_tpu.utils import key_hash as _framework_key_hash
-
-
-def _use_resident_general() -> bool:
-    """Route large multi-key batches through the device-resident
-    peel-and-compact resolver (ONE dispatch + one fetch) instead of the
-    host-orchestrated staged peeler (a state fetch + re-upload per
-    stage, CPU-pinned to survive remote-dispatch rigs).  Default on —
-    parity-tested bit-for-bit and faster on both rig shapes;
-    ``FANTOCH_GENERAL_RESIDENT=0`` keeps the host-staged escape hatch."""
-    import os
-
-    return os.environ.get("FANTOCH_GENERAL_RESIDENT", "1") != "0"
 
 
 # lazy module-level jax singleton: the resolve hot path used to re-run
@@ -105,10 +92,8 @@ def _jax_mods():
 _NO_DEP = np.int64(-1)  # packed-dep sentinel: no dependency in this slot
 # below this backlog size, ask the keyed kernel for full structure so
 # CHAIN_SIZE metrics stay exact (tests/sims); above it, skip the extra
-# device sort and only collect aggregate metrics.  This is the built-in
-# DEFAULT of the unified kernel-size gate: Config.graph_kernel_threshold
-# beats the FANTOCH_GRAPH_KERNEL_THRESHOLD env var beats this value
-# (executor/device_plane.resolve_threshold, the table-plane precedence)
+# device sort and only collect aggregate metrics.  The built-in default
+# of Config.graph_kernel_threshold
 _STRUCTURE_THRESHOLD = 4096
 
 
@@ -212,41 +197,28 @@ class BatchedDependencyGraph(DependencyGraph):
             self._dirty = False
             self._last_time: Optional[SysTime] = None
             self._native_auto: Optional[bool] = None
-            # the unified kernel-size gate (config > env > default)
+            # the kernel-size gate (Config field, else the default)
             from fantoch_tpu.executor.device_plane import resolve_threshold
 
             self._structure_threshold = resolve_threshold(
-                config.graph_kernel_threshold,
-                "FANTOCH_GRAPH_KERNEL_THRESHOLD",
-                _STRUCTURE_THRESHOLD,
+                config.graph_kernel_threshold, _STRUCTURE_THRESHOLD
             )
             # device-resident backlog plane (executor/graph/graph_plane.py):
             # the host-column machinery below stays the oracle twin.
             # Single-shard only — Dependency shard sets must survive on
             # host for cross-shard requests (ROADMAP item 2's sharded
             # planes are the multi-shard story)
-            from fantoch_tpu.executor.graph.graph_plane import (
-                graph_plane_enabled,
-            )
-
             if config.device_graph_plane and self._multi_shard:
                 raise ValueError(
                     "device_graph_plane requires shard_count == 1 (the "
                     "backlog plane keeps no per-dep shard sets)"
                 )
             self._plane = None
-            if graph_plane_enabled(config) and not self._multi_shard:
+            if config.device_graph_plane:
                 from fantoch_tpu.executor.graph.graph_plane import (
                     DeviceGraphPlane,
                 )
-                from fantoch_tpu.ops.pallas_resolve import (
-                    apply_pallas_config,
-                )
 
-                # fold Config.pallas_kernels into the kernel route before
-                # the plane's first dispatch (config > env > backend
-                # default)
-                apply_pallas_config(config)
                 self._plane = DeviceGraphPlane(
                     process_id, shard_id, config, self._frontier,
                     self._metrics,
@@ -825,48 +797,19 @@ class BatchedDependencyGraph(DependencyGraph):
                     )
                 )
                 self._metrics.collect_many(ExecutorMetricsKind.CHAIN_SIZE, sizes)
-        elif batch > self._structure_threshold:
-            # large multi-key batch: the peel-and-compact peeler's cost
-            # tracks the per-level live set instead of B x depth, so deep
-            # alternating chains don't fall off the fixed-budget cliff
-            # (VERDICT r3 weak #3); structure metrics are skipped at this
-            # size, matching the keyed path's gating.  The resident
-            # variant runs the whole stage schedule as ONE dispatch with
-            # the state device-resident between stages (no per-stage
-            # host round-trips — the r13 fallback-cliff fix)
-            if _use_resident_general():
-                # pad to pow2 so XLA compiles O(log) distinct programs as
-                # backlog sizes vary; pad rows resolve as rank-0
-                # singletons and are dropped from the emitted prefix
-                padded_b = _pad_pow2(batch)
-                padded_w = _pad_pow2(max(dep_rows.shape[1], 1))
-                mat = np.full((padded_b, padded_w), TERMINAL, dtype=np.int32)
-                mat[:batch, : dep_rows.shape[1]] = dep_rows
-                ps = np.zeros(padded_b, np.int32)
-                pq = np.zeros(padded_b, np.int32)
-                ps[:batch] = src32
-                pq[:batch] = seq32
-                res = resolve_general_resident(
-                    jnp.asarray(mat), jnp.asarray(ps), jnp.asarray(pq)
-                )
-                # one blocking transfer for all result fields
-                res = jax.device_get(res)
-                order = res.order
-                order = order[order < batch]
-                emitted = order[res.resolved[order]]
-                n_res = len(emitted)
-                stuck = res.stuck[:batch]
-                stuck_rows = np.nonzero(stuck)[0] if stuck.any() else None
-            else:
-                # host-orchestrated escape hatch (results host-side)
-                res = resolve_general_staged(dep_rows, src32, seq32)
-                order = res.order
-                emitted = order[res.resolved[order]]
-                n_res = len(emitted)
-                stuck_rows = (
-                    np.nonzero(res.stuck)[0] if res.stuck.any() else None
-                )
         else:
+            # multi-key batch.  Past the kernel-size gate the resident
+            # peel-and-compact peeler resolves it: its cost tracks the
+            # per-level live set instead of B x depth, so deep alternating
+            # chains don't fall off the fixed-budget cliff, and the whole
+            # stage schedule is ONE dispatch with the state
+            # device-resident between stages; structure metrics are
+            # skipped at this size, matching the keyed path's gating
+            large = batch > self._structure_threshold
+            resolver = resolve_general_resident if large else resolve_general
+            # pad to pow2 so XLA compiles O(log) distinct programs as
+            # backlog sizes vary; pad rows resolve as rank-0
+            # singletons and are dropped from the emitted prefix
             padded_b = _pad_pow2(batch)
             padded_w = _pad_pow2(max(dep_rows.shape[1], 1))
             mat = np.full((padded_b, padded_w), TERMINAL, dtype=np.int32)
@@ -875,7 +818,7 @@ class BatchedDependencyGraph(DependencyGraph):
             pq = np.zeros(padded_b, np.int32)
             ps[:batch] = src32
             pq[:batch] = seq32
-            res = resolve_general(jnp.asarray(mat), jnp.asarray(ps), jnp.asarray(pq))
+            res = resolver(jnp.asarray(mat), jnp.asarray(ps), jnp.asarray(pq))
             res = jax.device_get(res)  # all fields in one blocking transfer
             order = res.order
             order = order[order < batch]
@@ -883,7 +826,7 @@ class BatchedDependencyGraph(DependencyGraph):
             n_res = len(emitted)
             stuck = res.stuck[:batch]
             stuck_rows = np.nonzero(stuck)[0] if stuck.any() else None
-            if n_res:
+            if n_res and not large:
                 leaders = res.leader[emitted]
                 sizes = np.diff(
                     np.concatenate(

@@ -52,7 +52,6 @@ sequences at or above ``2^31 - 1`` with the shared typed error.
 
 from __future__ import annotations
 
-import os
 import time as _time
 from collections import deque
 from typing import Deque, Dict, List, Set, Tuple
@@ -72,18 +71,6 @@ from fantoch_tpu.ops.graph_resolve import MISSING, TERMINAL
 
 _INT32_MAX = (1 << 31) - 1
 _SEQ_MASK = (1 << 32) - 1
-
-def graph_plane_enabled(config: Config) -> bool:
-    """The plane routing switch: an explicit ``Config.device_graph_plane``
-    beats the ``FANTOCH_GRAPH_PLANE`` env var beats the default (off —
-    the host-column path stays the oracle twin)."""
-    if config.device_graph_plane is not None:
-        return bool(config.device_graph_plane)
-    env = os.environ.get("FANTOCH_GRAPH_PLANE")
-    if env is None or env == "":
-        return False
-    return env not in ("0", "false", "no")
-
 
 class DeviceGraphPlane(DevicePlane):
     """Resident dependency backlog + one fused dispatch per executor

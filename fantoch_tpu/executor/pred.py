@@ -521,11 +521,7 @@ class PredecessorsExecutor(Executor):
         # drives either twin identically (oracle-parity tested)
         if config.device_pred_plane and not config.execute_at_commit:
             from fantoch_tpu.executor.pred_plane import DevicePredPlane
-            from fantoch_tpu.ops.pallas_resolve import apply_pallas_config
 
-            # fold Config.pallas_kernels into the kernel route before the
-            # plane's first dispatch (config > env > backend default)
-            apply_pallas_config(config)
             self._graph = DevicePredPlane(process_id, config)
             # arm the fault plane (deadline + shadow-check) from config;
             # the runners re-seed and attach injectors/listeners on top

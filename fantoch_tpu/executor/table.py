@@ -324,8 +324,7 @@ class TableExecutor(Executor):
     # frontier-matrix element count (keys x n) at which the device kernel
     # beats host numpy: an order statistic over 3-5 columns is a few ns/row
     # on host, so the dispatch only amortizes at millions of elements.
-    # Default for Config.table_kernel_threshold = None without an env
-    # override (FANTOCH_TABLE_KERNEL_THRESHOLD)
+    # Default for Config.table_kernel_threshold = None
     _KERNEL_THRESHOLD = 1 << 20
 
     @classmethod
@@ -333,9 +332,7 @@ class TableExecutor(Executor):
         from fantoch_tpu.executor.device_plane import resolve_threshold
 
         return resolve_threshold(
-            config.table_kernel_threshold,
-            "FANTOCH_TABLE_KERNEL_THRESHOLD",
-            cls._KERNEL_THRESHOLD,
+            config.table_kernel_threshold, cls._KERNEL_THRESHOLD
         )
 
     def __init__(self, process_id: ProcessId, shard_id: ShardId, config: Config):
@@ -360,11 +357,7 @@ class TableExecutor(Executor):
         self._plane = None
         if config.device_table_plane:
             from fantoch_tpu.executor.table_plane import DeviceTablePlane
-            from fantoch_tpu.ops.pallas_resolve import apply_pallas_config
 
-            # fold Config.pallas_kernels into the kernel route before the
-            # plane's first dispatch (config > env > backend default)
-            apply_pallas_config(config)
             self._plane = DeviceTablePlane(config.n, stability_threshold)
             # arm the fault plane (deadline + shadow-check) from config;
             # the runners re-seed and attach injectors/listeners on top

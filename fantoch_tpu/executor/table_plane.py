@@ -2,9 +2,8 @@
 
 The host twin (executor/table.py) keeps one ``RangeEventSet`` per
 (key, process) and rebuilds + re-uploads the frontier matrix for every
-executor batch — ~68 ms of dispatch round-trip per 71 ms call on the
-remote-dispatch rig (BENCH_TPU_LATEST).  This plane applies the move that
-won the graph executor: the ``(key_bucket x process)`` frontier matrix
+executor batch (on the chip: not measured).  This plane applies the move
+of the graph executor: the ``(key_bucket x process)`` frontier matrix
 lives ON DEVICE across batches (donated buffers,
 ``ops/table_ops.fused_votes_commit``), and each batch is one fused
 dispatch doing vote-range coalescing (segment-max over sorted

@@ -6,7 +6,7 @@ when it rides a swept throughput-latency curve, not a single point.  A
 :class:`ScenarioSpec` declares the whole cross product once — protocol
 x (n, f) x fault plan (incl. device faults) x key skew x read/write mix
 x multi-key txn mix x offered open-loop rate x Config knobs (pipeline /
-ingest / pallas / planes) x placement — and :func:`expand` turns it into
+ingest / planes) x placement — and :func:`expand` turns it into
 a deterministic run matrix:
 
   * same spec + seed => byte-identical expansion
@@ -106,8 +106,8 @@ class ScenarioSpec:
     # sim-only fault schedule (sim/faults.FaultPlan.to_dict shape,
     # device faults included)
     fault_plan: Optional[Dict[str, Any]] = None
-    # Config.with_ overrides (pipeline depth, ingest deadline, pallas,
-    # device planes, admission limit, trace/telemetry knobs, ...)
+    # Config.with_ overrides (pipeline depth, ingest deadline, device
+    # planes, admission limit, trace/telemetry knobs, ...)
     knobs: Dict[str, Any] = field(default_factory=dict)
     # placement: {"mode": "regions", "regions": [...], "clients": [...]}
     # pins it; {"mode": "search", "candidates": [...], "clients": [...],

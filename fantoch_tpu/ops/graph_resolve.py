@@ -588,10 +588,10 @@ def resolve_general_staged(
     ``resolve_general``).  Missing-blocked rows and their dependents come
     back unresolved and not stuck.
 
-    Off the default path: the batched executor reaches it only under
-    ``FANTOCH_GENERAL_RESIDENT=0``; the in-dispatch resolvers
-    (``resolve_general``, ``resolve_general_resident``,
-    ``resolve_keyed_auto``) are the hot path."""
+    No executor calls it: the in-dispatch resolvers (``resolve_general``,
+    ``resolve_general_resident``, ``resolve_keyed_auto``) are the route.
+    It stays as the oracle that tests/test_ops_resolve.py holds
+    ``resolve_general_resident`` to."""
     import numpy as np
 
     deps = np.asarray(deps, dtype=np.int32)
@@ -858,7 +858,10 @@ class GraphPlaneStep(NamedTuple):
     leader: jax.Array  # int32[C] — structure modes: SCC leader (CHAIN_SIZE)
 
 
-def graph_plane_step_core(
+@functools.partial(
+    jax.jit, donate_argnums=(0, 1, 2, 3, 4, 5), static_argnames=("mode",)
+)
+def resolve_graph_plane_step(
     deps: jax.Array,  # int32[C, W] slot indices / TERMINAL / MISSING
     key: jax.Array,  # int32[C]
     src: jax.Array,  # int32[C]
@@ -991,37 +994,7 @@ def graph_plane_step_core(
     )
 
 
-# the composed program: graph_plane_step_core compiled as one donated
-# dispatch (the pre-Pallas default, and the fallback route).  The core
-# stays un-jitted so the Pallas kernel (ops/pallas_resolve.py) can trace
-# the IDENTICAL program inside its kernel body — parity by construction.
-resolve_graph_plane_step_xla = functools.partial(
-    jax.jit, donate_argnums=(0, 1, 2, 3, 4, 5), static_argnames=("mode",)
-)(graph_plane_step_core)
-
-register_program("graph_plane_step_xla", resolve_graph_plane_step_xla)
-
-
-def resolve_graph_plane_step(
-    deps, key, src, seq, occ, executed,
-    u_row, u_deps, u_key, u_src, u_seq,
-    p_row, p_col, p_val, e_row,
-    *,
-    mode: str,
-) -> GraphPlaneStep:
-    """Route one resident graph-plane dispatch: the Pallas-fused kernel
-    when :func:`fantoch_tpu.ops.pallas_resolve.pallas_enabled` says so,
-    else the composed :func:`resolve_graph_plane_step_xla`.  Same signature, donation set,
-    and bit-for-bit :class:`GraphPlaneStep` either way — executors, twin
-    replay, and shadow checks all call through here."""
-    from fantoch_tpu.ops import pallas_resolve as pr
-
-    args = (deps, key, src, seq, occ, executed,
-            u_row, u_deps, u_key, u_src, u_seq, p_row, p_col, p_val, e_row)
-    return pr.route_dispatch(
-        "graph_plane_step", pr.graph_plane_step_pallas,
-        resolve_graph_plane_step_xla, args, {"mode": mode},
-    )
+register_program("graph_plane_step", resolve_graph_plane_step)
 
 
 def _resolve_general_iterative(deps, dot_src, dot_seq, max_iters):

@@ -120,7 +120,7 @@ class PredPlaneStep(NamedTuple):
 
 
 @functools.partial(jax.jit, donate_argnums=(0, 1, 2, 3, 4))
-def resolve_pred_plane_step_xla(
+def resolve_pred_plane_step(
     deps: jax.Array,  # int32[C, W] slot indices / TERMINAL / MISSING
     clock: jax.Array,  # int32[C] — committed timestamp seq
     src: jax.Array,  # int32[C] — timestamp process id (clock uniqueness)
@@ -195,25 +195,5 @@ def resolve_pred_plane_step_xla(
     return PredPlaneStep(deps, clock, src, occ, done, newly)
 
 
-register_program("pred_plane_step_xla", resolve_pred_plane_step_xla)
+register_program("pred_plane_step", resolve_pred_plane_step)
 register_program("pred_resolve", resolve_pred)
-
-
-def resolve_pred_plane_step(
-    deps, clock, src, occ, executed,
-    u_row, u_deps, u_clock, u_src, p_row, p_col, p_val,
-) -> PredPlaneStep:
-    """Route one resident pred-plane dispatch: the Pallas-fused kernel
-    when :func:`fantoch_tpu.ops.pallas_resolve.pallas_enabled` says so,
-    else the composed :func:`resolve_pred_plane_step_xla`.  Same signature, donation set,
-    and bit-for-bit output either way — executors, twin replay, and
-    shadow checks all call through here, so every consumer follows one
-    route."""
-    from fantoch_tpu.ops import pallas_resolve as pr
-
-    args = (deps, clock, src, occ, executed,
-            u_row, u_deps, u_clock, u_src, p_row, p_col, p_val)
-    return pr.route_dispatch(
-        "pred_plane_step", pr.pred_plane_step_pallas,
-        resolve_pred_plane_step_xla, args, {},
-    )

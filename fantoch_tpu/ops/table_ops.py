@@ -257,20 +257,28 @@ def stable_clocks(frontiers: jax.Array, *, threshold: int) -> jax.Array:
 # ---------------------------------------------------------------------------
 
 
-def _votes_commit_core(
-    frontier: jax.Array,  # int32[K, n]
-    vkey: jax.Array,  # int32[V]
-    vby: jax.Array,  # int32[V]
+@functools.partial(jax.jit, static_argnames=("threshold",), donate_argnums=(0,))
+def fused_votes_commit(
+    frontier: jax.Array,  # int32[K, n], DONATED — resident vote frontiers
+    vkey: jax.Array,  # int32[V] — key bucket per vote range
+    vby: jax.Array,  # int32[V] — voting process, 0-based column index
     vstart: jax.Array,  # int32[V]
     vend: jax.Array,  # int32[V]
-    valid: jax.Array,  # bool[V]
+    valid: jax.Array,  # bool[V] — pad rows False
     *,
     threshold: int,
 ):
-    """Traceable body of :func:`fused_votes_commit` — shared with the
-    Pallas table kernel (ops/pallas_resolve.py), which traces this same
-    program inside one VMEM-resident kernel body so the two routes are
-    bit-for-bit by construction."""
+    """One dispatch for the executor side of the table plane: coalesce
+    vote ranges per (key, process), advance the resident frontiers, and
+    compute every key's stable clock.
+
+    Returns ``(new_frontier[K, n], stable[K], run_key[V], run_by[V],
+    run_start[V], run_end[V], residual[V])``: the ``run_*`` columns hold
+    the merged vote runs (one slot per run, invalid slots have
+    ``residual`` False) and ``residual`` marks runs that start beyond
+    the frontier gap — the caller buffers those and re-feeds them with
+    the next batch (RangeEventSet semantics preserved across batches).
+    """
     K, n = frontier.shape
     V = vkey.shape[0]
     int_min = jnp.iinfo(jnp.int32).min
@@ -318,49 +326,7 @@ def _votes_commit_core(
     return new_frontier, stable, run_key, run_by, run_start, run_end, residual
 
 
-@functools.partial(jax.jit, static_argnames=("threshold",), donate_argnums=(0,))
-def fused_votes_commit_xla(
-    frontier: jax.Array,  # int32[K, n], DONATED — resident vote frontiers
-    vkey: jax.Array,  # int32[V] — key bucket per vote range
-    vby: jax.Array,  # int32[V] — voting process, 0-based column index
-    vstart: jax.Array,  # int32[V]
-    vend: jax.Array,  # int32[V]
-    valid: jax.Array,  # bool[V] — pad rows False
-    *,
-    threshold: int,
-):
-    """One dispatch for the executor side of the table plane: coalesce
-    vote ranges per (key, process), advance the resident frontiers, and
-    compute every key's stable clock.
-
-    Returns ``(new_frontier[K, n], stable[K], run_key[V], run_by[V],
-    run_start[V], run_end[V], residual[V])``: the ``run_*`` columns hold
-    the merged vote runs (one slot per run, invalid slots have
-    ``residual`` False) and ``residual`` marks runs that start beyond
-    the frontier gap — the caller buffers those and re-feeds them with
-    the next batch (RangeEventSet semantics preserved across batches).
-    """
-    return _votes_commit_core(
-        frontier, vkey, vby, vstart, vend, valid, threshold=threshold
-    )
-
-
-register_program("votes_commit_xla", fused_votes_commit_xla)
-
-
-def fused_votes_commit(frontier, vkey, vby, vstart, vend, valid, *, threshold):
-    """Route one table-plane commit dispatch: the Pallas-fused kernel
-    when :func:`fantoch_tpu.ops.pallas_resolve.pallas_enabled` says so,
-    else the composed :func:`fused_votes_commit_xla`.  Same signature, donation, and
-    bit-for-bit 7-tuple either way (the residual-column protocol is
-    part of the contract)."""
-    from fantoch_tpu.ops import pallas_resolve as pr
-
-    args = (frontier, vkey, vby, vstart, vend, valid)
-    return pr.route_dispatch(
-        "votes_commit", pr.votes_commit_pallas, fused_votes_commit_xla,
-        args, {"threshold": threshold},
-    )
+register_program("votes_commit", fused_votes_commit)
 
 
 def _fused_round_core(prior, frontier, key, min_clock, threshold, voters):
@@ -391,7 +357,7 @@ def _fused_round_core(prior, frontier, key, min_clock, threshold, voters):
 @functools.partial(
     jax.jit, static_argnames=("threshold", "voters"), donate_argnums=(0, 1)
 )
-def fused_table_round_xla(
+def fused_table_round(
     prior: jax.Array,  # int32[K], DONATED
     frontier: jax.Array,  # int32[K, n], DONATED
     key: jax.Array,  # int32[B]
@@ -409,21 +375,7 @@ def fused_table_round_xla(
     return _fused_round_core(prior, frontier, key, min_clock, threshold, voters)
 
 
-register_program("table_round_xla", fused_table_round_xla)
-
-
-def fused_table_round(prior, frontier, key, min_clock, *, threshold, voters):
-    """Route one dense table round: the Pallas-fused kernel when
-    :func:`fantoch_tpu.ops.pallas_resolve.pallas_enabled` says so, else
-    the composed :func:`fused_table_round_xla`.  Bit-for-bit either way."""
-    from fantoch_tpu.ops import pallas_resolve as pr
-
-    args = (prior, frontier, key, min_clock)
-    kwargs = {"threshold": threshold, "voters": voters}
-    return pr.route_dispatch(
-        "table_round", pr.table_round_pallas, fused_table_round_xla,
-        args, kwargs,
-    )
+register_program("table_round", fused_table_round)
 
 
 @functools.partial(

@@ -63,13 +63,11 @@ from fantoch_tpu.run.ingest import (
     AdaptiveIngestBatcher,
     ChainAutoTuner,
     resolve_ingest_deadline_ms,
-    resolve_ingest_target,
     resolve_serving_chain_max,
 )
 from fantoch_tpu.run.pipeline import (
     BoundedSubmitRing,
     PipelineCore,
-    requested_pipeline_depth,
     resolve_pipeline_depth,
 )
 from fantoch_tpu.run.prelude import (
@@ -1655,10 +1653,6 @@ class DeviceRuntime:
         metrics_file: Optional[str] = None,
         metrics_interval_ms: int = 5000,
         pipeline: Optional[bool] = None,
-        pipeline_depth: Optional[int] = None,
-        ingest_deadline_ms: Optional[float] = None,
-        ingest_target: Optional[int] = None,
-        serving_chain_max: Optional[int] = None,
         mesh=None,
         telemetry_file: Optional[str] = None,
         metrics_port: Optional[int] = None,
@@ -1728,14 +1722,9 @@ class DeviceRuntime:
                 monitor_execution_order=monitor_execution_order,
                 mesh=mesh,
             )
-        # in-flight depth: explicit arg > Config.serving_pipeline_depth >
-        # FANTOCH_SERVING_PIPELINE_DEPTH env > 1 (run/pipeline.py) —
-        # live serving and the bench rig share one resolution, and ANY
-        # of the three spellings counts as the CPU pipelining opt-in
-        depth_requested = (
-            requested_pipeline_depth(pipeline_depth, config) is not None
-        )
-        self.pipeline_depth = resolve_pipeline_depth(pipeline_depth, config)
+        # in-flight depth: Config.serving_pipeline_depth, else 1
+        # (run/pipeline.py)
+        self.pipeline_depth = resolve_pipeline_depth(config)
         self.driver.pipeline_depth = self.pipeline_depth
         if pipeline is None:
             # dispatch/drain overlap needs a compute resource besides the
@@ -1746,7 +1735,7 @@ class DeviceRuntime:
             device0 = np.asarray(self.driver._mesh.devices).flat[0]
             pipeline = (
                 getattr(device0, "platform", "cpu") != "cpu"
-                or depth_requested
+                or config.serving_pipeline_depth is not None
             )
         # every driver implements the dispatch/drain split, so the
         # scaffold's step_pipelined is always available
@@ -1755,26 +1744,21 @@ class DeviceRuntime:
         # submissions until the EWMA size target or the deadline budget
         # fills, so rounds dispatch full under load; the idle-system
         # fast path keeps the lone closed-loop command synchronous.
-        # Same one-knob precedence as the depth above; deadline 0 turns
-        # the gate off (legacy dispatch-on-anything)
-        self.ingest_deadline_ms = resolve_ingest_deadline_ms(
-            ingest_deadline_ms, config
-        )
+        # Deadline 0 turns the gate off (dispatch on anything)
+        self.ingest_deadline_ms = resolve_ingest_deadline_ms(config)
+        chain_max = resolve_serving_chain_max(config)
         self._batcher = AdaptiveIngestBatcher(
             self.ingest_deadline_ms,
             # the size target never exceeds what one release can carry:
             # a full chain of full rounds
-            max_target=self.driver.batch_size
-            * resolve_serving_chain_max(serving_chain_max, config),
-            fixed_target=resolve_ingest_target(ingest_target, config),
+            max_target=self.driver.batch_size * chain_max,
+            fixed_target=config.ingest_target,
         )
         # chained-by-default serving: every dispatch may fuse up to S
         # rounds (PipelineCore.step_chained_pipelined; Newt runs them as
         # ONE device program), with S auto-tuned from the measured
         # per-round dispatch overhead vs in-dispatch time
-        self._chain_tuner = ChainAutoTuner(
-            resolve_serving_chain_max(serving_chain_max, config)
-        )
+        self._chain_tuner = ChainAutoTuner(chain_max)
         self.dot_gen = AtomicIdGen(process_id)
         self.metrics_file = metrics_file
         self.metrics_interval_ms = metrics_interval_ms

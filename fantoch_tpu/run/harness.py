@@ -380,7 +380,6 @@ async def run_device_server(
     deadline_ms: Optional[int] = None,
     monitor_execution_order: bool = True,
     pipeline: Optional[bool] = None,
-    pipeline_depth: Optional[int] = None,
     telemetry_file: Optional[str] = None,
     metrics_port: Optional[int] = None,
     trace_file: Optional[str] = None,
@@ -403,7 +402,6 @@ async def run_device_server(
         pending_capacity=pending_capacity,
         monitor_execution_order=monitor_execution_order,
         pipeline=pipeline,
-        pipeline_depth=pipeline_depth,
         telemetry_file=telemetry_file,
         metrics_port=metrics_port,
         trace_file=trace_file,

@@ -1,13 +1,12 @@
 """Adaptive ingest batching for the serving edge.
 
-The device kernels already amortize: one fused dispatch orders a whole
-4096-slot round in ~3 ms, and ``step_chained`` proves ~0.9M cmds/s
-in-dispatch.  End-to-end serving was ~25x slower because the serving
-loops dispatch the instant anything is queued — under open-loop load a
-round leaves with a handful of rows and the device round-trip is paid
-per trickle, not per batch.  This module is the accumulate-fuse-
-dispatch-lazily discipline of the GraphBLAS nonblocking-execution line
-(PAPERS.md) applied to that edge, shared by every serving surface
+A round's cost is mostly fixed: on the chip's own host ``enqueue`` +
+``fetch`` take 12-15 ms of a 19-25 ms open-loop round whatever the
+round carries (PERF.md section 5), so a serving loop that dispatches
+the instant anything is queued pays that per trickle, not per batch.
+This module is the accumulate-fuse-dispatch-lazily discipline of the
+GraphBLAS nonblocking-execution line (PAPERS.md) applied to that edge,
+shared by every serving surface
 (``DeviceRuntime._driver_task``, the process runner's executor pools,
 the sim's open-loop arrivals, and ``OrderingPool`` shard rounds):
 
@@ -26,9 +25,8 @@ the sim's open-loop arrivals, and ``OrderingPool`` shard rounds):
   round, shrink once it is amortized, clamp at
   ``Config.serving_chain_max``.
 
-Knob resolution follows the ``serving_pipeline_depth`` one-knob rule
-(run/pipeline.py): explicit argument > ``Config`` field > env var >
-default — any spelling is the same knob, never three.
+Each tuning value has one home: a ``Config`` field that one CLI flag
+sets, read here with the module's default where the field is unset.
 
 Time is injected (float milliseconds): the run layer passes a monotonic
 wall clock, the sim its virtual clock — the batcher itself never reads
@@ -39,95 +37,31 @@ a clock, which is what makes the sim wire-through deterministic
 from __future__ import annotations
 
 import math
-import os
 from typing import Any, List, Optional, Sequence, Tuple
 
-ENV_INGEST_DEADLINE_MS = "FANTOCH_INGEST_DEADLINE_MS"
-ENV_INGEST_TARGET = "FANTOCH_INGEST_TARGET"
-ENV_SERVING_CHAIN_MAX = "FANTOCH_SERVING_CHAIN_MAX"
-
-# the default latency budget a queued command may pay to batching: small
-# against the ~68 ms remote dispatch round-trip the batch amortizes, and
-# against any cross-region commit, yet ~the device kernel time — so a
-# deadline-released round still carries most of a saturated window
+# the default latency budget a queued command may pay to batching: a
+# tenth of the 19-25 ms an open-loop round takes on the chip's own host
+# (PERF.md section 5), and small against any cross-region commit
 DEFAULT_INGEST_DEADLINE_MS = 2.0
 # chain-length ceiling for the auto-tuner: 8 rounds per dispatch already
 # cuts per-round dispatch overhead 8x while keeping result lag bounded
 DEFAULT_SERVING_CHAIN_MAX = 8
 
 
-def requested_ingest_deadline_ms(
-    explicit: Optional[float] = None, config: Any = None
-) -> Optional[float]:
-    """The explicitly requested ingest deadline budget, by precedence:
-    an explicit value, then ``Config.ingest_deadline_ms``, then the
-    ``FANTOCH_INGEST_DEADLINE_MS`` env var — or None when no channel
-    requested one (callers that stay legacy-immediate unless asked, like
-    the sim and the host executor pools, branch on this)."""
-    deadline = explicit
-    if deadline is None and config is not None:
-        deadline = getattr(config, "ingest_deadline_ms", None)
-    if deadline is None:
-        raw = os.environ.get(ENV_INGEST_DEADLINE_MS)
-        if raw:
-            deadline = float(raw)
-    return None if deadline is None else float(deadline)
+def resolve_ingest_deadline_ms(config: Any) -> float:
+    """``Config.ingest_deadline_ms``, or 2 ms where the field is unset.
+    0 is a valid value: batching off, release immediately.  Callers that
+    stay immediate unless a deadline was asked for (the sim, the host
+    executor pools) test ``config.ingest_deadline_ms is not None``."""
+    deadline = config.ingest_deadline_ms
+    return DEFAULT_INGEST_DEADLINE_MS if deadline is None else float(deadline)
 
 
-def resolve_ingest_deadline_ms(
-    explicit: Optional[float] = None, config: Any = None
-) -> float:
-    """:func:`requested_ingest_deadline_ms` with the default applied
-    (2 ms).  0 is a valid resolution: batching off, release immediately
-    (the legacy dispatch-on-anything behavior)."""
-    deadline = requested_ingest_deadline_ms(explicit, config)
-    if deadline is None:
-        deadline = DEFAULT_INGEST_DEADLINE_MS
-    if deadline < 0:
-        raise ValueError(f"ingest deadline must be >= 0 ms, got {deadline}")
-    return deadline
-
-
-def resolve_ingest_target(
-    explicit: Optional[int] = None, config: Any = None
-) -> Optional[int]:
-    """Fixed size-target override (explicit > ``Config.ingest_target`` >
-    ``FANTOCH_INGEST_TARGET`` env).  None means adaptive: the batcher
-    tracks the target from the EWMA arrival rate."""
-    target = explicit
-    if target is None and config is not None:
-        target = getattr(config, "ingest_target", None)
-    if target is None:
-        raw = os.environ.get(ENV_INGEST_TARGET)
-        if raw:
-            target = int(raw)
-    if target is None:
-        return None
-    target = int(target)
-    if target < 1:
-        raise ValueError(f"ingest target must be >= 1, got {target}")
-    return target
-
-
-def resolve_serving_chain_max(
-    explicit: Optional[int] = None, config: Any = None
-) -> int:
-    """Chain-length ceiling for the auto-tuner (explicit >
-    ``Config.serving_chain_max`` > ``FANTOCH_SERVING_CHAIN_MAX`` env >
-    8).  1 disables chaining: every dispatch carries one round."""
-    chain_max = explicit
-    if chain_max is None and config is not None:
-        chain_max = getattr(config, "serving_chain_max", None)
-    if chain_max is None:
-        raw = os.environ.get(ENV_SERVING_CHAIN_MAX)
-        if raw:
-            chain_max = int(raw)
-    if chain_max is None:
-        chain_max = DEFAULT_SERVING_CHAIN_MAX
-    chain_max = int(chain_max)
-    if chain_max < 1:
-        raise ValueError(f"serving chain max must be >= 1, got {chain_max}")
-    return chain_max
+def resolve_serving_chain_max(config: Any) -> int:
+    """``Config.serving_chain_max``, or 8 where the field is unset.  1
+    disables chaining: every dispatch carries one round."""
+    chain_max = config.serving_chain_max
+    return DEFAULT_SERVING_CHAIN_MAX if chain_max is None else chain_max
 
 
 class AdaptiveIngestBatcher:

@@ -2,13 +2,13 @@
 
 Every device serving plane pays the same round shape: assemble a batch on
 the host, dispatch one fused device program (async), fetch its outputs
-(blocking), emit results.  On dispatch-dominated rigs the ~68 ms
-host<->device round trip dwarfs the ~3 ms kernel (BENCH_TPU_LATEST
-``dispatch_overhead_ms``), so the only way to keep the device busy is to
-run dispatch N rounds ahead of drain — transfer of round i+1 and the
-host-side result emit of round i-1 overlap with compute of round i, the
-nonblocking-execution move of the GraphBLAS lazy-evaluation line
-(PAPERS.md) applied to consensus serving.
+(blocking), emit results.  On the chip's own host ``enqueue`` + ``fetch``
+take 12-15 ms of a 19-25 ms open-loop round (PERF.md section 5), so the
+only way to keep the device busy is to run dispatch N rounds ahead of
+drain — transfer of round i+1 and the host-side result emit of round
+i-1 overlap with compute of round i, the nonblocking-execution move of
+the GraphBLAS lazy-evaluation line (PAPERS.md) applied to consensus
+serving.
 
 This module is the one place that machinery lives (the ROADMAP item-5
 refactor seam): drivers implement a ``dispatch(batch) -> token`` /
@@ -41,45 +41,20 @@ only aliasing hazard, and the ring's size (depth + 1) closes it.
 
 from __future__ import annotations
 
-import os
 from collections import deque
 from typing import Any, Deque, Dict, List, Optional, Sequence, Tuple
 
 import numpy as np
 
-ENV_PIPELINE_DEPTH = "FANTOCH_SERVING_PIPELINE_DEPTH"
 DEFAULT_PIPELINE_DEPTH = 1
 
 
-def requested_pipeline_depth(
-    explicit: Optional[int] = None, config: Any = None
-) -> Optional[int]:
-    """The explicitly requested serving pipeline depth, by precedence:
-    an explicit value, then ``Config.serving_pipeline_depth``, then the
-    ``FANTOCH_SERVING_PIPELINE_DEPTH`` env var — or None when no channel
-    requested one.  Any of the three spellings counts as the pipelining
-    opt-in on CPU backends (they are one knob, not three)."""
-    depth = explicit
-    if depth is None and config is not None:
-        depth = getattr(config, "serving_pipeline_depth", None)
-    if depth is None:
-        raw = os.environ.get(ENV_PIPELINE_DEPTH)
-        if raw:
-            depth = int(raw)
-    return None if depth is None else int(depth)
-
-
-def resolve_pipeline_depth(
-    explicit: Optional[int] = None, config: Any = None
-) -> int:
-    """:func:`requested_pipeline_depth` with the default applied: 1 (the
-    classic one-deep overlap) when nothing was requested."""
-    depth = requested_pipeline_depth(explicit, config)
-    if depth is None:
-        depth = DEFAULT_PIPELINE_DEPTH
-    if depth < 1:
-        raise ValueError(f"serving pipeline depth must be >= 1, got {depth}")
-    return depth
+def resolve_pipeline_depth(config: Any) -> int:
+    """``Config.serving_pipeline_depth``, or 1 (the classic one-deep
+    overlap) where the field is unset.  The range check is
+    ``Config.__post_init__``'s."""
+    depth = config.serving_pipeline_depth
+    return DEFAULT_PIPELINE_DEPTH if depth is None else depth
 
 
 class IngestRing:

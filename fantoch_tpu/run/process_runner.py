@@ -71,11 +71,7 @@ from fantoch_tpu.run.prelude import (
     Unregister,
     WarnQueue,
 )
-from fantoch_tpu.run.ingest import (
-    AdaptiveIngestBatcher,
-    requested_ingest_deadline_ms,
-    resolve_ingest_target,
-)
+from fantoch_tpu.run.ingest import AdaptiveIngestBatcher
 from fantoch_tpu.run.routing import worker_dot_index_shift
 from fantoch_tpu.run.rw import Rw, connect_with_retry, deserialize, serialize
 from fantoch_tpu.utils import key_hash, logger
@@ -1756,21 +1752,20 @@ class ProcessRuntime:
     async def _executor_task(self, position: int) -> None:
         queue = self.executor_pool.queue(position)
         executor = self.executors[position]
-        # adaptive ingest (run/ingest.py), opt-in: only when a channel
-        # requested a positive deadline (Config.ingest_deadline_ms or the
-        # env knob) does the drain hold for a fuller batch — unset keeps
-        # the legacy drain-whatever-is-queued behavior bit-for-bit
+        # adaptive ingest (run/ingest.py), opt-in: only when
+        # Config.ingest_deadline_ms is set and positive does the drain
+        # hold for a fuller batch — unset drains whatever is queued
         from time import monotonic
 
-        deadline = requested_ingest_deadline_ms(None, self.config)
+        deadline = self.config.ingest_deadline_ms
         batcher: Optional[AdaptiveIngestBatcher] = None
-        if deadline is not None and deadline > 0:
+        if deadline:
             batcher = AdaptiveIngestBatcher(
                 deadline,
                 # no device round bound on a host executor drain; 1024
                 # caps a hold at the batched-resolver sweet spot
                 max_target=1024,
-                fixed_target=resolve_ingest_target(None, self.config),
+                fixed_target=self.config.ingest_target,
             )
         while True:
             # drain the whole queue: batch-oriented executors (the batched

@@ -27,11 +27,7 @@ from fantoch_tpu.errors import FaultToleranceError, SimStalledError
 from fantoch_tpu.executor.monitor import ExecutionOrderMonitor
 from fantoch_tpu.observability.tracer import NOOP_TRACER, Tracer, edge_dot
 from fantoch_tpu.protocol.base import Protocol, ToForward, ToSend
-from fantoch_tpu.run.ingest import (
-    AdaptiveIngestBatcher,
-    requested_ingest_deadline_ms,
-    resolve_ingest_target,
-)
+from fantoch_tpu.run.ingest import AdaptiveIngestBatcher
 from fantoch_tpu.sim.faults import DEFER, DELIVER, DROP, FaultPlan, Nemesis, NemesisMark
 from fantoch_tpu.sim.schedule import Schedule
 from fantoch_tpu.sim.simulation import Simulation
@@ -233,15 +229,10 @@ class Runner:
         self._submit_counts: Dict[ProcessId, int] = {}
         self._client_latency = Histogram()
         # adaptive ingest batching (run/ingest.py), opt-in: engages only
-        # when a channel *requested* a deadline (Config field or env) and
-        # it is positive — 0 and unset both mean the legacy
-        # submit-immediately path, so the existing sim matrix is
-        # bit-for-bit unchanged.  One batcher + buffer per process, all
-        # on the virtual clock.
-        deadline = requested_ingest_deadline_ms(None, config)
-        self._ingest_deadline_ms = (
-            deadline if deadline is not None and deadline > 0 else None
-        )
+        # when Config.ingest_deadline_ms is set and positive — 0 and
+        # unset both mean the submit-immediately path.  One batcher +
+        # buffer per process, all on the virtual clock.
+        self._ingest_deadline_ms = config.ingest_deadline_ms or None
         self._ingest_batchers: Dict[ProcessId, AdaptiveIngestBatcher] = {}
         self._ingest_buffers: Dict[ProcessId, List[Command]] = {}
         self._ingest_tick_armed: Dict[ProcessId, bool] = {}
@@ -822,7 +813,7 @@ class Runner:
                 # a full protocol round has no device capacity bound here;
                 # 1024 caps a release at the batched-executor sweet spot
                 max_target=1024,
-                fixed_target=resolve_ingest_target(None, self._config),
+                fixed_target=self._config.ingest_target,
             )
             self._ingest_batchers[process_id] = batcher
         self._ingest_buffers.setdefault(process_id, []).append(cmd)

@@ -21,15 +21,6 @@ On jax >= 0.5 the guard is inactive and the regular suite already runs
 the modules; the script exits 0 without duplicating the work (pass
 ``--force`` to run the stripped copies anyway).
 
-A second leg re-runs the Pallas parity suite
-(tests/test_pallas_resolve.py) in its own pytest process with
-``FANTOCH_PALLAS=1`` forced through the environment: tier-1 already runs
-the suite with routes forced per-test, but this leg additionally proves
-the ENV opt-in path — the route every executor takes when the flag
-is set process-wide — end to end in interpret mode on the CPU pin (on a
-TPU the kernels do not lower and the suite would raise,
-ops/pallas_resolve.py).
-
 Usage: make test-device-stripped  (or: python scripts/run_device_stripped.py)
 """
 
@@ -57,28 +48,6 @@ def guarded_modules():
     return found
 
 
-def run_pallas_forced() -> int:
-    """Re-run the Pallas parity suite with FANTOCH_PALLAS=1 forced: the
-    env-route leg (executors resolve the route from the environment, not
-    a per-test override)."""
-    suite = os.path.join(REPO, "tests", "test_pallas_resolve.py")
-    if not os.path.exists(suite):
-        print(
-            "tests/test_pallas_resolve.py is gone: update "
-            "scripts/run_device_stripped.py",
-            file=sys.stderr,
-        )
-        return 2
-    return subprocess.run(
-        [
-            sys.executable, "-m", "pytest", suite, "-q",
-            "-p", "no:cacheprovider", "-p", "no:randomly",
-        ],
-        cwd=REPO,
-        env={**os.environ, "FANTOCH_PALLAS": "1"},
-    ).returncode
-
-
 def main() -> int:
     import jax
 
@@ -87,10 +56,9 @@ def main() -> int:
         print(
             f"jax {jax.__version__}: the version guard is inactive and the "
             "regular suite runs the guarded device modules — nothing to "
-            "strip (pass --force to run the stripped copies anyway); "
-            "running the FANTOCH_PALLAS=1 leg only"
+            "strip (pass --force to run the stripped copies anyway)"
         )
-        return run_pallas_forced()
+        return 0
 
     modules = guarded_modules()
     if not modules:
@@ -136,7 +104,7 @@ def main() -> int:
                 os.unlink(stripped)
             except OSError:
                 pass
-    return run_pallas_forced() or rc
+    return rc
 
 
 if __name__ == "__main__":

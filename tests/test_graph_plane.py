@@ -415,6 +415,105 @@ def test_graph_plane_nonstructure_modes_parity():
     assert run_feeds(low, feeds) == run_feeds(HOST_CFG, feeds)
 
 
+@pytest.mark.parametrize("mode", ["keyed", "general", "general_resident"])
+def test_graph_plane_step_non_pow2_capacity(mode):
+    """The plane's program at a capacity no plane builds (DevicePlane
+    rounds every window to a power of two): 48 slots, where the keyed
+    mode's residual size no longer divides the window.  Per-key chains
+    arrive shuffled over four feeds, so deps routinely point at later
+    slots or are MISSING until a later feed patches them; every mode
+    must execute exactly what a plain host fixpoint does, dependencies
+    first."""
+    import jax.numpy as jnp
+
+    from fantoch_tpu.ops.graph_resolve import (
+        MISSING,
+        TERMINAL,
+        resolve_graph_plane_step,
+    )
+
+    cap, width, feed_rows = 48, 4, 10
+    rng = random.Random(48)
+    chains = [(k, i) for k in range(4) for i in range(10)]
+    rng.shuffle(chains)
+    state = (
+        jnp.full((cap, width), TERMINAL, jnp.int32),
+        jnp.zeros((cap,), jnp.int32),
+        jnp.zeros((cap,), jnp.int32),
+        jnp.zeros((cap,), jnp.int32),
+        jnp.zeros((cap,), jnp.bool_),
+        jnp.zeros((cap,), jnp.bool_),
+    )
+    # the host twin of the resident window
+    deps = np.full((cap, width), TERMINAL, np.int32)
+    occ = np.zeros(cap, bool)
+    done = np.zeros(cap, bool)
+    slot_of, waiting = {}, {}
+    for at in range(0, len(chains), feed_rows):
+        u_row = np.full(16, cap, np.int32)
+        u_deps = np.full((16, width), TERMINAL, np.int32)
+        u_key = np.zeros(16, np.int32)
+        u_src = np.zeros(16, np.int32)
+        u_seq = np.zeros(16, np.int32)
+        patches = []
+        for i, (k, pos) in enumerate(chains[at : at + feed_rows]):
+            slot = at + i
+            slot_of[(k, pos)] = slot
+            u_row[i], u_key[i], u_src[i], u_seq[i] = slot, k, k + 1, pos + 1
+            if pos:
+                u_deps[i, 0] = slot_of.get((k, pos - 1), MISSING)
+                if u_deps[i, 0] == MISSING:
+                    waiting[(k, pos - 1)] = slot
+            if (k, pos) in waiting:
+                patches.append((waiting.pop((k, pos)), slot))
+        p_row = np.full(16, cap, np.int32)
+        p_col = np.zeros(16, np.int32)
+        p_val = np.full(16, TERMINAL, np.int32)
+        for j, (row, val) in enumerate(patches):
+            p_row[j], p_val[j] = row, val
+        e_row = np.full(4, cap, np.int32)
+
+        # host fixpoint: a pending row executes once every dep cell is
+        # TERMINAL or points at an executed slot; MISSING blocks
+        live = u_row < cap
+        deps[u_row[live]] = u_deps[live]
+        occ[u_row[live]] = True
+        for row, val in patches:
+            # a row installed by this same feed takes its patch too
+            deps[row, 0] = val
+        want = done.copy()
+        changed = True
+        while changed:
+            ready = (
+                (deps == TERMINAL) | ((deps >= 0) & want[np.maximum(deps, 0)])
+            ).all(axis=1)
+            new = occ & ready & ~want
+            changed = bool(new.any())
+            want |= new
+
+        out = resolve_graph_plane_step(
+            *state,
+            *(jnp.asarray(a) for a in (
+                u_row, u_deps, u_key, u_src, u_seq, p_row, p_col, p_val, e_row,
+            )),
+            mode=mode,
+        )
+        state = tuple(out[:6])
+        newly = np.asarray(out.newly)
+        assert np.array_equal(newly, want & ~done), (mode, at)
+        assert np.array_equal(np.asarray(out.executed), want)
+        assert not np.asarray(out.stuck).any()
+        order = np.asarray(out.order)
+        assert sorted(order.tolist()) == list(range(cap))
+        emitted = [r for r in order.tolist() if newly[r]]
+        place = {r: i for i, r in enumerate(emitted)}
+        for r in emitted:
+            d = int(deps[r, 0])
+            assert d < 0 or done[d] or place[d] < place[r], (mode, r, d)
+        done = want
+    assert done[: len(chains)].all() and not waiting
+
+
 def test_graph_plane_monitor_watchdog():
     """The liveness watchdog on the plane: overdue missing dots surface
     for nudge_recovery, a typed StalledExecutionError fires past
@@ -479,19 +578,17 @@ def test_graph_plane_device_counters_seam():
 
 
 def test_graph_kernel_threshold_precedence(monkeypatch):
-    """The unified kernel-size gate: explicit config beats the env var
-    beats the built-in 4096 (the Config.table_kernel_threshold pattern,
-    resolved through the shared device_plane.resolve_threshold)."""
-    monkeypatch.delenv("FANTOCH_GRAPH_KERNEL_THRESHOLD", raising=False)
-    g = BatchedDependencyGraph(1, SHARD, HOST_CFG)
-    assert g._structure_threshold == 4096
+    """The kernel-size gate: Config.graph_kernel_threshold, else the
+    built-in 4096; the plane it is handed to holds the same value, and
+    the environment moves neither."""
     monkeypatch.setenv("FANTOCH_GRAPH_KERNEL_THRESHOLD", "123")
     g = BatchedDependencyGraph(1, SHARD, HOST_CFG)
-    assert g._structure_threshold == 123
+    assert g._structure_threshold == 4096
     g = BatchedDependencyGraph(
-        1, SHARD, HOST_CFG.with_(graph_kernel_threshold=77)
+        1, SHARD, PLANE_CFG.with_(graph_kernel_threshold=77)
     )
     assert g._structure_threshold == 77
+    assert g._plane._structure_threshold == 77
 
 
 def test_graph_threshold_both_branches_agree():

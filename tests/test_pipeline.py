@@ -9,7 +9,6 @@ import pytest
 
 from fantoch_tpu.run.pipeline import (
     DEFAULT_PIPELINE_DEPTH,
-    ENV_PIPELINE_DEPTH,
     IngestRing,
     PipelineCore,
     resolve_pipeline_depth,
@@ -43,24 +42,16 @@ class _FakeDriver(PipelineCore):
 
 
 def test_resolve_depth_precedence(monkeypatch):
-    monkeypatch.delenv(ENV_PIPELINE_DEPTH, raising=False)
-    assert resolve_pipeline_depth() == DEFAULT_PIPELINE_DEPTH == 1
-    monkeypatch.setenv(ENV_PIPELINE_DEPTH, "3")
-    assert resolve_pipeline_depth() == 3
+    """One home: the Config field, else the module's default; the
+    environment is not a rung."""
+    from fantoch_tpu.core import Config
 
-    class Cfg:
-        serving_pipeline_depth = 2
-
-    # config beats env; explicit beats config
-    assert resolve_pipeline_depth(None, Cfg()) == 2
-    assert resolve_pipeline_depth(5, Cfg()) == 5
-
-    class CfgNone:
-        serving_pipeline_depth = None
-
-    assert resolve_pipeline_depth(None, CfgNone()) == 3  # falls to env
-    with pytest.raises(ValueError):
-        resolve_pipeline_depth(0)
+    monkeypatch.setenv("FANTOCH_SERVING_PIPELINE_DEPTH", "3")
+    assert resolve_pipeline_depth(Config(3, 1)) == DEFAULT_PIPELINE_DEPTH == 1
+    assert resolve_pipeline_depth(Config(3, 1, serving_pipeline_depth=2)) == 2
+    # the field is the only argument: no explicit rung beside it
+    with pytest.raises(TypeError):
+        resolve_pipeline_depth(5, Config(3, 1))
 
 
 def test_config_serving_pipeline_depth_validates():
@@ -201,59 +192,43 @@ def test_step_chained_parity_with_unbatched(depth):
 
 
 def test_ingest_knob_precedence(monkeypatch):
-    """The three r16 knobs follow the one-knob rule: explicit > Config
-    field > env var > default, any spelling the same knob."""
-    from fantoch_tpu.run.ingest import (
-        DEFAULT_INGEST_DEADLINE_MS,
-        DEFAULT_SERVING_CHAIN_MAX,
-        ENV_INGEST_DEADLINE_MS,
-        ENV_INGEST_TARGET,
-        ENV_SERVING_CHAIN_MAX,
-        requested_ingest_deadline_ms,
-        resolve_ingest_deadline_ms,
-        resolve_ingest_target,
-        resolve_serving_chain_max,
+    """The three r16 knobs have one home each: the Config field, read
+    with the module's default; the environment is not a rung."""
+    from fantoch_tpu.core import Config
+    from fantoch_tpu.run import ingest
+
+    monkeypatch.setenv("FANTOCH_INGEST_DEADLINE_MS", "7.5")
+    monkeypatch.setenv("FANTOCH_SERVING_CHAIN_MAX", "4")
+
+    # unset fields: the defaults (the opt-in surfaces test the field
+    # itself for None and stay immediate)
+    unset = Config(3, 1)
+    assert unset.ingest_deadline_ms is None
+    assert (
+        ingest.resolve_ingest_deadline_ms(unset)
+        == ingest.DEFAULT_INGEST_DEADLINE_MS == 2.0
+    )
+    assert (
+        ingest.resolve_serving_chain_max(unset)
+        == ingest.DEFAULT_SERVING_CHAIN_MAX == 8
     )
 
-    for var in (ENV_INGEST_DEADLINE_MS, ENV_INGEST_TARGET,
-                ENV_SERVING_CHAIN_MAX):
-        monkeypatch.delenv(var, raising=False)
+    cfg = Config(3, 1, ingest_deadline_ms=3, serving_chain_max=2)
+    deadline = ingest.resolve_ingest_deadline_ms(cfg)
+    assert deadline == 3.0 and isinstance(deadline, float)
+    assert ingest.resolve_serving_chain_max(cfg) == 2
+    # 0 is a valid deadline (batching off), not "unset"
+    assert ingest.resolve_ingest_deadline_ms(
+        Config(3, 1, ingest_deadline_ms=0.0)
+    ) == 0.0
 
-    # no channel set: requested is None (opt-in surfaces stay legacy),
-    # resolved falls to the defaults
-    assert requested_ingest_deadline_ms() is None
-    assert resolve_ingest_deadline_ms() == DEFAULT_INGEST_DEADLINE_MS
-    assert resolve_ingest_target() is None
-    assert resolve_serving_chain_max() == DEFAULT_SERVING_CHAIN_MAX
-
-    monkeypatch.setenv(ENV_INGEST_DEADLINE_MS, "7.5")
-    monkeypatch.setenv(ENV_INGEST_TARGET, "32")
-    monkeypatch.setenv(ENV_SERVING_CHAIN_MAX, "4")
-    assert requested_ingest_deadline_ms() == 7.5
-    assert resolve_ingest_target() == 32
-    assert resolve_serving_chain_max() == 4
-
-    class Cfg:
-        ingest_deadline_ms = 3.0
-        ingest_target = 16
-        serving_chain_max = 2
-
-    # config beats env; explicit beats config
-    assert requested_ingest_deadline_ms(None, Cfg()) == 3.0
-    assert requested_ingest_deadline_ms(1.0, Cfg()) == 1.0
-    assert resolve_ingest_target(None, Cfg()) == 16
-    assert resolve_ingest_target(8, Cfg()) == 8
-    assert resolve_serving_chain_max(None, Cfg()) == 2
-    assert resolve_serving_chain_max(6, Cfg()) == 6
-
-    # 0 is a valid deadline resolution (batching off), negatives are not
-    assert resolve_ingest_deadline_ms(0.0) == 0.0
-    with pytest.raises(ValueError):
-        resolve_ingest_deadline_ms(-1.0)
-    with pytest.raises(ValueError):
-        resolve_ingest_target(0)
-    with pytest.raises(ValueError):
-        resolve_serving_chain_max(0)
+    # the rungs that went: no requested_* twin, no target resolver, no
+    # variable names, no second copy of a range check (Config's is the
+    # one, test_config_ingest_knobs_validate)
+    for name in ("requested_ingest_deadline_ms", "resolve_ingest_target",
+                 "ENV_INGEST_DEADLINE_MS", "ENV_INGEST_TARGET",
+                 "ENV_SERVING_CHAIN_MAX"):
+        assert not hasattr(ingest, name), name
 
 
 def test_config_ingest_knobs_validate():

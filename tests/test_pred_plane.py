@@ -509,14 +509,14 @@ def test_table_plane_on_base_keeps_oracle_behavior():
     assert restored.resident_uploads - uploads == 1
 
 
-def test_resolve_threshold_precedence(monkeypatch):
-    """The shared kernel-threshold switch: explicit beats env beats
-    default (extracted from the table executor for every plane)."""
-    monkeypatch.delenv("FANTOCH_TEST_THRESHOLD", raising=False)
-    assert resolve_threshold(None, "FANTOCH_TEST_THRESHOLD", 7) == 7
-    monkeypatch.setenv("FANTOCH_TEST_THRESHOLD", "11")
-    assert resolve_threshold(None, "FANTOCH_TEST_THRESHOLD", 7) == 11
-    assert resolve_threshold(13, "FANTOCH_TEST_THRESHOLD", 7) == 13
+def test_resolve_threshold_precedence():
+    """The shared kernel-threshold switch: the Config field where it is
+    set, else the plane's default; there is no variable to name."""
+    assert resolve_threshold(None, 7) == 7
+    assert resolve_threshold(13, 7) == 13
+    assert resolve_threshold(0, 7) == 0  # 0 is a value, not "unset"
+    with pytest.raises(TypeError):
+        resolve_threshold(None, "FANTOCH_TEST_THRESHOLD", 7)
 
 
 # ---------------------------------------------------------------------------

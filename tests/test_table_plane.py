@@ -155,12 +155,12 @@ def test_config_rejects_plane_with_realtime_clocks():
 
 def test_kernel_threshold_config_knob_and_env(monkeypatch):
     base = Config(3, 1)
-    assert TableExecutor(1, SHARD, base)._kernel_threshold == (1 << 20)
     explicit = Config(3, 1, table_kernel_threshold=123)
+    assert TableExecutor(1, SHARD, base)._kernel_threshold == (1 << 20)
     assert TableExecutor(1, SHARD, explicit)._kernel_threshold == 123
+    # the field is the one home: the environment moves neither
     monkeypatch.setenv("FANTOCH_TABLE_KERNEL_THRESHOLD", "77")
-    assert TableExecutor(1, SHARD, base)._kernel_threshold == 77
-    # an explicit Config value beats the env override
+    assert TableExecutor(1, SHARD, base)._kernel_threshold == (1 << 20)
     assert TableExecutor(1, SHARD, explicit)._kernel_threshold == 123
 
 
