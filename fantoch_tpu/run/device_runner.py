@@ -59,6 +59,7 @@ from fantoch_tpu.core.ids import ClientId, Dot, ProcessId, Rifl, ShardId
 from fantoch_tpu.core.kvs import KVStore
 from fantoch_tpu.executor.aggregate import AggregatePending
 from fantoch_tpu.executor.base import ExecutorResult
+from fantoch_tpu.run.collector import CollectorSchedule
 from fantoch_tpu.run.ingest import (
     AdaptiveIngestBatcher,
     ChainAutoTuner,
@@ -1846,7 +1847,9 @@ class DeviceRuntime:
         self._loop_lag_hwm_ms = 0.0
         self._loop_stall_ms = 0.0
         self._loop_stalls = 0
-        self._gc_started = 0  # monotonic_ns of the full collection under way
+        # full collections of the cyclic collector run on the driver
+        # task's schedule, not on the allocator's (run/collector.py)
+        self._collector = CollectorSchedule()
         from fantoch_tpu.parallel.mesh_step import shards_on_devices
 
         self._shards_on_device = shards_on_devices(
@@ -1967,7 +1970,6 @@ class DeviceRuntime:
         self._servers = [server]
         self.spawn(self._driver_task())
         self.spawn(self._lag_task())
-        gc.callbacks.append(self._on_gc)
         if self.metrics_file is not None or self.telemetry is not None:
             self.spawn(self._telemetry_task())
         if self.metrics_port is not None:
@@ -1981,6 +1983,10 @@ class DeviceRuntime:
             )
             await self.metrics_server.start()
             self.metrics_port = self.metrics_server.port
+        # last, with everything start-up built alive and no client served
+        # yet: jax, the programs, the driver's tables leave the collector
+        self._collector.take_over()
+        gc.callbacks.append(self._on_gc)
 
     def _publish_tallies(self) -> None:
         """Called on the event-loop thread between device rounds (never
@@ -2036,6 +2042,11 @@ class DeviceRuntime:
             "loop_lag_hwm_ms": round(self._loop_lag_hwm_ms, 3),
             "loop_stall_ms": round(self._loop_stall_ms, 3),
             "loop_stalls": self._loop_stalls,
+            # the cyclic collector under the driver's schedule: beside
+            # stage_gc_ms / _n, what was frozen out of it at start-up, the
+            # full collections the driver ran, those it did not ask for
+            # (expected 0) and the objects they found unreachable
+            **self._collector.counters(),
             # adaptive ingest batcher tallies (run/ingest.py)
             **self._batcher.counters(),
             "precompiled_programs": d.precompiled_programs,
@@ -2094,6 +2105,7 @@ class DeviceRuntime:
         "queue_capacity", "device_idle_frac", "device_pipeline_depth",
         "dispatch_fill_frac", "serving_chain_len", "ingest_target",
         "ingest_rate_per_s", "loop_lag_hwm_ms", "precompiled_programs",
+        "gc_frozen_objects",
     })
 
     def telemetry_sample(self):
@@ -2139,15 +2151,12 @@ class DeviceRuntime:
 
     def _on_gc(self, phase: str, info: Dict[str, int]) -> None:
         """``gc.callbacks`` hook: a full (generation 2) collection stops
-        whichever thread allocates, under the GIL; it goes into the ring
-        as a ``gc`` entry so a late loop can be laid beside it."""
-        if info["generation"] < 2:
-            return
-        if phase == "start":
-            self._gc_started = monotonic_ns()
-        elif self._gc_started:
-            self.stages.record("gc", self._gc_started, monotonic_ns())
-            self._gc_started = 0
+        every thread, under the GIL; it goes into the ring as a ``gc``
+        entry so a late loop can be laid beside it, and its duration is
+        what the collector's schedule reads."""
+        span = self._collector.note(phase, info)
+        if span is not None:
+            self.stages.record("gc", *span)
 
     async def stop(self) -> None:
         if self.metrics_server is not None:
@@ -2158,6 +2167,8 @@ class DeviceRuntime:
         if self._on_gc in gc.callbacks:
             gc.callbacks.remove(self._on_gc)
         self.emit_final()
+        # the process's collector as start() found it
+        self._collector.hand_back()
         if self.telemetry is not None:
             self.telemetry.close()
         self.tracer.close()
@@ -2389,6 +2400,11 @@ class DeviceRuntime:
         tuner = self._chain_tuner
         idle_rounds = 0  # empty-input rounds yielding no results
         while True:
+            # between rounds, where no step runs on the pool thread (the
+            # pause then stops one thread, not two, and the pipelined
+            # round's program stays on the device), and before an idle
+            # wait, where no command is alive to be traversed
+            self._collector.run_if_due()
             # a round is named by the number of the dispatch it makes
             round_id = driver.dispatches + 1
             if not self._submit_queue and can_pipeline and driver.has_outstanding:
