@@ -1,7 +1,9 @@
 """The benchmark's contract as rules: each is a function of a tree (its
 ``BENCHMARK.json`` and the files it names) and holds for any later cell, not
-for today's four.  ``test_benchmark_contract.py`` applies them to the tree
-itself, and to a copy to which the next PR's cell was added as new files."""
+for today's five.  What the tests say of an entry the tree has, they say by
+its name and never by its place (``HELD_BY_NAME``), so the lists can grow.
+The tests apply all of them to the tree itself, and to copies to which a
+later PR's cell was added as new files and appended entries (``next_cell.py``)."""
 
 import json
 import os
@@ -117,7 +119,9 @@ def configurations_pass_their_shape_and_mark_what_they_assume(root):
         assert "f=1" not in entry["source"] and "1 key" not in entry["source"]
     for traffic in {cell["traffic"] for cell in spec["workloads"]}:
         mix = run._load(os.path.join(root, spec["paths"][0], "traffic", traffic + ".json"))
-        assert {"key_gen.coefficient", "clients", "read_share"} <= set(mix["assumed"])
+        # every parameter of the key generator but the key space, which the source names
+        drawn_by = {f"key_gen.{name}" for name in mix["key_gen"]} - {"key_gen.kind", "key_gen.keys_per_shard"}
+        assert drawn_by | {"clients", "read_share"} <= set(mix["assumed"])
         if mix["loop"] == "open":
             assert "arrivals" in mix["assumed"]
     for cell in spec["workloads"]:  # the mix sends what the deployment is laid out for
@@ -158,7 +162,30 @@ def the_harness_holds_no_cell_protocol_or_metric_name(root):
         assert word not in source, word
 
 
+# --- what the tree has, held by name ------------------------------------------------
+
+FOUR_CHIP_CONFIG, FOUR_CHIP_TRAFFIC = "tempo_n5_4shard_2key", "ycsbt_zipf07_sat"
+FOUR_CHIP_CELL = f"{FOUR_CHIP_CONFIG}.{FOUR_CHIP_TRAFFIC}"
+FOUR_CHIP_FIVE = ["cross_shard_share.sat", "shard_replies_per_cmd.sat", "collective_share.sat",
+                  "precompile_ms", "device_round_hbm_share.sat"]
+
+
+def the_four_chip_cell_is_there_once_with_its_five_metrics_in_their_order(root):
+    """PR 27's entries, wherever they stand: the configuration and the cell
+    once each, the cell among those on four chips (how many of those there may
+    be is ``the_file_has_the_contracts_keys_and_forms``'s to hold), the five
+    per-layer metrics all there, in the order they were appended in."""
+    spec = bench(root)
+    assert [c["name"] for c in spec["configs"]].count(FOUR_CHIP_CONFIG) == 1
+    ours = [c for c in spec["workloads"] if c["name"] == FOUR_CHIP_CELL]
+    assert len(ours) == 1 and ours[0]["chips"] == 4
+    assert (ours[0]["config"], ours[0]["traffic"]) == (FOUR_CHIP_CONFIG, FOUR_CHIP_TRAFFIC)
+    names = [m["name"] for m in spec["per_layer"]]
+    assert [name for name in names if name in FOUR_CHIP_FIVE] == FOUR_CHIP_FIVE
+
+
 RULES = [the_file_has_the_contracts_keys_and_forms,
          configurations_pass_their_shape_and_mark_what_they_assume,
          per_layer_metrics_follow_what_they_move,
          the_harness_holds_no_cell_protocol_or_metric_name]
+HELD_BY_NAME = [the_four_chip_cell_is_there_once_with_its_five_metrics_in_their_order]

@@ -1,7 +1,8 @@
 """BENCHMARK.json against the contract's form, and against the files it
 names.  The rules are functions of a tree (``contract_rules.py``): here they
-are applied to the tree itself, and to a copy of it to which the next PR's
-cell was added as new files and list entries only."""
+are applied to the tree itself, and to a copy of it for each cell
+``next_cell.py`` describes, added there as new files and appended list
+entries only."""
 
 import json
 import os
@@ -45,37 +46,63 @@ def _files(root):
             if not os.path.islink(path)}
 
 
-def test_the_rules_take_the_next_prs_four_chip_sharded_two_key_cell(tmp_path):
-    """The cell `PERF.md` s7 keeps first in line, as the PR that adds it will:
-    four chips, `--shard-count 4 --device-key-width 2`, a `kv_multi` mix, its
-    name on `goodput_cmds_s`, a per-layer metric of its own.  Every rule holds
-    for the tree with it, and no file that was there is changed."""
-    root = next_cell.copy_tree(str(tmp_path))
-    before = _files(root)
-    spec_before = rules.bench(root)
-    cell = next_cell.add_next_cell(root)
-    for rule in rules.RULES:
-        rule(root)
-    for workload in rules.cells(root):
-        rules.a_cell_finds_its_files_by_name(root, workload)
-    assert cell in rules.cells(root) and len(rules.cells(root)) == len(rules.cells(ROOT)) + 1
-    loaded = run.load_cell(root, cell)
+def _the_sharded_cell(root, loaded):
+    """Four chips, `--shard-count 4 --device-key-width 2`, a `kv_multi` mix,
+    a per-layer metric of its own that is reported in that cell alone."""
     assert loaded["chips"] == 4 and loaded["mix"]["generator"] == "kv_multi"
     flags = rules.flags_of(loaded["config"])
     assert flags["--shard-count"] == "4" and flags["--device-key-width"] == "2"
     assert next_cell.METRIC in {m["name"] for m in loaded["per_layer"]}
-    assert "goodput_cmds_s" in {m["name"] for m in loaded["end_to_end"]}
-    # the metric of its own is reported in that cell alone
     for other in rules.cells(ROOT):
         assert next_cell.METRIC not in {m["name"] for m in run.load_cell(root, other)["per_layer"]}
+
+
+def _the_conflict_cell(root, loaded):
+    """One chip, the tree's own EPaxos configuration as it is, the saturated
+    zipf mix with another key generator; it reports what that cell reports."""
+    beside = run.load_cell(root, "epaxos_n5_1m.zipf_sat")
+    assert loaded["chips"] == 1 and loaded["config"] == beside["config"]
+    assert loaded["mix"]["key_gen"] == {"kind": "conflict_rate", "rate": 50}
+    assert "key_gen.rate" in loaded["mix"]["assumed"]
+    for key in set(beside["mix"]) - {"key_gen", "assumed", "note"}:
+        assert loaded["mix"][key] == beside["mix"][key], key
+    for section in ("end_to_end", "per_layer"):
+        assert [m["name"] for m in loaded[section]] == [m["name"] for m in beside[section]]
+
+
+@pytest.mark.parametrize("cell,added,its_own", [
+    (next_cell.CELL, {"configs": 1, "workloads": 1, "per_layer": 1, "files": 3}, _the_sharded_cell),
+    (next_cell.CONFLICT_CELL, {"configs": 0, "workloads": 1, "per_layer": 0, "files": 1},
+     _the_conflict_cell),
+], ids=[next_cell.CELL, next_cell.CONFLICT_CELL])
+def test_the_rules_take_the_next_prs_four_chip_sharded_two_key_cell(tmp_path, cell, added, its_own):
+    """A cell `PERF.md` s7 keeps in line, as the PR that adds it will (the
+    name is the test's first: PR 26's sharded cell was its one case, s7's #1
+    of today, `epaxos_n5_1m.conflict50_sat`, is its second).  Every rule and
+    everything held by name holds for the tree with it, its name is on
+    `goodput_cmds_s`, and no file that was there is changed."""
+    root = next_cell.copy_tree(str(tmp_path))
+    before = _files(root)
+    spec_before = rules.bench(root)
+    assert next_cell.CELLS[cell](root) == cell
+    for rule in rules.RULES + rules.HELD_BY_NAME:
+        rule(root)
+    for workload in rules.cells(root):
+        rules.a_cell_finds_its_files_by_name(root, workload)
+    assert rules.cells(root) == rules.cells(ROOT) + [cell]
+    loaded = run.load_cell(root, cell)
+    assert "goodput_cmds_s" in {m["name"] for m in loaded["end_to_end"]}
+    its_own(root, loaded)
     # new files and appended entries only
     after = _files(root)
     changed = {path for path, content in before.items() if after.get(path) != content}
     assert changed == {os.path.join(root, "BENCHMARK.json")}
-    assert len(after) == len(before) + 3  # a configuration, a mix, a layer metric
+    assert len(after) == len(before) + added["files"]
     spec = rules.bench(root)
     for section in ("configs", "workloads", "per_layer"):
-        assert spec[section][:-1] == spec_before[section]
+        kept = len(spec_before[section])
+        assert spec[section][:kept] == spec_before[section]
+        assert len(spec[section]) == kept + added[section]
     for now, then in zip(spec["end_to_end"], spec_before["end_to_end"]):
         assert {**now, "workloads": None} == {**then, "workloads": None}
         assert now.get("workloads", [])[: len(then.get("workloads", []))] == then.get("workloads", [])

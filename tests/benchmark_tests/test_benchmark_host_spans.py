@@ -158,8 +158,8 @@ NEW = ["session_us_per_cmd", "queue_wait_ms", "gate_wait_ms", "assemble_us_per_c
        "loop_stall_ms", "round_p95_ms", "idle_unnamed_share", "idle_off_step_share"]
 
 
-def _bench():
-    with open(os.path.join(ROOT, "BENCHMARK.json")) as fh:
+def _bench(root=ROOT):
+    with open(os.path.join(root, "BENCHMARK.json")) as fh:
         return json.load(fh)
 
 
@@ -186,11 +186,12 @@ def test_each_round_stage_metrics_file_says_what_its_entry_says(name):
         "unnamed_share", "off_step_share"} <= published
 
 
-def test_the_new_entries_come_after_those_the_benchmark_had():
+def test_the_new_entries_come_after_those_the_benchmark_had(root):
     """Entries are appended: what PR 23 had comes before what PR 24 added,
-    and that before anything later (a later PR adds at the end; retiring
-    an entry, as PR 26 did, moves none of the others)."""
-    names = [m["name"] for m in _bench()["per_layer"]]
+    and that before anything later (a later PR adds at the end, `root`'s
+    copies among them; retiring an entry, as PR 26 did, moves none of the
+    others)."""
+    names = [m["name"] for m in _bench(root)["per_layer"]]
     first, second = names.index("slow_path_share.sat"), names.index("session_us_per_cmd.open")
     assert first + 1 == second and names[second:second + 25] == [
         base + kind for base in NEW for kind in (".open", ".sat") if base + kind != "gate_wait_ms.sat"]

@@ -1,9 +1,10 @@
 """The tree's own four-chip cell, `tempo_n5_4shard_2key.ycsbt_zipf07_sat`
 (PR 27): it loads and finds its files, carries its five metrics and no other
-cell carries the four that list it, its two new readers read a recorded
-capture and a shape, and the cell runs small on the CPU over four forced
-devices, one shard a device, as `test_benchmark_e2e_multi.py` runs the copy
-that `next_cell.py` makes."""
+cell carries the four that list it (said of `root`, `conftest.py`: of the
+tree and of its copies with a later PR's entries appended, so by name and not
+by place), its two new readers read a recorded capture and a shape, and the
+cell runs small on the CPU over four forced devices, one shard a device, as
+`test_benchmark_e2e_multi.py` runs the copy that `next_cell.py` makes."""
 
 import gzip
 import json
@@ -17,18 +18,18 @@ from tests.benchmark_tests import contract_rules as rules
 from tests.benchmark_tests import next_cell
 
 ROOT = run.ROOT
-CONFIG, TRAFFIC = "tempo_n5_4shard_2key", "ycsbt_zipf07_sat"
-CELL = f"{CONFIG}.{TRAFFIC}"
-LISTED = {"cross_shard_share.sat", "shard_replies_per_cmd.sat", "collective_share.sat",
-          "device_round_hbm_share.sat"}
+CELL = rules.FOUR_CHIP_CELL
+LISTED = set(rules.FOUR_CHIP_FIVE) - {"precompile_ms"}
 RECORDED = os.path.join(ROOT, "benchmark", "testdata", "trace_small.xplane.pb.gz")
 
 
-def test_the_cell_loads_finds_its_files_and_is_the_one_cell_on_four_chips():
-    rules.a_cell_finds_its_files_by_name(ROOT, CELL)
-    cell = run.load_cell(ROOT, CELL)
+def test_the_cell_loads_finds_its_files_and_is_the_one_cell_on_four_chips(root):
+    """(The name is the test's first: it is a cell on four chips, once; how
+    many such cells a tree may have is the contract rules' to hold.)"""
+    rules.a_cell_finds_its_files_by_name(root, CELL)
+    cell = run.load_cell(root, CELL)
     assert cell["chips"] == 4 and cell["mix"]["generator"] == "kv_multi"
-    assert [c["name"] for c in rules.bench(ROOT)["workloads"] if c["chips"] == 4] == [CELL]
+    assert CELL in [c["name"] for c in rules.bench(root)["workloads"] if c["chips"] == 4]
     flags = rules.flags_of(cell["config"])
     assert flags == {"--protocol": "newt", "-n": "5", "-f": "1", "--shard-count": "4",
                      "--device-key-width": "2", "--device-key-buckets": "4194304",
@@ -64,24 +65,22 @@ def test_the_cell_is_what_next_cell_writes_under_another_name(tmp_path):
     assert sum(c["chips"] == 4 for c in rules.bench(root)["workloads"]) == 2  # 2 of 6: allowed
 
 
-def test_the_cell_carries_its_five_metrics_and_no_other_cell_the_four_that_list_it():
-    spec = rules.bench(ROOT)
-    ours = {m["name"] for m in run.load_cell(ROOT, CELL)["per_layer"]}
+def test_the_cell_carries_its_five_metrics_and_no_other_cell_the_four_that_list_it(root):
+    spec = rules.bench(root)
+    ours = {m["name"] for m in run.load_cell(root, CELL)["per_layer"]}
     assert LISTED | {"precompile_ms"} <= ours
     entries = {m["name"]: m for m in spec["per_layer"]}
     for name in LISTED:
         assert entries[name]["workloads"] == [CELL] and entries[name]["moves"] == "goodput_cmds_s"
     assert "workloads" not in entries["precompile_ms"] and entries["precompile_ms"]["moves"] == "setup_s"
-    for other in rules.cells(ROOT):
-        carried = {m["name"] for m in run.load_cell(ROOT, other)["per_layer"]}
+    for other in rules.cells(root):
+        carried = {m["name"] for m in run.load_cell(root, other)["per_layer"]}
         assert "precompile_ms" in carried  # set-up is every cell's
         if other != CELL:
             assert not carried & LISTED
-    # appended, all of them: the five are the list's last
-    assert [m["name"] for m in spec["per_layer"][-5:]] == [
-        "cross_shard_share.sat", "shard_replies_per_cmd.sat", "collective_share.sat",
-        "precompile_ms", "device_round_hbm_share.sat"]
-    assert spec["configs"][-1]["name"] == CONFIG and spec["workloads"][-1]["name"] == CELL
+    # all five are there, in the order they were appended in, wherever they stand;
+    # the configuration and the cell are there once
+    rules.the_four_chip_cell_is_there_once_with_its_five_metrics_in_their_order(root)
 
 
 # --- the two readers that are new ---------------------------------------------------
