@@ -89,6 +89,10 @@ class KVStore:
     def digest(self) -> Optional["ExecutionDigest"]:
         return self._digest
 
+    def __len__(self) -> int:
+        """Records held: keys with a value (the snapshot's ``store_records``)."""
+        return len(self._store)
+
     def execute(self, key: Key, op: KVOp, rifl: "Rifl") -> KVOpResult:
         """Execute op on key, recording it in the monitor if enabled.
 

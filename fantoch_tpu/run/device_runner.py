@@ -1373,11 +1373,16 @@ class _DeviceClientSession:
         # rifl -> (key -> owning shard), alive while results are pending
         self._key_shard: Dict[Rifl, Dict[str, ShardId]] = {}
         self._shards_left: Dict[Rifl, int] = {}
+        # the read-only commands among them: what their replies carry is
+        # counted apart (gets_replied, get_value_bytes)
+        self._reads: set = set()
         self.client_ids: List[ClientId] = []
         self._flush_needed = asyncio.Event()
 
     def track(self, cmd: Command) -> None:
         """Register a submitted command for result aggregation."""
+        if cmd.read_only:
+            self._reads.add(cmd.rifl)
         single = cmd.single_key()
         if single is not None:
             sid, key = single
@@ -1405,10 +1410,11 @@ class _DeviceClientSession:
         key_shard = self._key_shard
         shards_left = self._shards_left
         pending_by_shard = self.pending_by_shard
+        reads = self._reads
         tracer = runtime.tracer
         tracing = tracer.enabled
         frames: List[bytes] = []
-        answered = completed = multi_shard = 0
+        answered = completed = multi_shard = gets = get_bytes = 0
         for result in results:
             rifl = result.rifl
             shards = key_shard.get(rifl)
@@ -1419,6 +1425,15 @@ class _DeviceClientSession:
                     answered += 1
                 continue
             done = pending_by_shard[shards[result.key]].add_executor_result(result)
+            is_read = bool(reads) and rifl in reads
+            if is_read:
+                for value in result.op_results:
+                    if value is not None:
+                        # what its frame carries: UTF-8, a byte a letter
+                        # where all are ASCII (a flag of the string)
+                        get_bytes += (
+                            len(value) if value.isascii() else len(value.encode())
+                        )
             if done is None:
                 continue
             if tracing:
@@ -1432,6 +1447,9 @@ class _DeviceClientSession:
                 del key_shard[rifl], shards_left[rifl], rifl_sessions[rifl]
                 answered += 1
                 completed += 1
+                if is_read:
+                    reads.discard(rifl)
+                    gets += 1
                 if len(shards) > 1 and len(set(shards.values())) > 1:
                     multi_shard += 1
         if frames:
@@ -1442,6 +1460,8 @@ class _DeviceClientSession:
             runtime._shard_replies += len(frames)
             runtime._commands_completed += completed
             runtime._multi_shard_completed += multi_shard
+            runtime._gets_replied += gets
+            runtime._get_value_bytes += get_bytes
             self._flush_needed.set()
         return answered
 
@@ -1840,6 +1860,8 @@ class DeviceRuntime:
         self._shard_replies = 0  # CommandResult frames: one per touched shard
         self._commands_completed = 0  # a command's last shard replied
         self._multi_shard_completed = 0  # ... of a command over several shards
+        self._gets_replied = 0  # read-only commands among the completed
+        self._get_value_bytes = 0  # bytes (UTF-8) of the values their replies carried
         self._admit_ns = 0  # a read's messages decoded -> its commands pushed
         self._queue_wait_ms = 0.0  # sum over released commands, ring time
         self._queue_released = 0
@@ -2037,6 +2059,11 @@ class DeviceRuntime:
             "shard_replies": self._shard_replies,
             "commands_completed": self._commands_completed,
             "multi_shard_completed": self._multi_shard_completed,
+            # reads: read-only commands completed, the bytes of the values
+            # their replies carried, and the records the store holds now
+            "gets_replied": self._gets_replied,
+            "get_value_bytes": self._get_value_bytes,
+            "store_records": len(d.store),
             # the event loop's lateness: worst wake-up, and the sum and
             # count of wake-ups later than LOOP_STALL_MS
             "loop_lag_hwm_ms": round(self._loop_lag_hwm_ms, 3),
