@@ -711,8 +711,10 @@ def newt_protocol_step(
     Collective layout: proposals are per-replica local work on the
     key-clock shard; the commit clock is a ``pmax`` over the fast quorum;
     the fast-path count-of-max and the Synod ack count are ``psum``s; the
-    per-key stable clock is an order statistic over an ``all_gather`` of
-    the vote frontiers along ``replica``.
+    stable clock of each key slot is an order statistic over an
+    ``all_gather`` along ``replica`` of the vote frontiers at the round's
+    slots (``[R, W, KW]``): but for the two donated tables, scattered in
+    place, and the hold-back's fill nothing is as long as the key space.
 
     Multi-key commands (KW > 1): each key slot proposes within its key's
     run independently and the row's proposal is the max over its slots —
@@ -754,7 +756,7 @@ def newt_protocol_step(
     if live_replicas is None:
         live_replicas = num_replicas
     replica_blocks = num_replicas // mesh.shape[REPLICA_AXIS]
-    int_min = jnp.iinfo(jnp.int32).min
+    int_min, int_max = jnp.iinfo(jnp.int32).min, jnp.iinfo(jnp.int32).max
 
     def step(
         key_clock, vote_frontier, pend_key, pend_src, pend_seq, pend_clock,
@@ -870,8 +872,8 @@ def newt_protocol_step(
 
         # vote/frontier update: each slot's OWNING shard's live replicas
         # chase every committed clock with (detached) votes — scatter-max
-        # into both tables over the key slots; other shards' replicas
-        # never learn foreign buckets
+        # by the real key (a committed carried row's key_full is private);
+        # other shards' replicas never learn foreign buckets
         upd = jnp.where(
             live[..., None]
             & own_slot
@@ -879,37 +881,35 @@ def newt_protocol_step(
             clock[None, :, None],
             0,
         )  # [r_blk, W, KW]
-        new_key_clock = key_clock.at[:, safe_key].max(
-            jnp.where(propose_slot[None], upd, 0)
-        )
-        # committed carried rows also vote (their key_full is private; use
-        # the real key for the frontier scatter)
         real_key = jnp.minimum(
             jnp.where(real_slot, key_cat, 0), key_buckets - 1
         )  # [W, KW]
         new_frontier = vote_frontier.at[:, real_key].max(upd)
-        # also reflect proposals consumed by this round in the key clock
-        # (live is [r_blk, 1]: broadcasts over the key axis)
-        new_key_clock = jnp.where(
-            live, jnp.maximum(new_key_clock, new_frontier), new_key_clock
+        # from here on the tables are read only at the round's key slots
+        slot_frontier = new_frontier[:, real_key]  # [r_blk, W, KW]
+        # a live replica's key clock chases its votes, this round's
+        # consumed proposals among them (upd <= slot_frontier): equal to
+        # maximum(key_clock, new_frontier) over the whole table, by the
+        # invariant 0 <= vote_frontier <= key_clock on live rows (both
+        # start at 0, votes rise only at real_key, live_replicas is static)
+        new_key_clock = key_clock.at[:, real_key].max(
+            jnp.where(live[..., None], slot_frontier, 0)
         )
 
-        # stability: per-key (n - threshold)-th smallest frontier across
-        # the key's OWNING shard's replicas (mod.rs:247-270; n is the
-        # shard size under partial replication) — gather the replica
-        # axis, sort within each shard's contiguous row block, then each
-        # bucket reads its owner shard's order statistic
-        full_frontier = jax.lax.all_gather(
-            new_frontier, REPLICA_AXIS, tiled=True
-        )  # [R, K]
+        # stability: per-slot (n - threshold)-th smallest frontier across
+        # the key's OWNING shard's replicas (mod.rs:247-270; n = shard
+        # size) — gather the slots' frontiers along replica, sort within
+        # each shard's row block, each slot reads its owner's statistic
         shard_stable = jnp.sort(
-            full_frontier.reshape(shard_count, per_shard, key_buckets), axis=1
-        )[:, per_shard - stability_threshold]  # [S, K]
-        bucket_ids = jnp.arange(key_buckets, dtype=jnp.int32)
-        stable_clock = shard_stable[bucket_ids % shard_count, bucket_ids]  # [K]
-        slot_stable = jnp.where(
-            real_slot, clock[:, None] <= stable_clock[real_key], True
-        )
+            jax.lax.all_gather(
+                slot_frontier.reshape(replica_blocks, -1), REPLICA_AXIS, tiled=True
+            ).reshape(shard_count, per_shard, work * key_width),  # slots minor
+            axis=1,
+        )[:, per_shard - stability_threshold].reshape(shard_count, work, key_width)
+        slot_stable_clock = jnp.where(
+            slot_shard == shard_ids[:, None, None], shard_stable, 0
+        ).max(axis=0)  # [W, KW]; a select, not a gather: frontiers are >= 0
+        slot_stable = jnp.where(real_slot, clock[:, None] <= slot_stable_clock, True)
         fully_stable = committed & valid & slot_stable.all(axis=-1)
         # per-key holdback (multi-key only matters): a command stable on
         # key A but blocked by its other key must also block every
@@ -918,7 +918,7 @@ def newt_protocol_step(
         # ops independently; whole-command execution needs the gate).
         # rank = position in the global (clock, dot) order; a key's
         # holdback is the min rank among its committed-but-blocked rows.
-        safe_clock = jnp.where(committed & valid, clock, jnp.iinfo(jnp.int32).max)
+        safe_clock = jnp.where(committed & valid, clock, int_max)
         order_cd = jnp.lexsort((seq_f, src_f, safe_clock)).astype(jnp.int32)
         rank_of = jnp.zeros((work,), jnp.int32).at[order_cd].set(
             jnp.arange(work, dtype=jnp.int32)
@@ -936,7 +936,7 @@ def newt_protocol_step(
 
         # execution order: stable rows by (clock, dot) — the VotesTable
         # sort id (mod.rs:18)
-        sort_key = jnp.where(executed, clock, jnp.iinfo(jnp.int32).max)
+        sort_key = jnp.where(executed, clock, int_max)
         order = jnp.lexsort((seq_f, src_f, sort_key)).astype(jnp.int32)
 
         # pending carry: valid unexecuted rows (uncommitted or unstable).
@@ -950,7 +950,7 @@ def newt_protocol_step(
         carry_rank = jnp.where(
             carry,
             jnp.where(committed, widx, widx + work32),
-            jnp.iinfo(jnp.int32).max,
+            int_max,
         )
         carry_order = jnp.argsort(carry_rank).astype(jnp.int32)
         take = carry_order[:pend_cap]
@@ -962,8 +962,8 @@ def newt_protocol_step(
         pending = carry.sum().astype(jnp.int32)
         pend_dropped = jnp.maximum(pending - pend_cap, 0).astype(jnp.int32)
 
-        seen = jnp.zeros((key_buckets,), bool).at[real_key].max(real_slot)
-        watermark = jnp.where(seen, stable_clock, jnp.iinfo(jnp.int32).max).min()
+        # min over the buckets seen; int_max = no keys this round
+        watermark = jnp.where(real_slot, slot_stable_clock, int_max).min()
 
         return (
             new_key_clock, new_frontier,

@@ -1,5 +1,7 @@
 """Multi-chip SPMD protocol step on the virtual 8-device CPU mesh."""
 
+import functools
+
 import jax
 import jax.numpy as jnp
 import numpy as np
@@ -848,6 +850,72 @@ def test_newt_multikey_fast_path_is_row_level(mesh):
     assert int(np.asarray(out.clock)[w]) == 5
     assert int(out.slow_paths) == 0
     assert bool(np.asarray(out.executed)[w])
+
+
+def _flat_equations(jaxpr):
+    """Every equation of ``jaxpr`` and of the jaxprs its equations carry
+    (shard_map, pjit, a loop's body), in program order, as ``(primitive,
+    input shapes, output shapes)``; a plain call is not listed itself."""
+    for eqn in jaxpr.eqns:
+        for value in eqn.params.values():
+            inner = getattr(value, "jaxpr", value)
+            if hasattr(inner, "eqns"):
+                yield from _flat_equations(inner)
+        if eqn.primitive.name not in ("pjit", "shard_map", "closed_call"):
+            yield (
+                eqn.primitive.name,
+                [getattr(var.aval, "shape", ()) for var in eqn.invars],
+                [var.aval.shape for var in eqn.outvars],
+            )
+
+
+@pytest.mark.parametrize("key_width", (1, 2))
+@pytest.mark.parametrize("shard_count", (1, 4))
+def test_nothing_in_the_newt_round_scales_with_the_key_space(shard_count, key_width):
+    """Traced at 4096 and at 65536 key buckets with the same working set,
+    the round is the same program but for the shapes of: the two donated
+    tables' scatters (in place), the gathers that read the tables and the
+    hold-back at the round's key slots (operand only: neither indices nor
+    result grow), and the hold-back's fill and scatter-min.  No sort,
+    all_gather, iota, reduction or elementwise pass is as long as the key
+    space: stability and the watermark are computed on the slots."""
+    devices = np.array(jax.devices()[:shard_count]).reshape(shard_count, 1)
+    mesh = jax.sharding.Mesh(devices, (mesh_step.REPLICA_AXIS, mesh_step.BATCH_AXIS))
+    batch, pending, rows = 16, 8, 5 * shard_count
+
+    def trace(key_buckets):
+        state = jax.eval_shape(
+            lambda: mesh_step.init_newt_state(
+                mesh, rows, key_buckets=key_buckets, pending_capacity=pending,
+                key_width=key_width,
+            )
+        )
+        column = jax.ShapeDtypeStruct((batch,), jnp.int32)
+        keys = jax.ShapeDtypeStruct((batch, key_width), jnp.int32)
+        round_ = functools.partial(
+            mesh_step.newt_protocol_step, mesh=mesh, shard_count=shard_count
+        )
+        return list(_flat_equations(jax.make_jaxpr(round_)(state, keys, column, column).jaxpr))
+
+    small, large = trace(4096), trace(65536)
+    assert [eqn[0] for eqn in small] == [eqn[0] for eqn in large]
+    block = rows // shard_count  # a device's replica rows
+    scaled = []
+    for (name, ins, outs), (_, ins_l, outs_l) in zip(small, large):
+        if (ins, outs) == (ins_l, outs_l):
+            continue
+        scaled.append(name)
+        if name == "gather":
+            assert ins[1:] == ins_l[1:] and outs == outs_l, "a gather's indices or result grew"
+        elif name in ("scatter-max", "scatter-min"):
+            assert ins[1:] == ins_l[1:], f"{name}'s indices or updates grew"
+        else:
+            assert (name, outs_l) == ("broadcast_in_dim", [(65536,)]), (name, ins_l, outs_l)
+    assert sorted(name for name in scaled if name != "gather") == [
+        "broadcast_in_dim", "scatter-max", "scatter-max", "scatter-min"]
+    tables = [ins_l[0] for (name, ins_l, _) in large if name == "scatter-max"
+              and ins_l[0][-1:] == (65536,)]
+    assert tables == [(block, 65536)] * 2  # vote_frontier, key_clock
 
 
 # ---------------------------------------------------------------------------

@@ -69,9 +69,10 @@ def assert_state_equal(state, reference):
     assert carried == [tuple(cmd) for cmd in reference.pending]
 
 
-def run_against_reference(shape, length, seed, dispatches=3, **quorum):
+def run_against_reference(shape, length, seed, dispatches=3, after_dispatch=None, **quorum):
     """``dispatches`` programs of ``length`` rounds each, every round held
-    to the reference's."""
+    to the reference's; ``after_dispatch(state, reference)`` sees the state
+    each program leaves."""
     mesh = forced_mesh(shape)
     state = mesh_step.init_newt_state(
         mesh, N * SHARDS, key_buckets=BUCKETS, pending_capacity=CAPACITY, key_width=WIDTH)
@@ -98,6 +99,8 @@ def run_against_reference(shape, length, seed, dispatches=3, **quorum):
             seen["executed"] += len(want.order)
             seen["slow_paths"] += want.slow_paths
             seen["dropped"] += want.dropped
+        if after_dispatch is not None:
+            after_dispatch(state, reference)
     assert_state_equal(state, reference)
     return seen, reference
 
@@ -107,6 +110,30 @@ def run_against_reference(shape, length, seed, dispatches=3, **quorum):
 def test_the_round_and_every_chain_length_equal_the_reference_on_every_layout(shape, length):
     seen, reference = run_against_reference(shape, length, seed=27)
     assert seen["executed"] > 10 * length and not reference.pending  # all live: nothing is held
+
+
+@pytest.mark.parametrize("live_replicas", (None, 18), ids=("all_live", "lagging_minority"))
+@pytest.mark.parametrize("shape", (MESHES["1x1"], MESHES["4x1"]), ids=("1x1", "4x1"))
+def test_a_live_members_key_clock_never_lags_its_votes_after_any_round(
+        shape, live_replicas):
+    """What lets the round raise the key clock only where its commands have
+    keys (``newt_protocol_step``'s invariant): over twenty rounds on hot
+    keys, with every member live and with two of the last shard's five
+    lagging, ``0 <= vote_frontier <= key_clock`` on every live row and
+    bucket after every round, and both tables and the carried commands
+    are the reference's, which takes the maximum over the whole table."""
+    def held(state, reference):
+        live = np.array(reference.live)
+        key_clock, votes = np.asarray(state.key_clock), np.asarray(state.vote_frontier)
+        assert (votes >= 0).all() and (key_clock[live] >= votes[live]).all()
+        # a lagging member learns nothing
+        assert not votes[~live].any() and not key_clock[~live].any()
+        assert_state_equal(state, reference)
+
+    seen, reference = run_against_reference(shape, 1, seed=33, dispatches=20,
+                                            after_dispatch=held, live_replicas=live_replicas)
+    # three of five still make a key stable
+    assert seen["executed"] > 200 and reference.votes.max() > 20
 
 
 @pytest.mark.parametrize("length", (1, 4))
