@@ -208,6 +208,10 @@ class _DriverCore(PipelineCore):
         self.slow_paths = 0
         self.executed = 0
         self.stable_watermark = 0
+        # where a program made ready before serving takes its columns on
+        # the mesh, by the rounds it carries (``_precompile``); empty for
+        # a driver whose round is jitted by its first dispatch
+        self._column_shardings: Dict[int, object] = {}
         # the depth-K dispatch/drain pipeline + staging ingest ring +
         # per-dispatch counters (step/step_pipelined/flush_pipeline and
         # _staging come from PipelineCore; drivers implement the halves
@@ -277,10 +281,42 @@ class _DriverCore(PipelineCore):
 
     def _columns_to_device(self, columns, S: int = 1):
         """The assembled columns of a dispatch of ``S`` rounds, handed
-        to jax."""
-        import jax.numpy as jnp
+        to jax: where the length's program was precompiled, straight to
+        where it takes its columns."""
+        import jax
 
-        return tuple(jnp.asarray(column) for column in columns)
+        shardings = self._column_shardings.get(S)
+        if shardings is None:
+            import jax.numpy as jnp
+
+            return tuple(jnp.asarray(column) for column in columns)
+        return jax.device_put(tuple(columns), shardings)
+
+    def _precompile(self, jitted, S: int = 1):
+        """The program of ``S`` rounds a dispatch, ready before serving:
+        ``jitted`` lowered on the real state's and the dot-keyed columns'
+        shapes and compiled, or loaded, through the persistent compile
+        cache (the jit's own cache is not touched), under one
+        ``precompile`` span.  Returns the executable for the caller to
+        keep and call; its dispatches then compile nothing, and their
+        columns go straight to where it takes them
+        (``_columns_to_device``)."""
+        import jax
+
+        b, kw = self.batch_size, self.key_width
+        lead = () if S == 1 else (S,)
+        columns = tuple(
+            jax.ShapeDtypeStruct(lead + shape, np.int32)
+            for shape in ((b, kw), (b,), (b,))
+        )
+        with self.stages.span("precompile", S):
+            program = jitted.lower(self._state, *columns).compile()
+        self._column_shardings[S] = tuple(program.input_shardings[0][1:])
+        return program
+
+    @property
+    def precompiled_programs(self) -> int:
+        return len(self._column_shardings)
 
     def _enqueue(self, columns):
         """Submit one device round over the assembled columns; returns
@@ -788,8 +824,6 @@ class NewtDeviceDriver(_DriverCore):
             live_replicas=live_replicas, shard_count=shard_count,
         )
         self._multi_step: Dict[int, object] = {}
-        # where a precompiled length's columns live on the mesh
-        self._column_shardings: Dict[int, object] = {}
         # no host identity mirror: the step outputs carry the working
         # rows' (src, seq) columns (NewtStepOutput.work_src/work_seq)
         self._pend_cap = pending_capacity
@@ -911,52 +945,28 @@ class NewtDeviceDriver(_DriverCore):
         the lengths now ready, in order; it stops at the first that
         cannot be made ready (the longer ones need more of whatever it
         lacked), and the caller keeps its tuner off the rest."""
-        import jax
-
         from fantoch_tpu.parallel import mesh_step
 
-        b, kw = self.batch_size, self.key_width
         ready: List[int] = []
         for S in lengths:
             if S not in self._column_shardings:
-                lead = () if S == 1 else (S,)
-                columns = tuple(
-                    jax.ShapeDtypeStruct(lead + shape, np.int32)
-                    for shape in ((b, kw), (b,), (b,))
-                )
                 jitted = self._step if S == 1 else mesh_step.jit_newt_multi_step(
                     self._mesh, **self._step_kwargs
                 )
                 try:
-                    with self.stages.span("precompile", S):
-                        program = jitted.lower(self._state, *columns).compile()
+                    program = self._precompile(jitted, S)
                 except Exception as exc:  # the compiler's own errors are many
                     logger.warning(
                         "chain length %d cannot be made ready (%r): serving "
                         "with chains of at most %d", S, exc, max(ready, default=1),
                     )
                     break
-                self._column_shardings[S] = tuple(program.input_shardings[0][1:])
                 if S == 1:
                     self._step = program
                 else:
                     self._multi_step[S] = program
             ready.append(S)
         return ready
-
-    @property
-    def precompiled_programs(self) -> int:
-        return len(self._column_shardings)
-
-    def _columns_to_device(self, columns, S: int = 1):
-        """Where the length's program was precompiled, straight to where
-        it takes its columns."""
-        import jax
-
-        shardings = self._column_shardings.get(S)
-        if shardings is None:
-            return super()._columns_to_device(columns, S)
-        return jax.device_put(tuple(columns), shardings)
 
     def _enqueue_chain(self, columns):
         from fantoch_tpu.parallel import mesh_step
@@ -1148,6 +1158,16 @@ class CaesarDeviceDriver(_DriverCore):
             self._mesh, num_replicas=num_replicas, live_replicas=live_replicas
         )
         self._pend_cap = pending_capacity
+
+    def precompile_chains(self, lengths: Sequence[int]) -> List[int]:
+        """A chain here is S plain rounds, so every length is ready once
+        the round is: it is compiled, or loaded, before serving
+        (``_precompile``), not traced and compiled by the first client's
+        first dispatch.  A round that cannot be compiled raises here, at
+        start-up: nothing could be served without it."""
+        if 1 not in self._column_shardings:
+            self._step = self._precompile(self._step)
+        return list(lengths)
 
     def _execute(self, _tok, out) -> List[ExecutorResult]:
         """Execute one fetched round's wait-cleared commands in

@@ -294,7 +294,9 @@ class PipelineCore:
         """The chain lengths of ``lengths`` whose programs are ready
         before serving.  Here a chain is S plain rounds and has no
         program of its own, so every length is; a driver with a fused
-        program per length (NewtDeviceDriver) compiles or loads them."""
+        program per length (NewtDeviceDriver) compiles or loads them, and
+        one that makes its plain round ready ahead (CaesarDeviceDriver)
+        does that here."""
         return list(lengths)
 
     @property

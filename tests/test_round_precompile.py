@@ -1,0 +1,111 @@
+"""A plain round made ready before the first client (`_DriverCore._precompile`,
+beside `tests/test_chain_precompile.py`, which holds the Newt ladder): the
+Caesar driver's round is compiled or loaded inside `DeviceRuntime.start()`,
+so its first dispatch compiles nothing; the EPaxos and FPaxos drivers start
+as they did; and the two tests that count the plane programs' jit signatures
+still pass after a precompile in the same process."""
+
+import asyncio
+import json
+
+import pytest
+
+from fantoch_tpu.core import Command, Config, Dot, KVOp, Rifl
+from fantoch_tpu.observability import device as obs
+from fantoch_tpu.run.device_runner import CaesarDeviceDriver, DeviceRuntime
+from fantoch_tpu.run.harness import free_port
+from tests.test_chain_precompile import LADDER, _tallies
+
+
+def _batch(first, count):
+    return [(Dot(1, first + i), Command.from_single(Rifl(7, first + i), 0, f"k{(first + i) % 5}",
+                                                     KVOp.put("v")))
+            for i in range(count)]
+
+
+def test_the_caesar_round_is_one_program_ready_once_and_then_dispatches_compile_nothing():
+    obs.subscribe_recompiles()
+    driver = CaesarDeviceDriver(7, batch_size=8, key_buckets=64, pending_capacity=8)
+    assert driver.precompiled_programs == 0 and driver.stages.n["precompile"] == 0
+    assert driver.precompile_chains(LADDER) == LADDER  # a chain is S plain rounds
+    assert driver.precompiled_programs == 1 and driver.stages.n["precompile"] == 1
+    before = _tallies()
+    assert driver.precompile_chains(LADDER) == LADDER  # ready already: nothing to do
+    executed = len(driver.step(_batch(0, 8)))
+    executed += len(driver.step_pipelined(_batch(8, 5)))
+    executed += len(driver.step_chained(([_batch(16, 8), _batch(24, 3)])))
+    executed += len(driver.step_chained_pipelined([_batch(32, 8), _batch(40, 8)]))
+    executed += len(driver.flush_pipeline())
+    assert _tallies() == before and driver.stages.n["precompile"] == 1
+    assert executed == 40 and driver.in_flight == 0 and driver.fast_paths == 40
+
+
+def _serve(protocol, tmp_path, commands=40):
+    """A runtime of ``protocol`` started, fed ``commands`` writes, stopped:
+    (its first snapshot, its last, the compile tallies when `start()`
+    returned, and when everything was executed)."""
+    obs.subscribe_recompiles()
+
+    async def go():
+        runtime = DeviceRuntime(
+            Config(7, 3, leader=1 if protocol == "fpaxos" else None),
+            ("127.0.0.1", free_port()), protocol=protocol, batch_size=8, key_buckets=64,
+            pending_capacity=8, metrics_file=str(tmp_path / "snap.json"),
+        )
+        runtime._write_metrics_snapshot()
+        with open(tmp_path / "snap.json") as fh:
+            first = json.load(fh)
+        await runtime.start()
+        started = _tallies()
+        for i in range(commands):
+            cmd = Command.from_single(Rifl(9, i + 1), 0, f"k{i % 7}", KVOp.put("v"))
+            runtime.submit(runtime.dot_gen.next_id(), cmd)
+        for _ in range(1500):
+            if runtime.failure is not None:
+                raise runtime.failure
+            if runtime.driver.executed >= commands and not runtime.driver.has_outstanding:
+                break
+            await asyncio.sleep(0.02)
+        served = _tallies()
+        assert runtime.driver.executed == commands
+        await runtime.stop()
+        with open(tmp_path / "snap.json") as fh:
+            return runtime, first, json.load(fh), started, served
+
+    return asyncio.run(go())
+
+
+def test_a_caesar_server_has_its_round_before_it_listens_and_says_how_long_that_took(tmp_path):
+    runtime, first, last, started, served = _serve("caesar", tmp_path)
+    assert first["precompiled_programs"] == 0 and first["stage_precompile_n"] == 0
+    assert last["precompiled_programs"] == 1 and last["stage_precompile_n"] == 1
+    assert last["stage_precompile_ms"] > 0
+    assert served == started  # the first dispatch, and every one after it, compiled nothing
+    assert runtime._chain_tuner.chain_max == 8  # every chain length is plain rounds: all ready
+    # what the round's trace left is frozen out of the collector with the rest of start-up
+    assert last["gc_frozen_objects"] > 0 and last["fast_paths"] == 40
+
+
+@pytest.mark.parametrize("protocol", ["epaxos", "fpaxos"])
+def test_the_other_plain_round_drivers_start_as_before(protocol, tmp_path):
+    """The mechanism is switched on for Caesar only: the dep-commit and the
+    leader round are still built by their first dispatch."""
+    runtime, first, last, started, served = _serve(protocol, tmp_path)
+    assert first["precompiled_programs"] == last["precompiled_programs"] == 0
+    assert last["stage_precompile_n"] == 0 and last["stage_precompile_ms"] == 0
+    assert not runtime.driver._column_shardings
+    assert served[0] + served[2] > started[0] + started[2]  # compiled, or loaded, while serving
+
+
+def test_the_signature_counting_tests_still_pass_after_a_precompile_in_this_process():
+    """`PERF.md` s7's two order-dependent tests count the jit signatures of
+    the registered plane programs; lowering and compiling a round ahead of
+    time touches no jit's cache."""
+    from tests.test_chip_smoke import test_leg_planes_tiny
+    from tests.test_compile_cache import test_plane_sweep_compiles_each_program_once
+
+    driver = CaesarDeviceDriver(7, batch_size=8, key_buckets=64, pending_capacity=8)
+    driver.precompile_chains(LADDER)
+    assert driver.precompiled_programs == 1
+    test_plane_sweep_compiles_each_program_once()
+    test_leg_planes_tiny()
