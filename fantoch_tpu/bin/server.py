@@ -162,6 +162,7 @@ def _backend_banner(backend: dict) -> str:
             if shards
             else ""
         )
+        + (f" resolver={backend['resolver']}" if "resolver" in backend else "")
         + f" compile_cache={backend['compile_cache_dir']}]"
     )
 

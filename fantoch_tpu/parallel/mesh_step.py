@@ -35,9 +35,13 @@ execute round for B commands on all replicas at once:
      skip-prepare trick (single.rs:86); replica accept indicators are
      counted with a ``psum`` along ``replica`` and the command commits once
      ``acks >= write_quorum_size`` (f + 1);
-  4. batched SCC/topological resolution of the committed batch
-     (ops/graph_resolve.resolve_functional), shared across the ``batch``
-     axis via one small all_gather;
+  4. topological resolution of the committed working set, shared across
+     the ``batch`` axis via one small all_gather: with one key a command a
+     row's level is its position in its key's run of step 1's sort and it
+     is blocked iff an uncommitted row stands before it there
+     (:func:`_resolve_run_position`; the round builds no cycle, so none is
+     searched for); with several keys the general resolver
+     (ops/graph_resolve.resolve_general);
   5. state update: scatter-max the committed dots into every replica's
      key-clock, advance the executed frontier, and compute the GC stability
      watermark = ``pmin`` of all replicas' frontiers (the AEClock meet of
@@ -57,12 +61,7 @@ import jax.numpy as jnp
 from jax import shard_map
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
-from fantoch_tpu.ops.graph_resolve import (
-    MISSING,
-    TERMINAL,
-    resolve_functional,
-    resolve_general,
-)
+from fantoch_tpu.ops.graph_resolve import MISSING, TERMINAL, resolve_general
 
 REPLICA_AXIS = "replica"
 BATCH_AXIS = "batch"
@@ -245,28 +244,72 @@ def init_state(
     )
 
 
-def _intra_batch_chain(keys: jax.Array) -> jax.Array:
-    """chain[i, w] = latest row j < i sharing key keys[i, w], else -1.
+def _key_runs(keys: jax.Array) -> Tuple[jax.Array, jax.Array]:
+    """Stable sort of the flattened (row-major) key slots: ``(perm, head)``.
 
-    Stable-sort the flattened (row-major) key slots, then each slot's
-    predecessor within its key run is the latest earlier slot of the same
-    key — the tensorized ``KeyDeps::add_cmd`` latest-per-key chain for
-    commands of the same round, one dependency slot per key.  Rows must
-    not repeat a key (commands hold distinct keys), so an in-run
-    predecessor is always an earlier row.
+    ``perm[p]`` is the slot at sorted position ``p``; the slots of one key
+    are one contiguous run in arrival order, and ``head[p]`` marks a run's
+    first position.
     """
-    batch, kw = keys.shape
     flat = keys.reshape(-1)
-    n = flat.shape[0]
-    idx = jnp.arange(n, dtype=jnp.int32)
     perm = jnp.argsort(flat, stable=True).astype(jnp.int32)
     sorted_key = flat[perm]
-    prev_same = jnp.where(
-        (idx > 0) & (sorted_key == jnp.roll(sorted_key, 1)),
-        jnp.roll(perm, 1) // kw,  # predecessor's row
-        jnp.int32(TERMINAL),
+    head = jnp.concatenate(
+        [jnp.ones((1,), bool), sorted_key[1:] != sorted_key[:-1]]
     )
-    return jnp.zeros((n,), jnp.int32).at[perm].set(prev_same).reshape(batch, kw)
+    return perm, head
+
+
+def _chain_of_runs(perm: jax.Array, head: jax.Array, shape) -> jax.Array:
+    """chain[i, w] = latest row j < i sharing key keys[i, w], else -1.
+
+    Each slot's predecessor within its key run is the latest earlier slot
+    of the same key — the tensorized ``KeyDeps::add_cmd`` latest-per-key
+    chain for commands of the same round, one dependency slot per key.
+    Rows must not repeat a key (commands hold distinct keys), so an in-run
+    predecessor is always an earlier row.
+    """
+    prev_same = jnp.where(
+        head, jnp.int32(TERMINAL), jnp.roll(perm, 1) // shape[1]  # predecessor's row
+    )
+    return jnp.zeros_like(perm).at[perm].set(prev_same).reshape(shape)
+
+
+def _intra_batch_chain(keys: jax.Array) -> jax.Array:
+    """The chain of :func:`_chain_of_runs` over the runs of ``keys``."""
+    return _chain_of_runs(*_key_runs(keys), keys.shape)
+
+
+def _resolve_run_position(
+    perm: jax.Array, head: jax.Array, blocking: jax.Array
+) -> Tuple[jax.Array, jax.Array]:
+    """``(order, resolved)`` of a one-key working set from its key runs.
+
+    The chain of :func:`_chain_of_runs` only ever points at the previous
+    row of a key's run, so there is no cycle to look for: a row's
+    topological level is its position in its run, and it resolves iff no
+    ``blocking`` row (valid and uncommitted: the resolvers' ``MISSING``)
+    stands at or before it in the run.  Two ``cummax`` in sorted space and
+    one scatter back give what pointer doubling over the same chain gives
+    (``ops/graph_resolve.resolve_functional``, the oracle of
+    ``tests/test_mesh_step.py``), element for element: ``order`` is rows by
+    (level, row), unresolved rows at the tail.
+    """
+    pos = jnp.arange(perm.shape[0], dtype=jnp.int32)
+    run_start = jax.lax.cummax(jnp.where(head, pos, 0))
+    last_blocked = jax.lax.cummax(jnp.where(blocking[perm], pos, -1))
+    unresolved = jnp.iinfo(jnp.int32).max
+    level = jnp.zeros_like(pos).at[perm].set(
+        jnp.where(last_blocked < run_start, pos - run_start, unresolved)
+    )
+    order = jnp.argsort(level, stable=True).astype(jnp.int32)
+    return order, level != unresolved
+
+
+def resolver_name(key_width: int) -> str:
+    """What resolves a :func:`protocol_step` round of this key width (the
+    snapshot's ``backend`` and the "serving clients" banner say it)."""
+    return "run_position" if key_width == 1 else "general"
 
 
 def protocol_step(
@@ -282,10 +325,14 @@ def protocol_step(
     """One batched commit+execute round over the (replica, batch) mesh.
 
     ``key`` may carry up to KW distinct key buckets per command (KEY_PAD
-    pads unused slots); multi-key rounds resolve through the general
-    out-degree-KW resolver (ops/graph_resolve.resolve_general), whose
-    arrival-order fast path covers the clean-commit case and whose
-    iterative pass handles quorum-failure MISSING blocking.
+    pads unused slots).  A one-key round (KW == 1) is resolved from the
+    key-sorted working set that builds its chain: level = position in the
+    key's run, blocked iff an uncommitted row stands earlier in the run
+    (:func:`_resolve_run_position`, :func:`resolver_name` ``run_position``).
+    Multi-key rounds resolve through the general out-degree-KW resolver
+    (ops/graph_resolve.resolve_general, ``general``), whose arrival-order
+    fast path covers the clean-commit case and whose iterative pass
+    handles quorum-failure MISSING blocking.
 
     ``live_replicas``: replicas (global rows) < this count respond to the
     Synod accept round; the rest are crashed/partitioned for the round.
@@ -355,7 +402,8 @@ def protocol_step(
 
         # 2. per-replica deps, one slot per key: intra-working-batch chain,
         # else the replica's key-clock entry (KeyDeps::add_cmd per replica)
-        chain = _intra_batch_chain(key_full)  # [W, KW] working row or -1
+        perm, head = _key_runs(key_full)
+        chain = _chain_of_runs(perm, head, key_full.shape)  # [W, KW] row or -1
         safe_key = jnp.minimum(key_full, key_buckets - 1)
         prior = jnp.where(real_slot[None], key_clock[:, safe_key], -1)
         dep_gid = jnp.where(
@@ -415,25 +463,20 @@ def protocol_step(
         slow_paths = ((~fast) & valid).sum().astype(jnp.int32)
 
         # 4. batched resolution of the committed working set.  A final dep
-        # is either a working row (pending gids included — matched via a
-        # sorted-gid searchsorted join) or already executed (pruned to
-        # TERMINAL).  Uncommitted commands are MISSING: they stay
-        # unresolved and so does everything dependency-chained to them.
-        masked_gid = jnp.where(valid, gid, int_max)
-        sort_row = jnp.argsort(masked_gid).astype(jnp.int32)
-        sort_gid = masked_gid[sort_row]
-        j = jnp.clip(
-            jnp.searchsorted(sort_gid, jnp.maximum(final_gid, 0)), 0, work - 1
-        )  # [W, KW]
-        in_work = (final_gid >= 0) & (sort_gid[j] == final_gid)
-        dep_idx = jnp.where(in_work, sort_row[j], jnp.int32(TERMINAL))
-        dep_idx = jnp.where(committed[:, None], dep_idx, jnp.int32(MISSING))
-        dep_idx = jnp.where(valid[:, None], dep_idx, jnp.int32(TERMINAL))
+        # is either a working row or already executed (pruned to TERMINAL);
+        # uncommitted commands are MISSING: they stay unresolved and so does
+        # everything dependency-chained to them.  The clock holds executed
+        # gids only (step 5), so a dep that is a working row is always the
+        # intra-batch chain's and never the clock's.
         if key_width == 1:
-            # exact O(log W) doubling: resolves every non-missing-blocked
-            # row regardless of chain depth
-            res = resolve_functional(dep_idx[:, 0], dot_src_f, dot_seq_f)
+            # one key a command: level and blocking are read off the key
+            # runs of step 2, the sort that built the chain
+            order, resolved = _resolve_run_position(
+                perm, head, valid & ~committed
+            )
         else:
+            dep_idx = jnp.where(committed[:, None], chain, jnp.int32(MISSING))
+            dep_idx = jnp.where(valid[:, None], dep_idx, jnp.int32(TERMINAL))
             # general resolver; max_iters = 2*W+8 guarantees convergence
             # for committed acyclic rows (>= one vertex finalizes per
             # iteration) and the while_loop's changed-flag exits early on
@@ -442,15 +485,19 @@ def protocol_step(
             res = resolve_general(
                 dep_idx, dot_src_f, dot_seq_f, max_iters=2 * work + 8
             )
-        executed = res.resolved & committed
+            order, resolved = res.order, res.resolved
+        executed = resolved & committed
 
         # 5. state update: every *live* replica learns the *executed* dots
         # on the buckets of ITS OWN shard (scatter-max by key slot; later
         # commands in the batch win) — a shard's replicas never store
         # other shards' key state (partial replication).  Only executed
-        # gids enter the key clock: the next round prunes
-        # out-of-working-set deps as already-executed (step 4), which is
-        # only sound if the clock never holds an unexecuted gid.
+        # gids enter the key clock, and an executed row is never carried,
+        # so the clock never holds a gid of a working set.  The next round
+        # leans on that twice (step 4): a dep read from the clock is
+        # already executed and prunes to TERMINAL, and it is never a
+        # working row, so no join of committed dep gids against the
+        # working set's gids is needed to find one.
         own_slot = row_shard == slot_shard[None]  # [r_blk, W, KW]
         clock_upd = jnp.where(
             live[..., None]
@@ -490,7 +537,7 @@ def protocol_step(
             new_pend_src,
             new_pend_seq,
             new_pend_gid,
-            res.order,
+            order,
             executed,
             fast,
             jnp.where(real_slot, final_gid, -1),

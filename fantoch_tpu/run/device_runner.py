@@ -185,6 +185,10 @@ class _DriverCore(PipelineCore):
     # leave headroom so a full batch plus in-round growth never wraps
     SEQ_WINDOW_MAX = 2**31 - (1 << 20)
 
+    # what resolves the round's dependency graph (mesh_step.resolver_name);
+    # None where the round executes in clock or slot order
+    resolver: Optional[str] = None
+
     def _init_core(
         self,
         shard_id: ShardId,
@@ -588,6 +592,7 @@ class DeviceDriver(_DriverCore):
         self._step = mesh_step.jit_protocol_step(
             self._mesh, live_replicas=live_replicas, shard_count=shard_count
         )
+        self.resolver = mesh_step.resolver_name(key_width)
         self._next_gid = 0  # host mirror of state.next_gid
         self._frontier_base = 0  # executed-count carried across gid epochs
         self.gid_epochs = 0
@@ -2110,6 +2115,7 @@ class DeviceRuntime:
         script can tell a chip run from a CPU run."""
         from fantoch_tpu.hostenv import device_report
 
+        resolver = self.driver.resolver
         return {
             **device_report(),
             "mesh_shape": {
@@ -2118,6 +2124,7 @@ class DeviceRuntime:
             # the shards whose replica rows each device holds, in the
             # mesh's device order
             "shards_on_device": self._shards_on_device,
+            **({"resolver": resolver} if resolver else {}),
         }
 
     def _write_metrics_snapshot(self) -> None:

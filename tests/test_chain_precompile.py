@@ -177,6 +177,7 @@ def test_a_server_has_every_chain_program_before_it_listens_and_says_how_long_th
     assert served == started and runtime.driver.executed == 40
     assert last["backend"]["mesh_shape"] == {"replica": 2, "batch": 4}
     assert last["backend"]["shards_on_device"] == [[0]] * 4 + [[1]] * 4
+    assert "resolver" not in last["backend"]  # the dep-commit round's alone
     for key in ("shard_replies", "commands_completed", "multi_shard_completed"):
         assert first[key] == 0
 

@@ -34,10 +34,13 @@ def test_leg_served_tiny(tmp_path):
     assert leg["device_dispatches"] > 0
     assert leg["recompiles_warm_burst"] == 0
     assert set(leg["backend"]) == {
-        "platform", "device_kind", "device_count", "mesh_shape", "shards_on_device"
+        "platform", "device_kind", "device_count", "mesh_shape", "shards_on_device",
+        "resolver",
     }
+    assert leg["backend"]["resolver"] == "run_position"  # one key a command
     assert "platform=cpu" in leg["banner"] and "mesh=" in leg["banner"]
     assert " shards_on_device=0" in leg["banner"]
+    assert " resolver=run_position compile_cache=" in leg["banner"]
 
 
 def test_leg_served_reports_a_dead_server(tmp_path):

@@ -185,6 +185,7 @@ def test_device_runtime_multi_key_tcp():
         assert client.issued_commands == 5
     assert runtime.driver.executed == 10
     assert runtime.driver.in_flight == 0
+    assert runtime.backend_report()["resolver"] == "general"
 
 
 def test_device_runtime_zipf_workload_tcp():
@@ -211,6 +212,7 @@ def test_device_runtime_zipf_workload_tcp():
     monitor = driver.store.monitor
     # zipf keys are numeric ranks within keys_per_shard
     assert all(1 <= int(k) <= 64 for k in monitor.keys())
+    assert runtime.backend_report()["resolver"] == "run_position"
 
 
 def test_device_runtime_read_mix_tcp():

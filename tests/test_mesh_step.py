@@ -1060,3 +1060,169 @@ def test_caesar_wait_gate_transitive_holdback(mesh):
     m_slot = min(range(3), key=lambda w: clock2[w])
     assert clock2[m_slot] == 21
     assert all(pos[m_slot] < pos[w] for w in range(3) if w != m_slot)
+
+
+# ---------------------------------------------------------------------------
+# The one-key dep-commit round against the formulation it replaced
+# ---------------------------------------------------------------------------
+
+
+def _one_device_mesh():
+    return jax.sharding.Mesh(
+        np.array(jax.devices()[:1]).reshape(1, 1), (mesh_step.REPLICA_AXIS, mesh_step.BATCH_AXIS)
+    )
+
+
+def _oracle_round(state, key, src, seq, *, shard_count, live_replicas):
+    """One dep-commit round at key width 1 over numpy, row by row, resolved
+    as the round was before it read its key runs: a final dep is joined
+    back to a working row by its gid, uncommitted rows are ``MISSING``, and
+    ``ops/graph_resolve.resolve_functional`` (pointer doubling) ranks the
+    graph.  Returns ``(next state, StepOutput)`` as numpy."""
+    from fantoch_tpu.ops.graph_resolve import MISSING, resolve_functional
+
+    clock, frontier, next_gid, pend_key, pend_src, pend_seq, pend_gid = (
+        np.asarray(x) for x in state
+    )
+    rows, buckets = clock.shape
+    per_shard = rows // shard_count
+    fast_quorum, write_quorum = mesh_step.quorum_sizes(per_shard)
+    cap, batch = len(pend_gid), len(key)
+    work = cap + batch
+    gid = np.concatenate([pend_gid, next_gid + np.arange(batch, dtype=np.int32)])
+    valid = gid >= 0
+    key_cat = np.concatenate([pend_key[:, 0], key])
+    real = valid & (key_cat != mesh_step.KEY_PAD)
+    src_f = np.where(valid, np.concatenate([pend_src, src]), 0).astype(np.int32)
+    seq_f = np.where(valid, np.concatenate([pend_seq, seq]), 0).astype(np.int32)
+
+    # step 2: the latest earlier working row on the same key, else the clock
+    chain, latest = np.full(work, TERMINAL, np.int32), {}
+    for i in np.flatnonzero(real):
+        chain[i] = latest.get(key_cat[i], TERMINAL)
+        latest[key_cat[i]] = i
+    safe = np.where(real, key_cat, 0)
+    dep = np.where(chain >= 0, gid[np.maximum(chain, 0)], np.where(real, clock[:, safe], -1))
+    # step 3: the fast quorum of the slot's shard, then the accept round
+    shard_of = np.where(real, key_cat % shard_count, 0)
+    member = np.arange(rows)
+    in_fq = (member[:, None] // per_shard == shard_of[None]) & (
+        member[:, None] % per_shard < fast_quorum
+    )
+    fq_max = np.where(in_fq, dep, np.iinfo(np.int32).min).max(axis=0)
+    fq_min = np.where(in_fq, dep, np.iinfo(np.int32).max).min(axis=0)
+    fast = (fq_max == fq_min) & valid
+    live = member < live_replicas
+    shard_live = np.bincount(member[live] // per_shard, minlength=shard_count)
+    committed = (fast | np.where(real, shard_live[shard_of] >= write_quorum, True)) & valid
+
+    # step 4 as it was: the gid join, then pointer doubling
+    row_of = {int(g): i for i, g in enumerate(gid) if g >= 0}
+    join = np.array([row_of.get(int(g), TERMINAL) if g >= 0 else TERMINAL for g in fq_max], np.int32)
+    assert np.array_equal(join, chain), "the join found a clock entry in the working set"
+    dep_idx = np.where(valid, np.where(committed, join, MISSING), TERMINAL).astype(np.int32)
+    res = resolve_functional(jnp.asarray(dep_idx), jnp.asarray(src_f), jnp.asarray(seq_f))
+    executed = np.asarray(res.resolved) & committed
+
+    # steps 5 and 6
+    new_clock = clock.copy()
+    for i in np.flatnonzero(executed & real):
+        learns = live & (member // per_shard == shard_of[i])
+        new_clock[learns, key_cat[i]] = np.maximum(new_clock[learns, key_cat[i]], gid[i])
+    new_frontier = frontier + np.where(live, executed.sum(), 0).astype(np.int32)
+    carry = np.flatnonzero(valid & ~executed)
+    take = carry[:cap]
+
+    def carried(column, empty):
+        out = np.full(cap, empty, np.int32)
+        out[: len(take)] = column[take]
+        return out
+
+    state = mesh_step.ReplicaState(
+        new_clock, new_frontier, np.int32(next_gid + batch),
+        carried(np.where(real, key_cat, mesh_step.KEY_PAD), mesh_step.KEY_PAD)[:, None],
+        carried(src_f, -1), carried(seq_f, -1), carried(gid, -1),
+    )
+    out = mesh_step.StepOutput(
+        np.asarray(res.order), executed, fast, np.where(real, fq_max, -1)[:, None],
+        np.where(valid, gid, -1), np.int32((~fast & valid).sum()), new_frontier.min(),
+        np.int32(min(len(carry), cap)), np.int32(max(len(carry) - cap, 0)),
+    )
+    return state, out
+
+
+@pytest.mark.parametrize("distinct_keys", (1, 30, 1000))
+@pytest.mark.parametrize(
+    "shard_count, lives",
+    [
+        (1, (5,)),  # all live
+        (1, (3, 3, 5)),  # at the write quorum: every slow path commits
+        (1, (2, 2, 2, 5, 5)),  # below it: MISSING rows, blocked suffixes, carry
+        (2, (10, 8, 8, 10)),  # the second shard at its write quorum
+        (2, (7, 7, 7, 10, 10)),  # the second shard below it
+    ],
+)
+def test_one_key_round_equals_the_gid_join_and_pointer_doubling(shard_count, lives, distinct_keys):
+    """Several rounds in sequence on one state, full and part-full batches,
+    pending carried and overflowing: every field of ``StepOutput`` and of
+    the next ``ReplicaState`` equals, element for element, what the round
+    gave when it exported its chain as gids, joined them back to rows and
+    resolved by pointer doubling (``_oracle_round``)."""
+    rows, batch, pending, buckets = 5 * shard_count, 48, 32, 2048
+    mesh = _one_device_mesh()
+    state = mesh_step.init_state(mesh, rows, key_buckets=buckets, pending_capacity=pending)
+    steps = {
+        live: mesh_step.jit_protocol_step(mesh, live_replicas=live, shard_count=shard_count)
+        for live in set(lives)
+    }
+    rng = np.random.default_rng(35 + distinct_keys + shard_count)
+    carried = 0
+    for r in range(max(7, 2 * len(lives) + 1)):
+        live = lives[r % len(lives)]
+        fill = batch if r % 3 == 0 else int(rng.integers(1, batch + 1))
+        key = np.full(batch, mesh_step.KEY_PAD, np.int32)
+        # from shard_count - 1 up: a single key is the last shard's, the degraded one
+        key[:fill] = shard_count - 1 + rng.integers(0, distinct_keys, fill)
+        src = np.zeros(batch, np.int32)
+        src[:fill] = rng.integers(1, 6, fill)
+        seq = np.zeros(batch, np.int32)
+        seq[:fill] = r * batch + np.arange(fill)
+        want_state, want = _oracle_round(
+            state, key, src, seq, shard_count=shard_count, live_replicas=live
+        )
+        state, out = steps[live](state, jnp.asarray(key), jnp.asarray(src), jnp.asarray(seq))
+        for name, got, expected in zip(out._fields + state._fields, out + state, want + want_state):
+            got = np.asarray(got)
+            assert got.dtype == np.asarray(expected).dtype or got.dtype == bool, (r, name)
+            assert np.array_equal(got, expected), (r, name)
+        carried += int(out.pending)
+    if min(lives) < 3 + 5 * (shard_count - 1) and distinct_keys < 1000:
+        assert carried > 0, "a quorum below the write quorum carried nothing: the case tests less than it says"
+
+
+def test_the_one_key_round_has_no_loop_and_no_more_gathers_at_a_larger_working_set():
+    """The one-key round's jaxpr at W = 512 and at W = 8192: no ``scan`` and
+    no ``while`` (pointer doubling unrolled 2 log2(2W) steps of gathers and
+    the gid join searched by a loop), and the same gathers and scatters,
+    one for one: their number does not grow with the working set."""
+    mesh = _one_device_mesh()
+
+    def trace(half):
+        state = jax.eval_shape(
+            lambda: mesh_step.init_state(mesh, 5, key_buckets=4096, pending_capacity=half)
+        )
+        column = jax.ShapeDtypeStruct((half,), jnp.int32)
+        round_ = functools.partial(mesh_step.protocol_step, mesh=mesh)
+        return [name for name, _, _ in _flat_equations(
+            jax.make_jaxpr(round_)(state, column, column, column).jaxpr)]
+
+    small, large = trace(256), trace(4096)
+    assert small == large
+    assert not {"scan", "while"} & set(small)
+    moves = [name for name in small if name == "gather" or name.startswith("scatter")]
+    # gathers: the sorted keys, the clock read, gid[chain], a slot's shard's
+    # live count, the blocking flags at the sorted positions, the carry's
+    # five reads; scatters: the chain and the level back to row order, the
+    # live count per shard, the clock's scatter-max
+    assert sorted(moves) == ["gather"] * 10 + ["scatter", "scatter", "scatter-add", "scatter-max"], moves
+    assert small.count("sort") == 3 and small.count("cummax") == 2

@@ -283,3 +283,99 @@ def test_sharded_newt_round_properties(rows):
     even = np.arange(0, 8, 2)
     assert (kc[0:3][:, odd] == 0).all() and (vf[0:3][:, odd] == 0).all()
     assert (kc[3:6][:, even] == 0).all() and (vf[3:6][:, even] == 0).all()
+
+
+# --- the dep-commit round's invariant: the clock holds executed gids only ---
+
+_EPAXOS_STEPS = {}
+
+
+def _epaxos_steps():
+    """The healthy and the degraded jitted dep-commit round of one mesh,
+    built once: hypothesis examples reuse the compiled programs."""
+    if not _EPAXOS_STEPS:
+        from fantoch_tpu.parallel import mesh_step
+        from fantoch_tpu.run.device_runner import DeviceDriver
+
+        probe = DeviceDriver(3, batch_size=8, key_buckets=16, pending_capacity=8)
+        _EPAXOS_STEPS["mesh"] = probe._mesh
+        _EPAXOS_STEPS[True] = probe._step
+        _EPAXOS_STEPS[False] = mesh_step.jit_protocol_step(probe._mesh, live_replicas=1)
+    return _EPAXOS_STEPS
+
+
+@settings(max_examples=30 // 5 if _CI else 30, deadline=None)
+@given(
+    st.lists(
+        st.tuples(
+            # all replicas live, or (twice as often) one of three: below the write quorum
+            st.sampled_from((True, False, False)),
+            st.lists(st.integers(min_value=0, max_value=3), min_size=1, max_size=8),
+        ),
+        min_size=4,
+        max_size=9,
+    ),
+    st.integers(min_value=1, max_value=5),
+)
+def test_the_key_clock_never_holds_a_gid_of_the_working_set(rounds, reset_before):
+    """Step 4 of the one-key round reads a working-row dependency off the
+    intra-batch chain alone, which is sound only while no key-clock entry
+    is the gid of a carried or a new row.  Over sequences of healthy and
+    degraded rounds (rows miss the fast path, fail the accept round and
+    carry), with a ``_gid_epoch_reset`` in the middle: after
+    every round, and after the reset, every clock entry is below
+    ``next_gid`` and none is a pending gid; and every command is executed
+    exactly once by the end."""
+    from fantoch_tpu.core.command import Command
+    from fantoch_tpu.core.ids import Dot, Rifl
+    from fantoch_tpu.core.kvs import KVOp
+    from fantoch_tpu.run.device_runner import DeviceDriver
+
+    steps = _epaxos_steps()
+    d = DeviceDriver(
+        3, batch_size=8, key_buckets=16, pending_capacity=8, mesh=steps["mesh"],
+        monitor_execution_order=True,
+    )
+
+    def holds(where):
+        st_ = d._state
+        clock = np.asarray(st_.key_clock)
+        pending = np.asarray(st_.pend_gid)
+        pending = set(pending[pending >= 0].tolist())
+        assert clock.max() < int(st_.next_gid), where
+        assert not pending & set(clock[clock >= 0].tolist()), where
+        assert pending == set(d._cmds), where  # the registry mirrors the device's carry
+
+    seq, done, backlog = 0, 0, []
+
+    def step(batch, where):
+        # what overflowed the device's pending buffer comes back through the
+        # requeue and goes in again ahead of the new commands
+        nonlocal done, backlog
+        backlog = d.take_requeue() + backlog + batch
+        batch, backlog = backlog[:8], backlog[8:]
+        done += len(d.step(batch))
+        holds(where)
+
+    reset = False
+    for at, (healthy, keys) in enumerate(rounds):
+        # the reset: at the first round boundary from ``reset_before`` on with
+        # rows carried (their gids, the registry and the clock rebase
+        # together), else before the last round
+        if not reset and at >= reset_before and (d._cmds or at == len(rounds) - 1):
+            if min(d._cmds, default=d._next_gid) > 0:
+                d._gid_epoch_reset()
+                holds(f"after the reset before round {at}")
+                reset = True
+        d._step = steps[healthy]
+        batch = []
+        for key in keys:
+            seq += 1
+            batch.append((Dot(1, seq), Command.from_single(Rifl(1, seq), 0, f"k{key}", KVOp.put(str(seq)))))
+        step(batch, f"after round {at}")
+    d._step = steps[True]
+    for _ in range(16):  # all live again: what was carried or requeued drains
+        if not (d.in_flight or backlog or d.has_requeue):
+            break
+        step([], "while draining")
+    assert d.in_flight == 0 and not backlog and done == seq == d.executed
