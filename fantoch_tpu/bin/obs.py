@@ -321,10 +321,15 @@ def _watch_sources(targets: List[str]) -> Dict[str, Dict[str, Any]]:
 
 def _render_watch(latest: Dict[str, Dict[str, Any]]) -> str:
     """One table frame: per source, submit/reply rates, the client or
-    end-to-end latency window, queue depth, sheds, device idle."""
+    end-to-end latency window, queue depth, sheds, device idle, and of a
+    device-step server the CPU its two served threads used in the window
+    (``host_cpu_ms``'s rate over wall time: 100 is one core) and the
+    milliseconds so far in which neither of them ran
+    (``loop_stopped_ms``)."""
     lines = [
         f"{'source':<12}{'submit/s':>10}{'reply/s':>10}{'p50ms':>8}"
         f"{'p95ms':>8}{'p99ms':>8}{'queue':>7}{'sheds':>7}{'idle':>6}"
+        f"{'cpu%':>6}{'stop':>8}"
     ]
     for src in sorted(latest):
         window = latest[src]
@@ -332,6 +337,7 @@ def _render_watch(latest: Dict[str, Dict[str, Any]]) -> str:
         ctr = window.get("ctr", {})
         gauges = window.get("g", {})
         hist = window.get("h", {}).get("latency_ms")
+        cpu_rate = rate.get("host_cpu_ms")
 
         def _num(value, fmt="{:.1f}"):
             return "-" if value is None else fmt.format(value)
@@ -346,6 +352,9 @@ def _render_watch(latest: Dict[str, Dict[str, Any]]) -> str:
             f"{_num(gauges.get('queue_depth'), '{:.0f}'):>7}"
             f"{_num(ctr.get('shed_submissions'), '{:.0f}'):>7}"
             f"{_num(gauges.get('device_idle_frac'), '{:.2f}'):>6}"
+            # a rate is per second: ms of CPU a second, over 10, is %
+            f"{_num(cpu_rate and cpu_rate / 10.0, '{:.0f}'):>6}"
+            f"{_num(ctr.get('loop_stopped_ms'), '{:.0f}'):>8}"
         )
     errors = [
         f"! {src}: {window['err']}"

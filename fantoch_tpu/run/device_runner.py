@@ -48,7 +48,7 @@ import asyncio
 import gc
 import os
 from collections import defaultdict, deque
-from time import monotonic, monotonic_ns
+from time import monotonic, monotonic_ns, thread_time_ns
 from typing import Any, Deque, Dict, List, NamedTuple, Optional, Sequence, Tuple
 
 import numpy as np
@@ -59,6 +59,12 @@ from fantoch_tpu.core.ids import ClientId, Dot, ProcessId, Rifl, ShardId
 from fantoch_tpu.core.kvs import KVStore
 from fantoch_tpu.executor.aggregate import AggregatePending
 from fantoch_tpu.executor.base import ExecutorResult
+from fantoch_tpu.observability.device import (
+    CPU_PAIR_EVERY_NS,
+    AccountSample,
+    ThreadAccount,
+    off_cpu_ns,
+)
 from fantoch_tpu.run.collector import CollectorSchedule
 from fantoch_tpu.run.ingest import (
     AdaptiveIngestBatcher,
@@ -1586,6 +1592,11 @@ class _DeviceClientSession:
         # commands in the ring
         now_ms = t0 / 1e6
         runtime = self.runtime
+        # the thread's CPU time of the pass beside its wall time, at most
+        # once in CPU_PAIR_EVERY_NS (as a stage's span takes it)
+        timed = t0 >= runtime._admit_cpu_due
+        if timed:
+            cpu0 = thread_time_ns()
         room = runtime.room()
         tracer = runtime.tracer
         tracing = tracer.enabled
@@ -1630,7 +1641,13 @@ class _DeviceClientSession:
         finally:
             if admitted:
                 runtime.submit_all(admitted, now_ms)
-            runtime._admit_ns += monotonic_ns() - t0
+            if timed:
+                runtime._admit_cpu_ns += thread_time_ns() - cpu0
+            took = monotonic_ns() - t0
+            if timed:
+                runtime._admit_timed_ns += took
+                runtime._admit_cpu_due = t0 + CPU_PAIR_EVERY_NS
+            runtime._admit_ns += took
 
     def _not_a_submit(self, msg: Any) -> None:
         if not isinstance(msg, Register):
@@ -1874,10 +1891,13 @@ class DeviceRuntime:
         # StageRecorder): the driver's recorder, shared, so the loop's
         # stages and the step's land in one ring on one clock
         self.stages = self.driver.stages
+        # who had the CPU meanwhile: this thread (the loop's) and the
+        # pool's, sampled where the tallies are published and by the probe
+        self.account = ThreadAccount()
         # the per-command boundaries: two clock reads each, no span
-        # [ns, frames, reads] of turning socket reads into messages,
-        # shared with every Rw
-        self._decode_tally = [0, 0, 0]
+        # [ns, frames, reads, CPU ns, timed ns, due ns] of turning socket
+        # reads into messages, shared with every Rw
+        self._decode_tally = [0, 0, 0, 0, 0, 0]
         self._flush_ns = 0  # awaits of rw.flush() in the sessions
         self._flushes = 0
         self._reply_writes = 0  # writes of a round's frames to a connection
@@ -1888,6 +1908,11 @@ class DeviceRuntime:
         self._gets_replied = 0  # read-only commands among the completed
         self._get_value_bytes = 0  # bytes (UTF-8) of the values their replies carried
         self._admit_ns = 0  # a read's messages decoded -> its commands pushed
+        # ... and of the passes that took a CPU pair: the loop's thread on
+        # a CPU, their wall time, when the next pair is due
+        self._admit_cpu_ns = 0
+        self._admit_timed_ns = 0
+        self._admit_cpu_due = 0
         self._queue_wait_ms = 0.0  # sum over released commands, ring time
         self._queue_released = 0
         # the loop's own lateness (_lag_task)
@@ -2048,6 +2073,7 @@ class DeviceRuntime:
         )
 
         d = self.driver
+        decode_ns, decoded, reads, decode_cpu_ns, decode_timed_ns, _ = self._decode_tally
         self._tallies = {
             "submitted": self.submitted,
             "replied": self.replied,
@@ -2065,13 +2091,34 @@ class DeviceRuntime:
             "shed_submissions": self._submit_queue.sheds,
             # per-dispatch device counters (observability/device.py)
             **d.device_counters(),
-            # a round's host time by stage: stage_<name>_ms / _n
+            # a round's host time by stage: stage_<name>_ms / _n, for the
+            # stages that compute _cpu_ms; the probe's stalls by class
             **self.stages.counters(),
+            # the two served threads' CPU time and run-queue wait, the
+            # process's CPU time, page faults and involuntary switches
+            **self.account.counters(self.PUBLISH_SAMPLE_FRESH_NS),
             # the per-command boundaries around the rounds
-            "session_decode_ms": round(self._decode_tally[0] / 1e6, 3),
-            "session_decoded": self._decode_tally[1],
-            "session_reads": self._decode_tally[2],
+            "session_decode_ms": round(decode_ns / 1e6, 3),
+            "session_decode_cpu_ms": round(decode_cpu_ns / 1e6, 3),
+            "session_decode_timed_ms": round(decode_timed_ns / 1e6, 3),
+            "session_decoded": decoded,
+            "session_reads": reads,
             "session_admit_ms": round(self._admit_ns / 1e6, 3),
+            "session_admit_cpu_ms": round(self._admit_cpu_ns / 1e6, 3),
+            "session_admit_timed_ms": round(self._admit_timed_ns / 1e6, 3),
+            # wall minus CPU of the stages that only compute and of the
+            # two session counters: charged to a stage while its thread
+            # was not running (from the spans and reads that took a CPU
+            # pair: all of them where a round outlasts CPU_PAIR_EVERY_NS)
+            "stage_wait_ms": round(
+                (
+                    self.stages.wait_ns()
+                    + off_cpu_ns(decode_ns, decode_timed_ns, decode_cpu_ns)
+                    + off_cpu_ns(self._admit_ns, self._admit_timed_ns, self._admit_cpu_ns)
+                )
+                / 1e6,
+                3,
+            ),
             "queue_wait_ms": round(self._queue_wait_ms, 3),
             "queue_released": self._queue_released,
             "reply_flush_ms": round(self._flush_ns / 1e6, 3),
@@ -2090,7 +2137,8 @@ class DeviceRuntime:
             "get_value_bytes": self._get_value_bytes,
             "store_records": len(d.store),
             # the event loop's lateness: worst wake-up, and the sum and
-            # count of wake-ups later than LOOP_STALL_MS
+            # count of wake-ups later than LOOP_STALL_MS (by class among
+            # the stage counters: loop_stall_<class>_ms, loop_stopped_ms)
             "loop_lag_hwm_ms": round(self._loop_lag_hwm_ms, 3),
             "loop_stall_ms": round(self._loop_stall_ms, 3),
             "loop_stalls": self._loop_stalls,
@@ -2221,6 +2269,7 @@ class DeviceRuntime:
         if self._on_gc in gc.callbacks:
             gc.callbacks.remove(self._on_gc)
         self.emit_final()
+        self.account.close()
         # the process's collector as start() found it
         self._collector.hand_back()
         if self.telemetry is not None:
@@ -2324,22 +2373,34 @@ class DeviceRuntime:
     # open on either thread at that time is the finding)
     LOOP_LAG_PROBE_MS = 10.0
     LOOP_STALL_MS = 20.0
+    # the published tallies take the probe's last sample of the thread
+    # account while it is no older than two of the probe's sleeps
+    PUBLISH_SAMPLE_FRESH_NS = 20_000_000
 
     async def _lag_task(self) -> None:
         """The loop's own lateness: sleep ``LOOP_LAG_PROBE_MS``, measure
-        how late the wake-up came."""
+        how late the wake-up came, and read the thread account at every
+        wake-up, so that a late one is classed by what the interval since
+        the one before it cost the two served threads."""
         probe_ns = int(self.LOOP_LAG_PROBE_MS * 1e6)
+        account, stages = self.account, self.stages
+        before = account.sample()
         while True:
             due = monotonic_ns() + probe_ns
             await asyncio.sleep(self.LOOP_LAG_PROBE_MS / 1000.0)
             now = monotonic_ns()
+            after = account.sample()
             late_ms = (now - due) / 1e6
             if late_ms > self._loop_lag_hwm_ms:
                 self._loop_lag_hwm_ms = late_ms
             if late_ms > self.LOOP_STALL_MS:
                 self._loop_stall_ms += late_ms
                 self._loop_stalls += 1
-                self.stages.record("loop_stall", due, now)
+                stages.stall(
+                    due, now, AccountSample(*(b - a for a, b in zip(before, after)))
+                )
+            stages.settle_stalls(now)
+            before = after
 
     async def _step_on_pool(self, round_id: int, step, *args):
         """One blocking driver call off the event loop (connections and
@@ -2353,7 +2414,8 @@ class DeviceRuntime:
 
         def on_pool():
             stages.record("handoff", called, stages.clock(), round_id, "round")
-            with stages.span("step", round_id, parent="round", cpu=True):
+            self.account.register("step")
+            with stages.span("step", round_id, parent="round"):
                 results = step(*args)
             return results, stages.clock()
 

@@ -20,8 +20,10 @@ import asyncio
 import pickle
 import socket
 import struct
-from time import monotonic_ns
+from time import monotonic_ns, thread_time_ns
 from typing import Any, List, Optional
+
+from fantoch_tpu.observability.device import CPU_PAIR_EVERY_NS
 
 _LEN = struct.Struct(">I")
 # what one ``recv_all`` asks the stream for: more than a ``StreamReader``
@@ -81,11 +83,14 @@ class Rw:
         writer: asyncio.StreamWriter,
         decode_tally: Optional[List[int]] = None,
     ):
-        """``decode_tally``: ``[ns, frames, reads]``, which the owner
-        shares among its connections; ``recv`` adds each frame's
-        unpickle time to it, ``recv_all`` a read's walk and unpickles
-        and the read itself (the device runtime's ``session_decode_ms``,
-        ``session_decoded``, ``session_reads``)."""
+        """``decode_tally``: ``[ns, frames, reads, CPU ns, timed ns, due
+        ns]``, which the owner shares among its connections; ``recv`` adds
+        each frame's unpickle time to it, ``recv_all`` a read's walk and
+        unpickles and the read itself, and at most once in
+        ``CPU_PAIR_EVERY_NS`` the thread's CPU time of the walk beside its
+        wall time (the device runtime's ``session_decode_ms``,
+        ``session_decoded``, ``session_reads``, ``session_decode_cpu_ms``,
+        ``session_decode_timed_ms``)."""
         self._reader = reader
         self._writer = writer
         self._decode_tally = decode_tally
@@ -131,7 +136,11 @@ class Rw:
                     # EOF inside a payload: what readexactly raises
                     raise asyncio.IncompleteReadError(self._tail[size:], None)
                 return None
+            tally = self._decode_tally
             t0 = monotonic_ns()
+            timed = tally is not None and t0 >= tally[5]
+            if timed:
+                cpu0 = thread_time_ns()
             if self._tail:
                 data = self._tail + data
             values: List[Any] = []
@@ -143,9 +152,14 @@ class Rw:
                 values.append(loads(data[at + size : stop]))
                 at = stop
             self._tail = data[at:]
-            tally = self._decode_tally
             if tally is not None:
-                tally[0] += monotonic_ns() - t0
+                if timed:
+                    tally[3] += thread_time_ns() - cpu0
+                took = monotonic_ns() - t0
+                if timed:
+                    tally[4] += took
+                    tally[5] = t0 + CPU_PAIR_EVERY_NS
+                tally[0] += took
                 tally[1] += len(values)
                 tally[2] += bool(values)
             if values:

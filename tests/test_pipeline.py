@@ -558,7 +558,7 @@ def test_stage_counters_are_numeric_and_monotone_and_the_ring_is_bounded():
                for name in ROUND_STAGES)  # every stage is in the first snapshot
     assert set(first.values()) == {0}
     for i in range(20):
-        with rec.span("step", i, cpu=True):
+        with rec.span("step", i):
             with rec.span("assemble", i) as inner:
                 pass
         rec.record("handoff", 5, 9, i, "round")
