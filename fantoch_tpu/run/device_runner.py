@@ -217,6 +217,10 @@ class _DriverCore(PipelineCore):
         self.fast_paths = 0
         self.slow_paths = 0
         self.executed = 0
+        # working rows a drain's Python visited: the executed rows (pads
+        # among them) and, when the device dropped rows, the overflow's
+        # candidates — not the working set
+        self.drain_rows_walked = 0
         self.stable_watermark = 0
         # where a program made ready before serving takes its columns on
         # the mesh, by the rounds it carries (``_precompile``); empty for
@@ -351,24 +355,44 @@ class _DriverCore(PipelineCore):
             seq_row[i] = self._device_seq(dot)
             self._cmds[self._packed(dot.source, seq_row[i])] = (dot, cmd)
 
+    @staticmethod
+    def _packed_column(work_src, work_seq, rows) -> List[int]:
+        """``_packed`` of the working rows ``rows``, made once as a
+        column (the identity columns are non-negative ``int32``; a pad
+        row's key is registered by no one either way)."""
+        return (
+            (work_src[rows].astype(np.int64) << 32) | work_seq[rows]
+        ).tolist()
+
     def _execute_ordered(
         self, order, executed, work_src, work_seq
     ) -> List[ExecutorResult]:
         """Pop and execute the round's executed rows in device order
-        (shared by every drain; pad rows are registered by no one and
-        skip)."""
+        (shared by every dot-keyed drain; pad rows are registered by no
+        one and skip).  Only the executed rows are visited: the mask
+        picks them out of the working set, device order kept."""
+        live = order[executed[order]]
+        self.drain_rows_walked += len(live)
         results: List[ExecutorResult] = []
-        for w in order.tolist():
-            if not executed[w]:
-                continue
-            entry = self._cmds.pop(
-                self._packed(work_src[w], work_seq[w]), None
-            )
+        for packed in self._packed_column(work_src, work_seq, live):
+            entry = self._cmds.pop(packed, None)
             if entry is None:
                 continue  # pad row
             results.extend(self._execute_entry(entry[1]))
             self.executed += 1
         return results
+
+    def _registered_rows(self, rows, work_src, work_seq) -> List[int]:
+        """Of the working rows ``rows`` (an overflow's candidates, in
+        working order), those the registry still holds."""
+        self.drain_rows_walked += len(rows)
+        return [
+            w
+            for w, packed in zip(
+                rows.tolist(), self._packed_column(work_src, work_seq, rows)
+            )
+            if packed in self._cmds
+        ]
 
     def _requeue_rows(self, rows, work_src, work_seq, label: str) -> None:
         """Re-queue overflow-dropped working rows under their original
@@ -510,25 +534,29 @@ class _DriverCore(PipelineCore):
         work_seq = np.asarray(out.work_seq)
         results = self._execute_ordered(order, executed, work_src, work_seq)
 
-        # after the pops, registry keys == this round's carried rows;
-        # committed first in working order (both device carries sort
-        # committed rows ahead — carry_rank in the mesh steps); rows
-        # beyond the device pending capacity were dropped there
-        carried = [
-            w
-            for w in range(len(work_src))
-            if self._packed(work_src[w], work_seq[w]) in self._cmds
-        ]
-        carried.sort(key=lambda w: (not committed[w], w))
-        dropped = carried[self._pend_cap:]
-        if any(committed[w] for w in dropped):
-            raise RuntimeError(
-                f"{label} device pending buffer overflowed with "
-                f"committed-but-{committed_noun} commands: raise "
-                "pending_capacity (a committed timestamp cannot be "
-                "re-proposed)"
+        # the device counts its valid unexecuted rows and says how many
+        # fell beyond its pending capacity; the registered unexecuted
+        # rows are among those it counted, so with none dropped there is
+        # nothing to look for
+        if int(out.pend_dropped) > 0:
+            # a carried row is one the round did not execute and the
+            # registry still holds; committed first in working order
+            # (both device carries sort committed rows ahead — carry_rank
+            # in the mesh steps); rows beyond the device pending capacity
+            # were dropped there
+            carried = self._registered_rows(
+                np.flatnonzero(~executed), work_src, work_seq
             )
-        self._requeue_rows(dropped, work_src, work_seq, label)
+            carried.sort(key=lambda w: (not committed[w], w))
+            dropped = carried[self._pend_cap:]
+            if any(committed[w] for w in dropped):
+                raise RuntimeError(
+                    f"{label} device pending buffer overflowed with "
+                    f"committed-but-{committed_noun} commands: raise "
+                    "pending_capacity (a committed timestamp cannot be "
+                    "re-proposed)"
+                )
+            self._requeue_rows(dropped, work_src, work_seq, label)
         return results
 
     def _rekey_registry_for_window(self) -> None:
@@ -699,7 +727,9 @@ class DeviceDriver(_DriverCore):
 
     def _assemble(self, batch: List[Tuple[Dot, Command]]):
         """One round's key/src/seq columns, each command registered
-        under the gid its row will get."""
+        under the gid its row will get, and the batch length: the round
+        gives every row of the fixed batch a gid and resolves the padding
+        too, so the drain needs it to leave the padding out."""
         assert len(batch) <= self.batch_size, (
             f"batch {len(batch)} exceeds the compiled batch size "
             f"{self.batch_size}; chunk at the caller"
@@ -730,14 +760,18 @@ class DeviceDriver(_DriverCore):
             src[i] = dot.source
             seq[i] = self._device_seq(dot)
             self._cmds[self._next_gid + i] = (dot, cmd)
-        return key, src, seq
+        return (key, src, seq), len(batch)
 
-    def _enqueue(self, columns):
+    def _enqueue(self, staged):
+        columns, n_batch = staged
         out = super()._enqueue(columns)
         self._next_gid += self.batch_size
-        return out
+        return out, n_batch
 
-    def _execute(self, _tok, out) -> List[ExecutorResult]:
+    def _token_outputs(self, tok):
+        return tok[0]
+
+    def _execute(self, tok, out) -> List[ExecutorResult]:
         """Execute one fetched round's resolved commands in device
         order against the KVStore."""
         order = np.asarray(out.order)
@@ -746,18 +780,23 @@ class DeviceDriver(_DriverCore):
         fast = np.asarray(out.fast_path)
         self.stable_watermark = self._frontier_base + int(out.stable)
 
+        # only the rows the round resolved are visited, device order
+        # kept; the working set is the pending buffer, then the batch,
+        # whose rows past the batch's length are padding
+        pend_cap = len(gids) - self.batch_size
+        live = order[
+            (gids[order] >= 0) & resolved[order] & (order < pend_cap + tok[1])
+        ]
+        self.drain_rows_walked += len(live)
         results: List[ExecutorResult] = []
-        for w in order.tolist():
-            gid = int(gids[w])
-            if gid < 0 or not resolved[w]:
-                continue
+        for gid, is_fast in zip(gids[live].tolist(), fast[live].tolist()):
             entry = self._cmds.pop(gid, None)
             if entry is None:
                 continue  # padding row (registered by no one)
             _dot, cmd = entry
             results.extend(self._execute_entry(cmd))
             self.executed += 1
-            if fast[w]:
+            if is_fast:
                 self.fast_paths += 1
         # valid new rows that missed the fast path took the Synod round
         self.slow_paths += int(out.slow_paths)
@@ -767,12 +806,9 @@ class DeviceDriver(_DriverCore):
         # them for the next round under fresh gids: they never executed
         # and never entered any key clock, so resubmission is safe.
         if int(out.pend_dropped) > 0:
-            carried = [
-                int(gids[w])
-                for w in range(len(gids))
-                if gids[w] >= 0 and not resolved[w]
-            ]  # working order == device carry order
-            pend_cap = self._state.pend_gid.shape[0]
+            # working order == device carry order
+            carried = gids[(gids >= 0) & ~resolved].tolist()
+            self.drain_rows_walked += len(carried)
             dropped = carried[pend_cap:]
             logger.warning(
                 "device pending buffer overflowed: re-queueing %d commands",
@@ -1369,15 +1405,14 @@ class PaxosDeviceDriver(_DriverCore):
         # device rolled its slot counter back over them, so re-queueing
         # them under the same dot is safe: no acceptor holds durable
         # state for a rolled-back slot.
-        carried = [
-            w
-            for w in range(len(work_src))
-            if slot[w] >= 0
-            and not executed[w]
-            and self._packed(work_src[w], work_seq[w]) in self._cmds
-        ]
-        carried.sort(key=lambda w: int(slot[w]))
-        self._requeue_rows(carried[self._pend_cap:], work_src, work_seq, "paxos")
+        if int(out.pend_dropped) > 0:
+            carried = self._registered_rows(
+                np.flatnonzero((slot >= 0) & ~executed), work_src, work_seq
+            )
+            carried.sort(key=lambda w: int(slot[w]))
+            self._requeue_rows(
+                carried[self._pend_cap:], work_src, work_seq, "paxos"
+            )
         return results
 
 
@@ -2079,6 +2114,7 @@ class DeviceRuntime:
             "replied": self.replied,
             "rounds": d.rounds,
             "executed": d.executed,
+            "drain_rows_walked": d.drain_rows_walked,
             "fast_paths": d.fast_paths,
             "slow_paths": d.slow_paths,
             "in_flight": d.in_flight,

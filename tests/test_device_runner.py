@@ -27,6 +27,7 @@ if tuple(int(x) for x in jax.__version__.split(".")[:2]) < (0, 5):
 
 from fantoch_tpu.client import ConflictRateKeyGen, Workload
 from fantoch_tpu.core import Command, Config, Dot, KVOp, Rifl
+from fantoch_tpu.run import device_runner as dr
 from fantoch_tpu.run.device_runner import DeviceDriver
 from fantoch_tpu.run.harness import run_device_server
 
@@ -1511,6 +1512,396 @@ def test_chained_pipelined_parity():
     # counted per protocol round
     assert d_chp.dispatches == 4
     assert d_chp.rounds == 12
+
+
+# --- the masked drain (PR 37) against the walks it replaced ---
+#
+# Until PR 37 every drain walked all W working rows in Python: the order
+# walk of `_execute_ordered` / `DeviceDriver._execute`, and in
+# `_drain_and_carry` / `PaxosDeviceDriver._execute` a second walk for the
+# carried rows.  The loops below are those, verbatim, as the oracle: the
+# drivers' drains must execute the same commands in the same order and
+# leave the same registry, tallies and requeue.
+
+
+class _WalkOracle:
+    """The dot-keyed drains as they stood: mixed in ahead of a driver."""
+
+    def _execute_ordered(self, order, executed, work_src, work_seq):
+        results = []
+        for w in order.tolist():
+            if not executed[w]:
+                continue
+            entry = self._cmds.pop(
+                self._packed(work_src[w], work_seq[w]), None
+            )
+            if entry is None:
+                continue  # pad row
+            results.extend(self._execute_entry(entry[1]))
+            self.executed += 1
+        return results
+
+    def _drain_and_carry(self, out, label, committed_noun):
+        order = np.asarray(out.order)
+        executed = np.asarray(out.executed)
+        committed = np.asarray(out.committed)
+        work_src = np.asarray(out.work_src)
+        work_seq = np.asarray(out.work_seq)
+        results = self._execute_ordered(order, executed, work_src, work_seq)
+
+        carried = [
+            w
+            for w in range(len(work_src))
+            if self._packed(work_src[w], work_seq[w]) in self._cmds
+        ]
+        carried.sort(key=lambda w: (not committed[w], w))
+        dropped = carried[self._pend_cap:]
+        if any(committed[w] for w in dropped):
+            raise RuntimeError(
+                f"{label} device pending buffer overflowed with "
+                f"committed-but-{committed_noun} commands: raise "
+                "pending_capacity (a committed timestamp cannot be "
+                "re-proposed)"
+            )
+        self._requeue_rows(dropped, work_src, work_seq, label)
+        return results
+
+
+class _EpaxosWalk(dr.DeviceDriver):
+    def _execute(self, _tok, out):
+        order = np.asarray(out.order)
+        resolved = np.asarray(out.resolved)
+        gids = np.asarray(out.gids)
+        fast = np.asarray(out.fast_path)
+        self.stable_watermark = self._frontier_base + int(out.stable)
+
+        results = []
+        for w in order.tolist():
+            gid = int(gids[w])
+            if gid < 0 or not resolved[w]:
+                continue
+            entry = self._cmds.pop(gid, None)
+            if entry is None:
+                continue  # padding row (registered by no one)
+            _dot, cmd = entry
+            results.extend(self._execute_entry(cmd))
+            self.executed += 1
+            if fast[w]:
+                self.fast_paths += 1
+        self.slow_paths += int(out.slow_paths)
+
+        if int(out.pend_dropped) > 0:
+            carried = [
+                int(gids[w])
+                for w in range(len(gids))
+                if gids[w] >= 0 and not resolved[w]
+            ]  # working order == device carry order
+            pend_cap = self._state.pend_gid.shape[0]
+            dropped = carried[pend_cap:]
+            for gid in dropped:
+                entry = self._cmds.pop(gid, None)
+                if entry is not None:
+                    self._requeue.append(entry)
+        return results
+
+class _NewtWalk(_WalkOracle, dr.NewtDeviceDriver):
+    pass
+
+class _CaesarWalk(_WalkOracle, dr.CaesarDeviceDriver):
+    pass
+
+class _PaxosWalk(_WalkOracle, dr.PaxosDeviceDriver):
+    def _execute(self, tok, out):
+        n_batch = tok[1]
+        order = np.asarray(out.order)
+        executed = np.asarray(out.executed)
+        slot = np.asarray(out.slot)
+        work_src = np.asarray(out.work_src)
+        work_seq = np.asarray(out.work_seq)
+        self._next_slot += n_batch - int(out.pend_dropped)
+        self.stable_watermark = self._slot_base + int(out.exec_frontier)
+        self.slow_paths += int(executed.sum())
+
+        results = self._execute_ordered(order, executed, work_src, work_seq)
+
+        carried = [
+            w
+            for w in range(len(work_src))
+            if slot[w] >= 0
+            and not executed[w]
+            and self._packed(work_src[w], work_seq[w]) in self._cmds
+        ]
+        carried.sort(key=lambda w: int(slot[w]))
+        self._requeue_rows(carried[self._pend_cap:], work_src, work_seq, "paxos")
+        return results
+
+
+# protocol -> (the driver, its walking oracle, the replicas of the degraded
+# scenarios the requeue tests above use, further constructor arguments)
+DRAIN_DRIVERS = {
+    "epaxos": (dr.DeviceDriver, _EpaxosWalk, 3, {}),
+    "newt": (dr.NewtDeviceDriver, _NewtWalk, 5, {"f": 2}),
+    "caesar": (dr.CaesarDeviceDriver, _CaesarWalk, 4, {}),
+    "fpaxos": (dr.PaxosDeviceDriver, _PaxosWalk, 3, {"f": 1}),
+}
+
+
+def _drain_pair(protocol, **kw):
+    """The protocol's driver and its walking oracle, built alike; the
+    oracle runs the driver's own jitted round."""
+    cls, walk, n, extra = DRAIN_DRIVERS[protocol]
+    kw = {"key_buckets": 64, "monitor_execution_order": True, **extra, **kw}
+    real, oracle = cls(n, **kw), walk(n, **kw)
+    oracle._step = real._step
+    return real, oracle
+
+
+def _degrade(protocol, *drivers, live=1):
+    """Swap in a round with ``live`` replicas answering (None: all),
+    one jit for all ``drivers``."""
+    from fantoch_tpu.parallel import mesh_step
+
+    d = drivers[0]
+    n = DRAIN_DRIVERS[protocol][2]
+    step = {
+        "epaxos": lambda: mesh_step.jit_protocol_step(d._mesh, live_replicas=live),
+        "newt": lambda: mesh_step.jit_newt_step(d._mesh, f=2, live_replicas=live),
+        "caesar": lambda: mesh_step.jit_caesar_step(
+            d._mesh, num_replicas=n, live_replicas=live),
+        "fpaxos": lambda: mesh_step.jit_paxos_step(
+            d._mesh, f=1, num_replicas=n, live_replicas=live),
+    }[protocol]()
+    for driver in drivers:
+        driver._step = step
+
+
+def _puts(seqs, own=True):
+    """Two batches of the same commands (a driver each): odd sequences
+    on the hot key, even ones on a key of their own (``own``) or there
+    too."""
+    return tuple(
+        [_put(1, s, "hot" if s % 2 or not own else f"own{s}", f"v{s}") for s in seqs]
+        for _ in range(2)
+    )
+
+
+def _flat(results):
+    return [(r.rifl, r.key, tuple(r.op_results)) for r in results]
+
+
+def _assert_same_drain(real, oracle):
+    assert {k: v[0] for k, v in real._cmds.items()} == {
+        k: v[0] for k, v in oracle._cmds.items()
+    }
+    assert [dot for dot, _ in real._requeue] == [dot for dot, _ in oracle._requeue]
+    for tally in ("executed", "fast_paths", "slow_paths", "stable_watermark", "rounds"):
+        assert getattr(real, tally) == getattr(oracle, tally), tally
+    for key in oracle.store.monitor.keys():
+        assert real.store.monitor.get_order(key) == oracle.store.monitor.get_order(key)
+
+
+def _step_both(real, oracle, seqs, **kw):
+    mine, theirs = _puts(seqs, **kw)
+    got, want = real.step(mine), oracle.step(theirs)
+    assert _flat(got) == _flat(want)
+    _assert_same_drain(real, oracle)
+    return got
+
+
+@pytest.mark.parametrize("protocol", DRAIN_DRIVERS)
+def test_masked_drain_executes_what_the_walk_executed(protocol):
+    """At the benchmark's working set (4096 + 4096 rows) a round 1.2%
+    full, a full one and an empty one: the same commands in the same
+    order, the same registry and the same tallies as the 8192-row walks
+    gave."""
+    real, oracle = _drain_pair(
+        protocol, batch_size=4096, pending_capacity=4096, key_buckets=1024
+    )
+    assert len(_step_both(real, oracle, range(1, 51))) == 50
+    assert len(_step_both(real, oracle, range(51, 51 + 4096))) == 4096
+    assert _step_both(real, oracle, []) == []
+    assert real.executed == 4146 and real.in_flight == 0
+    order = real.store.monitor.get_order("hot")
+    assert len(order) == len(set(order)) == 2073
+
+
+@pytest.mark.parametrize("protocol", DRAIN_DRIVERS)
+def test_a_part_full_drain_walks_its_executed_rows_not_the_working_set(protocol):
+    """`drain_rows_walked` counts the working rows a drain's Python
+    visited: 50 after a round of 50 commands at W = 8192 (the walks made
+    it 8192, Newt's and Caesar's 16384), the dep-commit round's 4046
+    resolved rows of padding left out too; and the runtime publishes it
+    beside `executed`."""
+    real, _ = _drain_pair(
+        protocol, batch_size=4096, pending_capacity=4096, key_buckets=1024
+    )
+    (mine, _) = _puts(range(1, 51))
+    assert len(real.step(mine)) == 50
+    assert real.step([]) == []
+    assert real.executed == 50 and real.drain_rows_walked == 50
+
+    runtime, _clients = _served(protocol=protocol)
+    t = runtime._tallies
+    assert t["drain_rows_walked"] == t["executed"] == 4 * COMMANDS_PER_CLIENT
+
+
+@pytest.mark.parametrize("protocol", DRAIN_DRIVERS)
+def test_overflow_requeues_what_the_walk_requeued_and_scans_only_then(protocol):
+    """A degraded round that carries without dropping runs no scan (the
+    device's `pend_dropped` is 0: nothing walked); one that overflows
+    the pending buffer of 4 requeues the rows the walk requeued, under
+    their dots and in its order; after healing both drain alike.  Newt's
+    committed overflow (and Caesar's, through the shared tail) fails as
+    loudly as it did."""
+    real, oracle = _drain_pair(protocol, batch_size=8, pending_capacity=4)
+    dropped, inner = [], real._execute
+
+    def watched(tok, out):
+        dropped.append(int(out.pend_dropped))
+        return inner(tok, out)
+
+    real._execute = watched
+
+    if protocol == "epaxos":
+        # degraded from the start: only replica 0 learns the seed, so
+        # what follows on its key splits the fast quorum and carries
+        _degrade(protocol, real, oracle)
+    assert len(_step_both(real, oracle, range(1, 5), own=False)) == 4
+    if protocol == "caesar":
+        # stagger replica 0's ceiling on the hot bucket: proposals diverge
+        from fantoch_tpu.utils import key_hash
+
+        for d in (real, oracle):
+            kc = np.array(d._state.key_clock)
+            kc[0, key_hash("hot") % 64] += 7
+            d._state = d._state._replace(key_clock=jax.device_put(
+                jax.numpy.asarray(kc), d._state.key_clock.sharding))
+    _degrade(protocol, real, oracle)
+    walked = real.drain_rows_walked
+
+    if protocol == "newt":
+        # the first degraded round commits and cannot stabilise: eight
+        # committed rows against a capacity of 4 cannot be re-proposed
+        mine, theirs = _puts(range(5, 13), own=False)
+        with pytest.raises(RuntimeError, match="committed-but-unstable") as theirs_exc:
+            oracle.step(theirs)
+        with pytest.raises(RuntimeError, match="committed-but-unstable") as mine_exc:
+            real.step(mine)
+        assert str(mine_exc.value) == str(theirs_exc.value)
+        assert dropped == [0, 4]
+        # an uncommitted overflow: a capacity of 12 holds the committed
+        # eight, the next round's uncommitted rows overflow it
+        real, oracle = _drain_pair(protocol, batch_size=8, pending_capacity=12)
+        _step_both(real, oracle, range(1, 5), own=False)
+        _degrade(protocol, real, oracle)
+        walked = real.drain_rows_walked
+        assert _step_both(real, oracle, range(5, 13), own=False) == []
+        assert real.in_flight == 8 and real.drain_rows_walked == walked
+        assert _step_both(real, oracle, range(13, 21), own=False) == []
+        assert [dot.sequence for dot, _ in real._requeue] == [17, 18, 19, 20]
+        # the scan's candidates: every unexecuted row of the 12 + 8
+        assert real.drain_rows_walked == walked + 20
+        total = 20
+    else:
+        # three rows carry, none dropped: no scan, nothing walked
+        assert _step_both(real, oracle, range(5, 8), own=False) == []
+        assert dropped[-1] == 0 and real.in_flight == 3
+        assert real.drain_rows_walked == walked and not real.has_requeue
+        # eight more: 11 unexecuted rows against a capacity of 4
+        assert _step_both(real, oracle, range(8, 16), own=False) == []
+        assert dropped[-1] == 7 and real.in_flight == 4
+        assert [dot.sequence for dot, _ in real._requeue] == list(range(9, 16))
+        # the scan's candidates: the 11 valid unexecuted rows (Caesar's
+        # tail masks on `executed` alone: the empty slot too)
+        assert real.drain_rows_walked == walked + (12 if protocol == "caesar" else 11)
+        total = 15
+
+    _degrade(protocol, real, oracle, live=None)
+    for _ in range(6):
+        mine, theirs = real.take_requeue(), oracle.take_requeue()
+        assert [dot for dot, _ in mine] == [dot for dot, _ in theirs]
+        assert _flat(real.step(mine)) == _flat(oracle.step(theirs))
+        _assert_same_drain(real, oracle)
+    assert real.in_flight == 0 and not real.has_requeue
+    assert real.executed == total
+    order = real.store.monitor.get_order("hot")
+    assert len(order) == len(set(order)) == total
+
+
+@pytest.mark.parametrize("label, noun", [("newt", "unstable"), ("caesar", "blocked")])
+def test_committed_overflow_fails_loudly_through_the_shared_tail(label, noun):
+    """`_drain_and_carry` on a round's outputs made by hand: six
+    registered unexecuted rows, five of them committed, against a
+    capacity of 4: the walk's `RuntimeError`, letter for letter; with
+    two committed the four uncommitted requeue committed-first order's
+    tail; and a `pend_dropped` of 0 leaves even those alone."""
+    from types import SimpleNamespace
+
+    protocol = label
+    real, oracle = _drain_pair(protocol, batch_size=8, pending_capacity=4)
+    W = 12
+
+    def out(committed_rows, pend_dropped):
+        committed = np.zeros(W, bool)
+        committed[committed_rows] = True
+        executed = np.zeros(W, bool)
+        executed[[4, 5]] = True
+        return SimpleNamespace(
+            order=np.array([5, 4] + [w for w in range(W) if w not in (4, 5)], np.int32),
+            executed=executed, committed=committed,
+            work_src=np.array([-1, -1, -1, -1] + [1] * 8, np.int32),
+            work_seq=np.array([-1, -1, -1, -1] + list(range(1, 9)), np.int32),
+            pend_dropped=np.int32(pend_dropped),
+        )
+
+    def register(d):
+        d._cmds.clear()
+        d._requeue.clear()
+        for dot, cmd in _puts(range(1, 9))[0]:
+            d._cmds[d._packed(dot.source, dot.sequence)] = (dot, cmd)
+
+    errors = []
+    for d in (real, oracle):
+        register(d)
+        with pytest.raises(RuntimeError, match=f"{label} .*committed-but-{noun}") as exc:
+            d._drain_and_carry(out([6, 7, 8, 9, 10], 2), label, noun)
+        errors.append(str(exc.value))
+    assert errors[0] == errors[1]
+
+    requeued = []
+    for d in (real, oracle):
+        register(d)
+        got = d._drain_and_carry(out([10, 11], 2), label, noun)
+        assert [r.rifl.sequence for r in got] == [2, 1]  # device order
+        requeued.append([dot.sequence for dot, _ in d.take_requeue()])
+        assert sorted(d._cmds) == [d._packed(1, s) for s in (3, 4, 7, 8)]
+    assert requeued[0] == requeued[1] == [5, 6]
+
+    register(real)
+    walked = real.drain_rows_walked
+    real._drain_and_carry(out([10, 11], 0), label, noun)
+    assert real.drain_rows_walked == walked + 2 and not real.has_requeue
+    assert len(real._cmds) == 6
+
+
+@pytest.mark.parametrize("seq", [0, 1, 2**31 - 2, -1])
+def test_the_key_column_is_packed_row_by_row(seq):
+    """`_packed_column` is `_packed` for the identity columns the rounds
+    return: `int32`, sources 0..12, sequences to the window's top; and
+    for an empty pending slot's (-1, -1)."""
+    from fantoch_tpu.run.device_runner import _DriverCore
+
+    sources = list(range(13)) if seq >= 0 else [-1]
+    work_src = np.array(sources, np.int32)
+    work_seq = np.full(len(sources), seq, np.int32)
+    rows = np.arange(len(sources))[::-1]
+    column = _DriverCore._packed_column(work_src, work_seq, rows)
+    assert column == [
+        _DriverCore._packed(work_src[w], work_seq[w]) for w in rows.tolist()
+    ]
+    assert all(type(packed) is int for packed in column)
+    if seq >= 0:
+        assert column == [(src << 32) | seq for src in reversed(sources)]
 
 
 def test_runtime_resolves_depth_from_config():
