@@ -163,6 +163,11 @@ def _backend_banner(backend: dict) -> str:
             else ""
         )
         + (f" resolver={backend['resolver']}" if "resolver" in backend else "")
+        + (
+            f" round={backend['round']} accept_quorum={backend['accept_quorum']}"
+            if "round" in backend
+            else ""
+        )
         + f" compile_cache={backend['compile_cache_dir']}]"
     )
 

@@ -194,6 +194,10 @@ class _DriverCore(PipelineCore):
     # what resolves the round's dependency graph (mesh_step.resolver_name);
     # None where the round executes in clock or slot order
     resolver: Optional[str] = None
+    # the leader round's name and its accept quorum (f + 1); None for the
+    # leaderless rounds
+    round_name: Optional[str] = None
+    accept_quorum: Optional[int] = None
 
     def _init_core(
         self,
@@ -221,6 +225,9 @@ class _DriverCore(PipelineCore):
         # among them) and, when the device dropped rows, the overflow's
         # candidates — not the working set
         self.drain_rows_walked = 0
+        # commands an overflow of the device's pending buffer handed back
+        # for the caller to submit again (take_requeue)
+        self.requeued = 0
         self.stable_watermark = 0
         # where a program made ready before serving takes its columns on
         # the mesh, by the rounds it carries (``_precompile``); empty for
@@ -276,20 +283,27 @@ class _DriverCore(PipelineCore):
             key_width=key_width,
         )
 
-    def _assemble(self, batch: List[Tuple[Dot, Command]]):
-        """The dot-keyed drivers' assembly (Newt/Caesar): fill the
-        fixed-size key/src/seq columns and register commands under
-        packed (source, window sequence)."""
+    def _column_specs(self):
+        """What ``_assemble`` stages for one round, and so what the
+        round's program takes after the state: (name, shape, dtype, fill)
+        a column.  Here the key/src/seq columns of the rounds that order
+        by key (dep-commit, Newt, Caesar); the leader round has its own."""
         from fantoch_tpu.parallel.mesh_step import KEY_PAD
 
-        assert len(batch) <= self.batch_size
-        self._ensure_seq_window(batch)
         b = self.batch_size
-        key, src, seq = self._staging(
+        return (
             ("key", (b, self.key_width), np.int32, KEY_PAD),
             ("src", (b,), np.int32, 0),
             ("seq", (b,), np.int32, 0),
         )
+
+    def _assemble(self, batch: List[Tuple[Dot, Command]]):
+        """The dot-keyed drivers' assembly (Newt/Caesar): fill the
+        fixed-size key/src/seq columns and register commands under
+        packed (source, window sequence)."""
+        assert len(batch) <= self.batch_size
+        self._ensure_seq_window(batch)
+        key, src, seq = self._staging(*self._column_specs())
         self._assemble_rows(batch, key, src, seq)
         return key, src, seq
 
@@ -308,20 +322,19 @@ class _DriverCore(PipelineCore):
 
     def _precompile(self, jitted, S: int = 1):
         """The program of ``S`` rounds a dispatch, ready before serving:
-        ``jitted`` lowered on the real state's and the dot-keyed columns'
-        shapes and compiled, or loaded, through the persistent compile
-        cache (the jit's own cache is not touched), under one
-        ``precompile`` span.  Returns the executable for the caller to
-        keep and call; its dispatches then compile nothing, and their
-        columns go straight to where it takes them
+        ``jitted`` lowered on the real state's and the driver's columns'
+        shapes (``_column_specs``) and compiled, or loaded, through the
+        persistent compile cache (the jit's own cache is not touched),
+        under one ``precompile`` span.  Returns the executable for the
+        caller to keep and call; its dispatches then compile nothing, and
+        their columns go straight to where it takes them
         (``_columns_to_device``)."""
         import jax
 
-        b, kw = self.batch_size, self.key_width
         lead = () if S == 1 else (S,)
         columns = tuple(
-            jax.ShapeDtypeStruct(lead + shape, np.int32)
-            for shape in ((b, kw), (b,), (b,))
+            jax.ShapeDtypeStruct(lead + shape, dtype)
+            for _name, shape, dtype, _fill in self._column_specs()
         )
         with self.stages.span("precompile", S):
             program = jitted.lower(self._state, *columns).compile()
@@ -331,6 +344,17 @@ class _DriverCore(PipelineCore):
     @property
     def precompiled_programs(self) -> int:
         return len(self._column_shardings)
+
+    def _plain_round_ahead(self, lengths: Sequence[int]) -> List[int]:
+        """``precompile_chains`` of a driver whose chain is S plain
+        rounds (Caesar, Paxos): every length is ready once the round is,
+        so the round is compiled, or loaded, before serving
+        (``_precompile``), not traced and compiled by the first client's
+        first dispatch.  A round that cannot be compiled raises here, at
+        start-up: nothing could be served without it."""
+        if 1 not in self._column_shardings:
+            self._step = self._precompile(self._step)
+        return list(lengths)
 
     def _enqueue(self, columns):
         """Submit one device round over the assembled columns; returns
@@ -405,6 +429,7 @@ class _DriverCore(PipelineCore):
             if entry is not None:
                 requeued += 1
                 self._requeue.append(entry)
+        self.requeued += requeued
         if requeued:
             logger.warning(
                 "%s device pending overflow: re-queueing %d commands",
@@ -734,14 +759,8 @@ class DeviceDriver(_DriverCore):
             f"batch {len(batch)} exceeds the compiled batch size "
             f"{self.batch_size}; chunk at the caller"
         )
-        from fantoch_tpu.parallel.mesh_step import KEY_PAD
-
         b = self.batch_size
-        key, src, seq = self._staging(
-            ("key", (b, self.key_width), np.int32, KEY_PAD),
-            ("src", (b,), np.int32, 0),
-            ("seq", (b,), np.int32, 0),
-        )
+        key, src, seq = self._staging(*self._column_specs())
         if self._next_gid + b >= self.GID_RESET_THRESHOLD:
             assert self._undrained == 0, (
                 "gid epoch reset with a pipelined round in flight; "
@@ -817,6 +836,7 @@ class DeviceDriver(_DriverCore):
             for gid in dropped:
                 entry = self._cmds.pop(gid, None)
                 if entry is not None:
+                    self.requeued += 1
                     self._requeue.append(entry)
         return results
 
@@ -1206,15 +1226,7 @@ class CaesarDeviceDriver(_DriverCore):
         )
         self._pend_cap = pending_capacity
 
-    def precompile_chains(self, lengths: Sequence[int]) -> List[int]:
-        """A chain here is S plain rounds, so every length is ready once
-        the round is: it is compiled, or loaded, before serving
-        (``_precompile``), not traced and compiled by the first client's
-        first dispatch.  A round that cannot be compiled raises here, at
-        start-up: nothing could be served without it."""
-        if 1 not in self._column_shardings:
-            self._step = self._precompile(self._step)
-        return list(lengths)
+    precompile_chains = _DriverCore._plain_round_ahead
 
     def _execute(self, _tok, out) -> List[ExecutorResult]:
         """Execute one fetched round's wait-cleared commands in
@@ -1254,6 +1266,7 @@ class PaxosDeviceDriver(_DriverCore):
     """
 
     key_width = None  # slot order needs no key rows: any command width
+    round_name = "paxos_slot"
 
     def __init__(
         self,
@@ -1288,9 +1301,22 @@ class PaxosDeviceDriver(_DriverCore):
         # no host identity mirror (PaxosStepOutput.work_src/work_seq);
         # fast_paths stays 0 — leader-based: every commit is the one path
         self._pend_cap = pending_capacity
+        self.accept_quorum = f + 1
         self._slot_base = 0  # slots below base + exec_frontier executed
         self._next_slot = 0  # host mirror of state.next_slot
-        self.slot_epochs = 0
+        self.slot_epochs = 0  # slot-space rebases (device_slot_epochs)
+
+    precompile_chains = _DriverCore._plain_round_ahead
+
+    def _column_specs(self):
+        """The leader round takes no key rows: which rows of the batch
+        hold a command, and the dots."""
+        b = self.batch_size
+        return (
+            ("valid", (b,), bool, False),
+            ("src", (b,), np.int32, 0),
+            ("seq", (b,), np.int32, 0),
+        )
 
     # the slot log is an int32 counter growing one per command; rebase
     # against the contiguous exec frontier (every live slot is at or
@@ -1360,12 +1386,7 @@ class PaxosDeviceDriver(_DriverCore):
                     "pinned too far behind to rebase"
                 )
         self._ensure_seq_window(batch)
-        b = self.batch_size
-        valid, src, seq = self._staging(
-            ("valid", (b,), bool, False),
-            ("src", (b,), np.int32, 0),
-            ("seq", (b,), np.int32, 0),
-        )
+        valid, src, seq = self._staging(*self._column_specs())
         for i, (dot, cmd) in enumerate(batch):
             valid[i] = True
             src[i] = dot.source
@@ -1395,7 +1416,9 @@ class PaxosDeviceDriver(_DriverCore):
         self._next_slot += n_batch - int(out.pend_dropped)
         self.stable_watermark = self._slot_base + int(out.exec_frontier)
         # every commit in the leader class takes the same (slow) path: one
-        # accept round — mirror the tally convention of the object runner
+        # accept round — mirror the tally convention of the object runner.
+        # The benchmark reads it as slow_path_share.sat 100: the tally's
+        # name for the leader's one path, not a retry
         self.slow_paths += int(executed.sum())
 
         results = self._execute_ordered(order, executed, work_src, work_seq)
@@ -2115,6 +2138,7 @@ class DeviceRuntime:
             "rounds": d.rounds,
             "executed": d.executed,
             "drain_rows_walked": d.drain_rows_walked,
+            "requeued": d.requeued,
             "fast_paths": d.fast_paths,
             "slow_paths": d.slow_paths,
             "in_flight": d.in_flight,
@@ -2199,7 +2223,14 @@ class DeviceRuntime:
         script can tell a chip run from a CPU run."""
         from fantoch_tpu.hostenv import device_report
 
-        resolver = self.driver.resolver
+        d = self.driver
+        # what the driver says of its round: the dep-commit round's
+        # resolver, the leader round's name and accept quorum
+        named = {
+            "resolver": d.resolver,
+            "round": d.round_name,
+            "accept_quorum": d.accept_quorum,
+        }
         return {
             **device_report(),
             "mesh_shape": {
@@ -2208,7 +2239,7 @@ class DeviceRuntime:
             # the shards whose replica rows each device holds, in the
             # mesh's device order
             "shards_on_device": self._shards_on_device,
-            **({"resolver": resolver} if resolver else {}),
+            **{key: value for key, value in named.items() if value},
         }
 
     def _write_metrics_snapshot(self) -> None:

@@ -176,8 +176,9 @@ class PipelineCore:
 
     Required subclass attribute: ``batch_size`` (the compiled per-round
     row capacity, read by the occupancy counters) must be set before
-    ``_init_pipeline``.  ``seq_epochs`` (window-advance tally) is
-    reported when present, 0 otherwise.
+    ``_init_pipeline``.  ``seq_epochs`` (window-advance tally) and
+    ``slot_epochs`` (the leader round's slot-space rebases) are reported
+    when present, 0 otherwise.
     """
 
     def _init_pipeline(self) -> None:
@@ -295,8 +296,8 @@ class PipelineCore:
         before serving.  Here a chain is S plain rounds and has no
         program of its own, so every length is; a driver with a fused
         program per length (NewtDeviceDriver) compiles or loads them, and
-        one that makes its plain round ready ahead (CaesarDeviceDriver)
-        does that here."""
+        one that makes its plain round ready ahead (CaesarDeviceDriver,
+        PaxosDeviceDriver) does that here."""
         return list(lengths)
 
     @property
@@ -493,4 +494,5 @@ class PipelineCore:
             "device_pipeline_depth": self.pipeline_depth,
             "device_pipelined_rounds": self.pipelined_rounds,
             "device_seq_epochs": getattr(self, "seq_epochs", 0),
+            "device_slot_epochs": getattr(self, "slot_epochs", 0),
         }

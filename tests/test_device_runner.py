@@ -2155,10 +2155,12 @@ def test_served_round_self_times_telescope(protocol, tmp_path):
     assert by_name["fetch"]["parent"] == by_name["assemble"]["parent"] == "step"
     assert by_name["step"]["thread"] != by_name["deliver"]["thread"]  # pool vs loop
     assert by_name["handoff"]["thread"] == by_name["step"]["thread"]
-    for row in rows:  # a step lies inside the round of its number
+    # a step lies inside a round of its number (a round that only retires the round in
+    # flight makes no dispatch, so it shares its number with the round after it)
+    for row in rows:
         if row["name"] == "step":
-            whole = next(r for r in rows if r["name"] == "round" and r["round"] == row["round"])
-            assert whole["t0_ns"] <= row["t0_ns"] <= row["t1_ns"] <= whole["t1_ns"]
+            assert any(whole["t0_ns"] <= row["t0_ns"] <= row["t1_ns"] <= whole["t1_ns"]
+                       for whole in rows if whole["name"] == "round" and whole["round"] == row["round"])
 
 
 def test_round_spans_are_written_only_beside_a_metrics_or_telemetry_file(tmp_path, monkeypatch):

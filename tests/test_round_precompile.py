@@ -1,9 +1,10 @@
 """A plain round made ready before the first client (`_DriverCore._precompile`,
 beside `tests/test_chain_precompile.py`, which holds the Newt ladder): the
-Caesar driver's round is compiled or loaded inside `DeviceRuntime.start()`,
-so its first dispatch compiles nothing; the EPaxos and FPaxos drivers start
-as they did; and the two tests that count the plane programs' jit signatures
-still pass after a precompile in the same process."""
+Caesar driver's round and the leader round of the Paxos driver (with its own
+columns: `valid` is `bool`) are compiled or loaded inside
+`DeviceRuntime.start()`, so their first dispatch compiles nothing; the EPaxos
+driver starts as it did; and the two tests that count the plane programs' jit
+signatures still pass after a precompile in the same process."""
 
 import asyncio
 import json
@@ -12,7 +13,7 @@ import pytest
 
 from fantoch_tpu.core import Command, Config, Dot, KVOp, Rifl
 from fantoch_tpu.observability import device as obs
-from fantoch_tpu.run.device_runner import CaesarDeviceDriver, DeviceRuntime
+from fantoch_tpu.run.device_runner import CaesarDeviceDriver, DeviceRuntime, PaxosDeviceDriver
 from fantoch_tpu.run.harness import free_port
 from tests.test_chain_precompile import LADDER, _tallies
 
@@ -23,9 +24,22 @@ def _batch(first, count):
             for i in range(count)]
 
 
-def test_the_caesar_round_is_one_program_ready_once_and_then_dispatches_compile_nothing():
+# the drivers whose plain round is made ready ahead, and the tally that counts their
+# commits: every Caesar command is fast while seven are live, and the leader class has
+# one path, which the tally calls slow
+AHEAD = {
+    "caesar": (lambda: CaesarDeviceDriver(7, batch_size=8, key_buckets=64, pending_capacity=8),
+               "fast_paths"),
+    "fpaxos": (lambda: PaxosDeviceDriver(5, f=1, batch_size=8, pending_capacity=8), "slow_paths"),
+}
+ahead = pytest.mark.parametrize("protocol", AHEAD)
+
+
+@ahead
+def test_the_round_is_one_program_ready_once_and_then_dispatches_compile_nothing(protocol):
     obs.subscribe_recompiles()
-    driver = CaesarDeviceDriver(7, batch_size=8, key_buckets=64, pending_capacity=8)
+    build, tally = AHEAD[protocol]
+    driver = build()
     assert driver.precompiled_programs == 0 and driver.stages.n["precompile"] == 0
     assert driver.precompile_chains(LADDER) == LADDER  # a chain is S plain rounds
     assert driver.precompiled_programs == 1 and driver.stages.n["precompile"] == 1
@@ -37,7 +51,7 @@ def test_the_caesar_round_is_one_program_ready_once_and_then_dispatches_compile_
     executed += len(driver.step_chained_pipelined([_batch(32, 8), _batch(40, 8)]))
     executed += len(driver.flush_pipeline())
     assert _tallies() == before and driver.stages.n["precompile"] == 1
-    assert executed == 40 and driver.in_flight == 0 and driver.fast_paths == 40
+    assert executed == 40 and driver.in_flight == 0 and getattr(driver, tally) == 40
 
 
 def _serve(protocol, tmp_path, commands=40):
@@ -75,21 +89,28 @@ def _serve(protocol, tmp_path, commands=40):
     return asyncio.run(go())
 
 
-def test_a_caesar_server_has_its_round_before_it_listens_and_says_how_long_that_took(tmp_path):
-    runtime, first, last, started, served = _serve("caesar", tmp_path)
+@ahead
+def test_a_server_has_its_round_before_it_listens_and_says_how_long_that_took(protocol, tmp_path):
+    runtime, first, last, started, served = _serve(protocol, tmp_path)
     assert first["precompiled_programs"] == 0 and first["stage_precompile_n"] == 0
     assert last["precompiled_programs"] == 1 and last["stage_precompile_n"] == 1
     assert last["stage_precompile_ms"] > 0
     assert served == started  # the first dispatch, and every one after it, compiled nothing
     assert runtime._chain_tuner.chain_max == 8  # every chain length is plain rounds: all ready
     # what the round's trace left is frozen out of the collector with the rest of start-up
-    assert last["gc_frozen_objects"] > 0 and last["fast_paths"] == 40
+    assert last["gc_frozen_objects"] > 0 and last[AHEAD[protocol][1]] == 40
+    if protocol == "fpaxos":  # what the leader driver knows of its round is in the snapshot
+        assert last["backend"]["round"] == "paxos_slot" and last["backend"]["accept_quorum"] == 4
+        assert last["requeued"] == 0 and last["device_slot_epochs"] == 0
+        assert last["stable_watermark"] == last["executed"] == 40  # a dense log: a slot a command
+    else:
+        assert "round" not in last["backend"] and "accept_quorum" not in last["backend"]
 
 
-@pytest.mark.parametrize("protocol", ["epaxos", "fpaxos"])
+@pytest.mark.parametrize("protocol", ["epaxos"])
 def test_the_other_plain_round_drivers_start_as_before(protocol, tmp_path):
-    """The mechanism is switched on for Caesar only: the dep-commit and the
-    leader round are still built by their first dispatch."""
+    """The mechanism is switched on for Caesar and FPaxos only: the
+    dep-commit round is still built by its first dispatch."""
     runtime, first, last, started, served = _serve(protocol, tmp_path)
     assert first["precompiled_programs"] == last["precompiled_programs"] == 0
     assert last["stage_precompile_n"] == 0 and last["stage_precompile_ms"] == 0
