@@ -192,6 +192,7 @@ class Command:
 
 
 _KIND_CODE = {kind: code for code, kind in enumerate(KINDS)}
+_tuple_new = tuple.__new__
 
 
 def _restore_command(source: int, sequence: int, shard, key=None, kind=0, value=None) -> Command:
@@ -199,9 +200,17 @@ def _restore_command(source: int, sequence: int, shard, key=None, kind=0, value=
     carries: ``shard, key, kind, value`` of the flat form, or in
     ``shard``'s place the general form's ``((shard, ((key, ((kind,
     value), ...)), ...)), ...)``."""
-    rifl = Rifl(source, sequence)
+    # tuple.__new__: Rifl's own __new__ is a Python-level call, and this
+    # runs once a frame on the server's loop
+    rifl = _tuple_new(Rifl, (source, sequence))
     if key is not None:
-        return Command.from_single(rifl, shard, key, KVOp(KINDS[kind], value))
+        # Command.from_single, spelled out (kind code 0 is the one read)
+        cmd = Command.__new__(Command)
+        cmd._rifl = rifl
+        cmd._shard_to_ops = {shard: {key: (KVOp(KINDS[kind], value),)}}
+        cmd._read_only = not kind
+        cmd._total_key_count = 1
+        return cmd
     # the constructor's scan, folded into the pass that builds the ops
     shard_to_ops: Dict[ShardId, Dict[Key, Tuple[KVOp, ...]]] = {}
     reads = writes = total = 0

@@ -85,7 +85,7 @@ from fantoch_tpu.run.prelude import (
     Submit,
     ToClient,
 )
-from fantoch_tpu.run.rw import Rw, frame
+from fantoch_tpu.run.rw import ProtocolError, Rw, reply_frame
 from fantoch_tpu.utils import key_hash, logger
 
 Address = Tuple[str, int]
@@ -1243,12 +1243,6 @@ class CaesarDeviceDriver(_DriverCore):
         return self._drain_and_carry(out, "caesar", "blocked")
 
 
-class ProtocolError(Exception):
-    """A client broke the wire contract: kills only its session, never
-    the runtime (the per-connection failure isolation of the reference's
-    client task, fantoch/src/run/task/process.rs:320-325)."""
-
-
 class PaxosDeviceDriver(_DriverCore):
     """Host control loop around the device-resident leader-based slot
     round (parallel/mesh_step.paxos_protocol_step): replica 0 assigns
@@ -1490,7 +1484,8 @@ class _DeviceClientSession:
         """Route one round's per-key partials of this session's commands,
         in the order the round executed them: aggregate each, encode a
         frame for every ``CommandResult`` that completes (one per shard
-        of the command), and hand the connection all of them in one
+        of the command; from its values, ``rw.reply_frame``: no
+        ``ToClient`` is built), and hand the connection all of them in one
         write.  Returns how many rifls are now fully answered; those are
         gone from ``runtime.rifl_sessions`` whether or not the write went
         through."""
@@ -1528,7 +1523,7 @@ class _DeviceClientSession:
             if tracing:
                 tracer.span("executed", rifl, pid=runtime.process_id)
                 tracer.edge("s", "Reply", runtime.process_id, 0, 0, rifl=rifl)
-            frames.append(frame(ToClient(done)))
+            frames.append(reply_frame(done))
             left = shards_left[rifl] - 1
             if left:
                 shards_left[rifl] = left
@@ -1547,6 +1542,7 @@ class _DeviceClientSession:
             runtime._reply_writes += 1
             runtime._reply_bytes += len(data)
             runtime._shard_replies += len(frames)
+            runtime._reply_plain_frames += len(frames)
             runtime._commands_completed += completed
             runtime._multi_shard_completed += multi_shard
             runtime._gets_replied += gets
@@ -1953,14 +1949,15 @@ class DeviceRuntime:
         # pool's, sampled where the tallies are published and by the probe
         self.account = ThreadAccount()
         # the per-command boundaries: two clock reads each, no span
-        # [ns, frames, reads, CPU ns, timed ns, due ns] of turning socket
-        # reads into messages, shared with every Rw
-        self._decode_tally = [0, 0, 0, 0, 0, 0]
+        # [ns, frames, reads, CPU ns, timed ns, due ns, frames of a kind]
+        # of turning socket reads into messages, shared with every Rw
+        self._decode_tally = [0, 0, 0, 0, 0, 0, 0]
         self._flush_ns = 0  # awaits of rw.flush() in the sessions
         self._flushes = 0
         self._reply_writes = 0  # writes of a round's frames to a connection
         self._reply_bytes = 0
         self._shard_replies = 0  # CommandResult frames: one per touched shard
+        self._reply_plain_frames = 0  # ... encoded from their values (rw.reply_frame)
         self._commands_completed = 0  # a command's last shard replied
         self._multi_shard_completed = 0  # ... of a command over several shards
         self._gets_replied = 0  # read-only commands among the completed
@@ -2131,7 +2128,9 @@ class DeviceRuntime:
         )
 
         d = self.driver
-        decode_ns, decoded, reads, decode_cpu_ns, decode_timed_ns, _ = self._decode_tally
+        decode_ns, decoded, reads, decode_cpu_ns, decode_timed_ns, _, plain_decoded = (
+            self._decode_tally
+        )
         self._tallies = {
             "submitted": self.submitted,
             "replied": self.replied,
@@ -2162,6 +2161,9 @@ class DeviceRuntime:
             "session_decode_cpu_ms": round(decode_cpu_ns / 1e6, 3),
             "session_decode_timed_ms": round(decode_timed_ns / 1e6, 3),
             "session_decoded": decoded,
+            # ... of them the frames that said their kind in a byte and
+            # went as plain values (a Submit; the handshake is a pickle)
+            "session_plain_decoded": plain_decoded,
             "session_reads": reads,
             "session_admit_ms": round(self._admit_ns / 1e6, 3),
             "session_admit_cpu_ms": round(self._admit_cpu_ns / 1e6, 3),
@@ -2189,6 +2191,7 @@ class DeviceRuntime:
             # frames written, commands whose last shard replied, and
             # those of them that touched more than one shard
             "shard_replies": self._shard_replies,
+            "reply_plain_frames": self._reply_plain_frames,
             "commands_completed": self._commands_completed,
             "multi_shard_completed": self._multi_shard_completed,
             # reads: read-only commands completed, the bytes of the values

@@ -104,11 +104,15 @@ class Unregister:
 @dataclass
 class Submit:
     """Client -> server: one command to order and execute.  The hot
-    frame of the way in, so it goes as the command's plain values under
-    one callable (``Command.__reduce__``: rifl source and sequence, then
-    shard, key, kind code and value of a one-key one-op command, 160-odd
-    bytes at a 100-byte value; or the nested ``shard -> key -> ops``
-    tuples of any other shape), with no class path or attribute name."""
+    frame of the way in, so it goes as the command's plain values
+    (``Command.__reduce__``: rifl source and sequence, then shard, key,
+    kind code and value of a one-key one-op command, 140-odd bytes at a
+    100-byte value; or the nested ``shard -> key -> ops`` tuples of any
+    other shape), with no class path or attribute name: on a client
+    connection under a kind byte (run/rw.py ``KIND_SUBMIT``), in a
+    generic pickle (a message that carries one, a deepcopy) under the
+    one callable :func:`_submit`, the form a sender before PR 39 framed
+    and a receiver still reads."""
 
     cmd: Command
 
@@ -117,7 +121,8 @@ class Submit:
 
 
 def _submit(*values) -> Submit:
-    """Unpickle a :class:`Submit` from its ``Command``'s values."""
+    """A :class:`Submit` from its ``Command``'s values: what unpickles
+    one, and what ``rw`` calls on a ``KIND_SUBMIT`` frame's tuple."""
     return Submit(_restore_command(*values))
 
 
@@ -127,13 +132,17 @@ class ToClient:
 
     def __reduce__(self):
         # the reply is the hot frame of the client plane: the result's
-        # plain values under one callable, instead of two class paths,
-        # the attribute names and a BUILD each
+        # plain values, instead of two class paths, the attribute names
+        # and a BUILD each; under a kind byte on a client connection
+        # (run/rw.py KIND_TO_CLIENT), under this one callable in a
+        # generic pickle
         return _to_client, self.cmd_result.__reduce__()[1]
 
 
 def _to_client(*values) -> ToClient:
-    """Unpickle a :class:`ToClient` from its ``CommandResult``'s values."""
+    """A :class:`ToClient` from its ``CommandResult``'s values: what
+    unpickles one, and what ``rw`` calls on a ``KIND_TO_CLIENT`` frame's
+    tuple."""
     return ToClient(_restore_result(*values))
 
 

@@ -1,17 +1,30 @@
 """Length-delimited framing over asyncio streams.
 
 Reference: fantoch/src/run/rw/{mod,connection}.rs — the reference frames
-with tokio's LengthDelimitedCodec + bincode; here frames are a u32
-big-endian length prefix + pickled payload.  The two hot messages of the
-client plane pickle as plain values under one callable each, with no
-class path or attribute name per field: the reply (``ToClient``, run/
-prelude.py) and the ``Submit`` (a command's rifl numbers, then ``shard,
-key, kind code, value`` where it has one key and one op, nested ``shard
--> key -> ops`` tuples otherwise; core/command.py).  ``write`` queues
-without flushing, ``send`` queues and flushes, mirroring the reference's
-explicit flush control (rw/mod.rs:55-84) that lets writers batch small
-protocol messages into one syscall; ``recv`` reads one frame, ``recv_all``
-every whole frame a socket read brought.
+with tokio's LengthDelimitedCodec + bincode; here a frame is a u32
+big-endian length prefix, then a payload whose first byte says what it is.
+
+- ``KIND_SUBMIT`` / ``KIND_TO_CLIENT`` (a byte below ``0x80``): the two hot
+  messages of the client plane.  The rest of the payload is the pickle of
+  the message's plain values as one tuple and names no callable: a
+  ``Submit``'s command as ``Command.__reduce__`` gives it (the rifl's two
+  numbers, then ``shard, key, kind code, value`` where it has one key and
+  one op, nested ``shard -> key -> ops`` tuples otherwise; core/command.py),
+  a ``ToClient``'s result as ``(source, sequence, key count, results)``.
+  The receiver calls the kind's restorer on the tuple itself.
+- ``0x80`` (how a pickle of protocol 2 and up starts): a pickle of the
+  message, whatever it is.  Every other message goes so (the handshakes,
+  ``Register``, ``Overloaded``, the peers' and links' messages), and so did
+  the two hot ones until PR 39 (under one callable each, ``prelude._submit``
+  and ``prelude._to_client``): a receiver still reads that form.
+
+A sender writes the one form of a message and a receiver reads either by
+the first byte; nothing chooses between them.  Any other first byte is a
+:class:`ProtocolError`.  ``write`` queues without flushing, ``send`` queues
+and flushes, mirroring the reference's explicit flush control
+(rw/mod.rs:55-84) that lets writers batch small protocol messages into one
+syscall; ``recv`` reads one frame, ``recv_all`` every whole frame a socket
+read brought.
 """
 
 from __future__ import annotations
@@ -23,9 +36,22 @@ import struct
 from time import monotonic_ns, thread_time_ns
 from typing import Any, List, Optional
 
+from fantoch_tpu.core.command import CommandResult, _restore_command
 from fantoch_tpu.observability.device import CPU_PAIR_EVERY_NS
+from fantoch_tpu.run.prelude import Submit, ToClient, _submit, _to_client
 
 _LEN = struct.Struct(">I")
+# how a frame of a kind starts: its length (kind byte and pickle), its kind
+_HEAD = struct.Struct(">IB")
+KIND_SUBMIT = 0x01
+KIND_TO_CLIENT = 0x02
+# the first byte of a pickle of protocol 2 and up (``pickle.PROTO``): a
+# kind is a byte below it
+_PICKLE = 0x80
+_SUBMIT, _TO_CLIENT = bytes((KIND_SUBMIT,)), bytes((KIND_TO_CLIENT,))
+# kind -> what makes the message of the values its payload pickles
+_RESTORERS = {KIND_SUBMIT: _submit, KIND_TO_CLIENT: _to_client}
+_PROTOCOL = pickle.HIGHEST_PROTOCOL
 # what one ``recv_all`` asks the stream for: more than a ``StreamReader``
 # holds (it pauses its transport above twice its ``limit``), so a read
 # takes all that is buffered and the reader's limit is the bound
@@ -36,19 +62,49 @@ _READ_ALL = 1 << 24
 _LINK = struct.Struct(">BQ")
 
 
+class ProtocolError(Exception):
+    """A client broke the wire contract: kills only its session, never
+    the runtime (the per-connection failure isolation of the reference's
+    client task, fantoch/src/run/task/process.rs:320-325)."""
+
+
 def serialize(value: Any) -> bytes:
-    return pickle.dumps(value, protocol=pickle.HIGHEST_PROTOCOL)
+    """A frame's payload: a ``Submit`` or a ``ToClient`` as its kind byte
+    and the pickle of its plain values, anything else as its pickle."""
+    cls = type(value)
+    if cls is Submit:
+        return _SUBMIT + pickle.dumps(value.cmd.__reduce__()[1], _PROTOCOL)
+    if cls is ToClient:
+        return _TO_CLIENT + pickle.dumps(value.cmd_result.__reduce__()[1], _PROTOCOL)
+    return pickle.dumps(value, _PROTOCOL)
 
 
-def deserialize(payload: bytes) -> Any:
-    return pickle.loads(payload)
+def deserialize(payload) -> Any:
+    """The message of a frame's payload (``bytes`` or a view of them), by
+    what its first byte says: a pickle, or the values of a kind."""
+    if not payload:
+        raise ProtocolError("empty frame")
+    kind = payload[0]
+    if kind == _PICKLE:
+        return pickle.loads(payload)
+    restore = _RESTORERS.get(kind)
+    if restore is None:
+        raise ProtocolError(f"unknown frame kind {kind:#04x}")
+    return restore(*pickle.loads(memoryview(payload)[1:]))
 
 
 def frame(value: Any) -> bytes:
     """One frame as it goes on the wire: u32 big-endian length, then the
-    pickle."""
+    payload."""
     payload = serialize(value)
     return _LEN.pack(len(payload)) + payload
+
+
+def reply_frame(result: CommandResult) -> bytes:
+    """``frame(ToClient(result))`` from the result's values, with no
+    ``ToClient`` built on the way."""
+    payload = pickle.dumps(result.__reduce__()[1], _PROTOCOL)
+    return _HEAD.pack(len(payload) + 1, KIND_TO_CLIENT) + payload
 
 
 async def connect_with_retry(
@@ -84,13 +140,15 @@ class Rw:
         decode_tally: Optional[List[int]] = None,
     ):
         """``decode_tally``: ``[ns, frames, reads, CPU ns, timed ns, due
-        ns]``, which the owner shares among its connections; ``recv`` adds
-        each frame's unpickle time to it, ``recv_all`` a read's walk and
-        unpickles and the read itself, and at most once in
-        ``CPU_PAIR_EVERY_NS`` the thread's CPU time of the walk beside its
-        wall time (the device runtime's ``session_decode_ms``,
-        ``session_decoded``, ``session_reads``, ``session_decode_cpu_ms``,
-        ``session_decode_timed_ms``)."""
+        ns, frames of a kind]``, which the owner shares among its
+        connections; ``recv`` adds each frame's decode time to it,
+        ``recv_all`` a read's walk and decodes and the read itself, and at
+        most once in ``CPU_PAIR_EVERY_NS`` the thread's CPU time of the
+        walk beside its wall time; both count the frames that said their
+        kind in a byte and named no callable (the device runtime's
+        ``session_decode_ms``, ``session_decoded``, ``session_reads``,
+        ``session_decode_cpu_ms``, ``session_decode_timed_ms``,
+        ``session_plain_decoded``)."""
         self._reader = reader
         self._writer = writer
         self._decode_tally = decode_tally
@@ -110,11 +168,12 @@ class Rw:
         payload = await self._reader.readexactly(length)
         tally = self._decode_tally
         if tally is None:
-            return pickle.loads(payload)
+            return deserialize(payload)
         t0 = monotonic_ns()
-        value = pickle.loads(payload)
+        value = deserialize(payload)
         tally[0] += monotonic_ns() - t0
         tally[1] += 1
+        tally[6] += payload[0] != _PICKLE
         return value
 
     async def recv_all(self) -> Optional[List[Any]]:
@@ -126,6 +185,7 @@ class Rw:
         read, unpack_from, loads, size = (
             self._reader.read, _LEN.unpack_from, pickle.loads, _LEN.size,
         )
+        restorers, submit, restore_command = _RESTORERS.get, Submit, _restore_command
         while True:
             try:
                 data = await read(_READ_ALL)
@@ -144,12 +204,30 @@ class Rw:
             if self._tail:
                 data = self._tail + data
             values: List[Any] = []
-            at, end = 0, len(data)
+            view = memoryview(data)
+            at, end, plain = 0, len(data), 0
             while end - at >= size:
-                stop = at + size + unpack_from(data, at)[0]
+                start = at + size
+                stop = start + unpack_from(data, at)[0]
                 if stop > end:
                     break
-                values.append(loads(data[at + size : stop]))
+                # as deserialize, on the read's own bytes
+                if stop == start:
+                    raise ProtocolError("empty frame")
+                kind = data[start]
+                if kind == KIND_SUBMIT:
+                    # the server's hot frame first, and _submit spelled out:
+                    # two calls a frame less
+                    values.append(submit(restore_command(*loads(view[start + 1 : stop]))))
+                    plain += 1
+                elif kind == _PICKLE:
+                    values.append(loads(view[start:stop]))
+                else:
+                    restore = restorers(kind)
+                    if restore is None:
+                        raise ProtocolError(f"unknown frame kind {kind:#04x}")
+                    values.append(restore(*loads(view[start + 1 : stop])))
+                    plain += 1
                 at = stop
             self._tail = data[at:]
             if tally is not None:
@@ -162,6 +240,7 @@ class Rw:
                 tally[0] += took
                 tally[1] += len(values)
                 tally[2] += bool(values)
+                tally[6] += plain
             if values:
                 return values
 

@@ -1,5 +1,7 @@
 """The way in of the device-step server (run/device_runner.py
-``_DeviceClientSession``): the compact ``Submit`` on the wire, ``Rw.recv_all``
+``_DeviceClientSession``): the ``Submit`` on the wire (since PR 39 its kind
+byte and its command's plain values; tests/test_wire_codec.py has the codec
+alone), ``Rw.recv_all``
 turning a socket read into messages, and the one admit pass per read,
 against a started ``DeviceRuntime`` on the CPU whose session reads from a
 ``StreamReader`` the test feeds, so that what a read holds is exact.
@@ -71,13 +73,13 @@ def test_the_compact_submit_round_trips(name):
     assert rw.frame(Submit(cmd)) == rw._LEN.pack(len(payload)) + payload
 
 
-def test_the_flat_frame_of_a_cells_command_is_under_200_bytes():
+def test_the_flat_frame_of_a_cells_command_is_under_150_bytes():
     put = Command.from_single(Rifl(8191, 123456), 0, "999999", KVOp.put(VALUE))
     assert put.single_key() == (0, "999999")
-    assert len(rw.frame(Submit(put))) < 200
+    assert len(rw.frame(Submit(put))) < 150  # 144: under 200 with PR 28's callable
     two = _cmd({2: {"905": (KVOp.put(VALUE),)}, 1: {"17": (KVOp.put(VALUE),)}})
     assert two.single_key() is None
-    assert len(rw.frame(Submit(two))) < 250  # the shared value goes once
+    assert len(rw.frame(Submit(two))) < 190  # 180 (under 250 then): the shared value goes once
 
 
 def test_a_mixed_command_is_refused_on_the_way_in_as_by_the_constructor():
@@ -102,7 +104,7 @@ def _collect(reads):
 
     async def go():
         reader = asyncio.StreamReader()
-        tally = [0, 0, 0, 0, 0, 0]
+        tally = [0, 0, 0, 0, 0, 0, 0]
         conn = rw.Rw(reader, _Writer(), decode_tally=tally)
         out = []
         pending = asyncio.ensure_future(conn.recv_all())
@@ -130,10 +132,11 @@ def test_recv_all_returns_every_whole_frame_of_a_read_and_keeps_the_tail():
     out, tally = _collect([whole[:cut], whole[cut:]])
     assert out == [msgs[:2], msgs[2:], None]
     assert tally[1:3] == [7, 2] and tally[0] >= tally[4] > 0  # the first read took the CPU pair
+    assert tally[6] == 5  # the Submits went under their kind byte, the other two as pickles
     # a byte at a time: a frame comes out with its last byte, never before
     out, tally = _collect([whole[i:i + 1] for i in range(len(whole))])
     assert out == [[m] for m in msgs] + [None]
-    assert tally[1:3] == [7, 7]  # a read that completes no frame is not counted
+    assert tally[1:3] == [7, 7] and tally[6] == 5  # a read that completes no frame is not counted
 
 
 @pytest.mark.parametrize("kept, error", [(0, None), (3, None), (4, asyncio.IncompleteReadError), (60, asyncio.IncompleteReadError)])
@@ -285,6 +288,8 @@ def test_a_burst_cut_at_any_byte_of_a_frame_gives_the_replies_of_the_whole_burst
             bursts = 1 + len(cuts)
             assert tallies["submitted"] == 6 * bursts == served.runtime.driver.executed
             assert tallies["session_decoded"] == 1 + 6 * bursts  # and the ClientHi
+            assert tallies["session_plain_decoded"] == 6 * bursts  # the handshake is a pickle
+            assert tallies["reply_plain_frames"] == tallies["shard_replies"] == 6 * bursts
             # each half of a cut burst completes a frame (the cut lies between
             # the end of the second frame and the end of the third)
             assert tallies["session_reads"] == 1 + 2 * len(cuts)
@@ -328,6 +333,72 @@ def test_an_unexpected_message_ends_the_session_after_the_submits_before_it():
             return served.tallies()["submitted"]
 
     assert asyncio.run(go()) == 1
+
+
+@pytest.mark.parametrize("kind", [0x00, 0x03, 0x7F, 0xFF])
+def test_an_unknown_kind_byte_ends_the_session_and_closes_its_connection(kind):
+    """The frames of a read before the one of no known kind are lost with
+    the read (the walk raises before the admit pass), the read before it
+    was admitted; the session's transport is closed, the runtime lives."""
+
+    async def go():
+        async with _Served() as served:
+            await served.read(rw.frame(_submit(1, 1, "a")))
+            assert [r.cmd_result.rifl for r in await served.replies(1)] == [Rifl(1, 1)]
+            bad = bytes((kind,)) + rw.serialize(_submit(1, 3, "c"))[1:]
+            await served.read(rw.frame(_submit(1, 2, "b")) + rw._LEN.pack(len(bad)) + bad)
+            await asyncio.wait([served.task], timeout=5)
+            assert isinstance(served.task.exception(), ProtocolError)
+            assert f"unknown frame kind {kind:#04x}" in str(served.task.exception())
+            assert served.writer.closed and not served.runtime.rifl_sessions
+            return served.tallies()["submitted"], served.runtime.failure
+
+    assert asyncio.run(go()) == (1, None)
+
+
+def _old_frame(msg):
+    """``msg`` as a sender before PR 39 framed it: the pickle of the
+    message, which names the one callable that restores it."""
+    payload = pickle.dumps(msg, protocol=pickle.HIGHEST_PROTOCOL)
+    assert payload[0] == 0x80
+    return rw._LEN.pack(len(payload)) + payload
+
+
+def test_the_two_counters_count_the_frames_that_took_the_path():
+    """A burst in today's frames, one in the frames of a sender before
+    PR 39 and one with a rejection: the same replies; ``session_plain_decoded``
+    grows by the first and third alone, ``reply_plain_frames`` with
+    ``shard_replies`` by every executed command's reply and not by the
+    rejection's, which goes through ``Rw.write``."""
+
+    async def go():
+        async with _Served() as served:
+            before = served.tallies()
+            assert (before["session_decoded"], before["session_plain_decoded"]) == (1, 0)
+            assert (before["shard_replies"], before["reply_plain_frames"]) == (0, 0)
+            await served.read(b"".join(rw.frame(m) for m in _burst(0)))
+            new = _told(await served.replies(6))
+            first = served.tallies()
+            assert (first["session_decoded"], first["session_plain_decoded"]) == (7, 6)
+            assert (first["shard_replies"], first["reply_plain_frames"]) == (6, 6)
+            await served.read(b"".join(_old_frame(m) for m in _burst(1)))
+            assert _told(await served.replies(6), 1) == new
+            second = served.tallies()
+            assert (second["session_decoded"], second["session_plain_decoded"]) == (13, 6)
+            assert (second["shard_replies"], second["reply_plain_frames"]) == (12, 12)
+            elsewhere = _submit(2, 9, "a", shard=1)  # not this server's shard: rejected
+            await served.read(rw.frame(elsewhere) + rw.frame(_submit(1, 9, "a")))
+            got = await served.replies(2)
+            assert [(r.cmd_result.rifl, r.cmd_result.ready) for r in got] == [
+                (Rifl(2, 9), True), (Rifl(1, 9), True)]
+            assert got[0].cmd_result.results == {}
+            third = served.tallies()
+            assert (third["session_decoded"], third["session_plain_decoded"]) == (15, 8)
+            assert (third["shard_replies"], third["reply_plain_frames"]) == (13, 13)
+            # every reply on the connection, the rejection's too, is a frame of its kind
+            assert third["reply_writes"] == 3 and third["replied"] == 13
+
+    asyncio.run(go())
 
 
 @pytest.mark.overload
