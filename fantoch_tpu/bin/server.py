@@ -38,7 +38,11 @@ def build_parser() -> argparse.ArgumentParser:
                         "program: 'newt' the timestamp-consensus round, "
                         "'caesar' the timestamp+predecessors round, 'fpaxos' "
                         "the leader-based slot round, anything else the "
-                        "EPaxos-style dep-commit round")
+                        "dep-commit round (dependencies with the read/write "
+                        "split): 'atlas' under Atlas's quorums (n/2 + f, "
+                        "f + 1) and its fast path (every dependency reported "
+                        "by f of the fast quorum), 'epaxos' and 'basic' "
+                        "under EPaxos's (f = n/2, identical reports)")
     parser.add_argument("--id", type=int, default=None,
                         help="process id (required without --device-step)")
     parser.add_argument("--shard-id", type=int, default=0)
@@ -160,6 +164,12 @@ def _backend_banner(backend: dict) -> str:
             " shards_on_device="
             + "|".join(",".join(map(str, held)) for held in shards)
             if shards
+            else ""
+        )
+        + (
+            f" rule={backend['rule']} quorums="
+            + "/".join(map(str, backend["quorums"]))
+            if "rule" in backend
             else ""
         )
         + (f" resolver={backend['resolver']}" if "resolver" in backend else "")

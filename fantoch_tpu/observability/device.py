@@ -382,13 +382,18 @@ class _Span:
     """One open stage: ``with recorder.span(name, round)``.  ``t0`` and
     ``t1`` (``time.monotonic_ns``) stay readable after the block."""
 
-    __slots__ = ("_rec", "name", "round", "parent", "_cpu0", "_note", "t0", "t1")
+    __slots__ = (
+        "_rec", "name", "round", "parent", "_cpu0", "_note", "t0", "t1", "read_rows",
+    )
 
     def __init__(self, rec, name, round_id, parent):
         self._rec = rec
         self.name = name
         self.round = round_id
         self.parent = parent
+        # the reads among what the span's round executed, set inside the
+        # block by a ``round`` span of the dep-commit round; else None
+        self.read_rows = None
         # thread CPU time at entry, for a stage of CPU_STAGES
         self._cpu0 = 0 if name in rec.cpu_ns else None
         self._note = rec._annotation("fantoch/" + name, round=round_id)
@@ -422,7 +427,7 @@ class _Span:
             rec.timed_ns[self.name] += self.t1 - self.t0
         self._note.__exit__(*exc)
         rec._stack().pop()
-        rec.record(self.name, self.t0, self.t1, self.round, self.parent)
+        rec.record(self.name, self.t0, self.t1, self.round, self.parent, self.read_rows)
         return False
 
 
@@ -435,7 +440,7 @@ class StageRecorder:
     ``fantoch/<stage>`` so the span lands in a profiler capture on the
     clock of the device planes (near free while no capture runs), a
     bounded ring of closed spans ``(name, t0_ns, t1_ns, round, thread,
-    parent)`` for :meth:`dump`, and, for ``step``, ``enqueue``, ``fetch``,
+    parent, read_rows)`` for :meth:`dump`, and, for ``step``, ``enqueue``, ``fetch``,
     ``assemble``, ``execute``, ``collect``, ``deliver`` and ``publish``,
     the CPU time of the span's thread (``stage_<name>_cpu_ms``, with the
     wall time of the same spans as ``stage_<name>_timed_ms``: a stage
@@ -463,9 +468,9 @@ class StageRecorder:
         self.cpu_ns: Dict[str, int] = dict.fromkeys(CPU_STAGES, 0)
         self.timed_ns: Dict[str, int] = dict.fromkeys(CPU_STAGES, 0)
         self.cpu_due: Dict[str, int] = dict.fromkeys(CPU_STAGES, 0)
-        self.ring: Deque[Tuple[str, int, int, int, int, Optional[str]]] = deque(
-            maxlen=ring
-        )
+        self.ring: Deque[
+            Tuple[str, int, int, int, int, Optional[str], Optional[int]]
+        ] = deque(maxlen=ring)
         self.stall_ns: Dict[str, int] = dict.fromkeys(STALL_CLASSES, 0)
         # kept stalls: those still waiting for their window of the ring,
         # oldest first, and a heap of (late ns, when due, record)
@@ -487,14 +492,14 @@ class StageRecorder:
         return _Span(self, name, round_id, parent)
 
     def record(self, name: str, t0_ns: int, t1_ns: int, round_id: int = 0,
-               parent: Optional[str] = None) -> None:
+               parent: Optional[str] = None, read_rows: Optional[int] = None) -> None:
         """A closed interval whose ends were read elsewhere (a hand-off
         between threads, a late wake-up): counters and ring, no
         annotation."""
         self.ns[name] = self.ns.get(name, 0) + t1_ns - t0_ns
         self.n[name] = self.n.get(name, 0) + 1
         self.ring.append(
-            (name, t0_ns, t1_ns, round_id, threading.get_ident(), parent)
+            (name, t0_ns, t1_ns, round_id, threading.get_ident(), parent, read_rows)
         )
 
     def stall(self, due_ns: int, woke_ns: int, spent: AccountSample) -> str:
@@ -585,7 +590,9 @@ class StageRecorder:
             json.dump(
                 {
                     "clock": "monotonic_ns",
-                    "columns": ["name", "t0_ns", "t1_ns", "round", "thread", "parent"],
+                    "columns": [
+                        "name", "t0_ns", "t1_ns", "round", "thread", "parent", "read_rows",
+                    ],
                     "spans": list(self.ring),
                     "stalls": stalls,
                 },

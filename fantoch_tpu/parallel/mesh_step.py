@@ -22,13 +22,18 @@ MCollect -> MCollectAck -> [MConsensus -> MConsensusAck] -> MCommit ->
 execute round for B commands on all replicas at once:
 
   1. per-replica dependency computation (scatter/gather over the replica's
-     key-clock shard) — each replica reports the latest conflicting command
-     it knows (``KeyDeps::add_cmd``);
+     two key-clock shards) — each replica reports the latest conflicting
+     commands it knows, with the read/write split of ``KeyDeps::add_cmd``
+     (deps/keys/locked.rs): a read depends on the latest write of each of
+     its keys, a write on the latest write and on the latest read since
+     it, two dependency slots a key;
   2. fast-path check over the **fast quorum only** (the first
      ``fast_quorum_size`` replicas, mirroring the distance-sorted quorum of
      fantoch/src/protocol/base.rs:59-131): EPaxos commits on the fast path
      iff all fast-quorum replicas report identical deps (epaxos.rs:339-345)
-     — here a masked ``pmax == pmin`` along ``replica``;
+     — here a masked ``pmax == pmin`` along ``replica``; Atlas iff every
+     dependency of the union was reported by at least ``f`` of them
+     (atlas.rs, ``QuorumDeps::check_threshold``), always so at ``f`` = 1;
   3. slow path (Synod accept round, fantoch_ps/src/protocol/common/synod/
      single.rs): for fast-path misses the coordinator proposes the *union*
      of fast-quorum deps (= masked max over singletons) at ballot 0 via the
@@ -37,13 +42,15 @@ execute round for B commands on all replicas at once:
      ``acks >= write_quorum_size`` (f + 1);
   4. topological resolution of the committed working set, shared across
      the ``batch`` axis via one small all_gather: with one key a command a
-     row's level is its position in its key's run of step 1's sort and it
-     is blocked iff an uncommitted row stands before it there
+     row's level is read off its place in its key's run of step 1's sort
+     (a segmented scan: reads of one stretch share a level) and it is
+     blocked iff an uncommitted row stands before it there
      (:func:`_resolve_run_position`; the round builds no cycle, so none is
      searched for); with several keys the general resolver
      (ops/graph_resolve.resolve_general);
-  5. state update: scatter-max the committed dots into every replica's
-     key-clock, advance the executed frontier, and compute the GC stability
+  5. state update: scatter-max the executed writes into every replica's
+     key-clock and the executed reads into its read-clock, advance the
+     executed frontier, and compute the GC stability
      watermark = ``pmin`` of all replicas' frontiers (the AEClock meet of
      fantoch/src/protocol/gc.rs:72-116, collapsed to a counter in this
      dense round-based regime).
@@ -71,9 +78,11 @@ KEY_PAD = -1  # empty key slot in a [.., KW] key matrix
 class ReplicaState(NamedTuple):
     """Per-replica device-resident protocol state.
 
-    ``key_clock[R, K]``: global id (see below) of the latest committed
-    command per key bucket, per replica; -1 when none.  The analog of the
-    per-process sequential ``KeyDeps`` map.
+    ``key_clock[R, K]``: global id (see below) of the latest executed
+    *write* per key bucket, per replica; -1 when none.  ``read_clock[R, K]``:
+    the same for the latest executed *read*.  Together the analog of the
+    per-process ``KeyDeps`` map with the read/write split
+    (deps/keys/locked.rs: ``LatestRWDep``).
 
     ``frontier[R]``: number of commands this replica has committed+executed
     (the AEClock frontier of fantoch/src/protocol/gc.rs, collapsed to a
@@ -90,13 +99,15 @@ class ReplicaState(NamedTuple):
     buckets (multi-key commands, command.rs:12-19), padded with KEY_PAD.
     """
 
-    key_clock: jax.Array  # int32[R, K]
+    key_clock: jax.Array  # int32[R, K] — latest executed write
     frontier: jax.Array  # int32[R]
     next_gid: jax.Array  # int32[] — global id of the next batch's first cmd
     pend_key: jax.Array  # int32[Pcap, KW]
     pend_src: jax.Array  # int32[Pcap]
     pend_seq: jax.Array  # int32[Pcap]
     pend_gid: jax.Array  # int32[Pcap] (-1 = empty slot)
+    read_clock: jax.Array  # int32[R, K] — latest executed read
+    pend_read: jax.Array  # bool[Pcap] — the carried command is a read
 
 
 class StepOutput(NamedTuple):
@@ -107,22 +118,47 @@ class StepOutput(NamedTuple):
     order: jax.Array  # int32[W] execution order (working-row indices)
     resolved: jax.Array  # bool[W] — executed this round
     fast_path: jax.Array  # bool[W] — committed on the fast path
-    deps_gid: jax.Array  # int32[W, KW] — final deps (global ids, -1 none)
+    # final deps (global ids, -1 none): columns [0, KW) the latest write
+    # of each key slot, columns [KW, 2KW) the latest read since it (a
+    # write's only)
+    deps_gid: jax.Array  # int32[W, 2*KW]
     gids: jax.Array  # int32[W] — global id per working row (-1 = empty)
     slow_paths: jax.Array  # int32[] — commands that took the Synod round
     stable: jax.Array  # int32[] — GC watermark: min executed frontier
     pending: jax.Array  # int32[] — commands carried to the next round
     pend_dropped: jax.Array  # int32[] — overflow beyond the pending capacity
+    # tallies over the rows executed this round, one vector (a drain
+    # fetches it in one transfer), by the names of ROUND_TALLIES
+    tallies: jax.Array  # int32[5]
 
 
-def quorum_sizes(num_replicas: int) -> Tuple[int, int]:
-    """(fast_quorum_size, write_quorum_size) for EPaxos with minority f.
+# StepOutput.tallies, in order: the executed rows' non-empty dependency
+# slots; their key slots with an earlier command on the bucket, and of
+# those the ones where both are reads (no dependency: reads commute); the
+# reads among the executed rows; those on more than one shard
+ROUND_TALLIES = (
+    "deps_committed", "key_links", "read_links_commuted", "read_rows",
+    "cross_shard_executed",
+)
 
-    Delegates to the shared protocol-fact formula
-    (Config.epaxos_quorum_sizes; EPaxos ignores config.f)."""
+
+DEP_COMMIT_RULES = ("epaxos", "atlas")
+
+
+def quorum_sizes(
+    num_replicas: int, f: int = 1, rule: str = "epaxos"
+) -> Tuple[int, int]:
+    """(fast_quorum_size, write_quorum_size) of the dep-commit round under
+    ``rule``: the shared protocol-fact formulas of ``Config``.  EPaxos
+    tolerates a minority whatever ``f`` says (``epaxos_quorum_sizes``
+    takes its own ``f = n // 2``); Atlas's are ``(n // 2 + f, f + 1)``."""
     from fantoch_tpu.core.config import Config
 
-    return Config(num_replicas, 0).epaxos_quorum_sizes()
+    assert rule in DEP_COMMIT_RULES, rule
+    config = Config(num_replicas, f)
+    if rule == "atlas":
+        return config.atlas_quorum_sizes()
+    return config.epaxos_quorum_sizes()
 
 
 def shard_of_row(row: int, num_replicas_total: int, shard_count: int) -> int:
@@ -224,9 +260,12 @@ def init_state(
     ``key_width``: max key buckets per command (multi-key commands route
     through the general resolver on-mesh)."""
     sharding = NamedSharding(mesh, P(REPLICA_AXIS, None))
-    key_clock = jax.device_put(
-        jnp.full((num_replicas, key_buckets), -1, dtype=jnp.int32), sharding
-    )
+
+    def clock():  # distinct buffers: donated state must not alias
+        return jax.device_put(
+            jnp.full((num_replicas, key_buckets), -1, dtype=jnp.int32), sharding
+        )
+
     frontier = jax.device_put(
         jnp.zeros((num_replicas,), dtype=jnp.int32),
         NamedSharding(mesh, P(REPLICA_AXIS)),
@@ -234,33 +273,51 @@ def init_state(
     rep = NamedSharding(mesh, P())
     next_gid = jax.device_put(jnp.int32(0), rep)
 
-    def empty(shape):  # distinct buffers: donated state must not alias
+    def empty(shape):
         return jax.device_put(jnp.full(shape, -1, dtype=jnp.int32), rep)
 
     cap = pending_capacity
     return ReplicaState(
-        key_clock, frontier, next_gid,
+        clock(), frontier, next_gid,
         empty((cap, key_width)), empty((cap,)), empty((cap,)), empty((cap,)),
+        clock(), jax.device_put(jnp.zeros((cap,), bool), rep),
     )
 
 
-def _key_runs(keys: jax.Array) -> Tuple[jax.Array, jax.Array]:
-    """Stable sort of the flattened (row-major) key slots: ``(perm, head)``.
+def _key_runs(
+    keys: jax.Array, flag: jax.Array | None = None
+) -> Tuple[jax.Array, jax.Array, jax.Array]:
+    """Stable sort of the flattened (row-major) key slots:
+    ``(perm, head, flag_at)``.
 
     ``perm[p]`` is the slot at sorted position ``p``; the slots of one key
     are one contiguous run in arrival order, and ``head[p]`` marks a run's
-    first position.
+    first position.  ``flag`` (bool, by row; default: no row) rides the
+    sort: ``flag_at[p]`` is the flag of the row of ``perm[p]``, as the
+    sorted keys are the sort's own (a gather each, saved).
     """
     flat = keys.reshape(-1)
-    perm = jnp.argsort(flat, stable=True).astype(jnp.int32)
-    sorted_key = flat[perm]
+    slots = jnp.arange(flat.shape[0], dtype=jnp.int32)
+    if flag is None:
+        flag = jnp.zeros((keys.shape[0],), bool)
+    sorted_key, perm, flag_at = jax.lax.sort(
+        (flat, slots, jnp.repeat(flag, keys.shape[1])), num_keys=1, is_stable=True
+    )
     head = jnp.concatenate(
         [jnp.ones((1,), bool), sorted_key[1:] != sorted_key[:-1]]
     )
-    return perm, head
+    return perm, head, flag_at
 
 
-def _chain_of_runs(perm: jax.Array, head: jax.Array, shape) -> jax.Array:
+def _run_start(head: jax.Array) -> jax.Array:
+    """The first sorted position of each position's key run."""
+    pos = jnp.arange(head.shape[0], dtype=jnp.int32)
+    return jax.lax.cummax(jnp.where(head, pos, 0))
+
+
+def _chain_of_runs(
+    perm: jax.Array, head: jax.Array, shape, member: jax.Array | None = None
+) -> jax.Array:
     """chain[i, w] = latest row j < i sharing key keys[i, w], else -1.
 
     Each slot's predecessor within its key run is the latest earlier slot
@@ -268,39 +325,77 @@ def _chain_of_runs(perm: jax.Array, head: jax.Array, shape) -> jax.Array:
     chain for commands of the same round, one dependency slot per key.
     Rows must not repeat a key (commands hold distinct keys), so an in-run
     predecessor is always an earlier row.
+
+    ``member`` (bool, by sorted position; default: every position) keeps
+    only predecessors of one class, the latest read or the latest write
+    of the key: the latest earlier position of the run that is a member.
     """
+    n = perm.shape[0]
+    pos = jnp.arange(n, dtype=jnp.int32)
+    if member is None:
+        member = jnp.ones((n,), bool)
+    # the latest member position so far and the slot standing there, in
+    # one running max: positions only grow, so packed with its slot below
+    # it a position still wins by itself (a gather of ``perm`` saved)
+    width = max(1, (n - 1).bit_length())
+    if 2 * width <= 31:
+        seen = jax.lax.cummax(jnp.where(member, (pos << width) | perm, -1))
+        before, slot = seen >> width, seen & ((1 << width) - 1)
+    else:  # a working set too long to pack into an int32
+        before = jax.lax.cummax(jnp.where(member, pos, -1))
+        slot = perm[jnp.maximum(before, 0)]
+
+    def earlier(x):
+        return jnp.concatenate([jnp.full((1,), -1, jnp.int32), x[:-1]])
+
     prev_same = jnp.where(
-        head, jnp.int32(TERMINAL), jnp.roll(perm, 1) // shape[1]  # predecessor's row
+        earlier(before) >= _run_start(head),
+        earlier(slot) // shape[1],  # predecessor's row
+        jnp.int32(TERMINAL),
     )
     return jnp.zeros_like(perm).at[perm].set(prev_same).reshape(shape)
 
 
 def _intra_batch_chain(keys: jax.Array) -> jax.Array:
     """The chain of :func:`_chain_of_runs` over the runs of ``keys``."""
-    return _chain_of_runs(*_key_runs(keys), keys.shape)
+    perm, head, _ = _key_runs(keys)
+    return _chain_of_runs(perm, head, keys.shape)
 
 
 def _resolve_run_position(
-    perm: jax.Array, head: jax.Array, blocking: jax.Array
+    perm: jax.Array, head: jax.Array, blocking: jax.Array, read_at: jax.Array
 ) -> Tuple[jax.Array, jax.Array]:
     """``(order, resolved)`` of a one-key working set from its key runs.
 
-    The chain of :func:`_chain_of_runs` only ever points at the previous
-    row of a key's run, so there is no cycle to look for: a row's
-    topological level is its position in its run, and it resolves iff no
-    ``blocking`` row (valid and uncommitted: the resolvers' ``MISSING``)
-    stands at or before it in the run.  Two ``cummax`` in sorted space and
-    one scatter back give what pointer doubling over the same chain gives
+    The chains of :func:`_chain_of_runs` only ever point back along a
+    key's run, so there is no cycle to look for.  A row's topological
+    level is read off its place in the run: a read stands one above the
+    last write before it (reads of one stretch share a level: they
+    commute), a write one above the last write, or two where a read
+    stands between; with no read in the run that is the position in the
+    run.  It resolves iff no ``blocking`` row (valid and uncommitted: the
+    resolvers' ``MISSING``) stands at or before it in the run: the reads
+    of one stretch report the same dependencies and commit or fail
+    together, so the one a later write depends on stands for all of them.
+    A segmented ``cumsum`` and two ``cummax`` in sorted space and one
+    scatter back give what pointer doubling over the same chain gives
     (``ops/graph_resolve.resolve_functional``, the oracle of
-    ``tests/test_mesh_step.py``), element for element: ``order`` is rows by
-    (level, row), unresolved rows at the tail.
+    ``tests/test_mesh_step.py``), element for element: ``order`` is rows
+    by (level, row), unresolved rows at the tail.  ``blocking`` is by
+    row, ``read_at`` (the row is a read) by sorted position.
     """
     pos = jnp.arange(perm.shape[0], dtype=jnp.int32)
-    run_start = jax.lax.cummax(jnp.where(head, pos, 0))
+    run_start = _run_start(head)
     last_blocked = jax.lax.cummax(jnp.where(blocking[perm], pos, -1))
+    write_at = (~read_at).astype(jnp.int32)
+    after_read = ~read_at & ~head & jnp.roll(read_at, 1)
+    rise = write_at + after_read
+    total = jnp.cumsum(rise)
+    # what the run's first position found: no smaller at a later run
+    depth = total - jax.lax.cummax(jnp.where(head, total - rise, 0))
     unresolved = jnp.iinfo(jnp.int32).max
     level = jnp.zeros_like(pos).at[perm].set(
-        jnp.where(last_blocked < run_start, pos - run_start, unresolved)
+        jnp.where(last_blocked < run_start, depth - write_at, unresolved)
     )
     order = jnp.argsort(level, stable=True).astype(jnp.int32)
     return order, level != unresolved
@@ -317,22 +412,42 @@ def protocol_step(
     key: jax.Array,  # int32[B] or int32[B, KW] key buckets, replicated
     dot_src: jax.Array,  # int32[B]
     dot_seq: jax.Array,  # int32[B]
+    read: jax.Array | None = None,  # bool[B] — the command is a read
     *,
     mesh: Mesh,
     live_replicas: int | None = None,
     shard_count: int = 1,
+    f: int = 1,
+    rule: str = "epaxos",
 ) -> Tuple[ReplicaState, StepOutput]:
     """One batched commit+execute round over the (replica, batch) mesh.
 
     ``key`` may carry up to KW distinct key buckets per command (KEY_PAD
     pads unused slots).  A one-key round (KW == 1) is resolved from the
-    key-sorted working set that builds its chain: level = position in the
-    key's run, blocked iff an uncommitted row stands earlier in the run
-    (:func:`_resolve_run_position`, :func:`resolver_name` ``run_position``).
-    Multi-key rounds resolve through the general out-degree-KW resolver
+    key-sorted working set that builds its chains: the level is read off
+    the row's place in its key's run, blocked iff an uncommitted row
+    stands earlier in the run (:func:`_resolve_run_position`,
+    :func:`resolver_name` ``run_position``).
+    Multi-key rounds resolve through the general out-degree-2KW resolver
     (ops/graph_resolve.resolve_general, ``general``), whose arrival-order
     fast path covers the clean-commit case and whose iterative pass
     handles quorum-failure MISSING blocking.
+
+    ``read`` marks the commands that only read (default: none does).  The
+    conflict relation is ``KeyDeps``'s with the read/write split
+    (deps/keys/locked.rs): a read depends on the latest write of each of
+    its keys and becomes the key's latest read; a write depends on the
+    latest write and on the latest read since it, and becomes the latest
+    write.  "Latest" is over the working set in arrival order (pending
+    rows, then the batch), then the replica's two clocks; "since" is by
+    gid, which is arrival order.  With no read in a round the second half
+    of ``deps_gid`` is empty and everything else is what a round without
+    the split gives.
+
+    ``rule`` picks the quorums and the fast-path test
+    (:func:`quorum_sizes`): ``epaxos``, every fast-quorum replica reported
+    the same dependencies; ``atlas``, every dependency of the union was
+    reported by at least ``f`` of the fast quorum (at ``f`` = 1, always).
 
     ``live_replicas``: replicas (global rows) < this count respond to the
     Synod accept round; the rest are crashed/partitioned for the round.
@@ -359,13 +474,15 @@ def protocol_step(
     assert key_width == state.pend_key.shape[1], (
         "key width must match init_state(key_width=...)"
     )
+    if read is None:
+        read = jnp.zeros((batch,), bool)
     pend_cap = state.pend_gid.shape[0]
     work = pend_cap + batch  # working rows: pending buffer first, then new
     assert num_replicas % shard_count == 0, (
         "replica rows must factor into shard_count equal shards"
     )
     per_shard = num_replicas // shard_count
-    fast_quorum, write_quorum = quorum_sizes(per_shard)
+    fast_quorum, write_quorum = quorum_sizes(per_shard, f, rule)
     if live_replicas is None:
         live_replicas = num_replicas
     replica_blocks = num_replicas // mesh.shape[REPLICA_AXIS]
@@ -374,7 +491,7 @@ def protocol_step(
 
     def step(
         key_clock, frontier, next_gid, pend_key, pend_src, pend_seq, pend_gid,
-        key_l, dot_src_l, dot_seq_l,
+        read_clock, pend_read, key_l, dot_src_l, dot_seq_l, read_l,
     ):
         # local blocks: key_clock [r_blk, K], key_l [b_blk, KW] (sharded
         # batch).  1. full batch view of the keys (commands are tiny; one
@@ -383,6 +500,7 @@ def protocol_step(
         key_new = jax.lax.all_gather(key_l, BATCH_AXIS, tiled=True)  # [B, KW]
         src_new = jax.lax.all_gather(dot_src_l, BATCH_AXIS, tiled=True)
         seq_new = jax.lax.all_gather(dot_seq_l, BATCH_AXIS, tiled=True)
+        read_new = jax.lax.all_gather(read_l, BATCH_AXIS, tiled=True)
 
         widx = jnp.arange(work, dtype=jnp.int32)
         gid = jnp.concatenate(
@@ -399,25 +517,38 @@ def protocol_step(
         key_full = jnp.where(real_slot, key_cat, key_buckets + slot_iota)
         dot_src_f = jnp.where(valid, jnp.concatenate([pend_src, src_new]), 0)
         dot_seq_f = jnp.where(valid, jnp.concatenate([pend_seq, seq_new]), 0)
+        read_f = valid & jnp.concatenate([pend_read, read_new])  # [W]
 
-        # 2. per-replica deps, one slot per key: intra-working-batch chain,
-        # else the replica's key-clock entry (KeyDeps::add_cmd per replica)
-        perm, head = _key_runs(key_full)
-        chain = _chain_of_runs(perm, head, key_full.shape)  # [W, KW] row or -1
+        # 2. per-replica deps, two slots per key (KeyDeps::add_cmd per
+        # replica, with the read/write split): the latest write and the
+        # latest read of the key among the earlier working rows, else the
+        # replica's clock entries; a read keeps the write alone, a write
+        # also the read where it came after that write
+        perm, head, read_at = _key_runs(key_full, read_f)  # by sorted position
+        chain_w = _chain_of_runs(perm, head, key_full.shape, ~read_at)
+        chain_r = _chain_of_runs(perm, head, key_full.shape, read_at)
         safe_key = jnp.minimum(key_full, key_buckets - 1)
-        prior = jnp.where(real_slot[None], key_clock[:, safe_key], -1)
-        dep_gid = jnp.where(
-            chain >= 0, gid[jnp.maximum(chain, 0)], prior
-        )  # [r_blk, W, KW]
+
+        def latest(chain, clock):  # [r_blk, W, KW] gids
+            prior = jnp.where(real_slot[None], clock[:, safe_key], -1)
+            return jnp.where(chain >= 0, gid[jnp.maximum(chain, 0)], prior)
+
+        write_gid = latest(chain_w, key_clock)
+        read_gid = latest(chain_r, read_clock)
+        since = ~read_f[None, :, None] & (read_gid > write_gid)
+        # the third block is no dependency: the key's latest read as each
+        # replica knows it, for the tally of the links that commuted
+        dep_gid = jnp.concatenate(
+            [write_gid, jnp.where(since, read_gid, -1), read_gid], axis=-1
+        )  # [r_blk, W, 3*KW]
 
         # 3. MCollectAck fan-in over each key slot's *shard* fast quorum =
         # the first fast_quorum member rows of the shard owning the slot's
         # bucket (distance-sorted quorum, base.rs:59-131; bucket b belongs
-        # to shard b % shard_count).  Fast path iff every quorum replica
-        # reported the same deps on every key slot (check_union,
-        # epaxos.rs:339-345) — for a multi-shard command that is every
-        # touched shard's quorum at once.  Pad slots have no real bucket:
-        # their dep is -1 on every replica, so any shard's quorum agrees.
+        # to shard b % shard_count).  For a multi-shard command the fast
+        # path is every touched shard's quorum at once.  Pad slots have no
+        # real bucket: their dep is -1 on every replica, so any shard's
+        # quorum agrees.
         row = (
             jax.lax.axis_index(REPLICA_AXIS) * replica_blocks
             + jnp.arange(replica_blocks, dtype=jnp.int32)
@@ -430,18 +561,47 @@ def protocol_step(
         in_fq = (row_shard == slot_shard[None]) & (
             row_member < fast_quorum
         )  # [r_blk, W, KW]
+        in_fq3 = jnp.tile(in_fq, (1, 1, 3))
         fq_max = jax.lax.pmax(
-            jnp.where(in_fq, dep_gid, int_min).max(axis=0), REPLICA_AXIS
-        )  # [W, KW]
-        fq_min = jax.lax.pmin(
-            jnp.where(in_fq, dep_gid, int_max).min(axis=0), REPLICA_AXIS
-        )  # [W, KW]
-        fast = (fq_max == fq_min).all(axis=-1) & valid
+            jnp.where(in_fq3, dep_gid, int_min).max(axis=0), REPLICA_AXIS
+        )  # [W, 3*KW]
         # slow-path proposal: union of fast-quorum deps (= per-slot max
         # over latest-per-key singletons), Synod ballot 0 / skip-prepare
         # (synod single.rs:86) — same value either way, so the committed
         # deps are fq_max; what the slow path adds is the accept round.
-        final_gid = fq_max  # [W, KW]
+        final_gid = fq_max[:, : 2 * key_width]  # [W, 2*KW]
+        proposed = dep_gid[..., : 2 * key_width]
+        in_fq2 = in_fq3[..., : 2 * key_width]
+        if rule == "epaxos":
+            # every quorum replica reported the same deps on every slot
+            # (check_union, epaxos.rs:339-345)
+            fq_min = jax.lax.pmin(
+                jnp.where(in_fq2, proposed, int_max).min(axis=0), REPLICA_AXIS
+            )
+            fast = (final_gid == fq_min).all(axis=-1) & valid
+        elif f == 1:
+            fast = valid  # whoever reported a dependency is one of f
+        else:
+            # every dependency a quorum replica reported was reported by
+            # f of its shard's quorum, on whichever of the shard's key
+            # slots (QuorumDeps::check_threshold, atlas.rs: a replica
+            # reports one set for all its keys)
+            everyone = jax.lax.all_gather(
+                jnp.where(in_fq2, proposed, -1), REPLICA_AXIS, tiled=True
+            )  # [R, W, 2*KW]
+            shard2 = jnp.tile(slot_shard, (1, 2))
+            same_shard = shard2[:, :, None] == shard2[:, None, :]  # [W, 2*KW, 2*KW]
+            said = (
+                (everyone[:, None, :, None, :] == proposed[None, :, :, :, None])
+                & same_shard[None, None]
+            ).any(axis=-1)  # [R, r_blk, W, 2*KW]: that replica, this report
+            enough = ~in_fq2 | (proposed < 0) | (said.sum(axis=0) >= f)
+            fast = (
+                jax.lax.pmin(
+                    enough.all(axis=(0, 2)).astype(jnp.int32), REPLICA_AXIS
+                ).astype(bool)
+                & valid
+            )
 
         # Synod accept round for fast-path misses: every *live* replica
         # of a slot's shard accepts the ballot-0 proposal (no competing
@@ -465,16 +625,26 @@ def protocol_step(
         # 4. batched resolution of the committed working set.  A final dep
         # is either a working row or already executed (pruned to TERMINAL);
         # uncommitted commands are MISSING: they stay unresolved and so does
-        # everything dependency-chained to them.  The clock holds executed
+        # everything dependency-chained to them.  The clocks hold executed
         # gids only (step 5), so a dep that is a working row is always the
-        # intra-batch chain's and never the clock's.
+        # intra-batch chain's and never a clock's.
         if key_width == 1:
             # one key a command: level and blocking are read off the key
-            # runs of step 2, the sort that built the chain
+            # runs of step 2, the sort that built the chains
             order, resolved = _resolve_run_position(
-                perm, head, valid & ~committed
+                perm, head, valid & ~committed, read_at
             )
         else:
+            # the latest read is a dependency only where the union kept it
+            chain = jnp.concatenate(
+                [
+                    chain_w,
+                    jnp.where(
+                        final_gid[:, key_width:] >= 0, chain_r, jnp.int32(TERMINAL)
+                    ),
+                ],
+                axis=-1,
+            )
             dep_idx = jnp.where(committed[:, None], chain, jnp.int32(MISSING))
             dep_idx = jnp.where(valid[:, None], dep_idx, jnp.int32(TERMINAL))
             # general resolver; max_iters = 2*W+8 guarantees convergence
@@ -490,29 +660,59 @@ def protocol_step(
 
         # 5. state update: every *live* replica learns the *executed* dots
         # on the buckets of ITS OWN shard (scatter-max by key slot; later
-        # commands in the batch win) — a shard's replicas never store
-        # other shards' key state (partial replication).  Only executed
-        # gids enter the key clock, and an executed row is never carried,
-        # so the clock never holds a gid of a working set.  The next round
-        # leans on that twice (step 4): a dep read from the clock is
-        # already executed and prunes to TERMINAL, and it is never a
-        # working row, so no join of committed dep gids against the
-        # working set's gids is needed to find one.
+        # commands in the batch win), writes into its key clock and reads
+        # into its read clock — a shard's replicas never store other
+        # shards' key state (partial replication).  Only executed gids
+        # enter the clocks, and an executed row is never carried, so the
+        # clocks never hold a gid of a working set.  The next round leans
+        # on that twice (step 4): a dep read from a clock is already
+        # executed and prunes to TERMINAL, and it is never a working row,
+        # so no join of committed dep gids against the working set's gids
+        # is needed to find one.
         own_slot = row_shard == slot_shard[None]  # [r_blk, W, KW]
-        clock_upd = jnp.where(
-            live[..., None]
-            & own_slot
-            & (executed[None, :, None] & real_slot[None]),
-            gid[None, :, None],
-            jnp.int32(-1),
-        )  # [r_blk, W, KW]
-        new_clock = key_clock.at[:, safe_key].max(clock_upd)
+        done_slot = executed[:, None] & real_slot  # [W, KW]
+        learns = live[..., None] & own_slot & done_slot[None]
+        reads = read_f[None, :, None]
+        new_clock = key_clock.at[:, safe_key].max(
+            jnp.where(learns & ~reads, gid[None, :, None], jnp.int32(-1))
+        )
+        # (a table's scatter costs the table, whatever it scatters: a
+        # round that executed no read leaves the read clock alone)
+        new_read_clock = jax.lax.cond(
+            (executed & read_f).any(),
+            lambda clock: clock.at[:, safe_key].max(
+                jnp.where(learns & reads, gid[None, :, None], jnp.int32(-1))
+            ),
+            lambda clock: clock,
+            read_clock,
+        )
         new_frontier = frontier + jnp.where(
             live[:, 0], executed.sum().astype(jnp.int32), 0
         )
         # GC stability watermark: the meet of all replicas' executed
         # frontiers (gc.rs stable()), here a pmin over the replica axis.
         stable = jax.lax.pmin(new_frontier.min(), REPLICA_AXIS)
+
+        # the round's tallies over its executed rows: dependency slots
+        # committed, key slots with a command before them on the bucket
+        # (in the working set or the quorum's clocks) and those of them
+        # where both are reads, reads, rows on more than one shard
+        def count(mask):
+            return mask.sum().astype(jnp.int32)
+
+        before_w, before_r = final_gid[:, :key_width], fq_max[:, 2 * key_width:]
+        linked = done_slot & ((before_w >= 0) | (before_r >= 0))
+        shards_lo = jnp.where(real_slot, slot_shard, shard_count).min(axis=-1)
+        shards_hi = jnp.where(real_slot, slot_shard, -1).max(axis=-1)
+        tallies = jnp.stack(
+            [
+                count(jnp.tile(done_slot, (1, 2)) & (final_gid >= 0)),
+                count(linked),
+                count(linked & read_f[:, None] & (before_r > before_w)),
+                count(executed & read_f),
+                count(executed & (shards_hi > shards_lo)),
+            ]
+        )  # ROUND_TALLIES
 
         # 6. pending carry (the liveness fix): valid-but-unexecuted rows
         # survive into the next round's buffer, oldest first; overflow
@@ -526,6 +726,7 @@ def protocol_step(
         new_pend_key = jnp.where(is_carry[:, None], key_cat[take], KEY_PAD)
         new_pend_src = jnp.where(is_carry, dot_src_f[take], -1)
         new_pend_seq = jnp.where(is_carry, dot_seq_f[take], -1)
+        new_pend_read = is_carry & read_f[take]
         pending = carry.sum().astype(jnp.int32)
         pend_dropped = jnp.maximum(pending - pend_cap, 0).astype(jnp.int32)
 
@@ -537,18 +738,21 @@ def protocol_step(
             new_pend_src,
             new_pend_seq,
             new_pend_gid,
+            new_read_clock,
+            new_pend_read,
             order,
             executed,
             fast,
-            jnp.where(real_slot, final_gid, -1),
+            jnp.where(jnp.tile(real_slot, (1, 2)), final_gid, -1),
             jnp.where(valid, gid, -1),
             slow_paths,
             stable,
             jnp.minimum(pending, pend_cap),
             pend_dropped,
+            tallies,
         )
 
-    specs_in = (
+    state_specs = (
         P(REPLICA_AXIS, None),  # key_clock
         P(REPLICA_AXIS),  # frontier
         P(),  # next_gid
@@ -556,56 +760,30 @@ def protocol_step(
         P(),  # pend_src
         P(),  # pend_seq
         P(),  # pend_gid
-        P(BATCH_AXIS),  # key
-        P(BATCH_AXIS),  # dot_src
-        P(BATCH_AXIS),  # dot_seq
+        P(REPLICA_AXIS, None),  # read_clock
+        P(),  # pend_read
     )
-    specs_out = (
-        P(REPLICA_AXIS, None),
-        P(REPLICA_AXIS),
-        P(),
-        P(),  # pend_key
-        P(),  # pend_src
-        P(),  # pend_seq
-        P(),  # pend_gid
-        P(),  # order (replicated full working set)
-        P(),
-        P(),
-        P(),  # deps_gid
-        P(),  # gids
-        P(),  # slow_paths
-        P(),  # stable
-        P(),  # pending
-        P(),  # pend_dropped
-    )
+    # key, dot_src, dot_seq, read
+    specs_in = state_specs + (P(BATCH_AXIS),) * 4
+    # the outputs are replicated: order (the full working set) ... the tallies
+    specs_out = state_specs + (P(),) * len(StepOutput._fields)
     # check_vma=False: outputs derived from all_gather/pmax results are
     # replicated by construction, but the static VMA analysis cannot see
     # through the gather+argsort chain.
     fn = shard_map(
         step, mesh=mesh, in_specs=specs_in, out_specs=specs_out, check_vma=False
     )
-    (
-        new_clock, new_frontier, new_gid,
-        new_pend_key, new_pend_src, new_pend_seq, new_pend_gid,
-        order, executed, fast, deps, gids, slow, stable, pending, dropped,
-    ) = fn(
-        state.key_clock, state.frontier, state.next_gid,
-        state.pend_key, state.pend_src, state.pend_seq, state.pend_gid,
-        key, dot_src, dot_seq,
-    )
-    return (
-        ReplicaState(
-            new_clock, new_frontier, new_gid,
-            new_pend_key, new_pend_src, new_pend_seq, new_pend_gid,
-        ),
-        StepOutput(
-            order, executed, fast, deps, gids, slow, stable, pending, dropped
-        ),
-    )
+    out = fn(*state, key, dot_src, dot_seq, read)
+    n_state = len(ReplicaState._fields)
+    return ReplicaState(*out[:n_state]), StepOutput(*out[n_state:])
 
 
 def jit_protocol_step(
-    mesh: Mesh, live_replicas: int | None = None, shard_count: int = 1
+    mesh: Mesh,
+    live_replicas: int | None = None,
+    shard_count: int = 1,
+    f: int = 1,
+    rule: str = "epaxos",
 ):
     """jit-compiled step with donated device-resident state."""
     import functools
@@ -616,6 +794,8 @@ def jit_protocol_step(
             mesh=mesh,
             live_replicas=live_replicas,
             shard_count=shard_count,
+            f=f,
+            rule=rule,
         ),
         donate_argnums=(0,),
     )

@@ -198,6 +198,11 @@ class _DriverCore(PipelineCore):
     # leaderless rounds
     round_name: Optional[str] = None
     accept_quorum: Optional[int] = None
+    # the dep-commit round's quorum rule (mesh_step.DEP_COMMIT_RULES) and
+    # the (fast, write) quorum sizes it gives; None elsewhere
+    rule: Optional[str] = None
+    fast_quorum: Optional[int] = None
+    write_quorum: Optional[int] = None
 
     def _init_core(
         self,
@@ -229,6 +234,9 @@ class _DriverCore(PipelineCore):
         # for the caller to submit again (take_requeue)
         self.requeued = 0
         self.stable_watermark = 0
+        # what the round tallies over the rows it executed, summed, by
+        # name (mesh_step.ROUND_TALLIES); empty where it tallies nothing
+        self.round_tallies: Dict[str, int] = {}
         # where a program made ready before serving takes its columns on
         # the mesh, by the rounds it carries (``_precompile``); empty for
         # a driver whose round is jitted by its first dispatch
@@ -639,6 +647,8 @@ class DeviceDriver(_DriverCore):
         shard_count: int = 1,
         monitor_execution_order: bool = False,
         mesh=None,
+        f: int = 1,
+        rule: str = "epaxos",
     ):
         from fantoch_tpu.parallel import mesh_step
 
@@ -648,10 +658,22 @@ class DeviceDriver(_DriverCore):
             mesh_step, num_replicas, shard_count, key_buckets,
             pending_capacity, key_width, mesh, mesh_step.init_state,
         )
+        # the quorums and the fast-path test of the round: EPaxos's, or
+        # Atlas's with its f (mesh_step.quorum_sizes)
+        self.rule = rule
+        self.fast_quorum, self.write_quorum = mesh_step.quorum_sizes(
+            num_replicas, f, rule
+        )
         self._step = mesh_step.jit_protocol_step(
-            self._mesh, live_replicas=live_replicas, shard_count=shard_count
+            self._mesh, live_replicas=live_replicas, shard_count=shard_count,
+            f=f, rule=rule,
         )
         self.resolver = mesh_step.resolver_name(key_width)
+        # the round's tallies over the rows it executed, summed
+        # (mesh_step.StepOutput): dependency slots committed, key slots
+        # with a command before them on the bucket and those of them
+        # where both are reads, reads, commands on more than one shard
+        self.round_tallies = dict.fromkeys(mesh_step.ROUND_TALLIES, 0)
         self._next_gid = 0  # host mirror of state.next_gid
         self._frontier_base = 0  # executed-count carried across gid epochs
         self.gid_epochs = 0
@@ -663,6 +685,13 @@ class DeviceDriver(_DriverCore):
             cmd, self.shard_id, self.key_buckets, self.key_width,
             self.shard_count, self._bucket_cache,
         )
+
+    def _column_specs(self):
+        """The dep-commit round's columns: the key/src/seq columns and
+        which commands only read."""
+        return super()._column_specs() + (("read", (self.batch_size,), np.bool_, False),)
+
+    precompile_chains = _DriverCore._plain_round_ahead
 
     # gid space is int32 and the key clock holds raw gids; when the space
     # nears exhaustion the epoch resets — rebase clock/frontier/pending
@@ -684,20 +713,23 @@ class DeviceDriver(_DriverCore):
                 "gid epoch reset ineffective: a command from gid 0 is "
                 "still in flight"
             )
-        key_clock = np.asarray(st.key_clock, dtype=np.int64)
-        # entries older than the oldest live gid clamp to -1 ("no live
-        # predecessor") — exactly their meaning to dep pruning, which
-        # treats out-of-working-set deps as already executed
-        key_clock = np.where(key_clock >= delta, key_clock - delta, -1)
+
+        def rebased(clock):
+            # entries older than the oldest live gid clamp to -1 ("no live
+            # predecessor") — exactly their meaning to dep pruning, which
+            # treats out-of-working-set deps as already executed
+            gids = np.asarray(clock, dtype=np.int64)
+            gids = np.where(gids >= delta, gids - delta, -1)
+            return jax.device_put(jnp.array(gids.astype(np.int32)), clock.sharding)
+
         pend_gid = np.asarray(st.pend_gid, dtype=np.int64)
         pend_gid = np.where(pend_gid >= 0, pend_gid - delta, -1)
         frontier = np.asarray(st.frontier, dtype=np.int64)
         fmin = int(frontier.min())
         self._frontier_base += fmin
         self._state = st._replace(
-            key_clock=jax.device_put(
-                jnp.array(key_clock.astype(np.int32)), st.key_clock.sharding
-            ),
+            key_clock=rebased(st.key_clock),
+            read_clock=rebased(st.read_clock),
             frontier=jax.device_put(
                 jnp.array((frontier - fmin).astype(np.int32)),
                 st.frontier.sharding,
@@ -751,7 +783,7 @@ class DeviceDriver(_DriverCore):
         )
 
     def _assemble(self, batch: List[Tuple[Dot, Command]]):
-        """One round's key/src/seq columns, each command registered
+        """One round's key/src/seq/read columns, each command registered
         under the gid its row will get, and the batch length: the round
         gives every row of the fixed batch a gid and resolves the padding
         too, so the drain needs it to leave the padding out."""
@@ -760,7 +792,7 @@ class DeviceDriver(_DriverCore):
             f"{self.batch_size}; chunk at the caller"
         )
         b = self.batch_size
-        key, src, seq = self._staging(*self._column_specs())
+        key, src, seq, read = self._staging(*self._column_specs())
         if self._next_gid + b >= self.GID_RESET_THRESHOLD:
             assert self._undrained == 0, (
                 "gid epoch reset with a pipelined round in flight; "
@@ -778,8 +810,9 @@ class DeviceDriver(_DriverCore):
             key[i, : len(row)] = row
             src[i] = dot.source
             seq[i] = self._device_seq(dot)
+            read[i] = cmd.read_only
             self._cmds[self._next_gid + i] = (dot, cmd)
-        return (key, src, seq), len(batch)
+        return (key, src, seq, read), len(batch)
 
     def _enqueue(self, staged):
         columns, n_batch = staged
@@ -788,7 +821,9 @@ class DeviceDriver(_DriverCore):
         return out, n_batch
 
     def _token_outputs(self, tok):
-        return tok[0]
+        # what the drain reads: the committed dependencies and the carry's
+        # count stay on the device (a transfer a leaf)
+        return tok[0]._replace(deps_gid=None, pending=None)
 
     def _execute(self, tok, out) -> List[ExecutorResult]:
         """Execute one fetched round's resolved commands in device
@@ -819,6 +854,9 @@ class DeviceDriver(_DriverCore):
                 self.fast_paths += 1
         # valid new rows that missed the fast path took the Synod round
         self.slow_paths += int(out.slow_paths)
+        tallies = self.round_tallies
+        for name, count in zip(tallies, out.tallies.tolist()):
+            tallies[name] += count
 
         # device pending overflow: rows beyond the pending capacity were
         # dropped by the device (loudly — out.pend_dropped).  Re-register
@@ -1827,9 +1865,13 @@ class DeviceRuntime:
                 mesh=mesh,
             )
         else:
-            # the EPaxos-style dep-commit round serves every other label
+            # the dep-commit round serves every other label: under
+            # Atlas's quorums and fast-path rule for 'atlas', under
+            # EPaxos's for the rest
             self.driver = DeviceDriver(
                 config.n,
+                f=config.f,
+                rule="atlas" if protocol == "atlas" else "epaxos",
                 batch_size=batch_size,
                 key_buckets=key_buckets,
                 key_width=key_width,
@@ -2140,6 +2182,8 @@ class DeviceRuntime:
             "requeued": d.requeued,
             "fast_paths": d.fast_paths,
             "slow_paths": d.slow_paths,
+            # the dep-commit round's tallies over its executed rows
+            **d.round_tallies,
             "in_flight": d.in_flight,
             "stable_watermark": d.stable_watermark,
             "queued": len(self._submit_queue),
@@ -2228,9 +2272,12 @@ class DeviceRuntime:
 
         d = self.driver
         # what the driver says of its round: the dep-commit round's
-        # resolver, the leader round's name and accept quorum
+        # resolver, quorum rule and (fast, write) quorum sizes, the leader
+        # round's name and accept quorum
         named = {
             "resolver": d.resolver,
+            "rule": d.rule,
+            "quorums": d.rule and [d.fast_quorum, d.write_quorum],
             "round": d.round_name,
             "accept_quorum": d.accept_quorum,
         }
@@ -2548,11 +2595,17 @@ class DeviceRuntime:
             self._queue_released += released
         return batches or [[]]  # nothing queued: a pending-buffer progress round
 
-    async def _serve_round(self, round_id: int, step, *args) -> List[ExecutorResult]:
-        """The tail every round shares: the step on the pool thread,
-        delivery of what it executed, the published tallies."""
+    async def _serve_round(self, whole, step, *args) -> List[ExecutorResult]:
+        """The tail every round shares, inside its ``round`` span
+        ``whole``: the step on the pool thread, delivery of what it
+        executed, the published tallies.  The span keeps the reads among
+        what the step executed, where the round tallies them."""
         stages = self.stages
+        round_id = whole.round
+        reads_before = self.driver.round_tallies.get("read_rows")
         results = await self._step_on_pool(round_id, step, *args)
+        if reads_before is not None:
+            whole.read_rows = self.driver.round_tallies["read_rows"] - reads_before
         with stages.span("deliver", round_id):
             self._deliver(results)
         with stages.span("publish", round_id):
@@ -2605,10 +2658,16 @@ class DeviceRuntime:
                 # arrival simply waits one flush, it can never interleave
                 # a dispatch into the flushing pipeline
                 with stages.span("round", round_id) as whole:
-                    await self._serve_round(round_id, driver.flush_pipeline)
+                    await self._serve_round(whole, driver.flush_pipeline)
                 self._trace_round(whole)
                 continue
-            if not self._submit_queue and driver.in_flight == 0:
+            # (rounds a truncated chain handed back wait in the requeue,
+            # registered nowhere yet: they are work too)
+            if (
+                not self._submit_queue
+                and driver.in_flight == 0
+                and not driver.has_requeue
+            ):
                 self._work.clear()
                 with stages.span("idle_wait"):
                     await self._work.wait()
@@ -2659,14 +2718,14 @@ class DeviceRuntime:
                 # fused device program on Newt, S plain rounds elsewhere)
                 if len(batches) > 1:
                     results = await self._serve_round(
-                        round_id,
+                        whole,
                         driver.step_chained_pipelined
                         if pipeline else driver.step_chained,
                         batches,
                     )
                 else:
                     results = await self._serve_round(
-                        round_id,
+                        whole,
                         driver.step_pipelined if pipeline else driver.step,
                         batches[0],
                     )

@@ -35,12 +35,13 @@ def test_leg_served_tiny(tmp_path):
     assert leg["recompiles_warm_burst"] == 0
     assert set(leg["backend"]) == {
         "platform", "device_kind", "device_count", "mesh_shape", "shards_on_device",
-        "resolver",
+        "resolver", "rule", "quorums",
     }
+    assert leg["backend"]["rule"] == "epaxos" and leg["backend"]["quorums"] == [2, 2]
     assert leg["backend"]["resolver"] == "run_position"  # one key a command
     assert "platform=cpu" in leg["banner"] and "mesh=" in leg["banner"]
     assert " shards_on_device=0" in leg["banner"]
-    assert " resolver=run_position compile_cache=" in leg["banner"]
+    assert " rule=epaxos quorums=2/2 resolver=run_position compile_cache=" in leg["banner"]
 
 
 def test_leg_served_reports_a_dead_server(tmp_path):

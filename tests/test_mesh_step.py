@@ -28,6 +28,24 @@ def test_intra_batch_chain():
     assert chain[:, 0].tolist() == [TERMINAL, TERMINAL, 0, 2, 1, TERMINAL]
 
 
+@pytest.mark.parametrize("rows", (300, 40000))
+def test_chain_of_one_class_by_a_loop_packed_or_too_long_to_pack(rows):
+    """The latest earlier read, and write, of each slot's key against a
+    loop over a dict: at 40000 slots a position and its slot no longer fit
+    an int32 together and the chain gathers the slot instead."""
+    rng = np.random.default_rng(rows)
+    keys, read = rng.integers(0, rows // 6, (rows, 1)).astype(np.int32), rng.random(rows) < 0.5
+    perm, head, read_at = mesh_step._key_runs(jnp.asarray(keys), jnp.asarray(read))
+    assert read_at.tolist() == read[np.asarray(perm)].tolist()
+    for member, wanted in ((read_at, True), (~read_at, False), (None, None)):
+        latest, want = {}, []
+        for row, (key, is_read) in enumerate(zip(keys[:, 0].tolist(), read.tolist())):
+            want.append(latest.get(key, TERMINAL))
+            if wanted is None or is_read == wanted:
+                latest[key] = row
+        assert mesh_step._chain_of_runs(perm, head, keys.shape, member)[:, 0].tolist() == want
+
+
 def test_intra_batch_chain_multikey():
     # rows tagged with up to two keys; per-slot chains follow each key
     keys = jnp.asarray(
@@ -209,7 +227,9 @@ def test_protocol_step_multikey(mesh):
     state, out2 = step(state, jnp.asarray(keys), src, seq2)
     new0 = state.pend_gid.shape[0]
     deps2 = np.asarray(out2.deps_gid)
-    assert (deps2[new0] >= 0).all() and (deps2[new0] < batch).all()
+    # the latest write of each key; no read since it (the second half)
+    assert (deps2[new0, :2] >= 0).all() and (deps2[new0, :2] < batch).all()
+    assert (deps2[:, 2:] == -1).all()
     assert np.asarray(out2.resolved)[np.asarray(out2.gids) >= 0].all()
     assert state.frontier.tolist() == [2 * batch] * num_replicas
 
@@ -854,13 +874,15 @@ def test_newt_multikey_fast_path_is_row_level(mesh):
 
 def _flat_equations(jaxpr):
     """Every equation of ``jaxpr`` and of the jaxprs its equations carry
-    (shard_map, pjit, a loop's body), in program order, as ``(primitive,
-    input shapes, output shapes)``; a plain call is not listed itself."""
+    (shard_map, pjit, a loop's body, a ``cond``'s branches), in program
+    order, as ``(primitive, input shapes, output shapes)``; a plain call is
+    not listed itself."""
     for eqn in jaxpr.eqns:
         for value in eqn.params.values():
-            inner = getattr(value, "jaxpr", value)
-            if hasattr(inner, "eqns"):
-                yield from _flat_equations(inner)
+            for carried in value if isinstance(value, (tuple, list)) else (value,):
+                inner = getattr(carried, "jaxpr", carried)
+                if hasattr(inner, "eqns"):
+                    yield from _flat_equations(inner)
         if eqn.primitive.name not in ("pjit", "shard_map", "closed_call"):
             yield (
                 eqn.primitive.name,
@@ -1082,7 +1104,7 @@ def _oracle_round(state, key, src, seq, *, shard_count, live_replicas):
     from fantoch_tpu.ops.graph_resolve import MISSING, resolve_functional
 
     clock, frontier, next_gid, pend_key, pend_src, pend_seq, pend_gid = (
-        np.asarray(x) for x in state
+        np.asarray(x) for x in state[:7]
     )
     rows, buckets = clock.shape
     per_shard = rows // shard_count
@@ -1138,15 +1160,23 @@ def _oracle_round(state, key, src, seq, *, shard_count, live_replicas):
         out[: len(take)] = column[take]
         return out
 
+    # the round as it was had one clock and no read flag: a round without
+    # a read leaves the read clock and the carried flags as they were
     state = mesh_step.ReplicaState(
         new_clock, new_frontier, np.int32(next_gid + batch),
         carried(np.where(real, key_cat, mesh_step.KEY_PAD), mesh_step.KEY_PAD)[:, None],
         carried(src_f, -1), carried(seq_f, -1), carried(gid, -1),
+        np.asarray(state.read_clock), np.zeros(cap, bool),
     )
+    deps = np.where(real, fq_max, -1)
+    # its one dependency slot a key is the first of the two; no read, so
+    # the second is empty and nothing commuted
     out = mesh_step.StepOutput(
-        np.asarray(res.order), executed, fast, np.where(real, fq_max, -1)[:, None],
+        np.asarray(res.order), executed, fast, np.stack([deps, np.full_like(deps, -1)], axis=1),
         np.where(valid, gid, -1), np.int32((~fast & valid).sum()), new_frontier.min(),
         np.int32(min(len(carry), cap)), np.int32(max(len(carry) - cap, 0)),
+        # mesh_step.ROUND_TALLIES: every dependency is a link, none of them between reads
+        np.array([(executed & (deps >= 0)).sum()] * 2 + [0, 0, 0], np.int32),
     )
     return state, out
 
@@ -1164,10 +1194,12 @@ def _oracle_round(state, key, src, seq, *, shard_count, live_replicas):
 )
 def test_one_key_round_equals_the_gid_join_and_pointer_doubling(shard_count, lives, distinct_keys):
     """Several rounds in sequence on one state, full and part-full batches,
-    pending carried and overflowing: every field of ``StepOutput`` and of
-    the next ``ReplicaState`` equals, element for element, what the round
-    gave when it exported its chain as gids, joined them back to rows and
-    resolved by pointer doubling (``_oracle_round``)."""
+    pending carried and overflowing, no read among them: every field of
+    ``StepOutput`` and of the next ``ReplicaState`` equals, element for
+    element, what the round gave before it knew reads from writes, when it
+    exported its chain as gids, joined them back to rows and resolved by
+    pointer doubling (``_oracle_round``); the second dependency slot, the
+    read clock and the tallies of reads stay empty."""
     rows, batch, pending, buckets = 5 * shard_count, 48, 32, 2048
     mesh = _one_device_mesh()
     state = mesh_step.init_state(mesh, rows, key_buckets=buckets, pending_capacity=pending)
@@ -1190,7 +1222,9 @@ def test_one_key_round_equals_the_gid_join_and_pointer_doubling(shard_count, liv
         want_state, want = _oracle_round(
             state, key, src, seq, shard_count=shard_count, live_replicas=live
         )
-        state, out = steps[live](state, jnp.asarray(key), jnp.asarray(src), jnp.asarray(seq))
+        state, out = steps[live](
+            state, jnp.asarray(key), jnp.asarray(src), jnp.asarray(seq), jnp.zeros(batch, bool)
+        )
         for name, got, expected in zip(out._fields + state._fields, out + state, want + want_state):
             got = np.asarray(got)
             assert got.dtype == np.asarray(expected).dtype or got.dtype == bool, (r, name)
@@ -1220,9 +1254,13 @@ def test_the_one_key_round_has_no_loop_and_no_more_gathers_at_a_larger_working_s
     assert small == large
     assert not {"scan", "while"} & set(small)
     moves = [name for name in small if name == "gather" or name.startswith("scatter")]
-    # gathers: the sorted keys, the clock read, gid[chain], a slot's shard's
+    # gathers: the two clocks' reads, gid[chain] of each, a slot's shard's
     # live count, the blocking flags at the sorted positions, the carry's
-    # five reads; scatters: the chain and the level back to row order, the
-    # live count per shard, the clock's scatter-max
-    assert sorted(moves) == ["gather"] * 10 + ["scatter", "scatter", "scatter-add", "scatter-max"], moves
-    assert small.count("sort") == 3 and small.count("cummax") == 2
+    # six reads (the sorted keys and the read flags at the sorted positions
+    # ride the key sort, and the latest write's and the latest read's slot
+    # of a run the running max that finds their position); scatters: the
+    # two chains and the level back to row order, the live count per shard,
+    # the two clocks' scatter-max (the read clock's under a ``cond``: a
+    # round that executed no read skips it)
+    assert sorted(moves) == ["gather"] * 12 + ["scatter"] * 3 + ["scatter-add"] + ["scatter-max"] * 2, moves
+    assert small.count("sort") == 3 and small.count("cumsum") == 1

@@ -539,13 +539,13 @@ def test_a_pipelined_drains_spans_carry_the_retired_rounds_id():
     assert d.step_pipelined(["a"]) == []          # dispatch 1 stays in flight
     assert d.step_pipelined(["b"]) == [(1, "a")]  # dispatch 2 retires round 1
     assert d.flush_pipeline() == [(2, "b")]
-    rounds = [(name, round_id) for name, _t0, _t1, round_id, _thread, _parent in d.stages.ring]
+    rounds = [(name, round_id) for name, _t0, _t1, round_id, *_ in d.stages.ring]
     assert rounds == [
         ("assemble", 1), ("enqueue", 1),
         ("assemble", 2), ("enqueue", 2), ("fetch", 1), ("execute", 1),
         ("fetch", 2), ("execute", 2),
     ]
-    for _name, t0, t1, _round, _thread, parent in d.stages.ring:
+    for _name, t0, t1, _round, _thread, parent, _reads in d.stages.ring:
         assert t1 >= t0 and parent is None  # no step span above a bare driver
 
 
@@ -563,7 +563,7 @@ def test_stage_counters_are_numeric_and_monotone_and_the_ring_is_bounded():
                 pass
         rec.record("handoff", 5, 9, i, "round")
     assert len(rec.ring) == 8 and inner.parent == "step" and inner.t1 >= inner.t0 > 0
-    assert rec.ring[-1] == ("handoff", 5, 9, 19, rec.ring[-1][4], "round")
+    assert rec.ring[-1] == ("handoff", 5, 9, 19, rec.ring[-1][4], "round", None)
     second = rec.counters()
     assert all(isinstance(value, (int, float)) for value in second.values())
     assert all(second[key] >= first[key] for key in first)
@@ -582,5 +582,5 @@ def test_the_ring_dumps_as_json(tmp_path):
     d.stages.dump(str(path))
     blob = json.loads(path.read_text())
     assert blob["clock"] == "monotonic_ns"
-    assert blob["columns"] == ["name", "t0_ns", "t1_ns", "round", "thread", "parent"]
+    assert blob["columns"] == ["name", "t0_ns", "t1_ns", "round", "thread", "parent", "read_rows"]
     assert [row[0] for row in blob["spans"]] == ["assemble", "enqueue", "fetch", "execute"]

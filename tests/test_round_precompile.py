@@ -1,10 +1,11 @@
 """A plain round made ready before the first client (`_DriverCore._precompile`,
 beside `tests/test_chain_precompile.py`, which holds the Newt ladder): the
-Caesar driver's round and the leader round of the Paxos driver (with its own
-columns: `valid` is `bool`) are compiled or loaded inside
-`DeviceRuntime.start()`, so their first dispatch compiles nothing; the EPaxos
-driver starts as it did; and the two tests that count the plane programs' jit
-signatures still pass after a precompile in the same process."""
+Caesar driver's round, the leader round of the Paxos driver (with its own
+columns: `valid` is `bool`) and the dep-commit round with its `read` column,
+under either quorum rule and at key width 1 and 2, are compiled or loaded
+inside `DeviceRuntime.start()`, so their first dispatch compiles nothing; and
+the two tests that count the plane programs' jit signatures still pass after
+a precompile in the same process."""
 
 import asyncio
 import json
@@ -13,14 +14,18 @@ import pytest
 
 from fantoch_tpu.core import Command, Config, Dot, KVOp, Rifl
 from fantoch_tpu.observability import device as obs
-from fantoch_tpu.run.device_runner import CaesarDeviceDriver, DeviceRuntime, PaxosDeviceDriver
+from fantoch_tpu.run.device_runner import (
+    CaesarDeviceDriver, DeviceDriver, DeviceRuntime, PaxosDeviceDriver,
+)
 from fantoch_tpu.run.harness import free_port
 from tests.test_chain_precompile import LADDER, _tallies
 
 
 def _batch(first, count):
-    return [(Dot(1, first + i), Command.from_single(Rifl(7, first + i), 0, f"k{(first + i) % 5}",
-                                                     KVOp.put("v")))
+    """Writes of one key, every third command a read of it."""
+    return [(Dot(1, first + i), Command.from_single(
+        Rifl(7, first + i), 0, f"k{(first + i) % 5}",
+        KVOp.get() if (first + i) % 3 == 0 else KVOp.put("v")))
             for i in range(count)]
 
 
@@ -31,7 +36,15 @@ AHEAD = {
     "caesar": (lambda: CaesarDeviceDriver(7, batch_size=8, key_buckets=64, pending_capacity=8),
                "fast_paths"),
     "fpaxos": (lambda: PaxosDeviceDriver(5, f=1, batch_size=8, pending_capacity=8), "slow_paths"),
+    # the dep-commit round, its read column among the precompiled shapes
+    "epaxos": (lambda: DeviceDriver(5, batch_size=8, key_buckets=64, pending_capacity=8),
+               "fast_paths"),
+    "atlas": (lambda: DeviceDriver(5, f=1, rule="atlas", batch_size=8, key_buckets=64,
+                                   pending_capacity=8), "fast_paths"),
+    "atlas_2key": (lambda: DeviceDriver(5, f=1, rule="atlas", batch_size=8, key_buckets=64,
+                                        key_width=2, pending_capacity=8), "fast_paths"),
 }
+SERVED = ("caesar", "fpaxos", "epaxos", "atlas")
 ahead = pytest.mark.parametrize("protocol", AHEAD)
 
 
@@ -89,7 +102,7 @@ def _serve(protocol, tmp_path, commands=40):
     return asyncio.run(go())
 
 
-@ahead
+@pytest.mark.parametrize("protocol", SERVED)
 def test_a_server_has_its_round_before_it_listens_and_says_how_long_that_took(protocol, tmp_path):
     runtime, first, last, started, served = _serve(protocol, tmp_path)
     assert first["precompiled_programs"] == 0 and first["stage_precompile_n"] == 0
@@ -105,17 +118,30 @@ def test_a_server_has_its_round_before_it_listens_and_says_how_long_that_took(pr
         assert last["stable_watermark"] == last["executed"] == 40  # a dense log: a slot a command
     else:
         assert "round" not in last["backend"] and "accept_quorum" not in last["backend"]
+    if protocol in ("epaxos", "atlas"):  # the dep-commit round says whose quorums it runs
+        assert last["backend"]["rule"] == protocol
+        # n = 7, f = 3: EPaxos's 3 + 2 and 4, Atlas's 3 + 3 and 4
+        assert last["backend"]["quorums"] == {"epaxos": [5, 4], "atlas": [6, 4]}[protocol]
+        assert [name for name, *_ in runtime.driver._column_specs()] == ["key", "src", "seq", "read"]
+    else:
+        assert "rule" not in last["backend"] and "quorums" not in last["backend"]
 
 
-@pytest.mark.parametrize("protocol", ["epaxos"])
-def test_the_other_plain_round_drivers_start_as_before(protocol, tmp_path):
-    """The mechanism is switched on for Caesar and FPaxos only: the
-    dep-commit round is still built by its first dispatch."""
-    runtime, first, last, started, served = _serve(protocol, tmp_path)
-    assert first["precompiled_programs"] == last["precompiled_programs"] == 0
-    assert last["stage_precompile_n"] == 0 and last["stage_precompile_ms"] == 0
-    assert not runtime.driver._column_shardings
-    assert served[0] + served[2] > started[0] + started[2]  # compiled, or loaded, while serving
+@pytest.mark.parametrize("key_width", (1, 2))
+def test_the_dep_commit_round_takes_its_read_column_at_either_key_width(key_width, tmp_path):
+    """One compile a key width: the program is lowered on the four staged
+    columns, `read` (`bool[B]`) among them, and a round with reads and
+    writes then compiles nothing."""
+    obs.subscribe_recompiles()
+    driver = DeviceDriver(5, f=1, rule="atlas", batch_size=8, key_buckets=64,
+                          key_width=key_width, pending_capacity=8)
+    assert driver.precompile_chains(LADDER) == LADDER
+    assert driver.precompiled_programs == 1 and driver.stages.n["precompile"] == 1
+    assert len(driver._column_shardings[1]) == 4
+    before = _tallies()
+    assert len(driver.step(_batch(0, 8))) == 8
+    assert _tallies() == before
+    assert driver.round_tallies["read_rows"] == 3  # commands 0, 3 and 6
 
 
 def test_the_signature_counting_tests_still_pass_after_a_precompile_in_this_process():

@@ -386,7 +386,7 @@ def test_the_probe_classes_a_stop_of_the_loop_and_the_dump_keeps_its_window(tmp_
         ring = json.load(fh)
     assert list(ring) == ["clock", "columns", "spans", "stalls"]
     assert ring["clock"] == "monotonic_ns"
-    assert ring["columns"] == ["name", "t0_ns", "t1_ns", "round", "thread", "parent"]
+    assert ring["columns"] == ["name", "t0_ns", "t1_ns", "round", "thread", "parent", "read_rows"]
     # (a loaded machine may add a stop of its own: the sleep's is the one with the idle loop)
     stalls = sorted((stall for stall in ring["stalls"] if stall["t1_ns"] - stall["t0_ns"] >= 60 * MS),
                     key=lambda stall: stall["spent"]["loop_cpu_ns"])
@@ -413,21 +413,25 @@ def test_a_pool_thread_registers_itself_at_its_first_step(tmp_path):
     async def go():
         runtime = _runtime(metrics_file=str(tmp_path / "snap.json"))
         await runtime.start()
-        for seq in range(1, 9):
-            cmd = Command.from_single(Rifl(9, seq), 0, f"k{seq}", KVOp.put("v"))
+        # the round is compiled before the server listens, so the steps
+        # themselves have to cost the thread more than a tick of its CPU
+        # clock (10 ms on some kernels): sixty rounds
+        for seq in range(1, COMMANDS + 1):
+            cmd = Command.from_single(Rifl(9, seq), 0, f"k{seq % 50}", KVOp.put("v"))
             runtime.submit(runtime.dot_gen.next_id(), cmd)
-        for _ in range(1500):
+        for _ in range(3000):
             if runtime.failure is not None:
                 raise runtime.failure
-            if runtime.driver.executed >= 8:
+            if runtime.driver.executed >= COMMANDS:
                 break
             await asyncio.sleep(0.01)
         await runtime.stop()
         return runtime
 
+    COMMANDS = 480
     runtime = asyncio.run(go())
     t = runtime._tallies
-    assert runtime.driver.executed == 8
+    assert runtime.driver.executed == COMMANDS
     assert len(runtime.account._threads["step"]) >= 1 and len(runtime.account._threads["loop"]) == 1
     assert t["thread_step_cpu_ms"] > 0 and t["stage_step_cpu_ms"] > 0
     # the step's thread is on its CPU inside its step spans, and little elsewhere
