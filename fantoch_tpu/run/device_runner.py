@@ -85,7 +85,7 @@ from fantoch_tpu.run.prelude import (
     Submit,
     ToClient,
 )
-from fantoch_tpu.run.rw import ProtocolError, Rw, reply_frame
+from fantoch_tpu.run.rw import ProtocolError, Rw, partial_reply_frame, reply_frame
 from fantoch_tpu.utils import key_hash, logger
 
 Address = Tuple[str, int]
@@ -1473,7 +1473,15 @@ class PaxosDeviceDriver(_DriverCore):
 
 class _DeviceClientSession:
     """Server side of one client connection against the device driver
-    (the client.rs:79-260 role, minus dot routing — the driver orders)."""
+    (the client.rs:79-260 role, minus dot routing — the driver orders).
+
+    What the session keeps for a command in flight depends on the
+    command's shape.  One key on one shard (the dominant shape): its
+    ``runtime.rifl_sessions`` entry and nothing else (and its rifl in
+    ``_reads`` where it is read-only); its one partial is its reply.
+    Any other shape: beside that entry an ``AggregatePending`` slot per
+    touched shard, its key -> shard map and its count of shards still to
+    answer, until its last shard replied."""
 
     def __init__(self, runtime: "DeviceRuntime", rw: Rw):
         self.runtime = runtime
@@ -1501,31 +1509,45 @@ class _DeviceClientSession:
         self._flush_needed = asyncio.Event()
 
     def track(self, cmd: Command) -> None:
-        """Register a submitted command for result aggregation."""
+        """Register a submitted command as in flight: route its results
+        here (``runtime.rifl_sessions``) and, unless it has one key on one
+        shard, set up their aggregation.  A one-key command is complete at
+        its first and only partial, so its rifl's routing entry is all it
+        keeps (``deliver`` frames its reply from that partial)."""
+        rifl = cmd.rifl
         if cmd.read_only:
-            self._reads.add(cmd.rifl)
-        single = cmd.single_key()
-        if single is not None:
-            sid, key = single
-            self.pending_by_shard[sid].wait_for(cmd)
-            self._key_shard[cmd.rifl] = {key: sid}
-            self._shards_left[cmd.rifl] = 1
+            self._reads.add(rifl)
+        self.runtime.rifl_sessions[rifl] = self
+        if cmd.single_key() is not None:
+            self.runtime._flat_admitted += 1
             return
         for sid in cmd.shards():
             self.pending_by_shard[sid].wait_for(cmd)
-        self._key_shard[cmd.rifl] = {
-            key: sid for sid, key in cmd.all_keys()
-        }
-        self._shards_left[cmd.rifl] = cmd.shard_count
+        self._key_shard[rifl] = {key: sid for sid, key in cmd.all_keys()}
+        self._shards_left[rifl] = cmd.shard_count
+
+    def forget(self) -> None:
+        """Drop what the session holds of its commands in flight (the
+        connection closed: ``DeviceRuntime.drop_session``)."""
+        for rifl, shards in self._key_shard.items():
+            for sid in set(shards.values()):
+                self.pending_by_shard[sid].cancel(rifl)
+        self._key_shard.clear()
+        self._shards_left.clear()
+        self._reads.clear()
 
     def deliver(self, results: List[ExecutorResult]) -> int:
         """Route one round's per-key partials of this session's commands,
-        in the order the round executed them: aggregate each, encode a
-        frame for every ``CommandResult`` that completes (one per shard
-        of the command; from its values, ``rw.reply_frame``: no
-        ``ToClient`` is built), and hand the connection all of them in one
-        write.  Returns how many rifls are now fully answered; those are
-        gone from ``runtime.rifl_sessions`` whether or not the write went
+        in the order the round executed them, and hand the connection
+        every reply they complete in one write.  A partial whose rifl has
+        no aggregation state is a one-key command's only one: its frame
+        is made from the partial itself (``rw.partial_reply_frame``, the
+        bytes ``rw.reply_frame`` gives for the ``CommandResult`` it would
+        complete).  Any other is aggregated, and every ``CommandResult``
+        that completes (one per shard of the command) is encoded from its
+        values (``rw.reply_frame``: no ``ToClient`` is built).  Returns
+        how many rifls are now fully answered; those are gone from
+        ``runtime.rifl_sessions`` whether or not the write went
         through."""
         runtime = self.runtime
         rifl_sessions = runtime.rifl_sessions
@@ -1536,17 +1558,9 @@ class _DeviceClientSession:
         tracer = runtime.tracer
         tracing = tracer.enabled
         frames: List[bytes] = []
-        answered = completed = multi_shard = gets = get_bytes = 0
+        flat = completed = multi_shard = gets = get_bytes = 0
         for result in results:
             rifl = result.rifl
-            shards = key_shard.get(rifl)
-            if shards is None:
-                # stale (the session re-registered the rifl, or a bug):
-                # counts as answered, once
-                if rifl_sessions.pop(rifl, None) is not None:
-                    answered += 1
-                continue
-            done = pending_by_shard[shards[result.key]].add_executor_result(result)
             is_read = bool(reads) and rifl in reads
             if is_read:
                 for value in result.op_results:
@@ -1556,24 +1570,38 @@ class _DeviceClientSession:
                         get_bytes += (
                             len(value) if value.isascii() else len(value.encode())
                         )
-            if done is None:
-                continue
+            shards = key_shard.get(rifl) if key_shard else None
+            if shards is None:
+                # one key on one shard: tracked by its rifl alone, complete
+                # at this partial.  No entry: stale (the same rifl twice in
+                # one round: answered at the first)
+                if rifl_sessions.pop(rifl, None) is None:
+                    continue
+                frame = partial_reply_frame(result)
+                flat += 1
+                last = True
+            else:
+                done = pending_by_shard[shards[result.key]].add_executor_result(result)
+                if done is None:
+                    continue
+                frame = reply_frame(done)
+                left = shards_left[rifl] - 1
+                last = not left
+                if last:
+                    del key_shard[rifl], shards_left[rifl], rifl_sessions[rifl]
+                    completed += 1
+                    if len(shards) > 1 and len(set(shards.values())) > 1:
+                        multi_shard += 1
+                else:
+                    shards_left[rifl] = left
             if tracing:
                 tracer.span("executed", rifl, pid=runtime.process_id)
                 tracer.edge("s", "Reply", runtime.process_id, 0, 0, rifl=rifl)
-            frames.append(reply_frame(done))
-            left = shards_left[rifl] - 1
-            if left:
-                shards_left[rifl] = left
-            else:
-                del key_shard[rifl], shards_left[rifl], rifl_sessions[rifl]
-                answered += 1
-                completed += 1
-                if is_read:
-                    reads.discard(rifl)
-                    gets += 1
-                if len(shards) > 1 and len(set(shards.values())) > 1:
-                    multi_shard += 1
+            frames.append(frame)
+            if is_read and last:
+                reads.discard(rifl)
+                gets += 1
+        completed += flat
         if frames:
             data = b"".join(frames)
             self.rw.write_frames(data)
@@ -1581,12 +1609,13 @@ class _DeviceClientSession:
             runtime._reply_bytes += len(data)
             runtime._shard_replies += len(frames)
             runtime._reply_plain_frames += len(frames)
+            runtime._reply_flat_frames += flat
             runtime._commands_completed += completed
             runtime._multi_shard_completed += multi_shard
             runtime._gets_replied += gets
             runtime._get_value_bytes += get_bytes
             self._flush_needed.set()
-        return answered
+        return completed
 
     async def _flush_loop(self) -> None:
         runtime = self.runtime
@@ -1676,7 +1705,11 @@ class _DeviceClientSession:
         (shed with a typed Overloaded BEFORE tracking, so the retry
         re-runs the full path with no leftover aggregation state),
         tracked and given its dot; the read's admitted commands then
-        enter the ring together.  Any other message is taken where it
+        enter the ring together.  A command of one key on one shard can
+        only name the wrong shard (one bucket never exceeds the key
+        width), so its shard is all that is checked, and what ``track``
+        does for it is done in place; every other shape goes through
+        ``_validate`` and ``track``.  Any other message is taken where it
         stands: what was admitted before it is pushed whatever it
         raises."""
         t0 = monotonic_ns()
@@ -1695,6 +1728,9 @@ class _DeviceClientSession:
         rifl_sessions = runtime.rifl_sessions
         next_dot = runtime.dot_gen.next_id
         validate, track = self._validate, self.track
+        reads = self._reads
+        served = self.pending_by_shard  # keyed by the shards this server has
+        flat = 0
         admitted: List[Tuple[Dot, Command, float]] = []
         try:
             for msg in msgs:
@@ -1708,7 +1744,15 @@ class _DeviceClientSession:
                     tracer.edge(
                         "r", "Submit", 0, runtime.process_id, 0, rifl=cmd.rifl,
                     )
-                why = validate(cmd)
+                # cmd.single_key()'s test, spelled out
+                shard_to_ops = cmd._shard_to_ops
+                one_key = cmd._total_key_count == 1 and len(shard_to_ops) == 1
+                if one_key:
+                    (sid,) = shard_to_ops
+                    # a wrong shard: the reason in _validate's words
+                    why = None if sid in served else validate(cmd)
+                else:
+                    why = validate(cmd)
                 if why is not None:
                     self._reject(cmd, why)
                     continue
@@ -1722,8 +1766,15 @@ class _DeviceClientSession:
                         room = 0
                     self._shed(cmd)
                     continue
-                track(cmd)
-                rifl_sessions[cmd.rifl] = self
+                if one_key:
+                    # track(cmd), in place
+                    rifl = cmd._rifl
+                    if cmd._read_only:
+                        reads.add(rifl)
+                    rifl_sessions[rifl] = self
+                    flat += 1
+                else:
+                    track(cmd)
                 dot = next_dot()
                 if tracing:
                     tracer.span(
@@ -1731,6 +1782,7 @@ class _DeviceClientSession:
                     )
                 admitted.append((dot, cmd, now_ms))
         finally:
+            runtime._flat_admitted += flat
             if admitted:
                 runtime.submit_all(admitted, now_ms)
             if timed:
@@ -2000,6 +2052,10 @@ class DeviceRuntime:
         self._reply_bytes = 0
         self._shard_replies = 0  # CommandResult frames: one per touched shard
         self._reply_plain_frames = 0  # ... encoded from their values (rw.reply_frame)
+        # ... of them a one-key command's, from its one partial
+        self._reply_flat_frames = 0
+        # admitted commands of one key on one shard: tracked by rifl alone
+        self._flat_admitted = 0
         self._commands_completed = 0  # a command's last shard replied
         self._multi_shard_completed = 0  # ... of a command over several shards
         self._gets_replied = 0  # read-only commands among the completed
@@ -2175,6 +2231,8 @@ class DeviceRuntime:
         )
         self._tallies = {
             "submitted": self.submitted,
+            # ... of them tracked by their rifl alone (one key, one shard)
+            "session_flat_admitted": self._flat_admitted,
             "replied": self.replied,
             "rounds": d.rounds,
             "executed": d.executed,
@@ -2236,6 +2294,7 @@ class DeviceRuntime:
             # those of them that touched more than one shard
             "shard_replies": self._shard_replies,
             "reply_plain_frames": self._reply_plain_frames,
+            "reply_flat_frames": self._reply_flat_frames,
             "commands_completed": self._commands_completed,
             "multi_shard_completed": self._multi_shard_completed,
             # reads: read-only commands completed, the bytes of the values
@@ -2457,6 +2516,7 @@ class DeviceRuntime:
         ]
         for rifl in stale:
             del self.rifl_sessions[rifl]
+        session.forget()
 
     def _deliver(self, results: List[ExecutorResult]) -> None:
         """The reply stage of a round: its results grouped by the

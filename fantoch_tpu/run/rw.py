@@ -107,6 +107,17 @@ def reply_frame(result: CommandResult) -> bytes:
     return _HEAD.pack(len(payload) + 1, KIND_TO_CLIENT) + payload
 
 
+def partial_reply_frame(partial) -> bytes:
+    """:func:`reply_frame` of the ``CommandResult`` that a one-key
+    command's only partial (an ``ExecutorResult``) completes, byte for
+    byte, with no ``CommandResult`` built on the way."""
+    rifl = partial.rifl
+    payload = pickle.dumps(
+        (rifl[0], rifl[1], 1, {partial.key: partial.op_results}), _PROTOCOL
+    )
+    return _HEAD.pack(len(payload) + 1, KIND_TO_CLIENT) + payload
+
+
 async def connect_with_retry(
     addr: tuple, attempts: int = 120, backoff_s: float = 0.05
 ) -> "Rw":

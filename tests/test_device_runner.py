@@ -2353,10 +2353,10 @@ def _reply_stage(shard_count=1, connections=3, failing=None):
 
 
 def _submitted(runtime, session, cmd):
-    """What ``_DeviceClientSession.run`` does for an admitted Submit,
+    """What ``_DeviceClientSession._admit`` does for an admitted Submit,
     short of the ring."""
     session.track(cmd)
-    runtime.rifl_sessions[cmd.rifl] = session
+    assert runtime.rifl_sessions[cmd.rifl] is session
 
 
 def _frames(data):
@@ -2410,7 +2410,9 @@ def _mixed_rounds(shard_count):
     """Two rounds of results over three live connections and a dropped
     one: single-key commands, a two-key command whose partials arrive
     in different rounds, a two-shard command (where the server has two
-    shards), a stale rifl and the dropped session's rifl."""
+    shards), a stale rifl (a one-key command of the third connection
+    whose partial comes again a round after its reply) and the dropped
+    session's rifl."""
     from fantoch_tpu.executor.base import ExecutorResult
 
     put = KVOp.put("v" * 100)
@@ -2424,6 +2426,7 @@ def _mixed_rounds(shard_count):
     if shard_count == 2:
         tracked[1] = tracked[1] + [Command(Rifl(11, 9), {0: {"c": (put,)}, 1: {"d": (put, put)}})]
     stale, dropped = Rifl(12, 77), Command.from_single(Rifl(13, 1), 0, "gone", put)
+    tracked[2] = tracked[2] + [Command.from_single(stale, 0, "x", put)]
 
     def res(rifl, key, *values):
         return ExecutorResult(rifl, key, values or ("prev-" + key,))
@@ -2445,7 +2448,6 @@ def test_a_rounds_replies_equal_the_one_at_a_time_reference(shard_count):
     for conn, cmds in tracked.items():
         for cmd in cmds:
             _submitted(runtime, sessions[conn], cmd)
-    runtime.rifl_sessions[stale] = sessions[2]  # routed, never tracked
     _submitted(runtime, sessions[3], dropped)
     runtime.drop_session(sessions[3])
     for results in rounds:
@@ -2457,8 +2459,8 @@ def test_a_rounds_replies_equal_the_one_at_a_time_reference(shard_count):
         assert got and all(t.cmd_result.ready for t in got)
     assert writers[3].writes == []
     answered = sum(len(cmds) for cmds in tracked.values())
-    # the stale rifl counts as answered, once; only the unexecuted commands stay routed
-    assert runtime.replied == answered - 2 + 1
+    # the stale rifl is answered once; only the unexecuted commands stay routed
+    assert runtime.replied == answered - 2
     assert set(runtime.rifl_sessions) == {Rifl(11, 3), Rifl(12, 3)}
     assert not sessions[0]._key_shard and not sessions[0]._shards_left
 
@@ -2481,7 +2483,9 @@ def test_a_round_is_one_write_per_session_and_the_snapshot_counts_it():
     t = runtime._tallies
     assert t["reply_writes"] == sum(len(w.writes) for w in writers) == 6
     assert t["reply_bytes"] == sum(len(data) for w in writers for data in w.writes)
-    assert t["replied"] == sum(len(_frames(data)) for w in writers for data in w.writes) == 8
+    assert t["replied"] == sum(len(_frames(data)) for w in writers for data in w.writes) == 9
+    # eight one-key commands framed from their one partial, the two-key one aggregated
+    assert (t["reply_flat_frames"], t["shard_replies"], t["reply_plain_frames"]) == (8, 9, 9)
 
 
 def test_a_dead_connection_costs_only_its_own_replies(caplog):
@@ -2533,12 +2537,222 @@ def test_every_reply_is_one_executed_span_and_one_reply_edge():
     for results in rounds:
         runtime._deliver(results)
     replies = [t.cmd_result.rifl for w in writers for t in _frames(b"".join(w.writes))]
-    assert len(replies) == 10  # 7 single-key, the two-key one, two shards of Rifl(11, 9)
+    assert len(replies) == 11  # 8 single-key, the two-key one, two shards of Rifl(11, 9)
     assert sorted(rifl for _stage, rifl, _pid in runtime.tracer.spans) == sorted(replies)
     assert {(stage, pid) for stage, _rifl, pid in runtime.tracer.spans} == {
         ("executed", runtime.process_id)}
     assert sorted(rifl for _d, _k, rifl in runtime.tracer.edges) == sorted(replies)
     assert {(d, k) for d, k, _rifl in runtime.tracer.edges} == {("s", "Reply")}
+
+
+# --- a one-key command carries no aggregation state (PR 41) ---
+
+
+def _partial(cmd, key, *values):
+    from fantoch_tpu.executor.base import ExecutorResult
+
+    return ExecutorResult(cmd.rifl, key, values)
+
+
+def _in_flight(runtime, sessions):
+    """Everything the runtime and its sessions hold of commands in flight."""
+    held = {"rifl_sessions": dict(runtime.rifl_sessions)}
+    for i, session in enumerate(sessions):
+        held[i, "_key_shard"] = dict(session._key_shard)
+        held[i, "_shards_left"] = dict(session._shards_left)
+        held[i, "_reads"] = set(session._reads)
+        for sid, pending in session.pending_by_shard.items():
+            held[i, "pending", sid] = dict(pending._pending)
+    return {name: left for name, left in held.items() if left}
+
+
+@pytest.mark.parametrize("op, values", [
+    (KVOp.put("v" * 100), ("previous",)),
+    (KVOp.get(), ("v" * 1000,)),
+    (KVOp.get(), (None,)),
+    (KVOp.put("größer \u2603 \U0001f600"), ("wert \u00e9\u4e2d",)),
+], ids=["put", "get-hit", "get-miss", "non-ascii"])
+def test_a_one_key_reply_is_the_command_results_frame_byte_for_byte(op, values):
+    from fantoch_tpu.core.command import CommandResult
+    from fantoch_tpu.run.rw import frame, reply_frame
+    from fantoch_tpu.run.prelude import ToClient
+
+    runtime, (session,), (writer,) = _reply_stage(connections=1)
+    cmd = Command.from_single(Rifl(2**40 + 7, 2**33), 0, "k\u00fc", op)
+    session.track(cmd)
+    assert not session._key_shard and not session._shards_left
+    assert not session.pending_by_shard[0]._pending
+    runtime._deliver([_partial(cmd, "k\u00fc", *values)])
+    aggregated = CommandResult(cmd.rifl, 1)
+    assert aggregated.add_partial("k\u00fc", values)
+    assert writer.writes == [reply_frame(aggregated)] == [frame(ToClient(aggregated))]
+    runtime._publish_tallies()
+    t = runtime._tallies
+    read = cmd.read_only
+    assert (t["gets_replied"], t["commands_completed"], t["replied"]) == (int(read), 1, 1)
+    carried = sum(len(v.encode()) for v in values if v is not None)
+    assert t["get_value_bytes"] == (carried if read else 0)
+    assert not _in_flight(runtime, [session])
+
+
+def _one_key_shapes(shard_count):
+    """One-key commands over every shard a client could name, and shapes
+    that have one key in all but not one shard."""
+    put, get = KVOp.put("v"), KVOp.get()
+    cmds = [
+        Command.from_single(Rifl(1, seq), sid, f"k{seq}", op)
+        for seq, (sid, op) in enumerate(
+            (sid, op) for sid in (-1, 0, 1, 3, 4, 7) for op in (put, get)
+        )
+    ]
+    cmds.append(Command(Rifl(1, 100), {0: {"a": (put,)}, 1: {}}))
+    cmds.append(Command(Rifl(1, 101), {shard_count: {"a": (put,)}, 0: {}}))
+    return cmds
+
+
+@pytest.mark.parametrize("shard_count", [1, 4])
+def test_admits_one_key_branch_rejects_what_validate_rejects_in_its_words(shard_count):
+    from fantoch_tpu.run.prelude import Submit
+
+    runtime, (session,), (writer,) = _reply_stage(shard_count, connections=1)
+    pushed, rejected = [], {}
+    runtime.submit_all = lambda admitted, now_ms: pushed.extend(cmd for _dot, cmd, _at in admitted)
+    reject = session._reject
+    session._reject = lambda cmd, why: (rejected.__setitem__(cmd.rifl, why), reject(cmd, why))
+    cmds = _one_key_shapes(shard_count)
+    reasons = {cmd.rifl: session._validate(cmd) for cmd in cmds}
+    session._admit([Submit(cmd) for cmd in cmds])
+    assert rejected == {rifl: why for rifl, why in reasons.items() if why is not None}
+    assert [cmd.rifl for cmd in pushed] == [r for r, why in reasons.items() if why is None]
+    assert rejected and pushed
+    # a rejection is answered with an empty result and leaves nothing behind
+    assert [_fields(t) for t in _frames(b"".join(writer.writes))] == [
+        (rifl, 0, {}, True) for rifl in rejected]
+    assert set(runtime.rifl_sessions) == {cmd.rifl for cmd in pushed}
+    assert session._reads == {cmd.rifl for cmd in pushed if cmd.read_only}
+    runtime._publish_tallies()
+    flat = [cmd for cmd in pushed if cmd.shard_count == 1]
+    assert runtime._tallies["session_flat_admitted"] == len(flat) > 0
+    assert set(session._key_shard) == {cmd.rifl for cmd in pushed} - {cmd.rifl for cmd in flat}
+
+
+@pytest.mark.parametrize("keys", [1, 2], ids=["one-key", "two-key"])
+def test_the_same_rifl_twice_in_one_round_is_answered_once(keys):
+    runtime, (session,), (writer,) = _reply_stage(connections=1)
+    put = KVOp.put("v")
+    cmd = Command(Rifl(5, 1), {0: {f"k{i}": (put,) for i in range(keys)}})
+    session.track(cmd)
+    session.track(cmd)  # the client sent it again before its reply
+    once = [_partial(cmd, f"k{i}", "first") for i in range(keys)]
+    again = [_partial(cmd, f"k{i}", "second") for i in range(keys)]
+    runtime._deliver(once + again)
+    (reply,) = _frames(b"".join(writer.writes))
+    assert _fields(reply) == (cmd.rifl, keys, {f"k{i}": ("first",) for i in range(keys)}, True)
+    assert runtime.replied == 1
+    runtime._publish_tallies()
+    assert runtime._tallies["reply_flat_frames"] == (1 if keys == 1 else 0)
+    assert runtime._tallies["commands_completed"] == 1
+    assert not _in_flight(runtime, [session])
+    runtime._deliver(again)  # ... and a round later: nowhere to go
+    assert len(writer.writes) == 1
+
+
+def _one_session_round(shard_count):
+    """One session's commands, one-key and two-key, reads among both, and
+    one round's partials of them, the two-key ones' apart."""
+    put, get = KVOp.put("v" * 10), KVOp.get()
+    other = shard_count - 1
+    cmds = [
+        Command.from_single(Rifl(3, 1), 0, "a", put),
+        Command(Rifl(3, 2), {0: {"b": (put,)}, other: {"c": (put,)}} if other
+                else {0: {"b": (put,), "c": (put,)}}),
+        Command.from_single(Rifl(3, 3), other, "d", get),
+        Command(Rifl(3, 4), {0: {"e": (get,), "f": (get,)}}),
+        Command.from_single(Rifl(3, 5), 0, "g", put),
+    ]
+    a, two, d, reads, g = cmds
+    results = [
+        _partial(two, "c", "c0"), _partial(a, "a", None), _partial(reads, "f", "f0"),
+        _partial(d, "d", "d0"), _partial(two, "b", "b0"), _partial(g, "g", "g0"),
+        _partial(reads, "e", None),
+    ]
+    return cmds, results
+
+
+@pytest.mark.parametrize("shard_count", [1, 2])
+def test_one_key_and_two_key_replies_of_a_round_keep_its_execution_order(shard_count):
+    runtime, (session,), (writer,) = _reply_stage(shard_count, connections=1)
+    cmds, results = _one_session_round(shard_count)
+    for cmd in cmds:
+        session.track(cmd)
+    assert set(session._key_shard) == {Rifl(3, 2), Rifl(3, 4)}
+    runtime._deliver(results)
+    assert len(writer.writes) == 1
+    got = [_fields(t) for t in _frames(writer.writes[0])]
+    if shard_count == 1:
+        first, second = [], [(Rifl(3, 2), 2, {"c": ("c0",), "b": ("b0",)}, True)]
+    else:  # one CommandResult a shard, each when its shard's keys are in
+        first = [(Rifl(3, 2), 1, {"c": ("c0",)}, True)]
+        second = [(Rifl(3, 2), 1, {"b": ("b0",)}, True)]
+    assert got == [
+        *first,
+        (Rifl(3, 1), 1, {"a": (None,)}, True),
+        (Rifl(3, 3), 1, {"d": ("d0",)}, True),
+        *second,
+        (Rifl(3, 5), 1, {"g": ("g0",)}, True),
+        (Rifl(3, 4), 2, {"f": ("f0",), "e": (None,)}, True),
+    ]
+    assert got == [_fields(t) for t in _one_at_a_time({0: cmds}, [results])[0]]
+    runtime._publish_tallies()
+    t = runtime._tallies
+    assert (t["reply_flat_frames"], t["shard_replies"]) == (3, 4 + shard_count)
+    assert (t["commands_completed"], t["multi_shard_completed"]) == (5, shard_count - 1)
+    assert (t["gets_replied"], t["get_value_bytes"]) == (2, 4)
+
+
+@pytest.mark.parametrize("end", ["replied", "dropped"])
+def test_nothing_is_left_of_a_command_after_its_reply_or_its_sessions_drop(end):
+    runtime, sessions, writers = _reply_stage(shard_count=2, connections=2)
+    cmds, results = _one_session_round(2)
+    other = [Command.from_single(Rifl(4, 1), 1, "z", KVOp.get())]
+    for cmd in cmds:
+        sessions[0].track(cmd)
+    sessions[1].track(other[0])
+    held = _in_flight(runtime, sessions)
+    assert {name[1] if isinstance(name, tuple) else name for name in held} == {
+        "rifl_sessions", "_key_shard", "_shards_left", "_reads", "pending"}
+    if end == "replied":
+        runtime._deliver(results + [_partial(other[0], "z", None)])
+        assert runtime.replied == 6
+    else:
+        runtime._deliver(results[:3])  # a two-key command half answered, then the close
+        runtime.drop_session(sessions[0])
+        assert set(runtime.rifl_sessions) == {Rifl(4, 1)}
+        runtime._deliver(results[3:])  # executed for the cluster, answered to no one
+        assert len(writers[0].writes) == 1
+        runtime.drop_session(sessions[1])
+    assert not _in_flight(runtime, sessions)
+
+
+def test_the_flat_counters_count_one_key_commands_from_admit_to_reply():
+    from fantoch_tpu.run.prelude import Submit
+
+    runtime, (session,), (writer,) = _reply_stage(shard_count=2, connections=1)
+    runtime.submit_all = lambda admitted, now_ms: None
+    cmds, results = _one_session_round(2)
+    session._admit([Submit(cmd) for cmd in cmds[:3]])
+    session._admit([Submit(cmd) for cmd in cmds[3:]])
+    runtime._publish_tallies()
+    assert runtime._tallies["session_flat_admitted"] == 3
+    assert runtime._tallies["reply_flat_frames"] == 0
+    runtime._deliver(results[:4])
+    runtime._deliver(results[4:])
+    runtime._publish_tallies()
+    t = runtime._tallies
+    assert (t["session_flat_admitted"], t["reply_flat_frames"]) == (3, 3)
+    assert t["shard_replies"] == t["reply_plain_frames"] == 6
+    assert t["replied"] == t["commands_completed"] == 5
+    assert not _in_flight(runtime, [session])
 
 
 @pytest.mark.parametrize("results, key_count", [
