@@ -13,8 +13,8 @@ device-resident across rounds (donated), and the host only
   * feeds command batches in (array columns assembled from client
     submissions), and
   * drains execution orders out (applying them to the host KVStore and
-    routing results back to client sessions through AggregatePending —
-    the same client plane as the object runner).
+    routing results back to client sessions, one reply per touched
+    shard — the client plane's wire contract, as the object runner's).
 
 ``DeviceDriver`` is the host-side control loop (usable without any
 networking: the driver dry-run and the simulator-style tests call it
@@ -47,6 +47,7 @@ from __future__ import annotations
 import asyncio
 import gc
 import os
+import sys
 from collections import defaultdict, deque
 from time import monotonic, monotonic_ns, thread_time_ns
 from typing import Any, Deque, Dict, List, NamedTuple, Optional, Sequence, Tuple
@@ -57,7 +58,6 @@ from fantoch_tpu.core.command import Command
 from fantoch_tpu.core.config import Config
 from fantoch_tpu.core.ids import ClientId, Dot, ProcessId, Rifl, ShardId
 from fantoch_tpu.core.kvs import KVStore
-from fantoch_tpu.executor.aggregate import AggregatePending
 from fantoch_tpu.executor.base import ExecutorResult
 from fantoch_tpu.observability.device import (
     CPU_PAIR_EVERY_NS,
@@ -85,7 +85,7 @@ from fantoch_tpu.run.prelude import (
     Submit,
     ToClient,
 )
-from fantoch_tpu.run.rw import ProtocolError, Rw, partial_reply_frame, reply_frame
+from fantoch_tpu.run.rw import ProtocolError, Rw, joined_reply_frame, partial_reply_frame
 from fantoch_tpu.utils import key_hash, logger
 
 Address = Tuple[str, int]
@@ -1479,29 +1479,32 @@ class _DeviceClientSession:
     command's shape.  One key on one shard (the dominant shape): its
     ``runtime.rifl_sessions`` entry and nothing else (and its rifl in
     ``_reads`` where it is read-only); its one partial is its reply.
-    Any other shape: beside that entry an ``AggregatePending`` slot per
-    touched shard, its key -> shard map and its count of shards still to
-    answer, until its last shard replied."""
+    Any other shape: beside that entry one record in ``_owed``, a dict
+    of the keys whose partials are still owed.  A key alone on its shard
+    maps to whether the command has several shards (a bool): its partial
+    is that shard's whole reply.  The keys that share a shard map to the
+    one list they share, ``[keys left, {key: op_results} in the order
+    the partials land, several shards?]``: the shard's reply is framed
+    when its last key lands.  A partial takes its key out; the command
+    is answered when the record is empty.  (A partial names its key and
+    no shard, so a command is taken to name a key once.)"""
 
     def __init__(self, runtime: "DeviceRuntime", rw: Rw):
         self.runtime = runtime
         self.rw = rw
-        # one aggregation per shard: a multi-shard command answers with
-        # one CommandResult PER SHARD (the per-shard-server contract the
-        # client plane counts on, run/client_runner.py submit()); the
-        # unified mesh server emits them all over the submit connection.
         driver = runtime.driver
-        sids = (
+        # the shards this server has
+        self._served = frozenset(
             range(driver.shard_count)
             if driver.shard_count > 1
             else (driver.shard_id,)  # single-shard may sit on any shard id
         )
-        self.pending_by_shard: Dict[ShardId, AggregatePending] = {
-            sid: AggregatePending(runtime.process_id, sid) for sid in sids
-        }
-        # rifl -> (key -> owning shard), alive while results are pending
-        self._key_shard: Dict[Rifl, Dict[str, ShardId]] = {}
-        self._shards_left: Dict[Rifl, int] = {}
+        # rifl -> the keys still owed, for a command that is not one key
+        # on one shard: a multi-shard command answers with one
+        # CommandResult PER SHARD (the per-shard-server contract the
+        # client plane counts on, run/client_runner.py submit()); the
+        # unified mesh server emits them all over the submit connection.
+        self._owed: Dict[Rifl, Dict[str, Any]] = {}
         # the read-only commands among them: what their replies carry is
         # counted apart (gets_replied, get_value_bytes)
         self._reads: set = set()
@@ -1511,54 +1514,60 @@ class _DeviceClientSession:
     def track(self, cmd: Command) -> None:
         """Register a submitted command as in flight: route its results
         here (``runtime.rifl_sessions``) and, unless it has one key on one
-        shard, set up their aggregation.  A one-key command is complete at
+        shard, write its record of the keys owed (the class's docstring),
+        in one pass over its shards.  A one-key command is complete at
         its first and only partial, so its rifl's routing entry is all it
         keeps (``deliver`` frames its reply from that partial)."""
-        rifl = cmd.rifl
-        if cmd.read_only:
+        rifl = cmd._rifl
+        if cmd._read_only:
             self._reads.add(rifl)
         self.runtime.rifl_sessions[rifl] = self
-        if cmd.single_key() is not None:
+        shard_to_ops = cmd._shard_to_ops
+        several = len(shard_to_ops) > 1
+        if cmd._total_key_count == 1 and not several:
             self.runtime._flat_admitted += 1
             return
-        for sid in cmd.shards():
-            self.pending_by_shard[sid].wait_for(cmd)
-        self._key_shard[rifl] = {key: sid for sid, key in cmd.all_keys()}
-        self._shards_left[rifl] = cmd.shard_count
+        owed: Dict[str, Any] = {}
+        for ops in shard_to_ops.values():
+            if len(ops) == 1:
+                for key in ops:
+                    owed[key] = several
+            else:
+                shared = [len(ops), {}, several]
+                for key in ops:
+                    owed[key] = shared
+        self._owed[rifl] = owed
 
     def forget(self) -> None:
         """Drop what the session holds of its commands in flight (the
         connection closed: ``DeviceRuntime.drop_session``)."""
-        for rifl, shards in self._key_shard.items():
-            for sid in set(shards.values()):
-                self.pending_by_shard[sid].cancel(rifl)
-        self._key_shard.clear()
-        self._shards_left.clear()
+        self._owed.clear()
         self._reads.clear()
 
     def deliver(self, results: List[ExecutorResult]) -> int:
         """Route one round's per-key partials of this session's commands,
         in the order the round executed them, and hand the connection
         every reply they complete in one write.  A partial whose rifl has
-        no aggregation state is a one-key command's only one: its frame
-        is made from the partial itself (``rw.partial_reply_frame``, the
-        bytes ``rw.reply_frame`` gives for the ``CommandResult`` it would
-        complete).  Any other is aggregated, and every ``CommandResult``
-        that completes (one per shard of the command) is encoded from its
-        values (``rw.reply_frame``: no ``ToClient`` is built).  Returns
-        how many rifls are now fully answered; those are gone from
+        no record is a one-key command's only one, and one whose key is
+        alone on its shard is that shard's whole reply: the frame is made
+        from the partial itself (``rw.partial_reply_frame``, the bytes
+        ``rw.reply_frame`` gives for the ``CommandResult`` it would
+        complete).  A key that shares its shard joins the shard's results,
+        and the last of them frames the shard's reply from those
+        (``rw.joined_reply_frame``: the same bytes, no ``CommandResult``
+        built).  A key the record does not owe (the same rifl twice in one
+        round) is skipped: a shard is answered once.  Returns how many
+        rifls are now fully answered; those are gone from
         ``runtime.rifl_sessions`` whether or not the write went
         through."""
         runtime = self.runtime
         rifl_sessions = runtime.rifl_sessions
-        key_shard = self._key_shard
-        shards_left = self._shards_left
-        pending_by_shard = self.pending_by_shard
+        owed_by_rifl = self._owed
         reads = self._reads
         tracer = runtime.tracer
         tracing = tracer.enabled
         frames: List[bytes] = []
-        flat = completed = multi_shard = gets = get_bytes = 0
+        flat = partial = completed = multi_shard = gets = get_bytes = 0
         for result in results:
             rifl = result.rifl
             is_read = bool(reads) and rifl in reads
@@ -1570,8 +1579,8 @@ class _DeviceClientSession:
                         get_bytes += (
                             len(value) if value.isascii() else len(value.encode())
                         )
-            shards = key_shard.get(rifl) if key_shard else None
-            if shards is None:
+            owed = owed_by_rifl.get(rifl) if owed_by_rifl else None
+            if owed is None:
                 # one key on one shard: tracked by its rifl alone, complete
                 # at this partial.  No entry: stale (the same rifl twice in
                 # one round: answered at the first)
@@ -1581,19 +1590,29 @@ class _DeviceClientSession:
                 flat += 1
                 last = True
             else:
-                done = pending_by_shard[shards[result.key]].add_executor_result(result)
-                if done is None:
-                    continue
-                frame = reply_frame(done)
-                left = shards_left[rifl] - 1
-                last = not left
-                if last:
-                    del key_shard[rifl], shards_left[rifl], rifl_sessions[rifl]
-                    completed += 1
-                    if len(shards) > 1 and len(set(shards.values())) > 1:
-                        multi_shard += 1
+                key = result.key
+                slot = owed.pop(key, None)
+                if slot is None:
+                    continue  # not owed: this shard's reply took it
+                if slot.__class__ is list:
+                    # one of its shard's several keys: the last frames them
+                    joined = slot[1]
+                    joined[key] = result.op_results
+                    left = slot[0] - 1
+                    if left:
+                        slot[0] = left
+                        continue
+                    frame = joined_reply_frame(rifl, len(joined), joined)
+                    several = slot[2]
                 else:
-                    shards_left[rifl] = left
+                    frame = partial_reply_frame(result)
+                    partial += 1
+                    several = slot
+                last = not owed
+                if last:
+                    del owed_by_rifl[rifl], rifl_sessions[rifl]
+                    completed += 1
+                    multi_shard += several
             if tracing:
                 tracer.span("executed", rifl, pid=runtime.process_id)
                 tracer.edge("s", "Reply", runtime.process_id, 0, 0, rifl=rifl)
@@ -1602,6 +1621,7 @@ class _DeviceClientSession:
                 reads.discard(rifl)
                 gets += 1
         completed += flat
+        partial += flat
         if frames:
             data = b"".join(frames)
             self.rw.write_frames(data)
@@ -1610,6 +1630,7 @@ class _DeviceClientSession:
             runtime._shard_replies += len(frames)
             runtime._reply_plain_frames += len(frames)
             runtime._reply_flat_frames += flat
+            runtime._reply_partial_frames += partial
             runtime._commands_completed += completed
             runtime._multi_shard_completed += multi_shard
             runtime._gets_replied += gets
@@ -1708,10 +1729,13 @@ class _DeviceClientSession:
         enter the ring together.  A command of one key on one shard can
         only name the wrong shard (one bucket never exceeds the key
         width), so its shard is all that is checked, and what ``track``
-        does for it is done in place; every other shape goes through
-        ``_validate`` and ``track``.  Any other message is taken where it
-        stands: what was admitted before it is pushed whatever it
-        raises."""
+        does for it is done in place.  Any other shape with at least one
+        key, no more keys than the key width and every shard the server's
+        is accepted as it stands too (distinct buckets never outnumber
+        keys) and goes to ``track``; whatever fails that test goes to
+        ``_validate``, which decides in its own words.  Any other message
+        is taken where it stands: what was admitted before it is pushed
+        whatever it raises."""
         t0 = monotonic_ns()
         # the read's one arrival time: it rides beside each of its
         # commands in the ring
@@ -1729,7 +1753,11 @@ class _DeviceClientSession:
         next_dot = runtime.dot_gen.next_id
         validate, track = self._validate, self.track
         reads = self._reads
-        served = self.pending_by_shard  # keyed by the shards this server has
+        served = self._served
+        # key_width None = the driver needs no key rows: no bound
+        key_width = runtime.driver.key_width
+        if key_width is None:
+            key_width = sys.maxsize
         flat = 0
         admitted: List[Tuple[Dot, Command, float]] = []
         try:
@@ -1751,6 +1779,11 @@ class _DeviceClientSession:
                     (sid,) = shard_to_ops
                     # a wrong shard: the reason in _validate's words
                     why = None if sid in served else validate(cmd)
+                elif (
+                    0 < cmd._total_key_count <= key_width
+                    and served.issuperset(shard_to_ops)
+                ):
+                    why = None
                 else:
                     why = validate(cmd)
                 if why is not None:
@@ -2051,9 +2084,12 @@ class DeviceRuntime:
         self._reply_writes = 0  # writes of a round's frames to a connection
         self._reply_bytes = 0
         self._shard_replies = 0  # CommandResult frames: one per touched shard
-        self._reply_plain_frames = 0  # ... encoded from their values (rw.reply_frame)
+        self._reply_plain_frames = 0  # ... encoded from their values (rw.*_reply_frame)
         # ... of them a one-key command's, from its one partial
         self._reply_flat_frames = 0
+        # ... made from one partial, whatever the command's shape (a key
+        # alone on its shard)
+        self._reply_partial_frames = 0
         # admitted commands of one key on one shard: tracked by rifl alone
         self._flat_admitted = 0
         self._commands_completed = 0  # a command's last shard replied
@@ -2295,6 +2331,7 @@ class DeviceRuntime:
             "shard_replies": self._shard_replies,
             "reply_plain_frames": self._reply_plain_frames,
             "reply_flat_frames": self._reply_flat_frames,
+            "reply_partial_frames": self._reply_partial_frames,
             "commands_completed": self._commands_completed,
             "multi_shard_completed": self._multi_shard_completed,
             # reads: read-only commands completed, the bytes of the values

@@ -103,8 +103,7 @@ def frame(value: Any) -> bytes:
 def reply_frame(result: CommandResult) -> bytes:
     """``frame(ToClient(result))`` from the result's values, with no
     ``ToClient`` built on the way."""
-    payload = pickle.dumps(result.__reduce__()[1], _PROTOCOL)
-    return _HEAD.pack(len(payload) + 1, KIND_TO_CLIENT) + payload
+    return joined_reply_frame(result._rifl, result._key_count, result._results)
 
 
 def partial_reply_frame(partial) -> bytes:
@@ -115,6 +114,15 @@ def partial_reply_frame(partial) -> bytes:
     payload = pickle.dumps(
         (rifl[0], rifl[1], 1, {partial.key: partial.op_results}), _PROTOCOL
     )
+    return _HEAD.pack(len(payload) + 1, KIND_TO_CLIENT) + payload
+
+
+def joined_reply_frame(rifl, key_count: int, results: dict) -> bytes:
+    """:func:`reply_frame` of the ``CommandResult`` of ``key_count`` keys
+    that holds ``results`` (``key -> op_results`` in the order the keys'
+    partials landed), byte for byte, with no ``CommandResult`` built on
+    the way: a shard's reply where the command has several keys there."""
+    payload = pickle.dumps((rifl[0], rifl[1], key_count, results), _PROTOCOL)
     return _HEAD.pack(len(payload) + 1, KIND_TO_CLIENT) + payload
 
 

@@ -2462,7 +2462,7 @@ def test_a_rounds_replies_equal_the_one_at_a_time_reference(shard_count):
     # the stale rifl is answered once; only the unexecuted commands stay routed
     assert runtime.replied == answered - 2
     assert set(runtime.rifl_sessions) == {Rifl(11, 3), Rifl(12, 3)}
-    assert not sessions[0]._key_shard and not sessions[0]._shards_left
+    assert not sessions[0]._owed
 
 
 def test_a_round_is_one_write_per_session_and_the_snapshot_counts_it():
@@ -2486,6 +2486,7 @@ def test_a_round_is_one_write_per_session_and_the_snapshot_counts_it():
     assert t["replied"] == sum(len(_frames(data)) for w in writers for data in w.writes) == 9
     # eight one-key commands framed from their one partial, the two-key one aggregated
     assert (t["reply_flat_frames"], t["shard_replies"], t["reply_plain_frames"]) == (8, 9, 9)
+    assert t["reply_partial_frames"] == 8
 
 
 def test_a_dead_connection_costs_only_its_own_replies(caplog):
@@ -2558,11 +2559,8 @@ def _in_flight(runtime, sessions):
     """Everything the runtime and its sessions hold of commands in flight."""
     held = {"rifl_sessions": dict(runtime.rifl_sessions)}
     for i, session in enumerate(sessions):
-        held[i, "_key_shard"] = dict(session._key_shard)
-        held[i, "_shards_left"] = dict(session._shards_left)
+        held[i, "_owed"] = dict(session._owed)
         held[i, "_reads"] = set(session._reads)
-        for sid, pending in session.pending_by_shard.items():
-            held[i, "pending", sid] = dict(pending._pending)
     return {name: left for name, left in held.items() if left}
 
 
@@ -2580,8 +2578,7 @@ def test_a_one_key_reply_is_the_command_results_frame_byte_for_byte(op, values):
     runtime, (session,), (writer,) = _reply_stage(connections=1)
     cmd = Command.from_single(Rifl(2**40 + 7, 2**33), 0, "k\u00fc", op)
     session.track(cmd)
-    assert not session._key_shard and not session._shards_left
-    assert not session.pending_by_shard[0]._pending
+    assert not session._owed
     runtime._deliver([_partial(cmd, "k\u00fc", *values)])
     aggregated = CommandResult(cmd.rifl, 1)
     assert aggregated.add_partial("k\u00fc", values)
@@ -2592,6 +2589,73 @@ def test_a_one_key_reply_is_the_command_results_frame_byte_for_byte(op, values):
     assert (t["gets_replied"], t["commands_completed"], t["replied"]) == (int(read), 1, 1)
     carried = sum(len(v.encode()) for v in values if v is not None)
     assert t["get_value_bytes"] == (carried if read else 0)
+    assert not _in_flight(runtime, [session])
+
+
+def _aggregating_reference(process_id, shard_ids, cmds, results):
+    """The parent's reply stage, kept here as the reference: an
+    ``AggregatePending`` a shard, a key -> shard map a command, and the
+    frame of every ``CommandResult`` that completes, in the round's
+    order.  Nothing of it is the session's code."""
+    from fantoch_tpu.executor.aggregate import AggregatePending
+    from fantoch_tpu.run.prelude import ToClient
+    from fantoch_tpu.run.rw import frame, reply_frame
+
+    pending = {sid: AggregatePending(process_id, sid) for sid in shard_ids}
+    key_shard = {}
+    for cmd in cmds:
+        for sid in cmd.shards():
+            pending[sid].wait_for(cmd)
+        key_shard[cmd.rifl] = {key: sid for sid, key in cmd.all_keys()}
+    frames = []
+    for result in results:
+        done = pending[key_shard[result.rifl][result.key]].add_executor_result(result)
+        if done is not None:
+            frames.append(frame(ToClient(done)))
+            assert frames[-1] == reply_frame(done)
+    return frames
+
+
+_SHAPES = {
+    "one-key": {0: ("k\u00fc",)},
+    "two-keys-two-shards": {0: ("a",), 1: ("b\u00e9",)},
+    "two-keys-one-shard": {1: ("a", "b")},
+    "three-keys-two-and-one": {0: ("a", "b"), 1: ("c",)},
+}
+
+
+@pytest.mark.parametrize("op, value", [
+    (KVOp.put("v" * 100), "previous"),
+    (KVOp.get(), "v" * 1000),
+    (KVOp.get(), None),
+    (KVOp.put("gr\u00f6\u00dfer \u2603 \U0001f600"), "wert \u00e9\u4e2d"),
+], ids=["put", "get-hit", "get-miss", "non-ascii"])
+@pytest.mark.parametrize("shape", list(_SHAPES))
+def test_a_rounds_frames_are_the_aggregating_references_byte_for_byte(shape, op, value):
+    """Whatever a command's shape, what its session writes for a round is
+    what the parent's aggregation wrote: the same frames, one a touched
+    shard, in the order their last partials land."""
+    runtime, (session,), (writer,) = _reply_stage(shard_count=2, connections=1)
+    rifl = Rifl(2**40 + 7, 2**33)
+    cmd = Command(rifl, {sid: {key: (op,) for key in keys} for sid, keys in _SHAPES[shape].items()})
+    neighbour = Command.from_single(Rifl(2**40 + 7, 1), 1, "n", op)
+    for tracked in (cmd, neighbour):
+        session.track(tracked)
+    assert set(session._owed) == ({rifl} if cmd.total_key_count > 1 or cmd.shard_count > 1 else set())
+    # the partials land last key first, the neighbour's between them
+    results = [_partial(cmd, key, value) for _sid, key in reversed(list(cmd.all_keys()))]
+    results.insert(1, _partial(neighbour, "n", value))
+    runtime._deliver(results)
+    assert len(writer.writes) == 1
+    expected = _aggregating_reference(runtime.process_id, (0, 1), [cmd, neighbour], results)
+    assert writer.writes[0] == b"".join(expected)
+    assert len(expected) == cmd.shard_count + 1
+    runtime._publish_tallies()
+    t = runtime._tallies
+    alone = sum(len(keys) == 1 for keys in _SHAPES[shape].values())
+    assert (t["shard_replies"], t["reply_partial_frames"]) == (cmd.shard_count + 1, alone + 1)
+    assert t["reply_flat_frames"] == 1 + (shape == "one-key")
+    assert (t["commands_completed"], t["multi_shard_completed"]) == (2, int(cmd.shard_count > 1))
     assert not _in_flight(runtime, [session])
 
 
@@ -2610,6 +2674,38 @@ def _one_key_shapes(shard_count):
     return cmds
 
 
+def _several_key_shapes(shard_count, key_buckets=64):
+    """Commands of several keys: what the server takes as it stands, and
+    every way ``_validate`` has of refusing one."""
+    from fantoch_tpu.utils import key_hash
+
+    put, get = KVOp.put("v"), KVOp.get()
+    per_shard = key_buckets // shard_count
+
+    def bucket(key):
+        return key_hash(key) % per_shard
+
+    names = [f"c{i}" for i in range(200)]
+    apart = []  # three keys in three buckets, then one that shares the first's
+    for key in names:
+        if len(apart) < 3 and all(bucket(key) != bucket(other) for other in apart):
+            apart.append(key)
+    same = next(key for key in names if key != apart[0] and bucket(key) == bucket(apart[0]))
+    last = shard_count - 1
+    return [
+        Command(Rifl(2, 1), {0: {"a": (put,), "b": (put,)}}),  # two keys, one shard
+        Command(Rifl(2, 2), {0: {"a": (get,)}, last: {"b": (get,)}}),  # ... two shards (or one)
+        Command(Rifl(2, 3), {0: {"a": (put,)}, shard_count: {"b": (put,)}}),  # a shard out of range
+        Command(Rifl(2, 4), {-1: {"a": (get,), "b": (get,)}}),
+        Command(Rifl(2, 5), {0: {"a": (put,)}, 1: {"b": (put,)}}),  # two shards, whatever the server has
+        Command(Rifl(2, 6), {0: {}}),  # a shard with no keys
+        Command(Rifl(2, 7), {0: {}, last: {}}),
+        # three keys at width 2: three buckets, and two where two of them collide
+        Command(Rifl(2, 8), {0: {key: (get,) for key in apart}}),
+        Command(Rifl(2, 9), {0: {key: (put,) for key in (apart[0], apart[1], same)}}),
+    ]
+
+
 @pytest.mark.parametrize("shard_count", [1, 4])
 def test_admits_one_key_branch_rejects_what_validate_rejects_in_its_words(shard_count):
     from fantoch_tpu.run.prelude import Submit
@@ -2619,41 +2715,66 @@ def test_admits_one_key_branch_rejects_what_validate_rejects_in_its_words(shard_
     runtime.submit_all = lambda admitted, now_ms: pushed.extend(cmd for _dot, cmd, _at in admitted)
     reject = session._reject
     session._reject = lambda cmd, why: (rejected.__setitem__(cmd.rifl, why), reject(cmd, why))
-    cmds = _one_key_shapes(shard_count)
+    cmds = _one_key_shapes(shard_count) + _several_key_shapes(shard_count)
     reasons = {cmd.rifl: session._validate(cmd) for cmd in cmds}
     session._admit([Submit(cmd) for cmd in cmds])
     assert rejected == {rifl: why for rifl, why in reasons.items() if why is not None}
     assert [cmd.rifl for cmd in pushed] == [r for r, why in reasons.items() if why is None]
     assert rejected and pushed
+    # every way _validate has of refusing a command of several keys, in its words
+    said = {why.split(" but ")[0].split(" shard ")[0] for rifl, why in rejected.items() if rifl[0] == 2}
+    assert said == {"command touches no keys", "command touches 3 key buckets"} | (
+        {"multi-shard command submitted to a single-shard device server"} if shard_count == 1
+        else {"command names"})
+    assert {Rifl(2, 1), Rifl(2, 9)} <= {cmd.rifl for cmd in pushed} and Rifl(2, 8) in rejected
     # a rejection is answered with an empty result and leaves nothing behind
     assert [_fields(t) for t in _frames(b"".join(writer.writes))] == [
         (rifl, 0, {}, True) for rifl in rejected]
     assert set(runtime.rifl_sessions) == {cmd.rifl for cmd in pushed}
     assert session._reads == {cmd.rifl for cmd in pushed if cmd.read_only}
     runtime._publish_tallies()
-    flat = [cmd for cmd in pushed if cmd.shard_count == 1]
+    flat = [cmd for cmd in pushed if cmd.single_key() is not None]
     assert runtime._tallies["session_flat_admitted"] == len(flat) > 0
-    assert set(session._key_shard) == {cmd.rifl for cmd in pushed} - {cmd.rifl for cmd in flat}
+    assert set(session._owed) == {cmd.rifl for cmd in pushed} - {cmd.rifl for cmd in flat}
+    # a shed command leaves as little as a rejected one
+    held = _in_flight(runtime, [session])
+    runtime.room = lambda: 0
+    shed = [Command(Rifl(9, 1), {0: {"a": (KVOp.get(),), "b": (KVOp.get(),)}}),
+            Command.from_single(Rifl(9, 2), 0, "a", KVOp.get())]
+    session._admit([Submit(cmd) for cmd in shed])
+    assert runtime._submit_queue.sheds == 2 and len(pushed) == len(reasons) - len(rejected)
+    assert _in_flight(runtime, [session]) == held
 
 
-@pytest.mark.parametrize("keys", [1, 2], ids=["one-key", "two-key"])
-def test_the_same_rifl_twice_in_one_round_is_answered_once(keys):
-    runtime, (session,), (writer,) = _reply_stage(connections=1)
+@pytest.mark.parametrize("shards, order", [
+    ((("k0",),), "k0 k0"),
+    ((("k0", "k1"),), "k0 k1 k0 k1"),
+    ((("k0", "k1"),), "k0 k0 k1 k1"),
+    ((("k0",), ("k1",)), "k0 k1 k0 k1"),
+    ((("k0",), ("k1",)), "k0 k0 k1 k1"),
+], ids=["one-key", "two-key", "two-key-pairwise", "two-shard", "two-shard-pairwise"])
+def test_the_same_rifl_twice_in_one_round_is_answered_once(shards, order):
+    runtime, (session,), (writer,) = _reply_stage(shard_count=2, connections=1)
     put = KVOp.put("v")
-    cmd = Command(Rifl(5, 1), {0: {f"k{i}": (put,) for i in range(keys)}})
+    cmd = Command(Rifl(5, 1), {sid: {key: (put,) for key in keys} for sid, keys in enumerate(shards)})
     session.track(cmd)
     session.track(cmd)  # the client sent it again before its reply
-    once = [_partial(cmd, f"k{i}", "first") for i in range(keys)]
-    again = [_partial(cmd, f"k{i}", "second") for i in range(keys)]
-    runtime._deliver(once + again)
-    (reply,) = _frames(b"".join(writer.writes))
-    assert _fields(reply) == (cmd.rifl, keys, {f"k{i}": ("first",) for i in range(keys)}, True)
+    seen, results = set(), []
+    for key in order.split():
+        results.append(_partial(cmd, key, "second" if key in seen else "first"))
+        seen.add(key)
+    runtime._deliver(results)
+    # one reply a shard, of the first execution's values
+    assert [_fields(reply) for reply in _frames(b"".join(writer.writes))] == [
+        (cmd.rifl, len(keys), {key: ("first",) for key in keys}, True) for keys in shards]
     assert runtime.replied == 1
     runtime._publish_tallies()
-    assert runtime._tallies["reply_flat_frames"] == (1 if keys == 1 else 0)
-    assert runtime._tallies["commands_completed"] == 1
+    t = runtime._tallies
+    assert t["reply_flat_frames"] == (1 if order == "k0 k0" else 0)
+    assert t["reply_partial_frames"] == sum(len(keys) == 1 for keys in shards)
+    assert (t["commands_completed"], t["multi_shard_completed"]) == (1, len(shards) - 1)
     assert not _in_flight(runtime, [session])
-    runtime._deliver(again)  # ... and a round later: nowhere to go
+    runtime._deliver(results[-2:])  # ... and a round later: nowhere to go
     assert len(writer.writes) == 1
 
 
@@ -2685,7 +2806,7 @@ def test_one_key_and_two_key_replies_of_a_round_keep_its_execution_order(shard_c
     cmds, results = _one_session_round(shard_count)
     for cmd in cmds:
         session.track(cmd)
-    assert set(session._key_shard) == {Rifl(3, 2), Rifl(3, 4)}
+    assert set(session._owed) == {Rifl(3, 2), Rifl(3, 4)}
     runtime._deliver(results)
     assert len(writer.writes) == 1
     got = [_fields(t) for t in _frames(writer.writes[0])]
@@ -2720,7 +2841,7 @@ def test_nothing_is_left_of_a_command_after_its_reply_or_its_sessions_drop(end):
     sessions[1].track(other[0])
     held = _in_flight(runtime, sessions)
     assert {name[1] if isinstance(name, tuple) else name for name in held} == {
-        "rifl_sessions", "_key_shard", "_shards_left", "_reads", "pending"}
+        "rifl_sessions", "_owed", "_reads"}
     if end == "replied":
         runtime._deliver(results + [_partial(other[0], "z", None)])
         assert runtime.replied == 6
@@ -2734,12 +2855,17 @@ def test_nothing_is_left_of_a_command_after_its_reply_or_its_sessions_drop(end):
     assert not _in_flight(runtime, sessions)
 
 
-def test_the_flat_counters_count_one_key_commands_from_admit_to_reply():
+@pytest.mark.parametrize("shard_count", [1, 2])
+def test_the_flat_counters_count_one_key_commands_from_admit_to_reply(shard_count):
+    """Three one-key commands beside a two-key one on one shard and, where
+    the server has two, one over both: ``reply_flat_frames`` counts the
+    one-key commands alone, ``reply_partial_frames`` every frame made from
+    one partial, ``shard_replies`` all."""
     from fantoch_tpu.run.prelude import Submit
 
-    runtime, (session,), (writer,) = _reply_stage(shard_count=2, connections=1)
+    runtime, (session,), (writer,) = _reply_stage(shard_count=shard_count, connections=1)
     runtime.submit_all = lambda admitted, now_ms: None
-    cmds, results = _one_session_round(2)
+    cmds, results = _one_session_round(shard_count)
     session._admit([Submit(cmd) for cmd in cmds[:3]])
     session._admit([Submit(cmd) for cmd in cmds[3:]])
     runtime._publish_tallies()
@@ -2750,8 +2876,11 @@ def test_the_flat_counters_count_one_key_commands_from_admit_to_reply():
     runtime._publish_tallies()
     t = runtime._tallies
     assert (t["session_flat_admitted"], t["reply_flat_frames"]) == (3, 3)
-    assert t["shard_replies"] == t["reply_plain_frames"] == 6
+    # ... and both shards of the command over two, each from its one partial
+    assert t["reply_partial_frames"] == 3 + (2 if shard_count == 2 else 0)
+    assert t["shard_replies"] == t["reply_plain_frames"] == 4 + shard_count
     assert t["replied"] == t["commands_completed"] == 5
+    assert t["multi_shard_completed"] == shard_count - 1
     assert not _in_flight(runtime, [session])
 
 

@@ -420,7 +420,7 @@ def test_a_read_that_crosses_the_rings_bound_sheds_the_commands_past_it():
             assert [r.cmd_result.rifl for r in answered] == [m.cmd.rifl for m in msgs[:4]]
             ring = served.runtime._submit_queue
             assert (ring.sheds, ring.depth_hwm) == (3, 4)
-            assert not served.session._key_shard and not served.runtime.rifl_sessions
+            assert not served.session._owed and not served.runtime.rifl_sessions
             # the ring has drained: the retry of a shed command goes through
             await served.read(rw.frame(msgs[5]))
             assert [r.cmd_result.rifl for r in await served.replies(1)] == [msgs[5].cmd.rifl]
