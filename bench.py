@@ -1361,14 +1361,15 @@ def bench_device_serving(
         driver = driver_cls(n, batch_size=batch_size, key_buckets=8192)
         driver.pipeline_depth = depth if pipelined else 1
         driver.step(cmds[:batch_size])  # compile + warm
-        step = driver.step_pipelined if pipelined else driver.step
         # idle_frac must cover only the steady-state timed region, not
         # the compile round
         driver.reset_overlap_instrument()
         t0 = time.perf_counter()
         served = 0
         for start in range(batch_size, total, batch_size):
-            served += len(step(cmds[start : start + batch_size]))
+            served += len(
+                driver.serve([cmds[start : start + batch_size]], overlap=pipelined)
+            )
         if pipelined:
             served += len(driver.flush_pipeline())
         wall_ms = (time.perf_counter() - t0) * 1000.0
@@ -1464,12 +1465,12 @@ def bench_device_serving(
             print(f"# {name} serving bench failed: {exc!r}", file=sys.stderr)
             out[f"serving_{name}_error"] = repr(exc)[:200]
     if "newt" in families:
-        # chained Newt serving (NewtDeviceDriver.step_chained): S rounds
+        # chained Newt serving (NewtDeviceDriver.serve of a chain): S rounds
         # per device dispatch — the serving twin of the fused table
         # rounds, what drops serving_newt_round_ms on dispatch-dominated
         # rigs.  Needs >= 2 full chains past the warm round.  The
         # _pipelined variant composes S in-dispatch rounds x depth-K
-        # in-flight chains (step_chained_pipelined).
+        # in-flight chains (serve under overlap).
         try:
             out.update(_measure_newt_chained(cmds, total, batch, n))
         except Exception as exc:  # noqa: BLE001
@@ -1500,7 +1501,7 @@ def _measure_newt_chained(
 ):
     """Per-round cost of the S-rounds-per-dispatch Newt serving chain;
     ``depth > 0`` composes it with the depth-K pipeline
-    (step_chained_pipelined: S in-dispatch rounds x K in-flight chain
+    (serve under overlap: S in-dispatch rounds x K in-flight chain
     dispatches — chaining amortizes the dispatch round trip, pipelining
     overlaps the surviving transfer + emit with compute)."""
     from fantoch_tpu.run.device_runner import NewtDeviceDriver
@@ -1516,7 +1517,9 @@ def _measure_newt_chained(
     if n_groups < 2:
         return {}  # not enough rounds for a steady-state chained measure
     groups = [batches[i * chain : (i + 1) * chain] for i in range(n_groups)]
-    run = driver.step_chained_pipelined if depth else driver.step_chained
+    def run(group):
+        return driver.serve(group, overlap=bool(depth))
+
     run(groups[0])  # compile the chained program
     if depth:
         driver.flush_pipeline()
@@ -1559,7 +1562,7 @@ def bench_serving_batched(
       round-trip is paid per near-empty round;
     * **batched**: the size-or-deadline gate holds arrivals, and a
       backlog covering ``chain`` rounds goes out as ONE chained dispatch
-      (``step_chained_pipelined``) — rounds leave full and the dispatch
+      (``serve`` under overlap) — rounds leave full and the dispatch
       round-trip is amortized ``chain``x.
 
     Both arms replay the same arrival schedule (command i arrives at
@@ -1630,11 +1633,12 @@ def bench_serving_batched(
         driver.step(cmds[:batch])  # compile + warm the single step
         if batched:
             # compile the S=chain fused program outside the timed region
-            driver.step_chained_pipelined(
+            driver.serve(
                 [
                     cmds[batch + i * batch : batch + (i + 1) * batch]
                     for i in range(chain)
-                ]
+                ],
+                overlap=True,
             )
             driver.flush_pipeline()
         feed = cmds[warm_rows:] if batched else cmds[batch:]
@@ -1665,7 +1669,7 @@ def bench_serving_batched(
                 continue
             if batcher is None:
                 take = min(queued, batch)
-                served += len(driver.step_pipelined(feed[taken : taken + take]))
+                served += len(driver.serve([feed[taken : taken + take]], overlap=True))
                 taken += take
                 continue
             if noted < arrived:
@@ -1686,13 +1690,14 @@ def bench_serving_batched(
                 batcher.note_release(now_ms, take)
                 fused_dispatches += 1
                 served += len(
-                    driver.step_chained_pipelined(
-                        [rows[i * batch : (i + 1) * batch] for i in range(chain)]
+                    driver.serve(
+                        [rows[i * batch : (i + 1) * batch] for i in range(chain)],
+                        overlap=True,
                     )
                 )
             else:
                 take = min(queued, batch)
-                served += len(driver.step_pipelined(feed[taken : taken + take]))
+                served += len(driver.serve([feed[taken : taken + take]], overlap=True))
                 taken += take
                 batcher.note_release(now_ms, take)
         served += len(driver.flush_pipeline())

@@ -89,7 +89,9 @@ def add_config_flags(parser: argparse.ArgumentParser) -> None:
                         metavar="K",
                         help="device serving pipeline depth (run/pipeline.py): "
                         "dispatched-but-undrained rounds kept in flight; "
-                        "default 1")
+                        "default 1.  Setting it also turns the overlap "
+                        "on where the backend is the CPU (off the CPU it "
+                        "is on already)")
     parser.add_argument("--ingest-deadline", type=float, default=None,
                         metavar="MS", dest="ingest_deadline_ms",
                         help="adaptive ingest batching deadline budget "

@@ -61,13 +61,6 @@ def build_parser() -> argparse.ArgumentParser:
     parser.add_argument("--device-key-buckets", type=int, default=4096)
     parser.add_argument("--device-key-width", type=int, default=1,
                         help="max conflict-key buckets per command")
-    parser.add_argument(
-        "--device-pipeline", choices=["auto", "on", "off"], default="auto",
-        help="dispatch/drain overlap for saturated serving (auto = on off "
-        "the CPU, or whenever a pipeline depth was requested; "
-        "overlap needs a compute resource besides the host cores).  The "
-        "in-flight depth is the --serving-pipeline-depth config flag "
-        "(default 1)")
     parser.add_argument("--device-pending", type=int, default=256,
                         help="device pending-buffer capacity")
     parser.add_argument(
@@ -219,8 +212,6 @@ async def serve_device_step(args: argparse.Namespace) -> None:
         monitor_execution_order=config.executor_monitor_execution_order,
         metrics_file=args.metrics_file,
         metrics_interval_ms=args.metrics_interval,
-        pipeline=None if args.device_pipeline == "auto"
-        else args.device_pipeline == "on",
         mesh=mesh,
         telemetry_file=args.telemetry_file,
         metrics_port=args.metrics_port,

@@ -172,7 +172,7 @@ class Config:
     # the EWMA-adaptive target.  Set by --ingest-target; None = adaptive
     ingest_target: Optional[int] = None
     # ceiling on the auto-tuned serving chain length S (rounds fused per
-    # device dispatch, NewtDeviceDriver.step_chained_pipelined): the
+    # device dispatch, the batches of one PipelineCore.serve): the
     # tuner grows S while per-round dispatch overhead dominates device
     # time and never past this.  Set by --serving-chain-max; None = 8;
     # 1 disables chaining

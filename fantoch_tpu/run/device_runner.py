@@ -48,9 +48,10 @@ import asyncio
 import gc
 import os
 import sys
-from collections import defaultdict, deque
+from collections import defaultdict
+from functools import partial
 from time import monotonic, monotonic_ns, thread_time_ns
-from typing import Any, Deque, Dict, List, NamedTuple, Optional, Sequence, Tuple
+from typing import Any, Dict, List, Optional, Sequence, Tuple
 
 import numpy as np
 
@@ -237,13 +238,13 @@ class _DriverCore(PipelineCore):
         # what the round tallies over the rows it executed, summed, by
         # name (mesh_step.ROUND_TALLIES); empty where it tallies nothing
         self.round_tallies: Dict[str, int] = {}
-        # where a program made ready before serving takes its columns on
-        # the mesh, by the rounds it carries (``_precompile``); empty for
-        # a driver whose round is jitted by its first dispatch
-        self._column_shardings: Dict[int, object] = {}
+        # the programs made ready, by the rounds a dispatch of theirs
+        # carries: the executable and where it takes its columns on the
+        # mesh (``_program``)
+        self._programs: Dict[int, Tuple[Any, tuple]] = {}
         # the depth-K dispatch/drain pipeline + staging ingest ring +
-        # per-dispatch counters (step/step_pipelined/flush_pipeline and
-        # _staging come from PipelineCore; drivers implement the halves
+        # per-dispatch counters (serve/step/flush_pipeline and _staging
+        # come from PipelineCore; drivers implement the halves
         # _assemble / _enqueue of a dispatch and _execute of a drain)
         self._init_pipeline()
 
@@ -315,28 +316,34 @@ class _DriverCore(PipelineCore):
         self._assemble_rows(batch, key, src, seq)
         return key, src, seq
 
-    def _columns_to_device(self, columns, S: int = 1):
-        """The assembled columns of a dispatch of ``S`` rounds, handed
-        to jax: where the length's program was precompiled, straight to
-        where it takes its columns."""
-        import jax
+    # whether a chain of S rounds is one dispatch of a program of its own
+    # (Newt's ``lax.scan`` of S rounds) or S dispatches of the round's
+    fuses_chains = False
 
-        shardings = self._column_shardings.get(S)
-        if shardings is None:
-            import jax.numpy as jnp
+    def _jit_rounds(self, S: int):
+        """The jitted program of ``S`` rounds a dispatch: here the
+        round's, the only one."""
+        assert S == 1
+        return self._step
 
-            return tuple(jnp.asarray(column) for column in columns)
-        return jax.device_put(tuple(columns), shardings)
+    def _program(self, S: int = 1):
+        """The program of ``S`` rounds a dispatch and where it takes its
+        columns: compiled, or loaded, the first time that length is asked
+        for (``_precompile``) and kept.  A server asks for every length
+        it may dispatch before its first client (``precompile_chains``),
+        so its dispatches compile nothing; a driver stepped without that
+        start-up reaches the same executable at its first dispatch."""
+        ready = self._programs.get(S)
+        if ready is None:
+            ready = self._programs[S] = self._precompile(self._jit_rounds(S), S)
+        return ready
 
     def _precompile(self, jitted, S: int = 1):
-        """The program of ``S`` rounds a dispatch, ready before serving:
-        ``jitted`` lowered on the real state's and the driver's columns'
-        shapes (``_column_specs``) and compiled, or loaded, through the
+        """``jitted`` lowered on the real state's and the driver's
+        columns' shapes (``_column_specs``, under a leading ``S`` for a
+        program of several rounds) and compiled, or loaded, through the
         persistent compile cache (the jit's own cache is not touched),
-        under one ``precompile`` span.  Returns the executable for the
-        caller to keep and call; its dispatches then compile nothing, and
-        their columns go straight to where it takes them
-        (``_columns_to_device``)."""
+        under one ``precompile`` span."""
         import jax
 
         lead = () if S == 1 else (S,)
@@ -346,31 +353,54 @@ class _DriverCore(PipelineCore):
         )
         with self.stages.span("precompile", S):
             program = jitted.lower(self._state, *columns).compile()
-        self._column_shardings[S] = tuple(program.input_shardings[0][1:])
-        return program
+        return program, tuple(program.input_shardings[0][1:])
+
+    def precompile_chains(self, lengths: Sequence[int]) -> List[int]:
+        """Make the program behind every chain length in ``lengths``
+        ready before serving: its own where the driver fuses a chain into
+        one dispatch, the round's where a chain is S plain rounds (every
+        length is then ready once the round is).  One ``precompile`` span
+        a program.  Returns the lengths now ready, in order; it stops at
+        the first that cannot be made ready (the longer ones need more of
+        whatever it lacked), and the caller keeps its tuner off the rest.
+        A round that cannot be compiled raises here, at start-up: nothing
+        could be served without it."""
+        ready: List[int] = []
+        for length in lengths:
+            S = length if self.fuses_chains else 1
+            try:
+                self._program(S)
+            except Exception as exc:  # the compiler's own errors are many
+                if S == 1:
+                    raise
+                logger.warning(
+                    "chain length %d cannot be made ready (%r): serving "
+                    "with chains of at most %d", S, exc, max(ready, default=1),
+                )
+                break
+            ready.append(length)
+        return ready
 
     @property
     def precompiled_programs(self) -> int:
-        return len(self._column_shardings)
+        """Programs made ready (gauge)."""
+        return len(self._programs)
 
-    def _plain_round_ahead(self, lengths: Sequence[int]) -> List[int]:
-        """``precompile_chains`` of a driver whose chain is S plain
-        rounds (Caesar, Paxos): every length is ready once the round is,
-        so the round is compiled, or loaded, before serving
-        (``_precompile``), not traced and compiled by the first client's
-        first dispatch.  A round that cannot be compiled raises here, at
-        start-up: nothing could be served without it."""
-        if 1 not in self._column_shardings:
-            self._step = self._precompile(self._step)
-        return list(lengths)
+    def _columns_to_device(self, columns, shardings):
+        """The assembled columns of a dispatch, handed to jax: straight
+        to where its program takes them."""
+        import jax
 
-    def _enqueue(self, columns):
-        """Submit one device round over the assembled columns; returns
-        the round token for ``drain``."""
-        self._state, out = self._step(
-            self._state, *self._columns_to_device(columns)
+        return jax.device_put(tuple(columns), shardings)
+
+    def _enqueue(self, columns, S: int = 1):
+        """Submit one dispatch of ``S`` rounds over the assembled
+        columns; returns its outputs, un-fetched."""
+        program, shardings = self._program(S)
+        self._state, out = program(
+            self._state, *self._columns_to_device(columns, shardings)
         )
-        self.rounds += 1
+        self.rounds += S
         return out
 
     def _assemble_rows(self, batch, key_rows, src_row, seq_row) -> None:
@@ -602,16 +632,6 @@ class _DriverCore(PipelineCore):
         }
 
 
-class _ChainToken(NamedTuple):
-    """Round token for an S-rounds-in-one-dispatch chain
-    (``NewtDeviceDriver.step_chained``): the un-fetched device outputs
-    plus the chain length, so the pipeline can carry whole chains in
-    flight and the drain can slice per-round outputs after ONE fetch."""
-
-    outs: Any
-    rounds: int
-
-
 class DeviceDriver(_DriverCore):
     """Host control loop around the donated-state device protocol step.
 
@@ -691,8 +711,6 @@ class DeviceDriver(_DriverCore):
         which commands only read."""
         return super()._column_specs() + (("read", (self.batch_size,), np.bool_, False),)
 
-    precompile_chains = _DriverCore._plain_round_ahead
-
     # gid space is int32 and the key clock holds raw gids; when the space
     # nears exhaustion the epoch resets — rebase clock/frontier/pending
     # against the oldest in-flight gid instead of dying by assert
@@ -765,13 +783,13 @@ class DeviceDriver(_DriverCore):
             )
         )
 
-    # step/step_pipelined/flush_pipeline come from _DriverCore; one
-    # device round covers up to ``batch_size`` new commands (the rest of
-    # the fixed batch is padding; excess raises) and returns the per-key
-    # results of every command *executed* that round — including
-    # commands carried from previous degraded rounds.  Pipelined, the
-    # device round overlaps the host's result-emit loop (what the
-    # overlap buys on the chip: not measured).
+    # serve/step/flush_pipeline come from _DriverCore; one device round
+    # covers up to ``batch_size`` new commands (the rest of the fixed
+    # batch is padding; excess raises) and returns the per-key results of
+    # every command *executed* that round — including commands carried
+    # from previous degraded rounds.  Under overlap, the device round
+    # overlaps the host's result-emit loop (what the overlap buys on the
+    # chip: not measured).
 
     def _pipeline_flush_needed(self, batch) -> bool:
         # a gid epoch reset rebases the registry and frontier base,
@@ -920,15 +938,11 @@ class NewtDeviceDriver(_DriverCore):
             self._mesh, f=f, tiny_quorums=tiny_quorums,
             live_replicas=live_replicas, shard_count=shard_count,
         )
-        # chained multi-round programs (step_chained), one per chain
-        # length: a server makes every length its tuner may pick ready
-        # before it serves (``precompile_chains``); a driver used without
-        # that start-up jits a length when it first meets it
+        # what the program of a chain is built with (``_jit_rounds``)
         self._step_kwargs = dict(
             f=f, tiny_quorums=tiny_quorums,
             live_replicas=live_replicas, shard_count=shard_count,
         )
-        self._multi_step: Dict[int, object] = {}
         # no host identity mirror: the step outputs carry the working
         # rows' (src, seq) columns (NewtStepOutput.work_src/work_seq)
         self._pend_cap = pending_capacity
@@ -994,14 +1008,26 @@ class NewtDeviceDriver(_DriverCore):
             or super()._pipeline_flush_needed(batch)
         )
 
+    # a chain is one dispatch: length 1 is the round itself
+    # (``jit_newt_step``), a longer one the ``lax.scan`` of that many
+    # rounds (``jit_newt_multi_step``), a program a length
+    fuses_chains = True
+
+    def _jit_rounds(self, S: int):
+        from fantoch_tpu.parallel import mesh_step
+
+        if S == 1:
+            return self._step
+        return mesh_step.jit_newt_multi_step(self._mesh, **self._step_kwargs)
+
     def _chain_windows_blocked(
-        self, batches: List[List[Tuple[Dot, Command]]]
+        self, batches: Sequence[List[Tuple[Dot, Command]]]
     ) -> bool:
         """True when a window rebase (clock or dot-sequence) could land
-        mid-chain — inside one dispatch no rebase can happen, so such
-        chains must take the per-round path (which rebases in drain as
-        usual).  The clock margin counts every round still in flight
-        plus this chain's S."""
+        mid-chain — inside one dispatch no rebase can happen, so such a
+        chain is dispatched round by round (each rebases in its assembly
+        or its drain as usual).  The clock margin counts every round
+        still in flight plus this chain's S."""
         S = len(batches)
         work = self._pend_cap + self.batch_size
         top = max(
@@ -1013,17 +1039,32 @@ class NewtDeviceDriver(_DriverCore):
             or top >= self.SEQ_WINDOW_MAX
         )
 
-    def _dispatch_chain(self, batches: List[List[Tuple[Dot, Command]]]):
-        """Assemble + dispatch S rounds as ONE device program
-        (parallel/mesh_step.jit_newt_multi_step; a server has loaded the
-        program of every length it dispatches before its first client,
-        ``precompile_chains``); returns the chain token for ``drain``.
-        The caller checked ``_chain_windows_blocked`` first."""
+    def _dispatches(self, batches):
+        """S rounds in ONE device dispatch: the host assembles all S
+        rounds' key/src/seq columns up front, the replica state threads
+        round-to-round on device via ``lax.scan``, and the chain pays a
+        single dispatch round-trip — where the fixed per-dispatch cost
+        dominates a round, per-round cost drops toward kernel time (the
+        serving twin of the votes-table plane's ``fused_table_rounds``).
+        Under overlap up to ``pipeline_depth`` such chains stay in
+        flight.  A chain that a window rebase could land in goes round by
+        round instead, and nothing is in flight across the rebase
+        (``_pipeline_flush_needed``, asked for each of its rounds)."""
+        if len(batches) > 1 and not self._chain_windows_blocked(batches):
+            return [batches]
+        return super()._dispatches(batches)
+
+    def _dispatch_chain(self, chain):
+        """A chain of one is the round itself, from the staging ring; a
+        longer one is assembled whole and runs the program of its length
+        (``_dispatches`` saw that no rebase can land in it)."""
+        if len(chain) == 1:
+            return super()._dispatch_chain(chain)
         return self._dispatch_halves(
-            self._assemble_chain, self._enqueue_chain, batches
+            self._assemble_chain, partial(self._enqueue, S=len(chain)), chain
         )
 
-    def _assemble_chain(self, batches: List[List[Tuple[Dot, Command]]]):
+    def _assemble_chain(self, batches: Sequence[List[Tuple[Dot, Command]]]):
         from fantoch_tpu.parallel.mesh_step import KEY_PAD
 
         S = len(batches)
@@ -1039,113 +1080,15 @@ class NewtDeviceDriver(_DriverCore):
             self._assemble_rows(batch, keys[r], srcs[r], seqs[r])
         return keys, srcs, seqs
 
-    def precompile_chains(self, lengths: Sequence[int]) -> List[int]:
-        """Make the program of every chain length in ``lengths`` ready
-        before serving: length 1 is the round itself
-        (``jit_newt_step``), a longer one the ``lax.scan`` of that many
-        rounds, each lowered on the real state's and columns' shapes and
-        compiled, or loaded, through the persistent compile cache.  The
-        dispatch of a ready length then runs the loaded executable and
-        compiles nothing.  One ``precompile`` span a program.  Returns
-        the lengths now ready, in order; it stops at the first that
-        cannot be made ready (the longer ones need more of whatever it
-        lacked), and the caller keeps its tuner off the rest."""
-        from fantoch_tpu.parallel import mesh_step
-
-        ready: List[int] = []
-        for S in lengths:
-            if S not in self._column_shardings:
-                jitted = self._step if S == 1 else mesh_step.jit_newt_multi_step(
-                    self._mesh, **self._step_kwargs
-                )
-                try:
-                    program = self._precompile(jitted, S)
-                except Exception as exc:  # the compiler's own errors are many
-                    logger.warning(
-                        "chain length %d cannot be made ready (%r): serving "
-                        "with chains of at most %d", S, exc, max(ready, default=1),
-                    )
-                    break
-                if S == 1:
-                    self._step = program
-                else:
-                    self._multi_step[S] = program
-            ready.append(S)
-        return ready
-
-    def _enqueue_chain(self, columns):
-        from fantoch_tpu.parallel import mesh_step
-
-        S = len(columns[0])
-        multi = self._multi_step.get(S)
-        if multi is None:
-            multi = mesh_step.jit_newt_multi_step(
-                self._mesh, **self._step_kwargs
-            )
-            self._multi_step[S] = multi
-        self._state, outs = multi(
-            self._state, *self._columns_to_device(columns, S)
-        )
-        self.rounds += S
-        return _ChainToken(outs, S)
+    def _enqueue(self, columns, S: int = 1):
+        """The token says how many rounds it carries."""
+        return super()._enqueue(columns, S), S
 
     def _token_rounds(self, tok) -> int:
-        return tok.rounds if isinstance(tok, _ChainToken) else 1
-
-    def step_chained(
-        self, batches: List[List[Tuple[Dot, Command]]]
-    ) -> List[ExecutorResult]:
-        """S rounds in ONE device dispatch: the host assembles all S
-        rounds' key/src/seq columns up front, the replica state threads
-        round-to-round on device via ``lax.scan``, and the chain pays a
-        single dispatch round-trip — where the fixed per-dispatch cost
-        dominates a round, per-round cost drops toward kernel time (the
-        serving twin of the votes-table plane's
-        ``fused_table_rounds``)."""
-        results = self.flush_pipeline()
-        S = len(batches)
-        if S == 0:
-            return results
-        if self._chain_windows_blocked(batches):
-            for batch in batches:
-                results.extend(self.step(batch))
-            return results
-        tok = self._track_dispatch(
-            lambda: self._dispatch_chain(batches),
-            sum(len(b) for b in batches),
-            S * self.batch_size,
-            S,
-        )
-        results.extend(self._drain_tracked(tok))
-        return results
-
-    def step_chained_pipelined(
-        self, batches: List[List[Tuple[Dot, Command]]]
-    ) -> List[ExecutorResult]:
-        """The composed serving mode: S in-dispatch rounds per chain x
-        up to ``pipeline_depth`` chains in flight — chaining amortizes
-        the dispatch round trip, pipelining overlaps the surviving
-        transfer + host emit with device compute.  Results arrive up to
-        ``pipeline_depth`` chains late; ``flush_pipeline`` retires the
-        tail.  Chains that could cross a window rebase flush and fall
-        back to synchronous per-round steps."""
-        S = len(batches)
-        if S == 0:
-            return []
-        if self._chain_windows_blocked(batches):
-            results = self.flush_pipeline()
-            for batch in batches:
-                results.extend(self.step(batch))
-            return results
-        return self._pipeline_dispatch(
-            lambda: self._dispatch_chain(batches),
-            sum(len(b) for b in batches),
-            S * self.batch_size,
-            S,
-        )
+        return tok[1]
 
     def _token_outputs(self, tok):
-        return tok.outs if isinstance(tok, _ChainToken) else tok
+        return tok[0]
 
     def _execute(self, tok, outs) -> List[ExecutorResult]:
         """Execute one fetched token's stable commands in (clock, dot)
@@ -1153,16 +1096,17 @@ class NewtDeviceDriver(_DriverCore):
         device->host round trip either way)."""
         from fantoch_tpu.parallel.mesh_step import NewtStepOutput
 
-        if isinstance(tok, _ChainToken):
-            results: List[ExecutorResult] = []
-            for r in range(tok.rounds):
-                results.extend(
-                    self._drain_round(
-                        NewtStepOutput(*(np.asarray(a)[r] for a in outs))
-                    )
+        S = tok[1]
+        if S == 1:
+            return self._drain_round(outs)
+        results: List[ExecutorResult] = []
+        for r in range(S):
+            results.extend(
+                self._drain_round(
+                    NewtStepOutput(*(np.asarray(a)[r] for a in outs))
                 )
-            return results
-        return self._drain_round(outs)
+            )
+        return results
 
     def _drain_round(self, out) -> List[ExecutorResult]:
         """One (already fetched) round's drain: advance watermark /
@@ -1264,8 +1208,6 @@ class CaesarDeviceDriver(_DriverCore):
         )
         self._pend_cap = pending_capacity
 
-    precompile_chains = _DriverCore._plain_round_ahead
-
     def _execute(self, _tok, out) -> List[ExecutorResult]:
         """Execute one fetched round's wait-cleared commands in
         (clock, dot) order."""
@@ -1337,8 +1279,6 @@ class PaxosDeviceDriver(_DriverCore):
         self._slot_base = 0  # slots below base + exec_frontier executed
         self._next_slot = 0  # host mirror of state.next_slot
         self.slot_epochs = 0  # slot-space rebases (device_slot_epochs)
-
-    precompile_chains = _DriverCore._plain_round_ahead
 
     def _column_specs(self):
         """The leader round takes no key rows: which rows of the batch
@@ -1892,7 +1832,6 @@ class DeviceRuntime:
         monitor_execution_order: bool = False,
         metrics_file: Optional[str] = None,
         metrics_interval_ms: int = 5000,
-        pipeline: Optional[bool] = None,
         mesh=None,
         telemetry_file: Optional[str] = None,
         metrics_port: Optional[int] = None,
@@ -1970,20 +1909,16 @@ class DeviceRuntime:
         # (run/pipeline.py)
         self.pipeline_depth = resolve_pipeline_depth(config)
         self.driver.pipeline_depth = self.pipeline_depth
-        if pipeline is None:
-            # dispatch/drain overlap needs a compute resource besides the
-            # host cores: on a CPU backend "device" rounds and the emit
-            # loop share the same cores, so auto-enable only off-CPU —
-            # unless a pipeline depth was explicitly configured, which IS
-            # the opt-in (depth > 1 is meaningless with pipelining off)
-            device0 = np.asarray(self.driver._mesh.devices).flat[0]
-            pipeline = (
-                getattr(device0, "platform", "cpu") != "cpu"
-                or config.serving_pipeline_depth is not None
-            )
-        # every driver implements the dispatch/drain split, so the
-        # scaffold's step_pipelined is always available
-        self.pipeline = bool(pipeline)
+        # dispatch/drain overlap needs a compute resource besides the
+        # host cores: on a CPU backend "device" rounds and the emit loop
+        # share the same cores, so it is on off the CPU only — unless a
+        # pipeline depth was configured, which IS the opt-in (depth > 1
+        # is meaningless without overlap)
+        device0 = np.asarray(self.driver._mesh.devices).flat[0]
+        self.pipeline = (
+            getattr(device0, "platform", "cpu") != "cpu"
+            or config.serving_pipeline_depth is not None
+        )
         # adaptive ingest batching (run/ingest.py): accumulate queued
         # submissions until the EWMA size target or the deadline budget
         # fills, so rounds dispatch full under load; the idle-system
@@ -1998,10 +1933,10 @@ class DeviceRuntime:
             max_target=self.driver.batch_size * chain_max,
             fixed_target=config.ingest_target,
         )
-        # chained-by-default serving: every dispatch may fuse up to S
-        # rounds (PipelineCore.step_chained_pipelined; Newt runs them as
-        # ONE device program), with S auto-tuned from the measured
-        # per-round dispatch overhead vs in-dispatch time
+        # chained-by-default serving: every call of PipelineCore.serve
+        # may carry up to S rounds (Newt runs them as ONE device
+        # program), with S auto-tuned from the measured per-round
+        # dispatch overhead vs in-dispatch time
         self._chain_tuner = ChainAutoTuner(chain_max)
         self.dot_gen = AtomicIdGen(process_id)
         self.metrics_file = metrics_file
@@ -2174,44 +2109,6 @@ class DeviceRuntime:
         for server in self._servers:
             server.close()
 
-    def _arm_device_faults(self) -> None:
-        """Arm the accelerator fault plane on any device planes the
-        serving driver exposes (the ``device_planes`` seam shared with
-        the executor pools): config knobs, ``FANTOCH_DEVICE_FAULT`` env
-        rehearsal faults, and a flight-ring dump per failover.  The
-        fused serving drivers expose no planes today, so this costs one
-        empty-tuple check — the seam exists so a driver that grows a
-        resident plane is covered without touching the runtime."""
-        planes = tuple(
-            getattr(self.driver, "device_planes", lambda: ())()
-        )
-        if not planes:
-            return
-        from fantoch_tpu.sim.device_faults import install_env_faults
-
-        pid = self.process_id
-        for plane in planes:
-            plane.configure_faults(self.config, process_id=pid)
-        install_env_faults(planes, process_id=pid)
-
-        def on_failure(plane, exc):
-            logger.warning(
-                "p%s: %s plane failed over (%r); serving from host twin",
-                pid, plane.plane_name, exc,
-            )
-            if self.flight is not None:
-                try:
-                    self.flight.dump(
-                        f"{self.flight_dir}/flight_p{pid}_{plane.plane_name}.json",
-                        f"device-failover: {plane.plane_name}: "
-                        f"{type(exc).__name__}",
-                    )
-                except OSError as dump_exc:
-                    logger.error("flight dump failed: %r", dump_exc)
-
-        for plane in planes:
-            plane.attach_failure_listener(on_failure)
-
     async def start(self) -> None:
         from fantoch_tpu.core.compile_cache import ensure_compile_cache
         from fantoch_tpu.observability.device import subscribe_recompiles
@@ -2226,7 +2123,6 @@ class DeviceRuntime:
         self._chain_tuner.limit_to(
             self.driver.precompile_chains(self._chain_tuner.ladder())
         )
-        self._arm_device_faults()
         server = await asyncio.start_server(self._on_client, *self.client_addr)
         self._servers = [server]
         self.spawn(self._driver_task())
@@ -2729,8 +2625,8 @@ class DeviceRuntime:
     async def _driver_task(self) -> None:
         driver = self.driver
         stages = self.stages
-        # dispatch/drain pipelining (DeviceDriver only): under saturation
-        # round k+1's device dispatch overlaps round k's host emit loop
+        # dispatch/drain overlap: under saturation round k+1's device
+        # dispatch overlaps round k's host emit loop
         can_pipeline = self.pipeline
         batcher = self._batcher
         tuner = self._chain_tuner
@@ -2811,21 +2707,11 @@ class DeviceRuntime:
                 pipeline = can_pipeline and (
                     driver.has_outstanding or len(self._submit_queue) > 0
                 )
-                # chains route through the shared chained surface (one
-                # fused device program on Newt, S plain rounds elsewhere)
-                if len(batches) > 1:
-                    results = await self._serve_round(
-                        whole,
-                        driver.step_chained_pipelined
-                        if pipeline else driver.step_chained,
-                        batches,
-                    )
-                else:
-                    results = await self._serve_round(
-                        whole,
-                        driver.step_pipelined if pipeline else driver.step,
-                        batches[0],
-                    )
+                # (a chain is one fused device program on Newt, S plain
+                # rounds elsewhere: the driver's own business)
+                results = await self._serve_round(
+                    whole, driver.serve, batches, pipeline
+                )
             self._trace_round(whole)
             # commands stuck in the device pending buffer (degraded quorum)
             # with no new submissions would otherwise hot-spin device

@@ -379,7 +379,6 @@ async def run_device_server(
     arrival_seed: Optional[int] = None,
     deadline_ms: Optional[int] = None,
     monitor_execution_order: bool = True,
-    pipeline: Optional[bool] = None,
     telemetry_file: Optional[str] = None,
     metrics_port: Optional[int] = None,
     trace_file: Optional[str] = None,
@@ -401,7 +400,6 @@ async def run_device_server(
         key_width=key_width,
         pending_capacity=pending_capacity,
         monitor_execution_order=monitor_execution_order,
-        pipeline=pipeline,
         telemetry_file=telemetry_file,
         metrics_port=metrics_port,
         trace_file=trace_file,

@@ -19,7 +19,7 @@ the sim's open-loop arrivals, and ``OrderingPool`` shard rounds):
   batching.  An **idle-system fast path** releases a lone closed-loop
   command immediately — sync latency never regresses.
 * :class:`ChainAutoTuner` — pick S, the serving rounds fused per device
-  dispatch (``step_chained_pipelined``), from the measured per-round
+  dispatch (the batches of one ``PipelineCore.serve``), from the measured per-round
   host dispatch overhead vs in-dispatch device time (the PR 6 busy/span
   counters): grow S while the dispatch round-trip still dominates a
   round, shrink once it is amortized, clamp at
@@ -224,7 +224,7 @@ class AdaptiveIngestBatcher:
 
 
 class ChainAutoTuner:
-    """Auto-tuned S for chained serving (``step_chained_pipelined``).
+    """Auto-tuned S for chained serving (the batches of one ``serve``).
 
     Starts at S=1 and adjusts from deltas of the shared PipelineCore
     counters: per-round host dispatch overhead

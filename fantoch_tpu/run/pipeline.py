@@ -15,22 +15,23 @@ refactor seam): drivers implement a ``dispatch(batch) -> token`` /
 ``drain(token) -> results`` split and inherit
 
   * :class:`PipelineCore` — a depth-K in-flight ring of round tokens
-    (``step`` / ``step_pipelined`` / ``flush_pipeline``), the stage
+    behind one entry (``serve``, its one-round form ``step``, and
+    ``flush_pipeline`` for the tail), the stage
     recorder whose spans split a dispatch into ``assemble`` / ``enqueue``
     and a drain into ``fetch`` / ``execute``
     (observability/device.py ``StageRecorder``), and the device
     busy/idle instrument (``device_idle_frac``);
   * :class:`IngestRing` — K+1 pre-staged host staging buffer sets for
     batch assembly, cycled round-robin so the columns a still-in-flight
-    round reads (``jnp.asarray`` zero-copy aliases host numpy on the CPU
-    backend) are never rewritten under it.
+    round reads (jax may alias host numpy zero-copy on the CPU backend)
+    are never rewritten under it.
 
 Depth semantics: ``pipeline_depth`` is the maximum number of
-dispatched-but-undrained rounds ``step_pipelined`` leaves in flight, i.e.
-the delivery lag in rounds.  Depth 1 is the classic double-buffered
-overlap; deeper pipelines amortize jittery transfer latency at the cost
-of K rounds of result lag.  ``step`` (synchronous) always flushes first,
-so mixing the two is safe.
+dispatched-but-undrained rounds ``serve(..., overlap=True)`` leaves in
+flight, i.e. the delivery lag in rounds.  Depth 1 is the classic
+double-buffered overlap; deeper pipelines amortize jittery transfer
+latency at the cost of K rounds of result lag.  ``serve`` without overlap
+(and so ``step``) always flushes first, so mixing the two is safe.
 
 Donation discipline (the PR 4 XLA-ownership rule): the pipeline never
 donates host staging buffers — only the drivers' device-resident *state*
@@ -247,8 +248,8 @@ class PipelineCore:
     def _staging(self, *specs) -> Tuple[np.ndarray, ...]:
         """The next pre-staged host staging slot for batch assembly:
         ``pipeline_depth + 1`` ring slots, so the columns a
-        still-in-flight round zero-copy aliases (``jnp.asarray`` on the
-        CPU backend) are never rewritten before that round drains."""
+        still-in-flight round may alias zero-copy (the CPU backend) are
+        never rewritten before that round drains."""
         slots = self.pipeline_depth + 1
         if self._ring is None or self._ring.slots < slots:
             self._ring = IngestRing(slots, specs)
@@ -271,66 +272,69 @@ class PipelineCore:
         """At least one dispatched-but-undrained pipelined round exists."""
         return bool(self._inflight)
 
+    def serve(self, batches, overlap: bool = False) -> List[Any]:
+        """Dispatch ``batches``, a round each, and return what was
+        retired meanwhile, oldest round first.
+
+        ``overlap=False``: everything in flight is retired first and each
+        dispatch is drained at once, so the call returns its own rounds'
+        results.  ``overlap=True``: dispatches are drained down to
+        ``pipeline_depth`` only — results arrive up to that many
+        dispatches late, in exchange for the device computing while the
+        host assembles the next round and emits the last one's results —
+        and ``flush_pipeline`` retires the tail.  Either way a dispatch
+        that would rebase what a round in flight still refers to
+        (``_pipeline_flush_needed``: once a sequence, clock, gid or slot
+        window) retires every round in flight first."""
+        results = [] if overlap else self.flush_pipeline()
+        depth = self.pipeline_depth if overlap else 0
+        for chain in self._dispatches(batches):
+            rounds = len(chain)
+            if self._inflight:
+                # (a dispatch of several rounds is made only where no
+                # rebase can land in it: ``_dispatches``)
+                if rounds == 1 and self._pipeline_flush_needed(chain[0]):
+                    results.extend(self.flush_pipeline())
+                else:
+                    self.pipelined_rounds += rounds
+            # a round is named by its dispatch's number (1-based)
+            round_id = self._span_round = self.dispatches + 1
+            if self._span_t0 is None:
+                self._span_t0 = self.stages.clock()
+            tok = self._dispatch_chain(chain)
+            t1 = self.stages.clock()
+            self.dispatches += 1
+            self.dispatched_rows += sum(map(len, chain))
+            self.dispatched_capacity += rounds * self.batch_size
+            self.chain_len = rounds
+            self._undrained += 1
+            self._undrained_rounds += rounds
+            if self._busy_t0 is None:
+                # the device has work from the moment the dispatch call
+                # returns (the submit is async); host assembly before it
+                # counts as idle, which is the point of the instrument
+                self._busy_t0 = t1
+            self._inflight.append((round_id, tok))
+            while len(self._inflight) > depth:
+                results.extend(self._drain_tracked(self._inflight.popleft()))
+        return results
+
     def step(self, batch) -> List[Any]:
-        """One synchronous round: flush any pipelined rounds, dispatch,
-        drain."""
-        results = self.flush_pipeline()
-        tok = self._dispatch_tracked(batch)
-        results.extend(self._drain_tracked(tok))
-        return results
+        """One synchronous round."""
+        return self.serve([batch])
 
-    def step_chained(self, batches) -> List[Any]:
-        """S rounds per call, synchronous.  The base implementation runs
-        them as S plain steps (exact same results, no fusion); drivers
-        with a fused multi-round program (NewtDeviceDriver) override to
-        pay ONE dispatch round-trip for the whole chain — the serving
-        loop routes through this surface unconditionally so chaining is
-        a driver capability, not a call-site branch."""
-        results = self.flush_pipeline()
-        for batch in batches:
-            results.extend(self.step(batch))
-        return results
+    def _dispatches(self, batches) -> List[Sequence[Any]]:
+        """How ``batches`` become dispatches, the one thing a driver
+        says: the rounds of each dispatch, in order.  Here one dispatch a
+        batch; a driver with a program of several rounds
+        (NewtDeviceDriver) makes one of the whole chain."""
+        return [(batch,) for batch in batches]
 
-    def precompile_chains(self, lengths) -> List[int]:
-        """The chain lengths of ``lengths`` whose programs are ready
-        before serving.  Here a chain is S plain rounds and has no
-        program of its own, so every length is; a driver with a fused
-        program per length (NewtDeviceDriver) compiles or loads them, and
-        one that makes its plain round ready ahead (CaesarDeviceDriver,
-        PaxosDeviceDriver) does that here."""
-        return list(lengths)
-
-    @property
-    def precompiled_programs(self) -> int:
-        """Chain programs loaded ahead of serving (gauge)."""
-        return 0
-
-    def step_chained_pipelined(self, batches) -> List[Any]:
-        """S rounds per call composed with the depth-K pipeline.  Base
-        implementation: S consecutive ``step_pipelined`` rounds (the
-        chain is a grouping hint, not a semantic change); fused drivers
-        override to dispatch the chain as one token."""
-        results: List[Any] = []
-        for batch in batches:
-            results.extend(self.step_pipelined(batch))
-        return results
-
-    def step_pipelined(self, batch) -> List[Any]:
-        """Dispatch ``batch`` and drain only rounds beyond the configured
-        ``pipeline_depth`` — results arrive up to ``pipeline_depth`` calls
-        late in exchange for overlapping device compute with host batch
-        assembly and the result-emit loop.  Call ``flush_pipeline`` to
-        retire the tail."""
-        if self._inflight and self._pipeline_flush_needed(batch):
-            # an epoch/window rebase would invalidate an in-flight
-            # round's identity or clock accounting — retire them all
-            # first (rare: once per int32 window)
-            early = self.flush_pipeline()
-            self._inflight.append(self._dispatch_tracked(batch))
-            return early
-        return self._pipeline_dispatch(
-            lambda: self.dispatch(batch), len(batch), self.batch_size, 1
-        )
+    def _dispatch_chain(self, chain):
+        """One dispatch of the rounds ``_dispatches`` put together: here
+        always the one."""
+        (batch,) = chain
+        return self.dispatch(batch)
 
     # --- the halves of a round, under their stage spans ---
 
@@ -360,17 +364,6 @@ class PipelineCore:
         more than the step's outputs override)."""
         return tok
 
-    def _pipeline_dispatch(
-        self, fn, rows: int, capacity: int, rounds: int
-    ) -> List[Any]:
-        """The shared pipelined-dispatch tail: tally overlap, push the
-        new round token, drain down to depth.  Chained drivers reuse it
-        with their chain thunks (the caller handled any flush trigger)."""
-        if self._inflight:
-            self.pipelined_rounds += rounds
-        self._inflight.append(self._track_dispatch(fn, rows, capacity, rounds))
-        return self._drain_to_depth()
-
     def flush_pipeline(self) -> List[Any]:
         """Drain every outstanding pipelined round, oldest first."""
         results: List[Any] = []
@@ -378,39 +371,7 @@ class PipelineCore:
             results.extend(self._drain_tracked(self._inflight.popleft()))
         return results
 
-    def _drain_to_depth(self) -> List[Any]:
-        results: List[Any] = []
-        while len(self._inflight) > self.pipeline_depth:
-            results.extend(self._drain_tracked(self._inflight.popleft()))
-        return results
-
-    # --- tracked dispatch/drain plumbing ---
-
-    def _dispatch_tracked(self, batch):
-        return self._track_dispatch(
-            lambda: self.dispatch(batch), len(batch), self.batch_size, 1
-        )
-
-    def _track_dispatch(self, fn, rows: int, capacity: int, rounds: int):
-        """Run one dispatch thunk; returns ``(round id, token)``, the
-        round id being the dispatch's number (1-based)."""
-        round_id = self._span_round = self.dispatches + 1
-        if self._span_t0 is None:
-            self._span_t0 = self.stages.clock()
-        tok = fn()
-        t1 = self.stages.clock()
-        self.dispatches += 1
-        self.dispatched_rows += rows
-        self.dispatched_capacity += capacity
-        self.chain_len = max(1, rounds)
-        self._undrained += 1
-        self._undrained_rounds += rounds
-        if self._busy_t0 is None:
-            # the device has work from the moment the dispatch call
-            # returns (the submit is async); host assembly before it
-            # counts as idle, which is the point of the instrument
-            self._busy_t0 = t1
-        return round_id, tok
+    # --- tracked drain plumbing ---
 
     def _drain_tracked(self, tracked):
         round_id, tok = tracked
@@ -421,8 +382,8 @@ class PipelineCore:
         return self.drain(tok)
 
     def _token_rounds(self, tok) -> int:
-        """Protocol rounds one dispatch token carries (chained drivers
-        override for their chain tokens)."""
+        """Protocol rounds one dispatch's token carries: a driver whose
+        dispatch may carry several has its token say."""
         return 1
 
     def _fetch(self, out):

@@ -84,8 +84,8 @@ class Pair:
         self.rounds = []  # the reference's, for the cases' own questions
 
     def set_live(self, live):
-        self.driver._step = mesh_step.jit_caesar_step(self.driver._mesh, num_replicas=N,
-                                                      live_replicas=live)
+        self.driver._programs[1] = self.driver._precompile(
+            mesh_step.jit_caesar_step(self.driver._mesh, num_replicas=N, live_replicas=live))
         self.reference.live = N if live is None else live
 
     def round(self, fresh):

@@ -81,9 +81,9 @@ def _batch(driver, first, count):
 
 @pytest.mark.parametrize("shards,width", [(1, 1), (4, 2)], ids=["one_shard", "four_shards_two_keys"])
 def test_after_the_precompile_no_dispatch_of_any_ladder_length_compiles(shards, width):
-    """`_enqueue` and `_enqueue_chain` run loaded executables: the process's
-    compile, compile-time and cache tallies stand still over dispatches of
-    every length, synchronous and pipelined."""
+    """`_enqueue` runs loaded executables: the process's compile,
+    compile-time and cache tallies stand still over dispatches of every
+    length, with and without overlap."""
     obs.subscribe_recompiles()
     driver = NewtDeviceDriver(5, f=1, batch_size=8, key_buckets=64, key_width=width,
                               pending_capacity=8, shard_count=shards)
@@ -97,13 +97,9 @@ def test_after_the_precompile_no_dispatch_of_any_ladder_length_compiles(shards, 
     for length in LADDER + LADDER[::-1]:
         chain = [_batch(driver, at + r * 8, 8) for r in range(length)]
         at += length * 8
-        if length == 1:
-            executed += len(driver.step(chain[0]))
-        else:
-            executed += len(driver.step_chained(chain))
-        executed += len(driver.step_chained_pipelined(
-            [_batch(driver, at + r * 8, 5) for r in range(length)]) if length > 1
-            else driver.step_pipelined(_batch(driver, at, 5)))
+        executed += len(driver.serve(chain))
+        executed += len(driver.serve(
+            [_batch(driver, at + r * 8, 5) for r in range(length)], overlap=True))
         at += length * 8
     executed += len(driver.flush_pipeline())
     assert _tallies() == before

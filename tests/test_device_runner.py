@@ -479,7 +479,8 @@ def test_device_runtime_sharded_pipelined_tcp_cluster(protocol):
     drivers (dep-commit and Newt timestamp) must serve saturated
     multi-shard traffic with cross-shard dependencies intact — the
     missing cells of the (sharded x pipelined) matrix."""
-    config = Config(3, 1, shard_count=2)
+    # (a depth set is the opt-in to overlap on the CPU test backend)
+    config = Config(3, 1, shard_count=2, serving_pipeline_depth=1)
     workload = Workload(
         shard_count=2,
         key_gen=ConflictRateKeyGen(50),
@@ -493,7 +494,6 @@ def test_device_runtime_sharded_pipelined_tcp_cluster(protocol):
             key_width=2, key_buckets=64,
             open_loop_interval_ms=1,
             protocol=protocol,
-            pipeline=True,  # auto would disable it on the CPU test backend
         )
     )
     for client in clients.values():
@@ -612,16 +612,18 @@ def test_sharded_newt_driver_randomized_soak():
                   if key_hash(f"a{i}{j}") % 2 == 0) for j in range(3)]
     keys1 = [next(f"b{i}{j}" for i in range(100)
                   if key_hash(f"b{i}{j}") % 2 == 1) for j in range(3)]
-    degraded_step = mesh_step.jit_newt_step(
+    # (what a dispatch of one round runs is ``_programs[1]``: the round
+    # compiled on the driver's state and columns)
+    degraded_step = d._precompile(mesh_step.jit_newt_step(
         d._mesh, f=1, shard_count=2, live_replicas=4
-    )
-    healthy_step = d._step
+    ))
+    healthy_step = d._program()
 
     seq = 0
     issued = 0
     multis = 0
     for round_no in range(12):
-        d._step = degraded_step if round_no in (4, 5, 6) else healthy_step
+        d._programs[1] = degraded_step if round_no in (4, 5, 6) else healthy_step
         batch = list(d.take_requeue())
         for _ in range(rng.randrange(1, 9)):
             seq += 1
@@ -647,7 +649,7 @@ def test_sharded_newt_driver_randomized_soak():
             d._requeue.append(extra)
 
     # drain: healthy empty rounds until everything in flight executes
-    d._step = healthy_step
+    d._programs[1] = healthy_step
     for _ in range(8):
         if d.in_flight == 0 and not d._requeue:
             break
@@ -691,7 +693,7 @@ def test_caesar_driver_degraded_requeue_recovery():
         4, batch_size=8, key_buckets=64, pending_capacity=4,
         monitor_execution_order=True,
     )
-    healthy = d._step
+    healthy = d._program()
     values = {i + 1: f"v{i + 1}" for i in range(12)}
     results = {}
 
@@ -713,14 +715,15 @@ def test_caesar_driver_degraded_requeue_recovery():
     d._state = d._state._replace(
         key_clock=jax.device_put(jnp.asarray(kc), d._state.key_clock.sharding)
     )
-    d._step = mesh_step.jit_caesar_step(d._mesh, num_replicas=4, live_replicas=1)
+    d._programs[1] = d._precompile(
+        mesh_step.jit_caesar_step(d._mesh, num_replicas=4, live_replicas=1))
     absorb(d.step([_put(1, s, "hot", values[s]) for s in range(5, 13)]))
     assert sorted(results) == [1, 2, 3, 4], "divergent views must not commit"
     requeued = d.take_requeue()
     assert len(requeued) == 4, "pending capacity 4 of 8 uncommitted"
     assert d.in_flight == 4  # the device-carried half stays registered
 
-    d._step = healthy
+    d._programs[1] = healthy
     absorb(d.step(requeued))
     for _ in range(4):
         if d.in_flight == 0 and not d._requeue:
@@ -911,7 +914,8 @@ def test_paxos_driver_degraded_requeue_recovery():
 
     # recovery: all replicas answer again (the runtime would re-jit the
     # step the same way on failure-detector feedback)
-    d._step = mesh_step.jit_paxos_step(d._mesh, f=1, num_replicas=3)
+    d._programs[1] = d._precompile(
+        mesh_step.jit_paxos_step(d._mesh, f=1, num_replicas=3))
     results = d.step(requeued)
     assert d.executed == 8 and d.in_flight == 0
     # carried slots (0-3) execute before the reassigned ones; per-key
@@ -1016,6 +1020,7 @@ def test_newt_runtime_requeue_after_degraded_round():
             driver._step = mesh_step.jit_newt_step(
                 driver._mesh, f=config.f, tiny_quorums=False
             )
+            driver._programs.clear()  # the next dispatch compiles the new round
             clients = await client_task
             for client in clients.values():
                 assert client.issued_commands == 8
@@ -1133,7 +1138,7 @@ def test_device_runtime_newt_multi_key_tcp():
 
 
 def test_driver_pipelined_equivalence():
-    """step_pipelined returns each round's results one call late and, with
+    """serve under overlap returns each round's results one call late and, with
     a final flush, produces exactly the sync driver's execution: same
     per-round result values, same per-key monitor order, same tallies
     (the overlap must be pure scheduling, never reordering)."""
@@ -1150,7 +1155,7 @@ def test_driver_pipelined_equivalence():
 
     d_sync, d_pipe = _driver(), _driver()
     sync_rounds = [d_sync.step(b) for b in batches()]
-    pipe_rounds = [d_pipe.step_pipelined(b) for b in batches()]
+    pipe_rounds = [d_pipe.serve([b], overlap=True) for b in batches()]
     assert pipe_rounds[0] == []  # one round of delivery lag
     pipe_rounds.append(d_pipe.flush_pipeline())
     assert not d_pipe.has_outstanding
@@ -1201,7 +1206,7 @@ def test_dot_driver_pipelined_equivalence(protocol):
 
     d_sync, d_pipe = mk(), mk()
     sync_rounds = [d_sync.step(b) for b in batches()]
-    pipe_rounds = [d_pipe.step_pipelined(b) for b in batches()]
+    pipe_rounds = [d_pipe.serve([b], overlap=True) for b in batches()]
     assert pipe_rounds[0] == []  # one round of delivery lag
     pipe_rounds.append(d_pipe.flush_pipeline())
     assert not d_pipe.has_outstanding
@@ -1224,7 +1229,7 @@ def test_dot_driver_pipelined_equivalence(protocol):
 def test_newt_pipelined_clock_threshold_flushes_outstanding():
     """A Newt clock-window advance must never run with a round in
     flight: when the max committed clock nears the reset threshold,
-    step_pipelined retires the outstanding round first (and the drain
+    serve under overlap retires the outstanding round first (and the drain
     asserts the invariant)."""
     from fantoch_tpu.run.device_runner import NewtDeviceDriver
 
@@ -1233,11 +1238,11 @@ def test_newt_pipelined_clock_threshold_flushes_outstanding():
                          monitor_execution_order=True)
     # force the flush condition without 2^31 rounds of work
     d._max_clock = NewtDeviceDriver.CLOCK_RESET_THRESHOLD - 1
-    r1 = d.step_pipelined([_put(1, 1, "k", "a")])
+    r1 = d.serve([[_put(1, 1, "k", "a")]], overlap=True)
     assert r1 == [] and d.has_outstanding
     # threshold trips: the next pipelined call must flush first
     assert d._pipeline_flush_needed([_put(1, 2, "k", "b")])
-    r2 = d.step_pipelined([_put(1, 2, "k", "b")])
+    r2 = d.serve([[_put(1, 2, "k", "b")]], overlap=True)
     # the early flush returned round 1's results; round 2 is in flight
     assert [r.op_results[0] for r in r2] == [None]
     assert d.has_outstanding
@@ -1248,15 +1253,15 @@ def test_newt_pipelined_clock_threshold_flushes_outstanding():
 
 def test_pipelined_gid_reset_flushes_outstanding():
     """The gid epoch reset rebases the registry that drain reads, so
-    step_pipelined must retire the outstanding round *before* resetting
+    serve under overlap must retire the outstanding round *before* resetting
     (the early-flush branch); the reset then proceeds and chains stay
     intact across it."""
     d = _driver(batch_size=16)
-    assert d.step_pipelined([_put(1, 1, "k", "a")]) == []
+    assert d.serve([[_put(1, 1, "k", "a")]], overlap=True) == []
     assert d.has_outstanding
     # lower the threshold on this instance so the next dispatch triggers
     d.GID_RESET_THRESHOLD = d._next_gid + d.batch_size
-    r1 = d.step_pipelined([_put(1, 2, "k", "b")])
+    r1 = d.serve([[_put(1, 2, "k", "b")]], overlap=True)
     # the early flush returned round 1's results ahead of the reset
     assert [r.op_results[0] for r in r1] == [None]
     assert d.gid_epochs == 1 and d.has_outstanding
@@ -1273,7 +1278,8 @@ def test_device_runtime_pipelined_tcp_serving(protocol):
     than the standing queue) and still answers every client with per-key
     order agreement — the TCP twin of the equivalence test; the Newt
     driver serves through the same dispatch/drain scaffold."""
-    config = Config(3, 1, shard_count=1)
+    # (a depth set is the opt-in to overlap on the CPU test backend)
+    config = Config(3, 1, shard_count=1, serving_pipeline_depth=1)
     workload = Workload(
         shard_count=1,
         key_gen=ConflictRateKeyGen(50),
@@ -1289,7 +1295,6 @@ def test_device_runtime_pipelined_tcp_serving(protocol):
             batch_size=8,
             open_loop_interval_ms=1,
             protocol=protocol,
-            pipeline=True,  # auto would disable it on the CPU test backend
         )
     )
     for client in clients.values():
@@ -1335,7 +1340,7 @@ def test_depth_k_pipelined_parity(proto_cls, depth):
     sync_rounds = [d_sync.step(b) for b in batches()]
     pipe_rounds = []
     for r, b in enumerate(batches()):
-        pipe_rounds.append(d_pipe.step_pipelined(b))
+        pipe_rounds.append(d_pipe.serve([b], overlap=True))
         if r == 3:  # mid-stream flush must retire in order, then refill
             pipe_rounds.append(d_pipe.flush_pipeline())
             assert not d_pipe.has_outstanding
@@ -1392,7 +1397,7 @@ def test_seq_window_advance_races_inflight_dispatches(depth):
     d_sync, d_pipe = mk(), mk()
     d_pipe.pipeline_depth = depth
     sync_rounds = [d_sync.step(b) for b in batches()]
-    pipe_rounds = [d_pipe.step_pipelined(b) for b in batches()]
+    pipe_rounds = [d_pipe.serve([b], overlap=True) for b in batches()]
     pipe_rounds.append(d_pipe.flush_pipeline())
 
     def flat(rounds):
@@ -1426,7 +1431,7 @@ def test_pipelined_requeue_interleaving():
                          pending_capacity=12,
                          monitor_execution_order=True)
     d.pipeline_depth = 2
-    healthy = d._step
+    healthy = d._program()
     values = {i + 1: f"v{i + 1}" for i in range(20)}
     results = {}
 
@@ -1436,14 +1441,15 @@ def test_pipelined_requeue_interleaving():
             results[r.rifl.sequence] = r.op_results[0]
 
     # healthy pipelined round seeds the hot-key chain
-    absorb(d.step_pipelined([_put(1, s, "hot", values[s]) for s in range(1, 5)]))
+    absorb(d.serve([[_put(1, s, "hot", values[s]) for s in range(1, 5)]], overlap=True))
     # degrade to one live replica with rounds in flight: round d1 still
     # commits (agreeing proposals) but cannot stabilize; round d2's rows
     # stay uncommitted and, with the committed backlog carried first,
     # overflow the 12-slot pending buffer into the host requeue
-    d._step = mesh_step.jit_newt_step(d._mesh, f=2, live_replicas=1)
-    absorb(d.step_pipelined([_put(1, s, "hot", values[s]) for s in range(5, 13)]))
-    absorb(d.step_pipelined([_put(1, s, "hot", values[s]) for s in range(13, 21)]))
+    d._programs[1] = d._precompile(
+        mesh_step.jit_newt_step(d._mesh, f=2, live_replicas=1))
+    absorb(d.serve([[_put(1, s, "hot", values[s]) for s in range(5, 13)]], overlap=True))
+    absorb(d.serve([[_put(1, s, "hot", values[s]) for s in range(13, 21)]], overlap=True))
     absorb(d.flush_pipeline())
     assert d.in_flight > 0  # carried (committed backlog + uncommitted)
     requeued = d.take_requeue()
@@ -1451,10 +1457,10 @@ def test_pipelined_requeue_interleaving():
 
     # heal and feed requeues back through pipelined rounds until drained
     # (empty rounds at the tail let the carried backlog stabilize)
-    d._step = healthy
+    d._programs[1] = healthy
     pending = requeued
     for _ in range(30):
-        absorb(d.step_pipelined(pending[:4]))
+        absorb(d.serve([pending[:4]], overlap=True))
         pending = pending[4:] + d.take_requeue()
         if not pending and d.in_flight == 0 and not d.has_outstanding:
             break
@@ -1470,9 +1476,9 @@ def test_pipelined_requeue_interleaving():
 
 
 def test_chained_pipelined_parity():
-    """step_chained_pipelined (S in-dispatch rounds x depth-K in-flight
-    chains) reproduces the sync per-round execution exactly, like
-    step_chained but with chains carried in flight."""
+    """serve of a chain under overlap (S in-dispatch rounds x depth-K
+    in-flight chains) reproduces the sync per-round execution exactly,
+    like the chain without overlap but with chains carried in flight."""
     from fantoch_tpu.run.device_runner import NewtDeviceDriver
 
     mk = lambda: NewtDeviceDriver(3, batch_size=8, key_buckets=64,  # noqa: E731
@@ -1494,7 +1500,7 @@ def test_chained_pipelined_parity():
     bs = batches()
     groups = [bs[i * 3 : (i + 1) * 3] for i in range(4)]
     sync_rounds = [d_sync.step(b) for b in bs]
-    chp_rounds = [d_chp.step_chained_pipelined(g) for g in groups]
+    chp_rounds = [d_chp.serve(g, overlap=True) for g in groups]
     chp_rounds.append(d_chp.flush_pipeline())
 
     def flat(rounds):
@@ -1648,17 +1654,17 @@ DRAIN_DRIVERS = {
 
 def _drain_pair(protocol, **kw):
     """The protocol's driver and its walking oracle, built alike; the
-    oracle runs the driver's own jitted round."""
+    oracle runs the driver's own programs."""
     cls, walk, n, extra = DRAIN_DRIVERS[protocol]
     kw = {"key_buckets": 64, "monitor_execution_order": True, **extra, **kw}
     real, oracle = cls(n, **kw), walk(n, **kw)
-    oracle._step = real._step
+    oracle._programs = real._programs
     return real, oracle
 
 
 def _degrade(protocol, *drivers, live=1):
     """Swap in a round with ``live`` replicas answering (None: all),
-    one jit for all ``drivers``."""
+    one program for all ``drivers``."""
     from fantoch_tpu.parallel import mesh_step
 
     d = drivers[0]
@@ -1671,8 +1677,9 @@ def _degrade(protocol, *drivers, live=1):
         "fpaxos": lambda: mesh_step.jit_paxos_step(
             d._mesh, f=1, num_replicas=n, live_replicas=live),
     }[protocol]()
+    program = d._precompile(step)
     for driver in drivers:
-        driver._step = step
+        driver._programs[1] = program
 
 
 def _puts(seqs, own=True):
@@ -1960,19 +1967,18 @@ def test_device_runtime_depth2_tcp_serving():
 def test_runtime_pipeline_engages_on_backlog():
     """Deterministic pipeline engagement: a backlog deeper than the batch
     is enqueued before the driver task first runs, so the queue is
-    non-empty at every early batch fill and step_pipelined must engage
+    non-empty at every early batch fill and the overlap must engage
     (no dependence on client arrival timing)."""
     from fantoch_tpu.run.device_runner import DeviceRuntime
     from fantoch_tpu.run.harness import free_port
 
     async def go():
-        config = Config(3, 1, shard_count=1)
+        config = Config(3, 1, shard_count=1, serving_pipeline_depth=1)
         runtime = DeviceRuntime(
             config,
             ("127.0.0.1", free_port()),
             batch_size=8,
             key_buckets=64,
-            pipeline=True,
             monitor_execution_order=True,
         )
         for i in range(24):
@@ -2103,7 +2109,7 @@ def test_lone_command_fast_path_releases_immediately():
 
 
 def _served(tmp_path=None, **kw):
-    config = Config(3, 1, shard_count=1, **kw.pop("config", {}))
+    config = Config(3, 1, shard_count=1, serving_pipeline_depth=1, **kw.pop("config", {}))
     workload = Workload(
         shard_count=1, key_gen=ConflictRateKeyGen(50), keys_per_command=1,
         commands_per_client=COMMANDS_PER_CLIENT, payload_size=1,
@@ -2111,7 +2117,7 @@ def _served(tmp_path=None, **kw):
     return asyncio.run(
         run_device_server(
             config, workload, client_count=4, batch_size=8,
-            open_loop_interval_ms=1, pipeline=True, **kw,
+            open_loop_interval_ms=1, **kw,
         )
     )
 

@@ -253,13 +253,12 @@ def test_no_full_collection_but_the_drivers_own_over_a_few_thousand_commands(pro
     commands = 400
     runtime, clients = asyncio.run(
         run_device_server(
-            Config(3, 1, shard_count=1),
+            Config(3, 1, shard_count=1, serving_pipeline_depth=1),  # overlap, on the CPU too
             Workload(
                 shard_count=1, key_gen=ConflictRateKeyGen(50), keys_per_command=1,
                 commands_per_client=commands, payload_size=100,
             ),
             client_count=8, protocol=protocol, batch_size=64, pending_capacity=64,
-            pipeline=True,
         )
     )
     t = runtime._tallies  # the last round's

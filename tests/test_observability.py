@@ -696,7 +696,7 @@ def test_a_capture_holds_the_round_stages_and_no_python_frames(tmp_path):
     from fantoch_tpu.observability.exposition import capture_device_profile
     from fantoch_tpu.run.harness import run_device_server
 
-    config = Config(3, 1, shard_count=1)
+    config = Config(3, 1, shard_count=1, serving_pipeline_depth=1)  # overlap, on the CPU too
     workload = Workload(
         shard_count=1, key_gen=ConflictRateKeyGen(50), keys_per_command=1,
         commands_per_client=10, payload_size=1,
@@ -705,7 +705,7 @@ def test_a_capture_holds_the_round_stages_and_no_python_frames(tmp_path):
     async def serve():
         return await run_device_server(
             config, workload, client_count=4, batch_size=8,
-            open_loop_interval_ms=1, pipeline=True,
+            open_loop_interval_ms=1,
         )
 
     async def go():

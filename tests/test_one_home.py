@@ -219,6 +219,47 @@ def test_defaults_the_cells_run():
     assert runtime._chain_tuner.ladder() == [1, 2, 4, 8]
 
 
+# --- a driver runs rounds one way (PR 44) ---
+
+
+def _driver_has(name):
+    from fantoch_tpu.run import device_runner as dr
+    from fantoch_tpu.run.pipeline import PipelineCore
+
+    return any(
+        hasattr(cls, name)
+        for cls in (PipelineCore, dr._DriverCore, dr.DeviceDriver, dr.NewtDeviceDriver,
+                    dr.CaesarDeviceDriver, dr.PaxosDeviceDriver)
+    )
+
+
+def _runtime_takes(argument):
+    import inspect
+
+    return argument in inspect.signature(DeviceRuntime.__init__).parameters
+
+
+ONE_WAY = [
+    # (what chose a route and is gone, whether anything still answers to it)
+    ("step_chained", lambda: _driver_has("step_chained")),
+    ("step_chained_pipelined", lambda: _driver_has("step_chained_pipelined")),
+    ("step_pipelined", lambda: _driver_has("step_pipelined")),
+    ("--device-pipeline",
+     lambda: "--device-pipeline" in bin_server.build_parser()._option_string_actions),
+    ("pipeline", lambda: _runtime_takes("pipeline")),
+]
+
+
+@pytest.mark.parametrize("name,answers", ONE_WAY, ids=[row[0] for row in ONE_WAY])
+def test_rounds_are_run_one_way(name, answers):
+    """``serve`` is the one entry and its ``overlap`` is the runtime's to
+    work out (off the CPU, or a depth set): no mode of its own, no flag
+    and no constructor argument chooses it."""
+    assert not answers()
+    assert _driver_has("serve") and _driver_has("step") and _driver_has("flush_pipeline")
+    assert _served("--serving-pipeline-depth", "1").pipeline and not _served().pipeline
+
+
 # --- rules over the tree ---
 
 

@@ -9,8 +9,8 @@ is exact (integers, booleans and strings, no tolerance).
    whole batch on any rows of it, `live` drawn anew each round from
    {n, f + 1, f, 0} (one jitted program a value) and a pending capacity
    under the batch, so that degraded rounds overflow and roll slots back.
-2. `PaxosDeviceDriver` through `step`, `step_pipelined`,
-   `step_chained_pipelined` and `flush_pipeline`: the reference's results in
+2. `PaxosDeviceDriver` through `step`, `serve` under overlap a round and a
+   chain a call, and `flush_pipeline`: the reference's results in
    the reference's order, each rifl once, through a degraded stretch, a
    re-queue, a slot-epoch rebase with carried slots, and the recovery.
 3. The object protocol upstream's shape lives in (`protocol/fpaxos.py` over
@@ -140,8 +140,8 @@ class Pair:
 
     def set_live(self, live):
         assert not self.driver.has_outstanding
-        self.driver._step = mesh_step.jit_paxos_step(
-            self.driver._mesh, f=self.F, num_replicas=self.N, live_replicas=live)
+        self.driver._programs[1] = self.driver._precompile(mesh_step.jit_paxos_step(
+            self.driver._mesh, f=self.F, num_replicas=self.N, live_replicas=live))
         self.reference.live = self.N if live is None else live
 
     def take_back(self):
@@ -160,15 +160,15 @@ class Pair:
         return batch
 
     def rounds(self, mode, sizes, fresh=True):
-        """A round of each size through the driver's ``mode`` (a chain takes
-        two) and, a round each, through the reference."""
-        sizes, chain = list(sizes), 2 if "chained" in mode else 1
+        """A round of each size through the driver's ``mode`` (``step``, or
+        ``serve`` under overlap a round (``overlap``) or a chain of two
+        (``chain``) a call) and, a round each, through the reference."""
+        sizes, chain = list(sizes), 2 if mode == "chain" else 1
         while sizes:
             batches = [self.batch(size, fresh) for size in sizes[:chain]]
             sizes = sizes[chain:]
             programs = [[to_program(cmd) for cmd in batch] for batch in batches]
-            call = getattr(self.driver, mode)
-            self.results += call(programs) if chain == 2 else call(programs[0])
+            self.results += self.driver.serve(programs, overlap=mode != "step")
             for batch in batches:
                 want = self.reference.round(batch)
                 returned = {row.dot: row.returned for row in want.carried_rows + want.batch_rows}
@@ -198,8 +198,8 @@ def test_the_driver_is_the_reference_through_every_mode_a_requeue_and_a_rebase()
 
     # all live, every mode: everything sent executes in the round that takes it
     pair.rounds("step", [BATCH, 3, 0, 7])
-    pair.rounds("step_pipelined", [BATCH, 5, BATCH, 1])
-    pair.rounds("step_chained_pipelined", [BATCH, 2, 6, BATCH])
+    pair.rounds("overlap", [BATCH, 5, BATCH, 1])
+    pair.rounds("chain", [BATCH, 2, 6, BATCH])
     pair.settle()
     executed_before = driver.executed
     assert executed_before == 64 and driver.requeued == 0 and not pair.reference.carried
@@ -210,8 +210,8 @@ def test_the_driver_is_the_reference_through_every_mode_a_requeue_and_a_rebase()
     pair.set_live(Pair.F)
     driver.SLOT_RESET_THRESHOLD = driver._next_slot + PENDING + BATCH
     pair.rounds("step", [BATCH, 4])
-    pair.rounds("step_pipelined", [BATCH, BATCH, 3])
-    pair.rounds("step_chained_pipelined", [5, BATCH])
+    pair.rounds("overlap", [BATCH, BATCH, 3])
+    pair.rounds("chain", [5, BATCH])
     pair.settle()
     del driver.SLOT_RESET_THRESHOLD  # the class's own again: one rebase and no more
     assert driver.executed == executed_before and driver.in_flight == PENDING
@@ -222,8 +222,8 @@ def test_the_driver_is_the_reference_through_every_mode_a_requeue_and_a_rebase()
     # recovery: the carried slots execute first, then what was handed back, then new ones
     pair.set_live(None)
     carried = [cmd.dot for _, cmd in pair.reference.carried]
-    pair.rounds("step_pipelined", [BATCH, 2, BATCH])
-    pair.rounds("step_chained_pipelined", [BATCH, BATCH])
+    pair.rounds("overlap", [BATCH, 2, BATCH])
+    pair.rounds("chain", [BATCH, BATCH])
     pair.rounds("step", [6])
     pair.settle()
     while pair.waiting:

@@ -64,12 +64,12 @@ def test_config_serving_pipeline_depth_validates():
 
 @pytest.mark.parametrize("depth", [1, 2, 3])
 def test_depth_k_lag_and_order(depth):
-    """step_pipelined returns results exactly ``depth`` calls late, in
+    """serve under overlap returns results exactly ``depth`` calls late, in
     dispatch order, and flush_pipeline retires the tail oldest-first."""
     d = _FakeDriver()
     d.pipeline_depth = depth
     rounds = [[f"r{i}a", f"r{i}b"] for i in range(6)]
-    outs = [d.step_pipelined(b) for b in rounds]
+    outs = [d.serve([b], overlap=True) for b in rounds]
     # the first `depth` calls return nothing; call k returns round k-depth
     for k, out in enumerate(outs):
         if k < depth:
@@ -90,11 +90,11 @@ def test_depth_k_lag_and_order(depth):
 
 def test_step_flushes_pipeline_first():
     """A synchronous step retires every in-flight round before its own,
-    so mixing step/step_pipelined can never reorder results."""
+    so mixing step and serve under overlap can never reorder results."""
     d = _FakeDriver()
     d.pipeline_depth = 2
-    assert d.step_pipelined(["a"]) == []
-    assert d.step_pipelined(["b"]) == []
+    assert d.serve([["a"]], overlap=True) == []
+    assert d.serve([["b"]], overlap=True) == []
     out = d.step(["c"])
     assert out == [(0, "a"), (1, "b"), (2, "c")]
     assert not d.has_outstanding
@@ -107,8 +107,8 @@ def test_flush_needed_retires_all_before_dispatch():
     d = _FakeDriver(flush_at={"RESET"})
     d.pipeline_depth = 3
     for i in range(3):
-        assert d.step_pipelined([f"x{i}"]) == []
-    out = d.step_pipelined(["RESET"])
+        assert d.serve([[f"x{i}"]], overlap=True) == []
+    out = d.serve([["RESET"]], overlap=True)
     assert out == [(0, "x0"), (1, "x1"), (2, "x2")]
     assert len(d._inflight) == 1  # the RESET round went in flight
     assert d.flush_pipeline() == [(3, "RESET")]
@@ -118,7 +118,7 @@ def test_counters_sane_and_idle_frac_bounded():
     d = _FakeDriver()
     d.pipeline_depth = 2
     for i in range(5):
-        d.step_pipelined([f"v{i}", f"w{i}"])
+        d.serve([[f"v{i}", f"w{i}"]], overlap=True)
     d.flush_pipeline()
     c = d.device_counters()
     assert c["device_dispatches"] == 5
@@ -136,7 +136,7 @@ def test_counters_snapshot_mid_flight():
     periodic metrics task does) without perturbing the instrument."""
     d = _FakeDriver()
     d.pipeline_depth = 2
-    d.step_pipelined(["a"])
+    d.serve([["a"]], overlap=True)
     c = d.device_counters()
     assert c["device_dispatches"] == 1
     assert 0.0 <= c["device_idle_frac"] <= 1.0
@@ -166,27 +166,27 @@ def test_ingest_ring_cycles_and_resets():
 
 
 @pytest.mark.parametrize("depth", [1, 2, 3])
-def test_step_chained_parity_with_unbatched(depth):
-    """The generic chained surfaces (base PipelineCore: S grouped
-    rounds, no fusion) are bit-for-bit the unbatched loop — same results
-    in the same order at every depth, chains only a grouping hint."""
+def test_serving_a_chain_parity_with_unbatched(depth):
+    """A chain through the base PipelineCore (S grouped rounds, no
+    fusion) is bit-for-bit the unbatched loop — same results in the same
+    order at every depth, the chain only a grouping hint."""
     rounds = [[f"r{i}a", f"r{i}b", f"r{i}c"] for i in range(12)]
     groups = [rounds[i * 3 : (i + 1) * 3] for i in range(4)]
 
     plain = _FakeDriver()
     plain.pipeline_depth = depth
-    expect = [r for b in rounds for r in plain.step_pipelined(b)]
+    expect = [r for b in rounds for r in plain.serve([b], overlap=True)]
     expect += plain.flush_pipeline()
 
     chained = _FakeDriver()
     chained.pipeline_depth = depth
-    got = [r for g in groups for r in chained.step_chained_pipelined(g)]
+    got = [r for g in groups for r in chained.serve(g, overlap=True)]
     got += chained.flush_pipeline()
     assert got == expect
     assert chained.dispatches == plain.dispatches == 12
 
     sync = _FakeDriver()
-    got_sync = [r for g in groups for r in sync.step_chained(g)]
+    got_sync = [r for g in groups for r in sync.serve(g)]
     assert got_sync == expect
     assert not sync.has_outstanding
 
@@ -482,7 +482,7 @@ def test_ingest_ring_slot_never_reused_while_in_flight():
         d.pipeline_depth = depth
         outs = []
         for i in range(8):
-            outs.extend(d.step_pipelined([10 * i + 1, 10 * i + 2]))
+            outs.extend(d.serve([[10 * i + 1, 10 * i + 2]], overlap=True))
         outs.extend(d.flush_pipeline())
         assert [v for _r, v in outs] == [
             10 * i + j for i in range(8) for j in (1, 2)
@@ -536,8 +536,8 @@ def test_drain_wall_is_fetch_plus_execute_and_dispatch_is_its_halves():
 def test_a_pipelined_drains_spans_carry_the_retired_rounds_id():
     d = _HalvesDriver()
     d.pipeline_depth = 1
-    assert d.step_pipelined(["a"]) == []          # dispatch 1 stays in flight
-    assert d.step_pipelined(["b"]) == [(1, "a")]  # dispatch 2 retires round 1
+    assert d.serve([["a"]], overlap=True) == []          # dispatch 1 stays in flight
+    assert d.serve([["b"]], overlap=True) == [(1, "a")]  # dispatch 2 retires round 1
     assert d.flush_pipeline() == [(2, "b")]
     rounds = [(name, round_id) for name, _t0, _t1, round_id, *_ in d.stages.ring]
     assert rounds == [

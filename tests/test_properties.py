@@ -291,16 +291,18 @@ _EPAXOS_STEPS = {}
 
 
 def _epaxos_steps():
-    """The healthy and the degraded jitted dep-commit round of one mesh,
-    built once: hypothesis examples reuse the compiled programs."""
+    """The healthy and the degraded dep-commit round of one mesh, compiled
+    once (as a driver's ``_programs[1]``): hypothesis examples reuse the
+    programs."""
     if not _EPAXOS_STEPS:
         from fantoch_tpu.parallel import mesh_step
         from fantoch_tpu.run.device_runner import DeviceDriver
 
         probe = DeviceDriver(3, batch_size=8, key_buckets=16, pending_capacity=8)
         _EPAXOS_STEPS["mesh"] = probe._mesh
-        _EPAXOS_STEPS[True] = probe._step
-        _EPAXOS_STEPS[False] = mesh_step.jit_protocol_step(probe._mesh, live_replicas=1)
+        _EPAXOS_STEPS[True] = probe._program()
+        _EPAXOS_STEPS[False] = probe._precompile(
+            mesh_step.jit_protocol_step(probe._mesh, live_replicas=1))
     return _EPAXOS_STEPS
 
 
@@ -367,13 +369,13 @@ def test_the_key_clock_never_holds_a_gid_of_the_working_set(rounds, reset_before
                 d._gid_epoch_reset()
                 holds(f"after the reset before round {at}")
                 reset = True
-        d._step = steps[healthy]
+        d._programs[1] = steps[healthy]
         batch = []
         for key in keys:
             seq += 1
             batch.append((Dot(1, seq), Command.from_single(Rifl(1, seq), 0, f"k{key}", KVOp.put(str(seq)))))
         step(batch, f"after round {at}")
-    d._step = steps[True]
+    d._programs[1] = steps[True]
     for _ in range(16):  # all live again: what was carried or requeued drains
         if not (d.in_flight or backlog or d.has_requeue):
             break

@@ -556,7 +556,7 @@ def test_pipelined_in_flight_rounds_replay_exactly_once():
     emitted = []
     for round_items in (["a1", "a2"], ["b1"], ["c1", "c2"], ["d1"]):
         wal_log.append(round_items)
-        emitted.extend(live.step_pipelined(round_items))
+        emitted.extend(live.serve([round_items], overlap=True))
     # depth 2: the last two rounds are still in flight — crash now
     assert len(live._inflight) == 2
     drained_rifls = [item for _r, item in emitted]
@@ -566,7 +566,7 @@ def test_pipelined_in_flight_rounds_replay_exactly_once():
     recovered.executed = list(drained_rifls)  # the durable executed log
     replayed = []
     for round_items in wal_log:
-        replayed.extend(recovered.step_pipelined(round_items))
+        replayed.extend(recovered.serve([round_items], overlap=True))
     replayed.extend(recovered.flush_pipeline())
     replayed_rifls = [item for _r, item in replayed]
     # exactly-once: every command executes once across both lives,

@@ -59,12 +59,38 @@ def test_the_round_is_one_program_ready_once_and_then_dispatches_compile_nothing
     before = _tallies()
     assert driver.precompile_chains(LADDER) == LADDER  # ready already: nothing to do
     executed = len(driver.step(_batch(0, 8)))
-    executed += len(driver.step_pipelined(_batch(8, 5)))
-    executed += len(driver.step_chained(([_batch(16, 8), _batch(24, 3)])))
-    executed += len(driver.step_chained_pipelined([_batch(32, 8), _batch(40, 8)]))
+    executed += len(driver.serve([_batch(8, 5)], overlap=True))
+    executed += len(driver.serve([_batch(16, 8), _batch(24, 3)]))
+    executed += len(driver.serve([_batch(32, 8), _batch(40, 8)], overlap=True))
     executed += len(driver.flush_pipeline())
     assert _tallies() == before and driver.stages.n["precompile"] == 1
     assert executed == 40 and driver.in_flight == 0 and getattr(driver, tally) == 40
+
+
+def _newt():
+    from fantoch_tpu.run.device_runner import NewtDeviceDriver
+
+    return NewtDeviceDriver(5, f=1, batch_size=8, key_buckets=64, pending_capacity=8)
+
+
+@pytest.mark.parametrize("protocol", [*AHEAD, "newt"])
+def test_a_driver_stepped_without_a_start_up_reaches_the_same_program(protocol):
+    """One way into a ready round: a driver that no server started makes
+    its round's program ready at its first `step`, by the route `start()`
+    takes (`_precompile`, under the `precompile` span), and keeps it: the
+    second `step` compiles nothing, and neither does a `precompile_chains`
+    of the length it already has."""
+    obs.subscribe_recompiles()
+    driver = AHEAD[protocol][0]() if protocol in AHEAD else _newt()
+    assert driver.precompiled_programs == 0 and driver.stages.n["precompile"] == 0
+    executed = len(driver.step(_batch(0, 8)))
+    assert driver.precompiled_programs == 1 and driver.stages.n["precompile"] == 1
+    program = driver._program()
+    before = _tallies()
+    executed += len(driver.step(_batch(8, 8)))
+    assert driver.precompile_chains([1]) == [1]
+    assert _tallies() == before and driver.stages.n["precompile"] == 1
+    assert driver._program() is program and executed == 16
 
 
 def _serve(protocol, tmp_path, commands=40):
@@ -137,7 +163,7 @@ def test_the_dep_commit_round_takes_its_read_column_at_either_key_width(key_widt
                           key_width=key_width, pending_capacity=8)
     assert driver.precompile_chains(LADDER) == LADDER
     assert driver.precompiled_programs == 1 and driver.stages.n["precompile"] == 1
-    assert len(driver._column_shardings[1]) == 4
+    assert len(driver._programs[1][1]) == 4  # where it takes its columns
     before = _tallies()
     assert len(driver.step(_batch(0, 8))) == 8
     assert _tallies() == before

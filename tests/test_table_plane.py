@@ -4,7 +4,7 @@
 (Config.device_table_plane), the resident clock-proposal table
 (table_batched.BatchedKeyClocks over resident_clock_proposal), the fused
 all-device round chain (fused_table_round/fused_table_rounds), and the
-chained Newt serving dispatch (NewtDeviceDriver.step_chained) — each
+chained Newt serving dispatch (NewtDeviceDriver.serve of a chain) — each
 oracle-checked bit-for-bit against the per-command host twins.
 """
 
@@ -652,8 +652,8 @@ def test_newt_driver_step_chained_matches_sequential_steps():
         seq_results.extend(seq_driver.step(batch))
 
     chain_driver = NewtDeviceDriver(3, batch_size=B, key_buckets=64)
-    chained = chain_driver.step_chained(batches[:3])
-    chained += chain_driver.step_chained(batches[3:])
+    chained = chain_driver.serve(batches[:3])
+    chained += chain_driver.serve(batches[3:])
 
     assert [(r.rifl, r.key) for r in chained] == [
         (r.rifl, r.key) for r in seq_results
