@@ -292,3 +292,30 @@ class DependencyGraph:
                     else:
                         visited.update(new_visited)
                 dots.extend(new_dots)
+
+
+def tarjan_order(dots: List[Dot], deps: List[List[int]], n: int):
+    """The order Tarjan gives rows that are all committed and closed under
+    their dependencies (``deps[i]``: the rows ``i`` depends on, by index):
+    ``(order, sizes)``, the row indices in execution order (components in
+    dependency order, each in dot order) and the components' sizes.  The
+    finisher of a device round whose resolver left a key's run uncut
+    (``ops/graph_resolve.resolve_key_runs``'s ``finish`` rows): one oracle
+    a call, on ``n`` processes whose ids the dots' sources are."""
+    from fantoch_tpu.core.timing import RunTime
+
+    time = RunTime()
+    graph = DependencyGraph(1, 0, Config(n, n // 2))
+    shards = frozenset({0})
+    for row, dot in enumerate(dots):
+        graph.handle_add(
+            dot, row, [Dependency(dots[dep], shards) for dep in deps[row]], time
+        )
+    order = graph.commands_to_execute()
+    assert len(order) == len(dots), (
+        f"the finisher's rows are not closed under their dependencies: "
+        f"{len(order)} of {len(dots)} ordered"
+    )
+    chains = graph.metrics().get_collected(ExecutorMetricsKind.CHAIN_SIZE)
+    sizes = [size for size, times in chains.values() for _ in range(times)]
+    return order, sizes

@@ -348,8 +348,12 @@ ROUND_STAGES = (
     # the probe's late wake-ups, the interpreter's full collections
     "snapshot", "loop_stall", "gc",
     # before the first round: a chain program compiled or loaded
-    # (``precompile_chains``; the span's round is the chain length)
+    # (``precompile_chains``; the span's round is the chain length); and
+    # the round's second program, where clients of a second site register
     "precompile",
+    # inside ``execute``: the host's Tarjan over the rows of a key's run
+    # the device's resolver did not cut (a coordinator at every site)
+    "finish",
 )
 # the stages that neither sleep nor wait on the device by design: what
 # their wall time holds beyond their CPU time is time their thread was not

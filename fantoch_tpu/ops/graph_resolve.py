@@ -1125,3 +1125,112 @@ def _resolve_general_iterative(deps, dot_src, dot_seq, max_iters):
 
     order = _order_from_ranks(rank, leader, dot_src, dot_seq)
     return order, resolved, rank, leader, stuck
+
+
+# ---------------------------------------------------------------------------
+# one key a command: the components of a key's run
+# ---------------------------------------------------------------------------
+
+
+class KeyRunResolution(NamedTuple):
+    """Result of :func:`resolve_key_runs`, by sorted position."""
+
+    order: jax.Array  # int32[W] positions: the resolved ones first, in execution order
+    resolved: jax.Array  # bool[W] — executes this round, at its place in ``order``
+    finish: jax.Array  # bool[W] — executes this round, at the place the host finds
+    scc_rows: jax.Array  # int32[] — resolved rows in a component of several
+    scc_count: jax.Array  # int32[] — components of several rows
+    scc_rows_max: jax.Array  # int32[] — the largest of them
+    iters: jax.Array  # int32[] — passes: this one, and the blocked set's
+
+
+def resolve_key_runs(
+    deps: jax.Array,  # int32[W, D] sorted positions; TERMINAL for none
+    head: jax.Array,  # bool[W] — the position begins a key's run
+    valid: jax.Array,  # bool[W]
+    committed: jax.Array,  # bool[W]
+    dot_src: jax.Array,  # int32[W]
+    dot_seq: jax.Array,  # int32[W]
+) -> KeyRunResolution:
+    """The strongly connected components of a working set of one-key
+    commands, and their order, where the rows stand sorted by key (each
+    key's rows one contiguous run) and every dependency of a row is a row
+    of its own run (a command conflicts on its one key) or ``TERMINAL``
+    (executed already).  Dependencies may point either way along the run:
+    replicas that saw a round's commands in different orders report
+    different predecessors, and the union of what they reported has cycles.
+
+    A row is *blocked* while it reaches a row that is valid and not
+    committed (the resolvers' ``MISSING``): found by spreading the mark
+    against the edges to a fixpoint, a loop that does not run where every
+    valid row is committed.  The other valid rows execute this round.
+    Among them a cycle needs an edge that points forward, and lies inside
+    the union of the spans of such edges that touch: so the runs cut into
+    *intervals* (a running maximum of the forward reach), every component
+    lies inside one, and an edge between two intervals points back.  An
+    interval in which every row but the first has an edge to the row just
+    before it is one component: the first row reaches the interval's end
+    over the forward spans and everything walks back down to it.  That is
+    the shape concurrent writes leave (each quorum member reports the
+    latest command it saw before this one, and with three members one of
+    them saw the row just before).  Such an interval executes in dot order
+    (``tarjan.rs:15``), the intervals of a run in position order, runs in
+    any order: one sort by (interval, dot).
+
+    A run that holds an interval which is not such a chain (reads that
+    commute leave some) is not cut further here: its executable rows are
+    marked ``finish``, and the caller hands them, with their
+    dependencies, to the host's Tarjan (``executor/graph/deps_graph.py``)
+    in the same round.  They execute this round either way: every row a
+    ``finish`` row reaches is executable too."""
+    work = deps.shape[0]
+    pos = jnp.arange(work, dtype=jnp.int32)
+    int_max = jnp.iinfo(jnp.int32).max
+    live = deps >= 0
+    safe = jnp.where(live, deps, 0)
+    run_start = jax.lax.cummax(jnp.where(head, pos, 0))
+
+    # blocked: reaches an uncommitted row (one hop a pass; no pass where
+    # every valid row is committed)
+    def spread(state):
+        blocked, _, passes = state
+        wider = blocked | (live & blocked[safe]).any(axis=-1)
+        return wider, (wider != blocked).any(), passes + 1
+
+    uncommitted = valid & ~committed
+    blocked, _, passes = jax.lax.while_loop(
+        lambda state: state[1], spread, (uncommitted, uncommitted.any(), jnp.int32(0))
+    )
+    runs = valid & committed & ~blocked  # executes this round
+
+    def before(x):  # the value one position earlier
+        return jnp.concatenate([jnp.full((1,), -1, jnp.int32), x[:-1]])
+
+    # intervals: a row begins one iff no executable row before it reaches
+    # it or past it (a reach never leaves its run, and runs stand in
+    # position order, so one running maximum serves every run)
+    reach = jnp.where(runs, jnp.maximum(jnp.where(live, deps, -1).max(axis=-1), pos), -1)
+    begins = runs & (before(jax.lax.cummax(reach)) < pos)
+    start = jax.lax.cummax(jnp.where(begins, pos, -1))
+    # a chain: every executable row that begins no interval has an edge to
+    # the executable row just before it
+    last_run = before(jax.lax.cummax(jnp.where(runs, pos, -1)))
+    chained = ~runs | begins | (deps == last_run[:, None]).any(axis=-1)
+    unchained = jnp.zeros((work,), bool).at[run_start].max(~chained)[run_start]
+    finish = runs & unchained
+    resolved = runs & ~unchained
+
+    order = jnp.lexsort(
+        (dot_seq, dot_src, jnp.where(resolved, start, int_max))
+    ).astype(jnp.int32)
+    size = jnp.zeros((work,), jnp.int32).at[
+        jnp.where(resolved, start, work)
+    ].add(1, mode="drop")
+    several = jnp.where(size > 1, size, 0)
+    return KeyRunResolution(
+        order, resolved, finish,
+        several.sum().astype(jnp.int32),
+        (size > 1).sum().astype(jnp.int32),
+        several.max().astype(jnp.int32),
+        passes + 1,
+    )

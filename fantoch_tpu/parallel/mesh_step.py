@@ -68,7 +68,12 @@ import jax.numpy as jnp
 from jax import shard_map
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
-from fantoch_tpu.ops.graph_resolve import MISSING, TERMINAL, resolve_general
+from fantoch_tpu.ops.graph_resolve import (
+    MISSING,
+    TERMINAL,
+    resolve_general,
+    resolve_key_runs,
+)
 
 REPLICA_AXIS = "replica"
 BATCH_AXIS = "batch"
@@ -132,6 +137,31 @@ class StepOutput(NamedTuple):
     tallies: jax.Array  # int32[5]
 
 
+class SiteStepOutput(NamedTuple):
+    """What the round with a coordinator at every site gives
+    (``protocol_step(sites=n)``): :class:`StepOutput`'s fields, with the
+    committed dependencies as a set and the rows the host orders."""
+
+    order: jax.Array  # int32[W] — the rows the device ordered first, in execution order
+    resolved: jax.Array  # bool[W] — executed this round (``finish`` rows among them)
+    fast_path: jax.Array  # bool[W]
+    # the union of what the fast quorum reported (global ids, -1 none,
+    # repeats left in): per member its latest write, then its latest read
+    # since it (a write's only)
+    deps_gid: jax.Array  # int32[W, 2*fast_quorum]
+    gids: jax.Array  # int32[W]
+    slow_paths: jax.Array  # int32[]
+    stable: jax.Array  # int32[]
+    pending: jax.Array  # int32[]
+    pend_dropped: jax.Array  # int32[]
+    # SITE_ROUND_TALLIES, then SITE_ROUND_GAUGES
+    tallies: jax.Array  # int32[10]
+    # executed this round at the place the host's Tarjan finds: the
+    # executable rows of a key's run the device's resolver did not cut
+    # (ops/graph_resolve.resolve_key_runs); not in ``order``'s front part
+    finish: jax.Array  # bool[W]
+
+
 # StepOutput.tallies, in order: the executed rows' non-empty dependency
 # slots; their key slots with an earlier command on the bucket, and of
 # those the ones where both are reads (no dependency: reads commute); the
@@ -140,6 +170,17 @@ ROUND_TALLIES = (
     "deps_committed", "key_links", "read_links_commuted", "read_rows",
     "cross_shard_executed",
 )
+# SiteStepOutput.tallies: those five, then what the round with a
+# coordinator at every site says of its graph, summed over rounds: the
+# executed rows in a strongly connected component of several rows, such
+# components, passes of the resolver, rows whose order the host's Tarjan
+# finds (the device's share; the driver adds what the finisher found) ...
+SITE_ROUND_TALLIES = ROUND_TALLIES + (
+    "scc_rows", "scc_count", "resolve_iters", "finisher_rows",
+)
+# ... and, last in the vector, a gauge: the largest component of the round
+# (the driver keeps the last round's that had one)
+SITE_ROUND_GAUGES = ("scc_rows_max",)
 
 
 DEP_COMMIT_RULES = ("epaxos", "atlas")
@@ -419,6 +460,8 @@ def protocol_step(
     shard_count: int = 1,
     f: int = 1,
     rule: str = "epaxos",
+    sites: int = 1,
+    site_base: int = 1,
 ) -> Tuple[ReplicaState, StepOutput]:
     """One batched commit+execute round over the (replica, batch) mesh.
 
@@ -466,7 +509,24 @@ def protocol_step(
     need no request RPCs at all: the working set is globally visible on
     the mesh, so the resolver orders a multi-shard command after ALL its
     deps (both shards') in the same gather it uses for one shard.
+
+    ``sites`` (static, like the key width): 1 is the round above, every
+    command coordinated by replica 0 and seen by every replica in arrival
+    order.  ``sites == n`` is the round with a coordinator at every site
+    (:func:`_protocol_step_sites`: EPaxos, one shard, one key a command):
+    a command's coordinator is the replica ``dot_src - site_base``, the
+    replicas see a round's commands in different orders and the committed
+    graph has cycles.  Same state, same columns, another program.
     """
+    if sites != 1:
+        assert sites == state.key_clock.shape[0] and shard_count == 1, (
+            "a coordinator at every site: one shard, and a site a replica"
+        )
+        assert rule == "epaxos", "sites are served under EPaxos's rule"
+        return _protocol_step_sites(
+            state, key, dot_src, dot_seq, read,
+            mesh=mesh, live_replicas=live_replicas, site_base=site_base,
+        )
     num_replicas, key_buckets = state.key_clock.shape
     if key.ndim == 1:
         key = key[:, None]
@@ -784,21 +844,309 @@ def jit_protocol_step(
     shard_count: int = 1,
     f: int = 1,
     rule: str = "epaxos",
+    sites: int = 1,
+    site_base: int = 1,
 ):
-    """jit-compiled step with donated device-resident state."""
+    """jit-compiled step with donated device-resident state (``sites``:
+    the round with a coordinator at every site, :func:`protocol_step`)."""
     import functools
 
-    return jax.jit(
-        functools.partial(
-            protocol_step,
-            mesh=mesh,
-            live_replicas=live_replicas,
-            shard_count=shard_count,
-            f=f,
-            rule=rule,
-        ),
-        donate_argnums=(0,),
+    static = dict(
+        mesh=mesh, live_replicas=live_replicas, shard_count=shard_count, f=f, rule=rule
     )
+    if sites != 1:
+        static.update(sites=sites, site_base=site_base)
+    return jax.jit(functools.partial(protocol_step, **static), donate_argnums=(0,))
+
+
+def _protocol_step_sites(
+    state: ReplicaState,
+    key: jax.Array,  # int32[B] or int32[B, 1]
+    dot_src: jax.Array,  # int32[B] — the coordinator's process: site_base + site
+    dot_seq: jax.Array,  # int32[B]
+    read: jax.Array | None,
+    *,
+    mesh: Mesh,
+    live_replicas: int | None,
+    site_base: int,
+) -> Tuple[ReplicaState, "SiteStepOutput"]:
+    """The dep-commit round with a coordinator at every site: EPaxos, one
+    shard, one key a command (the plain reference is
+    ``tests/sites_reference.py``, semantics and departures there).
+
+    A command's coordinator is the replica at its site, ``dot_src -
+    site_base`` (upstream's ``dot.source()``; the pending buffer carries
+    it as ``pend_src``, so the state is :func:`protocol_step`'s own).
+    What differs from the round with one coordinator:
+
+      * a replica's view of the working set: the pending rows in their
+        order, then the batch's rows of its own site, then the batch's
+        other rows, each in arrival order.  A replica's own word on a row
+        is ``KeyDeps::add_cmd`` over that view (the latest write before it
+        and, for a write, the latest read since that write; else the
+        replica's clocks); its report is that joined with the
+        coordinator's own;
+      * the fast quorum of the coordinator at site ``s`` is the replicas
+        ``s .. s + fast_quorum - 1 (mod n)``; fast path iff every member's
+        report is the same set, which is iff every member's own word is
+        within the coordinator's (``check_union``);
+      * the committed dependencies are the union of the members' words as
+        a set, up to ``fast_quorum`` a class, and they point both ways
+        along a key's run: resolved by
+        ``ops/graph_resolve.resolve_key_runs`` (components in dependency
+        order, each in dot order), with the rows of a run it does not cut
+        marked ``finish`` for the host's Tarjan.  Those execute this round
+        too: the clocks learn them and they are not carried.
+
+    Everything is computed where the rows stand sorted by key (a key's
+    rows one run, pending rows first, then arrival order: the order of a
+    replica's view but for where its own site's rows stand), and scattered
+    back to working rows at the end."""
+    num_replicas, key_buckets = state.key_clock.shape
+    if key.ndim == 2:
+        assert key.shape[1] == 1, "a coordinator at every site: one key a command"
+        key = key[:, 0]
+    assert state.pend_key.shape[1] == 1, "sites need init_state(key_width=1)"
+    batch = key.shape[0]
+    if read is None:
+        read = jnp.zeros((batch,), bool)
+    pend_cap = state.pend_gid.shape[0]
+    work = pend_cap + batch
+    fast_quorum, write_quorum = quorum_sizes(num_replicas, 1, "epaxos")
+    if live_replicas is None:
+        live_replicas = num_replicas
+    replica_blocks = num_replicas // mesh.shape[REPLICA_AXIS]
+    int_max = jnp.iinfo(jnp.int32).max
+
+    def step(
+        key_clock, frontier, next_gid, pend_key, pend_src, pend_seq, pend_gid,
+        read_clock, pend_read, key_l, dot_src_l, dot_seq_l, read_l,
+    ):
+        key_new = jax.lax.all_gather(key_l, BATCH_AXIS, tiled=True)  # [B]
+        src_new = jax.lax.all_gather(dot_src_l, BATCH_AXIS, tiled=True)
+        seq_new = jax.lax.all_gather(dot_seq_l, BATCH_AXIS, tiled=True)
+        read_new = jax.lax.all_gather(read_l, BATCH_AXIS, tiled=True)
+
+        # 1. the working set, as protocol_step has it
+        widx = jnp.arange(work, dtype=jnp.int32)
+        gid = jnp.concatenate(
+            [pend_gid, next_gid + jnp.arange(batch, dtype=jnp.int32)]
+        )
+        valid = gid >= 0
+        key_cat = jnp.concatenate([pend_key[:, 0], key_new])
+        real = valid & (key_cat != KEY_PAD)
+        key_full = jnp.where(real, key_cat, key_buckets + widx)
+        dot_src_f = jnp.where(valid, jnp.concatenate([pend_src, src_new]), 0)
+        dot_seq_f = jnp.where(valid, jnp.concatenate([pend_seq, seq_new]), 0)
+        read_f = valid & jnp.concatenate([pend_read, read_new])
+
+        # ... and sorted by key: ``perm[p]`` is the row at position ``p``
+        perm, head, read_at = _key_runs(key_full[:, None], read_f)
+        pos = widx
+        run_start = _run_start(head)
+        run_last = jax.lax.cummin(
+            jnp.where(jnp.concatenate([head[1:], jnp.ones((1,), bool)]), pos, work),
+            reverse=True,
+        )  # the last position of the position's run
+        gid_at, valid_at, real_at = gid[perm], valid[perm], real[perm]
+        key_at = jnp.minimum(key_full[perm], key_buckets - 1)
+        site_at = jnp.mod(dot_src_f[perm] - site_base, num_replicas)
+        new_at = perm >= pend_cap  # a row of this round's batch
+
+        # 2. each replica's own word, over its view: a row of the first
+        # part of the view (pending, or the replica's own site) has seen
+        # the first part's rows before it; a row of the second part the
+        # second part's rows before it, else the whole of the first part
+        row = (
+            jax.lax.axis_index(REPLICA_AXIS) * replica_blocks
+            + jnp.arange(replica_blocks, dtype=jnp.int32)
+        )  # global replica row ids of this block
+        second = new_at[None] & (site_at[None] != row[:, None])  # [r_blk, W]
+
+        def before(x):  # the value one position earlier
+            return jnp.concatenate(
+                [jnp.full(x.shape[:-1] + (1,), -1, jnp.int32), x[..., :-1]], axis=-1
+            )
+
+        def in_run(p):  # a position of another run is no predecessor
+            return jnp.where(p >= run_start[None], p, -1)
+
+        def latest_in_view(member):  # member: bool[W], by position
+            a = jax.lax.cummax(jnp.where(member[None] & ~second, pos[None], -1), axis=1)
+            b = jax.lax.cummax(jnp.where(member[None] & second, pos[None], -1), axis=1)
+            b_before = in_run(before(b))
+            return jnp.where(
+                second,
+                jnp.where(b_before >= 0, b_before, in_run(a[:, run_last])),
+                in_run(before(a)),
+            )  # [r_blk, W] the position, -1 where the view holds none
+
+        at_w = latest_in_view(~read_at)
+        at_r = latest_in_view(read_at)
+        # where a position stands in the replica's view
+        view_rank = pos[None] + work * second.astype(jnp.int32)
+
+        def rank_of(p):
+            return jnp.where(
+                p >= 0, jnp.take_along_axis(view_rank, jnp.maximum(p, 0), axis=1), -1
+            )
+
+        learnt_w = jnp.where(real_at[None], key_clock[:, key_at], -1)
+        learnt_r = jnp.where(real_at[None], read_clock[:, key_at], -1)
+        write_gid = jnp.where(at_w >= 0, gid_at[jnp.maximum(at_w, 0)], learnt_w)
+        read_gid = jnp.where(at_r >= 0, gid_at[jnp.maximum(at_r, 0)], learnt_r)
+        # the latest read came after the latest write: by the view where
+        # the read is a working row (a working row is after anything
+        # learnt), by arrival where both were learnt
+        since = jnp.where(
+            at_r >= 0,
+            (at_w < 0) | (rank_of(at_r) > rank_of(at_w)),
+            (at_w < 0) & (learnt_r > learnt_w),
+        )
+        kept = since & ~read_at[None]  # a write depends on it
+        own = jnp.stack(
+            [
+                write_gid,
+                jnp.where(kept, read_gid, -1),
+                at_w,
+                jnp.where(kept, at_r, -1),
+                # for the tallies: the replica knew a command on the key,
+                # and the latest it knew was a read
+                ((write_gid >= 0) | (read_gid >= 0)).astype(jnp.int32),
+                (since & (read_gid >= 0)).astype(jnp.int32),
+            ],
+            axis=-1,
+        )  # [r_blk, W, 6]
+        everyone = jax.lax.all_gather(own, REPLICA_AXIS, tiled=True)  # [R, W, 6]
+
+        # 3. the fast quorum of each row's coordinator, a ring from its
+        # site; the coordinator is member 0
+        members = jnp.stack(
+            [
+                everyone[jnp.mod(site_at + k, num_replicas), pos]
+                for k in range(fast_quorum)
+            ],
+            axis=1,
+        )  # [W, fast_quorum, 6]
+        said = members[..., :2]  # [W, fast_quorum, 2] gids
+        mine = said[:, :1, :]  # the coordinator's own word
+        # every report (a member's word joined with the coordinator's) is
+        # the same set iff every member's word is within the coordinator's
+        within = (
+            (said < 0)
+            | (said == mine[..., :1])
+            | (said == mine[..., 1:])
+        )
+        fast_at = within.all(axis=(1, 2)) & valid_at
+        deps_gid_at = said.reshape(work, 2 * fast_quorum)
+        deps_at = members[..., 2:4].reshape(work, 2 * fast_quorum)  # positions
+
+        # the accept round, as protocol_step has it: every live replica
+        # accepts the union at ballot 0
+        live = (row < live_replicas)[:, None]  # [r_blk, 1]
+        acks = jax.lax.psum(live.sum().astype(jnp.int32), REPLICA_AXIS)
+        committed_at = (fast_at | (acks >= write_quorum)) & valid_at
+        slow_paths = ((~fast_at) & valid_at).sum().astype(jnp.int32)
+
+        # 4. the components of each key's run, and their order
+        res = resolve_key_runs(
+            deps_at, head, valid_at, committed_at, dot_src_f[perm], dot_seq_f[perm]
+        )
+        executed_at = res.resolved | res.finish
+
+        def by_row(x_at):  # a column by position, back by working row
+            return jnp.zeros_like(x_at).at[perm].set(x_at)
+
+        executed = by_row(executed_at)
+        order = perm[res.order]
+
+        # 5. every live replica learns what executed, the host-ordered
+        # rows too (they execute this round)
+        done_at = executed_at & real_at
+        learns = live & done_at[None]  # [r_blk, W]
+        new_clock = key_clock.at[:, key_at].max(
+            jnp.where(learns & ~read_at[None], gid_at[None], jnp.int32(-1))
+        )
+        new_read_clock = jax.lax.cond(
+            (done_at & read_at).any(),
+            lambda clock: clock.at[:, key_at].max(
+                jnp.where(learns & read_at[None], gid_at[None], jnp.int32(-1))
+            ),
+            lambda clock: clock,
+            read_clock,
+        )
+        new_frontier = frontier + jnp.where(
+            live[:, 0], executed_at.sum().astype(jnp.int32), 0
+        )
+        stable = jax.lax.pmin(new_frontier.min(), REPLICA_AXIS)
+
+        def count(mask):
+            return mask.sum().astype(jnp.int32)
+
+        # distinct committed dependencies: a sorted row's repeats stand
+        # side by side
+        ranked = jnp.sort(deps_gid_at, axis=-1)
+        distinct = (ranked >= 0) & jnp.concatenate(
+            [jnp.ones((work, 1), bool), ranked[:, 1:] != ranked[:, :-1]], axis=-1
+        )
+        linked = done_at & (members[:, 0, 4] > 0)
+        tallies = jnp.stack(
+            [
+                count(distinct & done_at[:, None]),
+                count(linked),
+                count(linked & read_at & (members[:, 0, 5] > 0)),
+                count(done_at & read_at),
+                jnp.int32(0),  # one shard
+                res.scc_rows,
+                res.scc_count,
+                res.iters,
+                count(res.finish),
+                res.scc_rows_max,
+            ]
+        )  # SITE_ROUND_TALLIES, SITE_ROUND_GAUGES
+
+        # 6. pending carry, as protocol_step has it
+        carry = valid & ~executed
+        carry_order = jnp.argsort(jnp.where(carry, widx, int_max)).astype(jnp.int32)
+        take = carry_order[:pend_cap]
+        is_carry = carry[take]
+        pending = carry.sum().astype(jnp.int32)
+        return (
+            new_clock,
+            new_frontier,
+            next_gid + batch,
+            jnp.where(is_carry, key_cat[take], KEY_PAD)[:, None],
+            jnp.where(is_carry, dot_src_f[take], -1),
+            jnp.where(is_carry, dot_seq_f[take], -1),
+            jnp.where(is_carry, gid[take], -1),
+            new_read_clock,
+            is_carry & read_f[take],
+            order,
+            executed,
+            by_row(fast_at),
+            by_row(jnp.where(real_at[:, None], deps_gid_at, -1)),
+            jnp.where(valid, gid, -1),
+            slow_paths,
+            stable,
+            jnp.minimum(pending, pend_cap),
+            jnp.maximum(pending - pend_cap, 0).astype(jnp.int32),
+            tallies,
+            by_row(res.finish),
+        )
+
+    state_specs = (
+        P(REPLICA_AXIS, None), P(REPLICA_AXIS), P(), P(), P(), P(), P(),
+        P(REPLICA_AXIS, None), P(),
+    )  # ReplicaState, as protocol_step shards it
+    fn = shard_map(
+        step, mesh=mesh,
+        in_specs=state_specs + (P(BATCH_AXIS),) * 4,
+        out_specs=state_specs + (P(),) * len(SiteStepOutput._fields),
+        check_vma=False,
+    )
+    out = fn(*state, key, dot_src, dot_seq, read)
+    n_state = len(ReplicaState._fields)
+    return ReplicaState(*out[:n_state]), SiteStepOutput(*out[n_state:])
 
 
 # ---------------------------------------------------------------------------

@@ -50,6 +50,7 @@ import os
 import sys
 from collections import defaultdict
 from functools import partial
+from itertools import zip_longest
 from time import monotonic, monotonic_ns, thread_time_ns
 from typing import Any, Dict, List, Optional, Sequence, Tuple
 
@@ -57,7 +58,7 @@ import numpy as np
 
 from fantoch_tpu.core.command import Command
 from fantoch_tpu.core.config import Config
-from fantoch_tpu.core.ids import ClientId, Dot, ProcessId, Rifl, ShardId
+from fantoch_tpu.core.ids import AtomicIdGen, ClientId, Dot, ProcessId, Rifl, ShardId
 from fantoch_tpu.core.kvs import KVStore
 from fantoch_tpu.executor.base import ExecutorResult
 from fantoch_tpu.observability.device import (
@@ -170,6 +171,30 @@ def _bucket_row(
     return buckets
 
 
+def _sites_in_turn(batch):
+    """A round's batch with its sites' commands taken in turn: the first
+    of each site (in the order the sites first appear), then the second
+    of each, and so on; a site's own commands keep their order.  The
+    round with a coordinator at every site is given its batch so: the
+    commands a round collects are concurrent, and what a socket read
+    brings is some hundreds of frames of one connection, one site, in a
+    row, where a replica's network would deliver five coordinators'
+    ``MCollect``s interleaved (left as they arrive, only a stretch's
+    first command finds its fast quorum split).  ``batch``: ``(dot,
+    ...)`` entries; the site is the dot's source."""
+    by_site: Dict[int, list] = {}
+    for entry in batch:
+        by_site.setdefault(entry[0].source, []).append(entry)
+    if len(by_site) < 2:
+        return batch
+    return [
+        entry
+        for turn in zip_longest(*by_site.values())
+        for entry in turn
+        if entry is not None
+    ]
+
+
 class _DriverCore(PipelineCore):
     """The host-side machinery every device driver shares: the in-flight
     command registry, the overflow requeue channel, the KVStore, the
@@ -238,6 +263,8 @@ class _DriverCore(PipelineCore):
         # what the round tallies over the rows it executed, summed, by
         # name (mesh_step.ROUND_TALLIES); empty where it tallies nothing
         self.round_tallies: Dict[str, int] = {}
+        # ... and what it says of its last round alone (gauges), by name
+        self.round_gauges: Dict[str, int] = {}
         # the programs made ready, by the rounds a dispatch of theirs
         # carries: the executable and where it takes its columns on the
         # mesh (``_program``)
@@ -292,6 +319,22 @@ class _DriverCore(PipelineCore):
             key_width=key_width,
         )
 
+    # the sites clients are registered at: a client that names none is
+    # at site 0, and the rounds with one coordinator serve no other
+    sites_registered = 1
+
+    def register_site(self, site: int) -> None:
+        """A client plane's hello names the site its clients are at
+        (``ClientHi.site``).  Raises ``ValueError`` for a site the driver
+        cannot serve: here every site but 0 (``DeviceDriver`` serves the
+        others where its round can)."""
+        if site != 0:
+            raise ValueError(
+                f"clients at site {site}: this round has one coordinator, "
+                "replica 0 (a coordinator at every site is served under "
+                "epaxos, one shard, one key a command)"
+            )
+
     def _column_specs(self):
         """What ``_assemble`` stages for one round, and so what the
         round's program takes after the state: (name, shape, dtype, fill)
@@ -338,12 +381,13 @@ class _DriverCore(PipelineCore):
             ready = self._programs[S] = self._precompile(self._jit_rounds(S), S)
         return ready
 
-    def _precompile(self, jitted, S: int = 1):
+    def _precompile(self, jitted, S: int = 1, state=None):
         """``jitted`` lowered on the real state's and the driver's
         columns' shapes (``_column_specs``, under a leading ``S`` for a
         program of several rounds) and compiled, or loaded, through the
         persistent compile cache (the jit's own cache is not touched),
-        under one ``precompile`` span."""
+        under one ``precompile`` span.  ``state``: what stands for the
+        state where a round may hold the real one (a serving driver)."""
         import jax
 
         lead = () if S == 1 else (S,)
@@ -352,7 +396,9 @@ class _DriverCore(PipelineCore):
             for _name, shape, dtype, _fill in self._column_specs()
         )
         with self.stages.span("precompile", S):
-            program = jitted.lower(self._state, *columns).compile()
+            program = jitted.lower(
+                self._state if state is None else state, *columns
+            ).compile()
         return program, tuple(program.input_shardings[0][1:])
 
     def precompile_chains(self, lengths: Sequence[int]) -> List[int]:
@@ -669,6 +715,7 @@ class DeviceDriver(_DriverCore):
         mesh=None,
         f: int = 1,
         rule: str = "epaxos",
+        site_base: ProcessId = 1,
     ):
         from fantoch_tpu.parallel import mesh_step
 
@@ -692,11 +739,84 @@ class DeviceDriver(_DriverCore):
         # the round's tallies over the rows it executed, summed
         # (mesh_step.StepOutput): dependency slots committed, key slots
         # with a command before them on the bucket and those of them
-        # where both are reads, reads, commands on more than one shard
-        self.round_tallies = dict.fromkeys(mesh_step.ROUND_TALLIES, 0)
+        # where both are reads, reads, commands on more than one shard;
+        # then what the round with a coordinator at every site adds
+        # (SITE_ROUND_TALLIES; 0 while the round with one serves)
+        self.round_tallies = dict.fromkeys(mesh_step.SITE_ROUND_TALLIES, 0)
+        # a coordinator at every site: the sites clients registered at
+        # (``register_site``; a command's coordinator is its dot's
+        # source, ``site_base + site``), the round's second program once a
+        # second site made it ready (dispatches run it from then on); the
+        # largest component of the last such round (gauge)
+        self.num_replicas = num_replicas
+        self.site_base = site_base
+        self._sites = {0}
+        self._live_replicas = live_replicas
+        self._site_program: Optional[Tuple[Any, tuple]] = None
+        self.round_gauges = dict.fromkeys(mesh_step.SITE_ROUND_GAUGES, 0)
         self._next_gid = 0  # host mirror of state.next_gid
         self._frontier_base = 0  # executed-count carried across gid epochs
         self.gid_epochs = 0
+
+    # --- a coordinator at every site ---
+
+    @property
+    def sites_registered(self) -> int:
+        """Sites clients have registered at (gauge)."""
+        return len(self._sites)
+
+    @property
+    def serves_sites(self) -> bool:
+        """Whether the round can have a coordinator at every site: under
+        EPaxos's rule, on one shard, with one key a command."""
+        return self.rule == "epaxos" and self.shard_count == 1 and self.key_width == 1
+
+    def register_site(self, site: int) -> None:
+        """Clients at ``site``.  The first site but 0 makes the round's
+        second program ready (compiled, or loaded, through the persistent
+        cache under a ``precompile`` span) before this returns, and every
+        dispatch from the next one on runs it: same state, same columns, so
+        nothing is rebuilt and a round in flight drains as it was
+        dispatched.  Raises ``ValueError`` for a site that is none of the
+        replicas', or not 0 where the round cannot serve it."""
+        if site in self._sites:
+            return
+        if not 0 <= site < self.num_replicas:
+            raise ValueError(
+                f"clients at site {site}: the sites are the replicas, "
+                f"0 to {self.num_replicas - 1}"
+            )
+        if not self.serves_sites:
+            super().register_site(site)
+        if self._site_program is None:
+            import jax
+
+            from fantoch_tpu.parallel import mesh_step
+
+            # the state's shapes and places, not the state: a round may
+            # hold it (this runs beside the step's thread)
+            state = jax.tree_util.tree_map(
+                lambda leaf: jax.ShapeDtypeStruct(
+                    leaf.shape, leaf.dtype, sharding=leaf.sharding
+                ),
+                self._state,
+            )
+            self._site_program = self._precompile(
+                mesh_step.jit_protocol_step(
+                    self._mesh, live_replicas=self._live_replicas,
+                    sites=self.num_replicas, site_base=self.site_base,
+                ),
+                state=state,
+            )
+        self._sites.add(site)
+        self.resolver = "key_runs"
+
+    def _program(self, S: int = 1):
+        return self._site_program or super()._program(S)
+
+    @property
+    def precompiled_programs(self) -> int:
+        return len(self._programs) + (self._site_program is not None)
 
     # --- the serving round ---
 
@@ -823,6 +943,8 @@ class DeviceDriver(_DriverCore):
                     "pins the epoch (oldest live gid too old to rebase)"
                 )
         self._ensure_seq_window(batch)
+        if self._site_program is not None:
+            batch = _sites_in_turn(batch)
         for i, (dot, cmd) in enumerate(batch):
             row = self._bucket_row(cmd)
             key[i, : len(row)] = row
@@ -856,9 +978,31 @@ class DeviceDriver(_DriverCore):
         # kept; the working set is the pending buffer, then the batch,
         # whose rows past the batch's length are padding
         pend_cap = len(gids) - self.batch_size
-        live = order[
-            (gids[order] >= 0) & resolved[order] & (order < pend_cap + tok[1])
-        ]
+        ours = (gids >= 0) & resolved
+        ours[pend_cap + tok[1]:] = False
+        # valid new rows that missed the fast path took the Synod round
+        self.slow_paths += int(out.slow_paths)
+        tallies = self.round_tallies
+        counts = out.tallies.tolist()
+        for name, count in zip(tallies, counts):
+            tallies[name] += count
+        # the round with a coordinator at every site: its gauge stands
+        # last (kept from the last round that had a component of several
+        # rows: a round of padding between two full ones has none), and
+        # the rows of a key's run its resolver did not cut execute where
+        # the host's Tarjan puts them (keys apart: after the rest is as
+        # good as between)
+        finish = getattr(out, "finish", None)
+        if finish is not None:
+            if counts[-1]:
+                self.round_gauges["scc_rows_max"] = counts[-1]
+            finish = ours & np.asarray(finish)
+            ours &= ~finish
+        live = order[ours[order]]
+        if finish is not None and finish.any():
+            live = np.concatenate(
+                [live, self._finish_order(tok[0], gids, finish, counts[-1])]
+            )
         self.drain_rows_walked += len(live)
         results: List[ExecutorResult] = []
         for gid, is_fast in zip(gids[live].tolist(), fast[live].tolist()):
@@ -870,11 +1014,6 @@ class DeviceDriver(_DriverCore):
             self.executed += 1
             if is_fast:
                 self.fast_paths += 1
-        # valid new rows that missed the fast path took the Synod round
-        self.slow_paths += int(out.slow_paths)
-        tallies = self.round_tallies
-        for name, count in zip(tallies, out.tallies.tolist()):
-            tallies[name] += count
 
         # device pending overflow: rows beyond the pending capacity were
         # dropped by the device (loudly — out.pend_dropped).  Re-register
@@ -895,6 +1034,38 @@ class DeviceDriver(_DriverCore):
                     self.requeued += 1
                     self._requeue.append(entry)
         return results
+
+    def _finish_order(self, out, gids, finish, largest: int) -> np.ndarray:
+        """The working rows ``finish`` marks, in the order the host's
+        Tarjan gives them (``executor/graph/deps_graph.tarjan_order``:
+        components in dependency order, each in dot order), under a
+        ``finish`` span; their committed dependencies are fetched here,
+        in these rounds alone.  What it finds of components joins the
+        round's tallies (the device counted the rows it handed over),
+        ``largest`` being the device's own largest of this round."""
+        from fantoch_tpu.executor.graph.deps_graph import tarjan_order
+
+        with self.stages.span("finish", self._span_round):
+            rows = np.flatnonzero(finish)
+            row_of = {gid: at for at, gid in enumerate(gids[rows].tolist())}
+            # the oracle's processes are 1 .. n: a dot by its site
+            dots = []
+            for gid in row_of:
+                dot = self._cmds[gid][0]
+                site = (dot.source - self.site_base) % self.num_replicas
+                dots.append(Dot(1 + site, dot.sequence))
+            # (a dependency outside the rows executed before them)
+            deps = [
+                sorted({row_of[dep] for dep in row if dep in row_of})
+                for row in np.asarray(out.deps_gid)[rows].tolist()
+            ]
+            order, sizes = tarjan_order(dots, deps, self.num_replicas)
+            several = [size for size in sizes if size > 1]
+            self.round_tallies["scc_rows"] += sum(several)
+            self.round_tallies["scc_count"] += len(several)
+            if several:  # beside the device's own of this round
+                self.round_gauges["scc_rows_max"] = max([largest] + several)
+        return rows[order]
 
 
 class NewtDeviceDriver(_DriverCore):
@@ -1449,6 +1620,9 @@ class _DeviceClientSession:
         # counted apart (gets_replied, get_value_bytes)
         self._reads: set = set()
         self.client_ids: List[ClientId] = []
+        # the next dot of the coordinator at the clients' site (site 0
+        # until the hello says otherwise)
+        self._next_dot = runtime.dot_gen.next_id
         self._flush_needed = asyncio.Event()
 
     def track(self, cmd: Command) -> None:
@@ -1690,7 +1864,7 @@ class _DeviceClientSession:
         tracer = runtime.tracer
         tracing = tracer.enabled
         rifl_sessions = runtime.rifl_sessions
-        next_dot = runtime.dot_gen.next_id
+        next_dot = self._next_dot
         validate, track = self._validate, self.track
         reads = self._reads
         served = self._served
@@ -1787,6 +1961,13 @@ class _DeviceClientSession:
             if not isinstance(hi, ClientHi):
                 raise ProtocolError(f"expected ClientHi, got {hi!r}")
             self.client_ids = hi.client_ids
+            # the site its clients are at coordinates their commands: the
+            # round is made ready for it before the hello is acknowledged,
+            # and a site that cannot be served ends the session here
+            try:
+                self._next_dot = self.runtime.register_site(hi.site)
+            except ValueError as exc:
+                raise ProtocolError(str(exc)) from None
             await self.rw.send(ClientHiAck())
             flusher = self.runtime.spawn(self._flush_loop(), fatal=False)
             try:
@@ -1838,8 +2019,6 @@ class DeviceRuntime:
         trace_file: Optional[str] = None,
         flight_dir: Optional[str] = None,
     ):
-        from fantoch_tpu.core.ids import AtomicIdGen
-
         self.config = config
         self.process_id = process_id
         self.client_addr = client_addr
@@ -1904,6 +2083,7 @@ class DeviceRuntime:
                 shard_count=config.shard_count,
                 monitor_execution_order=monitor_execution_order,
                 mesh=mesh,
+                site_base=process_id,
             )
         # in-flight depth: Config.serving_pipeline_depth, else 1
         # (run/pipeline.py)
@@ -1938,7 +2118,10 @@ class DeviceRuntime:
         # program), with S auto-tuned from the measured per-round
         # dispatch overhead vs in-dispatch time
         self._chain_tuner = ChainAutoTuner(chain_max)
+        # a dot per coordinator: the replica at site ``s`` is process
+        # ``process_id + s``, one sequence a site (site 0: this process)
         self.dot_gen = AtomicIdGen(process_id)
+        self._site_dot_gens = {0: self.dot_gen}
         self.metrics_file = metrics_file
         self.metrics_interval_ms = metrics_interval_ms
         # live telemetry plane (observability/timeseries.py): one writer,
@@ -2172,8 +2355,11 @@ class DeviceRuntime:
             "requeued": d.requeued,
             "fast_paths": d.fast_paths,
             "slow_paths": d.slow_paths,
-            # the dep-commit round's tallies over its executed rows
+            # the dep-commit round's tallies over its executed rows, and
+            # its gauges; the sites clients have registered at
             **d.round_tallies,
+            **d.round_gauges,
+            "sites_registered": d.sites_registered,
             "in_flight": d.in_flight,
             "stable_watermark": d.stable_watermark,
             "queued": len(self._submit_queue),
@@ -2316,7 +2502,7 @@ class DeviceRuntime:
         "queue_capacity", "device_idle_frac", "device_pipeline_depth",
         "dispatch_fill_frac", "serving_chain_len", "ingest_target",
         "ingest_rate_per_s", "loop_lag_hwm_ms", "precompiled_programs",
-        "gc_frozen_objects",
+        "gc_frozen_objects", "sites_registered", "scc_rows_max",
     })
 
     def telemetry_sample(self):
@@ -2392,6 +2578,21 @@ class DeviceRuntime:
             self, Rw(reader, writer, decode_tally=self._decode_tally)
         )
         self.spawn(session.run(), fatal=False)
+
+    def register_site(self, site: int):
+        """The site a hello names (``ClientHi.site``; 0 where it names
+        none): the driver is told (it makes the round with a coordinator
+        at every site ready at the first site but 0, and raises
+        ``ValueError`` for a site it cannot serve), and the caller gets
+        the coordinator's dot generator, ``next_id`` of
+        ``AtomicIdGen(process_id + site)``."""
+        if type(site) is not int:
+            raise ValueError(f"a site is a replica's number, not {site!r}")
+        self.driver.register_site(site)
+        gen = self._site_dot_gens.get(site)
+        if gen is None:
+            gen = self._site_dot_gens[site] = AtomicIdGen(self.process_id + site)
+        return gen.next_id
 
     def room(self) -> Optional[int]:
         """Admission check for sessions: how many more commands the

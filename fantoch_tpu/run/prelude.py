@@ -66,7 +66,15 @@ class ProcessHi:
 
 @dataclass
 class ClientHi:
+    """Client -> server: the logical clients of this connection and the
+    site they are at.  A command's coordinator is the replica at its
+    client's site; a hello that names none is at site 0 (every client
+    attached to replica 0: the deployment a one-coordinator round is).  A
+    device-step server refuses a site that is none of its replicas', or
+    not 0 where its round has one coordinator (run/device_runner.py)."""
+
     client_ids: List[ClientId]
+    site: int = 0
 
 
 @dataclass

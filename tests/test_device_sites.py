@@ -1,0 +1,219 @@
+"""A client's site on the served path (`ClientHi.site`,
+`_DeviceClientSession.run`, `DeviceRuntime.register_site`,
+`DeviceDriver.register_site`): a hello that names none serves as before, on
+the same program; the first site but 0 makes the round with a coordinator at
+every site ready before its hello is acknowledged and the next dispatch runs
+it; a site that cannot be served ends the session before the ack; a dot is a
+coordinator's, one sequence a site.  (A file of its own: `--dist loadfile`
+keeps a file on one worker.)"""
+
+import asyncio
+import pickle
+
+import numpy as np
+import pytest
+
+from fantoch_tpu.core import Command, Config, Dot, KVOp, Rifl
+from fantoch_tpu.observability import device as obs
+from fantoch_tpu.parallel import mesh_step
+from fantoch_tpu.run.device_runner import (
+    CaesarDeviceDriver, DeviceDriver, DeviceRuntime, NewtDeviceDriver, PaxosDeviceDriver,
+    _DriverCore,
+)
+from fantoch_tpu.run.harness import free_port
+from fantoch_tpu.run.prelude import ClientHi, ClientHiAck, Submit, ToClient
+from fantoch_tpu.run.rw import Rw
+
+
+def _put(client, seq, key):
+    return Command.from_single(Rifl(client, seq), 0, key, KVOp.put(f"{client}:{seq}"))
+
+
+def _runtime(protocol="epaxos", n=5, **kwargs):
+    config = Config(n, 1, shard_count=kwargs.pop("shard_count", 1))
+    port = free_port()
+    runtime = DeviceRuntime(config, ("127.0.0.1", port), protocol=protocol, batch_size=16,
+                            key_buckets=64, pending_capacity=16, **kwargs)
+    return runtime, port
+
+
+async def _hello(port, hi):
+    reader, writer = await asyncio.open_connection("127.0.0.1", port)
+    rw = Rw(reader, writer)
+    await rw.send(hi)
+    return rw, writer, await rw.recv()
+
+
+async def _call(rw, cmd):
+    await rw.send(Submit(cmd))
+    reply = await rw.recv()
+    assert isinstance(reply, ToClient) and reply.cmd_result.rifl == cmd.rifl
+    return reply
+
+
+def test_a_hello_takes_a_site_and_one_without_is_at_site_0():
+    assert ClientHi([1, 2]).site == 0 and ClientHi([1], site=3).site == 3
+    # a hello framed by a client that knows no site (its pickle holds no such attribute)
+    old = ClientHi([7])
+    del old.__dict__["site"]
+    assert "site" not in pickle.loads(pickle.dumps(old)).__dict__
+    assert pickle.loads(pickle.dumps(old)).site == 0
+
+
+def test_a_hello_without_a_site_serves_as_before_on_the_same_program():
+    obs.subscribe_recompiles()
+
+    async def go():
+        runtime, port = _runtime()
+        await runtime.start()
+        try:
+            driver = runtime.driver
+            program = driver._program(1)
+            assert driver.precompiled_programs == 1 and driver.sites_registered == 1
+            for client in (1, 2):
+                rw, writer, ack = await _hello(port, ClientHi([client]))
+                assert isinstance(ack, ClientHiAck)
+                await _call(rw, _put(client, 1, "k"))
+                writer.close()
+            assert driver._program(1) is program and driver._site_program is None
+            assert driver.precompiled_programs == 1 and driver.stages.n["precompile"] == 1
+            assert driver.sites_registered == 1 and driver.resolver == "run_position"
+            assert runtime._tallies["sites_registered"] == 1
+            assert runtime._tallies["scc_rows"] == runtime._tallies["finisher_rows"] == 0
+            assert driver.executed == 2 and driver.slow_paths == 0
+        finally:
+            await runtime.stop()
+
+    asyncio.run(go())
+
+
+def test_a_second_sites_hello_makes_the_second_program_ready_before_its_ack():
+    obs.subscribe_recompiles()
+
+    async def go():
+        runtime, port = _runtime()
+        await runtime.start()
+        try:
+            driver = runtime.driver
+            one_site = driver._program(1)
+            rw0, writer0, ack = await _hello(port, ClientHi([1]))
+            await _call(rw0, _put(1, 1, "k"))
+            assert driver._site_program is None and driver.stages.n["precompile"] == 1
+            rw2, writer2, ack = await _hello(port, ClientHi([2], site=2))
+            # the ack came after the program: nothing was dispatched in between
+            assert isinstance(ack, ClientHiAck)
+            assert driver._site_program is not None and driver.stages.n["precompile"] == 2
+            assert driver.precompiled_programs == 2 and driver.sites_registered == 2
+            assert driver._program(1) is driver._site_program is not one_site
+            compiled = obs.recompile_count() + obs.cache_hit_count()
+            rounds = driver.rounds
+            # the next dispatch runs it: the two sites' commands on one key disagree
+            for seq in range(2, 6):
+                await asyncio.gather(_call(rw0, _put(1, seq, "k")), _call(rw2, _put(2, seq, "k")))
+            assert driver.rounds > rounds and driver.round_tallies["resolve_iters"] > 0
+            assert driver.round_tallies["resolve_iters"] == driver.rounds - rounds
+            assert obs.recompile_count() + obs.cache_hit_count() == compiled  # nothing since
+            assert driver.executed == 9 and driver.in_flight == 0
+            assert runtime._tallies["sites_registered"] == 2
+            assert runtime.backend_report()["resolver"] == "key_runs"
+            # a third site: the program is there
+            rw4, writer4, ack = await _hello(port, ClientHi([3], site=4))
+            assert isinstance(ack, ClientHiAck) and driver.stages.n["precompile"] == 2
+            await _call(rw4, _put(3, 1, "k"))
+            assert driver.sites_registered == 3
+            for writer in (writer0, writer2, writer4):
+                writer.close()
+        finally:
+            await runtime.stop()
+        return runtime
+
+    runtime = asyncio.run(go())
+    store = runtime.driver.store
+    assert store.execute("k", KVOp.get(), Rifl(9, 9)) is not None
+
+
+@pytest.mark.parametrize("protocol, kwargs, site, why", [
+    ("epaxos", {}, 5, "the sites are the replicas"),
+    ("epaxos", {}, -1, "the sites are the replicas"),
+    ("epaxos", {}, "1", "a site is a replica's number"),
+    ("epaxos", {}, True, "a site is a replica's number"),
+    ("newt", {}, 1, "one coordinator"),
+    ("caesar", {"n": 7}, 1, "one coordinator"),
+    ("fpaxos", {}, 1, "one coordinator"),
+    ("atlas", {}, 1, "one coordinator"),
+    ("epaxos", {"shard_count": 2}, 1, "one coordinator"),
+    ("epaxos", {"key_width": 2}, 1, "one coordinator"),
+])
+def test_a_site_that_cannot_be_served_is_refused_before_the_ack(protocol, kwargs, site, why, caplog):
+    async def go():
+        runtime, port = _runtime(protocol, **dict(kwargs))
+        await runtime.start()
+        try:
+            with caplog.at_level("WARNING"):
+                _rw, _writer, ack = await _hello(port, ClientHi([1], site=site))
+                assert ack is None  # the connection closed: no ClientHiAck
+                await asyncio.sleep(0.05)
+            assert why in caplog.text
+            assert runtime.failure is None and runtime.driver.sites_registered == 1
+            assert runtime.driver.precompiled_programs == runtime.driver.stages.n["precompile"]
+            # site 0 by name is every driver's, and the server still serves
+            rw, writer, ack = await _hello(port, ClientHi([2], site=0))
+            assert isinstance(ack, ClientHiAck)
+            await _call(rw, _put(2, 1, "k"))
+            writer.close()
+        finally:
+            await runtime.stop()
+
+    asyncio.run(go())
+
+
+def test_dots_are_a_coordinators_and_two_sites_never_collide_in_a_registry():
+    runtime, _port = _runtime()
+    gens = [runtime.register_site(site) for site in (0, 3, 0, 3)]
+    dots = [gen() for gen in gens for _ in range(3)]
+    # one sequence a site, whoever asks: site 0 is the process itself, site 3 process 4
+    assert dots[:3] + dots[6:9] == [Dot(1, seq) for seq in range(1, 7)]
+    assert dots[3:6] + dots[9:] == [Dot(4, seq) for seq in range(1, 7)]
+    assert len(set(dots)) == 12
+    # the registries' key of a dot (`_packed`), row by row and as a column
+    src = np.array([dot.source for dot in dots], np.int32)
+    seq = np.array([dot.sequence for dot in dots], np.int32)
+    packed = _DriverCore._packed_column(src, seq, np.arange(12))
+    assert len(set(packed)) == 12
+    assert packed == [_DriverCore._packed(dot.source, dot.sequence) for dot in dots]
+    # and the round reads the coordinator off the dot: the site program's ring
+    driver = runtime.driver
+    assert driver.site_base == 1 and driver.sites_registered == 2
+    batch = [(dot, _put(1 + at, 1, "k")) for at, dot in enumerate(dots)]
+    assert len(driver.serve([batch])) == 12
+    assert driver.slow_paths > 0 and driver.round_tallies["scc_rows"] > 0
+
+
+@pytest.mark.parametrize("build", [
+    lambda: NewtDeviceDriver(5, batch_size=8, key_buckets=64, pending_capacity=8),
+    lambda: CaesarDeviceDriver(7, batch_size=8, key_buckets=64, pending_capacity=8),
+    lambda: PaxosDeviceDriver(5, batch_size=8, pending_capacity=8),
+    lambda: DeviceDriver(5, rule="atlas", batch_size=8, key_buckets=64, pending_capacity=8),
+    lambda: DeviceDriver(5, batch_size=8, key_buckets=64, pending_capacity=8, key_width=2),
+])
+def test_a_driver_with_one_coordinator_takes_site_0_and_no_other(build):
+    driver = build()
+    driver.register_site(0)
+    with pytest.raises(ValueError, match="one coordinator"):
+        driver.register_site(1)
+    assert driver.sites_registered == 1 and driver.precompiled_programs == 0
+    assert not getattr(driver, "serves_sites", False)
+
+
+def test_the_one_site_program_is_the_round_without_a_sites_argument():
+    """`sites == 1` traces today's program: the jitted round of a driver that
+    no site but 0 registered at is built without the argument, and the second
+    program takes the state and the columns of the first."""
+    driver = DeviceDriver(5, batch_size=8, key_buckets=64, pending_capacity=8)
+    assert "sites" not in driver._step.__wrapped__.keywords
+    driver.register_site(2)
+    one, two = driver._programs, driver._site_program
+    assert not one  # the one-site program was never asked for
+    program, shardings = two
+    assert len(shardings) == len(driver._column_specs())
+    assert len(mesh_step.SITE_ROUND_TALLIES) + len(mesh_step.SITE_ROUND_GAUGES) == 10

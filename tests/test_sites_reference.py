@@ -1,0 +1,362 @@
+"""The round with a coordinator at every site (`parallel/mesh_step.py`
+`protocol_step(sites=n)`, resolved by `ops/graph_resolve.resolve_key_runs` and
+finished by `executor/graph/deps_graph.tarjan_order`) against the plain
+reference `tests/sites_reference.py`, on seeded rounds at small sizes: batch
+64 to 256, 16 to 64 keys, conflict rates 0 / 50 / 100, clients at 1 to 5
+sites, with and without reads, with every replica live and with rows carried
+by rounds under the write quorum.  Round by round: each quorum member's
+report, `fast`, the committed dependencies as sets, what executed, the
+execution order key bucket by key bucket, every component's members
+contiguous and in dot order, the slow paths and the tallies.  Integers: no
+tolerance.
+
+Then the same through `DeviceDriver.serve` (the registry, the drain, the
+finisher on the served path), and, marked `slow`, at the benchmark cell's
+shape for the chip."""
+
+import functools
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+import pytest
+
+from fantoch_tpu.core.command import Command
+from fantoch_tpu.core.ids import Dot, Rifl
+from fantoch_tpu.core.kvs import KVOp
+from fantoch_tpu.executor.graph.deps_graph import tarjan_order
+from fantoch_tpu.parallel import mesh_step
+from fantoch_tpu.run.device_runner import DeviceDriver, _sites_in_turn
+from tests import sites_reference as plain
+
+N = 5
+FAST, WRITE = plain.quorum_sizes(N)
+
+
+def test_the_reference_imports_nothing_of_the_round():
+    with open(plain.__file__) as fh:
+        source = fh.read()
+    imports = [line for line in source.splitlines() if line.startswith(("import ", "from "))]
+    assert imports == [
+        "from __future__ import annotations",
+        "from typing import Dict, FrozenSet, List, NamedTuple, Optional, Set, Tuple",
+    ]
+
+
+@functools.lru_cache(maxsize=None)
+def _mesh():
+    return jax.sharding.Mesh(
+        np.array(jax.devices()[:1]).reshape(1, 1), (mesh_step.REPLICA_AXIS, mesh_step.BATCH_AXIS)
+    )
+
+
+@functools.lru_cache(maxsize=None)
+def _step(live):
+    return mesh_step.jit_protocol_step(_mesh(), live_replicas=live, sites=N, site_base=1)
+
+
+def conflict_commands(rng, fill, keys, rate, sites, read_share, first_seq):
+    """`fill` commands of the conflict-rate generator: key 0 at `rate` %,
+    else the client's own key; a client is at one of `sites` sites."""
+    out = []
+    for at in range(fill):
+        client = int(rng.integers(0, 4 * keys))
+        site = client % sites
+        key = 0 if rng.integers(0, 100) < rate else 1 + client % (keys - 1)
+        out.append(plain.Command(1 + site, first_seq + at, key, bool(rng.random() < read_share), site))
+    return out
+
+
+def per_key(order, commands):
+    """An execution order key by key.  Where a key has writes alone the graph
+    orders every two of its commands (each has an edge to the one that
+    arrived just before it), so its order is one; a read is ordered against
+    the writes it or they depend on only (`locked.rs` keeps the latest read
+    alone), so keys with reads are held to the graph, not to a sequence."""
+    out = {}
+    for dot in order:
+        out.setdefault(commands[dot].key, []).append(dot)
+    return {key: dots for key, dots in out.items() if not any(commands[d].read for d in dots)}
+
+
+class Rounds:
+    """The device round and the reference, fed the same commands."""
+
+    def __init__(self, batch, keys, pending, seed):
+        self.batch, self.keys, self.pending = batch, keys, pending
+        self.state = mesh_step.init_state(
+            _mesh(), N, key_buckets=keys, pending_capacity=pending, key_width=1
+        )
+        self.reference = plain.Reference(N)
+        self.rng = np.random.default_rng(seed)
+        self.sent = 0
+        self.dot_of = {}  # gid -> dot
+        self.commands = {}  # dot -> plain.Command
+        self.slow_paths = self.finished = self.scc_rows = 0
+
+    def commands_for(self, fill, rate, sites, read_share):
+        out = conflict_commands(self.rng, fill, self.keys, rate, sites, read_share, self.sent + 1)
+        self.sent += fill
+        return out
+
+    def round(self, commands, live=N):
+        """One round on both; everything compared; returns the device's output."""
+        batch = self.batch
+        key = np.full(batch, mesh_step.KEY_PAD, np.int32)
+        src, seq = np.zeros(batch, np.int32), np.zeros(batch, np.int32)
+        read = np.zeros(batch, bool)
+        first = int(self.state.next_gid)
+        for i, cmd in enumerate(commands):
+            key[i], src[i], seq[i], read[i] = cmd.key, cmd.src, cmd.seq, cmd.read
+            self.dot_of[first + i] = cmd.dot
+            self.commands[cmd.dot] = cmd
+        want = self.reference.round(commands, live)
+        self.state, out = _step(live)(
+            self.state, jnp.asarray(key), jnp.asarray(src), jnp.asarray(seq), jnp.asarray(read)
+        )
+        gids = np.asarray(out.gids)
+        deps = np.asarray(out.deps_gid)
+        fast, executed = np.asarray(out.fast_path), np.asarray(out.resolved)
+        finish = np.asarray(out.finish)
+        rows = {self.dot_of[int(g)]: w for w, g in enumerate(gids) if int(g) in self.dot_of}
+        assert set(rows) == set(want.verdicts)  # the working set: what was carried, what came
+
+        def dots(columns):
+            return frozenset(self.dot_of[int(g)] for g in columns if g >= 0)
+
+        for dot, verdict in want.verdicts.items():
+            w, cmd = rows[dot], self.commands[dot]
+            # member k of the coordinator's ring stands in columns 2k, 2k + 1;
+            # its report is its own word joined with the coordinator's
+            for k, member in enumerate(plain.fast_quorum(cmd.site, N)):
+                got = dots(deps[w, 2 * k: 2 * k + 2]) | dots(deps[w, :2])
+                assert got == verdict.reports[member], (dot, member, got, verdict)
+            assert dots(deps[w]) == verdict.deps, (dot, verdict)
+            assert bool(fast[w]) == verdict.fast, (dot, verdict)
+            assert bool(executed[w]) == verdict.executed, (dot, verdict)
+            assert not finish[w] or executed[w]
+        # the order: the device's, then what the host's Tarjan makes of the rest
+        ordered = [w for w in np.asarray(out.order).tolist()
+                   if executed[w] and not finish[w] and int(gids[w]) in self.dot_of]
+        left = [w for w in np.flatnonzero(finish).tolist()]
+        if left:
+            at = {int(gids[w]): i for i, w in enumerate(left)}
+            ordered += [left[i] for i in tarjan_order(
+                [Dot(*self.dot_of[int(gids[w])]) for w in left],
+                [sorted({at[int(g)] for g in deps[w] if int(g) in at}) for w in left], N)[0]]
+        order = [self.dot_of[int(gids[w])] for w in ordered]
+        assert sorted(order) == sorted(want.order)
+        assert per_key(order, self.commands) == per_key(want.order, self.commands)
+        # every component contiguous and in dot order, after all it depends on
+        place = {dot: at for at, dot in enumerate(order)}
+        for component in want.components:
+            places = [place[dot] for dot in component]
+            assert places == list(range(places[0], places[0] + len(component))), component
+            for dot in component:
+                for dep in want.verdicts[dot].deps - set(component):
+                    assert dep not in place or place[dep] < places[0], (dot, dep)
+        assert int(out.slow_paths) == want.slow_paths
+        assert int(out.pending) == len(self.reference.carried) and int(out.pend_dropped) == 0
+        tallies = dict(zip(mesh_step.SITE_ROUND_TALLIES + mesh_step.SITE_ROUND_GAUGES,
+                           np.asarray(out.tallies).tolist()))
+        tally = want.tally()
+        assert tallies["deps_committed"] == tally["deps_committed"]
+        assert tallies["finisher_rows"] == len(left)
+        assert tallies["read_rows"] == sum(self.commands[d].read for d in want.order)
+        if not left:  # else the finisher's components join the device's: the driver's sum
+            for name in ("scc_rows", "scc_count", "scc_rows_max"):
+                assert tallies[name] == tally[name], (name, tallies, tally)
+        assert tallies["resolve_iters"] >= 1
+        self.slow_paths += want.slow_paths
+        self.finished += len(left)
+        self.scc_rows += tally["scc_rows"]
+        return out, want
+
+
+@pytest.mark.parametrize("read_share", (0.0, 0.4))
+@pytest.mark.parametrize("sites", (1, 2, 3, 4, 5))
+@pytest.mark.parametrize("rate", (0, 50, 100))
+@pytest.mark.parametrize("batch, keys", [(64, 16), (128, 32), (256, 64)])
+def test_the_round_agrees_with_the_plain_reference(batch, keys, rate, sites, read_share):
+    """Three rounds, the second part-full: every round equal to the reference's."""
+    rounds = Rounds(batch, keys, pending=batch, seed=batch + 7 * sites + rate)
+    for r in range(3):
+        fill = batch if r != 1 else int(rounds.rng.integers(1, batch))
+        rounds.round(rounds.commands_for(fill, rate, sites, read_share))
+    assert not rounds.reference.carried and len(rounds.reference.executed) == rounds.sent
+    if sites == 1:  # one coordinator, one view: nothing to disagree about
+        assert rounds.slow_paths == 0 and rounds.scc_rows == 0 and rounds.finished == 0
+    elif rate:
+        assert rounds.slow_paths > 0 and rounds.scc_rows > 0  # the case is what it says
+    if not read_share and sites >= 1:  # writes alone leave chains: the device cuts them all
+        assert rounds.finished == 0
+
+
+@pytest.mark.parametrize("read_share", (0.0, 0.4))
+@pytest.mark.parametrize("sites", (2, 5))
+@pytest.mark.parametrize("rate", (50, 100))
+def test_rows_carried_by_rounds_under_the_write_quorum_agree_too(rate, sites, read_share):
+    """Two rounds with everyone live, three with two of five (a command that
+    missed the fast path is not accepted, and whatever reaches it waits: rows
+    are carried, and the three that stopped learning report what they knew),
+    three live again (what was carried commits and runs)."""
+    rounds = Rounds(64, 16, pending=192, seed=3 * sites + rate)
+    carried = 0
+    for r, live in enumerate([N] * 2 + [WRITE - 1] * 3 + [N] * 3):
+        fill = 64 if r % 2 == 0 else int(rounds.rng.integers(1, 64))
+        out, _ = rounds.round(rounds.commands_for(fill, rate, sites, read_share), live)
+        carried += int(out.pending)
+    assert carried > 0
+    assert not rounds.reference.carried and len(rounds.reference.executed) == rounds.sent
+
+
+def test_the_references_components_are_the_host_tarjans():
+    """`sites_reference.components_of` against `executor/graph/tarjan.py`
+    (through `tarjan_order`) on seeded graphs: the same components, and an
+    order that puts each after what it depends on."""
+    rng = np.random.default_rng(17)
+    for _ in range(20):
+        size = int(rng.integers(2, 60))
+        dots = [(1 + int(rng.integers(0, N)), at + 1) for at in range(size)]
+        deps = [sorted({int(d) for d in rng.integers(0, size, int(rng.integers(0, 4))) if d != at})
+                for at in range(size)]
+        order, sizes = tarjan_order([Dot(*dot) for dot in dots], deps, N)
+        found = plain.components_of({dots[at]: [dots[d] for d in deps[at]] for at in range(size)})
+        assert sorted(map(len, found)) == sorted(sizes)
+        cut, at = [], 0
+        for component in sorted(found, key=lambda c: order.index(dots.index(c[0]))):
+            cut.append(sorted(dots[row] for row in order[at: at + len(component)]))
+            at += len(component)
+        assert sorted(cut) == sorted(sorted(c) for c in found)
+
+
+# --- through the driver ---------------------------------------------------------
+
+
+def _serve(driver, reference, commands, values):
+    """One round through `DeviceDriver.serve` and the reference; the rifls
+    the driver executed, in its order, beside the reference's dots."""
+    batch = [
+        (Dot(cmd.src, cmd.seq),
+         Command.from_single(Rifl(cmd.src, cmd.seq), 0, str(cmd.key),
+                             KVOp.get() if cmd.read else KVOp.put(values[cmd.dot])))
+        for cmd in commands
+    ]
+    # the driver takes the sites' commands in turn: so is the reference given them
+    by_dot = {cmd.dot: cmd for cmd in commands}
+    want = reference.round([by_dot[dot.source, dot.sequence] for dot, _ in _sites_in_turn(batch)])
+    results = driver.serve([batch])
+    return [(r.rifl.source, r.rifl.sequence) for r in results], want
+
+
+def _driver(batch, keys, **kwargs):
+    return DeviceDriver(N, batch_size=batch, key_buckets=keys, pending_capacity=batch,
+                        mesh=_mesh(), **kwargs)
+
+
+def _bucket_keys(keys):
+    """Key names whose buckets are `0 .. keys - 1`, one each (the driver
+    hashes a key's name to its bucket)."""
+    from fantoch_tpu.utils import key_hash
+
+    names = {}
+    at = 0
+    while len(names) < keys:
+        names.setdefault(key_hash(str(at)) % keys, at)
+        at += 1
+    return names
+
+
+@pytest.mark.parametrize("read_share", (0.0, 0.4))
+@pytest.mark.parametrize("rate", (0, 50, 100))
+def test_the_served_round_executes_in_the_references_order(rate, read_share):
+    """`DeviceDriver.serve` with clients at five sites: what it executes,
+    round by round, is the reference's order key by key; its tallies are the
+    reference's, the finisher's components counted in."""
+    keys = 32
+    names = _bucket_keys(keys)
+    driver = _driver(128, keys)
+    for site in range(N):
+        driver.register_site(site)
+    assert driver.sites_registered == N and driver.resolver == "key_runs"
+    reference = plain.Reference(N)
+    rng = np.random.default_rng(5 + rate)
+    sent = scc_rows = scc_count = 0
+    for r in range(4):
+        fill = 128 if r != 2 else 57
+        commands = [cmd._replace(key=names[cmd.key]) for cmd in
+                    conflict_commands(rng, fill, keys, rate, N, read_share, sent + 1)]
+        sent += fill
+        values = {cmd.dot: f"{cmd.src}:{cmd.seq}" for cmd in commands}
+        got, want = _serve(driver, reference, commands, values)
+        by_dot = {cmd.dot: cmd for cmd in commands}
+        assert per_key(got, by_dot) == per_key(want.order, by_dot)
+        tally = want.tally()
+        scc_rows += tally["scc_rows"]
+        scc_count += tally["scc_count"]
+        if tally["scc_rows_max"]:  # the gauge keeps the last round's that had a component
+            assert driver.round_gauges["scc_rows_max"] == tally["scc_rows_max"]
+    assert driver.executed == sent and driver.in_flight == 0
+    assert driver.round_tallies["scc_rows"] == scc_rows
+    assert driver.round_tallies["scc_count"] == scc_count
+    assert driver.round_tallies["resolve_iters"] == driver.rounds
+    if read_share and rate:
+        assert driver.round_tallies["finisher_rows"] > 0
+        assert driver.stages.n["finish"] > 0
+    if not read_share:
+        assert driver.round_tallies["finisher_rows"] == 0 and driver.stages.n["finish"] == 0
+
+
+@pytest.mark.slow
+def test_two_hundred_rounds_at_the_cells_shape_agree_with_the_reference():
+    """On the chip, by hand, outside pytest (`tests/conftest.py` holds pytest
+    to the CPU): `chiprun -- python3 -c "from tests.test_sites_reference import
+    test_two_hundred_rounds_at_the_cells_shape_agree_with_the_reference as t;
+    t()"`; under pytest (`-m slow`) it runs on the CPU, two minutes.  200
+    rounds at the shape of `epaxos_n5_1m_5site.conflict50_sat` (n=5,
+    1,048,576 buckets, batch and pending 4096) of that cell's traffic
+    (conflict rate 50, 8192 clients over five sites, one key a command,
+    writes) through `DeviceDriver.serve`: the execution order compared with
+    the reference's key by key, the components' tallies round by round."""
+    buckets, batch, clients = 1_048_576, 4096, 8192
+    from fantoch_tpu.utils import key_hash
+
+    driver = DeviceDriver(N, batch_size=batch, key_buckets=buckets, pending_capacity=batch)
+    for site in range(N):
+        driver.register_site(site)
+    reference = plain.Reference(N)
+    rng = np.random.default_rng(46)
+    seqs = [0] * N
+    next_of = {}  # a client's writes so far
+    scc_rows = executed = 0
+    for r in range(200):
+        fill = batch if r % 7 else int(rng.integers(1, batch))
+        commands, batch_in = [], []
+        for client in rng.permutation(clients)[:fill].tolist():
+            client += 1
+            site = (client - 1) % N  # kv_sites: process p holds clients 1 + p, 6 + p, ...
+            seqs[site] += 1
+            name = "0" if rng.integers(0, 100) < 50 else str(client)
+            cmd = plain.Command(1 + site, seqs[site], key_hash(name) % buckets, False, site)
+            commands.append(cmd)
+            next_of[client] = next_of.get(client, 0) + 1
+            batch_in.append((Dot(cmd.src, cmd.seq), Command.from_single(
+                Rifl(client, next_of[client]), 0, name, KVOp.put(f"{client}:{next_of[client]}"))))
+        by_dot = {cmd.dot: cmd for cmd in commands}
+        want = reference.round([by_dot[d.source, d.sequence] for d, _ in _sites_in_turn(batch_in)])
+        rifl_of = {(c.rifl.source, c.rifl.sequence): d for d, c in batch_in}
+        got = [rifl_of[r_.rifl.source, r_.rifl.sequence] for r_ in driver.serve([batch_in])]
+        got = [(d.source, d.sequence) for d in got]
+        assert per_key(got, by_dot) == per_key(want.order, by_dot), r
+        assert len(got) == len(want.order) == fill
+        tally = want.tally()
+        scc_rows += tally["scc_rows"]
+        executed += fill
+        if tally["scc_rows_max"]:
+            assert driver.round_gauges["scc_rows_max"] == tally["scc_rows_max"], r
+    assert driver.round_tallies["scc_rows"] == scc_rows and driver.executed == executed
+    assert driver.round_tallies["finisher_rows"] == 0
+    # key 0 is half the rows; taken in turn, nearly every one of them finds its quorum split
+    assert 0.4 * executed < driver.slow_paths < 0.55 * executed
+    print(f"200 rounds, {executed} commands, scc_rows {scc_rows}, "
+          f"slow_paths {driver.slow_paths}, on {jax.default_backend()}")
