@@ -53,6 +53,7 @@ from functools import partial
 from itertools import zip_longest
 from time import monotonic, monotonic_ns, thread_time_ns
 from typing import Any, Dict, List, Optional, Sequence, Tuple
+from zlib import crc32
 
 import numpy as np
 
@@ -88,87 +89,106 @@ from fantoch_tpu.run.prelude import (
     ToClient,
 )
 from fantoch_tpu.run.rw import ProtocolError, Rw, joined_reply_frame, partial_reply_frame
-from fantoch_tpu.utils import key_hash, logger
+from fantoch_tpu.utils import logger
 
 Address = Tuple[str, int]
 
 
-_BUCKET_CACHE_MAX = 1 << 18  # bound the string->bucket memo (~25 MB)
+_HASH_MIX = 2654435761  # Knuth's multiplier, 2**32 / phi
+_HASH_MASK = 0xFFFFFFFF
+
+
+def _bucket(sid: ShardId, k: str, key_buckets: int, shard_count: int) -> int:
+    """One key's bucket: ``h`` of its shard's ``key_buckets //
+    shard_count``, and on several shards bucket ``b`` belongs to shard
+    ``b % shard_count`` (the sharded-key-axis contract of
+    mesh_step.protocol_step).  ``h`` is the server's own business (which
+    unrelated keys share a clock entry), not the shard rule
+    (``utils.key_hash``): CRC-32 of the key's bytes in C, the same in
+    every process, spread by the multiplier and reduced by its HIGH bits
+    (CRC is linear, and its low bits over decimal strings fill a quarter
+    fewer buckets than a random function).  ``_key_column`` spells the
+    same line in its loop."""
+    per_shard = key_buckets // shard_count
+    h = ((crc32(k.encode()) * _HASH_MIX & _HASH_MASK) * per_shard) >> 32
+    return h if shard_count == 1 else sid + shard_count * h
 
 
 def _buckets(
-    cmd: Command,
-    shard_id: ShardId,
-    key_buckets: int,
-    shard_count: int = 1,
-    cache: Optional[Dict] = None,
+    cmd: Command, shard_id: ShardId, key_buckets: int, shard_count: int = 1
 ) -> List[int]:
-    """Distinct key buckets for one command — the single definition shared
-    by the driver's row builder and the session-boundary validator, so the
-    two can never drift (colliding keys dedup, which only coarsens
-    conflicts).
+    """Distinct key buckets for one command, ascending: the plain
+    definition, any shape (colliding keys dedup, which only coarsens
+    conflicts).  The session-boundary validator decides by it for the
+    rare command that fails ``_admit``'s quick test, and the driver's
+    ``_key_column`` is held to it row by row (tests/test_key_column.py).
 
-    ``cache`` memoizes the per-key FNV hash->bucket map (workloads repeat
-    keys heavily — the hot-key half of the north-star workload is ONE
-    key); it is cleared wholesale past ``_BUCKET_CACHE_MAX`` entries so a
-    long-running server's key churn cannot grow it unboundedly.
-
-    Sharded (shard_count > 1): buckets span EVERY shard the command
-    touches, and bucket ``b`` encodes its owner as ``b % shard_count``
-    (the sharded-key-axis contract of mesh_step.protocol_step); the
-    ``shard_id`` argument is ignored — the unified mesh orders the whole
-    command."""
-    if cache is not None and len(cache) > _BUCKET_CACHE_MAX:
-        cache.clear()
-    single = cmd.single_key()
-    if single is not None:
-        # one key: nothing to dedup or sort
-        sid, k = single
-        if shard_count == 1 and sid != shard_id:
-            return []
-        return [_bucket(sid, k, key_buckets, shard_count, cache)]
+    One shard: the keys the command has on ``shard_id``.  Sharded
+    (shard_count > 1): buckets span EVERY shard the command touches and
+    the ``shard_id`` argument is ignored — the unified mesh orders the
+    whole command."""
     if shard_count == 1:
-        return sorted(
-            {_bucket(shard_id, k, key_buckets, 1, cache) for k in cmd.keys(shard_id)}
-        )
+        return sorted({_bucket(shard_id, k, key_buckets, 1) for k in cmd.keys(shard_id)})
     return sorted(
-        {_bucket(sid, k, key_buckets, shard_count, cache) for sid, k in cmd.all_keys()}
+        {_bucket(sid, k, key_buckets, shard_count) for sid, k in cmd.all_keys()}
     )
 
 
-def _bucket(
-    sid: ShardId, k: str, key_buckets: int, shard_count: int, cache: Optional[Dict]
-) -> int:
-    """One key's bucket, through the memo where there is one (keyed by
-    the key alone on one shard, by ``(shard, key)`` on several)."""
-    ck = k if shard_count == 1 else (sid, k)
-    b = None if cache is None else cache.get(ck)
-    if b is None:
+def _key_column(
+    batch, key_rows, shard_id: ShardId, key_buckets: int, shard_count: int = 1
+) -> None:
+    """One round's key column: row ``i`` of ``key_rows`` (``int32[>=
+    len(batch), key_width]``, handed over filled with ``KEY_PAD``) takes
+    ``_buckets`` of ``batch[i]``'s command (device key-row contract: a
+    row's buckets ascend and never repeat).  One pass: a command's
+    buckets are read off its own ops onto the round's one flat list,
+    which becomes the column by one conversion.  What a command shows
+    decides its branch: one key is its bucket, two are ordered by one
+    comparison (the pad where both fell in one bucket), three or more
+    are sorted and deduplicated.  A command with no bucket, or more than
+    the key width, is the caller's fault (the session boundary admits
+    neither) and asserts, once a round: a row that fits adds
+    ``key_width`` entries, and a row of pads alone starts with one."""
+    from fantoch_tpu.parallel.mesh_step import KEY_PAD
+
+    width = key_rows.shape[1]
+    per_shard = key_buckets // shard_count
+    pads = [[KEY_PAD] * (width - n) for n in range(width + 1)]
+    flat: List[int] = []
+    for _dot, cmd in batch:
         if shard_count == 1:
-            b = key_hash(k) % key_buckets
+            row = [
+                ((crc32(k.encode()) * _HASH_MIX & _HASH_MASK) * per_shard) >> 32
+                for k in cmd._shard_to_ops.get(shard_id, ())
+            ]
         else:
-            b = sid + shard_count * (key_hash(k) % (key_buckets // shard_count))
-        if cache is not None:
-            cache[ck] = b
-    return b
-
-
-def _bucket_row(
-    cmd: Command,
-    shard_id: ShardId,
-    key_buckets: int,
-    key_width: int,
-    shard_count: int = 1,
-    cache: Optional[Dict] = None,
-):
-    """Key-bucket row for one command (device key-row contract: a row must
-    not repeat a bucket)."""
-    buckets = _buckets(cmd, shard_id, key_buckets, shard_count, cache)
-    assert 1 <= len(buckets) <= key_width, (
-        f"command touches {len(buckets)} key buckets but the device state "
-        f"was initialized with key_width={key_width}"
+            row = [
+                sid + shard_count
+                * (((crc32(k.encode()) * _HASH_MIX & _HASH_MASK) * per_shard) >> 32)
+                for sid, ops in cmd._shard_to_ops.items()
+                for k in ops
+            ]
+        n = len(row)
+        if n == 2:
+            a, b = row
+            if a > b:
+                row = (b, a)
+            elif a == b:
+                row = (a,)
+                n = 1
+        elif n > 2:
+            row = sorted(set(row))
+            n = len(row)
+        flat += row
+        if n < width:
+            flat += pads[n]
+    column = key_rows[: len(batch)]
+    assert len(flat) == column.size, (
+        "a command touches more key buckets than the device state was "
+        f"initialized with (key_width={width})"
     )
-    return buckets
+    column[:] = np.array(flat, dtype=np.int32).reshape(column.shape)
+    assert (column[:, 0] != KEY_PAD).all(), "a command touches no key bucket"
 
 
 def _sites_in_turn(batch):
@@ -244,7 +264,6 @@ class _DriverCore(PipelineCore):
         # commands in flight: registered at step entry, dropped at execution
         self._cmds: Dict[int, Tuple[Dot, Command]] = {}
         self._requeue: List[Tuple[Dot, Command]] = []
-        self._bucket_cache: Dict = {}  # key -> bucket memo (see _buckets)
         self._seq_base = 0  # device seq column = dot.sequence - seq_base
         self.seq_epochs = 0  # window advances (observability)
         self.store = KVStore(monitor_execution_order)
@@ -453,12 +472,8 @@ class _DriverCore(PipelineCore):
         """Fill one round's fixed-size key/src/seq columns in place and
         register each command under its packed (source, window sequence)
         — the caller guarantees the sequence window already fits."""
+        _key_column(batch, key_rows, self.shard_id, self.key_buckets, self.shard_count)
         for i, (dot, cmd) in enumerate(batch):
-            buckets = _bucket_row(
-                cmd, self.shard_id, self.key_buckets, self.key_width,
-                self.shard_count, cache=self._bucket_cache,
-            )
-            key_rows[i, : len(buckets)] = buckets
             src_row[i] = dot.source
             seq_row[i] = self._device_seq(dot)
             self._cmds[self._packed(dot.source, seq_row[i])] = (dot, cmd)
@@ -693,11 +708,14 @@ class DeviceDriver(_DriverCore):
         machine is control-plane: string keys, tiny values — it stays on
         the host by design, fantoch/src/kvs.rs).
 
-    Key hashing: string keys map to ``key_buckets`` conflict buckets.
-    Bucket collisions create *false* dependencies — extra ordering, never
-    missed ordering — so correctness is preserved and only parallelism is
-    lost (same argument as the reference's worker-partitioned KeyDeps,
-    which also orders by hash partition).
+    Key hashing: string keys map to ``key_buckets`` conflict buckets
+    (``_bucket``: a C hash of the key's bytes, the server's own and not
+    the shard rule's; a round's key column is made from its commands'
+    ops in one pass, ``_key_column``, and nothing is remembered between
+    rounds).  Bucket collisions create *false* dependencies — extra
+    ordering, never missed ordering — so correctness is preserved and
+    only parallelism is lost (same argument as the reference's
+    worker-partitioned KeyDeps, which also orders by hash partition).
     """
 
     def __init__(
@@ -820,12 +838,6 @@ class DeviceDriver(_DriverCore):
 
     # --- the serving round ---
 
-    def _bucket_row(self, cmd: Command) -> List[int]:
-        return _bucket_row(
-            cmd, self.shard_id, self.key_buckets, self.key_width,
-            self.shard_count, self._bucket_cache,
-        )
-
     def _column_specs(self):
         """The dep-commit round's columns: the key/src/seq columns and
         which commands only read."""
@@ -945,9 +957,8 @@ class DeviceDriver(_DriverCore):
         self._ensure_seq_window(batch)
         if self._site_program is not None:
             batch = _sites_in_turn(batch)
+        _key_column(batch, key, self.shard_id, self.key_buckets, self.shard_count)
         for i, (dot, cmd) in enumerate(batch):
-            row = self._bucket_row(cmd)
-            key[i, : len(row)] = row
             src[i] = dot.source
             seq[i] = self._device_seq(dot)
             read[i] = cmd.read_only
@@ -1801,9 +1812,12 @@ class _DeviceClientSession:
         self._flush_needed.set()
 
     def _validate(self, cmd: Command) -> Optional[str]:
-        """The session-boundary twin of the driver's `_bucket_row`
-        contract; returns the rejection reason for commands the compiled
-        device state cannot carry."""
+        """What the driver's key column asserts (``_key_column``: at
+        least one bucket, no more than the key width, every shard the
+        server's), decided at the session boundary for the command that
+        fails ``_admit``'s quick test: returns the rejection reason for
+        commands the compiled device state cannot carry, by the plain
+        definition of a command's buckets (``_buckets``)."""
         driver = self.runtime.driver
         # sharded: a shard id outside the compiled range would alias
         # another shard's buckets on-device (safe_key clamping) — reject
@@ -1821,8 +1835,7 @@ class _DeviceClientSession:
                 "device server"
             )
         buckets = _buckets(
-            cmd, driver.shard_id, driver.key_buckets, driver.shard_count,
-            driver._bucket_cache,
+            cmd, driver.shard_id, driver.key_buckets, driver.shard_count
         )
         if not buckets:
             return "command touches no keys"

@@ -35,7 +35,7 @@ def distinct_keys(count):
     the reference is a bucket of the device."""
     names, taken = [], set()
     for number in range(10 * BUCKETS):
-        bucket = _bucket(0, f"k{number}", BUCKETS, 1, None)
+        bucket = _bucket(0, f"k{number}", BUCKETS, 1)
         if bucket not in taken:
             taken.add(bucket)
             names.append(f"k{number}")
@@ -160,7 +160,7 @@ class Pair:
         # every replica's clock on every key, the stale replicas' too
         table = np.asarray(driver._state.key_clock)
         keys = sorted(reference.executed)
-        buckets = [_bucket(0, key, BUCKETS, 1, None) for key in keys]
+        buckets = [_bucket(0, key, BUCKETS, 1) for key in keys]
         assert table[:, buckets].tolist() == [[clock.get(key, 0) for key in keys]
                                               for clock in reference.clock]
         assert table.sum() == table[:, buckets].sum()  # and nothing anywhere else

@@ -687,7 +687,7 @@ def test_caesar_driver_degraded_requeue_recovery():
     import jax
     import jax.numpy as jnp
 
-    from fantoch_tpu.utils import key_hash
+    from fantoch_tpu.run.device_runner import _bucket
 
     d = CaesarDeviceDriver(
         4, batch_size=8, key_buckets=64, pending_capacity=4,
@@ -709,7 +709,7 @@ def test_caesar_driver_degraded_requeue_recovery():
     # stagger replica 0's hot-bucket ceiling: the next proposals diverge
     # across the fast quorum -> retry path; with live=1 < write quorum
     # the retry cannot commit, so everything carries
-    bucket = key_hash("hot") % 64
+    bucket = _bucket(0, "hot", 64, 1)
     kc = np.array(d._state.key_clock)
     kc[0, bucket] += 7
     d._state = d._state._replace(
@@ -1048,8 +1048,8 @@ def test_device_runtime_survives_bad_client():
     from fantoch_tpu.run.harness import free_port
     from fantoch_tpu.run.client_runner import run_clients
     from fantoch_tpu.run.prelude import ClientHi, ClientHiAck, Submit, ToClient
+    from fantoch_tpu.run.device_runner import _bucket
     from fantoch_tpu.run.rw import Rw
-    from fantoch_tpu.utils import key_hash
 
     key_buckets = 64
     # two keys guaranteed to land in distinct buckets (over-wide for kw=1)
@@ -1057,7 +1057,7 @@ def test_device_runtime_survives_bad_client():
     key_b = next(
         k
         for k in (f"b{i}" for i in range(1000))
-        if key_hash(k) % key_buckets != key_hash(key_a) % key_buckets
+        if _bucket(0, k, key_buckets, 1) != _bucket(0, key_a, key_buckets, 1)
     )
 
     async def go():
@@ -1776,11 +1776,11 @@ def test_overflow_requeues_what_the_walk_requeued_and_scans_only_then(protocol):
     assert len(_step_both(real, oracle, range(1, 5), own=False)) == 4
     if protocol == "caesar":
         # stagger replica 0's ceiling on the hot bucket: proposals diverge
-        from fantoch_tpu.utils import key_hash
+        from fantoch_tpu.run.device_runner import _bucket
 
         for d in (real, oracle):
             kc = np.array(d._state.key_clock)
-            kc[0, key_hash("hot") % 64] += 7
+            kc[0, _bucket(0, "hot", 64, 1)] += 7
             d._state = d._state._replace(key_clock=jax.device_put(
                 jax.numpy.asarray(kc), d._state.key_clock.sharding))
     _degrade(protocol, real, oracle)
@@ -2683,13 +2683,12 @@ def _one_key_shapes(shard_count):
 def _several_key_shapes(shard_count, key_buckets=64):
     """Commands of several keys: what the server takes as it stands, and
     every way ``_validate`` has of refusing one."""
-    from fantoch_tpu.utils import key_hash
+    from fantoch_tpu.run.device_runner import _bucket
 
     put, get = KVOp.put("v"), KVOp.get()
-    per_shard = key_buckets // shard_count
 
-    def bucket(key):
-        return key_hash(key) % per_shard
+    def bucket(key):  # every key of these names is on shard 0
+        return _bucket(0, key, key_buckets, shard_count)
 
     names = [f"c{i}" for i in range(200)]
     apart = []  # three keys in three buckets, then one that shares the first's

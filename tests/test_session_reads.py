@@ -20,9 +20,8 @@ if tuple(int(x) for x in jax.__version__.split(".")[:2]) < (0, 5):
 
 from fantoch_tpu.core import Command, Config, KVOp, Rifl
 from fantoch_tpu.run import rw
-from fantoch_tpu.run.device_runner import DeviceRuntime, ProtocolError, _DeviceClientSession
+from fantoch_tpu.run.device_runner import DeviceRuntime, ProtocolError, _bucket, _DeviceClientSession
 from fantoch_tpu.run.prelude import ClientHi, ClientHiAck, Overloaded, Register, Submit, ToClient
-from fantoch_tpu.utils import key_hash
 
 KEY_BUCKETS = 64
 VALUE = "v" * 100  # the cells' payload
@@ -434,7 +433,7 @@ def test_a_read_that_crosses_the_rings_bound_sheds_the_commands_past_it():
 def test_a_rejected_command_in_a_read_does_not_stop_the_ones_after_it():
     key_b = next(
         k for k in (f"b{i}" for i in range(1000))
-        if key_hash(k) % KEY_BUCKETS != key_hash("a") % KEY_BUCKETS
+        if _bucket(0, k, KEY_BUCKETS, 1) != _bucket(0, "a", KEY_BUCKETS, 1)
     )
 
     async def go():

@@ -26,7 +26,7 @@ from fantoch_tpu.core.ids import Dot, Rifl
 from fantoch_tpu.core.kvs import KVOp
 from fantoch_tpu.executor.graph.deps_graph import tarjan_order
 from fantoch_tpu.parallel import mesh_step
-from fantoch_tpu.run.device_runner import DeviceDriver, _sites_in_turn
+from fantoch_tpu.run.device_runner import DeviceDriver, _bucket, _sites_in_turn
 from tests import sites_reference as plain
 
 N = 5
@@ -257,12 +257,10 @@ def _driver(batch, keys, **kwargs):
 def _bucket_keys(keys):
     """Key names whose buckets are `0 .. keys - 1`, one each (the driver
     hashes a key's name to its bucket)."""
-    from fantoch_tpu.utils import key_hash
-
     names = {}
     at = 0
     while len(names) < keys:
-        names.setdefault(key_hash(str(at)) % keys, at)
+        names.setdefault(_bucket(0, str(at), keys, 1), at)
         at += 1
     return names
 
@@ -319,8 +317,6 @@ def test_two_hundred_rounds_at_the_cells_shape_agree_with_the_reference():
     writes) through `DeviceDriver.serve`: the execution order compared with
     the reference's key by key, the components' tallies round by round."""
     buckets, batch, clients = 1_048_576, 4096, 8192
-    from fantoch_tpu.utils import key_hash
-
     driver = DeviceDriver(N, batch_size=batch, key_buckets=buckets, pending_capacity=batch)
     for site in range(N):
         driver.register_site(site)
@@ -337,7 +333,7 @@ def test_two_hundred_rounds_at_the_cells_shape_agree_with_the_reference():
             site = (client - 1) % N  # kv_sites: process p holds clients 1 + p, 6 + p, ...
             seqs[site] += 1
             name = "0" if rng.integers(0, 100) < 50 else str(client)
-            cmd = plain.Command(1 + site, seqs[site], key_hash(name) % buckets, False, site)
+            cmd = plain.Command(1 + site, seqs[site], _bucket(0, name, buckets, 1), False, site)
             commands.append(cmd)
             next_of[client] = next_of.get(client, 0) + 1
             batch_in.append((Dot(cmd.src, cmd.seq), Command.from_single(
