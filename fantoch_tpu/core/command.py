@@ -132,8 +132,7 @@ class Command:
         command — and the dominant shape is a single key with a single op,
         which skips the genexpr entirely.
         """
-        from fantoch_tpu.executor.base import ExecutorResult
-
+        ExecutorResult = _ExecutorResult or _bind_executor_result()
         rifl = self._rifl
         out = []
         for key, key_ops in self._shard_to_ops.get(shard_id, {}).items():
@@ -189,6 +188,20 @@ class Command:
     def __repr__(self) -> str:
         keys = {s: sorted(ops) for s, ops in self._shard_to_ops.items()}
         return f"Command({self._rifl}, {keys})"
+
+
+# ``Command.execute``'s result class: executor/base.py is imported by a
+# package that imports this module, so it is bound at the first execute and
+# not by an ``import`` statement every call
+_ExecutorResult = None
+
+
+def _bind_executor_result():
+    global _ExecutorResult
+    from fantoch_tpu.executor.base import ExecutorResult
+
+    _ExecutorResult = ExecutorResult
+    return ExecutorResult
 
 
 _KIND_CODE = {kind: code for code, kind in enumerate(KINDS)}

@@ -50,7 +50,7 @@ import os
 import sys
 from collections import defaultdict
 from functools import partial
-from itertools import zip_longest
+from itertools import repeat, zip_longest
 from time import monotonic, monotonic_ns, thread_time_ns
 from typing import Any, Dict, List, Optional, Sequence, Tuple
 from zlib import crc32
@@ -299,6 +299,14 @@ class _DriverCore(PipelineCore):
         """Commands registered but not yet executed (device pending)."""
         return len(self._cmds)
 
+    @property
+    def executed_in_pass(self) -> int:
+        """Of ``executed``, the commands the store's one pass applied by
+        its one-op spelling (``_execute_rows``): all of them, unless the
+        store has a monitor or a digest, or a method the pass spells out
+        has been replaced."""
+        return self.store.applied_in_pass
+
     def _pipeline_flush_needed(self, batch) -> bool:
         """True when the upcoming dispatch may trigger a rebase that
         must not happen with rounds in flight.  The dot drivers all
@@ -495,14 +503,42 @@ class _DriverCore(PipelineCore):
         one and skip).  Only the executed rows are visited: the mask
         picks them out of the working set, device order kept."""
         live = order[executed[order]]
-        self.drain_rows_walked += len(live)
+        return self._execute_rows(self._packed_column(work_src, work_seq, live))
+
+    def _execute_rows(self, keys: List[int], fast=None) -> List[ExecutorResult]:
+        """Pop a round's executed rows from the registry as a column
+        (``keys``: their registry keys, device order kept; a row
+        registered by no one is padding and drops out) and apply their
+        commands to the KVStore in that order: in the store's one pass
+        (``KVStore.execute_commands``) while what the pass spells out is
+        what ``_execute_entry`` would do, a command at a time through
+        ``_execute_entry`` otherwise.  The tallies move once a round;
+        ``fast`` (the dependency rounds') marks the rows that took the
+        fast path, counted among those the registry held."""
+        entries = list(map(self._cmds.pop, keys, repeat(None)))
+        cmds = [entry[1] for entry in entries if entry is not None]
+        self.drain_rows_walked += len(keys)
+        self.executed += len(cmds)
+        if fast is not None:
+            if len(cmds) == len(keys):
+                self.fast_paths += int(np.count_nonzero(fast))
+            else:
+                self.fast_paths += sum(
+                    1
+                    for entry, is_fast in zip(entries, fast.tolist())
+                    if is_fast and entry is not None
+                )
+        store = self.store
+        if (
+            getattr(self._execute_entry, "__func__", None) is _EXECUTE_ENTRY
+            and store.plain
+        ):
+            return store.execute_commands(
+                cmds, self.shard_id if self.shard_count == 1 else None
+            )
         results: List[ExecutorResult] = []
-        for packed in self._packed_column(work_src, work_seq, live):
-            entry = self._cmds.pop(packed, None)
-            if entry is None:
-                continue  # pad row
-            results.extend(self._execute_entry(entry[1]))
-            self.executed += 1
+        for cmd in cmds:
+            results.extend(self._execute_entry(cmd))
         return results
 
     def _registered_rows(self, rows, work_src, work_seq) -> List[int]:
@@ -691,6 +727,10 @@ class _DriverCore(PipelineCore):
             for entry in self._cmds.values()
             for dot in (entry[0],)
         }
+
+
+# the method ``_execute_rows`` takes the store's pass in place of, by name
+_EXECUTE_ENTRY = _DriverCore._execute_entry
 
 
 class DeviceDriver(_DriverCore):
@@ -1014,17 +1054,7 @@ class DeviceDriver(_DriverCore):
             live = np.concatenate(
                 [live, self._finish_order(tok[0], gids, finish, counts[-1])]
             )
-        self.drain_rows_walked += len(live)
-        results: List[ExecutorResult] = []
-        for gid, is_fast in zip(gids[live].tolist(), fast[live].tolist()):
-            entry = self._cmds.pop(gid, None)
-            if entry is None:
-                continue  # padding row (registered by no one)
-            _dot, cmd = entry
-            results.extend(self._execute_entry(cmd))
-            self.executed += 1
-            if is_fast:
-                self.fast_paths += 1
+        results = self._execute_rows(gids[live].tolist(), fast[live])
 
         # device pending overflow: rows beyond the pending capacity were
         # dropped by the device (loudly — out.pend_dropped).  Re-register
@@ -2364,6 +2394,7 @@ class DeviceRuntime:
             "replied": self.replied,
             "rounds": d.rounds,
             "executed": d.executed,
+            "executed_in_pass": d.executed_in_pass,
             "drain_rows_walked": d.drain_rows_walked,
             "requeued": d.requeued,
             "fast_paths": d.fast_paths,

@@ -42,3 +42,75 @@ def test_serve_is_that_many_steps_in_order(protocol, overlap, length):
     assert served.device_counters()["serving_chain_len"] == fused
     for key in stepped.store.monitor.keys():
         assert served.store.monitor.get_order(key) == stepped.store.monitor.get_order(key)
+
+
+# --- a round's executed commands applied in one pass (PR 48) ---
+
+
+def _seeded_batches(protocol, seed, batch_size):
+    """Rounds of one- and two-key commands over a few keys (two shards where
+    the driver has them), full and part-full: ``(dot, command)`` lists."""
+    import random
+
+    from fantoch_tpu.core import Command, Dot, KVOp, Rifl
+    from fantoch_tpu.utils import key_hash
+
+    rng = random.Random(seed)
+    shards = 2 if protocol in ("epaxos", "newt") else 1
+    batches, seq = [], 0
+    for fill in (batch_size, 5, batch_size, 1, 3):
+        batch = []
+        for _ in range(fill):
+            seq += 1
+            read = rng.random() < 0.4
+            by_shard = {}
+            for key in rng.sample([f"k{i}" for i in range(6)], rng.choice((1, 2))):
+                op = KVOp.get() if read else (
+                    KVOp.delete() if rng.random() < 0.2 else KVOp.put(f"v{seq}"))
+                by_shard.setdefault(key_hash(key) % shards, {})[key] = (op,)
+            batch.append((Dot(1, seq), Command(Rifl(1, seq), by_shard)))
+        batches.append(batch)
+    return batches
+
+
+class _PerCommand:
+    """Mixed in ahead of a driver: a method of its own where the pass spells
+    out ``_execute_entry``, so its drains run the per-command loop."""
+
+    def _execute_entry(self, cmd):
+        return super()._execute_entry(cmd)
+
+
+@pytest.mark.parametrize("protocol", DRAIN_DRIVERS)
+def test_serve_applies_a_round_in_one_pass_as_the_per_command_loop_does(protocol):
+    """``serve`` of seeded batches, two-key and part-full rounds among them:
+    the results and the store are those of replaying the returned order
+    through ``Command.execute``, and the tallies read what a twin that runs
+    the per-command loop counted."""
+    from fantoch_tpu.core.kvs import KVStore
+
+    cls, _walk, n, extra = DRAIN_DRIVERS[protocol]
+    shards = {"shard_count": 2} if protocol in ("epaxos", "newt") else {}
+    kw = {"batch_size": 8, "key_buckets": 64, **extra, **shards}
+    if protocol != "fpaxos":  # the slot log takes a command of any width
+        kw["key_width"] = 2
+    passed, looped = cls(n, **kw), type("Looped", (_PerCommand, cls), {})(n, **kw)
+    batches = _seeded_batches(protocol, 48, 8)
+    cmds = {cmd.rifl: cmd for batch in batches for _dot, cmd in batch}
+    got, want = [], []
+    for at in range(0, len(batches), 2):
+        got += passed.serve(batches[at: at + 2], overlap=True)
+        want += looped.serve(batches[at: at + 2], overlap=True)
+    got += passed.flush_pipeline()
+    want += looped.flush_pipeline()
+    assert got == want and passed.store._store == looped.store._store
+    for tally in ("executed", "fast_paths", "slow_paths", "drain_rows_walked", "rounds"):
+        assert getattr(passed, tally) == getattr(looped, tally), tally
+    assert passed.executed == passed.executed_in_pass == len(cmds) and passed.in_flight == 0
+    assert looped.executed_in_pass == 0
+    # the returned order, replayed through the plain definition
+    replay, order = KVStore(), list(dict.fromkeys(r.rifl for r in got))
+    assert len(order) == len(cmds)
+    assert got == [r for rifl in order for shard in cmds[rifl].shards()
+                   for r in cmds[rifl].execute(shard, replay)]
+    assert replay._store == passed.store._store
