@@ -459,20 +459,44 @@ class GeneralResolution(NamedTuple):
     rank: jax.Array  # int32[B]
     leader: jax.Array  # int32[B]
     stuck: jax.Array  # bool[B] — not resolved and not missing-blocked:
-    # cycles the device pass could not collapse; host oracle finishes them.
+    # cycles the device pass could not collapse (or, from the components
+    # pass, rows its residual could not hold); host oracle finishes them.
+    # the components pass alone: the passes its two loops made
+    iters: jax.Array | None = None  # int32[]
 
 
-@functools.partial(jax.jit, static_argnames=("max_iters",))
+def components_residual(batch: int) -> int:
+    """The residual a round of ``batch`` working rows gives
+    :func:`resolve_general`'s components pass: a quarter of the rows (the
+    rows of a round that wait for another row of it are a tenth at the
+    benchmark's shapes), the whole of a small working set."""
+    return min(batch, max(256, batch // 4))
+
+
+@functools.partial(jax.jit, static_argnames=("max_iters", "residual"))
 def resolve_general(
     deps: jax.Array,  # int32[B, D]
     dot_src: jax.Array,
     dot_seq: jax.Array,
     *,
     max_iters: int = 0,  # 0 -> auto: 4 * log2(B) + 8
+    residual: int = 0,  # > 0: the components pass, over that many rows
 ) -> GeneralResolution:
     """Batched resolution for out-degree-D graphs.
 
-    Affine-max pointer doubling: each dependency slot of vertex v is a
+    What the served path calls: the round with one coordinator
+    (``parallel/mesh_step.protocol_step`` at key width 2 and above) builds
+    dependencies that point backward only, and takes the arrival pass
+    below, the iterative pass where a shard under its write quorum leaves
+    ``MISSING`` rows; the round with a coordinator at every site builds
+    edges both ways, across keys and shards, and asks for the **components
+    pass** (``residual`` > 0, :func:`_resolve_general_components`): exact
+    strongly connected components and their order for every row that
+    reaches no ``MISSING`` one, in a number of passes bounded by
+    ``log2(residual) + 1``, ``stuck`` only where more than ``residual``
+    rows wait for another row of the batch.
+
+    The iterative pass, affine-max pointer doubling: each dependency slot of vertex v is a
     constraint ``rank[v] >= max(floor, add + rank[target])``.  A slot whose
     target has finalized folds into the floor; a slot whose target has
     exactly one live slot composes through it (chain doubling); any live
@@ -500,6 +524,8 @@ def resolve_general(
     # left in, they'd read as unfinishable frozen slots in the iterative
     # pass and falsely disqualify the backward fast path
     deps = jnp.where(deps == idx[:, None], TERMINAL, deps)
+    if residual:
+        return _resolve_general_components(deps, dot_src, dot_seq, residual)
 
     # --- fast path: every dependency points backward in batch order and
     # nothing is missing.  This is the dominant executor shape (deps are
@@ -1127,6 +1153,100 @@ def _resolve_general_iterative(deps, dot_src, dot_seq, max_iters):
     return order, resolved, rank, leader, stuck
 
 
+def _reaches(deps, marked):
+    """``(reaches, passes)``: the rows that are ``marked`` or reach a marked
+    row over ``deps`` (indices; negative: none), found by spreading the mark
+    against the edges to a fixpoint, one hop a pass; no pass where no row
+    is marked."""
+    live = deps >= 0
+    safe = jnp.where(live, deps, 0)
+
+    def spread(state):
+        reaches, _, passes = state
+        wider = reaches | (live & reaches[safe]).any(axis=-1)
+        return wider, (wider != reaches).any(), passes + 1
+
+    reaches, _, passes = jax.lax.while_loop(
+        lambda state: state[1], spread, (marked, marked.any(), jnp.int32(0))
+    )
+    return reaches, passes
+
+
+def _resolve_general_components(deps, dot_src, dot_seq, residual):
+    """Exact components of a graph whose edges point either way
+    (:func:`resolve_general` with ``residual``; self-dependencies pruned).
+
+    A row is *blocked* while it reaches a row with a ``MISSING`` slot:
+    found by spreading the mark against the edges to a fixpoint, one hop a
+    pass, a loop that does not run where no slot is ``MISSING``.  Every
+    other row is resolved or ``stuck``, so a caller that hands the stuck
+    rows to the host's Tarjan strands nothing.  Of those rows, the ones
+    with no dependency left run first, in batch order: whatever depends on
+    them comes after.  The rows that do wait for another (a tenth of a
+    round at the benchmark's shapes) are compacted into ``residual``
+    slots, in batch order, and their reachability is closed there as a
+    dense 0/1 matrix squared on the MXU until it stops changing: at most
+    ``ceil(log2(residual))`` squarings, fewer where the longest path is
+    short (each doubles the path length covered).  Two rows are one
+    component iff each reaches the other; its leader is its first row; a
+    component that depends on another reaches strictly more rows, so
+    ``(rows reached, leader, dot)`` is an execution order with components
+    contiguous and in dot order (``tarjan.rs:15``).  Where more than
+    ``residual`` rows wait, all of them come back ``stuck``: they depend
+    on resolved rows and on each other only, and nothing resolved depends
+    on them.  ``iters`` counts the passes of both loops."""
+    batch, width = deps.shape
+    size = min(residual, batch)
+    idx = jnp.arange(batch, dtype=jnp.int32)
+    live = deps >= 0
+    safe = jnp.where(live, deps, 0)
+
+    blocked, passes = _reaches(deps, (deps == MISSING).any(axis=-1))
+    waits = ~blocked & live.any(axis=-1)  # for a row that runs this round too
+    waiting = waits.sum().astype(jnp.int32)
+    overflow = waiting > size
+
+    # the waiting rows, compacted in batch order; an edge to a row that
+    # does not wait is dropped (that row runs first)
+    _, rows = jax.lax.sort(
+        ((~waits).astype(jnp.int32), idx), num_keys=1, is_stable=True
+    )
+    rows = rows[:size]
+    local = jnp.arange(size, dtype=jnp.int32)
+    held = local < waiting
+    slot_of = jnp.full((batch,), -1, jnp.int32).at[
+        jnp.where(held, rows, batch)
+    ].set(local, mode="drop")
+    edges = jnp.where(live[rows] & held[:, None], slot_of[safe[rows]], -1)  # [size, D]
+    reach = local[:, None] == local[None, :]
+    for d in range(width):
+        reach = reach | (edges[:, d, None] == local[None, :])
+
+    def square(state):
+        reach, _, squarings = state
+        ones = reach.astype(jnp.bfloat16)  # 0/1, summed in float32: exact
+        wider = jnp.dot(ones, ones, preferred_element_type=jnp.float32) > 0
+        return wider, (wider != reach).any(), squarings + 1
+
+    bound = max(1, (size - 1).bit_length())
+    reach, _, squarings = jax.lax.while_loop(
+        lambda state: state[1] & (state[2] < bound),
+        square, (reach, waiting > 0, jnp.int32(0)),
+    )
+    reached = reach.sum(axis=-1).astype(jnp.int32)  # itself among them
+    first = jnp.argmax(reach & reach.T, axis=-1).astype(jnp.int32)  # leader's slot
+
+    placed = jnp.where(held & ~overflow, rows, batch)
+    stuck = waits & overflow
+    resolved = ~blocked & ~stuck
+    rank = jnp.where(resolved, 0, _UNRESOLVED_RANK).astype(jnp.int32).at[placed].set(
+        reached, mode="drop"
+    )
+    leader = idx.at[placed].set(rows[first], mode="drop")
+    order = _order_from_ranks(rank, leader, dot_src, dot_seq)
+    return GeneralResolution(order, resolved, rank, leader, stuck, passes + squarings)
+
+
 # ---------------------------------------------------------------------------
 # one key a command: the components of a key's run
 # ---------------------------------------------------------------------------
@@ -1187,20 +1307,11 @@ def resolve_key_runs(
     pos = jnp.arange(work, dtype=jnp.int32)
     int_max = jnp.iinfo(jnp.int32).max
     live = deps >= 0
-    safe = jnp.where(live, deps, 0)
     run_start = jax.lax.cummax(jnp.where(head, pos, 0))
 
-    # blocked: reaches an uncommitted row (one hop a pass; no pass where
-    # every valid row is committed)
-    def spread(state):
-        blocked, _, passes = state
-        wider = blocked | (live & blocked[safe]).any(axis=-1)
-        return wider, (wider != blocked).any(), passes + 1
-
-    uncommitted = valid & ~committed
-    blocked, _, passes = jax.lax.while_loop(
-        lambda state: state[1], spread, (uncommitted, uncommitted.any(), jnp.int32(0))
-    )
+    # blocked: reaches an uncommitted row (no pass where every valid row
+    # is committed)
+    blocked, passes = _reaches(deps, valid & ~committed)
     runs = valid & committed & ~blocked  # executes this round
 
     def before(x):  # the value one position earlier

@@ -71,6 +71,7 @@ from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 from fantoch_tpu.ops.graph_resolve import (
     MISSING,
     TERMINAL,
+    components_residual,
     resolve_general,
     resolve_key_runs,
 )
@@ -145,20 +146,22 @@ class SiteStepOutput(NamedTuple):
     order: jax.Array  # int32[W] — the rows the device ordered first, in execution order
     resolved: jax.Array  # bool[W] — executed this round (``finish`` rows among them)
     fast_path: jax.Array  # bool[W]
-    # the union of what the fast quorum reported (global ids, -1 none,
-    # repeats left in): per member its latest write, then its latest read
-    # since it (a write's only)
-    deps_gid: jax.Array  # int32[W, 2*fast_quorum]
+    # the union of what the fast quorums reported (global ids, -1 none,
+    # repeats left in): per key slot and member its latest write, then its
+    # latest read since it (a write's only)
+    deps_gid: jax.Array  # int32[W, 2*KW*fast_quorum]
     gids: jax.Array  # int32[W]
     slow_paths: jax.Array  # int32[]
     stable: jax.Array  # int32[]
     pending: jax.Array  # int32[]
     pend_dropped: jax.Array  # int32[]
     # SITE_ROUND_TALLIES, then SITE_ROUND_GAUGES
-    tallies: jax.Array  # int32[10]
-    # executed this round at the place the host's Tarjan finds: the
-    # executable rows of a key's run the device's resolver did not cut
-    # (ops/graph_resolve.resolve_key_runs); not in ``order``'s front part
+    tallies: jax.Array  # int32[12]
+    # executed this round at the place the host's Tarjan finds: one key a
+    # command, the executable rows of a key's run the device's resolver
+    # did not cut (ops/graph_resolve.resolve_key_runs); several, the rows
+    # the components pass of ``resolve_general`` could not hold (its
+    # ``stuck``); not in ``order``'s front part
     finish: jax.Array  # bool[W]
 
 
@@ -173,10 +176,15 @@ ROUND_TALLIES = (
 # SiteStepOutput.tallies: those five, then what the round with a
 # coordinator at every site says of its graph, summed over rounds: the
 # executed rows in a strongly connected component of several rows, such
-# components, passes of the resolver, rows whose order the host's Tarjan
-# finds (the device's share; the driver adds what the finisher found) ...
+# components, passes of the resolver's loops, rows whose order the host's
+# Tarjan finds (the device's share; the driver adds what the finisher
+# found), the executed rows in a component of several whose rows hold more
+# than one key bucket and in one whose rows' keys lie on more than one shard
+# (the device's components only; 0 with one key a command, where a
+# component lies in one key's run) ...
 SITE_ROUND_TALLIES = ROUND_TALLIES + (
     "scc_rows", "scc_count", "resolve_iters", "finisher_rows",
+    "scc_span_rows", "scc_shard_rows",
 )
 # ... and, last in the vector, a gauge: the largest component of the round
 # (the driver keeps the last round's that had one)
@@ -442,10 +450,15 @@ def _resolve_run_position(
     return order, level != unresolved
 
 
-def resolver_name(key_width: int) -> str:
+def resolver_name(key_width: int, sites: int = 1) -> str:
     """What resolves a :func:`protocol_step` round of this key width (the
-    snapshot's ``backend`` and the "serving clients" banner say it)."""
-    return "run_position" if key_width == 1 else "general"
+    snapshot's ``backend`` and the "serving clients" banner say it): with
+    one coordinator the key runs' positions or ``resolve_general``'s
+    arrival pass, with a coordinator at every site ``resolve_key_runs`` or
+    ``resolve_general``'s components pass."""
+    if sites == 1:
+        return "run_position" if key_width == 1 else "general"
+    return "key_runs" if key_width == 1 else "general_components"
 
 
 def protocol_step(
@@ -472,9 +485,11 @@ def protocol_step(
     stands earlier in the run (:func:`_resolve_run_position`,
     :func:`resolver_name` ``run_position``).
     Multi-key rounds resolve through the general out-degree-2KW resolver
-    (ops/graph_resolve.resolve_general, ``general``), whose arrival-order
-    fast path covers the clean-commit case and whose iterative pass
-    handles quorum-failure MISSING blocking.
+    (ops/graph_resolve.resolve_general, ``general``): with one coordinator
+    every dependency points backward, so its arrival pass covers the
+    clean-commit case and its iterative pass the ``MISSING`` rows a shard
+    under its write quorum leaves; with a coordinator at every site
+    (``sites``, below) its components pass, every round.
 
     ``read`` marks the commands that only read (default: none does).  The
     conflict relation is ``KeyDeps``'s with the read/write split
@@ -512,20 +527,23 @@ def protocol_step(
 
     ``sites`` (static, like the key width): 1 is the round above, every
     command coordinated by replica 0 and seen by every replica in arrival
-    order.  ``sites == n`` is the round with a coordinator at every site
-    (:func:`_protocol_step_sites`: EPaxos, one shard, one key a command):
-    a command's coordinator is the replica ``dot_src - site_base``, the
-    replicas see a round's commands in different orders and the committed
-    graph has cycles.  Same state, same columns, another program.
+    order.  ``sites == n`` (the replicas a shard) is the round with a
+    coordinator at every site (:func:`_protocol_step_sites`: any key width
+    and shard count, EPaxos's rule or Atlas's at ``f`` = 1): a command's
+    coordinator is the replica at site ``dot_src - site_base`` of every
+    shard it touches, the replicas see a round's commands in different
+    orders and the committed graph has cycles, across keys and shards
+    where commands have several keys.  Same state, same columns, another
+    program.
     """
     if sites != 1:
-        assert sites == state.key_clock.shape[0] and shard_count == 1, (
-            "a coordinator at every site: one shard, and a site a replica"
+        assert sites * shard_count == state.key_clock.shape[0], (
+            "a coordinator at every site: a site a replica of every shard"
         )
-        assert rule == "epaxos", "sites are served under EPaxos's rule"
         return _protocol_step_sites(
             state, key, dot_src, dot_seq, read,
             mesh=mesh, live_replicas=live_replicas, site_base=site_base,
+            shard_count=shard_count, f=f, rule=rule,
         )
     num_replicas, key_buckets = state.key_clock.shape
     if key.ndim == 1:
@@ -861,7 +879,7 @@ def jit_protocol_step(
 
 def _protocol_step_sites(
     state: ReplicaState,
-    key: jax.Array,  # int32[B] or int32[B, 1]
+    key: jax.Array,  # int32[B] or int32[B, KW]
     dot_src: jax.Array,  # int32[B] — the coordinator's process: site_base + site
     dot_seq: jax.Array,  # int32[B]
     read: jax.Array | None,
@@ -869,50 +887,75 @@ def _protocol_step_sites(
     mesh: Mesh,
     live_replicas: int | None,
     site_base: int,
+    shard_count: int,
+    f: int,
+    rule: str,
 ) -> Tuple[ReplicaState, "SiteStepOutput"]:
-    """The dep-commit round with a coordinator at every site: EPaxos, one
-    shard, one key a command (the plain reference is
+    """The dep-commit round with a coordinator at every site, for any key
+    width and shard count and under either rule (the plain reference is
     ``tests/sites_reference.py``, semantics and departures there).
 
     A command's coordinator is the replica at its site, ``dot_src -
     site_base`` (upstream's ``dot.source()``; the pending buffer carries
-    it as ``pend_src``, so the state is :func:`protocol_step`'s own).
-    What differs from the round with one coordinator:
+    it as ``pend_src``, so the state is :func:`protocol_step`'s own), in
+    every shard the command touches: the replica of shard ``k`` at site
+    ``s`` is row ``k * n + s``.  What differs from the round with one
+    coordinator:
 
       * a replica's view of the working set: the pending rows in their
         order, then the batch's rows of its own site, then the batch's
-        other rows, each in arrival order.  A replica's own word on a row
-        is ``KeyDeps::add_cmd`` over that view (the latest write before it
-        and, for a write, the latest read since that write; else the
-        replica's clocks); its report is that joined with the
-        coordinator's own;
-      * the fast quorum of the coordinator at site ``s`` is the replicas
-        ``s .. s + fast_quorum - 1 (mod n)``; fast path iff every member's
-        report is the same set, which is iff every member's own word is
-        within the coordinator's (``check_union``);
+        other rows, each in arrival order.  A replica's own word on a key
+        slot of its shard is ``KeyDeps::add_cmd`` over that view (the
+        latest write before it and, for a write, the latest read since
+        that write; else the replica's clocks); its report is its words on
+        the command's slots of its shard, joined with the coordinator's;
+      * the fast quorum of a slot is a ring from the coordinator's site
+        inside the slot's shard, the rows ``k * n + (s + j) % n``, ``j <
+        fast_quorum``; under EPaxos's rule the fast path is taken iff, in
+        every touched shard, every member's report is the same set, which
+        is iff every member's own word is within the coordinator's
+        (``check_union``); under Atlas's at ``f`` = 1 always; a command
+        commits when every shard it touches has (``partial.rs``);
       * the committed dependencies are the union of the members' words as
-        a set, up to ``fast_quorum`` a class, and they point both ways
-        along a key's run: resolved by
-        ``ops/graph_resolve.resolve_key_runs`` (components in dependency
-        order, each in dot order), with the rows of a run it does not cut
-        marked ``finish`` for the host's Tarjan.  Those execute this round
-        too: the clocks learn them and they are not carried.
+        a set, ``2 * fast_quorum`` a key slot, and they point both ways
+        along a key's run and, with several keys a command, across runs.
+        One key a command: every dependency lies in the row's own run, and
+        the runs go to ``ops/graph_resolve.resolve_key_runs`` (components
+        in dependency order, each in dot order), with the rows of a run it
+        does not cut marked ``finish`` for the host's Tarjan.  Several:
+        ``ops/graph_resolve.resolve_general``'s components pass, which
+        marks ``finish`` what its residual cannot hold.  ``finish`` rows
+        execute this round too: the clocks learn them and they are not
+        carried.
 
-    Everything is computed where the rows stand sorted by key (a key's
-    rows one run, pending rows first, then arrival order: the order of a
-    replica's view but for where its own site's rows stand), and scattered
-    back to working rows at the end."""
+    What a site's replicas have seen before a slot is computed once a
+    site (the view is the site's, whichever the shard), where the key
+    slots stand sorted by key (a key's slots one run, pending rows first,
+    then arrival order: the order of a view but for where the site's own
+    rows stand): ``[n, slots]``, not a row of it a replica.  A slot's
+    ``fast_quorum`` members then each take their site's view and, where
+    it holds nothing, their own row's clocks (one gather of ``[slots,
+    fast_quorum]`` entries a table), and the words are scattered back to
+    working rows for the quorums."""
     num_replicas, key_buckets = state.key_clock.shape
-    if key.ndim == 2:
-        assert key.shape[1] == 1, "a coordinator at every site: one key a command"
-        key = key[:, 0]
-    assert state.pend_key.shape[1] == 1, "sites need init_state(key_width=1)"
-    batch = key.shape[0]
+    if key.ndim == 1:
+        key = key[:, None]
+    batch, key_width = key.shape
+    assert key_width == state.pend_key.shape[1], (
+        "key width must match init_state(key_width=...)"
+    )
+    assert num_replicas % shard_count == 0
+    per_shard = num_replicas // shard_count  # replicas a shard: the sites
+    assert rule == "epaxos" or f == 1, (
+        "a coordinator at every site under Atlas's rule: f = 1"
+    )
     if read is None:
         read = jnp.zeros((batch,), bool)
     pend_cap = state.pend_gid.shape[0]
     work = pend_cap + batch
-    fast_quorum, write_quorum = quorum_sizes(num_replicas, 1, "epaxos")
+    slots = work * key_width
+    fast_quorum, write_quorum = quorum_sizes(per_shard, f, rule)
+    width = 2 * key_width * fast_quorum  # committed dependencies a row
     if live_replicas is None:
         live_replicas = num_replicas
     replica_blocks = num_replicas // mesh.shape[REPLICA_AXIS]
@@ -922,7 +965,7 @@ def _protocol_step_sites(
         key_clock, frontier, next_gid, pend_key, pend_src, pend_seq, pend_gid,
         read_clock, pend_read, key_l, dot_src_l, dot_seq_l, read_l,
     ):
-        key_new = jax.lax.all_gather(key_l, BATCH_AXIS, tiled=True)  # [B]
+        key_new = jax.lax.all_gather(key_l, BATCH_AXIS, tiled=True)  # [B, KW]
         src_new = jax.lax.all_gather(dot_src_l, BATCH_AXIS, tiled=True)
         seq_new = jax.lax.all_gather(dot_seq_l, BATCH_AXIS, tiled=True)
         read_new = jax.lax.all_gather(read_l, BATCH_AXIS, tiled=True)
@@ -933,35 +976,34 @@ def _protocol_step_sites(
             [pend_gid, next_gid + jnp.arange(batch, dtype=jnp.int32)]
         )
         valid = gid >= 0
-        key_cat = jnp.concatenate([pend_key[:, 0], key_new])
-        real = valid & (key_cat != KEY_PAD)
-        key_full = jnp.where(real, key_cat, key_buckets + widx)
+        key_cat = jnp.concatenate([pend_key, key_new], axis=0)  # [W, KW]
+        real_slot = valid[:, None] & (key_cat != KEY_PAD)
+        slot_iota = jnp.arange(slots, dtype=jnp.int32).reshape(work, key_width)
+        key_full = jnp.where(real_slot, key_cat, key_buckets + slot_iota)
         dot_src_f = jnp.where(valid, jnp.concatenate([pend_src, src_new]), 0)
         dot_seq_f = jnp.where(valid, jnp.concatenate([pend_seq, seq_new]), 0)
         read_f = valid & jnp.concatenate([pend_read, read_new])
+        slot_shard = jnp.where(real_slot, key_cat % shard_count, 0)  # [W, KW]
 
-        # ... and sorted by key: ``perm[p]`` is the row at position ``p``
-        perm, head, read_at = _key_runs(key_full[:, None], read_f)
-        pos = widx
+        # ... and its key slots sorted by key: ``perm[p]`` is the slot at
+        # position ``p``, ``row_at[p]`` its working row
+        perm, head, read_at = _key_runs(key_full, read_f)
+        row_at = perm // key_width if key_width > 1 else perm
+        pos = jnp.arange(slots, dtype=jnp.int32)
         run_start = _run_start(head)
-        run_last = jax.lax.cummin(
-            jnp.where(jnp.concatenate([head[1:], jnp.ones((1,), bool)]), pos, work),
-            reverse=True,
-        )  # the last position of the position's run
-        gid_at, valid_at, real_at = gid[perm], valid[perm], real[perm]
-        key_at = jnp.minimum(key_full[perm], key_buckets - 1)
-        site_at = jnp.mod(dot_src_f[perm] - site_base, num_replicas)
-        new_at = perm >= pend_cap  # a row of this round's batch
+        gid_at, real_at = gid[row_at], real_slot.reshape(-1)[perm]
+        key_at = jnp.minimum(key_full.reshape(-1)[perm], key_buckets - 1)
+        shard_at = slot_shard.reshape(-1)[perm]
+        site_at = jnp.mod(dot_src_f[row_at] - site_base, per_shard)
+        new_at = row_at >= pend_cap  # a slot of this round's batch
 
-        # 2. each replica's own word, over its view: a row of the first
-        # part of the view (pending, or the replica's own site) has seen
-        # the first part's rows before it; a row of the second part the
-        # second part's rows before it, else the whole of the first part
-        row = (
-            jax.lax.axis_index(REPLICA_AXIS) * replica_blocks
-            + jnp.arange(replica_blocks, dtype=jnp.int32)
-        )  # global replica row ids of this block
-        second = new_at[None] & (site_at[None] != row[:, None])  # [r_blk, W]
+        # 2. what each site's replicas have seen before a slot.  The view
+        # is the site's, whichever the shard: a slot of the first part of
+        # the view (pending, or the site's own) has seen the first part's
+        # slots before it; a slot of the second part the second part's
+        # slots before it, else the whole of the first part
+        site = jnp.arange(per_shard, dtype=jnp.int32)
+        second = new_at[None] & (site_at[None] != site[:, None])  # [n, P]
 
         def before(x):  # the value one position earlier
             return jnp.concatenate(
@@ -971,99 +1013,212 @@ def _protocol_step_sites(
         def in_run(p):  # a position of another run is no predecessor
             return jnp.where(p >= run_start[None], p, -1)
 
-        def latest_in_view(member):  # member: bool[W], by position
-            a = jax.lax.cummax(jnp.where(member[None] & ~second, pos[None], -1), axis=1)
+        # runs counted down, above a position's bits: in a running maximum
+        # from the far end the nearest run wins, and in it the latest position
+        bits = slots.bit_length()
+        run_down = (slots - jnp.cumsum(head.astype(jnp.int32)))[None]
+
+        def whole_run(v, so_far):  # the latest of ``v`` (positions, -1 none) in the run
+            if 2 * bits > 31:  # a working set too long to pack into an int32
+                run_last = jax.lax.cummin(
+                    jnp.where(jnp.concatenate([head[1:], jnp.ones((1,), bool)]), pos, slots),
+                    reverse=True,
+                )
+                return in_run(so_far[:, run_last])
+            later = jax.lax.cummax(
+                jnp.where(v >= 0, (run_down << bits) | (v + 1), 0), axis=1, reverse=True
+            )
+            after = jnp.where(
+                (later >> bits) == run_down, (later & ((1 << bits) - 1)) - 1, -1
+            )
+            return jnp.maximum(in_run(so_far), after)
+
+        def latest_in_view(member):  # member: bool[P], by position
+            first = jnp.where(member[None] & ~second, pos[None], -1)
+            a = jax.lax.cummax(first, axis=1)
             b = jax.lax.cummax(jnp.where(member[None] & second, pos[None], -1), axis=1)
             b_before = in_run(before(b))
-            return jnp.where(
+            late = second & (b_before >= 0)  # found in the view's second part
+            at = jnp.where(
                 second,
-                jnp.where(b_before >= 0, b_before, in_run(a[:, run_last])),
+                jnp.where(late, b_before, whole_run(first, a)),
                 in_run(before(a)),
-            )  # [r_blk, W] the position, -1 where the view holds none
+            )  # [n, P] the position, -1 where the view holds none
+            # ... and where it stands in the view
+            return at, at + slots * late.astype(jnp.int32)
 
-        at_w = latest_in_view(~read_at)
-        at_r = latest_in_view(read_at)
-        # where a position stands in the replica's view
-        view_rank = pos[None] + work * second.astype(jnp.int32)
+        at_w, rank_w = latest_in_view(~read_at)
+        at_r, rank_r = latest_in_view(read_at)
+        # the latest read of the view came after its latest write
+        read_later = (at_w < 0) | (rank_r > rank_w)  # [n, P], where at_r >= 0
 
-        def rank_of(p):
-            return jnp.where(
-                p >= 0, jnp.take_along_axis(view_rank, jnp.maximum(p, 0), axis=1), -1
-            )
+        # 3. the fast quorum of each slot's coordinator, a ring from its
+        # site inside the slot's shard; the coordinator is member 0.  A
+        # member's word on the slot: what its site's view holds, else what
+        # the replica itself has learnt (its clocks: a row of this block,
+        # or of another device's)
+        # (the members stand on the leading axis here: the slots are the
+        # long one)
+        ring = jnp.mod(
+            site_at[None] + jnp.arange(fast_quorum, dtype=jnp.int32)[:, None], per_shard
+        )  # [fast_quorum, P] sites
 
-        learnt_w = jnp.where(real_at[None], key_clock[:, key_at], -1)
-        learnt_r = jnp.where(real_at[None], read_clock[:, key_at], -1)
-        write_gid = jnp.where(at_w >= 0, gid_at[jnp.maximum(at_w, 0)], learnt_w)
-        read_gid = jnp.where(at_r >= 0, gid_at[jnp.maximum(at_r, 0)], learnt_r)
+        def of_ring(x, rows, picked):  # x[rows[k, p], p], where picked; else -1 or False
+            mine = (jnp.arange(x.shape[0], dtype=jnp.int32)[:, None, None] == rows[None]) & picked
+            if x.dtype == jnp.bool_:
+                return (mine & x[:, None]).any(axis=0)
+            return jnp.where(mine, x[:, None], -1).max(axis=0)  # values >= -1
+
+        row0 = jax.lax.axis_index(REPLICA_AXIS) * replica_blocks
+        row = row0 + jnp.arange(replica_blocks, dtype=jnp.int32)  # this block's rows
+        # the member's row of the tables, where this block holds it: each
+        # table is read once a slot for every row held (a column a slot)
+        # and the member's entry picked out of the column
+        held = (shard_at * per_shard)[None] + ring - row0
+        here = (held >= 0) & (held < replica_blocks) & real_at[None]
+        learnt = jax.lax.pmax(
+            jnp.stack(
+                [
+                    of_ring(key_clock[:, key_at], held, here),
+                    of_ring(read_clock[:, key_at], held, here),
+                ]
+            ),
+            REPLICA_AXIS,
+        )  # [2, fast_quorum, P]: nothing learnt is -1, and no row holds less
+        everywhere = jnp.ones_like(here)
+        seen_w, seen_r = of_ring(at_w, ring, everywhere), of_ring(at_r, ring, everywhere)
+        write_gid = jnp.where(seen_w >= 0, gid_at[jnp.maximum(seen_w, 0)], learnt[0])
+        read_gid = jnp.where(seen_r >= 0, gid_at[jnp.maximum(seen_r, 0)], learnt[1])
         # the latest read came after the latest write: by the view where
         # the read is a working row (a working row is after anything
         # learnt), by arrival where both were learnt
         since = jnp.where(
-            at_r >= 0,
-            (at_w < 0) | (rank_of(at_r) > rank_of(at_w)),
-            (at_w < 0) & (learnt_r > learnt_w),
+            seen_r >= 0,
+            of_ring(read_later, ring, everywhere),
+            (seen_w < 0) & (learnt[1] > learnt[0]),
         )
         kept = since & ~read_at[None]  # a write depends on it
-        own = jnp.stack(
+        members_at = jnp.stack(
             [
                 write_gid,
                 jnp.where(kept, read_gid, -1),
-                at_w,
-                jnp.where(kept, at_r, -1),
+                seen_w,
+                jnp.where(kept, seen_r, -1),
                 # for the tallies: the replica knew a command on the key,
                 # and the latest it knew was a read
                 ((write_gid >= 0) | (read_gid >= 0)).astype(jnp.int32),
                 (since & (read_gid >= 0)).astype(jnp.int32),
             ],
             axis=-1,
-        )  # [r_blk, W, 6]
-        everyone = jax.lax.all_gather(own, REPLICA_AXIS, tiled=True)  # [R, W, 6]
-
-        # 3. the fast quorum of each row's coordinator, a ring from its
-        # site; the coordinator is member 0
-        members = jnp.stack(
-            [
-                everyone[jnp.mod(site_at + k, num_replicas), pos]
-                for k in range(fast_quorum)
-            ],
-            axis=1,
-        )  # [W, fast_quorum, 6]
-        said = members[..., :2]  # [W, fast_quorum, 2] gids
-        mine = said[:, :1, :]  # the coordinator's own word
-        # every report (a member's word joined with the coordinator's) is
-        # the same set iff every member's word is within the coordinator's
-        within = (
-            (said < 0)
-            | (said == mine[..., :1])
-            | (said == mine[..., 1:])
+        ).transpose(1, 0, 2)  # [P, fast_quorum, 6]
+        # ... by working row and key slot
+        members = (
+            jnp.zeros_like(members_at).at[perm].set(members_at)
+            .reshape(work, key_width, fast_quorum, 6)
         )
-        fast_at = within.all(axis=(1, 2)) & valid_at
-        deps_gid_at = said.reshape(work, 2 * fast_quorum)
-        deps_at = members[..., 2:4].reshape(work, 2 * fast_quorum)  # positions
+        said = members[..., :2]  # [W, KW, fast_quorum, 2] gids
+        if rule == "epaxos":
+            # every report of a shard (a member's words joined with the
+            # coordinator's) is the same set iff every member's word is
+            # within the coordinator's words on the command's slots of
+            # that shard
+            # (the rows on the minor axis: the other axes are a few long)
+            said_t = said.transpose(1, 2, 3, 0)  # [KW, fast_quorum, 2, W]
+            mine = said_t[:, 0]  # [KW, 2, W]
+            shard_t = slot_shard.T  # [KW, W]
+            same_shard = shard_t[:, None] == shard_t[None]  # [KW, KW, W]
+            found = (
+                (said_t[:, :, :, None, None] == mine[None, None, None])
+                & same_shard[:, None, None, :, None]
+            ).any(axis=(3, 4))
+            fast = ((said_t < 0) | found).all(axis=(0, 1, 2)) & valid
+        else:
+            fast = valid  # Atlas at f = 1: whoever reported a dependency is one of f
+        deps_gid = jnp.where(
+            real_slot[:, :, None, None], said, -1
+        ).reshape(work, width)
 
         # the accept round, as protocol_step has it: every live replica
-        # accepts the union at ballot 0
+        # of a slot's shard accepts the union at ballot 0, and a command
+        # commits once every shard it touches has its write quorum
         live = (row < live_replicas)[:, None]  # [r_blk, 1]
-        acks = jax.lax.psum(live.sum().astype(jnp.int32), REPLICA_AXIS)
-        committed_at = (fast_at | (acks >= write_quorum)) & valid_at
-        slow_paths = ((~fast_at) & valid_at).sum().astype(jnp.int32)
+        shard_live = jax.lax.psum(
+            jnp.zeros((shard_count,), jnp.int32).at[row // per_shard].add(
+                live[:, 0].astype(jnp.int32)
+            ),
+            REPLICA_AXIS,
+        )  # [S]
+        slow_ok = jnp.where(
+            real_slot, shard_live[slot_shard] >= write_quorum, True
+        ).all(axis=-1)
+        committed = (fast | slow_ok) & valid
+        slow_paths = ((~fast) & valid).sum().astype(jnp.int32)
 
-        # 4. the components of each key's run, and their order
-        res = resolve_key_runs(
-            deps_at, head, valid_at, committed_at, dot_src_f[perm], dot_seq_f[perm]
-        )
-        executed_at = res.resolved | res.finish
+        def count(mask):
+            return mask.sum().astype(jnp.int32)
 
-        def by_row(x_at):  # a column by position, back by working row
-            return jnp.zeros_like(x_at).at[perm].set(x_at)
+        # 4. the components of the committed graph, and their order
+        if key_width == 1:
+            # every dependency of a row is a row of its key's run
+            res = resolve_key_runs(
+                members_at[..., 2:4].reshape(slots, 2 * fast_quorum), head,
+                valid[perm], committed[perm], dot_src_f[perm], dot_seq_f[perm],
+            )
 
-        executed = by_row(executed_at)
-        order = perm[res.order]
+            def by_row(x_at):  # a column by position, back by working row
+                return jnp.zeros_like(x_at).at[perm].set(x_at)
 
-        # 5. every live replica learns what executed, the host-ordered
-        # rows too (they execute this round)
-        done_at = executed_at & real_at
-        learns = live & done_at[None]  # [r_blk, W]
+            executed = by_row(res.resolved | res.finish)
+            finish = by_row(res.finish)
+            order = perm[res.order]
+            graph = [
+                res.scc_rows, res.scc_count, res.iters, count(finish),
+                jnp.int32(0), jnp.int32(0),  # a component lies in one key's run
+                res.scc_rows_max,
+            ]
+        else:
+            at = members[..., 2:4].reshape(work, width)  # sorted positions
+            dep_row = jnp.where(at >= 0, row_at[jnp.maximum(at, 0)], jnp.int32(TERMINAL))
+            dep_idx = jnp.where(committed[:, None], dep_row, jnp.int32(MISSING))
+            dep_idx = jnp.where(valid[:, None], dep_idx, jnp.int32(TERMINAL))
+            res = resolve_general(
+                dep_idx, dot_src_f, dot_seq_f, residual=components_residual(work)
+            )
+            finish = res.stuck & committed
+            executed = (res.resolved & committed) | finish
+            order = res.order
+            # the components of several rows, by their first row
+            in_one = res.resolved & committed
+            size = jnp.zeros((work,), jnp.int32).at[
+                jnp.where(in_one, res.leader, work)
+            ].add(1, mode="drop")
+            several = in_one & (size[res.leader] > 1)
+            lead = jnp.where(several, res.leader, work)  # dropped where none
+            # ... whose rows hold more than one key bucket, and buckets of
+            # more than one shard
+            lead_real = jnp.where(real_slot, lead[:, None], work)
+
+            def lowest(values, above):
+                return jnp.full((work,), above, jnp.int32).at[lead_real].min(values, mode="drop")
+
+            def highest(values):
+                return jnp.full((work,), -1, jnp.int32).at[lead_real].max(values, mode="drop")
+
+            spans = highest(key_cat) > lowest(key_cat, int_max)
+            crosses = highest(slot_shard) > lowest(slot_shard, shard_count)
+            safe_lead = jnp.minimum(lead, work - 1)
+            graph = [
+                count(several), count(size > 1), res.iters, count(finish),
+                count(several & spans[safe_lead]), count(several & crosses[safe_lead]),
+                jnp.where(size > 1, size, 0).max().astype(jnp.int32),
+            ]
+
+        # 5. every live replica learns what executed on the buckets of its
+        # own shard, the host-ordered rows too (they execute this round)
+        done_at = executed[row_at] & real_at
+        learns = (
+            live & ((row // per_shard)[:, None] == shard_at[None]) & done_at[None]
+        )  # [r_blk, P]
         new_clock = key_clock.at[:, key_at].max(
             jnp.where(learns & ~read_at[None], gid_at[None], jnp.int32(-1))
         )
@@ -1076,33 +1231,29 @@ def _protocol_step_sites(
             read_clock,
         )
         new_frontier = frontier + jnp.where(
-            live[:, 0], executed_at.sum().astype(jnp.int32), 0
+            live[:, 0], executed.sum().astype(jnp.int32), 0
         )
         stable = jax.lax.pmin(new_frontier.min(), REPLICA_AXIS)
 
-        def count(mask):
-            return mask.sum().astype(jnp.int32)
-
         # distinct committed dependencies: a sorted row's repeats stand
         # side by side
-        ranked = jnp.sort(deps_gid_at, axis=-1)
+        ranked = jnp.sort(deps_gid, axis=-1)
         distinct = (ranked >= 0) & jnp.concatenate(
             [jnp.ones((work, 1), bool), ranked[:, 1:] != ranked[:, :-1]], axis=-1
         )
-        linked = done_at & (members[:, 0, 4] > 0)
+        done_slot = executed[:, None] & real_slot  # [W, KW]
+        linked = done_slot & (members[:, :, 0, 4] > 0)
+        shards_lo = jnp.where(real_slot, slot_shard, shard_count).min(axis=-1)
+        shards_hi = jnp.where(real_slot, slot_shard, -1).max(axis=-1)
         tallies = jnp.stack(
             [
-                count(distinct & done_at[:, None]),
+                count(distinct & executed[:, None]),
                 count(linked),
-                count(linked & read_at & (members[:, 0, 5] > 0)),
-                count(done_at & read_at),
-                jnp.int32(0),  # one shard
-                res.scc_rows,
-                res.scc_count,
-                res.iters,
-                count(res.finish),
-                res.scc_rows_max,
+                count(linked & read_f[:, None] & (members[:, :, 0, 5] > 0)),
+                count(executed & read_f),
+                count(executed & (shards_hi > shards_lo)),
             ]
+            + graph
         )  # SITE_ROUND_TALLIES, SITE_ROUND_GAUGES
 
         # 6. pending carry, as protocol_step has it
@@ -1115,7 +1266,7 @@ def _protocol_step_sites(
             new_clock,
             new_frontier,
             next_gid + batch,
-            jnp.where(is_carry, key_cat[take], KEY_PAD)[:, None],
+            jnp.where(is_carry[:, None], key_cat[take], KEY_PAD),
             jnp.where(is_carry, dot_src_f[take], -1),
             jnp.where(is_carry, dot_seq_f[take], -1),
             jnp.where(is_carry, gid[take], -1),
@@ -1123,15 +1274,15 @@ def _protocol_step_sites(
             is_carry & read_f[take],
             order,
             executed,
-            by_row(fast_at),
-            by_row(jnp.where(real_at[:, None], deps_gid_at, -1)),
+            fast,
+            deps_gid,
             jnp.where(valid, gid, -1),
             slow_paths,
             stable,
             jnp.minimum(pending, pend_cap),
             jnp.maximum(pending - pend_cap, 0).astype(jnp.int32),
             tallies,
-            by_row(res.finish),
+            finish,
         )
 
     state_specs = (

@@ -359,7 +359,7 @@ class _DriverCore(PipelineCore):
             raise ValueError(
                 f"clients at site {site}: this round has one coordinator, "
                 "replica 0 (a coordinator at every site is served under "
-                "epaxos, one shard, one key a command)"
+                "epaxos, and under atlas at f = 1)"
             )
 
     def _column_specs(self):
@@ -785,7 +785,7 @@ class DeviceDriver(_DriverCore):
         )
         # the quorums and the fast-path test of the round: EPaxos's, or
         # Atlas's with its f (mesh_step.quorum_sizes)
-        self.rule = rule
+        self.rule, self.f = rule, f
         self.fast_quorum, self.write_quorum = mesh_step.quorum_sizes(
             num_replicas, f, rule
         )
@@ -826,8 +826,10 @@ class DeviceDriver(_DriverCore):
     @property
     def serves_sites(self) -> bool:
         """Whether the round can have a coordinator at every site: under
-        EPaxos's rule, on one shard, with one key a command."""
-        return self.rule == "epaxos" and self.shard_count == 1 and self.key_width == 1
+        EPaxos's rule, and under Atlas's at ``f`` = 1 (its threshold over
+        per-site views at a larger ``f`` is not written), at any key width
+        and shard count."""
+        return self.rule == "epaxos" or self.f == 1
 
     def register_site(self, site: int) -> None:
         """Clients at ``site``.  The first site but 0 makes the round's
@@ -862,12 +864,15 @@ class DeviceDriver(_DriverCore):
             self._site_program = self._precompile(
                 mesh_step.jit_protocol_step(
                     self._mesh, live_replicas=self._live_replicas,
+                    shard_count=self.shard_count, f=self.f, rule=self.rule,
                     sites=self.num_replicas, site_base=self.site_base,
                 ),
                 state=state,
             )
+            self.resolver = mesh_step.resolver_name(
+                self.key_width, sites=self.num_replicas
+            )
         self._sites.add(site)
-        self.resolver = "key_runs"
 
     def _program(self, S: int = 1):
         return self._site_program or super()._program(S)

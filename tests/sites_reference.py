@@ -1,55 +1,70 @@
 """The plain reference of the dependency round with a coordinator at every
-site (``--protocol epaxos``, one shard, one key a command, clients registered
-at more than one site: ``parallel/mesh_step.py`` ``protocol_step(sites=n)``):
-its rules one command at a time over ``dict``s, ``set``s and ``list``s.
+site (``--protocol epaxos`` or ``--protocol atlas -f 1``, any ``--shard-count``
+and ``--device-key-width``, clients registered at more than one site:
+``parallel/mesh_step.py`` ``protocol_step(sites=n)``): its rules one command
+at a time over ``dict``s, ``set``s and ``list``s.
 Nothing here is the program's round: no import from ``fantoch_tpu.parallel``
 or ``fantoch_tpu.ops``, no ``jax``, no ``numpy``, no batch tensor, no sort, no
 scan.  (``tests/test_sites_reference.py`` holds this file's components to the
 repo's host Tarjan, ``executor/graph/tarjan.py``, as a second opinion.)
 
-The protocol is EPaxos (Moraru et al., SOSP'13) as upstream implements it
-(``fantoch_ps/src/protocol/epaxos.rs``; the conflict index
+The protocols are EPaxos (Moraru et al., SOSP'13) and Atlas (Enes et al.,
+EuroSys'20; with shards, the Tempo paper's Janus*) as upstream implements them
+(``fantoch_ps/src/protocol/epaxos.rs``, ``atlas.rs``, partial replication
+``partial.rs``; the conflict index
 ``fantoch_ps/src/protocol/common/graph/deps/keys/locked.rs``; the executor
 ``fantoch_ps/src/executor/graph/tarjan.rs``), in the dense, round-based form
 of ``protocol_step``:
 
-* **Sites.**  ``n`` replicas, one a site.  A command is submitted at a site;
-  that site's replica is its coordinator, and its dot is ``(coordinator's
-  process, the coordinator's next sequence)``.
+* **Sites, shards and replicas.**  ``shards`` shards of ``n`` replicas each,
+  one replica of every shard a site; key ``k`` belongs to shard ``k % shards``
+  and the replica of shard ``k`` at site ``s`` is row ``k * n + s``.  A command
+  (a read or a write of one or several distinct keys) is submitted at a site;
+  that site's replica coordinates it in every shard it touches
+  (``partial.rs:8``: the coordinator forwards the submit to its own site's
+  process of every other shard), and its dot is ``(coordinator's process, the
+  coordinator's next sequence)``.
 * **A replica's view of a round.**  What the last round carried comes first,
   in the order it was carried (every replica saw it in an earlier round).
   Then the round's new commands: replica ``r`` has the commands of its own
   site first, in arrival order, then every other command in arrival order.
   (All commands of one round are concurrent: a replica has its own clients'
   commands before any ``MCollect`` reaches it.)
-* **The index** of a replica keeps, per key, the latest write and the latest
-  read since it (``LatestRWDep``).  A read depends on the latest write and
+* **The index** of a replica keeps, per key of its shard, the latest write and
+  the latest read since it (``LatestRWDep``).  A read depends on the latest write and
   becomes the latest read; a write depends on the latest write and on the
   latest read since it, and becomes the latest write.  "Latest" and "since"
   are by the replica's own view.
 * **A report.**  A replica adds the working set in the order of its view on
-  top of what it has learnt; what it finds for a command is its own word.  Its
-  report to the command's coordinator is that joined with the coordinator's
+  top of what it has learnt, each command on its keys of the replica's shard;
+  what it finds for a command, over those keys, is its own word.  Its report to
+  the command's coordinator in its shard is that joined with the coordinator's
   own (``KeyDeps::add_cmd(dot, cmd, past)``: the ``MCollect`` carries the
   coordinator's dependencies and a member adds them to what it reports).
-* **Quorums.**  ``f = n // 2``, fast ``f + (f + 1) // 2``, write ``f + 1``
-  (``config.rs``).  The coordinator at site ``s`` collects from ``s, s + 1,
-  ..., s + fast - 1 (mod n)``: a ring stands for "the closest".
-* **Fast path** iff every member of the fast quorum reported the same set
-  (``epaxos.rs:339-345``, ``check_union``).  Otherwise the union is proposed
-  and accepted iff at least the write quorum of the replicas is live.  The
-  committed dependencies are the union either way: up to ``fast`` commands a
-  key and class.
+* **Quorums**, per shard.  EPaxos: ``f = n // 2``, fast ``f + (f + 1) // 2``,
+  write ``f + 1``; Atlas: fast ``n // 2 + f``, write ``f + 1`` (``config.rs``).
+  The coordinator at site ``s`` collects, in shard ``k``, from the rows ``k * n
+  + (s + j) % n``, ``j < fast``: a ring stands for "the closest".
+* **Fast path**, per shard: EPaxos iff every member of the fast quorum
+  reported the same set (``epaxos.rs:339-345``, ``check_union``); Atlas iff
+  every dependency of the union was reported by at least ``f`` members
+  (``atlas.rs``, ``check_threshold``; at ``f`` = 1 always).  A command is fast
+  iff every shard it touches is.  Otherwise the union is proposed and accepted
+  iff every touched shard has at least its write quorum live.  The committed
+  dependencies are the union over the command's shards either way
+  (``partial.rs``'s aggregation): up to ``fast`` commands a key and class.
 * **Execution**: Tarjan over the committed commands of the working set
   (``tarjan.rs``): the strongly connected components in dependency order,
   each in dot order; a component runs once everything it depends on has, so
   an uncommitted command holds back whatever reaches it.
 * **Learning.**  At the round's end every live replica learns what was
-  executed: per key the latest write and the latest read, by arrival.
+  executed on the keys of its shard: per key the latest write and the latest
+  read, by arrival.
 * **Carry.**  What did not execute is carried into the next round in working
   order (what was carried, then arrival order).
 
-Departures from ``epaxos.rs``, noted and followed by the device round:
+Departures from ``epaxos.rs``, ``atlas.rs`` and ``partial.rs``, noted and
+followed by the device round:
 
 1. **Bucket aliasing.**  The driver hashes a key to a bucket and the round
    orders buckets, so two keys of one bucket conflict.  The keys given to
@@ -80,6 +95,18 @@ Departures from ``epaxos.rs``, noted and followed by the device round:
    what one socket read brings is hundreds of commands of one site in a row,
    where a replica's network would deliver five coordinators' ``MCollect``s
    interleaved.  A site's own commands keep their order.
+8. **A command that missed the fast path in one shard runs the accept round
+   in every shard it touches**, and commits when all of them accepted, in the
+   round that proposed it.  In ``partial.rs`` each shard commits on its own
+   and the coordinator aggregates the ``MShardCommit``s.
+9. **Atlas's threshold is taken over the reports**, each joined with the
+   coordinator's own, as ``atlas.rs`` joins them: a dependency the coordinator
+   found is reported by every member.  At ``f`` = 1, the one ``f`` the device
+   round serves with a coordinator at every site, the fast path is
+   unconditional either way.
+10. **Every replica of a shard has every command of the round that touches
+   its shard in its view** (departure 3, per shard), and a shard's replicas
+   see nothing of a command's keys on other shards.
 """
 
 from __future__ import annotations
@@ -90,11 +117,12 @@ Dot = Tuple[int, int]  # (source, sequence)
 
 
 class Command(NamedTuple):
-    """A read or a write of one key, submitted at ``site``."""
+    """A read or a write, submitted at ``site``, of one key (``key`` an
+    ``int``) or of several distinct ones (a tuple of them)."""
 
     src: int
     seq: int
-    key: int
+    key: int | Tuple[int, ...]
     read: bool
     site: int
 
@@ -102,15 +130,22 @@ class Command(NamedTuple):
     def dot(self) -> Dot:
         return (self.src, self.seq)
 
+    @property
+    def keys(self) -> Tuple[int, ...]:
+        return self.key if isinstance(self.key, tuple) else (self.key,)
+
 
 class Verdict(NamedTuple):
     """What a round made of one command of its working set."""
 
-    reports: Dict[int, FrozenSet[Dot]]  # fast-quorum member -> what it reported
+    # fast-quorum member (its replica row, ``shard * n + site``) -> what it reported
+    reports: Dict[int, FrozenSet[Dot]]
     fast: bool
     committed: bool
     deps: FrozenSet[Dot]  # the union of the reports
     executed: bool
+    # key -> the members' own words on that key, joined
+    by_key: Dict[int, FrozenSet[Dot]] = {}
 
 
 class Round(NamedTuple):
@@ -118,31 +153,51 @@ class Round(NamedTuple):
     order: List[Dot]  # the executed ones, in execution order
     components: List[List[Dot]]  # the executed components, each in dot order
     slow_paths: int
+    commands: Dict[Dot, Command] = {}  # the working set
+    shards: int = 1
 
     def tally(self) -> Dict[str, int]:
-        """The device round's tallies over what this round executed."""
+        """The device round's tallies over what this round executed.  Of the
+        components of several commands: ``scc_span_rows`` counts the commands
+        of those whose members hold more than one key, ``scc_shard_rows`` of
+        those whose members' keys lie on more than one shard."""
         multi = [c for c in self.components if len(c) > 1]
+        span = shard = 0
+        for component in multi:
+            on_keys = {key for dot in component for key in self.commands[dot].keys}
+            span += len(component) * (len(on_keys) > 1)
+            shard += len(component) * (len({key % self.shards for key in on_keys}) > 1)
         return {
             "deps_committed": sum(len(self.verdicts[dot].deps) for dot in self.order),
+            "cross_shard_executed": sum(
+                len({key % self.shards for key in self.commands[dot].keys}) > 1
+                for dot in self.order
+            ),
             "scc_rows": sum(map(len, multi)),
             "scc_count": len(multi),
             "scc_rows_max": max(map(len, multi), default=0),
+            "scc_span_rows": span,
+            "scc_shard_rows": shard,
         }
 
 
-def quorum_sizes(n: int) -> Tuple[int, int]:
-    """(fast, write) of EPaxos in ``config.rs``."""
+def quorum_sizes(n: int, rule: str = "epaxos", f: int = 1) -> Tuple[int, int]:
+    """(fast, write) of ``config.rs``: EPaxos's whatever ``f``, Atlas's."""
+    if rule == "atlas":
+        return n // 2 + f, f + 1
+    assert rule == "epaxos", rule
     minority = n // 2
     return minority + (minority + 1) // 2, minority + 1
 
 
-def fast_quorum(site: int, n: int) -> List[int]:
-    """The members the coordinator at ``site`` collects from."""
-    return [(site + k) % n for k in range(quorum_sizes(n)[0])]
+def fast_quorum(site: int, n: int, rule: str = "epaxos", f: int = 1) -> List[int]:
+    """The members of a shard the coordinator at ``site`` collects from."""
+    return [(site + k) % n for k in range(quorum_sizes(n, rule, f)[0])]
 
 
 def view(replica: int, carried: List[Command], new: List[Command]) -> List[Command]:
-    """The working set in the order ``replica`` has seen it."""
+    """The working set in the order the replica at site ``replica`` (of
+    whichever shard) has seen it."""
     own = [cmd for cmd in new if cmd.site == replica]
     others = [cmd for cmd in new if cmd.site != replica]
     return list(carried) + own + others
@@ -157,18 +212,18 @@ class _Latest:
         self.write, self.read = write, read
 
 
-def _add(index: Dict[int, _Latest], learnt: Dict[int, _Latest], cmd: Command, arrival: int):
-    """``KeyDeps::add_cmd`` on the command's key: the arrivals depended on."""
-    entry = index.get(cmd.key)
+def _add(index: Dict[int, _Latest], learnt: Dict[int, _Latest], key: int, read: bool, arrival: int):
+    """``KeyDeps::add_cmd`` on one key: the arrivals depended on."""
+    entry = index.get(key)
     if entry is None:
-        below = learnt.get(cmd.key)
-        entry = index[cmd.key] = _Latest()
+        below = learnt.get(key)
+        entry = index[key] = _Latest()
         if below is not None:
             entry.write = below.write
             # what it learnt by arrival: a read is "since" a write if later
             if below.read is not None and (below.write is None or below.read > below.write):
                 entry.read = below.read
-    if cmd.read:
+    if read:
         depends = {entry.write}
         entry.read = arrival
     else:
@@ -178,48 +233,74 @@ def _add(index: Dict[int, _Latest], learnt: Dict[int, _Latest], cmd: Command, ar
 
 
 class Reference:
-    def __init__(self, n: int):
-        self.n = n
-        self.fast_quorum, self.write_quorum = quorum_sizes(n)
-        self.learnt: List[Dict[int, _Latest]] = [{} for _ in range(n)]
+    def __init__(self, n: int, shards: int = 1, rule: str = "epaxos", f: int = 1):
+        self.n, self.shards, self.rule, self.f = n, shards, rule, f
+        self.fast_quorum, self.write_quorum = quorum_sizes(n, rule, f)
+        # learnt[row], row = shard * n + site
+        self.learnt: List[Dict[int, _Latest]] = [{} for _ in range(n * shards)]
         self.arrival: Dict[Dot, int] = {}  # a command's place in arrival order
         self.dot_at: Dict[int, Dot] = {}
         self.carried: List[Command] = []
         self.executed: Set[Dot] = set()
 
     def round(self, commands: List[Command], live: Optional[int] = None) -> Round:
-        n = self.n
-        live = n if live is None else live
+        """One round; the replica rows below ``live`` are live."""
+        n, shards = self.n, self.shards
+        live = n * shards if live is None else live
         for cmd in commands:
             assert cmd.dot not in self.arrival and 0 <= cmd.site < n
+            assert len(set(cmd.keys)) == len(cmd.keys)
             self.arrival[cmd.dot] = len(self.arrival)
             self.dot_at[self.arrival[cmd.dot]] = cmd.dot
         working = self.carried + list(commands)
 
-        # every replica adds the working set, in the order of its view
-        own: List[Dict[Dot, FrozenSet[int]]] = []
-        for replica in range(n):
-            index: Dict[int, _Latest] = {}
+        # every replica adds the working set, in the order of its view, each
+        # command on its keys of the replica's shard: own[row][dot][key]
+        own: List[Dict[Dot, Dict[int, FrozenSet[int]]]] = []
+        for row in range(n * shards):
+            shard, index = row // n, {}
             own.append({
-                cmd.dot: _add(index, self.learnt[replica], cmd, self.arrival[cmd.dot])
-                for cmd in view(replica, self.carried, list(commands))
+                cmd.dot: {
+                    key: _add(index, self.learnt[row], key, cmd.read, self.arrival[cmd.dot])
+                    for key in cmd.keys if key % shards == shard
+                }
+                for cmd in view(row % n, self.carried, list(commands))
             })
+
+        def word(row: int, dot: Dot) -> FrozenSet[int]:
+            return frozenset().union(*own[row][dot].values())
+
+        def dots(arrivals) -> FrozenSet[Dot]:
+            return frozenset(self.dot_at[at] for at in arrivals)
 
         verdicts: Dict[Dot, Verdict] = {}
         slow_paths = 0
         for cmd in working:
-            reports = {
-                member: own[member][cmd.dot] | own[cmd.site][cmd.dot]
-                for member in fast_quorum(cmd.site, n)
-            }
-            said = list(reports.values())
-            fast = all(one == said[0] for one in said)
+            fast = accepted = True
+            reports: Dict[int, FrozenSet[int]] = {}
+            by_key: Dict[int, Set[int]] = {key: set() for key in cmd.keys}
+            for shard in sorted({key % shards for key in cmd.keys}):
+                rows = [shard * n + m for m in fast_quorum(cmd.site, n, self.rule, self.f)]
+                said = [word(row, cmd.dot) | word(rows[0], cmd.dot) for row in rows]
+                if self.rule == "epaxos":
+                    fast &= all(one == said[0] for one in said)
+                else:
+                    fast &= all(
+                        sum(dep in one for one in said) >= self.f
+                        for dep in frozenset().union(*said)
+                    )
+                accepted &= sum(shard * n + m < live for m in range(n)) >= self.write_quorum
+                reports.update(zip(rows, said))
+                for row in rows:
+                    for key, found in own[row][cmd.dot].items():
+                        by_key[key] |= found
             slow_paths += not fast
             verdicts[cmd.dot] = Verdict(
-                reports={m: frozenset(self.dot_at[d] for d in r) for m, r in reports.items()},
-                fast=fast, committed=fast or live >= self.write_quorum,
-                deps=frozenset(self.dot_at[d] for d in frozenset().union(*said)),
+                reports={row: dots(said) for row, said in reports.items()},
+                fast=fast, committed=fast or accepted,
+                deps=dots(frozenset().union(*reports.values())),
                 executed=False,
+                by_key={key: dots(found) for key, found in by_key.items()},
             )
 
         components = self._execute(working, verdicts)
@@ -230,14 +311,19 @@ class Reference:
         by_dot = {cmd.dot: cmd for cmd in working}
         for dot in sorted(order, key=self.arrival.__getitem__):
             cmd = by_dot[dot]
-            for replica in range(min(live, n)):
-                entry = self.learnt[replica].setdefault(cmd.key, _Latest())
-                if cmd.read:
-                    entry.read = self.arrival[dot]
-                else:
-                    entry.write = self.arrival[dot]
+            for key in cmd.keys:
+                for row in range(key % shards * n, (key % shards + 1) * n):
+                    if row < live:
+                        # (reads commute: one carried behind a write of
+                        # another of its keys may execute a round after a
+                        # read that arrived later; the later arrival stays)
+                        entry = self.learnt[row].setdefault(key, _Latest())
+                        if cmd.read:
+                            entry.read = max(entry.read or 0, self.arrival[dot])
+                        else:
+                            entry.write = max(entry.write or 0, self.arrival[dot])
         self.carried = [cmd for cmd in working if cmd.dot not in self.executed]
-        return Round(verdicts, order, components, slow_paths)
+        return Round(verdicts, order, components, slow_paths, by_dot, shards)
 
     def _execute(self, working: List[Command], verdicts: Dict[Dot, Verdict]) -> List[List[Dot]]:
         """Tarjan over the committed commands of the working set; a component

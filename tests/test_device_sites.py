@@ -30,7 +30,7 @@ def _put(client, seq, key):
 
 
 def _runtime(protocol="epaxos", n=5, **kwargs):
-    config = Config(n, 1, shard_count=kwargs.pop("shard_count", 1))
+    config = Config(n, kwargs.pop("f", 1), shard_count=kwargs.pop("shard_count", 1))
     port = free_port()
     runtime = DeviceRuntime(config, ("127.0.0.1", port), protocol=protocol, batch_size=16,
                             key_buckets=64, pending_capacity=16, **kwargs)
@@ -140,9 +140,9 @@ def test_a_second_sites_hello_makes_the_second_program_ready_before_its_ack():
     ("newt", {}, 1, "one coordinator"),
     ("caesar", {"n": 7}, 1, "one coordinator"),
     ("fpaxos", {}, 1, "one coordinator"),
-    ("atlas", {}, 1, "one coordinator"),
-    ("epaxos", {"shard_count": 2}, 1, "one coordinator"),
-    ("epaxos", {"key_width": 2}, 1, "one coordinator"),
+    ("atlas", {"f": 2}, 1, "one coordinator"),
+    ("atlas", {"f": 2, "shard_count": 2}, 1, "atlas at f = 1"),
+    ("atlas", {"f": 2, "key_width": 2}, 1, "atlas at f = 1"),
 ])
 def test_a_site_that_cannot_be_served_is_refused_before_the_ack(protocol, kwargs, site, why, caplog):
     async def go():
@@ -193,8 +193,9 @@ def test_dots_are_a_coordinators_and_two_sites_never_collide_in_a_registry():
     lambda: NewtDeviceDriver(5, batch_size=8, key_buckets=64, pending_capacity=8),
     lambda: CaesarDeviceDriver(7, batch_size=8, key_buckets=64, pending_capacity=8),
     lambda: PaxosDeviceDriver(5, batch_size=8, pending_capacity=8),
-    lambda: DeviceDriver(5, rule="atlas", batch_size=8, key_buckets=64, pending_capacity=8),
-    lambda: DeviceDriver(5, batch_size=8, key_buckets=64, pending_capacity=8, key_width=2),
+    lambda: DeviceDriver(5, rule="atlas", f=2, batch_size=8, key_buckets=64, pending_capacity=8),
+    lambda: DeviceDriver(5, rule="atlas", f=2, batch_size=8, key_buckets=64, pending_capacity=8,
+                         key_width=2, shard_count=2),
 ])
 def test_a_driver_with_one_coordinator_takes_site_0_and_no_other(build):
     driver = build()
@@ -216,4 +217,101 @@ def test_the_one_site_program_is_the_round_without_a_sites_argument():
     assert not one  # the one-site program was never asked for
     program, shardings = two
     assert len(shardings) == len(driver._column_specs())
-    assert len(mesh_step.SITE_ROUND_TALLIES) + len(mesh_step.SITE_ROUND_GAUGES) == 10
+    assert len(mesh_step.SITE_ROUND_TALLIES) + len(mesh_step.SITE_ROUND_GAUGES) == 12
+
+
+@pytest.mark.parametrize("protocol, shard_count, key_width", [
+    ("atlas", 4, 2), ("epaxos", 4, 2), ("epaxos", 1, 2), ("atlas", 1, 1), ("epaxos", 2, 1),
+])
+def test_a_hello_with_a_site_is_served_on_a_sharded_server_of_several_keys(
+        protocol, shard_count, key_width):
+    """Janus* as the benchmark runs it (`--protocol atlas -f 1 --shard-count 4
+    --device-key-width 2`), and EPaxos's rule on one shard and four: the second
+    program, made with the driver's own shard count, `f`, rule and key width,
+    is ready before the hello of a site other than 0 is acknowledged; two
+    sites' commands over the same two keys of two shards take their
+    coordinators' dots, disagree, and execute."""
+    obs.subscribe_recompiles()
+
+    def put(client, seq):  # one key on shard 0, one on the last shard
+        op = (KVOp.put(f"{client}:{seq}"),)
+        if key_width == 1:
+            return Command(Rifl(client, seq), {0: {"a": op}})
+        if shard_count == 1:
+            return Command(Rifl(client, seq), {0: {"a": op, "b": op}})
+        return Command(Rifl(client, seq), {0: {"a": op}, shard_count - 1: {"b": op}})
+
+    async def call(rw, cmd):  # a reply a shard touched
+        await rw.send(Submit(cmd))
+        for _ in range(len(cmd._shard_to_ops)):
+            reply = await rw.recv()
+            assert isinstance(reply, ToClient) and reply.cmd_result.rifl == cmd.rifl
+
+    async def go():
+        runtime, port = _runtime(protocol, shard_count=shard_count, key_width=key_width)
+        await runtime.start()
+        try:
+            driver = runtime.driver
+            assert driver.serves_sites and driver.rule == ("atlas" if protocol == "atlas" else "epaxos")
+            rw0, writer0, ack = await _hello(port, ClientHi([1]))
+            await call(rw0, put(1, 1))
+            assert driver._site_program is None and driver.stages.n["precompile"] == 1
+            one_site = driver.resolver
+            rw3, writer3, ack = await _hello(port, ClientHi([2], site=3))
+            # the ack came after the program: nothing was dispatched in between
+            assert isinstance(ack, ClientHiAck)
+            assert driver._site_program is not None and driver.stages.n["precompile"] == 2
+            assert driver.precompiled_programs == 2 and driver.sites_registered == 2
+            assert one_site == ("run_position" if key_width == 1 else "general")
+            assert runtime.backend_report()["resolver"] == (
+                "key_runs" if key_width == 1 else "general_components")
+            compiled = obs.recompile_count() + obs.cache_hit_count()
+            for seq in range(2, 8):
+                await asyncio.gather(call(rw0, put(1, seq)), call(rw3, put(2, seq)))
+            assert obs.recompile_count() + obs.cache_hit_count() == compiled  # nothing since
+            assert driver.executed == 13 and driver.in_flight == 0
+            tallies = runtime._tallies
+            assert tallies["sites_registered"] == 2
+            assert tallies["finisher_rows"] == 0 and "scc_span_rows" in tallies
+            if protocol == "atlas":  # f = 1: the fast path is unconditional
+                assert driver.slow_paths == 0
+            assert tallies["cross_shard_executed"] == (
+                13 if key_width > 1 and shard_count > 1 else 0)
+            # the coordinators' dots: site 0 is the process itself, site 3 process 4
+            gen0, gen3 = runtime.register_site(0), runtime.register_site(3)
+            assert gen0() == Dot(1, 8) and gen3() == Dot(4, 7)
+            writer0.close()
+            writer3.close()
+        finally:
+            await runtime.stop()
+
+    asyncio.run(go())
+
+
+@pytest.mark.parametrize("protocol, shard_count", [("atlas", 4), ("epaxos", 4), ("epaxos", 1)])
+def test_two_sites_two_shard_commands_take_their_coordinators_dots_and_one_component(
+        protocol, shard_count):
+    """Commands of sites 0 and 2 over the same two keys, one on the first shard
+    and one on the last, in one round: each takes its coordinator's dot, the
+    site program's rings disagree about their order (site 2's replica is in
+    site 0's quorum and has its own command first), and they execute as one
+    component that spans both keys and, on four shards, two shards."""
+    runtime, _port = _runtime(protocol, shard_count=shard_count, key_width=2)
+    gens = [runtime.register_site(site) for site in (0, 2)]
+    driver = runtime.driver
+    assert driver.sites_registered == 2 and driver.resolver == "general_components"
+    op = (KVOp.put("v"),)
+    batch = []
+    for at in range(6):
+        dot = gens[at % 2]()
+        keys = {0: {"a": op}, shard_count - 1: {"b": op}} if shard_count > 1 else {0: {"a": op, "b": op}}
+        batch.append((dot, Command(Rifl(1 + at, 1), keys)))
+    assert [dot.source for dot, _ in batch] == [1, 3] * 3
+    results = driver.serve([batch])
+    assert len({(r.rifl.source, r.rifl.sequence) for r in results}) == 6
+    tallies = driver.round_tallies
+    assert tallies["scc_rows"] == tallies["scc_span_rows"] == 6 and tallies["scc_count"] == 1
+    assert tallies["scc_shard_rows"] == (6 if shard_count > 1 else 0)
+    assert tallies["finisher_rows"] == 0 and tallies["resolve_iters"] >= 1
+    assert (driver.slow_paths == 0) == (protocol == "atlas")
+    assert driver.executed == 6 and driver.in_flight == 0

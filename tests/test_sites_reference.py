@@ -1,8 +1,10 @@
 """The round with a coordinator at every site (`parallel/mesh_step.py`
-`protocol_step(sites=n)`, resolved by `ops/graph_resolve.resolve_key_runs` and
-finished by `executor/graph/deps_graph.tarjan_order`) against the plain
-reference `tests/sites_reference.py`, on seeded rounds at small sizes: batch
-64 to 256, 16 to 64 keys, conflict rates 0 / 50 / 100, clients at 1 to 5
+`protocol_step(sites=n)`, resolved by `ops/graph_resolve.resolve_key_runs`
+with one key a command and by `resolve_general`'s components pass with
+several, and finished by `executor/graph/deps_graph.tarjan_order`) against
+the plain reference `tests/sites_reference.py`, on seeded rounds at small
+sizes: batch 64 to 256, 16 to 64 keys a shard, conflict rates 0 / 50 / 100,
+key width 1 / 2 / 3, 1 / 2 / 4 shards, both rules, clients at 1 to 5
 sites, with and without reads, with every replica live and with rows carried
 by rounds under the write quorum.  Round by round: each quorum member's
 report, `fast`, the committed dependencies as sets, what executed, the
@@ -51,8 +53,10 @@ def _mesh():
 
 
 @functools.lru_cache(maxsize=None)
-def _step(live):
-    return mesh_step.jit_protocol_step(_mesh(), live_replicas=live, sites=N, site_base=1)
+def _step(live, shards=1, rule="epaxos"):
+    return mesh_step.jit_protocol_step(
+        _mesh(), live_replicas=live, shard_count=shards, rule=rule, sites=N, site_base=1
+    )
 
 
 def conflict_commands(rng, fill, keys, rate, sites, read_share, first_seq):
@@ -75,62 +79,90 @@ def per_key(order, commands):
     alone), so keys with reads are held to the graph, not to a sequence."""
     out = {}
     for dot in order:
-        out.setdefault(commands[dot].key, []).append(dot)
+        for key in commands[dot].keys:
+            out.setdefault(key, []).append(dot)
     return {key: dots for key, dots in out.items() if not any(commands[d].read for d in dots)}
+
+
+def several_keys_commands(rng, fill, keys, shards, width, sites, read_share, first_seq):
+    """`fill` commands of `width` distinct keys each, drawn with a skew over
+    `shards` x `keys` buckets (bucket `b` is shard `b % shards`'s); a command
+    reads all its keys or writes them all, as `kv_multi`'s do."""
+    out = []
+    for at in range(fill):
+        site = int(rng.integers(0, sites))
+        chosen = set()
+        while len(chosen) < width:
+            chosen.add(int(shards * keys * rng.random() ** 2))
+        out.append(plain.Command(1 + site, first_seq + at, tuple(sorted(chosen)),
+                                 bool(rng.random() < read_share), site))
+    return out
 
 
 class Rounds:
     """The device round and the reference, fed the same commands."""
 
-    def __init__(self, batch, keys, pending, seed):
+    def __init__(self, batch, keys, pending, seed, width=1, shards=1, rule="epaxos"):
         self.batch, self.keys, self.pending = batch, keys, pending
+        self.width, self.shards, self.rule = width, shards, rule
         self.state = mesh_step.init_state(
-            _mesh(), N, key_buckets=keys, pending_capacity=pending, key_width=1
+            _mesh(), N * shards, key_buckets=keys * shards, pending_capacity=pending,
+            key_width=width,
         )
-        self.reference = plain.Reference(N)
+        self.reference = plain.Reference(N, shards, rule)
         self.rng = np.random.default_rng(seed)
         self.sent = 0
         self.dot_of = {}  # gid -> dot
         self.commands = {}  # dot -> plain.Command
-        self.slow_paths = self.finished = self.scc_rows = 0
+        self.slow_paths = self.finished = self.scc_rows = self.span_rows = self.shard_rows = 0
 
     def commands_for(self, fill, rate, sites, read_share):
         out = conflict_commands(self.rng, fill, self.keys, rate, sites, read_share, self.sent + 1)
         self.sent += fill
         return out
 
-    def round(self, commands, live=N):
+    def round(self, commands, live=None):
         """One round on both; everything compared; returns the device's output."""
-        batch = self.batch
-        key = np.full(batch, mesh_step.KEY_PAD, np.int32)
+        batch, shards = self.batch, self.shards
+        live = N * shards if live is None else live
+        key = np.full((batch, self.width), mesh_step.KEY_PAD, np.int32)
         src, seq = np.zeros(batch, np.int32), np.zeros(batch, np.int32)
         read = np.zeros(batch, bool)
         first = int(self.state.next_gid)
         for i, cmd in enumerate(commands):
-            key[i], src[i], seq[i], read[i] = cmd.key, cmd.src, cmd.seq, cmd.read
+            key[i, : len(cmd.keys)], src[i], seq[i], read[i] = cmd.keys, cmd.src, cmd.seq, cmd.read
             self.dot_of[first + i] = cmd.dot
             self.commands[cmd.dot] = cmd
         want = self.reference.round(commands, live)
-        self.state, out = _step(live)(
+        self.state, out = _step(live, shards, self.rule)(
             self.state, jnp.asarray(key), jnp.asarray(src), jnp.asarray(seq), jnp.asarray(read)
         )
         gids = np.asarray(out.gids)
-        deps = np.asarray(out.deps_gid)
+        fast_quorum = self.reference.fast_quorum
+        # by key slot, member of the slot's ring and class (write, read)
+        deps = np.asarray(out.deps_gid).reshape(len(gids), self.width, fast_quorum, 2)
         fast, executed = np.asarray(out.fast_path), np.asarray(out.resolved)
         finish = np.asarray(out.finish)
         rows = {self.dot_of[int(g)]: w for w, g in enumerate(gids) if int(g) in self.dot_of}
         assert set(rows) == set(want.verdicts)  # the working set: what was carried, what came
 
         def dots(columns):
-            return frozenset(self.dot_of[int(g)] for g in columns if g >= 0)
+            return frozenset(self.dot_of[int(g)] for g in np.ravel(columns) if g >= 0)
 
         for dot, verdict in want.verdicts.items():
             w, cmd = rows[dot], self.commands[dot]
-            # member k of the coordinator's ring stands in columns 2k, 2k + 1;
-            # its report is its own word joined with the coordinator's
-            for k, member in enumerate(plain.fast_quorum(cmd.site, N)):
-                got = dots(deps[w, 2 * k: 2 * k + 2]) | dots(deps[w, :2])
-                assert got == verdict.reports[member], (dot, member, got, verdict)
+            # member k of a key slot's ring stands in that slot's columns 2k,
+            # 2k + 1; a member's report is its words on the command's keys
+            # of its shard, joined with the coordinator's
+            for shard in {key % shards for key in cmd.keys}:
+                at = [a for a, key in enumerate(cmd.keys) if key % shards == shard]
+                ring = plain.fast_quorum(cmd.site, N, self.rule)
+                for k, member in enumerate(ring):
+                    got = dots(deps[w, at, k]) | dots(deps[w, at, 0])
+                    assert got == verdict.reports[shard * N + member], (dot, member, got, verdict)
+            for a, key in enumerate(cmd.keys):
+                assert dots(deps[w, a]) == verdict.by_key[key], (dot, key, verdict)
+            assert not (deps[w, len(cmd.keys):] >= 0).any()
             assert dots(deps[w]) == verdict.deps, (dot, verdict)
             assert bool(fast[w]) == verdict.fast, (dot, verdict)
             assert bool(executed[w]) == verdict.executed, (dot, verdict)
@@ -143,7 +175,7 @@ class Rounds:
             at = {int(gids[w]): i for i, w in enumerate(left)}
             ordered += [left[i] for i in tarjan_order(
                 [Dot(*self.dot_of[int(gids[w])]) for w in left],
-                [sorted({at[int(g)] for g in deps[w] if int(g) in at}) for w in left], N)[0]]
+                [sorted({at[int(g)] for g in deps[w].ravel() if int(g) in at}) for w in left], N)[0]]
         order = [self.dot_of[int(gids[w])] for w in ordered]
         assert sorted(order) == sorted(want.order)
         assert per_key(order, self.commands) == per_key(want.order, self.commands)
@@ -163,13 +195,16 @@ class Rounds:
         assert tallies["deps_committed"] == tally["deps_committed"]
         assert tallies["finisher_rows"] == len(left)
         assert tallies["read_rows"] == sum(self.commands[d].read for d in want.order)
+        assert tallies["cross_shard_executed"] == tally["cross_shard_executed"]
         if not left:  # else the finisher's components join the device's: the driver's sum
-            for name in ("scc_rows", "scc_count", "scc_rows_max"):
+            for name in ("scc_rows", "scc_count", "scc_rows_max", "scc_span_rows", "scc_shard_rows"):
                 assert tallies[name] == tally[name], (name, tallies, tally)
-        assert tallies["resolve_iters"] >= 1
+        assert tallies["resolve_iters"] >= (1 if self.width == 1 else 0)
         self.slow_paths += want.slow_paths
         self.finished += len(left)
         self.scc_rows += tally["scc_rows"]
+        self.span_rows += tally["scc_span_rows"]
+        self.shard_rows += tally["scc_shard_rows"]
         return out, want
 
 
@@ -228,6 +263,102 @@ def test_the_references_components_are_the_host_tarjans():
             cut.append(sorted(dots[row] for row in order[at: at + len(component)]))
             at += len(component)
         assert sorted(cut) == sorted(sorted(c) for c in found)
+
+
+# --- several keys a command, shards, both rules ---------------------------------
+
+
+@pytest.mark.parametrize("read_share", (0.0, 0.5))
+@pytest.mark.parametrize("rule", ("epaxos", "atlas"))
+@pytest.mark.parametrize("sites", (1, 2, 5))
+@pytest.mark.parametrize("width, shards", [(1, 2), (1, 4), (2, 1), (2, 2), (2, 4), (3, 1), (3, 4)])
+def test_the_round_of_several_keys_and_shards_agrees_with_the_plain_reference(
+        width, shards, sites, rule, read_share):
+    """Three rounds of skewed `width`-key commands over `shards` shards, the
+    second part-full: every round equal to the reference's, member by member,
+    key by key, component by component."""
+    batch, keys = (64, 16) if width == 2 else (128, 32)
+    rounds = Rounds(batch, keys, pending=batch, seed=7 * width + shards + sites,
+                    width=width, shards=shards, rule=rule)
+    for r in range(3):
+        fill = batch if r != 1 else int(rounds.rng.integers(1, batch))
+        rounds.round(several_keys_commands(
+            rounds.rng, fill, keys, shards, width, sites, read_share, rounds.sent + 1))
+        rounds.sent += fill
+    assert not rounds.reference.carried and len(rounds.reference.executed) == rounds.sent
+    if rule == "atlas":  # f = 1: whoever reported a dependency is one of f
+        assert rounds.slow_paths == 0
+    if sites == 1:
+        assert rounds.slow_paths == 0 and rounds.scc_rows == 0 and rounds.finished == 0
+    else:  # the case is what it says: cycles, and with several keys across them
+        assert rounds.scc_rows > 0
+        assert (rounds.span_rows > 0) == (width > 1)
+        assert (rounds.shard_rows > 0) == (width > 1 and shards > 1)
+    if width > 1:  # the whole working set fits the components pass's residual
+        assert rounds.finished == 0
+
+
+@pytest.mark.parametrize("rule", ("epaxos", "atlas"))
+@pytest.mark.parametrize("width, shards", [(2, 1), (2, 4), (3, 2)])
+def test_rows_of_several_keys_carried_under_the_write_quorum_agree_too(width, shards, rule):
+    """Two rounds with everyone live, three with the last shard one short of
+    its write quorum (a command of that shard that missed the fast path is not
+    accepted there, so it commits on none of its shards, and whatever reaches
+    it waits), three live again.  Under Atlas's rule at `f` = 1 nothing misses
+    the fast path, so nothing is carried."""
+    rounds = Rounds(64, 16, pending=192, seed=5 * width + shards, width=width,
+                    shards=shards, rule=rule)
+    short = N * (shards - 1) + rounds.reference.write_quorum - 1
+    carried = 0
+    for r, live in enumerate([None] * 2 + [short] * 3 + [None] * 3):
+        fill = 64 if r % 2 == 0 else int(rounds.rng.integers(1, 64))
+        out, _ = rounds.round(several_keys_commands(
+            rounds.rng, fill, 16, shards, width, 5, 0.5, rounds.sent + 1), live)
+        rounds.sent += fill
+        carried += int(out.pending)
+    assert (carried > 0) == (rule == "epaxos")
+    assert not rounds.reference.carried and len(rounds.reference.executed) == rounds.sent
+
+
+def test_a_working_set_beyond_the_residual_goes_to_the_finisher_whole():
+    """1024 working rows give the components pass a residual of 256; 512
+    two-key commands over 2 x 8 keys nearly all wait for another, so the pass
+    hands every one that waits to the host's Tarjan (`finish`), the rows that
+    wait for none run first, and the round still equals the reference's."""
+    rounds = Rounds(512, 8, pending=512, seed=11, width=2, shards=2, rule="atlas")
+    for _ in range(2):
+        out, want = rounds.round(several_keys_commands(
+            rounds.rng, 512, 8, 2, 2, 5, 0.5, rounds.sent + 1))
+        rounds.sent += 512
+        assert int(np.asarray(out.finish).sum()) > 512 - 32
+    assert rounds.finished > 0 and not rounds.reference.carried
+
+
+def test_three_commands_on_three_keys_of_two_shards_with_no_mutual_edge_run_in_dot_order():
+    """Built by hand: x = write(k2, k3) and then z = write(k2, k3), both at
+    site 0, then y = write(k1, k2) at site 1; k2 on shard 0, k1 and k3 on
+    shard 1.  Replica 1 of shard 0, in x's quorum, has its own y first, so x
+    finds y on k2; y's other members find z, the latest before it there, and
+    not x; z finds x on both its keys.  x -> y -> z -> x: one component, no
+    two of them finding each other, its dependencies on two keys, its rows on
+    three keys of both shards.  (Two commands alone on a key always
+    leave a mutual edge: the later one finds the earlier one.)  They execute
+    together, in dot order."""
+    rounds = Rounds(8, 2, pending=8, seed=0, width=2, shards=2, rule="atlas")
+    x = plain.Command(1, 1, (2, 3), False, 0)
+    z = plain.Command(1, 2, (2, 3), False, 0)
+    y = plain.Command(2, 1, (1, 2), False, 1)
+    out, want = rounds.round([x, z, y])
+    found = {cmd.dot: want.verdicts[cmd.dot].deps for cmd in (x, y, z)}
+    assert found == {x.dot: {y.dot}, y.dot: {z.dot}, z.dot: {x.dot}}
+    assert want.components == [[x.dot, z.dot, y.dot]] == [want.order]
+    assert want.verdicts[z.dot].by_key == {2: {x.dot}, 3: {x.dot}}
+    tallies = dict(zip(mesh_step.SITE_ROUND_TALLIES, np.asarray(out.tallies).tolist()))
+    assert tallies["scc_rows"] == tallies["scc_span_rows"] == tallies["scc_shard_rows"] == 3
+    assert tallies["scc_count"] == 1 and tallies["finisher_rows"] == 0
+    # the batch's rows stand after the 8 pending ones (its padding runs first)
+    order = [w for w in np.asarray(out.order).tolist() if w in (8, 9, 10)]
+    assert order == [8, 9, 10] and np.asarray(out.resolved)[8:11].all()  # x, z, y
 
 
 # --- through the driver ---------------------------------------------------------
@@ -305,6 +436,80 @@ def test_the_served_round_executes_in_the_references_order(rate, read_share):
         assert driver.round_tallies["finisher_rows"] == 0 and driver.stages.n["finish"] == 0
 
 
+def _names_of_buckets(keys, shards):
+    """`{bucket: (shard, key name)}` for the buckets `0 .. keys * shards - 1`:
+    bucket `b` is shard `b % shards`'s, and the driver hashes a key's name to
+    one of its shard's."""
+    names, at = {}, 0
+    while len(names) < keys * shards:
+        for shard in range(shards):
+            names.setdefault(_bucket(shard, str(at), keys * shards, shards), (shard, str(at)))
+        at += 1
+    return names
+
+
+@pytest.mark.parametrize("read_share", (0.0, 0.5))
+@pytest.mark.parametrize("rule, width, shards", [
+    ("atlas", 2, 4), ("epaxos", 2, 4), ("epaxos", 2, 1), ("atlas", 3, 2), ("epaxos", 1, 2),
+])
+def test_the_served_round_of_several_keys_executes_in_the_references_order(
+        rule, width, shards, read_share):
+    """`DeviceDriver.serve` under either rule with clients at five sites and
+    commands of several keys over several shards: what it executes, round by
+    round, is the reference's order key by key, every component together and
+    in dot order; its tallies are the reference's."""
+    keys, batch = 16, 128
+    names = _names_of_buckets(keys, shards)
+    driver = DeviceDriver(N, batch_size=batch, key_buckets=keys * shards, key_width=width,
+                          shard_count=shards, pending_capacity=batch, mesh=_mesh(), rule=rule)
+    assert driver.serves_sites
+    for site in range(N):
+        driver.register_site(site)
+    assert driver.sites_registered == N
+    assert driver.resolver == ("key_runs" if width == 1 else "general_components")
+    reference = plain.Reference(N, shards, rule)
+    rng = np.random.default_rng(3 * width + shards)
+    sent, want_tally = 0, dict.fromkeys(
+        ("scc_rows", "scc_count", "scc_span_rows", "scc_shard_rows", "cross_shard_executed"), 0)
+    for r in range(4):
+        fill = batch if r != 2 else 57
+        commands = several_keys_commands(rng, fill, keys, shards, width, N, read_share, sent + 1)
+        sent += fill
+        batch_in = []
+        for cmd in commands:
+            op = KVOp.get() if cmd.read else KVOp.put(f"{cmd.src}:{cmd.seq}")
+            by_shard = {}
+            for key in cmd.keys:
+                shard, name = names[key]
+                by_shard.setdefault(shard, {})[name] = (op,)
+            batch_in.append((Dot(cmd.src, cmd.seq), Command(Rifl(cmd.src, cmd.seq), by_shard)))
+        by_dot = {cmd.dot: cmd for cmd in commands}
+        want = reference.round([by_dot[d.source, d.sequence] for d, _ in _sites_in_turn(batch_in)])
+        got = []
+        for result in driver.serve([batch_in]):  # a result a key: a command's stand together
+            dot = (result.rifl.source, result.rifl.sequence)
+            if not got or got[-1] != dot:
+                got.append(dot)
+        assert sorted(got) == sorted(want.order) and len(got) == fill
+        assert per_key(got, by_dot) == per_key(want.order, by_dot)
+        place = {dot: at for at, dot in enumerate(got)}
+        for component in want.components:
+            places = [place[dot] for dot in component]
+            assert places == list(range(places[0], places[0] + len(component))), component
+            for dot in component:
+                for dep in want.verdicts[dot].deps - set(component):
+                    assert dep not in place or place[dep] < places[0], (dot, dep)
+        for name, count in want.tally().items():
+            if name in want_tally:
+                want_tally[name] += count
+    assert driver.executed == sent and driver.in_flight == 0
+    assert driver.round_tallies["finisher_rows"] == 0 or width == 1
+    got_tally = {name: driver.round_tallies[name] for name in want_tally}
+    if width > 1:
+        assert got_tally == want_tally and want_tally["scc_span_rows"] > 0
+    assert (driver.slow_paths == 0) == (rule == "atlas")
+
+
 @pytest.mark.slow
 def test_two_hundred_rounds_at_the_cells_shape_agree_with_the_reference():
     """On the chip, by hand, outside pytest (`tests/conftest.py` holds pytest
@@ -356,3 +561,86 @@ def test_two_hundred_rounds_at_the_cells_shape_agree_with_the_reference():
     assert 0.4 * executed < driver.slow_paths < 0.55 * executed
     print(f"200 rounds, {executed} commands, scc_rows {scc_rows}, "
           f"slow_paths {driver.slow_paths}, on {jax.default_backend()}")
+
+
+@pytest.mark.slow
+def test_two_hundred_rounds_at_the_several_keys_cells_shape_agree_with_the_reference():
+    """On the chip, by hand, outside pytest, as the case above: `chiprun --
+    python3 -c "from tests.test_sites_reference import
+    test_two_hundred_rounds_at_the_several_keys_cells_shape_agree_with_the_reference
+    as t; t()"`.  200 rounds at the shape of
+    `atlas_n5_4shard_2key_5site.ycsbt_w50_zipf07_5site_sat` (Atlas's rule, f =
+    1, 4 shards x n=5, 4,194,304 buckets, key width 2, batch and pending 4096)
+    of that cell's traffic (two distinct zipf-0.7 keys over 4 x 1,000,000, half
+    the commands reads, 8192 clients over five sites) through
+    `DeviceDriver.serve`: the execution order compared with the reference's key
+    by key, every component together and in dot order, the components' tallies
+    round by round."""
+    from fantoch_tpu.utils import key_hash
+
+    buckets, batch, clients, shards, total = 4_194_304, 4096, 8192, 4, 4_000_000
+    driver = DeviceDriver(N, batch_size=batch, key_buckets=buckets, key_width=2, shard_count=shards,
+                          pending_capacity=batch, rule="atlas")
+    for site in range(N):
+        driver.register_site(site)
+    reference = plain.Reference(N, shards, "atlas")
+    rng = np.random.default_rng(49)
+    cdf = np.cumsum(np.arange(1, total + 1, dtype=np.float64) ** -0.7)
+    cdf /= cdf[-1]
+    seqs = [0] * N
+    next_of = {}
+    names = ("scc_rows", "scc_count", "scc_span_rows", "scc_shard_rows", "cross_shard_executed")
+    sums, executed, largest = dict.fromkeys(names, 0), 0, 0
+    for r in range(200):
+        fill = batch if r % 7 else int(rng.integers(1, batch))
+        ranks = 1 + np.searchsorted(cdf, rng.random((fill, 3)))
+        commands, batch_in = [], []
+        for client, drawn in zip(rng.permutation(clients)[:fill].tolist(), ranks.tolist()):
+            client += 1
+            site = (client - 1) % N  # kv_multi_sites: process p holds clients 1 + p, 6 + p, ...
+            seqs[site] += 1
+            chosen = list(dict.fromkeys(drawn))[:2]
+            if len(chosen) < 2:
+                chosen.append(chosen[0] % total + 1)
+            read = bool(rng.random() < 0.5)
+            next_of[client] = next_of.get(client, 0) + 1
+            op = (KVOp.get() if read else KVOp.put(f"{client}:{next_of[client]}"),)
+            by_shard, found = {}, []
+            for key in chosen:
+                shard = key_hash(str(key)) % shards
+                by_shard.setdefault(shard, {})[str(key)] = op
+                found.append(_bucket(shard, str(key), buckets, shards))
+            if found[0] == found[1]:  # two keys of one bucket: the driver's row holds it once
+                found = found[:1]
+            cmd = plain.Command(1 + site, seqs[site], tuple(sorted(found)), read, site)
+            commands.append(cmd)
+            batch_in.append((Dot(cmd.src, cmd.seq), Command(Rifl(client, next_of[client]), by_shard)))
+        by_dot = {cmd.dot: cmd for cmd in commands}
+        want = reference.round([by_dot[d.source, d.sequence] for d, _ in _sites_in_turn(batch_in)])
+        dot_of = {(c.rifl.source, c.rifl.sequence): (d.source, d.sequence) for d, c in batch_in}
+        got = []
+        for result in driver.serve([batch_in]):
+            dot = dot_of[result.rifl.source, result.rifl.sequence]
+            if not got or got[-1] != dot:
+                got.append(dot)
+        assert len(got) == len(want.order) == fill, r
+        assert per_key(got, by_dot) == per_key(want.order, by_dot), r
+        place = {dot: at for at, dot in enumerate(got)}
+        for component in want.components:
+            if len(component) > 1:
+                places = [place[dot] for dot in component]
+                assert places == list(range(places[0], places[0] + len(component))), (r, component)
+        tally = want.tally()
+        for name in names:
+            sums[name] += tally[name]
+        executed += fill
+        largest = max(largest, tally["scc_rows_max"])
+        if tally["scc_rows_max"]:
+            assert driver.round_gauges["scc_rows_max"] == tally["scc_rows_max"], r
+    assert {name: driver.round_tallies[name] for name in names} == sums
+    assert driver.executed == executed and driver.slow_paths == 0
+    assert driver.round_tallies["finisher_rows"] == 0
+    assert 0.03 * executed < sums["scc_span_rows"] <= sums["scc_rows"] < 0.3 * executed
+    print(f"200 rounds, {executed} commands, {sums}, largest component {largest}, "
+          f"resolve_iters {driver.round_tallies['resolve_iters']}, finisher_rows 0, "
+          f"on {jax.default_backend()}")
