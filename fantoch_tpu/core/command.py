@@ -3,6 +3,11 @@
 Reference: fantoch/src/command.rs:12-262.  A command is a map
 ``shard -> key -> op`` identified by a Rifl; conflict = key intersection;
 results aggregate per-key op results until all keys have reported.
+
+A command has two forms of its ops, and is born with one: the dicts
+``shard -> key -> (KVOp, ...)`` its constructor is given, or the tuple of
+plain values its frame carried (``Command.__reduce__``).  Each is built from
+the other at its first use and kept.
 """
 
 from __future__ import annotations
@@ -16,15 +21,37 @@ if TYPE_CHECKING:
     from fantoch_tpu.executor.base import ExecutorResult
 
 
-class Command:
-    """A client command spanning one or more shards (fantoch/src/command.rs:12-170)."""
+# how many values the flat form of a command's ``_wire`` has (one shard, one
+# key, one op); the nested form has three
+FLAT = 6
 
-    __slots__ = ("_rifl", "_shard_to_ops", "_read_only", "_total_key_count")
+
+class Command:
+    """A client command spanning one or more shards (fantoch/src/command.rs:12-170).
+
+    ``_shard_to_ops`` is the dict form of its ops, ``shard -> key -> (KVOp,
+    ...)``; ``_wire`` the plain values it goes on the wire as: ``(source,
+    sequence, shard, key, kind code, value)`` where it has one shard, one key
+    and one op (``FLAT`` values), ``(source, sequence, ((shard, ((key,
+    ((kind code, value), ...)), ...)), ...))`` otherwise, shards and keys in
+    the dicts' order, a kind's code its index in ``KINDS``.  A command made
+    by the constructor, ``from_single`` or ``from_keys`` is born with the
+    dicts, one restored from a frame with the frame's own tuple
+    (``_off_wire``), and the slot of the other form stays unset until
+    something reads it (``__getattr__``: the dicts of a command off the wire
+    are built for the caller that asks, which on the served path nobody
+    does).  Every answer is the same whichever form a command was born
+    with."""
+
+    __slots__ = (
+        "_rifl", "_wire", "_shard_to_ops", "_read_only", "_total_key_count", "_off_wire",
+    )
 
     def __init__(self, rifl: Rifl, shard_to_ops: Dict[ShardId, Dict[Key, Tuple[KVOp, ...]]]):
         assert shard_to_ops, "commands must have at least one shard"
         self._rifl = rifl
         self._shard_to_ops = shard_to_ops
+        self._off_wire = False
         # read_only inference (fantoch/src/command.rs:28-36): a command is
         # read-only iff every op on every key is a read.  One pass over the
         # ops — this constructor sits on the client submit path, so no
@@ -58,7 +85,20 @@ class Command:
         cmd._shard_to_ops = {shard_id: {key: (op,)}}
         cmd._read_only = op.is_read
         cmd._total_key_count = 1
+        cmd._off_wire = False
         return cmd
+
+    def __getattr__(self, name: str):
+        # only an unset slot comes here: the form the command was not born
+        # with, built from the other (read through its slot: both unset is
+        # an AttributeError, not a loop) and kept
+        if name == "_shard_to_ops":
+            built = self._shard_to_ops = _dicts_of(_WIRE_SLOT.__get__(self))
+        elif name == "_wire":
+            built = self._wire = _wire_of(self._rifl, _DICTS_SLOT.__get__(self))
+        else:
+            raise AttributeError(name)
+        return built
 
     @staticmethod
     def from_keys(rifl: Rifl, shard_id: ShardId, key_ops: Dict[Key, Tuple[KVOp, ...]]) -> "Command":
@@ -154,36 +194,9 @@ class Command:
         return hash(self._rifl)
 
     def __reduce__(self):
-        # plain values on the wire: the rifl's two numbers, then the
-        # command's shape, kinds as their index in ``KINDS``.  One
-        # shard, one key, one op (what the command itself shows) goes
-        # flat; every other shape as nested tuples, shard -> key -> ops
-        # in the dicts' order.
-        rifl = self._rifl
-        shard_to_ops = self._shard_to_ops
-        single = self.single_key()
-        if single is not None:
-            shard_id, key = single
-            key_ops = shard_to_ops[shard_id][key]
-            if len(key_ops) == 1:
-                op = key_ops[0]
-                return _restore_command, (
-                    rifl[0], rifl[1], shard_id, key, _KIND_CODE[op.kind], op.value,
-                )
-        return _restore_command, (
-            rifl[0],
-            rifl[1],
-            tuple(
-                (
-                    shard_id,
-                    tuple(
-                        (key, tuple((_KIND_CODE[op.kind], op.value) for op in key_ops))
-                        for key, key_ops in ops.items()
-                    ),
-                )
-                for shard_id, ops in shard_to_ops.items()
-            ),
-        )
+        # plain values on the wire (the class's docstring): a command off
+        # the wire gives its frame's own tuple back
+        return _restore_command, self._wire
 
     def __repr__(self) -> str:
         keys = {s: sorted(ops) for s, ops in self._shard_to_ops.items()}
@@ -206,46 +219,121 @@ def _bind_executor_result():
 
 _KIND_CODE = {kind: code for code, kind in enumerate(KINDS)}
 _tuple_new = tuple.__new__
+_new_command = Command.__new__
+_WIRE_SLOT = Command.__dict__["_wire"]
+_DICTS_SLOT = Command.__dict__["_shard_to_ops"]
 
 
-def _restore_command(source: int, sequence: int, shard, key=None, kind=0, value=None) -> Command:
+def _wire_of(rifl: Rifl, shard_to_ops: Dict[ShardId, Dict[Key, Tuple[KVOp, ...]]]) -> tuple:
+    """The wire form of a command of ``shard_to_ops`` (``Command``'s
+    docstring)."""
+    if len(shard_to_ops) == 1:
+        for shard_id, ops in shard_to_ops.items():
+            if len(ops) == 1:
+                for key, key_ops in ops.items():
+                    if len(key_ops) == 1:
+                        op = key_ops[0]
+                        return (rifl[0], rifl[1], shard_id, key, _kind_code(op), op.value)
+    return (
+        rifl[0],
+        rifl[1],
+        tuple(
+            (
+                shard_id,
+                tuple(
+                    (key, tuple((_kind_code(op), op.value) for op in key_ops))
+                    for key, key_ops in ops.items()
+                ),
+            )
+            for shard_id, ops in shard_to_ops.items()
+        ),
+    )
+
+
+def _kind_code(op: KVOp) -> int:
+    code = _KIND_CODE.get(op.kind)
+    if code is None:
+        # in ``KVStore._do_execute``'s words: the store's one pass reads
+        # the code where the plain route reads the kind
+        raise AssertionError(f"unknown op kind {op.kind}")
+    return code
+
+
+def _dicts_of(wire: tuple) -> Dict[ShardId, Dict[Key, Tuple[KVOp, ...]]]:
+    """The dict form of a command of the wire form ``wire``.  A shard or a
+    key named twice keeps its last, as a dict does."""
+    if len(wire) == FLAT:
+        return {wire[2]: {wire[3]: (KVOp(KINDS[wire[4]], wire[5]),)}}
+    return {
+        shard_id: {
+            key: tuple([KVOp(KINDS[code], value) for code, value in key_ops])
+            for key, key_ops in keys
+        }
+        for shard_id, keys in wire[2]
+    }
+
+
+def _restore_command(*wire) -> Command:
     """Unpickle a :class:`Command` from the values its ``__reduce__``
-    carries: ``shard, key, kind, value`` of the flat form, or in
-    ``shard``'s place the general form's ``((shard, ((key, ((kind,
-    value), ...)), ...)), ...)``."""
+    carries."""
+    return _off_wire(wire)
+
+
+def _off_wire(wire: tuple) -> Command:
+    """The :class:`Command` of the tuple a frame unpickled to (``Command``'s
+    docstring has the two forms), which it keeps as it is: no ``KVOp``, no
+    dict.  What the constructor checks is checked here, on the tuple, with
+    a kind's code: a code outside ``KINDS`` (an ``IndexError``), no shard,
+    a ``Get`` in a command that writes."""
+    cmd = _new_command(Command)
     # tuple.__new__: Rifl's own __new__ is a Python-level call, and this
     # runs once a frame on the server's loop
-    rifl = _tuple_new(Rifl, (source, sequence))
-    if key is not None:
-        # Command.from_single, spelled out (kind code 0 is the one read)
-        cmd = Command.__new__(Command)
-        cmd._rifl = rifl
-        cmd._shard_to_ops = {shard: {key: (KVOp(KINDS[kind], value),)}}
+    cmd._rifl = _tuple_new(Rifl, wire[:2])
+    if len(wire) == FLAT and wire[3] is not None:
+        kind = wire[4]
+        KINDS[kind]
+        # Command.from_single (kind code 0 is the one read)
         cmd._read_only = not kind
         cmd._total_key_count = 1
-        return cmd
-    # the constructor's scan, folded into the pass that builds the ops
-    shard_to_ops: Dict[ShardId, Dict[Key, Tuple[KVOp, ...]]] = {}
-    reads = writes = total = 0
-    for shard_id, keys in shard:
-        ops = shard_to_ops[shard_id] = {}
-        for k, key_ops in keys:
-            ops[k] = tuple([KVOp(KINDS[code], v) for code, v in key_ops])
-            for code, _ in key_ops:
-                if code:
-                    writes += 1
-                else:
-                    reads += 1
-        total += len(ops)
-    assert shard_to_ops, "commands must have at least one shard"
-    assert reads == 0 or writes == 0, (
-        "non-read-only commands cannot contain Get operations"
-    )
-    cmd = Command.__new__(Command)
-    cmd._rifl = rifl
-    cmd._shard_to_ops = shard_to_ops
-    cmd._read_only = writes == 0
-    cmd._total_key_count = total
+    elif len(wire) == 3:
+        # the constructor's scan
+        nested = wire[2]
+        if not nested:
+            raise AssertionError("commands must have at least one shard")
+        ops = writes = total = 0
+        # a name twice: two shards (the shape of most commands of several
+        # keys) by one comparison, more by their dict
+        if len(nested) == 2:
+            twice = nested[0][0] == nested[1][0]
+        else:
+            twice = len(nested) > 2 and len(dict(nested)) != len(nested)
+        for _shard_id, keys in nested:
+            for _key, key_ops in keys:
+                for code, _ in key_ops:
+                    KINDS[code]
+                    if code:
+                        writes += 1
+                ops += len(key_ops)
+            if len(keys) > 1 and len(dict(keys)) != len(keys):
+                twice = True
+            total += len(keys)
+        if writes and writes != ops:
+            raise AssertionError("non-read-only commands cannot contain Get operations")
+        if twice:
+            # a shard or a key named twice, which no ``__reduce__`` gives:
+            # the command is what the dicts make of it
+            shard_to_ops = _dicts_of(wire)
+            wire = _wire_of(cmd._rifl, shard_to_ops)
+            total = sum(map(len, shard_to_ops.values()))
+        cmd._read_only = not writes
+        cmd._total_key_count = total
+    else:
+        raise TypeError(
+            "a command's values are (source, sequence, shard, key, kind, value) "
+            f"or (source, sequence, shards), not {wire!r}"
+        )
+    cmd._wire = wire
+    cmd._off_wire = True
     return cmd
 
 

@@ -11,6 +11,7 @@ from __future__ import annotations
 
 from dataclasses import dataclass
 from enum import Enum
+from operator import attrgetter
 from typing import Dict, List, Optional, TYPE_CHECKING
 
 if TYPE_CHECKING:
@@ -82,8 +83,11 @@ class KVStore:
             from fantoch_tpu.core.audit import ExecutionDigest
 
             self._digest = ExecutionDigest()
-        # commands ``execute_commands`` applied by its one-op spelling
+        # commands ``execute_commands`` applied by its one-op spelling, and
+        # those of them that came off the wire (``Command._off_wire``: read
+        # off their frame's own tuple, no dict form ever asked of them here)
         self.applied_in_pass = 0
+        self.applied_off_wire = 0
 
     @property
     def monitor(self) -> Optional["ExecutionOrderMonitor"]:
@@ -147,10 +151,14 @@ class KVStore:
         """Apply a round's commands in order, in one pass: what
         ``Command.execute`` a shard a command gives, the same results in
         the same order and the same store after (every shard of a command
-        where ``shard_id`` is None, the one shard otherwise).  On a
-        ``plain`` store a key's one op is spelled out on the dict, with no
-        call a command; several ops a key, and every op of a store that is
-        not plain, go through ``execute``."""
+        where ``shard_id`` is None, the one shard otherwise).  A command's
+        ops are read off its wire form (``Command._wire``: for a command
+        off a frame, the frame's own tuple), and on a ``plain`` store a
+        key's one op is spelled out on the dict by its kind's code, with
+        no call a command; several ops a key, a code the pass does not
+        spell, and every op of a store that is not plain, go through
+        ``execute`` in the command's dict form."""
+        from fantoch_tpu.core.command import FLAT
         from fantoch_tpu.executor.base import ExecutorResult
 
         spelled = self.plain
@@ -161,43 +169,56 @@ class KVStore:
         results: List["ExecutorResult"] = []
         append = results.append
         every_shard = shard_id is None
-        routed = set()  # commands with a key that went through ``execute``
-        for cmd in cmds:
+        # the commands with a key that went through ``execute`` (by id),
+        # and whether each came off the wire
+        routed: Dict[int, bool] = {}
+
+        def through_execute(cmd: "Command", shard: "ShardId", key: Key) -> None:
+            routed[id(cmd)] = cmd._off_wire
             rifl = cmd._rifl
-            if every_shard:
-                portions = cmd._shard_to_ops.values()
-            else:
-                ops = cmd._shard_to_ops.get(shard_id)
-                if ops is None:
-                    continue
-                portions = (ops,)
-            for ops in portions:
-                for key, key_ops in ops.items():
-                    if spelled and len(key_ops) == 1:
-                        op = key_ops[0]
-                        kind = op.kind
-                        if kind is _GET:
+            key_ops = cmd._shard_to_ops[shard][key]
+            append(ExecutorResult(rifl, key, tuple([execute(key, op, rifl) for op in key_ops])))
+
+        for cmd in cmds:
+            wire = cmd._wire
+            if len(wire) == FLAT:
+                # one shard, one key, one op: (source, sequence, shard, key, code, value)
+                if every_shard or wire[2] == shard_id:
+                    key = wire[3]
+                    code = wire[4] if spelled else None
+                    if code == 0:
+                        value = get(key)
+                    elif code == 1:
+                        # the previous value, as ``_put`` returns it
+                        value = get(key)
+                        store[key] = wire[5]
+                    elif code == 2:
+                        value = pop(key, None)
+                    else:
+                        through_execute(cmd, wire[2], key)
+                        continue
+                    # tuple.__new__: a NamedTuple's own __new__ is a
+                    # Python-level call (core/command.py, _off_wire)
+                    append(_tuple_new(ExecutorResult, (cmd._rifl, key, (value,))))
+                continue
+            for shard, keys in wire[2]:
+                if every_shard or shard == shard_id:
+                    for key, key_ops in keys:
+                        code = key_ops[0][0] if spelled and len(key_ops) == 1 else None
+                        if code == 0:
                             value = get(key)
-                        elif kind is _PUT_KIND:
-                            # the previous value, as ``_put`` returns it
+                        elif code == 1:
                             value = get(key)
-                            store[key] = op.value
-                        elif kind is _DELETE:
+                            store[key] = key_ops[0][1]
+                        elif code == 2:
                             value = pop(key, None)
                         else:
-                            value = execute(key, op, rifl)  # raises
-                        # tuple.__new__: a NamedTuple's own __new__ is a
-                        # Python-level call (core/command.py, _restore_command)
-                        append(_tuple_new(ExecutorResult, (rifl, key, (value,))))
-                    else:
-                        routed.add(id(cmd))
-                        append(
-                            ExecutorResult(
-                                rifl, key, tuple([execute(key, op, rifl) for op in key_ops])
-                            )
-                        )
+                            through_execute(cmd, shard, key)
+                            continue
+                        append(_tuple_new(ExecutorResult, (cmd._rifl, key, (value,))))
         if spelled:
             self.applied_in_pass += len(cmds) - len(routed)
+            self.applied_off_wire += sum(map(_OFF_WIRE, cmds)) - sum(routed.values())
         return results
 
 
@@ -205,5 +226,5 @@ class KVStore:
 _EXECUTE = KVStore.execute
 _DO_EXECUTE = KVStore._do_execute
 _PUT = KVStore._put
-_GET, _PUT_KIND, _DELETE = KINDS
 _tuple_new = tuple.__new__
+_OFF_WIRE = attrgetter("_off_wire")

@@ -51,13 +51,14 @@ import sys
 from collections import defaultdict
 from functools import partial
 from itertools import repeat, zip_longest
+from operator import itemgetter
 from time import monotonic, monotonic_ns, thread_time_ns
 from typing import Any, Dict, List, Optional, Sequence, Tuple
 from zlib import crc32
 
 import numpy as np
 
-from fantoch_tpu.core.command import Command
+from fantoch_tpu.core.command import FLAT, Command
 from fantoch_tpu.core.config import Config
 from fantoch_tpu.core.ids import AtomicIdGen, ClientId, Dot, ProcessId, Rifl, ShardId
 from fantoch_tpu.core.kvs import KVStore
@@ -85,7 +86,6 @@ from fantoch_tpu.run.prelude import (
     ClientHiAck,
     Overloaded,
     Register,
-    Submit,
     ToClient,
 )
 from fantoch_tpu.run.rw import ProtocolError, Rw, joined_reply_frame, partial_reply_frame
@@ -96,6 +96,8 @@ Address = Tuple[str, int]
 
 _HASH_MIX = 2654435761  # Knuth's multiplier, 2**32 / phi
 _HASH_MASK = 0xFFFFFFFF
+# the shard of a ``(shard, keys)`` entry of a command's nested wire form
+_SHARD = itemgetter(0)
 
 
 def _bucket(sid: ShardId, k: str, key_buckets: int, shard_count: int) -> int:
@@ -141,8 +143,9 @@ def _key_column(
     len(batch), key_width]``, handed over filled with ``KEY_PAD``) takes
     ``_buckets`` of ``batch[i]``'s command (device key-row contract: a
     row's buckets ascend and never repeat).  One pass: a command's
-    buckets are read off its own ops onto the round's one flat list,
-    which becomes the column by one conversion.  What a command shows
+    buckets are read off its own ops (its wire form, ``Command._wire``:
+    the tuple its frame carried) onto the round's one flat list, which
+    becomes the column by one conversion.  What a command shows
     decides its branch: one key is its bucket, two are ordered by one
     comparison (the pad where both fell in one bucket), three or more
     are sorted and deduplicated.  A command with no bucket, or more than
@@ -154,19 +157,34 @@ def _key_column(
     width = key_rows.shape[1]
     per_shard = key_buckets // shard_count
     pads = [[KEY_PAD] * (width - n) for n in range(width + 1)]
+    sharded = shard_count > 1
     flat: List[int] = []
     for _dot, cmd in batch:
-        if shard_count == 1:
-            row = [
-                ((crc32(k.encode()) * _HASH_MIX & _HASH_MASK) * per_shard) >> 32
-                for k in cmd._shard_to_ops.get(shard_id, ())
-            ]
-        else:
+        wire = cmd._wire
+        if len(wire) == FLAT:
+            # one key: (source, sequence, shard, key, code, value)
+            h = ((crc32(wire[3].encode()) * _HASH_MIX & _HASH_MASK) * per_shard) >> 32
+            if sharded:
+                flat.append(wire[2] + shard_count * h)
+            elif wire[2] == shard_id:
+                flat.append(h)
+            else:
+                flat.append(KEY_PAD)  # another shard's key: no bucket here
+            flat += pads[1]
+            continue
+        if sharded:
             row = [
                 sid + shard_count
                 * (((crc32(k.encode()) * _HASH_MIX & _HASH_MASK) * per_shard) >> 32)
-                for sid, ops in cmd._shard_to_ops.items()
-                for k in ops
+                for sid, keys in wire[2]
+                for k, _ops in keys
+            ]
+        else:
+            row = [
+                ((crc32(k.encode()) * _HASH_MIX & _HASH_MASK) * per_shard) >> 32
+                for sid, keys in wire[2]
+                if sid == shard_id
+                for k, _ops in keys
             ]
         n = len(row)
         if n == 2:
@@ -306,6 +324,14 @@ class _DriverCore(PipelineCore):
         store has a monitor or a digest, or a method the pass spells out
         has been replaced."""
         return self.store.applied_in_pass
+
+    @property
+    def executed_off_wire(self) -> int:
+        """Of ``executed_in_pass``, the commands that came off a frame
+        (``Command._off_wire``), whose ops the pass read off the frame's
+        own tuple: all of them on the served path; none of a round
+        stepped by hand with commands the constructor made."""
+        return self.store.applied_off_wire
 
     def _pipeline_flush_needed(self, batch) -> bool:
         """True when the upcoming dispatch may trigger a rebase that
@@ -1632,6 +1658,11 @@ class _DeviceClientSession:
     """Server side of one client connection against the device driver
     (the client.rs:79-260 role, minus dot routing — the driver orders).
 
+    A command comes in as ``Rw.recv_all`` gives it: the tuple its frame
+    unpickled to, kept as its ops (``Command._wire``), and ``_admit`` and
+    ``track`` read that tuple in place; the dict form is built only where
+    a command is refused in ``_validate``'s words.
+
     What the session keeps for a command in flight depends on the
     command's shape.  One key on one shard (the dominant shape): its
     ``runtime.rifl_sessions`` entry and nothing else (and its rifl in
@@ -1675,26 +1706,26 @@ class _DeviceClientSession:
         """Register a submitted command as in flight: route its results
         here (``runtime.rifl_sessions``) and, unless it has one key on one
         shard, write its record of the keys owed (the class's docstring),
-        in one pass over its shards.  A one-key command is complete at
-        its first and only partial, so its rifl's routing entry is all it
-        keeps (``deliver`` frames its reply from that partial)."""
+        in one pass over the shards of its wire form.  A one-key command
+        is complete at its first and only partial, so its rifl's routing
+        entry is all it keeps (``deliver`` frames its reply from that
+        partial)."""
         rifl = cmd._rifl
         if cmd._read_only:
             self._reads.add(rifl)
         self.runtime.rifl_sessions[rifl] = self
-        shard_to_ops = cmd._shard_to_ops
-        several = len(shard_to_ops) > 1
+        wire = cmd._wire
+        several = len(wire) != FLAT and len(wire[2]) > 1
         if cmd._total_key_count == 1 and not several:
             self.runtime._flat_admitted += 1
             return
         owed: Dict[str, Any] = {}
-        for ops in shard_to_ops.values():
-            if len(ops) == 1:
-                for key in ops:
-                    owed[key] = several
+        for _shard, keys in wire[2]:
+            if len(keys) == 1:
+                owed[keys[0][0]] = several
             else:
-                shared = [len(ops), {}, several]
-                for key in ops:
+                shared = [len(keys), {}, several]
+                for key, _ops in keys:
                     owed[key] = shared
         self._owed[rifl] = owed
 
@@ -1883,9 +1914,10 @@ class _DeviceClientSession:
         return None
 
     def _admit(self, msgs: List[Any]) -> None:
-        """One pass over the messages of a socket read, in frame order.
-        A ``Submit`` is validated, checked against the ring's bound
-        (shed with a typed Overloaded BEFORE tracking, so the retry
+        """One pass over the messages of a socket read, in frame order
+        (as ``Rw.recv_all`` gives them: a ``Submit``'s frame is its
+        ``Command``).  A command is validated, checked against the ring's
+        bound (shed with a typed Overloaded BEFORE tracking, so the retry
         re-runs the full path with no leftover aggregation state),
         tracked and given its dot; the read's admitted commands then
         enter the ring together.  A command of one key on one shard can
@@ -1923,27 +1955,25 @@ class _DeviceClientSession:
         flat = 0
         admitted: List[Tuple[Dot, Command, float]] = []
         try:
-            for msg in msgs:
-                if not isinstance(msg, Submit):
-                    self._not_a_submit(msg)
+            for cmd in msgs:
+                if cmd.__class__ is not Command:
+                    self._not_a_submit(cmd)
                     continue
-                cmd = msg.cmd
                 if tracing:
                     # ingress edge: client->server network vs queue
                     # split in the critpath report
                     tracer.edge(
                         "r", "Submit", 0, runtime.process_id, 0, rifl=cmd.rifl,
                     )
-                # cmd.single_key()'s test, spelled out
-                shard_to_ops = cmd._shard_to_ops
-                one_key = cmd._total_key_count == 1 and len(shard_to_ops) == 1
+                # what the frame carried (``Command._wire``), read in place
+                wire = cmd._wire
+                one_key = len(wire) == FLAT
                 if one_key:
-                    (sid,) = shard_to_ops
                     # a wrong shard: the reason in _validate's words
-                    why = None if sid in served else validate(cmd)
+                    why = None if wire[2] in served else validate(cmd)
                 elif (
                     0 < cmd._total_key_count <= key_width
-                    and served.issuperset(shard_to_ops)
+                    and served.issuperset(map(_SHARD, wire[2]))
                 ):
                     why = None
                 else:
@@ -2400,6 +2430,7 @@ class DeviceRuntime:
             "rounds": d.rounds,
             "executed": d.executed,
             "executed_in_pass": d.executed_in_pass,
+            "executed_off_wire": d.executed_off_wire,
             "drain_rows_walked": d.drain_rows_walked,
             "requeued": d.requeued,
             "fast_paths": d.fast_paths,

@@ -17,7 +17,7 @@ from typing import Any, Dict, List, Optional
 from fantoch_tpu.core.command import (
     Command,
     CommandResult,
-    _restore_command,
+    _off_wire,
     _restore_result,
 )
 from fantoch_tpu.core.ids import ClientId, ProcessId, Rifl, ShardId
@@ -120,7 +120,9 @@ class Submit:
     connection under a kind byte (run/rw.py ``KIND_SUBMIT``), in a
     generic pickle (a message that carries one, a deepcopy) under the
     one callable :func:`_submit`, the form a sender before PR 39 framed
-    and a receiver still reads."""
+    and a receiver still reads.  The sender's class, and what
+    ``rw.deserialize`` and ``Rw.recv`` give; the device server's way in
+    (``Rw.recv_all``) hands on the command of the frame and makes none."""
 
     cmd: Command
 
@@ -131,7 +133,7 @@ class Submit:
 def _submit(*values) -> Submit:
     """A :class:`Submit` from its ``Command``'s values: what unpickles
     one, and what ``rw`` calls on a ``KIND_SUBMIT`` frame's tuple."""
-    return Submit(_restore_command(*values))
+    return Submit(_off_wire(values))
 
 
 @dataclass

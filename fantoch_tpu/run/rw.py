@@ -11,7 +11,8 @@ big-endian length prefix, then a payload whose first byte says what it is.
   numbers, then ``shard, key, kind code, value`` where it has one key and
   one op, nested ``shard -> key -> ops`` tuples otherwise; core/command.py),
   a ``ToClient``'s result as ``(source, sequence, key count, results)``.
-  The receiver calls the kind's restorer on the tuple itself.
+  The receiver calls the kind's restorer on the tuple itself; the command
+  keeps that tuple as its ops and builds nothing from it (``Command._wire``).
 - ``0x80`` (how a pickle of protocol 2 and up starts): a pickle of the
   message, whatever it is.  Every other message goes so (the handshakes,
   ``Register``, ``Overloaded``, the peers' and links' messages), and so did
@@ -24,7 +25,8 @@ the first byte; nothing chooses between them.  Any other first byte is a
 and flushes, mirroring the reference's explicit flush control
 (rw/mod.rs:55-84) that lets writers batch small protocol messages into one
 syscall; ``recv`` reads one frame, ``recv_all`` every whole frame a socket
-read brought.
+read brought (the server's way in: a ``Submit``'s frame comes out as its
+command, with no ``Submit`` around it).
 """
 
 from __future__ import annotations
@@ -36,7 +38,7 @@ import struct
 from time import monotonic_ns, thread_time_ns
 from typing import Any, List, Optional
 
-from fantoch_tpu.core.command import CommandResult, _restore_command
+from fantoch_tpu.core.command import CommandResult, _off_wire
 from fantoch_tpu.observability.device import CPU_PAIR_EVERY_NS
 from fantoch_tpu.run.prelude import Submit, ToClient, _submit, _to_client
 
@@ -199,12 +201,15 @@ class Rw:
         """Every whole frame the connection holds, decoded, in order:
         one read of the stream when no frame is whole yet, then a walk
         over the bytes; the incomplete tail waits for the next read.
-        None on EOF.  A connection that has called this stays with it
-        (``recv`` does not see the tail)."""
+        None on EOF.  A ``Submit``'s frame gives its ``Command`` (under
+        ``KIND_SUBMIT`` the tuple it unpickled to, checked and kept:
+        ``command._off_wire``), every other frame the message
+        ``deserialize`` gives.  A connection that has called this stays
+        with it (``recv`` does not see the tail)."""
         read, unpack_from, loads, size = (
             self._reader.read, _LEN.unpack_from, pickle.loads, _LEN.size,
         )
-        restorers, submit, restore_command = _RESTORERS.get, Submit, _restore_command
+        restorers, off_wire = _RESTORERS.get, _off_wire
         while True:
             try:
                 data = await read(_READ_ALL)
@@ -235,12 +240,15 @@ class Rw:
                     raise ProtocolError("empty frame")
                 kind = data[start]
                 if kind == KIND_SUBMIT:
-                    # the server's hot frame first, and _submit spelled out:
-                    # two calls a frame less
-                    values.append(submit(restore_command(*loads(view[start + 1 : stop]))))
+                    # the server's hot frame first: its command, and no
+                    # Submit made for _admit to take off again
+                    values.append(off_wire(loads(view[start + 1 : stop])))
                     plain += 1
                 elif kind == _PICKLE:
-                    values.append(loads(view[start:stop]))
+                    value = loads(view[start:stop])
+                    # a Submit as a sender before PR 39 framed it gives
+                    # its command as well
+                    values.append(value.cmd if value.__class__ is Submit else value)
                 else:
                     restore = restorers(kind)
                     if restore is None:

@@ -2713,8 +2713,6 @@ def _several_key_shapes(shard_count, key_buckets=64):
 
 @pytest.mark.parametrize("shard_count", [1, 4])
 def test_admits_one_key_branch_rejects_what_validate_rejects_in_its_words(shard_count):
-    from fantoch_tpu.run.prelude import Submit
-
     runtime, (session,), (writer,) = _reply_stage(shard_count, connections=1)
     pushed, rejected = [], {}
     runtime.submit_all = lambda admitted, now_ms: pushed.extend(cmd for _dot, cmd, _at in admitted)
@@ -2722,7 +2720,7 @@ def test_admits_one_key_branch_rejects_what_validate_rejects_in_its_words(shard_
     session._reject = lambda cmd, why: (rejected.__setitem__(cmd.rifl, why), reject(cmd, why))
     cmds = _one_key_shapes(shard_count) + _several_key_shapes(shard_count)
     reasons = {cmd.rifl: session._validate(cmd) for cmd in cmds}
-    session._admit([Submit(cmd) for cmd in cmds])
+    session._admit(list(cmds))
     assert rejected == {rifl: why for rifl, why in reasons.items() if why is not None}
     assert [cmd.rifl for cmd in pushed] == [r for r, why in reasons.items() if why is None]
     assert rejected and pushed
@@ -2746,7 +2744,7 @@ def test_admits_one_key_branch_rejects_what_validate_rejects_in_its_words(shard_
     runtime.room = lambda: 0
     shed = [Command(Rifl(9, 1), {0: {"a": (KVOp.get(),), "b": (KVOp.get(),)}}),
             Command.from_single(Rifl(9, 2), 0, "a", KVOp.get())]
-    session._admit([Submit(cmd) for cmd in shed])
+    session._admit(list(shed))
     assert runtime._submit_queue.sheds == 2 and len(pushed) == len(reasons) - len(rejected)
     assert _in_flight(runtime, [session]) == held
 
@@ -2866,13 +2864,11 @@ def test_the_flat_counters_count_one_key_commands_from_admit_to_reply(shard_coun
     the server has two, one over both: ``reply_flat_frames`` counts the
     one-key commands alone, ``reply_partial_frames`` every frame made from
     one partial, ``shard_replies`` all."""
-    from fantoch_tpu.run.prelude import Submit
-
     runtime, (session,), (writer,) = _reply_stage(shard_count=shard_count, connections=1)
     runtime.submit_all = lambda admitted, now_ms: None
     cmds, results = _one_session_round(shard_count)
-    session._admit([Submit(cmd) for cmd in cmds[:3]])
-    session._admit([Submit(cmd) for cmd in cmds[3:]])
+    session._admit(cmds[:3])
+    session._admit(cmds[3:])
     runtime._publish_tallies()
     assert runtime._tallies["session_flat_admitted"] == 3
     assert runtime._tallies["reply_flat_frames"] == 0

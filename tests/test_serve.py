@@ -114,3 +114,53 @@ def test_serve_applies_a_round_in_one_pass_as_the_per_command_loop_does(protocol
     assert got == [r for rifl in order for shard in cmds[rifl].shards()
                    for r in cmds[rifl].execute(shard, replay)]
     assert replay._store == passed.store._store
+
+
+# --- a served command is what its frame carried (PR 50) ---
+
+
+def _off_the_wire(batches):
+    """The same rounds with every command as the server's way in gives it:
+    restored from its frame, born with the frame's tuple and no dicts."""
+    from fantoch_tpu.run import rw
+    from fantoch_tpu.run.prelude import Submit
+
+    return [[(dot, rw.deserialize(rw.serialize(Submit(cmd))).cmd) for dot, cmd in batch]
+            for batch in batches]
+
+
+@pytest.mark.parametrize("protocol", DRAIN_DRIVERS)
+def test_rounds_of_commands_off_the_wire_are_served_from_their_tuples_alone(protocol):
+    """The seeded rounds, one- and two-key, in each form: the same results,
+    store and tallies; ``executed_off_wire`` counts every command of the
+    driver fed from frames and none of the one stepped with commands the
+    constructor made; and a command off the wire has been asked for its
+    dicts by nothing from the key column to the store (a per-command twin
+    asks every one)."""
+    from tests.test_command_forms import has_dicts
+
+    cls, _walk, n, extra = DRAIN_DRIVERS[protocol]
+    shards = {"shard_count": 2} if protocol in ("epaxos", "newt") else {}
+    kw = {"batch_size": 8, "key_buckets": 64, **extra, **shards}
+    if protocol != "fpaxos":
+        kw["key_width"] = 2
+    built, framed = _seeded_batches(protocol, 50, 8), _off_the_wire(_seeded_batches(protocol, 50, 8))
+    by_hand, served, looped = cls(n, **kw), cls(n, **kw), type("Looped", (_PerCommand, cls), {})(n, **kw)
+    relooped = _off_the_wire(built)
+    got, want, loop = [], [], []
+    for at in range(0, len(built), 2):
+        want += by_hand.serve(built[at: at + 2], overlap=True)
+        got += served.serve(framed[at: at + 2], overlap=True)
+        loop += looped.serve(relooped[at: at + 2], overlap=True)
+    want += by_hand.flush_pipeline()
+    got += served.flush_pipeline()
+    loop += looped.flush_pipeline()
+    assert got == want == loop and served.store._store == by_hand.store._store == looped.store._store
+    count = sum(len(batch) for batch in built)
+    for tally in ("executed", "executed_in_pass", "fast_paths", "slow_paths", "rounds"):
+        assert getattr(served, tally) == getattr(by_hand, tally), tally
+    assert served.executed_off_wire == served.executed_in_pass == served.executed == count
+    assert by_hand.executed_off_wire == 0 and by_hand.executed_in_pass == count
+    assert (looped.executed, looped.executed_in_pass, looped.executed_off_wire) == (count, 0, 0)
+    assert not any(has_dicts(cmd) for batch in framed for _dot, cmd in batch)
+    assert all(has_dicts(cmd) for batch in relooped for _dot, cmd in batch)

@@ -22,6 +22,8 @@ from fantoch_tpu.core import Command, Config, KVOp, Rifl
 from fantoch_tpu.run import rw
 from fantoch_tpu.run.device_runner import DeviceRuntime, ProtocolError, _bucket, _DeviceClientSession
 from fantoch_tpu.run.prelude import ClientHi, ClientHiAck, Overloaded, Register, Submit, ToClient
+from tests.test_command_forms import has_dicts
+from tests.test_wire_codec import BROKEN
 
 KEY_BUCKETS = 64
 VALUE = "v" * 100  # the cells' payload
@@ -126,6 +128,9 @@ def test_recv_all_returns_every_whole_frame_of_a_read_and_keeps_the_tail():
     msgs = [_submit(1, seq, f"k{seq}") for seq in range(1, 6)] + [Register(None), ClientHi([4])]
     frames = [rw.frame(m) for m in msgs]
     whole = b"".join(frames)
+    # a Submit's frame comes out as its command, with no Submit around it
+    msgs = [m.cmd if isinstance(m, Submit) else m for m in msgs]
+    assert [type(m) for m in msgs] == [Command] * 5 + [Register, ClientHi]
     assert _collect([whole])[0] == [msgs, None]
     cut = len(frames[0]) + len(frames[1]) + 9  # inside the third
     out, tally = _collect([whole[:cut], whole[cut:]])
@@ -145,7 +150,7 @@ def test_recv_all_at_eof_is_recvs_eof(kept, error):
     frames = [rw.frame(_submit(1, 1, "a")), rw.frame(_submit(1, 2, "b"))]
     data = frames[0] + frames[1][:kept]
     if error is None:
-        assert _collect([data])[0] == [[_submit(1, 1, "a")], None]
+        assert _collect([data])[0] == [[_submit(1, 1, "a").cmd], None]
     else:
         with pytest.raises(error):
             _collect([data])
@@ -188,11 +193,11 @@ class _Writer:
 class _Served:
     """A started runtime and one session of it over a fed reader."""
 
-    def __init__(self, shard_count=1, key_width=1, **config):
+    def __init__(self, shard_count=1, key_width=1, monitor=True, **config):
         self.runtime = DeviceRuntime(
             Config(3, 1, shard_count=shard_count, **config), ("127.0.0.1", 0),
             batch_size=16, key_buckets=KEY_BUCKETS, key_width=key_width,
-            monitor_execution_order=True,
+            monitor_execution_order=monitor,
         )
 
     async def __aenter__(self):
@@ -477,3 +482,145 @@ def test_a_reply_comes_only_after_execution_and_once_per_rifl():
             assert served.runtime.replied == 3 and not served.runtime.rifl_sessions
 
     asyncio.run(go())
+
+
+# --- a command in flight is what its frame carried (PR 50) ---
+
+
+@pytest.mark.parametrize("shard_count", [1, 2])
+def test_a_served_command_is_its_frames_tuple_from_the_read_to_the_reply(shard_count):
+    """A read of one-key frames (and, on two shards, two-key ones) through
+    ``recv_all`` and ``_admit``: what enters the ring is a ``Command`` that
+    holds plain values alone, the tuple its frame unpickled to among them;
+    a round later, answered, nothing has asked it for its dicts; and the
+    tallies count every executed command as read off the wire."""
+    import gc
+
+    async def go():
+        async with _Served(shard_count=shard_count, key_width=2, monitor=False) as served:
+            runtime, ring = served.runtime, []
+            submit_all = runtime.submit_all
+
+            def entering(admitted, now_ms):
+                for _dot, cmd, _at in admitted:
+                    assert type(cmd) is Command and not has_dicts(cmd)
+                    assert not any(isinstance(x, (dict, KVOp, Submit)) for x in gc.get_referents(cmd))
+                    ring.append(cmd)
+                return submit_all(admitted, now_ms)
+
+            runtime.submit_all = entering
+            msgs = [_submit(1 + seq % 3, seq, f"k{seq % 4}", shard=seq % shard_count) for seq in range(1, 9)]
+            if shard_count == 2:
+                msgs.append(Submit(Command(Rifl(2, 20), {1: {"x": (KVOp.get(),)}, 0: {"y": (KVOp.get(),)}})))
+                msgs.append(Submit(Command(Rifl(3, 21), {0: {"p": (KVOp.put("1"),), "q": (KVOp.put("2"),)}})))
+            data = b"".join(rw.frame(m) for m in msgs)
+            await served.read(data)
+            got = await served.replies(len(msgs) + (1 if shard_count == 2 else 0))  # a reply a shard
+            assert {r.cmd_result.rifl for r in got} == {m.cmd.rifl for m in msgs}
+            assert [cmd.rifl for cmd in ring] == [m.cmd.rifl for m in msgs]
+            wires = [pickle.loads(rw.serialize(m)[1:]) for m in msgs]
+            assert [cmd._wire for cmd in ring] == wires
+            # executed, delivered, answered: still the tuple alone
+            assert not any(has_dicts(cmd) for cmd in ring)
+            assert not any(isinstance(x, (dict, KVOp)) for cmd in ring for x in gc.get_referents(cmd))
+            tallies = served.tallies()
+            assert tallies["executed_off_wire"] == tallies["executed_in_pass"] == tallies["executed"] == len(msgs)
+            assert not runtime.rifl_sessions and not served.session._owed
+            return runtime.failure
+
+    assert asyncio.run(go()) is None
+
+
+def test_under_a_monitor_the_drain_asks_for_the_dicts_and_counts_nothing_off_the_wire():
+    """The per-command loop (a store with a monitor) goes through
+    ``Command.execute``: the dicts are built there, for the caller that asks."""
+
+    async def go():
+        async with _Served() as served:
+            ring, submit_all = [], served.runtime.submit_all
+            served.runtime.submit_all = lambda admitted, now_ms: (
+                ring.extend(cmd for _dot, cmd, _at in admitted), submit_all(admitted, now_ms))[1]
+            await served.read(b"".join(rw.frame(m) for m in _burst(0)))
+            await served.replies(6)
+            assert len(ring) == 6 and all(has_dicts(cmd) for cmd in ring)
+            tallies = served.tallies()
+            return tallies["executed"], tallies["executed_in_pass"], tallies["executed_off_wire"]
+
+    assert asyncio.run(go()) == (6, 0, 0)
+
+
+def test_a_submit_object_is_not_what_admit_takes():
+    """``_admit`` tells a command by its class: the sender's ``Submit``, which
+    ``recv_all`` never hands it, is any other unexpected message."""
+
+    async def go():
+        async with _Served() as served:
+            pushed = []
+            served.runtime.submit_all = lambda admitted, now_ms: pushed.extend(admitted)
+            with pytest.raises(ProtocolError, match="unexpected message Submit"):
+                served.session._admit([_submit(1, 1, "a").cmd, _submit(1, 2, "b")])
+            return [cmd.rifl for _dot, cmd, _at in pushed]
+
+    assert asyncio.run(go()) == [Rifl(1, 1)]  # what was admitted before it is pushed
+
+
+# of tests/test_wire_codec.py's frames, the three kinds of breach the parent's restorer named
+BROKEN_FRAMES = ("a_kind_code_out_of_range", "no_shard", "a_get_in_a_command_that_writes")
+
+
+@pytest.mark.parametrize("name", BROKEN_FRAMES)
+def test_a_frame_that_breaks_the_commands_contract_ends_the_session_as_on_the_parent(name):
+    """As an unknown kind does: the walk raises what the parent's restorer
+    raised (the same type, the same words), before the admit pass, so
+    nothing of that read reaches ``submit_all``, the ring or the step's
+    thread; the read before it was admitted and answered; the session's
+    transport is closed and the runtime lives."""
+    values, error, words = BROKEN[name]
+
+    async def go():
+        async with _Served(key_width=2) as served:
+            pushed, submit_all = [], served.runtime.submit_all
+            served.runtime.submit_all = lambda admitted, now_ms: (
+                pushed.extend(cmd.rifl for _dot, cmd, _at in admitted), submit_all(admitted, now_ms))[1]
+            await served.read(rw.frame(_submit(1, 1, "a")))
+            assert [r.cmd_result.rifl for r in await served.replies(1)] == [Rifl(1, 1)]
+            bad = bytes((rw.KIND_SUBMIT,)) + pickle.dumps(values, protocol=pickle.HIGHEST_PROTOCOL)
+            await served.read(rw.frame(_submit(1, 2, "b")) + rw._LEN.pack(len(bad)) + bad + rw.frame(_submit(1, 3, "c")))
+            await asyncio.wait([served.task], timeout=5)
+            exc = served.task.exception()
+            assert type(exc) is error and words in str(exc)
+            assert served.writer.closed and not served.runtime.rifl_sessions and not served.writer.data
+            assert pushed == [Rifl(1, 1)] and len(served.runtime._submit_queue) == 0
+            return served.tallies()["submitted"], served.runtime.driver.executed, served.runtime.failure
+
+    assert asyncio.run(go()) == (1, 1, None)
+
+
+def test_a_command_off_the_wire_is_refused_in_validates_words_as_one_the_constructor_made():
+    """What fails ``_admit``'s quick test on the tuple goes to ``_validate``,
+    which reads the command by its accessors: the same words for both births."""
+
+    async def go():
+        async with _Served(shard_count=2, key_width=2) as served:
+            session = served.session
+            shapes = [
+                {5: {"a": (KVOp.put("1"),)}},  # a shard the server does not have
+                {0: {"a": (KVOp.get(),)}, 3: {"b": (KVOp.get(),)}},  # one of two
+                {0: {"a": (KVOp.put("1"),), "b": (KVOp.put("2"),)}, 1: {"c": (KVOp.put("3"),)}},  # three buckets
+                {0: {}},  # no key
+            ]
+            said = []
+            for number, shape in enumerate(shapes, start=1):
+                built = Command(Rifl(4, number), shape)
+                restored = rw.deserialize(rw.serialize(Submit(built))).cmd
+                assert session._validate(restored) == session._validate(built) is not None
+                said.append(session._validate(built))
+            rejected = []
+            session._reject = lambda cmd, why: rejected.append(why)
+            data = b"".join(rw.frame(Submit(Command(Rifl(4, n), shape))) for n, shape in enumerate(shapes, start=1))
+            await served.read(data + rw.frame(_submit(1, 1, "a")))
+            assert rejected == said and len(set(said)) == 4
+            assert [r.cmd_result.rifl for r in await served.replies(1)] == [Rifl(1, 1)]
+            return served.tallies()["submitted"]
+
+    assert asyncio.run(go()) == 1

@@ -76,10 +76,23 @@ def _to_client(name):
 
 def _same_submit(got, sent):
     assert type(got) is Submit and got == sent
-    assert type(got.cmd.rifl) is Rifl
-    assert list(got.cmd.all_keys()) == list(sent.cmd.all_keys())  # the order of execution
-    assert (got.cmd.read_only, got.cmd.total_key_count, got.cmd.single_key()) == (
+    _same_command(got.cmd, sent)
+
+
+def _same_command(got, sent):
+    """``got``: the command of the ``Submit`` ``sent``, as ``deserialize`` and
+    ``recv`` give it inside a ``Submit`` and ``recv_all`` bare."""
+    assert type(got) is Command and got == sent.cmd
+    assert type(got.rifl) is Rifl
+    assert list(got.all_keys()) == list(sent.cmd.all_keys())  # the order of execution
+    assert (got.read_only, got.total_key_count, got.single_key()) == (
         sent.cmd.read_only, sent.cmd.total_key_count, sent.cmd.single_key())
+
+
+def _as_recv_all_gives(msgs):
+    """A ``Submit`` comes out of ``recv_all`` as its command, every other
+    message as itself."""
+    return [m.cmd if type(m) is Submit else m for m in msgs]
 
 
 def _fields(to_client):
@@ -155,6 +168,67 @@ def test_an_unknown_kind_byte_is_a_protocol_error(kind):
 def test_an_empty_payload_is_a_protocol_error():
     with pytest.raises(rw.ProtocolError, match="empty frame"):
         rw.deserialize(b"")
+
+
+# --- a frame of the kind whose values break the command's contract ---
+
+# what the parent's restorer raised by building (KINDS[code] of a KVOp, the
+# constructor's two asserts), raised now by the check of the tuple in the walk
+BROKEN = {
+    "a_kind_code_out_of_range": (
+        (7, 9, 0, "k", 3, None), IndexError, "tuple index out of range"),
+    "a_kind_code_out_of_range_among_several_keys": (
+        (7, 9, ((0, (("a", ((1, "x"),)), ("b", ((7, None),)))),)), IndexError, "tuple index out of range"),
+    "a_kind_code_that_is_no_number": (
+        (7, 9, 0, "k", "Put", "x"), TypeError, "tuple indices must be integers or slices, not str"),
+    "no_shard": (
+        (7, 9, ()), AssertionError, "commands must have at least one shard"),
+    "a_get_in_a_command_that_writes": (
+        (7, 9, ((0, (("a", ((0, None),)),)), (1, (("b", ((1, "x"),)),)))), AssertionError,
+        "non-read-only commands cannot contain Get operations"),
+    "a_get_and_a_put_on_one_key": (
+        (7, 9, ((0, (("a", ((1, "x"), (0, None))),)),)), AssertionError,
+        "non-read-only commands cannot contain Get operations"),
+    "two_values": ((7, 9), TypeError, "a command's values are"),
+    "seven_values": ((7, 9, 0, "k", 1, "x", None), TypeError, "a command's values are"),
+    "a_flat_form_without_its_key": ((7, 9, 0, None, 1, "x"), TypeError, "a command's values are"),
+}
+
+
+def _broken_payload(name):
+    return bytes((rw.KIND_SUBMIT,)) + pickle.dumps(BROKEN[name][0], protocol=pickle.HIGHEST_PROTOCOL)
+
+
+@pytest.mark.parametrize("by", ["deserialize", "recv", "recv_all", "pickle"])
+@pytest.mark.parametrize("name", sorted(BROKEN))
+def test_a_frame_that_breaks_the_commands_contract_raises_in_the_walk_as_on_the_parent(name, by):
+    """The exception's type and words are the parent's (read off a copy of
+    its tree) for a code outside ``KINDS``, no shard and a ``Get`` among
+    writes; a tuple of no command's length is a ``TypeError`` as there."""
+    _values, error, words = BROKEN[name]
+    payload = _broken_payload(name)
+    good = rw.frame(_submit("one_key_put"))
+
+    async def send(client):
+        client.write_frames(good + rw._LEN.pack(len(payload)) + payload + good)
+        await client.flush()
+
+    async def receive(served):
+        if by == "recv":
+            _same_submit(await served.recv(), _submit("one_key_put"))
+            await served.recv()
+        else:
+            await _recv_n(served, 3)
+
+    with pytest.raises(error, match=words):
+        if by == "deserialize":
+            rw.deserialize(payload)
+        elif by == "pickle":  # the command alone, under its restorer's name
+            from fantoch_tpu.core.command import _restore_command
+
+            _restore_command(*_values)
+        else:
+            _over_tcp(send, receive)
 
 
 # --- the form before PR 39: a pickle that names one callable ---
@@ -259,8 +333,9 @@ def test_a_submit_round_trips_through_rw_by_recv_and_by_recv_all(name):
     one, tally = _over_tcp(send, lambda served: served.recv())
     _same_submit(one, sent)
     assert tally[1] == 1 and tally[6] == 1  # decoded through its kind byte
+    # the server's way in: the command, with no Submit around it
     (got,), tally = _over_tcp(send, lambda served: _recv_n(served, 1))
-    _same_submit(got, sent)
+    _same_command(got, sent)
     assert tally[1:3] == [1, 1] and tally[6] == 1
 
 
@@ -304,8 +379,8 @@ def test_a_connection_carries_both_forms_and_counts_the_ones_of_a_kind():
         await client.flush()
 
     got, tally = _over_tcp(send, lambda served: _recv_n(served, 9))
-    sent = others[:1] + [msg for _, msg in _parents()] + news + others[1:]
-    assert len(got) == len(sent) == 9
+    sent = _as_recv_all_gives(others[:1] + [msg for _, msg in _parents()] + news + others[1:])
+    assert len(got) == len(sent) == 9 and sum(type(msg) is Command for msg in sent) == 4
     for back, msg in zip(got, sent):
         if isinstance(msg, ToClient):  # a result compares by identity
             _same_to_client(back, msg)
@@ -391,11 +466,12 @@ def test_a_three_frame_read_cut_at_every_byte_gives_the_three_messages_and_the_r
                   for p in (pickle.dumps(m, protocol=pickle.HIGHEST_PROTOCOL) for m in THREE)]
     whole = b"".join(frames)
     ends = [len(frames[0]), len(frames[0]) + len(frames[1]), len(whole)]
-    assert _fed([whole])[0] == [THREE]
+    three = _as_recv_all_gives(THREE)
+    assert _fed([whole])[0] == [three] and [type(m) for m in three] == [Command, ClientHi, Command]
     for cut in range(1, len(whole)):
         out, tails, tally = _fed([whole[:cut], whole[cut:]])
         done = sum(end <= cut for end in ends)  # frames whole in the first read
-        assert [m for msgs in out for m in msgs] == THREE, cut
+        assert [m for msgs in out for m in msgs] == three, cut
         assert [len(msgs) for msgs in out] == [n for n in (done, 3 - done) if n], cut
         # the first read leaves what follows its last whole frame, the second nothing
         assert tails == [whole[([0] + ends)[done]:cut], b""], cut
