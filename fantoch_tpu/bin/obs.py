@@ -15,7 +15,9 @@ into answers:
     python -m fantoch_tpu.bin.obs diff a.jsonl b.jsonl
 
     # live terminal view of a cluster's telemetry (series files, an obs
-    # dir, or /metrics endpoints; --once renders a single frame)
+    # dir, or /metrics endpoints; --once renders a single frame); of a
+    # device-step server also loop% (its loop's thread outside the
+    # selector), cpu% (its two served threads) and stop (neither ran)
     python -m fantoch_tpu.bin.obs watch obs_dir/ 127.0.0.1:9090
 
     # one exposition scrape (raw Prometheus text, or parsed --json)
@@ -321,15 +323,19 @@ def _watch_sources(targets: List[str]) -> Dict[str, Dict[str, Any]]:
 
 def _render_watch(latest: Dict[str, Dict[str, Any]]) -> str:
     """One table frame: per source, submit/reply rates, the client or
-    end-to-end latency window, queue depth, sheds, device idle, and of a
+    end-to-end latency window, queue depth, sheds, of a device-step
+    server started through ``bin/server`` the share of the window its
+    loop's thread spent outside the selector (``loop%``:
+    ``loop_busy_ms``'s rate over wall time, 100 is a loop that never
+    sleeps; "-" where the counter is absent), device idle, and of a
     device-step server the CPU its two served threads used in the window
     (``host_cpu_ms``'s rate over wall time: 100 is one core) and the
     milliseconds so far in which neither of them ran
     (``loop_stopped_ms``)."""
     lines = [
         f"{'source':<12}{'submit/s':>10}{'reply/s':>10}{'p50ms':>8}"
-        f"{'p95ms':>8}{'p99ms':>8}{'queue':>7}{'sheds':>7}{'idle':>6}"
-        f"{'cpu%':>6}{'stop':>8}"
+        f"{'p95ms':>8}{'p99ms':>8}{'queue':>7}{'sheds':>7}{'loop%':>7}"
+        f"{'idle':>6}{'cpu%':>6}{'stop':>8}"
     ]
     for src in sorted(latest):
         window = latest[src]
@@ -338,6 +344,7 @@ def _render_watch(latest: Dict[str, Dict[str, Any]]) -> str:
         gauges = window.get("g", {})
         hist = window.get("h", {}).get("latency_ms")
         cpu_rate = rate.get("host_cpu_ms")
+        loop_rate = rate.get("loop_busy_ms")
 
         def _num(value, fmt="{:.1f}"):
             return "-" if value is None else fmt.format(value)
@@ -351,6 +358,8 @@ def _render_watch(latest: Dict[str, Dict[str, Any]]) -> str:
             f"{_num(hist and hist.get('p99'), '{:.0f}'):>8}"
             f"{_num(gauges.get('queue_depth'), '{:.0f}'):>7}"
             f"{_num(ctr.get('shed_submissions'), '{:.0f}'):>7}"
+            # a rate is per second: ms outside the selector a second, over 10, is %
+            f"{_num(None if loop_rate is None else loop_rate / 10.0, '{:.0f}'):>7}"
             f"{_num(gauges.get('device_idle_frac'), '{:.2f}'):>6}"
             # a rate is per second: ms of CPU a second, over 10, is %
             f"{_num(cpu_rate and cpu_rate / 10.0, '{:.0f}'):>6}"

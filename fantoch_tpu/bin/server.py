@@ -175,8 +175,11 @@ def _backend_banner(backend: dict) -> str:
     )
 
 
-async def serve_device_step(args: argparse.Namespace) -> None:
-    """The TPU serving path: one server, the protocol round on-device."""
+async def serve_device_step(args: argparse.Namespace, loop_selector=None) -> None:
+    """The TPU serving path: one server, the protocol round on-device.
+    ``loop_selector``: the selector the running loop was made over, where
+    it is one that reads the clock (``main``): the runtime's recorder then
+    keeps the loop's thread's account of its own time."""
     protocol_by_name(args.protocol)  # validate the label even when unused
     config = config_from_args(args)
     # the platform rule, before a mesh is built or a port is bound
@@ -217,6 +220,7 @@ async def serve_device_step(args: argparse.Namespace) -> None:
         metrics_port=args.metrics_port,
         trace_file=args.trace_file,
         flight_dir=args.flight_dir,
+        loop_selector=loop_selector,
     )
     await runtime.start()
     _arm_profile_signal(args)
@@ -270,11 +274,11 @@ def _arm_profile_signal(args: argparse.Namespace) -> None:
     )
 
 
-async def serve(args: argparse.Namespace) -> None:
+async def serve(args: argparse.Namespace, loop_selector=None) -> None:
     from fantoch_tpu.run.process_runner import ProcessRuntime
 
     if args.device_step:
-        await serve_device_step(args)
+        await serve_device_step(args, loop_selector)
         return
     if args.id is None or args.port is None or args.addresses is None:
         raise SystemExit(
@@ -365,8 +369,20 @@ async def serve(args: argparse.Namespace) -> None:
 def main(argv=None) -> None:
     args = build_parser().parse_args(argv)
     maybe_log_file(args.log_file)
+    selector = loop_factory = None
+    if args.device_step:
+        # the served path's loop runs over a selector that reads the clock
+        # around each of its calls: what the loop's thread does with its
+        # time is the runtime's recorder's to say (observability/device.py)
+        from fantoch_tpu.observability.device import TimedSelector
+
+        selector = TimedSelector()
+
+        def loop_factory():
+            return asyncio.SelectorEventLoop(selector)
+
     try:
-        asyncio.run(serve(args))
+        asyncio.run(serve(args, selector), loop_factory=loop_factory)
     except (KeyboardInterrupt, asyncio.CancelledError):
         pass
 

@@ -31,6 +31,11 @@ profiler's clock, and kept in a bounded ring that the runtime writes out
 when it stops.  Who had the CPU meanwhile is :class:`ThreadAccount`'s: the
 CPU time and the run-queue wait of the served path's two threads, which
 :func:`classify_stall` reads at the two ends of a late wake-up of the loop.
+The loop's thread accounts for its own time through :class:`TimedSelector`:
+two clock reads around each visit to the selector split its wall time into
+turns (``loop_busy_ms``), sleeps (``loop_poll_wait_ms``) and polls that
+cannot sleep (``loop_poll_ready_ms``: the wait for the interpreter lock),
+and what no stage names of a turn is ``loop_unnamed_ms``.
 """
 
 from __future__ import annotations
@@ -39,6 +44,7 @@ import heapq
 import json
 import os
 import resource
+import selectors
 import threading
 import time
 from collections import deque
@@ -347,6 +353,9 @@ ROUND_STAGES = (
     # on the loop beside the rounds: the telemetry tick's snapshot write,
     # the probe's late wake-ups, the interpreter's full collections
     "snapshot", "loop_stall", "gc",
+    # a socket read's pass on the loop: from the first byte ``Rw.recv_all``
+    # walks to ``_admit`` returning, from the clock reads those two take
+    "read",
     # before the first round: a chain program compiled or loaded
     # (``precompile_chains``; the span's round is the chain length); and
     # the round's second program, where clients of a second site register
@@ -373,6 +382,19 @@ CPU_PAIR_EVERY_NS = 50_000_000
 # closed spans kept for the dump: a round closes 13, so about 5,000 rounds
 # (two to three open-loop runs of 20 s, many more saturated ones)
 SPAN_RING = 65536
+# the spans that close on the loop's thread and compute there: what a turn
+# of the loop holds beyond them is ``loop_unnamed_ms``.  Not ``round``,
+# ``handoff``, ``resume``, ``gate_wait``, ``idle_wait``, ``loop_stall``:
+# they span awaits
+LOOP_NAMED_STAGES = frozenset(
+    {"read", "collect", "deliver", "publish", "snapshot", "gc", "precompile"}
+)
+# the stages the step is made of: what it holds beyond them is ``step_unnamed_ms``
+STEP_NAMED_STAGES = ("assemble", "enqueue", "fetch", "execute")
+# a turn of the loop, and a socket read's pass, is a row of the ring from
+# this length on; a shorter one is counted only (an open cell makes
+# thousands a second, and the ring has to hold a window's ``round`` spans)
+LOOP_ROW_NS = 1_000_000
 
 
 def off_cpu_ns(wall_ns: int, timed_ns: int, cpu_ns: int) -> float:
@@ -457,9 +479,20 @@ class StageRecorder:
 
     The recorder also files the loop's late wake-ups (:meth:`stall`): each
     under its class (:func:`classify_stall`), and the longest that were
-    not the loop's own work with the window of the ring around them."""
+    not the loop's own work with the window of the ring around them.
+
+    Where the loop runs over a :class:`TimedSelector` that hands its clock
+    reads here (:meth:`turn`), the recorder keeps the loop's thread's own
+    account too: turns, sleeps, the polls that cannot sleep, and of the
+    turns what ``LOOP_NAMED_STAGES`` do not name.  A recorder no selector
+    reports to leaves those counters out."""
 
     clock = staticmethod(time.monotonic_ns)
+    # a profiler capture is running (observability/exposition.py sets and
+    # clears it; the profiler is the process's, so is the flag): the spans
+    # too frequent to annotate always, a socket read's walk and ``_admit``,
+    # annotate themselves while it is set (:meth:`annotate`)
+    capturing = False
 
     def __init__(self, ring: int = SPAN_RING):
         from jax.profiler import TraceAnnotation
@@ -481,6 +514,21 @@ class StageRecorder:
         self._unsettled: List[Dict[str, Any]] = []
         self._stalls: List[Tuple[int, int, Dict[str, Any]]] = []
         self._local = threading.local()
+        # the loop's thread, by its selector's clock reads (:meth:`turn`):
+        # None until a first visit to the selector is reported
+        self.loop_thread: Optional[int] = None
+        self.loop_t0_ns = 0  # that first visit's start: the account's origin
+        self.loop_returned_ns = 0  # the last visit's return: the open turn's start
+        self.loop_turns = 0
+        self.loop_busy_ns = 0
+        self.loop_poll_wait_ns = 0
+        self.loop_poll_ready_ns = 0
+        self.loop_poll_ready_n = 0
+        # wall time of the closed turns under a span of LOOP_NAMED_STAGES,
+        # and the open turn's: (start, ns) of each such span closed in it
+        # that no later one encloses
+        self.loop_named_ns = 0
+        self._turn_named: List[Tuple[int, int]] = []
 
     def _stack(self) -> List[str]:
         try:
@@ -495,16 +543,66 @@ class StageRecorder:
         (a span whose parent runs on the other thread names it)."""
         return _Span(self, name, round_id, parent)
 
+    def annotate(self, name: str):
+        """An entered annotation ``name`` on the profiler's clock, for a
+        caller that has seen :attr:`capturing` set; it calls
+        ``__exit__(None, None, None)`` on what it gets."""
+        note = self._annotation(name)
+        note.__enter__()
+        return note
+
     def record(self, name: str, t0_ns: int, t1_ns: int, round_id: int = 0,
-               parent: Optional[str] = None, read_rows: Optional[int] = None) -> None:
+               parent: Optional[str] = None, read_rows: Optional[int] = None,
+               row: bool = True) -> None:
         """A closed interval whose ends were read elsewhere (a hand-off
-        between threads, a late wake-up): counters and ring, no
-        annotation."""
-        self.ns[name] = self.ns.get(name, 0) + t1_ns - t0_ns
+        between threads, a late wake-up, a socket read's pass): counters
+        and, unless ``row`` is false, ring; no annotation."""
+        took = t1_ns - t0_ns
+        self.ns[name] = self.ns.get(name, 0) + took
         self.n[name] = self.n.get(name, 0) + 1
-        self.ring.append(
-            (name, t0_ns, t1_ns, round_id, threading.get_ident(), parent, read_rows)
-        )
+        thread = threading.get_ident()
+        if row:
+            self.ring.append((name, t0_ns, t1_ns, round_id, thread, parent, read_rows))
+        if thread == self.loop_thread and name in LOOP_NAMED_STAGES:
+            # the rows of one thread close in the order of their ends,
+            # nested or apart: what this one encloses of those already
+            # taken is in it (an unscheduled collection inside ``deliver``)
+            named = self._turn_named
+            while named and named[-1][0] >= t0_ns:
+                named.pop()
+            named.append((t0_ns, took))
+
+    def turn(self, called_ns: int, returned_ns: int, ready: bool) -> None:
+        """One visit of the loop to its selector (:class:`TimedSelector`,
+        on the loop's thread): ``ready`` says the call could not sleep
+        (timeout 0: callbacks were waiting), so what it took beyond the
+        system call is the wait to take the interpreter lock back.  The
+        turn that ended at ``called_ns`` began at the previous visit's
+        return: its wall time is the loop's thread outside the selector,
+        a forced hand-over of the lock in the middle included; the spans
+        of ``LOOP_NAMED_STAGES`` that closed in it are its named part.  A
+        turn of ``LOOP_ROW_NS`` and more is a row of the ring."""
+        began = self.loop_returned_ns
+        if began:
+            busy = called_ns - began
+            self.loop_turns += 1
+            self.loop_busy_ns += busy
+            if busy >= LOOP_ROW_NS:
+                self.ring.append(("turn", began, called_ns, 0, self.loop_thread, None, None))
+        else:
+            self.loop_thread = threading.get_ident()
+            self.loop_t0_ns = called_ns
+        named = self._turn_named
+        if named:
+            # no span of the list is open across a visit to the selector
+            self.loop_named_ns += sum(took for _, took in named)
+            named.clear()
+        if ready:
+            self.loop_poll_ready_ns += returned_ns - called_ns
+            self.loop_poll_ready_n += 1
+        else:
+            self.loop_poll_wait_ns += returned_ns - called_ns
+        self.loop_returned_ns = returned_ns
 
     def stall(self, due_ns: int, woke_ns: int, spent: AccountSample) -> str:
         """A wake-up of the loop that was due at ``due_ns`` and came at
@@ -579,6 +677,20 @@ class StageRecorder:
         out["loop_stopped_ms"] = round(
             (self.stall_ns["runq"] + self.stall_ns["blocked"]) / 1e6, 3
         )
+        # what no stage names, on both threads: of the step, and (where a
+        # selector reports the loop's turns) of the turns closed so far
+        out["step_unnamed_ms"] = round(
+            (self.ns["step"] - sum(self.ns[name] for name in STEP_NAMED_STAGES)) / 1e6, 3
+        )
+        if self.loop_thread is not None:
+            out["loop_turns"] = self.loop_turns
+            out["loop_busy_ms"] = round(self.loop_busy_ns / 1e6, 3)
+            out["loop_poll_wait_ms"] = round(self.loop_poll_wait_ns / 1e6, 3)
+            out["loop_poll_ready_ms"] = round(self.loop_poll_ready_ns / 1e6, 3)
+            out["loop_poll_ready_n"] = self.loop_poll_ready_n
+            out["loop_unnamed_ms"] = round(
+                (self.loop_busy_ns - self.loop_named_ns) / 1e6, 3
+            )
         return out
 
     def dump(self, path: str) -> None:
@@ -602,3 +714,24 @@ class StageRecorder:
                 },
                 fh,
             )
+
+
+class TimedSelector(selectors.DefaultSelector):
+    """The selector of a loop whose thread accounts for its time: each
+    ``select`` reads the recorder's clock before and after the call and
+    hands both to :meth:`StageRecorder.turn`.  ``bin/server`` makes its
+    loop over one (``asyncio.SelectorEventLoop(selector)``) and gives it
+    to the ``DeviceRuntime``, which sets :attr:`recorder`; until then,
+    and on any other loop, it is the selector it extends."""
+
+    recorder: Optional[StageRecorder] = None
+
+    def select(self, timeout=None):
+        recorder = self.recorder
+        if recorder is None:
+            return super().select(timeout)
+        called = recorder.clock()
+        events = super().select(timeout)
+        # asyncio asks with timeout 0 whenever callbacks are ready
+        recorder.turn(called, recorder.clock(), timeout is not None and timeout <= 0)
+        return events

@@ -211,6 +211,11 @@ async def capture_device_profile(out_dir: str, ms: int) -> Dict[str, Any]:
     ms = max(1, min(int(ms), 60_000))
     path = f"{out_dir}/device_trace_{_time.time_ns() // 1_000_000}"
     _capture_active = True
+    # the spans that annotate themselves only under a capture (a socket
+    # read's ``fantoch/decode`` and ``fantoch/admit``) do so from here on
+    from fantoch_tpu.observability.device import StageRecorder
+
+    StageRecorder.capturing = True
     try:
         options = profiler.ProfileOptions()
         options.python_tracer_level = 0
@@ -222,7 +227,7 @@ async def capture_device_profile(out_dir: str, ms: int) -> Dict[str, Any]:
     except Exception as exc:  # noqa: BLE001 — a failed capture must not kill serving
         return {"error": f"profiler capture failed: {exc!r}"}
     finally:
-        _capture_active = False
+        StageRecorder.capturing = _capture_active = False
     logger.warning("device profile captured: %s (%d ms)", path, ms)
     return {"path": path, "ms": ms}
 

@@ -65,8 +65,10 @@ from fantoch_tpu.core.kvs import KVStore
 from fantoch_tpu.executor.base import ExecutorResult
 from fantoch_tpu.observability.device import (
     CPU_PAIR_EVERY_NS,
+    LOOP_ROW_NS,
     AccountSample,
     ThreadAccount,
+    TimedSelector,
     off_cpu_ns,
 )
 from fantoch_tpu.run.collector import CollectorSchedule
@@ -1935,6 +1937,9 @@ class _DeviceClientSession:
         # commands in the ring
         now_ms = t0 / 1e6
         runtime = self.runtime
+        stages = runtime.stages
+        # on the capture's clock too, while one runs
+        note = stages.annotate("fantoch/admit") if stages.capturing else None
         # the thread's CPU time of the pass beside its wall time, at most
         # once in CPU_PAIR_EVERY_NS (as a stage's span takes it)
         timed = t0 >= runtime._admit_cpu_due
@@ -2012,11 +2017,20 @@ class _DeviceClientSession:
                 runtime.submit_all(admitted, now_ms)
             if timed:
                 runtime._admit_cpu_ns += thread_time_ns() - cpu0
-            took = monotonic_ns() - t0
+            end = monotonic_ns()
+            took = end - t0
             if timed:
                 runtime._admit_timed_ns += took
                 runtime._admit_cpu_due = t0 + CPU_PAIR_EVERY_NS
             runtime._admit_ns += took
+            if note is not None:
+                note.__exit__(None, None, None)
+            # the socket read's pass, from the first byte ``recv_all``
+            # walked: its clock read and this pass's, no third
+            read_t0 = self.rw.read_t0
+            if read_t0:
+                self.rw.read_t0 = 0
+                stages.record("read", read_t0, end, row=end - read_t0 >= LOOP_ROW_NS)
 
     def _not_a_submit(self, msg: Any) -> None:
         if not isinstance(msg, Register):
@@ -2096,6 +2110,7 @@ class DeviceRuntime:
         metrics_port: Optional[int] = None,
         trace_file: Optional[str] = None,
         flight_dir: Optional[str] = None,
+        loop_selector: Optional[TimedSelector] = None,
     ):
         self.config = config
         self.process_id = process_id
@@ -2268,6 +2283,13 @@ class DeviceRuntime:
         # StageRecorder): the driver's recorder, shared, so the loop's
         # stages and the step's land in one ring on one clock
         self.stages = self.driver.stages
+        # the selector of the loop this runtime is made on, where the loop
+        # was made over one that reads the clock (``bin/server``): from
+        # here on each of the loop's visits to it is the recorder's, which
+        # keeps the loop's thread's own account (loop_busy_ms and the rest);
+        # without one the snapshot has none of those counters
+        if loop_selector is not None:
+            loop_selector.recorder = self.stages
         # who had the CPU meanwhile: this thread (the loop's) and the
         # pool's, sampled where the tallies are published and by the probe
         self.account = ThreadAccount()
@@ -2514,6 +2536,10 @@ class DeviceRuntime:
             **self._collector.counters(),
             # adaptive ingest batcher tallies (run/ingest.py)
             **self._batcher.counters(),
+            # where the chain tuner stands: its S (serving_chain_len is
+            # what the last dispatch carried) and how often it has moved
+            "serving_chain": self._chain_tuner.chain,
+            "chain_adjustments": self._chain_tuner.adjustments,
             "precompiled_programs": d.precompiled_programs,
             "jax_recompiles": recompile_count(),
             "jax_compile_ms": compile_ms(),
@@ -2580,7 +2606,7 @@ class DeviceRuntime:
     _GAUGE_TALLIES = frozenset({
         "in_flight", "stable_watermark", "queued", "queued_hwm",
         "queue_capacity", "device_idle_frac", "device_pipeline_depth",
-        "dispatch_fill_frac", "serving_chain_len", "ingest_target",
+        "dispatch_fill_frac", "serving_chain_len", "serving_chain", "ingest_target",
         "ingest_rate_per_s", "loop_lag_hwm_ms", "precompiled_programs",
         "gc_frozen_objects", "sites_registered", "scc_rows_max",
     })
@@ -2655,7 +2681,7 @@ class DeviceRuntime:
 
     async def _on_client(self, reader, writer) -> None:
         session = _DeviceClientSession(
-            self, Rw(reader, writer, decode_tally=self._decode_tally)
+            self, Rw(reader, writer, decode_tally=self._decode_tally, stages=self.stages)
         )
         self.spawn(session.run(), fatal=False)
 

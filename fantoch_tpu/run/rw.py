@@ -159,6 +159,7 @@ class Rw:
         reader: asyncio.StreamReader,
         writer: asyncio.StreamWriter,
         decode_tally: Optional[List[int]] = None,
+        stages=None,
     ):
         """``decode_tally``: ``[ns, frames, reads, CPU ns, timed ns, due
         ns, frames of a kind]``, which the owner shares among its
@@ -169,10 +170,16 @@ class Rw:
         kind in a byte and named no callable (the device runtime's
         ``session_decode_ms``, ``session_decoded``, ``session_reads``,
         ``session_decode_cpu_ms``, ``session_decode_timed_ms``,
-        ``session_plain_decoded``)."""
+        ``session_plain_decoded``).  ``stages``: the owner's
+        ``StageRecorder``; while it says a profiler capture is running,
+        ``recv_all`` annotates its walk as ``fantoch/decode``."""
         self._reader = reader
         self._writer = writer
         self._decode_tally = decode_tally
+        self._stages = stages
+        # when the walk of the last ``recv_all`` that gave frames began (its
+        # own first clock read): where the session's ``read`` span starts
+        self.read_t0 = 0
         self._tail = b""  # recv_all: the bytes of a frame not yet whole
         sock = writer.get_extra_info("socket")
         if sock is not None:
@@ -210,6 +217,7 @@ class Rw:
             self._reader.read, _LEN.unpack_from, pickle.loads, _LEN.size,
         )
         restorers, off_wire = _RESTORERS.get, _off_wire
+        stages = self._stages
         while True:
             try:
                 data = await read(_READ_ALL)
@@ -225,37 +233,48 @@ class Rw:
             timed = tally is not None and t0 >= tally[5]
             if timed:
                 cpu0 = thread_time_ns()
+            # on the capture's clock too, while one runs (and only then: a
+            # read is too frequent a span to annotate always)
+            note = (
+                stages.annotate("fantoch/decode")
+                if stages is not None and stages.capturing
+                else None
+            )
             if self._tail:
                 data = self._tail + data
             values: List[Any] = []
             view = memoryview(data)
             at, end, plain = 0, len(data), 0
-            while end - at >= size:
-                start = at + size
-                stop = start + unpack_from(data, at)[0]
-                if stop > end:
-                    break
-                # as deserialize, on the read's own bytes
-                if stop == start:
-                    raise ProtocolError("empty frame")
-                kind = data[start]
-                if kind == KIND_SUBMIT:
-                    # the server's hot frame first: its command, and no
-                    # Submit made for _admit to take off again
-                    values.append(off_wire(loads(view[start + 1 : stop])))
-                    plain += 1
-                elif kind == _PICKLE:
-                    value = loads(view[start:stop])
-                    # a Submit as a sender before PR 39 framed it gives
-                    # its command as well
-                    values.append(value.cmd if value.__class__ is Submit else value)
-                else:
-                    restore = restorers(kind)
-                    if restore is None:
-                        raise ProtocolError(f"unknown frame kind {kind:#04x}")
-                    values.append(restore(*loads(view[start + 1 : stop])))
-                    plain += 1
-                at = stop
+            try:
+                while end - at >= size:
+                    start = at + size
+                    stop = start + unpack_from(data, at)[0]
+                    if stop > end:
+                        break
+                    # as deserialize, on the read's own bytes
+                    if stop == start:
+                        raise ProtocolError("empty frame")
+                    kind = data[start]
+                    if kind == KIND_SUBMIT:
+                        # the server's hot frame first: its command, and no
+                        # Submit made for _admit to take off again
+                        values.append(off_wire(loads(view[start + 1 : stop])))
+                        plain += 1
+                    elif kind == _PICKLE:
+                        value = loads(view[start:stop])
+                        # a Submit as a sender before PR 39 framed it gives
+                        # its command as well
+                        values.append(value.cmd if value.__class__ is Submit else value)
+                    else:
+                        restore = restorers(kind)
+                        if restore is None:
+                            raise ProtocolError(f"unknown frame kind {kind:#04x}")
+                        values.append(restore(*loads(view[start + 1 : stop])))
+                        plain += 1
+                    at = stop
+            finally:
+                if note is not None:
+                    note.__exit__(None, None, None)
             self._tail = data[at:]
             if tally is not None:
                 if timed:
@@ -269,6 +288,7 @@ class Rw:
                 tally[2] += bool(values)
                 tally[6] += plain
             if values:
+                self.read_t0 = t0
                 return values
 
     def write(self, value: Any) -> None:
