@@ -51,7 +51,7 @@ import sys
 from collections import defaultdict
 from functools import partial
 from itertools import repeat, zip_longest
-from operator import itemgetter
+from operator import attrgetter, itemgetter
 from time import monotonic, monotonic_ns, thread_time_ns
 from typing import Any, Dict, List, Optional, Sequence, Tuple
 from zlib import crc32
@@ -100,6 +100,16 @@ _HASH_MIX = 2654435761  # Knuth's multiplier, 2**32 / phi
 _HASH_MASK = 0xFFFFFFFF
 # the shard of a ``(shard, keys)`` entry of a command's nested wire form
 _SHARD = itemgetter(0)
+# a batch entry ``(dot, cmd)``'s halves, and what a round's identity
+# columns read off them (``_DriverCore._identity_columns``)
+_DOT, _CMD = itemgetter(0), itemgetter(1)
+_SOURCE, _SEQUENCE = attrgetter("source"), attrgetter("sequence")
+_READ_ONLY = attrgetter("_read_only")
+
+
+def _top_sequence(batch) -> int:
+    """The highest dot sequence of a batch that is not empty."""
+    return max(map(_SEQUENCE, map(_DOT, batch)))
 
 
 def _bucket(sid: ShardId, k: str, key_buckets: int, shard_count: int) -> int:
@@ -342,8 +352,7 @@ class _DriverCore(PipelineCore):
         (gid epoch, clock window, slot log)."""
         if not batch:
             return False
-        top = max(dot.sequence for dot, _ in batch) - self._seq_base
-        return top >= self.SEQ_WINDOW_MAX
+        return _top_sequence(batch) - self._seq_base >= self.SEQ_WINDOW_MAX
 
     def _init_sharded_mesh(
         self, mesh_step, num_replicas: int, shard_count: int,
@@ -409,9 +418,8 @@ class _DriverCore(PipelineCore):
         fixed-size key/src/seq columns and register commands under
         packed (source, window sequence)."""
         assert len(batch) <= self.batch_size
-        self._ensure_seq_window(batch)
         key, src, seq = self._staging(*self._column_specs())
-        self._assemble_rows(batch, key, src, seq)
+        self._assemble_round(batch, key, src, seq)
         return key, src, seq
 
     # whether a chain of S rounds is one dispatch of a program of its own
@@ -504,15 +512,50 @@ class _DriverCore(PipelineCore):
         self.rounds += S
         return out
 
-    def _assemble_rows(self, batch, key_rows, src_row, seq_row) -> None:
+    def _assemble_round(self, batch, key_rows, src_row, seq_row) -> None:
         """Fill one round's fixed-size key/src/seq columns in place and
-        register each command under its packed (source, window sequence)
-        — the caller guarantees the sequence window already fits."""
+        register its commands under their packed (source, window
+        sequence)."""
         _key_column(batch, key_rows, self.shard_id, self.key_buckets, self.shard_count)
-        for i, (dot, cmd) in enumerate(batch):
-            src_row[i] = dot.source
-            seq_row[i] = self._device_seq(dot)
-            self._cmds[self._packed(dot.source, seq_row[i])] = (dot, cmd)
+        self._identity_columns(batch, src_row, seq_row)
+
+    def _identity_columns(
+        self, batch, src_row, seq_row, read_row=None, valid_row=None,
+        first_gid: Optional[int] = None,
+    ) -> None:
+        """One round's identity columns and its registry entries, a
+        column at a time, for every driver.  ``src_row`` and ``seq_row``
+        take the dots' sources and window sequences (``sequence -
+        _seq_base``; the window is advanced first where the batch's top
+        sequence asks for it, ``_ensure_seq_window``); where the driver
+        stages them, ``read_row`` takes which commands only read and
+        ``valid_row`` which rows hold a command.  Each is one conversion,
+        and the rows past the batch keep their fill.  The registry takes
+        the batch's own entries in one update: under the gids from
+        ``first_gid`` on where the driver keys by gid, under the packed
+        (source, window sequence) where it keys by dot."""
+        n = len(batch)
+        if not n:
+            return
+        dots = list(map(_DOT, batch))
+        sequences = np.fromiter(map(_SEQUENCE, dots), np.int64, n)
+        self._ensure_seq_window(batch, int(sequences.max()))
+        sequences -= self._seq_base
+        assert sequences.min() >= 0, (
+            f"dot sequence {int(sequences.min()) + self._seq_base} outside "
+            f"the device window (base {self._seq_base})"
+        )
+        src_row[:n] = np.fromiter(map(_SOURCE, dots), np.int32, n)
+        seq_row[:n] = sequences
+        if read_row is not None:
+            read_row[:n] = np.fromiter(map(_READ_ONLY, map(_CMD, batch)), np.bool_, n)
+        if valid_row is not None:
+            valid_row[:n] = True
+        if first_gid is None:
+            keys = self._packed_column(src_row, seq_row, slice(n))
+        else:
+            keys = range(first_gid, first_gid + n)
+        self._cmds.update(zip(keys, batch))
 
     @staticmethod
     def _packed_column(work_src, work_seq, rows) -> List[int]:
@@ -630,16 +673,11 @@ class _DriverCore(PipelineCore):
 
     # --- the 31-bit dot-sequence window ---
 
-    def _device_seq(self, dot: Dot) -> int:
-        seq = dot.sequence - self._seq_base
-        assert 0 <= seq < 2**31 - 1, (
-            f"dot sequence {dot.sequence} outside the device window "
-            f"(base {self._seq_base}); _ensure_seq_window must run first"
-        )
-        return seq
-
-    def _ensure_seq_window(self, batch: List[Tuple[Dot, Command]]) -> None:
-        """Advance the sequence window if this batch would overflow it.
+    def _ensure_seq_window(
+        self, batch: List[Tuple[Dot, Command]], top_sequence: int
+    ) -> None:
+        """Advance the sequence window if this batch, whose highest dot
+        sequence is ``top_sequence``, would overflow it.
 
         The new base is the oldest sequence still relevant to the device:
         min over in-flight registry dots, requeued dots, and the incoming
@@ -647,9 +685,7 @@ class _DriverCore(PipelineCore):
         so the uniform shift is order-preserving; the driver-specific
         ``_shift_seq_state`` rebases device-resident and mirrored
         sequence columns."""
-        if not batch:
-            return
-        top = max(dot.sequence for dot, _ in batch) - self._seq_base
+        top = top_sequence - self._seq_base
         if top < self.SEQ_WINDOW_MAX:
             return
         # the rebase rewrites device-resident sequence columns an
@@ -1027,15 +1063,12 @@ class DeviceDriver(_DriverCore):
                     "gid space exhausted: a long-stuck in-flight command "
                     "pins the epoch (oldest live gid too old to rebase)"
                 )
-        self._ensure_seq_window(batch)
         if self._site_program is not None:
             batch = _sites_in_turn(batch)
         _key_column(batch, key, self.shard_id, self.key_buckets, self.shard_count)
-        for i, (dot, cmd) in enumerate(batch):
-            src[i] = dot.source
-            seq[i] = self._device_seq(dot)
-            read[i] = cmd.read_only
-            self._cmds[self._next_gid + i] = (dot, cmd)
+        self._identity_columns(
+            batch, src, seq, read_row=read, first_gid=self._next_gid
+        )
         return (key, src, seq, read), len(batch)
 
     def _enqueue(self, staged):
@@ -1275,9 +1308,8 @@ class NewtDeviceDriver(_DriverCore):
         still in flight plus this chain's S."""
         S = len(batches)
         work = self._pend_cap + self.batch_size
-        top = max(
-            (d.sequence for batch in batches for d, _ in batch), default=0
-        ) - self._seq_base
+        tops = map(_top_sequence, filter(None, batches))
+        top = max(tops, default=0) - self._seq_base
         return (
             self._max_clock + (self._undrained_rounds + S) * work
             >= self.CLOCK_RESET_THRESHOLD
@@ -1320,9 +1352,14 @@ class NewtDeviceDriver(_DriverCore):
         keys = np.full((S, b, self.key_width), KEY_PAD, dtype=np.int32)
         srcs = np.zeros((S, b), dtype=np.int32)
         seqs = np.zeros((S, b), dtype=np.int32)
+        epochs = self.seq_epochs
         for r, batch in enumerate(batches):
             assert len(batch) <= b
-            self._assemble_rows(batch, keys[r], srcs[r], seqs[r])
+            self._assemble_round(batch, keys[r], srcs[r], seqs[r])
+        assert self.seq_epochs == epochs, (
+            "dot-sequence window advance inside a chain "
+            "(_chain_windows_blocked must prevent this)"
+        )
         return keys, srcs, seqs
 
     def _enqueue(self, columns, S: int = 1):
@@ -1602,13 +1639,8 @@ class PaxosDeviceDriver(_DriverCore):
                     "slot log exhausted: the contiguous exec frontier is "
                     "pinned too far behind to rebase"
                 )
-        self._ensure_seq_window(batch)
         valid, src, seq = self._staging(*self._column_specs())
-        for i, (dot, cmd) in enumerate(batch):
-            valid[i] = True
-            src[i] = dot.source
-            seq[i] = self._device_seq(dot)
-            self._cmds[self._packed(dot.source, seq[i])] = (dot, cmd)
+        self._identity_columns(batch, src, seq, valid_row=valid)
         return (valid, src, seq), len(batch)
 
     def _enqueue(self, staged):
@@ -1933,8 +1965,8 @@ class _DeviceClientSession:
         is taken where it stands: what was admitted before it is pushed
         whatever it raises."""
         t0 = monotonic_ns()
-        # the read's one arrival time: it rides beside each of its
-        # commands in the ring
+        # the read's one arrival time: it rides beside the read's run in
+        # the ring
         now_ms = t0 / 1e6
         runtime = self.runtime
         stages = runtime.stages
@@ -1958,7 +1990,7 @@ class _DeviceClientSession:
         if key_width is None:
             key_width = sys.maxsize
         flat = 0
-        admitted: List[Tuple[Dot, Command, float]] = []
+        admitted: List[Tuple[Dot, Command]] = []
         try:
             for cmd in msgs:
                 if cmd.__class__ is not Command:
@@ -2010,7 +2042,7 @@ class _DeviceClientSession:
                     tracer.span(
                         "payload", cmd.rifl, dot=dot, pid=runtime.process_id,
                     )
-                admitted.append((dot, cmd, now_ms))
+                admitted.append((dot, cmd))
         finally:
             runtime._flat_admitted += flat
             if admitted:
@@ -2322,6 +2354,9 @@ class DeviceRuntime:
         self._admit_cpu_due = 0
         self._queue_wait_ms = 0.0  # sum over released commands, ring time
         self._queue_released = 0
+        # the slices ``_collect`` took from the ring: a read's run, or
+        # the part of one a round had room for
+        self._collect_slices = 0
         # the loop's own lateness (_lag_task)
         self._loop_lag_hwm_ms = 0.0
         self._loop_stall_ms = 0.0
@@ -2505,6 +2540,7 @@ class DeviceRuntime:
             ),
             "queue_wait_ms": round(self._queue_wait_ms, 3),
             "queue_released": self._queue_released,
+            "collect_slices": self._collect_slices,
             "reply_flush_ms": round(self._flush_ns / 1e6, 3),
             "reply_flushes": self._flushes,
             "reply_writes": self._reply_writes,
@@ -2719,18 +2755,18 @@ class DeviceRuntime:
         return base * max(1, len(ring) // max(1, self.driver.batch_size))
 
     def submit(self, dot: Dot, cmd: Command) -> None:
-        now_ms = monotonic() * 1000.0
-        self.submit_all([(dot, cmd, now_ms)], now_ms)
+        self.submit_all([(dot, cmd)], monotonic() * 1000.0)
 
     def submit_all(
-        self, admitted: List[Tuple[Dot, Command, float]], now_ms: float
+        self, admitted: List[Tuple[Dot, Command]], now_ms: float
     ) -> None:
         """The commands a session admitted from one socket read, in
-        their order, each with the read's arrival time ``now_ms`` beside
-        it (the ring wait of a command is read off it at release,
-        ``queue_wait_ms``): the pushes, one note to the ingest batcher
-        and one wake-up of the driver task for all of them."""
-        if not self._submit_queue.try_extend(admitted):
+        their order: one run of the ring, with the read's arrival time
+        ``now_ms`` beside it (the ring wait of its commands is read off
+        it at release, ``queue_wait_ms``).  One push, one note to the
+        ingest batcher and one wake-up of the driver task for all of
+        them; the list is the ring's from here on."""
+        if not self._submit_queue.try_extend(admitted, now_ms):
             # unreachable via sessions (room() is counted on the same
             # cooperative tick, with no await between count and
             # submit) — a real exception, not an assert, so a future
@@ -2845,31 +2881,42 @@ class DeviceRuntime:
     def _collect(self, round_id: int, chain: int) -> List[List[Tuple[Dot, Command]]]:
         """Up to ``chain`` rounds (the auto-tuned chain length) from the
         requeue and the released queue, canonicalised to the pow2 ladder
-        of chain lengths."""
+        of chain lengths.  A round's batch is the requeue's head, then
+        the ring's runs whole while they fit, then the head of the run
+        that does not: slices, each counted once (``collect_slices``),
+        and no command is touched alone while the tracer is off."""
         driver = self.driver
+        ring = self._submit_queue
+        size = driver.batch_size
+        take = ring.take
         tracer = self.tracer
+        tracing = tracer.enabled
         batches: List[List[Tuple[Dot, Command]]] = []
         pending = driver.take_requeue()
+        taken = 0  # of the requeue
         released = 0
+        slices = 0
         arrived_ms = 0.0
-        while (pending or self._submit_queue) and len(batches) < chain:
-            batch: List[Tuple[Dot, Command]] = []
-            while pending and len(batch) < driver.batch_size:
-                batch.append(pending.pop(0))
-            while self._submit_queue and len(batch) < driver.batch_size:
-                dot, cmd, at_ms = self._submit_queue.popleft()
-                if tracer.enabled:
+        while (taken < len(pending) or ring) and len(batches) < chain:
+            batch = pending[taken : taken + size]
+            taken += len(batch)
+            while ring and len(batch) < size:
+                run, at_ms = take(size - len(batch))
+                if tracing:
                     # batch release: payload->ingest is the queue +
                     # batching wait (critpath's ingest-batching
                     # bucket); the round says which `rs` slice it rode
-                    tracer.span(
-                        "ingest", cmd.rifl, dot=dot, pid=self.process_id,
-                        meta={"round": round_id},
-                    )
-                batch.append((dot, cmd))
-                released += 1
-                arrived_ms += at_ms
+                    for dot, cmd in run:
+                        tracer.span(
+                            "ingest", cmd.rifl, dot=dot, pid=self.process_id,
+                            meta={"round": round_id},
+                        )
+                batch += run
+                slices += 1
+                released += len(run)
+                arrived_ms += len(run) * at_ms
             batches.append(batch)
+        pending = pending[taken:]
         if len(batches) > 1:
             # canonicalize the dispatched chain length to the pow2
             # ladder: each chain length is a program of its own, and
@@ -2893,6 +2940,7 @@ class DeviceRuntime:
             self._batcher.note_release(now_ms, released)
             self._queue_wait_ms += released * now_ms - arrived_ms
             self._queue_released += released
+            self._collect_slices += slices
         return batches or [[]]  # nothing queued: a pending-buffer progress round
 
     async def _serve_round(self, whole, step, *args) -> List[ExecutorResult]:

@@ -104,55 +104,78 @@ class BoundedSubmitRing:
     """Bounded FIFO of pending submissions feeding a serving loop — the
     device runtime's admission edge (run/backpressure.py plane).
 
-    ``try_push`` refuses entries past ``capacity`` (the caller replies
-    with a typed Overloaded frame instead of queueing without bound);
-    the depth high-watermark rides the ring for the metrics snapshot,
-    and the admission edge that refuses a command tallies it on
-    ``sheds`` (the ring only *checks* the bound — counting belongs to
-    whoever owns the reply, so one shed is never counted twice).
-    ``capacity=None`` keeps the legacy unbounded behavior.
+    What it holds is *runs*: the entries one push brought (the commands
+    a session admitted from one socket read), in their order, with the
+    push's one arrival time beside them.  What it counts is entries:
+    ``len``, ``capacity``, ``depth_hwm`` and the refusal read commands,
+    however they were pushed.  ``try_extend`` pushes a run, all of it or
+    none where it would pass ``capacity`` (the caller replies with a
+    typed Overloaded frame instead of queueing without bound), and
+    ``take`` hands it back by slices; ``try_push`` / ``popleft`` are the
+    one-entry forms (a run of one, a slice of one).  The depth
+    high-watermark rides the ring for the metrics snapshot, and the
+    admission edge that refuses a command tallies it on ``sheds`` (the
+    ring only *checks* the bound — counting belongs to whoever owns the
+    reply, so one shed is never counted twice).  ``capacity=None`` keeps
+    the legacy unbounded behavior.
     """
 
-    __slots__ = ("capacity", "depth_hwm", "sheds", "_items")
+    __slots__ = ("capacity", "depth_hwm", "sheds", "_runs", "_depth")
 
     def __init__(self, capacity: Optional[int] = None):
         assert capacity is None or capacity >= 1
         self.capacity = capacity
         self.depth_hwm = 0
         self.sheds = 0
-        self._items: Deque[Any] = deque()
+        # (entries, arrival time) a run; the first may be the tail of
+        # one a ``take`` split
+        self._runs: Deque[Tuple[List[Any], float]] = deque()
+        self._depth = 0
 
-    def try_push(self, item: Any) -> bool:
-        if self.capacity is not None and len(self._items) >= self.capacity:
-            return False
-        self._items.append(item)
-        if len(self._items) > self.depth_hwm:
-            self.depth_hwm = len(self._items)
-        return True
+    def try_push(self, item: Any, at_ms: float = 0.0) -> bool:
+        return self.try_extend([item], at_ms)
 
-    def try_extend(self, items: List[Any]) -> bool:
-        """All of ``items`` in their order, or none of them where they
-        would pass ``capacity``."""
-        depth = len(self._items) + len(items)
+    def try_extend(self, items: List[Any], at_ms: float = 0.0) -> bool:
+        """All of ``items`` in their order, as one run that arrived at
+        ``at_ms`` (the ring keeps the list: the caller is done with it),
+        or none of them where they would pass ``capacity``."""
+        depth = self._depth + len(items)
         if self.capacity is not None and depth > self.capacity:
             return False
-        self._items.extend(items)
-        if depth > self.depth_hwm:
-            self.depth_hwm = depth
+        if items:
+            self._runs.append((items, at_ms))
+            self._depth = depth
+            if depth > self.depth_hwm:
+                self.depth_hwm = depth
         return True
 
+    def take(self, limit: int) -> Tuple[List[Any], float]:
+        """The next slice and its arrival time: the first run whole
+        where it has at most ``limit`` (>= 1) entries, else its first
+        ``limit``, the rest staying first in the ring under the same
+        arrival time.  The ring must not be empty."""
+        run = self._runs[0]
+        items, at_ms = run
+        if len(items) <= limit:
+            self._runs.popleft()
+            self._depth -= len(items)
+            return run
+        self._runs[0] = (items[limit:], at_ms)
+        self._depth -= limit
+        return items[:limit], at_ms
+
     def popleft(self) -> Any:
-        return self._items.popleft()
+        return self.take(1)[0][0]
 
     def __len__(self) -> int:
-        return len(self._items)
+        return self._depth
 
     def __bool__(self) -> bool:
-        return bool(self._items)
+        return self._depth > 0
 
     def stats(self) -> Dict[str, float]:
         return {
-            "depth": len(self._items),
+            "depth": self._depth,
             "depth_hwm": self.depth_hwm,
             "capacity": self.capacity if self.capacity is not None else 0,
             "sheds": self.sheds,
@@ -344,8 +367,8 @@ class PipelineCore:
         return self._dispatch_halves(self._assemble, self._enqueue, batch)
 
     def _dispatch_halves(self, assemble, enqueue, work):
-        """``assemble``: staging slot, window checks, the row loop, the
-        registry.  ``enqueue``: the columns handed to jax and the jitted
+        """``assemble``: staging slot, window checks, the round's columns,
+        the registry.  ``enqueue``: the columns handed to jax and the jitted
         call returning."""
         with self.stages.span("assemble", self._span_round):
             staged = assemble(work)

@@ -2715,7 +2715,7 @@ def _several_key_shapes(shard_count, key_buckets=64):
 def test_admits_one_key_branch_rejects_what_validate_rejects_in_its_words(shard_count):
     runtime, (session,), (writer,) = _reply_stage(shard_count, connections=1)
     pushed, rejected = [], {}
-    runtime.submit_all = lambda admitted, now_ms: pushed.extend(cmd for _dot, cmd, _at in admitted)
+    runtime.submit_all = lambda admitted, now_ms: pushed.extend(cmd for _dot, cmd in admitted)
     reject = session._reject
     session._reject = lambda cmd, why: (rejected.__setitem__(cmd.rifl, why), reject(cmd, why))
     cmds = _one_key_shapes(shard_count) + _several_key_shapes(shard_count)

@@ -502,7 +502,7 @@ def test_a_served_command_is_its_frames_tuple_from_the_read_to_the_reply(shard_c
             submit_all = runtime.submit_all
 
             def entering(admitted, now_ms):
-                for _dot, cmd, _at in admitted:
+                for _dot, cmd in admitted:
                     assert type(cmd) is Command and not has_dicts(cmd)
                     assert not any(isinstance(x, (dict, KVOp, Submit)) for x in gc.get_referents(cmd))
                     ring.append(cmd)
@@ -539,7 +539,7 @@ def test_under_a_monitor_the_drain_asks_for_the_dicts_and_counts_nothing_off_the
         async with _Served() as served:
             ring, submit_all = [], served.runtime.submit_all
             served.runtime.submit_all = lambda admitted, now_ms: (
-                ring.extend(cmd for _dot, cmd, _at in admitted), submit_all(admitted, now_ms))[1]
+                ring.extend(cmd for _dot, cmd in admitted), submit_all(admitted, now_ms))[1]
             await served.read(b"".join(rw.frame(m) for m in _burst(0)))
             await served.replies(6)
             assert len(ring) == 6 and all(has_dicts(cmd) for cmd in ring)
@@ -559,7 +559,7 @@ def test_a_submit_object_is_not_what_admit_takes():
             served.runtime.submit_all = lambda admitted, now_ms: pushed.extend(admitted)
             with pytest.raises(ProtocolError, match="unexpected message Submit"):
                 served.session._admit([_submit(1, 1, "a").cmd, _submit(1, 2, "b")])
-            return [cmd.rifl for _dot, cmd, _at in pushed]
+            return [cmd.rifl for _dot, cmd in pushed]
 
     assert asyncio.run(go()) == [Rifl(1, 1)]  # what was admitted before it is pushed
 
@@ -581,7 +581,7 @@ def test_a_frame_that_breaks_the_commands_contract_ends_the_session_as_on_the_pa
         async with _Served(key_width=2) as served:
             pushed, submit_all = [], served.runtime.submit_all
             served.runtime.submit_all = lambda admitted, now_ms: (
-                pushed.extend(cmd.rifl for _dot, cmd, _at in admitted), submit_all(admitted, now_ms))[1]
+                pushed.extend(cmd.rifl for _dot, cmd in admitted), submit_all(admitted, now_ms))[1]
             await served.read(rw.frame(_submit(1, 1, "a")))
             assert [r.cmd_result.rifl for r in await served.replies(1)] == [Rifl(1, 1)]
             bad = bytes((rw.KIND_SUBMIT,)) + pickle.dumps(values, protocol=pickle.HIGHEST_PROTOCOL)
