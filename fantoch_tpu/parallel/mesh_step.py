@@ -364,6 +364,14 @@ def _run_start(head: jax.Array) -> jax.Array:
     return jax.lax.cummax(jnp.where(head, pos, 0))
 
 
+def _run_end(head: jax.Array) -> jax.Array:
+    """The last sorted position of each position's key run."""
+    size = head.shape[0]
+    pos = jnp.arange(size, dtype=jnp.int32)
+    tail = jnp.concatenate([head[1:], jnp.ones((1,), bool)])
+    return jax.lax.cummin(jnp.where(tail, pos, size), reverse=True)
+
+
 def _chain_of_runs(
     perm: jax.Array, head: jax.Array, shape, member: jax.Array | None = None
 ) -> jax.Array:
@@ -1020,11 +1028,7 @@ def _protocol_step_sites(
 
         def whole_run(v, so_far):  # the latest of ``v`` (positions, -1 none) in the run
             if 2 * bits > 31:  # a working set too long to pack into an int32
-                run_last = jax.lax.cummin(
-                    jnp.where(jnp.concatenate([head[1:], jnp.ones((1,), bool)]), pos, slots),
-                    reverse=True,
-                )
-                return in_run(so_far[:, run_last])
+                return in_run(so_far[:, _run_end(head)])
             later = jax.lax.cummax(
                 jnp.where(v >= 0, (run_down << bits) | (v + 1), 0), axis=1, reverse=True
             )
@@ -1350,6 +1354,34 @@ class NewtStepOutput(NamedTuple):
     work_seq: jax.Array  # int32[W]
 
 
+class NewtSiteStepOutput(NamedTuple):
+    """What the Newt round with a coordinator at every site gives
+    (``newt_protocol_step(sites=n)``): :class:`NewtStepOutput`'s fields, so
+    a drain reads it as it reads that, and the round's tallies."""
+
+    order: jax.Array
+    executed: jax.Array
+    committed: jax.Array
+    fast_path: jax.Array
+    clock: jax.Array
+    slow_paths: jax.Array
+    stable_watermark: jax.Array
+    pending: jax.Array
+    pend_dropped: jax.Array
+    work_src: jax.Array
+    work_seq: jax.Array
+    tallies: jax.Array  # int32[3], by the names of NEWT_SITE_ROUND_TALLIES
+
+
+# NewtSiteStepOutput.tallies, in order: over the rows committed this round,
+# the sum of the timestamp less the lowest proposal of the row's fast quorum
+# (how far the views disagree), and the rows that share (key, clock) with
+# another of them (the dot decided); the rows executed this round that came
+# out before a row of their key, executed this round too, that stood earlier
+# in the working set (timestamp order is not arrival order there)
+NEWT_SITE_ROUND_TALLIES = ("site_clock_spread", "clock_ties", "arrival_reordered")
+
+
 def newt_quorum_sizes(
     num_replicas: int, f: int, tiny_quorums: bool = False
 ) -> Tuple[int, int, int]:
@@ -1417,6 +1449,70 @@ def _segmented_proposal(prior_of_row, key_full, work):
     return jnp.zeros_like(base).at[:, perm].set(clock_sorted)
 
 
+def _site_proposals(clock_at, perm, head, run_id, propose_at, site_at, fast_quorum, f):
+    """The proposals of a Newt round with a coordinator at every site, over
+    the working set sorted by key (:func:`_key_runs`: a key's rows one run,
+    in working order): ``(timestamp, fast, lowest)`` by working row, the
+    highest proposal of each row's fast quorum, whether at least ``f``
+    members reported it, and the lowest proposal of the quorum.
+
+    ``clock_at``: int32[n, W], every replica's clock for the key at each
+    sorted position; ``run_id``: the number of each position's run;
+    ``propose_at`` / ``site_at``: by position, whether the
+    row proposes this round and the site of its coordinator.  Replica
+    ``r``'s view is its own site's rows in working order, then the others'
+    (the plain reference is ``tests/tempo_sites_reference.py``):
+
+      * the coordinator's proposal ``c(x)`` is its clock plus its own rows
+        of the key up to ``x`` (it proposes ``clock + 1`` each time, and
+        nothing of another site stands before them in its view);
+      * a member ``r`` of the ring ``(s + j) % n``, ``j < fast_quorum``, that
+        is not the coordinator starts, for a key, from its clock plus its
+        own rows of the key, and proposes ``v = max(c(x), v + 1)`` at each
+        other row whose ring holds it, in working order: with ``i`` such
+        rows so far, ``i + max(start, the running max of c - i)``, the
+        segmented scan of :func:`_segmented_proposal` with a base a row.
+
+    No recurrence couples two replicas: ``c`` depends on the site's own run
+    alone."""
+    from fantoch_tpu.ops.table_ops import segmented_running_max
+
+    n, work = clock_at.shape
+    int_min, int_max = jnp.iinfo(jnp.int32).min, jnp.iinfo(jnp.int32).max
+
+    def count_in_run(x):  # how many of ``x`` the run holds up to here
+        total = jnp.cumsum(x.astype(jnp.int32), axis=1)
+        # less what the run's first position found before it: totals only
+        # grow, so the latest head's wins a running max
+        return total - jax.lax.cummax(
+            jnp.where(head[None], total - x.astype(jnp.int32), 0), axis=1
+        )
+
+    replica = jnp.arange(n, dtype=jnp.int32)[:, None]
+    own = propose_at[None] & (site_at[None] == replica)  # [n, W]
+    member = propose_at[None] & (jnp.mod(replica - site_at[None], n) < fast_quorum)
+    other = member & ~own  # a member that is not the coordinator
+    own_so_far = count_in_run(own)
+    coordinator = jnp.where(own, clock_at + own_so_far, 0).sum(axis=0)  # c(x), [W]
+    start = clock_at + own_so_far[:, _run_end(head)]
+    turn = count_in_run(other)
+    lifted = segmented_running_max(
+        run_id, jnp.where(other, coordinator[None] - turn, int_min), axis=-1
+    )
+    proposal = jnp.where(
+        own, coordinator[None], jnp.where(other, turn + jnp.maximum(start, lifted), int_min)
+    )  # [n, W]: a replica outside the ring proposes nothing
+    timestamp = proposal.max(axis=0)
+    reports = (member & (proposal == timestamp[None])).sum(axis=0)
+    lowest = jnp.where(member, proposal, int_max).min(axis=0)
+    by_row = jnp.zeros((work, 3), jnp.int32).at[perm].set(
+        jnp.stack(
+            [timestamp, (propose_at & (reports >= f)).astype(jnp.int32), lowest], axis=-1
+        )
+    )
+    return by_row[:, 0], by_row[:, 1] > 0, by_row[:, 2]
+
+
 def newt_protocol_step(
     state: NewtMeshState,
     key: jax.Array,  # int32[B] or int32[B, KW] key buckets (KEY_PAD pads)
@@ -1428,6 +1524,8 @@ def newt_protocol_step(
     tiny_quorums: bool = False,
     live_replicas: int | None = None,
     shard_count: int = 1,
+    sites: int = 1,
+    site_base: int = 1,
 ) -> Tuple[NewtMeshState, NewtStepOutput]:
     """One batched Newt round: timestamp proposal, max aggregation over
     the fast quorum, count-of-max fast path, Synod accept for misses, and
@@ -1462,6 +1560,21 @@ def newt_protocol_step(
     stable on every key it touches (each key judged by its own shard's
     frontiers).  A replica's key-clock/frontier learn only its own
     shard's buckets.
+
+    ``sites`` (static, like the key width): 1 is the round above, every
+    command coordinated by replica 0, every replica seeing the working
+    set in the same order and the fast quorum the first ``fast_quorum``
+    rows.  ``sites == n`` is the round with a coordinator at every site
+    (one shard, one key a command; the plain reference is
+    ``tests/tempo_sites_reference.py``, semantics and departures there):
+    a command's coordinator is the replica at site ``dot_src -
+    site_base``, a replica has its own site's commands before every
+    other's, the coordinator's proposal floors its quorum's, the quorum
+    is a ring from the coordinator's site (:func:`_site_proposals`), and
+    the count-of-max test can fail at ``f`` = 2.  Everything from the
+    commit on is the round above.  Same state, same columns, another
+    program, which also gives :data:`NEWT_SITE_ROUND_TALLIES`
+    (:class:`NewtSiteStepOutput`).
     """
     num_replicas, key_buckets = state.key_clock.shape
     if key.ndim == 1:
@@ -1470,6 +1583,14 @@ def newt_protocol_step(
     assert key_width == state.pend_key.shape[1], (
         "key width must match init_newt_state(key_width=...)"
     )
+    assert sites in (1, num_replicas // shard_count), (
+        "a coordinator at every site: a site a replica"
+    )
+    if sites != 1:
+        assert key_width == 1 and shard_count == 1, (
+            "the Newt round with a coordinator at every site: one key a "
+            "command, one shard"
+        )
     pend_cap = state.pend_key.shape[0]
     work = pend_cap + batch
     assert num_replicas % shard_count == 0, (
@@ -1523,61 +1644,81 @@ def newt_protocol_step(
         row_shard = (row // per_shard)[:, None, None]  # [r_blk, 1, 1]
         own_slot = row_shard == slot_shard[None]  # [r_blk, W, KW]
 
-        # per-replica-block per-slot proposals over the flattened slots
-        # (only the owning shard's replicas read their key clock; other
-        # replicas' lanes compute masked-out garbage)
-        prior_rows = jnp.where(
-            propose_slot[None] & own_slot, key_clock[:, safe_key], 0
-        )  # [r_blk, W, KW]
-        slot_prop = _segmented_proposal(
-            prior_rows.reshape(replica_blocks, work * key_width),
-            key_full.reshape(work * key_width),
-            work * key_width,
-        ).reshape(replica_blocks, work, key_width)
+        if sites != 1:
+            # a coordinator at every site: the working set sorted by key
+            # (a carried, committed row stays in its key's run and
+            # proposes nothing), every replica's clock at each position,
+            # and the rings' proposals
+            perm, head, _ = _key_runs(
+                jnp.where(valid[:, None], key_cat, key_buckets + slot_iota)
+            )
+            clock_at = jax.lax.all_gather(
+                key_clock[:, jnp.minimum(key_cat[perm, 0], key_buckets - 1)],
+                REPLICA_AXIS, tiled=True,
+            )  # [n, W]; a pad's position reads any entry and proposes nothing
+            run_id = jnp.cumsum(head.astype(jnp.int32)) - 1
+            timestamp, fast, lowest = _site_proposals(
+                clock_at, perm, head, run_id, propose[perm],
+                jnp.mod(src_f - site_base, num_replicas)[perm], fast_quorum, f,
+            )
+            fq_max = jnp.where(propose, timestamp, 0)
+            shard_ids = jnp.zeros((1,), jnp.int32)  # one shard
+        else:
+            # per-replica-block per-slot proposals over the flattened slots
+            # (only the owning shard's replicas read their key clock; other
+            # replicas' lanes compute masked-out garbage)
+            prior_rows = jnp.where(
+                propose_slot[None] & own_slot, key_clock[:, safe_key], 0
+            )  # [r_blk, W, KW]
+            slot_prop = _segmented_proposal(
+                prior_rows.reshape(replica_blocks, work * key_width),
+                key_full.reshape(work * key_width),
+                work * key_width,
+            ).reshape(replica_blocks, work, key_width)
 
-        # MCollectAck aggregation: a replica's proposal for a row is ONE
-        # clock per shard it owns — the max over the row's slots in that
-        # shard (the reference's proposal is per command, newt.rs:272-338)
-        # — aggregated over that shard's fast quorum (its first
-        # fast_quorum member rows).  Fast path iff EVERY touched shard's
-        # max was reported by >= f of its quorum members (newt.rs:527-546
-        # via QuorumClocks max_count; the multi-shard fast path needs
-        # every touched shard fast).  For shard_count == 1 this is
-        # exactly the row-level aggregation of the unsharded round, for
-        # every key width.
-        shard_ids = jnp.arange(shard_count, dtype=jnp.int32)
-        slot_onehot = (
-            propose_slot[:, :, None] & (slot_shard[:, :, None] == shard_ids)
-        )  # [W, KW, S]
-        touched = slot_onehot.any(axis=1)  # [W, S]
-        shard_prop = jnp.where(
-            slot_onehot[None], slot_prop[..., None], int_min
-        ).max(axis=2)  # [r_blk, W, S] — this replica's per-shard row clock
-        rep_shard = (row // per_shard)[:, None] == shard_ids[None]  # [r_blk, S]
-        in_fq_rs = (
-            ((row % per_shard) < fast_quorum)[:, None] & rep_shard
-        )[:, None, :]  # [r_blk, 1, S]
-        shard_fq_max = jax.lax.pmax(
-            jnp.where(in_fq_rs, shard_prop, int_min).max(axis=0), REPLICA_AXIS
-        )  # [W, S]
-        shard_reports = jax.lax.psum(
-            (in_fq_rs & (shard_prop == shard_fq_max[None]))
-            .astype(jnp.int32)
-            .sum(axis=0),
-            REPLICA_AXIS,
-        )  # [W, S]
-        fast = (
-            jnp.where(touched, shard_reports >= f, True).all(axis=-1)
-            & propose
-        )
-        # the commit clock: max over the touched shards' commit clocks
-        # (the MShardCommit max aggregation, partial.rs:37-142);
-        # propose rows always have >= 1 real slot, others read 0
-        fq_max = jnp.where(
-            propose,
-            jnp.where(touched, shard_fq_max, int_min).max(axis=-1),
-            0,
-        )  # [W]
+            # MCollectAck aggregation: a replica's proposal for a row is ONE
+            # clock per shard it owns — the max over the row's slots in that
+            # shard (the reference's proposal is per command, newt.rs:272-338)
+            # — aggregated over that shard's fast quorum (its first
+            # fast_quorum member rows).  Fast path iff EVERY touched shard's
+            # max was reported by >= f of its quorum members (newt.rs:527-546
+            # via QuorumClocks max_count; the multi-shard fast path needs
+            # every touched shard fast).  For shard_count == 1 this is
+            # exactly the row-level aggregation of the unsharded round, for
+            # every key width.
+            shard_ids = jnp.arange(shard_count, dtype=jnp.int32)
+            slot_onehot = (
+                propose_slot[:, :, None] & (slot_shard[:, :, None] == shard_ids)
+            )  # [W, KW, S]
+            touched = slot_onehot.any(axis=1)  # [W, S]
+            shard_prop = jnp.where(
+                slot_onehot[None], slot_prop[..., None], int_min
+            ).max(axis=2)  # [r_blk, W, S] — this replica's per-shard row clock
+            rep_shard = (row // per_shard)[:, None] == shard_ids[None]  # [r_blk, S]
+            in_fq_rs = (
+                ((row % per_shard) < fast_quorum)[:, None] & rep_shard
+            )[:, None, :]  # [r_blk, 1, S]
+            shard_fq_max = jax.lax.pmax(
+                jnp.where(in_fq_rs, shard_prop, int_min).max(axis=0), REPLICA_AXIS
+            )  # [W, S]
+            shard_reports = jax.lax.psum(
+                (in_fq_rs & (shard_prop == shard_fq_max[None]))
+                .astype(jnp.int32)
+                .sum(axis=0),
+                REPLICA_AXIS,
+            )  # [W, S]
+            fast = (
+                jnp.where(touched, shard_reports >= f, True).all(axis=-1)
+                & propose
+            )
+            # the commit clock: max over the touched shards' commit clocks
+            # (the MShardCommit max aggregation, partial.rs:37-142);
+            # propose rows always have >= 1 real slot, others read 0
+            fq_max = jnp.where(
+                propose,
+                jnp.where(touched, shard_fq_max, int_min).max(axis=-1),
+                0,
+            )  # [W]
 
         # Synod ballot-0 accept round for fast-path misses: every touched
         # shard must reach write_quorum (f + 1) live acks
@@ -1691,7 +1832,7 @@ def newt_protocol_step(
         # min over the buckets seen; int_max = no keys this round
         watermark = jnp.where(real_slot, slot_stable_clock, int_max).min()
 
-        return (
+        outputs = (
             new_key_clock, new_frontier,
             new_pend_key, new_pend_src, new_pend_seq, new_pend_clock,
             order, executed, committed, fast & valid, clock,
@@ -1699,6 +1840,30 @@ def newt_protocol_step(
             jnp.minimum(pending, pend_cap), pend_dropped,
             src_f, seq_f,
         )
+        if sites == 1:
+            return outputs
+        # what the views cost, NEWT_SITE_ROUND_TALLIES: how far a quorum's
+        # proposals lie apart; the rows committed this round whose (key,
+        # clock) another of them has, neighbours once sorted by both (a row
+        # not of them a key of its own); the executed rows with a row of
+        # their key, earlier in the working set, later in (clock, dot) order
+        from fantoch_tpu.ops.table_ops import segmented_running_max
+
+        spread = jnp.where(newly_committed, clock - lowest, 0).sum()
+        tie_key, tie_clock = jax.lax.sort(
+            (jnp.where(newly_committed, key_cat[:, 0], key_buckets + widx), clock),
+            num_keys=2,
+        )
+        same = (tie_key[1:] == tie_key[:-1]) & (tie_clock[1:] == tie_clock[:-1])
+        no = jnp.zeros((1,), bool)
+        ties = (jnp.concatenate([no, same]) | jnp.concatenate([same, no])).sum()
+        place_at = jnp.where(executed, rank_of, -1)[perm]
+        before_at = segmented_running_max(
+            run_id, place_at
+        )  # the latest place so far in the key's run, this position's among them
+        reordered = ((place_at >= 0) & (place_at < before_at)).sum()
+        tallies = jnp.stack([spread, ties, reordered]).astype(jnp.int32)
+        return outputs + (tallies,)
 
     specs_in = (
         P(REPLICA_AXIS, None),  # key_clock
@@ -1713,28 +1878,31 @@ def newt_protocol_step(
         P(), P(), P(), P(), P(),  # order/executed/committed/fast/clock
         P(), P(), P(), P(),  # slow/watermark/pending/dropped
         P(), P(),  # work identity columns
-    )
+    ) + ((P(),) if sites != 1 else ())  # the site round's tallies
     fn = shard_map(
         step, mesh=mesh, in_specs=specs_in, out_specs=specs_out, check_vma=False
     )
-    (
-        kc, vf, pk, ps_, pq, pc,
-        order, executed, committed, fast, clock,
-        slow, watermark, pending, dropped,
-        work_src, work_seq,
-    ) = fn(
+    out = fn(
         state.key_clock, state.vote_frontier,
         state.pend_key, state.pend_src, state.pend_seq, state.pend_clock,
         key, dot_src, dot_seq,
     )
-    return (
-        NewtMeshState(kc, vf, pk, ps_, pq, pc),
-        NewtStepOutput(
-            order, executed, committed, fast, clock,
-            slow, watermark, pending, dropped,
-            work_src, work_seq,
-        ),
+    n_state = len(NewtMeshState._fields)
+    output = NewtStepOutput if sites == 1 else NewtSiteStepOutput
+    return NewtMeshState(*out[:n_state]), output(*out[n_state:])
+
+
+def _newt_static(mesh, f, tiny_quorums, live_replicas, shard_count, sites, site_base):
+    """The static arguments of a Newt program: those of the round with one
+    coordinator, and ``sites`` / ``site_base`` only where every site has one
+    (the program with one is built without them, as it always was)."""
+    static = dict(
+        mesh=mesh, f=f, tiny_quorums=tiny_quorums,
+        live_replicas=live_replicas, shard_count=shard_count,
     )
+    if sites != 1:
+        static.update(sites=sites, site_base=site_base)
+    return static
 
 
 def jit_newt_step(
@@ -1743,20 +1911,19 @@ def jit_newt_step(
     tiny_quorums: bool = False,
     live_replicas: int | None = None,
     shard_count: int = 1,
+    sites: int = 1,
+    site_base: int = 1,
 ):
-    """jit-compiled Newt round with donated device-resident state."""
+    """jit-compiled Newt round with donated device-resident state
+    (``sites``: the round with a coordinator at every site,
+    :func:`newt_protocol_step`)."""
     import functools
 
+    static = _newt_static(
+        mesh, f, tiny_quorums, live_replicas, shard_count, sites, site_base
+    )
     return jax.jit(
-        functools.partial(
-            newt_protocol_step,
-            mesh=mesh,
-            f=f,
-            tiny_quorums=tiny_quorums,
-            live_replicas=live_replicas,
-            shard_count=shard_count,
-        ),
-        donate_argnums=(0,),
+        functools.partial(newt_protocol_step, **static), donate_argnums=(0,)
     )
 
 
@@ -1771,6 +1938,8 @@ def newt_protocol_multi_step(
     tiny_quorums: bool = False,
     live_replicas: int | None = None,
     shard_count: int = 1,
+    sites: int = 1,
+    site_base: int = 1,
 ) -> Tuple[NewtMeshState, NewtStepOutput]:
     """S chained Newt rounds in ONE dispatch via ``lax.scan`` — the
     votes-table plane's in-dispatch chaining (ops/table_ops.
@@ -1782,15 +1951,16 @@ def newt_protocol_multi_step(
     Outputs are the per-round :class:`NewtStepOutput` arrays stacked on a
     leading ``S`` axis; the caller drains all S rounds afterwards (the
     dispatch/drain pipelining contract of ``work_src``/``work_seq``).
+    ``sites`` / ``site_base``: the round's (a chain of rounds with a
+    coordinator at every site stacks :class:`NewtSiteStepOutput`).
     """
+    static = _newt_static(
+        mesh, f, tiny_quorums, live_replicas, shard_count, sites, site_base
+    )
 
     def body(carry, xs):
         key, src, seq = xs
-        new_state, out = newt_protocol_step(
-            carry, key, src, seq,
-            mesh=mesh, f=f, tiny_quorums=tiny_quorums,
-            live_replicas=live_replicas, shard_count=shard_count,
-        )
+        new_state, out = newt_protocol_step(carry, key, src, seq, **static)
         return new_state, out
 
     return jax.lax.scan(body, state, (keys, dot_srcs, dot_seqs))
@@ -1802,6 +1972,8 @@ def jit_newt_multi_step(
     tiny_quorums: bool = False,
     live_replicas: int | None = None,
     shard_count: int = 1,
+    sites: int = 1,
+    site_base: int = 1,
 ):
     """The multi-round Newt chain with donated state, jitted: S rides the
     inputs' leading axis, so each chain length is a program of its own.
@@ -1810,16 +1982,11 @@ def jit_newt_multi_step(
     (run/device_runner.py ``NewtDeviceDriver.precompile_chains``)."""
     import functools
 
+    static = _newt_static(
+        mesh, f, tiny_quorums, live_replicas, shard_count, sites, site_base
+    )
     return jax.jit(
-        functools.partial(
-            newt_protocol_multi_step,
-            mesh=mesh,
-            f=f,
-            tiny_quorums=tiny_quorums,
-            live_replicas=live_replicas,
-            shard_count=shard_count,
-        ),
-        donate_argnums=(0,),
+        functools.partial(newt_protocol_multi_step, **static), donate_argnums=(0,)
     )
 
 

@@ -49,6 +49,7 @@ import gc
 import os
 import sys
 from collections import defaultdict
+from concurrent.futures import ThreadPoolExecutor
 from functools import partial
 from itertools import repeat, zip_longest
 from operator import attrgetter, itemgetter
@@ -318,6 +319,9 @@ class _DriverCore(PipelineCore):
         # carries: the executable and where it takes its columns on the
         # mesh (``_program``)
         self._programs: Dict[int, Tuple[Any, tuple]] = {}
+        # the sites clients are registered at (``register_site``): a
+        # client that names none is at site 0
+        self._sites = {0}
         # the depth-K dispatch/drain pipeline + staging ingest ring +
         # per-dispatch counters (serve/step/flush_pipeline and _staging
         # come from PipelineCore; drivers implement the halves
@@ -383,21 +387,57 @@ class _DriverCore(PipelineCore):
             key_width=key_width,
         )
 
-    # the sites clients are registered at: a client that names none is
-    # at site 0, and the rounds with one coordinator serve no other
-    sites_registered = 1
+    # whether the round can have a coordinator at every site (the
+    # dep-commit and the Newt drivers say where); the rounds with one
+    # coordinator serve site 0 alone
+    serves_sites = False
+
+    @property
+    def sites_registered(self) -> int:
+        """Sites clients have registered at (gauge)."""
+        return len(self._sites)
 
     def register_site(self, site: int) -> None:
         """A client plane's hello names the site its clients are at
-        (``ClientHi.site``).  Raises ``ValueError`` for a site the driver
-        cannot serve: here every site but 0 (``DeviceDriver`` serves the
-        others where its round can)."""
-        if site != 0:
+        (``ClientHi.site``).  The first site but 0 makes the round with a
+        coordinator at every site ready before this returns
+        (``_make_site_programs``: compiled, or loaded, through the
+        persistent cache under ``precompile`` spans), and every dispatch
+        from the next one on runs it: same state, same columns, so nothing
+        is rebuilt and a round in flight drains as it was dispatched.
+        Raises ``ValueError`` for a site the driver cannot serve: every
+        site but 0 where the round has one coordinator, and a site that is
+        none of the replicas'."""
+        if site in self._sites:
+            return
+        if not self.serves_sites:
             raise ValueError(
                 f"clients at site {site}: this round has one coordinator, "
                 "replica 0 (a coordinator at every site is served under "
-                "epaxos, and under atlas at f = 1)"
+                "epaxos, under atlas at f = 1, and under newt with one key "
+                "a command on one shard)"
             )
+        if not 0 <= site < self.num_replicas:
+            raise ValueError(
+                f"clients at site {site}: the sites are the replicas, "
+                f"0 to {self.num_replicas - 1}"
+            )
+        if len(self._sites) == 1:
+            self._make_site_programs()
+        self._sites.add(site)
+
+    def _state_shapes(self):
+        """The state's shapes and places, not the state: what a program
+        is lowered on while a round may hold the state (beside the step's
+        thread)."""
+        import jax
+
+        return jax.tree_util.tree_map(
+            lambda leaf: jax.ShapeDtypeStruct(
+                leaf.shape, leaf.dtype, sharding=leaf.sharding
+            ),
+            self._state,
+        )
 
     def _column_specs(self):
         """What ``_assemble`` stages for one round, and so what the
@@ -451,6 +491,13 @@ class _DriverCore(PipelineCore):
         persistent compile cache (the jit's own cache is not touched),
         under one ``precompile`` span.  ``state``: what stands for the
         state where a round may hold the real one (a serving driver)."""
+        with self.stages.span("precompile", S):
+            program = self._lowered(jitted, S, state).compile()
+        return program, tuple(program.input_shardings[0][1:])
+
+    def _lowered(self, jitted, S: int = 1, state=None):
+        """``jitted`` traced and lowered on the state's and the columns'
+        shapes, not compiled yet."""
         import jax
 
         lead = () if S == 1 else (S,)
@@ -458,11 +505,7 @@ class _DriverCore(PipelineCore):
             jax.ShapeDtypeStruct(lead + shape, dtype)
             for _name, shape, dtype, _fill in self._column_specs()
         )
-        with self.stages.span("precompile", S):
-            program = jitted.lower(
-                self._state if state is None else state, *columns
-            ).compile()
-        return program, tuple(program.input_shardings[0][1:])
+        return jitted.lower(self._state if state is None else state, *columns)
 
     def precompile_chains(self, lengths: Sequence[int]) -> List[int]:
         """Make the program behind every chain length in ``lengths``
@@ -872,7 +915,6 @@ class DeviceDriver(_DriverCore):
         # largest component of the last such round (gauge)
         self.num_replicas = num_replicas
         self.site_base = site_base
-        self._sites = {0}
         self._live_replicas = live_replicas
         self._site_program: Optional[Tuple[Any, tuple]] = None
         self.round_gauges = dict.fromkeys(mesh_step.SITE_ROUND_GAUGES, 0)
@@ -883,11 +925,6 @@ class DeviceDriver(_DriverCore):
     # --- a coordinator at every site ---
 
     @property
-    def sites_registered(self) -> int:
-        """Sites clients have registered at (gauge)."""
-        return len(self._sites)
-
-    @property
     def serves_sites(self) -> bool:
         """Whether the round can have a coordinator at every site: under
         EPaxos's rule, and under Atlas's at ``f`` = 1 (its threshold over
@@ -895,48 +932,21 @@ class DeviceDriver(_DriverCore):
         and shard count."""
         return self.rule == "epaxos" or self.f == 1
 
-    def register_site(self, site: int) -> None:
-        """Clients at ``site``.  The first site but 0 makes the round's
-        second program ready (compiled, or loaded, through the persistent
-        cache under a ``precompile`` span) before this returns, and every
-        dispatch from the next one on runs it: same state, same columns, so
-        nothing is rebuilt and a round in flight drains as it was
-        dispatched.  Raises ``ValueError`` for a site that is none of the
-        replicas', or not 0 where the round cannot serve it."""
-        if site in self._sites:
-            return
-        if not 0 <= site < self.num_replicas:
-            raise ValueError(
-                f"clients at site {site}: the sites are the replicas, "
-                f"0 to {self.num_replicas - 1}"
-            )
-        if not self.serves_sites:
-            super().register_site(site)
-        if self._site_program is None:
-            import jax
+    def _make_site_programs(self) -> None:
+        """The round's second program (``protocol_step(sites=n)``)."""
+        from fantoch_tpu.parallel import mesh_step
 
-            from fantoch_tpu.parallel import mesh_step
-
-            # the state's shapes and places, not the state: a round may
-            # hold it (this runs beside the step's thread)
-            state = jax.tree_util.tree_map(
-                lambda leaf: jax.ShapeDtypeStruct(
-                    leaf.shape, leaf.dtype, sharding=leaf.sharding
-                ),
-                self._state,
-            )
-            self._site_program = self._precompile(
-                mesh_step.jit_protocol_step(
-                    self._mesh, live_replicas=self._live_replicas,
-                    shard_count=self.shard_count, f=self.f, rule=self.rule,
-                    sites=self.num_replicas, site_base=self.site_base,
-                ),
-                state=state,
-            )
-            self.resolver = mesh_step.resolver_name(
-                self.key_width, sites=self.num_replicas
-            )
-        self._sites.add(site)
+        self._site_program = self._precompile(
+            mesh_step.jit_protocol_step(
+                self._mesh, live_replicas=self._live_replicas,
+                shard_count=self.shard_count, f=self.f, rule=self.rule,
+                sites=self.num_replicas, site_base=self.site_base,
+            ),
+            state=self._state_shapes(),
+        )
+        self.resolver = mesh_step.resolver_name(
+            self.key_width, sites=self.num_replicas
+        )
 
     def _program(self, S: int = 1):
         return self._site_program or super()._program(S)
@@ -1203,6 +1213,7 @@ class NewtDeviceDriver(_DriverCore):
         shard_count: int = 1,
         monitor_execution_order: bool = False,
         mesh=None,
+        site_base: ProcessId = 1,
     ):
         from fantoch_tpu.parallel import mesh_step
 
@@ -1212,6 +1223,18 @@ class NewtDeviceDriver(_DriverCore):
             mesh_step, num_replicas, shard_count, key_buckets,
             pending_capacity, key_width, mesh, mesh_step.init_newt_state,
         )
+        # a coordinator at every site: the sites clients registered at
+        # (``register_site``; a command's coordinator is its dot's source,
+        # ``site_base + site``), the chain lengths a server's tuner may
+        # dispatch (``precompile_chains``), the programs with one
+        # coordinator once a second site put theirs in their place, and
+        # what the round with a coordinator at every site tallies
+        # (mesh_step.NEWT_SITE_ROUND_TALLIES; 0 while one coordinator serves)
+        self.num_replicas = num_replicas
+        self.site_base = site_base
+        self._chain_lengths: List[int] = [1]
+        self._one_site_programs: Dict[int, Tuple[Any, tuple]] = {}
+        self.round_tallies = dict.fromkeys(mesh_step.NEWT_SITE_ROUND_TALLIES, 0)
         self._step = mesh_step.jit_newt_step(
             self._mesh, f=f, tiny_quorums=tiny_quorums,
             live_replicas=live_replicas, shard_count=shard_count,
@@ -1298,6 +1321,62 @@ class NewtDeviceDriver(_DriverCore):
             return self._step
         return mesh_step.jit_newt_multi_step(self._mesh, **self._step_kwargs)
 
+    # --- a coordinator at every site ---
+
+    @property
+    def serves_sites(self) -> bool:
+        """Whether the round can have a coordinator at every site: with one
+        key a command on one shard (``newt_protocol_step(sites=n)``; per-shard
+        rings and a row's several runs are not written)."""
+        return self.shard_count == 1 and self.key_width == 1
+
+    def precompile_chains(self, lengths: Sequence[int]) -> List[int]:
+        """``_DriverCore.precompile_chains``, and the lengths now ready are
+        kept: what a second site's hello makes ready again."""
+        self._chain_lengths = super().precompile_chains(lengths) or [1]
+        return self._chain_lengths
+
+    def _make_site_programs(self) -> None:
+        """The round with a coordinator at every site, once for every
+        chain length a dispatch may run (those ``precompile_chains`` kept;
+        the round alone on a driver stepped by hand): each lowered here and
+        compiled, or loaded, beside the others (the compiler leaves the
+        interpreter lock, tracing does not: 14.8 s for four on an empty
+        cache on a v5e where one after another they took 24.5 s, against a
+        client's 30 s wait for its ack; PR 53's chip run), a ``precompile``
+        span for its lowering and one for the wait.  A length asked for
+        later is built as these were (``_jit_rounds``)."""
+        from fantoch_tpu.parallel import mesh_step
+
+        self._step_kwargs = dict(
+            self._step_kwargs, sites=self.num_replicas, site_base=self.site_base
+        )
+        self._step = mesh_step.jit_newt_step(self._mesh, **self._step_kwargs)
+        state = self._state_shapes()
+        ready = {}
+        with ThreadPoolExecutor(len(self._chain_lengths)) as pool:
+            compiling = []
+            for S in self._chain_lengths:
+                with self.stages.span("precompile", S):
+                    lowered = self._lowered(self._jit_rounds(S), S, state)
+                compiling.append((S, pool.submit(lowered.compile)))
+            for S, compiled in compiling:
+                with self.stages.span("precompile", S):
+                    program = compiled.result()
+                ready[S] = program, tuple(program.input_shardings[0][1:])
+        self._one_site_programs, self._programs = self._programs, ready
+
+    @property
+    def precompiled_programs(self) -> int:
+        return len(self._programs) + len(self._one_site_programs)
+
+    def _assemble_round(self, batch, key_rows, src_row, seq_row) -> None:
+        """A round's columns, the round of a chain too; with a coordinator
+        at every site the batch's sites take turns (``_sites_in_turn``)."""
+        if len(self._sites) > 1:
+            batch = _sites_in_turn(batch)
+        super()._assemble_round(batch, key_rows, src_row, seq_row)
+
     def _chain_windows_blocked(
         self, batches: Sequence[List[Tuple[Dot, Command]]]
     ) -> bool:
@@ -1376,17 +1455,13 @@ class NewtDeviceDriver(_DriverCore):
         """Execute one fetched token's stable commands in (clock, dot)
         order: a single round, or a whole chain's rounds (ONE
         device->host round trip either way)."""
-        from fantoch_tpu.parallel.mesh_step import NewtStepOutput
-
         S = tok[1]
         if S == 1:
             return self._drain_round(outs)
         results: List[ExecutorResult] = []
         for r in range(S):
             results.extend(
-                self._drain_round(
-                    NewtStepOutput(*(np.asarray(a)[r] for a in outs))
-                )
+                self._drain_round(type(outs)(*(np.asarray(a)[r] for a in outs)))
             )
         return results
 
@@ -1428,6 +1503,13 @@ class NewtDeviceDriver(_DriverCore):
         # may only *stabilize* (execute) rounds later, when the flag is no
         # longer set — counting at execution would undercount
         self.fast_paths += int(np.asarray(out.fast_path).sum())
+        # what the round with a coordinator at every site adds to the
+        # round's output (mesh_step.NewtSiteStepOutput)
+        counts = getattr(out, "tallies", None)
+        if counts is not None:
+            tallies = self.round_tallies
+            for name, count in zip(tallies, counts.tolist()):
+                tallies[name] += count
 
         return self._drain_and_carry(out, "newt", "unstable")
 
@@ -2169,6 +2251,7 @@ class DeviceRuntime:
                 shard_count=config.shard_count,
                 monitor_execution_order=monitor_execution_order,
                 mesh=mesh,
+                site_base=process_id,
             )
         elif protocol == "caesar":
             self.driver = CaesarDeviceDriver(

@@ -137,7 +137,9 @@ def test_a_second_sites_hello_makes_the_second_program_ready_before_its_ack():
     ("epaxos", {}, -1, "the sites are the replicas"),
     ("epaxos", {}, "1", "a site is a replica's number"),
     ("epaxos", {}, True, "a site is a replica's number"),
-    ("newt", {}, 1, "one coordinator"),
+    ("newt", {"shard_count": 2}, 1, "one coordinator"),
+    ("newt", {"key_width": 2}, 1, "one coordinator"),
+    ("newt", {"f": 2}, 5, "the sites are the replicas"),
     ("caesar", {"n": 7}, 1, "one coordinator"),
     ("fpaxos", {}, 1, "one coordinator"),
     ("atlas", {"f": 2}, 1, "one coordinator"),
@@ -190,7 +192,8 @@ def test_dots_are_a_coordinators_and_two_sites_never_collide_in_a_registry():
 
 
 @pytest.mark.parametrize("build", [
-    lambda: NewtDeviceDriver(5, batch_size=8, key_buckets=64, pending_capacity=8),
+    lambda: NewtDeviceDriver(5, batch_size=8, key_buckets=64, pending_capacity=8, shard_count=2),
+    lambda: NewtDeviceDriver(5, batch_size=8, key_buckets=64, pending_capacity=8, key_width=2),
     lambda: CaesarDeviceDriver(7, batch_size=8, key_buckets=64, pending_capacity=8),
     lambda: PaxosDeviceDriver(5, batch_size=8, pending_capacity=8),
     lambda: DeviceDriver(5, rule="atlas", f=2, batch_size=8, key_buckets=64, pending_capacity=8),
@@ -315,3 +318,145 @@ def test_two_sites_two_shard_commands_take_their_coordinators_dots_and_one_compo
     assert tallies["finisher_rows"] == 0 and tallies["resolve_iters"] >= 1
     assert (driver.slow_paths == 0) == (protocol == "atlas")
     assert driver.executed == 6 and driver.in_flight == 0
+
+
+# --- Tempo: the Newt round with a coordinator at every site -----------------
+
+
+def test_a_newt_driver_no_site_but_0_registered_at_dispatches_the_parents_programs():
+    """`sites == 1` traces today's program: the round and the chained program
+    of a Newt driver that no site but 0 registered at are built without the
+    two arguments, its tallies read 0, and the programs of a second site take
+    the state and the columns of the first."""
+    driver = NewtDeviceDriver(5, f=2, batch_size=8, key_buckets=64, pending_capacity=8)
+    assert driver.serves_sites and driver.sites_registered == 1
+    for length in (1, 2):
+        assert set(driver._jit_rounds(length).__wrapped__.keywords) == {
+            "mesh", "f", "tiny_quorums", "live_replicas", "shard_count"}
+    driver.register_site(0)
+    batch = [(Dot(1, 1 + at), _put(1 + at, 1, "k")) for at in range(6)]
+    assert len(driver.serve([batch[:3], batch[3:]])) == 6
+    assert driver.precompiled_programs == len(driver._programs) == 1  # the chain of two
+    assert not driver._one_site_programs and driver.slow_paths == 0
+    assert driver.round_tallies == dict.fromkeys(mesh_step.NEWT_SITE_ROUND_TALLIES, 0)
+    one_site = dict(driver._programs)
+    driver.register_site(2)
+    assert driver._one_site_programs == one_site and set(driver._programs) == {1}
+    assert driver.precompiled_programs == 2 and driver.sites_registered == 2
+    for length in (1, 2):
+        keywords = driver._jit_rounds(length).__wrapped__.keywords
+        assert keywords["sites"] == 5 and keywords["site_base"] == 1
+    program, shardings = driver._program(1)
+    assert len(shardings) == len(driver._column_specs())
+
+
+def test_a_second_sites_hello_on_a_newt_server_makes_every_chain_length_ready_before_its_ack():
+    """The tuner's ladder is compiled at start-up with one coordinator; the
+    second site's hello compiles it again with five (each length lowered under
+    a `precompile` span, compiled beside the others and waited for under a
+    second one), before its ack; no dispatch after it compiles, whatever its length;
+    two sites' commands on one key execute, some on the slow path at f = 2."""
+    obs.subscribe_recompiles()
+
+    async def go():
+        runtime, port = _runtime("newt", f=2)
+        await runtime.start()
+        try:
+            driver = runtime.driver
+            ladder = runtime._chain_tuner.ladder()
+            assert len(ladder) > 1 and driver._chain_lengths == ladder
+            assert driver.precompiled_programs == driver.stages.n["precompile"] == len(ladder)
+            one_site = dict(driver._programs)
+            rw0, writer0, ack = await _hello(port, ClientHi([1]))
+            await _call(rw0, _put(1, 1, "k"))
+            assert driver.sites_registered == 1 and not driver._one_site_programs
+            rw2, writer2, ack = await _hello(port, ClientHi([2], site=2))
+            # the ack came after the programs: nothing was dispatched in between
+            assert isinstance(ack, ClientHiAck)
+            assert driver.stages.n["precompile"] == 3 * len(ladder)
+            assert driver.precompiled_programs == 2 * len(ladder) and driver.sites_registered == 2
+            assert set(driver._programs) == set(ladder) == set(one_site)
+            assert all(driver._programs[S] is not one_site[S] for S in ladder)
+            compiled = obs.recompile_count() + obs.cache_hit_count()
+            rounds = driver.rounds
+            for seq in range(2, 8):
+                await asyncio.gather(_call(rw0, _put(1, seq, "k")), _call(rw2, _put(2, seq, "k")))
+            # a chain of every length through the served driver, on the loop's
+            # thread while no client sends: the programs are there
+            dots = runtime.register_site(2)
+            for S in ladder:
+                chain = [[(dots(), _put(40 + S, 1 + at, "k"))] for at in range(S)]
+                await asyncio.get_running_loop().run_in_executor(None, driver.serve, chain)
+            assert driver.rounds > rounds
+            assert obs.recompile_count() + obs.cache_hit_count() == compiled  # nothing since
+            assert driver.stages.n["precompile"] == 3 * len(ladder)
+            assert runtime._tallies["sites_registered"] == 2
+            assert driver.in_flight == 0
+            # a third site: the programs are there
+            rw4, writer4, ack = await _hello(port, ClientHi([3], site=4))
+            assert isinstance(ack, ClientHiAck) and driver.stages.n["precompile"] == 3 * len(ladder)
+            await _call(rw4, _put(3, 1, "k"))
+            assert driver.sites_registered == 3
+            runtime._publish_tallies()
+            assert {"site_clock_spread", "clock_ties", "arrival_reordered"} <= set(runtime._tallies)
+            assert runtime._tallies["precompiled_programs"] == 2 * len(ladder)
+            for writer in (writer0, writer2, writer4):
+                writer.close()
+        finally:
+            await runtime.stop()
+        return runtime
+
+    runtime = asyncio.run(go())
+    assert runtime.driver.store.execute("k", KVOp.get(), Rifl(9, 9)) is not None
+
+
+def test_a_newt_chain_takes_each_rounds_batch_by_sites_in_turn():
+    """Three rounds in one dispatch, each given as one site's stretch after
+    another: every round of the chain is assembled with its sites' commands in
+    turn, a site's own in order, and a driver with one site leaves a batch as
+    it came."""
+    driver = NewtDeviceDriver(5, f=2, batch_size=8, key_buckets=64, pending_capacity=8)
+    sources = [1, 1, 1, 4, 4, 2]
+
+    def chain(first):
+        return [[(Dot(src, first + 10 * r + at), _put(src, first + 10 * r + at, f"k{at}"))
+                 for at, src in enumerate(sources)] for r in range(3)]
+
+    _keys, srcs, _seqs = driver._assemble_chain(chain(1))
+    assert srcs[:, :6].tolist() == [sources] * 3
+    driver._cmds.clear()
+    driver.register_site(3)
+    _keys, srcs, seqs = driver._assemble_chain(chain(100))
+    assert srcs[:, :6].tolist() == [[1, 4, 2, 1, 4, 1]] * 3
+    assert seqs[0, :6].tolist() == [100, 103, 105, 101, 104, 102]
+    driver._cmds.clear()
+    key, src, seq = driver._assemble(chain(200)[0])
+    assert src[:6].tolist() == [1, 4, 2, 1, 4, 1]
+    driver._cmds.clear()
+    # ... and the chain serves: one dispatch, three rounds, every command once
+    results = driver.serve(chain(300))
+    assert driver.rounds == 3 and len(results) == 18 and driver.in_flight == 0
+    assert len({(r.rifl.source, r.rifl.sequence) for r in results}) == 18
+
+
+def test_dots_of_two_sites_never_collide_in_the_newt_registry():
+    runtime, _port = _runtime("newt", f=2)
+    driver = runtime.driver
+    gens = [runtime.register_site(site) for site in (0, 3, 0, 3)]
+    dots = [gen() for gen in gens for _ in range(3)]
+    assert dots[:3] + dots[6:9] == [Dot(1, seq) for seq in range(1, 7)]
+    assert dots[3:6] + dots[9:] == [Dot(4, seq) for seq in range(1, 7)]
+    assert driver.site_base == 1 and driver.sites_registered == 2
+    batch = [(dot, _put(1 + at, 1, "k")) for at, dot in enumerate(dots)]
+    # the registry's keys while the round is assembled: one a dot
+    _key, src, seq = driver._assemble(batch)
+    assert len(driver._cmds) == 12
+    assert set(driver._cmds) == {
+        _DriverCore._packed(dot.source, dot.sequence) for dot in dots}
+    driver._cmds.clear()
+    # same sequences at two sites on one key: both coordinators' commands execute,
+    # in one (clock, dot) order, some of them on the slow path
+    results = driver.serve([batch])
+    assert len(results) == 12 and driver.executed == 12 and driver.in_flight == 0
+    assert driver.fast_paths + driver.slow_paths == 12 and driver.slow_paths > 0
+    assert driver.round_tallies["site_clock_spread"] > 0
