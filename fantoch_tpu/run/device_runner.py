@@ -54,7 +54,7 @@ from functools import partial
 from itertools import repeat, zip_longest
 from operator import attrgetter, itemgetter
 from time import monotonic, monotonic_ns, thread_time_ns
-from typing import Any, Dict, List, Optional, Sequence, Tuple
+from typing import Any, Dict, List, NamedTuple, Optional, Sequence, Tuple
 from zlib import crc32
 
 import numpy as np
@@ -81,7 +81,11 @@ from fantoch_tpu.run.ingest import (
 )
 from fantoch_tpu.run.pipeline import (
     BoundedSubmitRing,
+    PackedOutput,
     PipelineCore,
+    StagedColumns,
+    packed_round,
+    packed_shape,
     resolve_pipeline_depth,
 )
 from fantoch_tpu.run.prelude import (
@@ -222,6 +226,14 @@ def _key_column(
     assert (column[:, 0] != KEY_PAD).all(), "a command touches no key bucket"
 
 
+class _RoundOutput(NamedTuple):
+    """What a dispatch leaves on the device for its drain."""
+
+    packed: Any  # int32[(S,) L]: what the drain reads, the one leaf fetched
+    rest: Any  # the output tuple's un-fetched device leaves, None elsewhere
+    layout: PackedOutput  # how ``packed`` reads back, the program's own
+
+
 def _sites_in_turn(batch):
     """A round's batch with its sites' commands taken in turn: the first
     of each site (in the order the sites first appear), then the second
@@ -316,9 +328,9 @@ class _DriverCore(PipelineCore):
         # ... and what it says of its last round alone (gauges), by name
         self.round_gauges: Dict[str, int] = {}
         # the programs made ready, by the rounds a dispatch of theirs
-        # carries: the executable and where it takes its columns on the
-        # mesh (``_program``)
-        self._programs: Dict[int, Tuple[Any, tuple]] = {}
+        # carries: the executable, where it takes its packed columns on
+        # the mesh and how its packed output reads back (``_program``)
+        self._programs: Dict[int, Tuple[Any, Any, PackedOutput]] = {}
         # the sites clients are registered at (``register_site``): a
         # client that names none is at site 0
         self._sites = {0}
@@ -441,9 +453,13 @@ class _DriverCore(PipelineCore):
 
     def _column_specs(self):
         """What ``_assemble`` stages for one round, and so what the
-        round's program takes after the state: (name, shape, dtype, fill)
-        a column.  Here the key/src/seq columns of the rounds that order
-        by key (dep-commit, Newt, Caesar); the leader round has its own."""
+        round function takes after the state: (name, shape, dtype, fill)
+        a column.  The one place that says so: the staging ring lays a
+        slot's one buffer out by it and the round's program unpacks that
+        buffer by it (``pipeline.packed_columns``; the dtype is the
+        round's, the buffer is ``int32`` and a ``bool`` column 0/1 in
+        it).  Here the key/src/seq columns of the rounds that order by
+        key (dep-commit, Newt, Caesar); the leader round has its own."""
         from fantoch_tpu.parallel.mesh_step import KEY_PAD
 
         b = self.batch_size
@@ -458,9 +474,9 @@ class _DriverCore(PipelineCore):
         fixed-size key/src/seq columns and register commands under
         packed (source, window sequence)."""
         assert len(batch) <= self.batch_size
-        key, src, seq = self._staging(*self._column_specs())
-        self._assemble_round(batch, key, src, seq)
-        return key, src, seq
+        staged = self._staging(*self._column_specs())
+        self._assemble_round(batch, *staged)
+        return staged
 
     # whether a chain of S rounds is one dispatch of a program of its own
     # (Newt's ``lax.scan`` of S rounds) or S dispatches of the round's
@@ -473,8 +489,9 @@ class _DriverCore(PipelineCore):
         return self._step
 
     def _program(self, S: int = 1):
-        """The program of ``S`` rounds a dispatch and where it takes its
-        columns: compiled, or loaded, the first time that length is asked
+        """The program of ``S`` rounds a dispatch, where it takes its
+        packed columns and how its packed output reads back: compiled,
+        or loaded, the first time that length is asked
         for (``_precompile``) and kept.  A server asks for every length
         it may dispatch before its first client (``precompile_chains``),
         so its dispatches compile nothing; a driver stepped without that
@@ -485,27 +502,56 @@ class _DriverCore(PipelineCore):
         return ready
 
     def _precompile(self, jitted, S: int = 1, state=None):
-        """``jitted`` lowered on the real state's and the driver's
-        columns' shapes (``_column_specs``, under a leading ``S`` for a
-        program of several rounds) and compiled, or loaded, through the
-        persistent compile cache (the jit's own cache is not touched),
-        under one ``precompile`` span.  ``state``: what stands for the
-        state where a round may hold the real one (a serving driver)."""
+        """The packed program of the jitted round function ``jitted``
+        (``_lowered``), compiled, or loaded, through the persistent
+        compile cache (the jit's own cache is not touched), under one
+        ``precompile`` span.  ``state``: what stands for the state where
+        a round may hold the real one (a serving driver)."""
         with self.stages.span("precompile", S):
-            program = self._lowered(jitted, S, state).compile()
-        return program, tuple(program.input_shardings[0][1:])
+            lowered, layout = self._lowered(jitted, S, state)
+            return self._compiled(lowered, layout)
+
+    @staticmethod
+    def _compiled(lowered, layout):
+        """A ``_programs`` entry of a lowered packed program."""
+        program = lowered.compile()
+        return program, program.input_shardings[0][1], layout
+
+    # the fields of the round's output tuple no drain reads: they stay
+    # device leaves of the token (``_RoundOutput.rest``)
+    _unfetched_outputs: Tuple[str, ...] = ()
 
     def _lowered(self, jitted, S: int = 1, state=None):
-        """``jitted`` traced and lowered on the state's and the columns'
-        shapes, not compiled yet."""
+        """The round function under ``jitted`` (a ``mesh_step.jit_*``
+        form: ``(state, *columns) -> (state, out)``) as the program a
+        dispatch runs, ``(state, packed) -> (state, packed_out, rest)``
+        (``pipeline.packed_round``, in one ``jax.jit`` that donates the
+        state), traced and lowered on the state's shapes and the packed
+        columns' (``_column_specs`` as one ``int32`` array, under a
+        leading ``S`` for a program of several rounds, split along the
+        batch axis as the columns are), not compiled yet; and how its
+        packed output reads back."""
         import jax
+        from jax.sharding import NamedSharding, PartitionSpec
 
+        from fantoch_tpu.parallel.mesh_step import BATCH_AXIS
+
+        specs = self._column_specs()
         lead = () if S == 1 else (S,)
-        columns = tuple(
-            jax.ShapeDtypeStruct(lead + shape, dtype)
-            for _name, shape, dtype, _fill in self._column_specs()
+        program, layout = packed_round(
+            jitted.__wrapped__, specs, self._unfetched_outputs,
+            out_sharding=NamedSharding(self._mesh, PartitionSpec()),
         )
-        return jitted.lower(self._state if state is None else state, *columns)
+        packed = jax.ShapeDtypeStruct(
+            lead + packed_shape(specs), np.int32,
+            sharding=NamedSharding(
+                self._mesh, PartitionSpec(*(None,) * (len(lead) + 1), BATCH_AXIS)
+            ),
+        )
+        lowered = jax.jit(program, donate_argnums=(0,)).lower(
+            self._state if state is None else state, packed
+        )
+        return lowered, layout
 
     def precompile_chains(self, lengths: Sequence[int]) -> List[int]:
         """Make the program behind every chain length in ``lengths``
@@ -538,22 +584,30 @@ class _DriverCore(PipelineCore):
         """Programs made ready (gauge)."""
         return len(self._programs)
 
-    def _columns_to_device(self, columns, shardings):
-        """The assembled columns of a dispatch, handed to jax: straight
-        to where its program takes them."""
+    def _columns_to_device(self, staged: StagedColumns, sharding):
+        """The assembled columns of a dispatch, handed to jax as the one
+        buffer they are views of: one array, straight to where its
+        program takes it."""
         import jax
 
-        return jax.device_put(tuple(columns), shardings)
+        self.transfers += 1
+        return jax.device_put(staged.packed, sharding)
 
-    def _enqueue(self, columns, S: int = 1):
+    def _enqueue(self, staged: StagedColumns, S: int = 1) -> _RoundOutput:
         """Submit one dispatch of ``S`` rounds over the assembled
         columns; returns its outputs, un-fetched."""
-        program, shardings = self._program(S)
-        self._state, out = program(
-            self._state, *self._columns_to_device(columns, shardings)
+        program, sharding, layout = self._program(S)
+        self._state, packed_out, rest = program(
+            self._state, self._columns_to_device(staged, sharding)
         )
         self.rounds += S
-        return out
+        return _RoundOutput(packed_out, rest, layout)
+
+    def _fetch(self, out: _RoundOutput):
+        """One leaf comes down, the packed array, and the round's own
+        output tuple is rebuilt from it as numpy views (None where a
+        field stayed on the device)."""
+        return out.layout.unpack(super()._fetch(out.packed))
 
     def _assemble_round(self, batch, key_rows, src_row, seq_row) -> None:
         """Fill one round's fixed-size key/src/seq columns in place and
@@ -916,7 +970,7 @@ class DeviceDriver(_DriverCore):
         self.num_replicas = num_replicas
         self.site_base = site_base
         self._live_replicas = live_replicas
-        self._site_program: Optional[Tuple[Any, tuple]] = None
+        self._site_program: Optional[Tuple[Any, Any, PackedOutput]] = None
         self.round_gauges = dict.fromkeys(mesh_step.SITE_ROUND_GAUGES, 0)
         self._next_gid = 0  # host mirror of state.next_gid
         self._frontier_base = 0  # executed-count carried across gid epochs
@@ -959,7 +1013,7 @@ class DeviceDriver(_DriverCore):
 
     def _column_specs(self):
         """The dep-commit round's columns: the key/src/seq columns and
-        which commands only read."""
+        which commands only read (staged as 0/1)."""
         return super()._column_specs() + (("read", (self.batch_size,), np.bool_, False),)
 
     # gid space is int32 and the key clock holds raw gids; when the space
@@ -1061,7 +1115,8 @@ class DeviceDriver(_DriverCore):
             f"{self.batch_size}; chunk at the caller"
         )
         b = self.batch_size
-        key, src, seq, read = self._staging(*self._column_specs())
+        staged = self._staging(*self._column_specs())
+        key, src, seq, read = staged
         if self._next_gid + b >= self.GID_RESET_THRESHOLD:
             assert self._undrained == 0, (
                 "gid epoch reset with a pipelined round in flight; "
@@ -1079,7 +1134,7 @@ class DeviceDriver(_DriverCore):
         self._identity_columns(
             batch, src, seq, read_row=read, first_gid=self._next_gid
         )
-        return (key, src, seq, read), len(batch)
+        return staged, len(batch)
 
     def _enqueue(self, staged):
         columns, n_batch = staged
@@ -1087,10 +1142,12 @@ class DeviceDriver(_DriverCore):
         self._next_gid += self.batch_size
         return out, n_batch
 
+    # no drain reads the carry's count, and the committed dependencies
+    # only ``_finish_order``, in the rounds that have ``finish`` rows
+    _unfetched_outputs = ("deps_gid", "pending")
+
     def _token_outputs(self, tok):
-        # what the drain reads: the committed dependencies and the carry's
-        # count stay on the device (a transfer a leaf)
-        return tok[0]._replace(deps_gid=None, pending=None)
+        return tok[0]
 
     def _execute(self, tok, out) -> List[ExecutorResult]:
         """Execute one fetched round's resolved commands in device
@@ -1128,7 +1185,7 @@ class DeviceDriver(_DriverCore):
         live = order[ours[order]]
         if finish is not None and finish.any():
             live = np.concatenate(
-                [live, self._finish_order(tok[0], gids, finish, counts[-1])]
+                [live, self._finish_order(tok[0].rest, gids, finish, counts[-1])]
             )
         results = self._execute_rows(gids[live].tolist(), fast[live])
 
@@ -1156,7 +1213,8 @@ class DeviceDriver(_DriverCore):
         """The working rows ``finish`` marks, in the order the host's
         Tarjan gives them (``executor/graph/deps_graph.tarjan_order``:
         components in dependency order, each in dot order), under a
-        ``finish`` span; their committed dependencies are fetched here,
+        ``finish`` span; their committed dependencies (``out``: the
+        round's un-fetched leaves) are fetched here, one transfer more,
         in these rounds alone.  What it finds of components joins the
         round's tallies (the device counted the rows it handed over),
         ``largest`` being the device's own largest of this round."""
@@ -1172,6 +1230,7 @@ class DeviceDriver(_DriverCore):
                 site = (dot.source - self.site_base) % self.num_replicas
                 dots.append(Dot(1 + site, dot.sequence))
             # (a dependency outside the rows executed before them)
+            self.transfers += 1
             deps = [
                 sorted({row_of[dep] for dep in row if dep in row_of})
                 for row in np.asarray(out.deps_gid)[rows].tolist()
@@ -1233,7 +1292,7 @@ class NewtDeviceDriver(_DriverCore):
         self.num_replicas = num_replicas
         self.site_base = site_base
         self._chain_lengths: List[int] = [1]
-        self._one_site_programs: Dict[int, Tuple[Any, tuple]] = {}
+        self._one_site_programs: Dict[int, Tuple[Any, Any, PackedOutput]] = {}
         self.round_tallies = dict.fromkeys(mesh_step.NEWT_SITE_ROUND_TALLIES, 0)
         self._step = mesh_step.jit_newt_step(
             self._mesh, f=f, tiny_quorums=tiny_quorums,
@@ -1359,11 +1418,10 @@ class NewtDeviceDriver(_DriverCore):
             for S in self._chain_lengths:
                 with self.stages.span("precompile", S):
                     lowered = self._lowered(self._jit_rounds(S), S, state)
-                compiling.append((S, pool.submit(lowered.compile)))
+                compiling.append((S, pool.submit(self._compiled, *lowered)))
             for S, compiled in compiling:
                 with self.stages.span("precompile", S):
-                    program = compiled.result()
-                ready[S] = program, tuple(program.input_shardings[0][1:])
+                    ready[S] = compiled.result()
         self._one_site_programs, self._programs = self._programs, ready
 
     @property
@@ -1421,25 +1479,20 @@ class NewtDeviceDriver(_DriverCore):
         )
 
     def _assemble_chain(self, batches: Sequence[List[Tuple[Dot, Command]]]):
-        from fantoch_tpu.parallel.mesh_step import KEY_PAD
-
-        S = len(batches)
-        b = self.batch_size
         # chains allocate fresh staging (shape varies with S and chains
         # already amortize the dispatch; the ring serves the per-round
-        # hot path)
-        keys = np.full((S, b, self.key_width), KEY_PAD, dtype=np.int32)
-        srcs = np.zeros((S, b), dtype=np.int32)
-        seqs = np.zeros((S, b), dtype=np.int32)
+        # hot path): one buffer, the columns under a leading S
+        staged = StagedColumns(self._column_specs(), lead=(len(batches),))
+        keys, srcs, seqs = staged
         epochs = self.seq_epochs
         for r, batch in enumerate(batches):
-            assert len(batch) <= b
+            assert len(batch) <= self.batch_size
             self._assemble_round(batch, keys[r], srcs[r], seqs[r])
         assert self.seq_epochs == epochs, (
             "dot-sequence window advance inside a chain "
             "(_chain_windows_blocked must prevent this)"
         )
-        return keys, srcs, seqs
+        return staged
 
     def _enqueue(self, columns, S: int = 1):
         """The token says how many rounds it carries."""
@@ -1454,7 +1507,7 @@ class NewtDeviceDriver(_DriverCore):
     def _execute(self, tok, outs) -> List[ExecutorResult]:
         """Execute one fetched token's stable commands in (clock, dot)
         order: a single round, or a whole chain's rounds (ONE
-        device->host round trip either way)."""
+        device->host transfer either way)."""
         S = tok[1]
         if S == 1:
             return self._drain_round(outs)
@@ -1646,7 +1699,7 @@ class PaxosDeviceDriver(_DriverCore):
 
     def _column_specs(self):
         """The leader round takes no key rows: which rows of the batch
-        hold a command, and the dots."""
+        hold a command (staged as 0/1), and the dots."""
         b = self.batch_size
         return (
             ("valid", (b,), bool, False),
@@ -1721,9 +1774,10 @@ class PaxosDeviceDriver(_DriverCore):
                     "slot log exhausted: the contiguous exec frontier is "
                     "pinned too far behind to rebase"
                 )
-        valid, src, seq = self._staging(*self._column_specs())
+        staged = self._staging(*self._column_specs())
+        valid, src, seq = staged
         self._identity_columns(batch, src, seq, valid_row=valid)
-        return (valid, src, seq), len(batch)
+        return staged, len(batch)
 
     def _enqueue(self, staged):
         columns, n_batch = staged

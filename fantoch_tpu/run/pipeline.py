@@ -21,10 +21,15 @@ refactor seam): drivers implement a ``dispatch(batch) -> token`` /
     and a drain into ``fetch`` / ``execute``
     (observability/device.py ``StageRecorder``), and the device
     busy/idle instrument (``device_idle_frac``);
-  * :class:`IngestRing` — K+1 pre-staged host staging buffer sets for
-    batch assembly, cycled round-robin so the columns a still-in-flight
-    round reads (jax may alias host numpy zero-copy on the CPU backend)
-    are never rewritten under it.
+  * :class:`IngestRing` — K+1 pre-staged host staging slots for batch
+    assembly, each one buffer with a round's columns as views of it,
+    cycled round-robin so the buffer a still-in-flight round reads (jax
+    may alias host numpy zero-copy on the CPU backend) is never
+    rewritten under it;
+  * :func:`packed_round` — a round function wrapped so that a dispatch
+    crosses to the device once and comes back once: the staged columns
+    go up as that one buffer and what a drain reads comes down as one
+    array (:class:`PackedOutput`).
 
 Depth semantics: ``pipeline_depth`` is the maximum number of
 dispatched-but-undrained rounds ``serve(..., overlap=True)`` leaves in
@@ -58,46 +63,171 @@ def resolve_pipeline_depth(config: Any) -> int:
     return DEFAULT_PIPELINE_DEPTH if depth is None else depth
 
 
-class IngestRing:
-    """K+1 pre-staged host staging buffer sets, cycled round-robin.
+def packed_shape(specs) -> Tuple[int, int]:
+    """The shape of the one ``int32`` array a round's staged columns
+    make, ``(rows, B)``: a column of shape ``(B,)`` is a row of it, one of
+    shape ``(B, k)`` is ``k`` rows (its transpose), in spec order."""
+    (batch,) = {shape[0] for _name, shape, _dtype, _fill in specs}
+    rows = sum(shape[1] if len(shape) == 2 else 1 for _name, shape, _dtype, _fill in specs)
+    return rows, batch
 
-    Each slot holds one set of named numpy columns (the per-round
-    key/src/seq staging arrays).  ``acquire()`` resets the next slot's
+
+def packed_columns(packed, specs) -> tuple:
+    """The columns ``specs`` names as views of ``packed``
+    (``int32[..., rows, B]``, :func:`packed_shape`; the leading axes are
+    kept: the rounds of a chain), in spec order.  Static slices, so the
+    host's staging buffer (numpy) and the device's program (a traced
+    array) read one layout; every view is ``int32``, a ``bool`` column
+    as 0/1."""
+    columns, row = [], 0
+    for _name, shape, dtype, _fill in specs:
+        assert np.dtype(dtype) in (np.int32, np.bool_), "a staged column is int32 or bool"
+        if len(shape) == 2:
+            columns.append(packed[..., row:row + shape[1], :].swapaxes(-1, -2))
+            row += shape[1]
+        else:
+            columns.append(packed[..., row, :])
+            row += 1
+    return tuple(columns)
+
+
+class StagedColumns(tuple):
+    """One dispatch's staged columns, in spec order, filled with their
+    fill values: views of ``packed``, the one C-contiguous ``int32``
+    buffer that goes to the device (``lead``: the rounds of a chain, a
+    leading axis of the buffer and of every column)."""
+
+    def __new__(cls, specs, lead: tuple = ()):
+        packed = np.empty(lead + packed_shape(specs), dtype=np.int32)
+        self = super().__new__(cls, packed_columns(packed, specs))
+        self.packed = packed
+        self.fills = tuple(fill for _name, _shape, _dtype, fill in specs)
+        self.reset()
+        return self
+
+    def reset(self) -> None:
+        for column, fill in zip(self, self.fills):
+            column.fill(fill)
+
+
+class PackedOutput:
+    """How a round's output tuple lies in the one ``int32`` array a drain
+    fetches: the fields a drain reads, each flattened, one after the
+    other along the last axis (a mask as 0/1; a scalar is one entry),
+    under the leading axes they share (none for a round, ``S`` for a
+    chain's stacked rounds).  Written once, when the program that packs
+    is traced (:func:`packed_round`); read at every drain."""
+
+    __slots__ = ("type", "fields")
+
+    def __init__(self):
+        self.type = None  # the round's own output tuple
+        # (offset, size, shape, is a mask) a field; None one left on the device
+        self.fields: Optional[List[Optional[Tuple[int, int, tuple, bool]]]] = None
+
+    def pack(self, out, lead: int, kept: Sequence[str]):
+        """``out`` (a NamedTuple of traced arrays with ``lead`` leading
+        axes in common) as ``(packed, rest)``: the one array, and ``out``
+        with the fields ``kept`` names left as they are and None
+        elsewhere."""
+        import jax.numpy as jnp
+
+        self.type, self.fields = type(out), []
+        flat, offset = [], 0
+        for name, leaf in zip(out._fields, out):
+            if name in kept:
+                self.fields.append(None)
+                continue
+            shape = leaf.shape[lead:]
+            size = int(np.prod(shape, dtype=np.int64))
+            self.fields.append((offset, size, shape, leaf.dtype == np.bool_))
+            flat.append(leaf.astype(jnp.int32).reshape(leaf.shape[:lead] + (size,)))
+            offset += size
+        rest = self.type._make(
+            leaf if field is None else None for leaf, field in zip(out, self.fields)
+        )
+        return jnp.concatenate(flat, axis=-1), rest
+
+    def unpack(self, packed: np.ndarray):
+        """The output tuple again, from the fetched array: numpy views
+        of it (a mask a ``bool`` copy), None where the field stayed on
+        the device."""
+        lead = packed.shape[:-1]
+        values = []
+        for field in self.fields:
+            if field is None:
+                values.append(None)
+                continue
+            offset, size, shape, mask = field
+            value = packed[..., offset:offset + size].reshape(lead + shape)
+            values.append(value != 0 if mask else value)
+        return self.type._make(values)
+
+
+def packed_round(round_fn, specs, kept: Sequence[str], out_sharding):
+    """``round_fn(state, *columns) -> (state, out)`` as ``(state, packed)
+    -> (state, packed_out, rest)``, and the :class:`PackedOutput` that
+    reads ``packed_out`` back: the columns ``specs`` names are unpacked
+    from the one array by static slices (:func:`packed_columns`; a
+    ``bool`` column from its 0/1) before the round, and what a drain
+    reads of ``out`` is packed after it (``rest``: the fields ``kept``
+    names, device leaves nobody fetches by default), all inside whatever
+    ``jax.jit`` the caller puts around it.  Leading axes of ``packed``
+    pass through to ``round_fn``'s columns and are taken to lead its
+    outputs (a chain's ``lax.scan``).  ``out_sharding``: where the packed
+    output is held to (replicated, as its fields are)."""
+    layout = PackedOutput()
+
+    def program(state, packed):
+        import jax
+
+        columns = [
+            column != 0 if np.dtype(dtype) == np.bool_ else column
+            for column, (_name, _shape, dtype, _fill) in zip(
+                packed_columns(packed, specs), specs
+            )
+        ]
+        state, out = round_fn(state, *columns)
+        packed_out, rest = layout.pack(out, packed.ndim - 2, kept)
+        return state, jax.lax.with_sharding_constraint(packed_out, out_sharding), rest
+
+    return program, layout
+
+
+class IngestRing:
+    """K+1 pre-staged host staging slots, cycled round-robin.
+
+    Each slot is one C-contiguous ``int32`` buffer (what a dispatch hands
+    the device, one array) and the named columns of a round (the key /
+    src / seq staging arrays; a ``bool`` column staged as 0/1) as views
+    of it (:class:`StagedColumns`).  ``acquire()`` resets the next slot's
     columns to their fill values in place and returns them — no per-round
     allocation, and a slot is only revisited after ``slots`` more
     acquires, which the pipeline guarantees is after its round drained
     (rounds in flight <= depth < slots).
     """
 
-    __slots__ = ("_slots", "_specs", "_next")
+    __slots__ = ("_slots", "_next")
 
     def __init__(
         self, slots: int, specs: Sequence[Tuple[str, tuple, Any, Any]]
     ):
         """``specs``: (name, shape, dtype, fill) per staging column."""
         assert slots >= 1
-        self._specs = list(specs)
-        self._slots = [
-            tuple(
-                np.full(shape, fill, dtype=dtype)
-                for _name, shape, dtype, fill in self._specs
-            )
-            for _ in range(slots)
-        ]
+        self._slots = [StagedColumns(specs) for _ in range(slots)]
         self._next = 0
 
     @property
     def slots(self) -> int:
         return len(self._slots)
 
-    def acquire(self) -> Tuple[np.ndarray, ...]:
+    def acquire(self) -> StagedColumns:
         """The next slot's columns, reset in place to their fill values
-        (in spec order)."""
-        arrays = self._slots[self._next]
+        (in spec order); the buffer they alias is their ``packed``."""
+        staged = self._slots[self._next]
         self._next = (self._next + 1) % len(self._slots)
-        for arr, (_name, _shape, _dtype, fill) in zip(arrays, self._specs):
-            arr.fill(fill)
-        return arrays
+        staged.reset()
+        return staged
 
 
 class BoundedSubmitRing:
@@ -221,6 +351,10 @@ class PipelineCore:
         # DeviceRuntime) — dispatch/drain/fetch wall-ms are reads of it
         self.stages = StageRecorder()
         self.dispatches = 0
+        # arrays that crossed to the device and back for the dispatches:
+        # those handed to ``device_put`` (the drivers count theirs) and
+        # the leaves ``_fetch`` handed to ``device_get``; two a dispatch
+        self.transfers = 0
         self.dispatched_rows = 0
         self.dispatched_capacity = 0
         self.pipelined_rounds = 0  # rounds dispatched over an in-flight one
@@ -268,10 +402,11 @@ class PipelineCore:
         """The blocking device->host wait inside drains."""
         return self.stages.ms("fetch")
 
-    def _staging(self, *specs) -> Tuple[np.ndarray, ...]:
-        """The next pre-staged host staging slot for batch assembly:
-        ``pipeline_depth + 1`` ring slots, so the columns a
-        still-in-flight round may alias zero-copy (the CPU backend) are
+    def _staging(self, *specs) -> StagedColumns:
+        """The next pre-staged host staging slot for batch assembly (its
+        columns, and as their ``packed`` the one buffer they alias):
+        ``pipeline_depth + 1`` ring slots, so the buffer a
+        still-in-flight round may alias zero-copy (the CPU backend) is
         never rewritten before that round drains."""
         slots = self.pipeline_depth + 1
         if self._ring is None or self._ring.slots < slots:
@@ -376,8 +511,8 @@ class PipelineCore:
             return enqueue(staged)
 
     def drain(self, tok):
-        """Fetch one token's outputs (ONE blocking device->host round
-        trip) and execute what they resolved."""
+        """Fetch one token's outputs (ONE blocking device->host
+        transfer) and execute what they resolved."""
         fetched = self._fetch(self._token_outputs(tok))
         with self.stages.span("execute", self._span_round):
             return self._execute(tok, fetched)
@@ -410,14 +545,17 @@ class PipelineCore:
         return 1
 
     def _fetch(self, out):
-        """ONE blocking pytree fetch for a round's outputs: device_get
-        issues async copies for every leaf before blocking, so the round
-        pays a single device->host round trip instead of one per field.
-        Also the busy/idle
-        bookkeeping point: when this fetch retires the last in-flight
-        round, the device goes idle until the next dispatch."""
+        """The ONE blocking fetch of a dispatch's outputs.  ``device_get``
+        issues an async copy for every leaf before blocking, but each
+        leaf is still a transfer and a wait of its own (``transfers``
+        counts them), so a driver hands it one leaf: the array its
+        program packed what a drain reads into
+        (:func:`packed_round`).  Also the busy/idle bookkeeping point:
+        when this fetch retires the last in-flight round, the device goes
+        idle until the next dispatch."""
         import jax
 
+        self.transfers += len(jax.tree_util.tree_leaves(out))
         with self.stages.span("fetch", self._span_round) as span:
             out = jax.device_get(out)
         t1 = span.t1
@@ -465,6 +603,7 @@ class PipelineCore:
         )
         return {
             "device_dispatches": self.dispatches,
+            "device_transfers": self.transfers,
             "device_dispatched_rows": self.dispatched_rows,
             "device_batch_capacity": self.dispatched_capacity,
             "dispatch_fill_frac": round(fill_frac, 4),

@@ -109,16 +109,15 @@ def test_after_the_precompile_no_dispatch_of_any_ladder_length_compiles(shards, 
 def test_a_length_that_cannot_be_made_ready_keeps_the_tuner_below_it(monkeypatch):
     real = mesh_step.jit_newt_multi_step
 
-    class Refuses:
-        def lower(self, state, keys, *rest):
+    def jit_newt_multi_step(mesh, **kwargs):
+        chain = real(mesh, **kwargs).__wrapped__
+
+        def refuses(state, keys, *rest):  # where the driver lowers its packed program
             if keys.shape[0] >= 4:
                 raise RuntimeError("RESOURCE_EXHAUSTED: no room for this program")
-            return self.inner.lower(state, keys, *rest)
+            return chain(state, keys, *rest)
 
-    def jit_newt_multi_step(mesh, **kwargs):
-        refuses = Refuses()
-        refuses.inner = real(mesh, **kwargs)
-        return refuses
+        return jax.jit(refuses, donate_argnums=(0,))
 
     monkeypatch.setattr(mesh_step, "jit_newt_multi_step", jit_newt_multi_step)
     driver = NewtDeviceDriver(3, f=1, batch_size=8, key_buckets=64, pending_capacity=8)

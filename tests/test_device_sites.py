@@ -218,8 +218,9 @@ def test_the_one_site_program_is_the_round_without_a_sites_argument():
     driver.register_site(2)
     one, two = driver._programs, driver._site_program
     assert not one  # the one-site program was never asked for
-    program, shardings = two
-    assert len(shardings) == len(driver._column_specs())
+    program, sharding, layout = two
+    assert sharding.shard_shape((4, 8))[0] == len(driver._column_specs())  # a row a column, one array
+    assert layout.type is mesh_step.SiteStepOutput
     assert len(mesh_step.SITE_ROUND_TALLIES) + len(mesh_step.SITE_ROUND_GAUGES) == 12
 
 
@@ -346,8 +347,9 @@ def test_a_newt_driver_no_site_but_0_registered_at_dispatches_the_parents_progra
     for length in (1, 2):
         keywords = driver._jit_rounds(length).__wrapped__.keywords
         assert keywords["sites"] == 5 and keywords["site_base"] == 1
-    program, shardings = driver._program(1)
-    assert len(shardings) == len(driver._column_specs())
+    program, sharding, layout = driver._program(1)
+    assert sharding.shard_shape((3, 8))[0] == len(driver._column_specs())  # a row a column, one array
+    assert layout.type is mesh_step.NewtSiteStepOutput
 
 
 def test_a_second_sites_hello_on_a_newt_server_makes_every_chain_length_ready_before_its_ack():

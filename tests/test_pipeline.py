@@ -459,7 +459,7 @@ def test_ingest_ring_slot_never_reused_while_in_flight():
             self.live = {}  # round -> staging array it aliases
 
         def dispatch(self, batch):
-            (col,) = self._staging(("col", (4,), np.int64, 0))
+            (col,) = self._staging(("col", (4,), np.int32, 0))
             col[: len(batch)] = batch
             tok = (self._round, col, list(batch))
             self._round += 1

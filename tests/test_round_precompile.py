@@ -156,14 +156,17 @@ def test_a_server_has_its_round_before_it_listens_and_says_how_long_that_took(pr
 @pytest.mark.parametrize("key_width", (1, 2))
 def test_the_dep_commit_round_takes_its_read_column_at_either_key_width(key_width, tmp_path):
     """One compile a key width: the program is lowered on the four staged
-    columns, `read` (`bool[B]`) among them, and a round with reads and
-    writes then compiles nothing."""
+    columns as one array, `read` (`bool[B]` to the round, 0/1 in it) among
+    them, and a round with reads and writes then compiles nothing."""
     obs.subscribe_recompiles()
     driver = DeviceDriver(5, f=1, rule="atlas", batch_size=8, key_buckets=64,
                           key_width=key_width, pending_capacity=8)
     assert driver.precompile_chains(LADDER) == LADDER
     assert driver.precompiled_programs == 1 and driver.stages.n["precompile"] == 1
-    assert len(driver._programs[1][1]) == 4  # where it takes its columns
+    _program, sharding, layout = driver._programs[1]
+    # where it takes its columns: key_width rows of keys, then src, seq and read, along the batch
+    assert sharding.shard_shape((key_width + 3, 8))[0] == key_width + 3
+    assert layout.fields[3] is None and layout.fields[7] is None  # deps_gid, pending: un-fetched
     before = _tallies()
     assert len(driver.step(_batch(0, 8))) == 8
     assert _tallies() == before

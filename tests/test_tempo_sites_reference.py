@@ -270,6 +270,7 @@ def test_rounds_at_the_cells_shape_agree_with_the_reference(rounds=6, small=True
     ``NewtDeviceDriver``'s own programs, the round and a chain of two; under
     pytest a small shape on the CPU, by hand on the chip the cell's."""
     from fantoch_tpu.run.device_runner import NewtDeviceDriver
+    from fantoch_tpu.run.pipeline import StagedColumns
 
     buckets, batch = (256, 64) if small else (1048576, 4096)
     driver = NewtDeviceDriver(5, f=2, batch_size=batch, key_buckets=buckets,
@@ -283,11 +284,16 @@ def test_rounds_at_the_cells_shape_agree_with_the_reference(rounds=6, small=True
     for first in range(0, rounds, 3):  # a round, then a chain of two
         for length in (1, 2):
             at = first + (length == 2)
-            program, shardings = driver._program(length)
+            program, sharding, layout = driver._program(length)
             columns = (key[at][:, None], src[at], seq[at]) if length == 1 else (
                 key[at:at + 2, :, None], src[at:at + 2], seq[at:at + 2])
-            driver._state, out = program(driver._state, *jax.device_put(columns, shardings))
-            out = jax.device_get(out)
+            # the columns as a dispatch stages them: views of the one array that goes up
+            staged = StagedColumns(driver._column_specs(), lead=() if length == 1 else (2,))
+            for view, column in zip(staged, columns):
+                view[...] = column
+            driver._state, packed, _rest = program(
+                driver._state, jax.device_put(staged.packed, sharding))
+            out = layout.unpack(jax.device_get(packed))  # ... and the one that comes down
             outs = [out] if length == 1 else [
                 type(out)(*(column[r] for column in out)) for r in range(2)]
             for r, one in enumerate(outs):
