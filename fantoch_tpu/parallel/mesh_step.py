@@ -156,7 +156,7 @@ class SiteStepOutput(NamedTuple):
     pending: jax.Array  # int32[]
     pend_dropped: jax.Array  # int32[]
     # SITE_ROUND_TALLIES, then SITE_ROUND_GAUGES
-    tallies: jax.Array  # int32[12]
+    tallies: jax.Array  # int32[15]
     # executed this round at the place the host's Tarjan finds: one key a
     # command, the executable rows of a key's run the device's resolver
     # did not cut (ops/graph_resolve.resolve_key_runs); several, the rows
@@ -181,10 +181,17 @@ ROUND_TALLIES = (
 # found), the executed rows in a component of several whose rows hold more
 # than one key bucket and in one whose rows' keys lie on more than one shard
 # (the device's components only; 0 with one key a command, where a
-# component lies in one key's run) ...
+# component lies in one key's run); then what Atlas's threshold at ``f`` >= 2
+# made of the executed rows (0 under EPaxos's rule, where the second is
+# ``slow_paths``, and at ``f`` = 1, where nothing is compared): the distinct
+# dependencies of the union that fewer than ``f`` members of a shard's ring
+# reported (what the accept round was run for), the rows whose members'
+# reports were not one set (``check_union`` would have sent them slow), and
+# of those the rows the threshold still took fast ...
 SITE_ROUND_TALLIES = ROUND_TALLIES + (
     "scc_rows", "scc_count", "resolve_iters", "finisher_rows",
     "scc_span_rows", "scc_shard_rows",
+    "threshold_short_deps", "split_quorum_rows", "threshold_fast_split_rows",
 )
 # ... and, last in the vector, a gauge: the largest component of the round
 # (the driver keeps the last round's that had one)
@@ -537,7 +544,7 @@ def protocol_step(
     command coordinated by replica 0 and seen by every replica in arrival
     order.  ``sites == n`` (the replicas a shard) is the round with a
     coordinator at every site (:func:`_protocol_step_sites`: any key width
-    and shard count, EPaxos's rule or Atlas's at ``f`` = 1): a command's
+    and shard count, EPaxos's rule or Atlas's at any ``f``): a command's
     coordinator is the replica at site ``dot_src - site_base`` of every
     shard it touches, the replicas see a round's commands in different
     orders and the committed graph has cycles, across keys and shards
@@ -885,6 +892,48 @@ def jit_protocol_step(
     return jax.jit(functools.partial(protocol_step, **static), donate_argnums=(0,))
 
 
+def _atlas_threshold(said: jax.Array, slot_shard: jax.Array, f: int):
+    """Atlas's fast-path test at ``f`` >= 2 with a coordinator at every site
+    (``QuorumDeps::check_threshold`` over the reports, each joined with the
+    coordinator's own: ``tests/sites_reference.py``, departure 9), by working
+    row.  ``said``: int32[W, KW, fast_quorum, 2], the ring members' words on
+    each key slot (gids, -1 none; member 0 is the coordinator); ``slot_shard``:
+    int32[W, KW].  Returns ``(enough, short, split)``: whether, in every shard
+    the row touches, every dependency of the union was reported by at least
+    ``f`` members of the shard's ring; how many distinct dependencies were not;
+    whether the members' reports were not one set (EPaxos's ``check_union``).
+
+    A member's report in a shard is its words on the row's slots of that
+    shard joined with the coordinator's, so a word is compared with every
+    word on a slot of the same shard, ``(KW * fast_quorum * 2)^2`` a row, and
+    whatever the coordinator found counts ``fast_quorum`` times."""
+    work, key_width, fast_quorum, _ = said.shape
+    entries = key_width * fast_quorum * 2
+    with jax.named_scope("atlas_threshold"):
+        # (the rows on the minor axis: the other axes are a few long)
+        word = said.reshape(work, entries).T  # [E, W]
+        equal = word[:, None] == word[None]  # [E, E, W]
+        same = equal
+        if key_width > 1:  # a report holds the words of one shard's slots
+            shard = jnp.repeat(slot_shard, 2 * fast_quorum, axis=1).T  # [E, W]
+            same = equal & (shard[:, None] == shard[None])
+        # the members whose own word on a slot of the shard is this word
+        reported = same.reshape(
+            entries, key_width, fast_quorum, 2, work
+        ).any(axis=(1, 3))  # [E, fast_quorum, W]
+        count = jnp.where(reported[:, 0], fast_quorum, reported.sum(axis=1))
+        none = word < 0
+        short = ~none & (count < f)  # [E, W]
+        # a dependency counts once a row, however many words hold it
+        earlier = jnp.tril(jnp.ones((entries, entries), bool), -1)
+        repeat = (equal & short[None] & earlier[:, :, None]).any(axis=1)
+        return (
+            ~short.any(axis=0),
+            (short & ~repeat).sum(axis=0).astype(jnp.int32),
+            ~(none | reported[:, 0]).all(axis=0),
+        )
+
+
 def _protocol_step_sites(
     state: ReplicaState,
     key: jax.Array,  # int32[B] or int32[B, KW]
@@ -922,8 +971,12 @@ def _protocol_step_sites(
         fast_quorum``; under EPaxos's rule the fast path is taken iff, in
         every touched shard, every member's report is the same set, which
         is iff every member's own word is within the coordinator's
-        (``check_union``); under Atlas's at ``f`` = 1 always; a command
-        commits when every shard it touches has (``partial.rs``);
+        (``check_union``); under Atlas's iff, in every touched shard,
+        every dependency of the union was reported by at least ``f``
+        members (``check_threshold``, :func:`_atlas_threshold`; at ``f`` =
+        1 always, and nothing is compared); a command that missed it
+        takes the accept round, and commits when every shard it touches
+        has (``partial.rs``);
       * the committed dependencies are the union of the members' words as
         a set, ``2 * fast_quorum`` a key slot, and they point both ways
         along a key's run and, with several keys a command, across runs.
@@ -954,9 +1007,6 @@ def _protocol_step_sites(
     )
     assert num_replicas % shard_count == 0
     per_shard = num_replicas // shard_count  # replicas a shard: the sites
-    assert rule == "epaxos" or f == 1, (
-        "a coordinator at every site under Atlas's rule: f = 1"
-    )
     if read is None:
         read = jnp.zeros((batch,), bool)
     pend_cap = state.pend_gid.shape[0]
@@ -1121,6 +1171,7 @@ def _protocol_step_sites(
             .reshape(work, key_width, fast_quorum, 6)
         )
         said = members[..., :2]  # [W, KW, fast_quorum, 2] gids
+        threshold = None  # where Atlas's threshold is taken: (short, split) by row
         if rule == "epaxos":
             # every report of a shard (a member's words joined with the
             # coordinator's) is the same set iff every member's word is
@@ -1136,8 +1187,11 @@ def _protocol_step_sites(
                 & same_shard[:, None, None, :, None]
             ).any(axis=(3, 4))
             fast = ((said_t < 0) | found).all(axis=(0, 1, 2)) & valid
+        elif f == 1:
+            fast = valid  # whoever reported a dependency is one of f
         else:
-            fast = valid  # Atlas at f = 1: whoever reported a dependency is one of f
+            enough, *threshold = _atlas_threshold(said, slot_shard, f)
+            fast = enough & valid
         deps_gid = jnp.where(
             real_slot[:, :, None, None], said, -1
         ).reshape(work, width)
@@ -1178,8 +1232,8 @@ def _protocol_step_sites(
             graph = [
                 res.scc_rows, res.scc_count, res.iters, count(finish),
                 jnp.int32(0), jnp.int32(0),  # a component lies in one key's run
-                res.scc_rows_max,
             ]
+            largest = res.scc_rows_max
         else:
             at = members[..., 2:4].reshape(work, width)  # sorted positions
             dep_row = jnp.where(at >= 0, row_at[jnp.maximum(at, 0)], jnp.int32(TERMINAL))
@@ -1214,8 +1268,8 @@ def _protocol_step_sites(
             graph = [
                 count(several), count(size > 1), res.iters, count(finish),
                 count(several & spans[safe_lead]), count(several & crosses[safe_lead]),
-                jnp.where(size > 1, size, 0).max().astype(jnp.int32),
             ]
+            largest = jnp.where(size > 1, size, 0).max().astype(jnp.int32)
 
         # 5. every live replica learns what executed on the buckets of its
         # own shard, the host-ordered rows too (they execute this round)
@@ -1249,6 +1303,16 @@ def _protocol_step_sites(
         linked = done_slot & (members[:, :, 0, 4] > 0)
         shards_lo = jnp.where(real_slot, slot_shard, shard_count).min(axis=-1)
         shards_hi = jnp.where(real_slot, slot_shard, -1).max(axis=-1)
+        if threshold is None:  # nothing was compared
+            threshold = [jnp.int32(0)] * 3
+        else:
+            short_deps, split = threshold
+            with jax.named_scope("atlas_threshold"):
+                threshold = [
+                    jnp.where(executed, short_deps, 0).sum().astype(jnp.int32),
+                    count(executed & split),
+                    count(executed & split & fast),
+                ]
         tallies = jnp.stack(
             [
                 count(distinct & executed[:, None]),
@@ -1257,7 +1321,7 @@ def _protocol_step_sites(
                 count(executed & read_f),
                 count(executed & (shards_hi > shards_lo)),
             ]
-            + graph
+            + graph + threshold + [largest]
         )  # SITE_ROUND_TALLIES, SITE_ROUND_GAUGES
 
         # 6. pending carry, as protocol_step has it

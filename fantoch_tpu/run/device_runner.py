@@ -425,9 +425,10 @@ class _DriverCore(PipelineCore):
         if not self.serves_sites:
             raise ValueError(
                 f"clients at site {site}: this round has one coordinator, "
-                "replica 0 (a coordinator at every site is served under "
-                "epaxos, under atlas at f = 1, and under newt with one key "
-                "a command on one shard)"
+                "replica 0 (caesar's and fpaxos's always, newt's under "
+                "--shard-count or --device-key-width above 1; a coordinator "
+                "at every site is served under epaxos, atlas, and newt with "
+                "one key a command on one shard)"
             )
         if not 0 <= site < self.num_replicas:
             raise ValueError(
@@ -978,13 +979,9 @@ class DeviceDriver(_DriverCore):
 
     # --- a coordinator at every site ---
 
-    @property
-    def serves_sites(self) -> bool:
-        """Whether the round can have a coordinator at every site: under
-        EPaxos's rule, and under Atlas's at ``f`` = 1 (its threshold over
-        per-site views at a larger ``f`` is not written), at any key width
-        and shard count."""
-        return self.rule == "epaxos" or self.f == 1
+    # the round can have a coordinator at every site under either rule, at
+    # any ``f`` the quorum formula admits, any key width and shard count
+    serves_sites = True
 
     def _make_site_programs(self) -> None:
         """The round's second program (``protocol_step(sites=n)``)."""

@@ -1,6 +1,7 @@
 """The plain reference of the dependency round with a coordinator at every
-site (``--protocol epaxos`` or ``--protocol atlas -f 1``, any ``--shard-count``
-and ``--device-key-width``, clients registered at more than one site:
+site (``--protocol epaxos`` or ``--protocol atlas`` at any ``-f``, any
+``--shard-count`` and ``--device-key-width``, clients registered at more than
+one site:
 ``parallel/mesh_step.py`` ``protocol_step(sites=n)``): its rules one command
 at a time over ``dict``s, ``set``s and ``list``s.
 Nothing here is the program's round: no import from ``fantoch_tpu.parallel``
@@ -94,16 +95,23 @@ followed by the device round:
    second of each, and so on (``run/device_runner.py`` ``_sites_in_turn``):
    what one socket read brings is hundreds of commands of one site in a row,
    where a replica's network would deliver five coordinators' ``MCollect``s
-   interleaved.  A site's own commands keep their order.
+   interleaved.  A site's own commands keep their order.  The sites take
+   their turns in the order they first appear in the batch, and under
+   Atlas's threshold (departure 9) that order shows: a command's predecessor
+   in turn is reported by every member but the one at the predecessor's own
+   site, so fewer commands fall short where that site is the one replica
+   outside the coordinator's ring.
 8. **A command that missed the fast path in one shard runs the accept round
    in every shard it touches**, and commits when all of them accepted, in the
    round that proposed it.  In ``partial.rs`` each shard commits on its own
    and the coordinator aggregates the ``MShardCommit``s.
 9. **Atlas's threshold is taken over the reports**, each joined with the
    coordinator's own, as ``atlas.rs`` joins them: a dependency the coordinator
-   found is reported by every member.  At ``f`` = 1, the one ``f`` the device
-   round serves with a coordinator at every site, the fast path is
-   unconditional either way.
+   found is reported by every member, so only what a member that is not the
+   coordinator found alone, or with fewer than ``f`` of the ring, sends a
+   command to the accept round.  At ``f`` = 1 the fast path is unconditional
+   either way, and the device round compares nothing there; at ``f`` >= 2 it
+   serves this rule (``mesh_step._atlas_threshold``).
 10. **Every replica of a shard has every command of the round that touches
    its shard in its view** (departure 3, per shard), and a shard's replicas
    see nothing of a command's keys on other shards.
@@ -146,6 +154,11 @@ class Verdict(NamedTuple):
     executed: bool
     # key -> the members' own words on that key, joined
     by_key: Dict[int, FrozenSet[Dot]] = {}
+    # where Atlas's threshold is taken (``f`` >= 2): the dependencies of the
+    # union that fewer than ``f`` members of some shard's ring reported, and
+    # whether some shard's reports were not one set (EPaxos's test)
+    short: FrozenSet[Dot] = frozenset()
+    split: bool = False
 
 
 class Round(NamedTuple):
@@ -160,7 +173,11 @@ class Round(NamedTuple):
         """The device round's tallies over what this round executed.  Of the
         components of several commands: ``scc_span_rows`` counts the commands
         of those whose members hold more than one key, ``scc_shard_rows`` of
-        those whose members' keys lie on more than one shard."""
+        those whose members' keys lie on more than one shard.  The last three
+        are Atlas's threshold's, 0 where none is taken (EPaxos's rule, ``f`` =
+        1): the dependencies that fell short of ``f``, the commands whose
+        quorum was split, and those of them that were fast all the same."""
+        ran = [self.verdicts[dot] for dot in self.order]
         multi = [c for c in self.components if len(c) > 1]
         span = shard = 0
         for component in multi:
@@ -178,6 +195,9 @@ class Round(NamedTuple):
             "scc_rows_max": max(map(len, multi), default=0),
             "scc_span_rows": span,
             "scc_shard_rows": shard,
+            "threshold_short_deps": sum(len(verdict.short) for verdict in ran),
+            "split_quorum_rows": sum(verdict.split for verdict in ran),
+            "threshold_fast_split_rows": sum(verdict.split and verdict.fast for verdict in ran),
         }
 
 
@@ -277,6 +297,7 @@ class Reference:
         slow_paths = 0
         for cmd in working:
             fast = accepted = True
+            split, short = False, set()
             reports: Dict[int, FrozenSet[int]] = {}
             by_key: Dict[int, Set[int]] = {key: set() for key in cmd.keys}
             for shard in sorted({key % shards for key in cmd.keys}):
@@ -285,10 +306,14 @@ class Reference:
                 if self.rule == "epaxos":
                     fast &= all(one == said[0] for one in said)
                 else:
-                    fast &= all(
-                        sum(dep in one for one in said) >= self.f
-                        for dep in frozenset().union(*said)
-                    )
+                    fell = {
+                        dep for dep in frozenset().union(*said)
+                        if sum(dep in one for one in said) < self.f
+                    }  # at f = 1 none can
+                    fast &= not fell
+                    if self.f > 1:  # the device round compares, and counts, from there on
+                        short |= fell
+                        split |= any(one != said[0] for one in said)
                 accepted &= sum(shard * n + m < live for m in range(n)) >= self.write_quorum
                 reports.update(zip(rows, said))
                 for row in rows:
@@ -301,6 +326,7 @@ class Reference:
                 deps=dots(frozenset().union(*reports.values())),
                 executed=False,
                 by_key={key: dots(found) for key, found in by_key.items()},
+                short=dots(short), split=split,
             )
 
         components = self._execute(working, verdicts)

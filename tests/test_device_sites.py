@@ -51,6 +51,23 @@ async def _call(rw, cmd):
     return reply
 
 
+def _put_keys(client, seq, shard_count, key_width):
+    """A write of one key on shard 0 and, at key width 2, one on the last shard."""
+    op = (KVOp.put(f"{client}:{seq}"),)
+    if key_width == 1:
+        return Command(Rifl(client, seq), {0: {"a": op}})
+    if shard_count == 1:
+        return Command(Rifl(client, seq), {0: {"a": op, "b": op}})
+    return Command(Rifl(client, seq), {0: {"a": op}, shard_count - 1: {"b": op}})
+
+
+async def _call_shards(rw, cmd):  # a reply a shard touched
+    await rw.send(Submit(cmd))
+    for _ in range(len(cmd._shard_to_ops)):
+        reply = await rw.recv()
+        assert isinstance(reply, ToClient) and reply.cmd_result.rifl == cmd.rifl
+
+
 def test_a_hello_takes_a_site_and_one_without_is_at_site_0():
     assert ClientHi([1, 2]).site == 0 and ClientHi([1], site=3).site == 3
     # a hello framed by a client that knows no site (its pickle holds no such attribute)
@@ -142,9 +159,9 @@ def test_a_second_sites_hello_makes_the_second_program_ready_before_its_ack():
     ("newt", {"f": 2}, 5, "the sites are the replicas"),
     ("caesar", {"n": 7}, 1, "one coordinator"),
     ("fpaxos", {}, 1, "one coordinator"),
-    ("atlas", {"f": 2}, 1, "one coordinator"),
-    ("atlas", {"f": 2, "shard_count": 2}, 1, "atlas at f = 1"),
-    ("atlas", {"f": 2, "key_width": 2}, 1, "atlas at f = 1"),
+    ("atlas", {"f": 2}, 5, "the sites are the replicas"),
+    ("atlas", {"f": 2, "shard_count": 2}, -1, "the sites are the replicas"),
+    ("atlas", {"f": 2, "key_width": 2}, "1", "a site is a replica's number"),
 ])
 def test_a_site_that_cannot_be_served_is_refused_before_the_ack(protocol, kwargs, site, why, caplog):
     async def go():
@@ -196,9 +213,6 @@ def test_dots_are_a_coordinators_and_two_sites_never_collide_in_a_registry():
     lambda: NewtDeviceDriver(5, batch_size=8, key_buckets=64, pending_capacity=8, key_width=2),
     lambda: CaesarDeviceDriver(7, batch_size=8, key_buckets=64, pending_capacity=8),
     lambda: PaxosDeviceDriver(5, batch_size=8, pending_capacity=8),
-    lambda: DeviceDriver(5, rule="atlas", f=2, batch_size=8, key_buckets=64, pending_capacity=8),
-    lambda: DeviceDriver(5, rule="atlas", f=2, batch_size=8, key_buckets=64, pending_capacity=8,
-                         key_width=2, shard_count=2),
 ])
 def test_a_driver_with_one_coordinator_takes_site_0_and_no_other(build):
     driver = build()
@@ -207,6 +221,32 @@ def test_a_driver_with_one_coordinator_takes_site_0_and_no_other(build):
         driver.register_site(1)
     assert driver.sites_registered == 1 and driver.precompiled_programs == 0
     assert not getattr(driver, "serves_sites", False)
+
+
+@pytest.mark.parametrize("shard_count, key_width", [(1, 1), (2, 2)])
+def test_an_atlas_driver_at_f_two_takes_every_site_and_makes_the_second_program_ready(
+        shard_count, key_width):
+    """Atlas at f = 2 (a ring of four, the threshold over per-site views): the
+    hello of site 1 is taken, the second program is ready when it returns, and
+    every other site's finds it there; a site that is none of the replicas' is
+    still refused."""
+    driver = DeviceDriver(5, rule="atlas", f=2, batch_size=8,
+                          key_buckets=64, pending_capacity=8, key_width=key_width,
+                          shard_count=shard_count)
+    assert driver.serves_sites and (driver.fast_quorum, driver.write_quorum) == (4, 3)
+    driver.register_site(0)
+    assert driver.precompiled_programs == 0
+    driver.register_site(1)
+    assert driver._site_program is not None and driver.stages.n["precompile"] == 1
+    for site in (2, 3, 4):
+        driver.register_site(site)
+    assert driver.sites_registered == 5 and driver.stages.n["precompile"] == 1
+    assert driver.resolver == ("key_runs" if key_width == 1 else "general_components")
+    with pytest.raises(ValueError, match="the sites are the replicas"):
+        driver.register_site(5)
+    batch = [(Dot(1 + at % 5, 1 + at // 5), _put(1 + at, 1, "k")) for at in range(8)]
+    assert len(driver.serve([batch])) == 8 and driver.in_flight == 0
+    assert driver.round_tallies["split_quorum_rows"] > 0
 
 
 def test_the_one_site_program_is_the_round_without_a_sites_argument():
@@ -221,7 +261,7 @@ def test_the_one_site_program_is_the_round_without_a_sites_argument():
     program, sharding, layout = two
     assert sharding.shard_shape((4, 8))[0] == len(driver._column_specs())  # a row a column, one array
     assert layout.type is mesh_step.SiteStepOutput
-    assert len(mesh_step.SITE_ROUND_TALLIES) + len(mesh_step.SITE_ROUND_GAUGES) == 12
+    assert len(mesh_step.SITE_ROUND_TALLIES) + len(mesh_step.SITE_ROUND_GAUGES) == 15
 
 
 @pytest.mark.parametrize("protocol, shard_count, key_width", [
@@ -236,20 +276,10 @@ def test_a_hello_with_a_site_is_served_on_a_sharded_server_of_several_keys(
     sites' commands over the same two keys of two shards take their
     coordinators' dots, disagree, and execute."""
     obs.subscribe_recompiles()
+    call = _call_shards
 
-    def put(client, seq):  # one key on shard 0, one on the last shard
-        op = (KVOp.put(f"{client}:{seq}"),)
-        if key_width == 1:
-            return Command(Rifl(client, seq), {0: {"a": op}})
-        if shard_count == 1:
-            return Command(Rifl(client, seq), {0: {"a": op, "b": op}})
-        return Command(Rifl(client, seq), {0: {"a": op}, shard_count - 1: {"b": op}})
-
-    async def call(rw, cmd):  # a reply a shard touched
-        await rw.send(Submit(cmd))
-        for _ in range(len(cmd._shard_to_ops)):
-            reply = await rw.recv()
-            assert isinstance(reply, ToClient) and reply.cmd_result.rifl == cmd.rifl
+    def put(client, seq):
+        return _put_keys(client, seq, shard_count, key_width)
 
     async def go():
         runtime, port = _runtime(protocol, shard_count=shard_count, key_width=key_width)
@@ -319,6 +349,51 @@ def test_two_sites_two_shard_commands_take_their_coordinators_dots_and_one_compo
     assert tallies["finisher_rows"] == 0 and tallies["resolve_iters"] >= 1
     assert (driver.slow_paths == 0) == (protocol == "atlas")
     assert driver.executed == 6 and driver.in_flight == 0
+
+
+@pytest.mark.parametrize("shard_count, key_width", [(1, 1), (4, 2)])
+def test_a_server_under_atlas_at_f_two_acknowledges_a_hello_from_every_site(shard_count, key_width):
+    """`--protocol atlas -f 2`, on one shard with one key a command and on four
+    with two: the second site's hello makes the second program ready before its
+    ack, every other site's is acknowledged on it, and five sites' writes of
+    one key take both paths: the threshold's tallies reach the snapshot."""
+    obs.subscribe_recompiles()
+
+    async def go():
+        runtime, port = _runtime("atlas", f=2, shard_count=shard_count, key_width=key_width)
+        await runtime.start()
+        try:
+            driver = runtime.driver
+            assert driver.serves_sites and (driver.rule, driver.f) == ("atlas", 2)
+            assert (driver.fast_quorum, driver.write_quorum) == (4, 3)
+            sessions = []
+            for site in range(5):
+                rw, writer, ack = await _hello(port, ClientHi([1 + site], site=site))
+                assert isinstance(ack, ClientHiAck)
+                # one program more at the second site's hello, none after it
+                assert driver.stages.n["precompile"] == 1 + (site > 0)
+                assert (driver._site_program is not None) == (site > 0)
+                sessions.append((rw, writer))
+            assert driver.sites_registered == 5 and driver.precompiled_programs == 2
+            compiled = obs.recompile_count() + obs.cache_hit_count()
+            for seq in range(1, 9):
+                await asyncio.gather(*(
+                    _call_shards(rw, _put_keys(1 + site, seq, shard_count, key_width))
+                    for site, (rw, _) in enumerate(sessions)))
+            assert obs.recompile_count() + obs.cache_hit_count() == compiled  # nothing since
+            assert driver.executed == 40 and driver.in_flight == 0
+            tallies = runtime._tallies
+            assert tallies["sites_registered"] == 5
+            assert 0 < tallies["slow_paths"] <= tallies["threshold_short_deps"]
+            assert 0 < tallies["threshold_fast_split_rows"] < tallies["split_quorum_rows"]
+            assert (tallies["split_quorum_rows"] - tallies["threshold_fast_split_rows"]
+                    == tallies["slow_paths"])
+            for _, writer in sessions:
+                writer.close()
+        finally:
+            await runtime.stop()
+
+    asyncio.run(go())
 
 
 # --- Tempo: the Newt round with a coordinator at every site -----------------
