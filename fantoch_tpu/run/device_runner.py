@@ -47,6 +47,7 @@ from __future__ import annotations
 import asyncio
 import gc
 import os
+import select
 import sys
 from collections import defaultdict
 from concurrent.futures import ThreadPoolExecutor
@@ -2226,6 +2227,8 @@ class _DeviceClientSession:
             except ValueError as exc:
                 raise ProtocolError(str(exc)) from None
             await self.rw.send(ClientHiAck())
+            # live from here on: its socket is the runtime's to hold
+            self.runtime.add_session(self)
             flusher = self.runtime.spawn(self._flush_loop(), fatal=False)
             try:
                 while True:
@@ -2252,7 +2255,9 @@ class DeviceRuntime:
     ``bin/client.py`` drive it unchanged.  One driver task loops:
     drain submissions -> one device step -> route results to sessions.
     The device dispatch runs in a thread-pool executor so the event loop
-    keeps serving connections during the (blocking) device round-trip.
+    keeps accepting connections and flushing replies during the (blocking)
+    device round-trip; the sessions' sockets are read between steps, not
+    during them (``_step_on_pool``).
     """
 
     def __init__(
@@ -2438,6 +2443,18 @@ class DeviceRuntime:
         # holds one connection per shard; only the target shard's carries
         # the Submit)
         self.rifl_sessions: Dict[Rifl, _DeviceClientSession] = {}
+        # the sessions whose hello was acknowledged and that have not been
+        # dropped: the sockets a step holds (_step_on_pool)
+        self._sessions: set = set()
+        # a hold is on
+        self._reads_held = False
+        # the sessions a release found with bytes waiting and whose read
+        # has not come in yet; set while there is none (_driver_task)
+        self._reads_due: set = set()
+        self._reads_in = asyncio.Event()
+        self._reads_in.set()
+        # dispatches made while live sessions' sockets were held
+        self._held_dispatches = 0
         # bounded submit ring (run/pipeline.py): the device serving
         # loop's admission edge.  Config.admission_limit bounds queued
         # submissions; past it sessions shed with a typed Overloaded
@@ -2641,6 +2658,9 @@ class DeviceRuntime:
             "shed_submissions": self._submit_queue.sheds,
             # per-dispatch device counters (observability/device.py)
             **d.device_counters(),
+            # ... of the dispatches, those whose step had live sessions'
+            # sockets held (_step_on_pool)
+            "device_held_dispatches": self._held_dispatches,
             # a round's host time by stage: stage_<name>_ms / _n, for the
             # stages that compute _cpu_ms; the probe's stalls by class
             **self.stages.counters(),
@@ -2918,9 +2938,29 @@ class DeviceRuntime:
         self._batcher.note_arrivals(now_ms, len(admitted))
         self._work.set()
 
+    def add_session(self, session: "_DeviceClientSession") -> None:
+        """A session whose hello was acknowledged: from here to
+        ``drop_session`` its socket is among those a step holds, and one
+        that joins during a hold joins the hold (its commands could be
+        dispatched no sooner than the others')."""
+        self._sessions.add(session)
+        if self._reads_held:
+            session.rw.hold_reading()
+
+    def _read_in(self, session: "_DeviceClientSession") -> None:
+        """The read a release found waiting on ``session``'s socket has
+        returned (or the session has ended): the last of them lets the
+        driver task collect."""
+        due = self._reads_due
+        due.discard(session)
+        if not due:
+            self._reads_in.set()
+
     def drop_session(self, session: "_DeviceClientSession") -> None:
         """Forget a closed session's in-flight rifls (their results have
         nowhere to go; the driver still executes them for the cluster)."""
+        self._sessions.discard(session)
+        self._read_in(session)
         stale = [
             rifl for rifl, s in self.rifl_sessions.items() if s is session
         ]
@@ -2990,13 +3030,30 @@ class DeviceRuntime:
             before = after
 
     async def _step_on_pool(self, round_id: int, step, *args):
-        """One blocking driver call off the event loop (connections and
+        """One blocking driver call off the event loop (the listener and
         result flushes stay live during the round), between its two
         hand-offs: ``handoff`` from here to the first line on the pool
         thread, ``resume`` from the call's return there to this task
         running again.  Both share the GIL with whatever the loop does
-        meanwhile."""
+        meanwhile.
+
+        The live sessions' sockets are held while the step runs: the loop
+        does not read them, so it takes the interpreter from the step's
+        thread a reply flush at a time and not a read at a time, the kernel
+        keeps what arrives, and at the release one read a socket brings it
+        all, one run of the ring with one arrival time.  Nothing a read
+        brings could be dispatched before the step returns (this task is
+        the driver's only caller).  The release asks the kernel which
+        sockets have bytes waiting (one ``poll``, beside the loop's own),
+        and the driver task collects the next round once those sessions'
+        reads are in (``_reads_in``)."""
         stages = self.stages
+        driver = self.driver
+        sessions = self._sessions
+        dispatched = driver.dispatches
+        self._reads_held = True
+        for session in sessions:
+            session.rw.hold_reading()
         called = stages.clock()
 
         def on_pool():
@@ -3006,10 +3063,29 @@ class DeviceRuntime:
                 results = step(*args)
             return results, stages.clock()
 
-        results, returned = await asyncio.get_running_loop().run_in_executor(
-            None, on_pool
-        )
-        stages.record("resume", returned, stages.clock(), round_id, "round")
+        try:
+            results, returned = await asyncio.get_running_loop().run_in_executor(
+                None, on_pool
+            )
+            stages.record("resume", returned, stages.clock(), round_id, "round")
+        finally:
+            self._reads_held = False
+            if sessions:
+                self._held_dispatches += driver.dispatches - dispatched
+                poller, by_fd = select.poll(), {}
+                for session in sessions:
+                    session.rw.release_reading()
+                    fd = session.rw.fileno()
+                    if fd >= 0:
+                        by_fd[fd] = session
+                        poller.register(fd, select.POLLIN)
+                due = self._reads_due
+                for fd, _ in poller.poll(0):
+                    session = by_fd[fd]
+                    due.add(session)
+                    session.rw.on_read = partial(self._read_in, session)
+                if due:
+                    self._reads_in.clear()
         return results
 
     def _collect(self, round_id: int, chain: int) -> List[List[Tuple[Dot, Command]]]:
@@ -3126,6 +3202,13 @@ class DeviceRuntime:
             # round's program stays on the device), and before an idle
             # wait, where no command is alive to be traversed
             self._collector.run_if_due()
+            # what the kernel kept through the last step comes in before
+            # the next round is collected, whatever the ring already holds
+            # (a read that landed in the turn the hold began in was
+            # admitted during the step): else that round would carry a
+            # straggler or two and hold the sockets, full by now, for a
+            # second step
+            await self._reads_in.wait()
             # a round is named by the number of the dispatch it makes
             round_id = driver.dispatches + 1
             if not self._submit_queue and can_pipeline and driver.has_outstanding:

@@ -181,6 +181,15 @@ class Rw:
         # own first clock read): where the session's ``read`` span starts
         self.read_t0 = 0
         self._tail = b""  # recv_all: the bytes of a frame not yet whole
+        # the owner's hold on the socket's reads (``hold_reading``), and
+        # whether it is the hold that paused the transport: the reader
+        # pauses it too, for its own flow control, and that pause is not
+        # the hold's to undo
+        self._held = False
+        self._hold_paused = False
+        # the owner's to set: called once, when the next read of the
+        # stream returns (``recv_all``: bytes, a part of a frame or the end)
+        self.on_read = None
         sock = writer.get_extra_info("socket")
         if sock is not None:
             # TCP_NODELAY, as the reference's Connection (connection.rs:46-51)
@@ -222,12 +231,23 @@ class Rw:
             try:
                 data = await read(_READ_ALL)
             except ConnectionResetError:
-                return None
+                data = None
+            if self.on_read is not None:
+                # whatever the read brought; a task the call wakes runs no
+                # sooner than this one next waits, so what the read holds
+                # is with the owner by then
+                on_read, self.on_read = self.on_read, None
+                on_read()
             if not data:
-                if len(self._tail) >= size:
+                if data is not None and len(self._tail) >= size:
                     # EOF inside a payload: what readexactly raises
                     raise asyncio.IncompleteReadError(self._tail[size:], None)
                 return None
+            if self._held:
+                # a read that emptied the reader has ended the reader's own
+                # pause: the hold takes the transport over before the loop
+                # polls it
+                self._pause_for_hold()
             tally = self._decode_tally
             t0 = monotonic_ns()
             timed = tally is not None and t0 >= tally[5]
@@ -290,6 +310,42 @@ class Rw:
             if values:
                 self.read_t0 = t0
                 return values
+
+    # --- the owner's hold on the reads ---
+
+    def hold_reading(self) -> None:
+        """Leave what arrives from here on in the kernel's socket buffer
+        until :meth:`release_reading`: the loop does not poll the socket,
+        and the next read brings all of it at once.  What the reader
+        already holds is still given out.  Writes go on.  The
+        ``StreamReader`` pauses the same transport when it holds more than
+        twice its limit and resumes it from ``read``; the two reasons are
+        kept apart: a release leaves the reader's pause standing, and a
+        ``read`` that ends the reader's pause during a hold hands the
+        transport to the hold (``recv_all``)."""
+        self._held = True
+        self._pause_for_hold()
+
+    def _pause_for_hold(self) -> None:
+        transport = self._writer.transport
+        if transport is not None and transport.is_reading():
+            transport.pause_reading()
+            self._hold_paused = True
+
+    def release_reading(self) -> None:
+        """End the hold: the socket is polled again, unless the reader has
+        it paused for its own reasons.  Nothing on a connection not held,
+        or closed meanwhile."""
+        self._held = False
+        if self._hold_paused:
+            self._hold_paused = False
+            self._writer.transport.resume_reading()
+
+    def fileno(self) -> int:
+        """The socket's descriptor, to poll it beside the loop; -1 where
+        the transport has none, or has closed it."""
+        sock = self._writer.get_extra_info("socket")
+        return -1 if sock is None else sock.fileno()
 
     def write(self, value: Any) -> None:
         """Queue one frame without flushing."""
