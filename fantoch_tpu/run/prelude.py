@@ -71,7 +71,8 @@ class ClientHi:
     client's site; a hello that names none is at site 0 (every client
     attached to replica 0: the deployment a one-coordinator round is).  A
     device-step server refuses a site that is none of its replicas', or
-    not 0 where its round has one coordinator (run/device_runner.py)."""
+    not 0 where its round has one coordinator (run/device_drivers.py
+    ``register_site``)."""
 
     client_ids: List[ClientId]
     site: int = 0

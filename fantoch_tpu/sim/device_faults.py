@@ -189,7 +189,7 @@ def install_env_faults(
 ) -> Optional[DeviceFaultInjector]:
     """Attach one env-configured injector to every device plane of a
     live runtime (run/process_runner.py executor pools,
-    run/device_runner.py drivers).  No-op (returns None) when
+    run/device_drivers.py drivers).  No-op (returns None) when
     ``FANTOCH_DEVICE_FAULT`` is unset or no plane exists."""
     faults = faults_from_env()
     if not faults:

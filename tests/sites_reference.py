@@ -92,7 +92,7 @@ followed by the device round:
 7. **Arrival order inside a round is the sites' commands in turn.**  This
    file takes a round's commands in the order it is given them.  The driver
    gives the round its batch with the first command of each site, then the
-   second of each, and so on (``run/device_runner.py`` ``_sites_in_turn``):
+   second of each, and so on (``run/device_drivers.py`` ``_sites_in_turn``):
    what one socket read brings is hundreds of commands of one site in a row,
    where a replica's network would deliver five coordinators' ``MCollect``s
    interleaved.  A site's own commands keep their order.  The sites take

@@ -73,7 +73,7 @@ Departures from ``newt.rs``, noted and followed by the device round:
    competing proposal for its dot.
 7. **Arrival order inside a round is the sites' commands in turn.**  This file
    takes a round's commands in the order it is given them; the driver gives the
-   round its batch by ``run/device_runner.py`` ``_sites_in_turn``.
+   round its batch by ``run/device_drivers.py`` ``_sites_in_turn``.
 8. **A proposal lasts as long as its round.**  A replica's clock for a key is,
    between rounds, the highest of what it had and its votes (a live replica's
    proposals for committed commands are at most their timestamps, so nothing is

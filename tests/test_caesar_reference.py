@@ -24,7 +24,7 @@ from fantoch_tpu.core.command import Command
 from fantoch_tpu.core.ids import Dot, Rifl
 from fantoch_tpu.core.kvs import KVOp
 from fantoch_tpu.parallel import mesh_step
-from fantoch_tpu.run.device_runner import CaesarDeviceDriver, _bucket
+from fantoch_tpu.run.device_drivers import CaesarDeviceDriver, _bucket
 from tests import caesar_reference as ref
 
 N, BUCKETS, BATCH, PENDING, CLIENTS, COMMANDS, SEED = 7, 64, 32, 32, 24, 600, 34

@@ -20,7 +20,7 @@ from fantoch_tpu.core.ids import Dot
 from fantoch_tpu.core.kvs import KVStore
 from fantoch_tpu.parallel.mesh_step import KEY_PAD
 from fantoch_tpu.run import rw
-from fantoch_tpu.run.device_runner import _buckets, _key_column
+from fantoch_tpu.run.device_drivers import _buckets, _key_column
 from fantoch_tpu.run.prelude import Submit
 
 RIFL = Rifl(2**40 + 7, 2**33)

@@ -687,7 +687,7 @@ def test_caesar_driver_degraded_requeue_recovery():
     import jax
     import jax.numpy as jnp
 
-    from fantoch_tpu.run.device_runner import _bucket
+    from fantoch_tpu.run.device_drivers import _bucket
 
     d = CaesarDeviceDriver(
         4, batch_size=8, key_buckets=64, pending_capacity=4,
@@ -1048,7 +1048,7 @@ def test_device_runtime_survives_bad_client():
     from fantoch_tpu.run.harness import free_port
     from fantoch_tpu.run.client_runner import run_clients
     from fantoch_tpu.run.prelude import ClientHi, ClientHiAck, Submit, ToClient
-    from fantoch_tpu.run.device_runner import _bucket
+    from fantoch_tpu.run.device_drivers import _bucket
     from fantoch_tpu.run.rw import Rw
 
     key_buckets = 64
@@ -1776,7 +1776,7 @@ def test_overflow_requeues_what_the_walk_requeued_and_scans_only_then(protocol):
     assert len(_step_both(real, oracle, range(1, 5), own=False)) == 4
     if protocol == "caesar":
         # stagger replica 0's ceiling on the hot bucket: proposals diverge
-        from fantoch_tpu.run.device_runner import _bucket
+        from fantoch_tpu.run.device_drivers import _bucket
 
         for d in (real, oracle):
             kc = np.array(d._state.key_clock)
@@ -2346,7 +2346,8 @@ def _reply_stage(shard_count=1, connections=3, failing=None):
     """An unstarted runtime with one session per connection, each over
     a counting writer; ``failing`` maps a connection to what its writes
     raise."""
-    from fantoch_tpu.run.device_runner import DeviceRuntime, _DeviceClientSession
+    from fantoch_tpu.run.device_runner import DeviceRuntime
+    from fantoch_tpu.run.device_session import _DeviceClientSession
     from fantoch_tpu.run.rw import Rw
 
     runtime = DeviceRuntime(
@@ -2683,7 +2684,7 @@ def _one_key_shapes(shard_count):
 def _several_key_shapes(shard_count, key_buckets=64):
     """Commands of several keys: what the server takes as it stands, and
     every way ``_validate`` has of refusing one."""
-    from fantoch_tpu.run.device_runner import _bucket
+    from fantoch_tpu.run.device_drivers import _bucket
 
     put, get = KVOp.put("v"), KVOp.get()
 

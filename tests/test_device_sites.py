@@ -16,10 +16,10 @@ import pytest
 from fantoch_tpu.core import Command, Config, Dot, KVOp, Rifl
 from fantoch_tpu.observability import device as obs
 from fantoch_tpu.parallel import mesh_step
-from fantoch_tpu.run.device_runner import (
-    CaesarDeviceDriver, DeviceDriver, DeviceRuntime, NewtDeviceDriver, PaxosDeviceDriver,
-    _DriverCore,
+from fantoch_tpu.run.device_drivers import (
+    CaesarDeviceDriver, DeviceDriver, NewtDeviceDriver, PaxosDeviceDriver, _DriverCore,
 )
+from fantoch_tpu.run.device_runner import DeviceRuntime
 from fantoch_tpu.run.harness import free_port
 from fantoch_tpu.run.prelude import ClientHi, ClientHiAck, Submit, ToClient
 from fantoch_tpu.run.rw import Rw

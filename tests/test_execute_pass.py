@@ -15,7 +15,7 @@ from fantoch_tpu.core.command import Command
 from fantoch_tpu.core.ids import Dot, Rifl
 from fantoch_tpu.core.kvs import KVOp, KVOpKind, KVStore
 from fantoch_tpu.executor.base import ExecutorResult
-from fantoch_tpu.run.device_runner import DeviceDriver, NewtDeviceDriver, _DriverCore
+from fantoch_tpu.run.device_drivers import DeviceDriver, NewtDeviceDriver, _DriverCore
 from fantoch_tpu.utils import key_hash
 from tests.benchmark_tests import broken_multi_server, broken_server, stale_read_server
 

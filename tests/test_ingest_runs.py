@@ -25,15 +25,16 @@ from fantoch_tpu.core.command import Command
 from fantoch_tpu.core.config import Config
 from fantoch_tpu.core.ids import Dot, Rifl
 from fantoch_tpu.core.kvs import KVOp
-from fantoch_tpu.run import device_runner
-from fantoch_tpu.run.device_runner import (
+from fantoch_tpu.run import device_drivers, device_runner
+from fantoch_tpu.run.device_drivers import (
     CaesarDeviceDriver,
     DeviceDriver,
-    DeviceRuntime,
     NewtDeviceDriver,
     PaxosDeviceDriver,
     _sites_in_turn,
+    _top_sequence,
 )
+from fantoch_tpu.run.device_runner import DeviceRuntime
 from fantoch_tpu.run.pipeline import BoundedSubmitRing
 
 # --- the ring and _collect ---
@@ -114,6 +115,9 @@ class _Requeue:
     def take_requeue(self):
         out, self._requeue = self._requeue, []
         return out
+
+    def give_back(self, pending):
+        self._requeue[:0] = pending
 
 
 class _Releases:
@@ -331,7 +335,8 @@ def test_collect_has_no_loop_over_commands_but_the_tracers():
     assert len(per_command) == 1 and lines[per_command[0] - 4].strip() == "if tracing:"
     assert sum("tracing = tracer.enabled" in line for line in lines) == 1
     assert not any("popleft" in line or "pop(0)" in line for line in lines)
-    source = inspect.getsource(device_runner)
+    # ... nor does the driver's assembly (``_assemble`` and what it calls) walk one
+    source = inspect.getsource(device_drivers)
     assert "enumerate(batch)" not in source
 
 
@@ -540,9 +545,9 @@ def test_a_sequence_past_the_window_still_raises_what_it_raised(name):
 def test_the_drivers_flush_test_reads_the_batchs_top_sequence():
     driver = NewtDeviceDriver(3, batch_size=BATCH, key_buckets=64, pending_capacity=BATCH)
     batch = [_cmd(1 + i % 3, 7 + (i * 5) % 11) for i in range(9)]
-    assert device_runner._top_sequence(batch) == max(dot.sequence for dot, _ in batch)
+    assert _top_sequence(batch) == max(dot.sequence for dot, _ in batch)
     assert not driver._pipeline_flush_needed([]) and not driver._pipeline_flush_needed(batch)
-    driver.SEQ_WINDOW_MAX = device_runner._top_sequence(batch)
+    driver.SEQ_WINDOW_MAX = _top_sequence(batch)
     assert driver._pipeline_flush_needed(batch) and not driver._pipeline_flush_needed(batch[:1])
     assert driver._chain_windows_blocked([batch[:1], [], batch])
     assert not driver._chain_windows_blocked([batch[:1], []]) and not driver._chain_windows_blocked([[], []])

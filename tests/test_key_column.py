@@ -1,4 +1,4 @@
-"""A round's key column (``device_runner._key_column``) held to the plain
+"""A round's key column (``device_drivers._key_column``) held to the plain
 definition of a command's buckets (``_buckets``), the three conditions on
 the bucket hash, and ``utils.key_hash`` pinned where the shard rule reads
 it."""
@@ -14,7 +14,7 @@ from fantoch_tpu.core.command import Command
 from fantoch_tpu.core.ids import Dot, Rifl
 from fantoch_tpu.core.kvs import KVOp
 from fantoch_tpu.parallel.mesh_step import KEY_PAD
-from fantoch_tpu.run.device_runner import (
+from fantoch_tpu.run.device_drivers import (
     CaesarDeviceDriver,
     DeviceDriver,
     NewtDeviceDriver,
@@ -191,7 +191,7 @@ def test_a_keys_bucket_is_the_same_in_a_fresh_process():
     the same buckets."""
     here = [_bucket(s, k, n, c) for k in _PROBE for s, n, c in ((0, 1 << 20, 1), (3, 4 * 5 * (1 << 18), 4))]
     code = (
-        "from fantoch_tpu.run.device_runner import _bucket\n"
+        "from fantoch_tpu.run.device_drivers import _bucket\n"
         f"print([_bucket(s, k, n, c) for k in {_PROBE!r} "
         "for s, n, c in ((0, 1 << 20, 1), (3, 4 * 5 * (1 << 18), 4))])"
     )

@@ -34,7 +34,7 @@ from fantoch_tpu.observability.device import (
     StageRecorder,
     TimedSelector,
 )
-from fantoch_tpu.run import device_runner, rw
+from fantoch_tpu.run import device_runner, device_session, rw
 from tests.test_cli import REPO, cli_env, free_port
 from tests.test_session_reads import _Served, _submit
 
@@ -275,7 +275,7 @@ def test_a_read_is_one_span_from_the_clock_reads_its_walk_and_its_admit_take(fra
             session._admit = counted_admit
             before = served.tallies()
             monkeypatch.setattr(rw, "monotonic_ns", walk_clock)
-            monkeypatch.setattr(device_runner, "monotonic_ns", admit_clock)
+            monkeypatch.setattr(device_session, "monotonic_ns", admit_clock)
             reads = 3
             for n in range(reads):
                 if n == 2:

@@ -14,7 +14,7 @@ import pytest
 
 from fantoch_tpu.core import Command, Dot, KVOp, Rifl
 from fantoch_tpu.parallel import mesh_step
-from fantoch_tpu.run.device_runner import (
+from fantoch_tpu.run.device_drivers import (
     CaesarDeviceDriver, DeviceDriver, NewtDeviceDriver, PaxosDeviceDriver, _bucket,
 )
 from fantoch_tpu.run.pipeline import StagedColumns, packed_columns, packed_shape

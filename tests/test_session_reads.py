@@ -1,4 +1,4 @@
-"""The way in of the device-step server (run/device_runner.py
+"""The way in of the device-step server (run/device_session.py
 ``_DeviceClientSession``): the ``Submit`` on the wire (since PR 39 its kind
 byte and its command's plain values; tests/test_wire_codec.py has the codec
 alone), ``Rw.recv_all``
@@ -20,8 +20,11 @@ if tuple(int(x) for x in jax.__version__.split(".")[:2]) < (0, 5):
 
 from fantoch_tpu.core import Command, Config, KVOp, Rifl
 from fantoch_tpu.run import rw
-from fantoch_tpu.run.device_runner import DeviceRuntime, ProtocolError, _bucket, _DeviceClientSession
+from fantoch_tpu.run.device_drivers import _bucket
+from fantoch_tpu.run.device_runner import DeviceRuntime
+from fantoch_tpu.run.device_session import _DeviceClientSession
 from fantoch_tpu.run.prelude import ClientHi, ClientHiAck, Overloaded, Register, Submit, ToClient
+from fantoch_tpu.run.rw import ProtocolError
 from tests.test_command_forms import has_dicts
 from tests.test_wire_codec import BROKEN
 

@@ -29,7 +29,7 @@ from fantoch_tpu.core.ids import Dot, Rifl
 from fantoch_tpu.core.kvs import KVOp
 from fantoch_tpu.executor.graph.deps_graph import tarjan_order
 from fantoch_tpu.parallel import mesh_step
-from fantoch_tpu.run.device_runner import DeviceDriver, _bucket, _sites_in_turn
+from fantoch_tpu.run.device_drivers import DeviceDriver, _bucket, _sites_in_turn
 from tests import sites_reference as plain
 
 N = 5

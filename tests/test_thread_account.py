@@ -348,7 +348,8 @@ def test_stage_wait_ms_adds_the_session_planes_two_counters_to_the_stages():
     tally = runtime._decode_tally
     tally[0], tally[3], tally[4] = 400 * MS, 390 * MS + 500_000, 400 * MS
     # one admit pass in ten took its CPU pair: 35 ms of wall, 31 on the CPU
-    runtime._admit_ns, runtime._admit_timed_ns, runtime._admit_cpu_ns = 350 * MS, 35 * MS, 31 * MS
+    session = runtime.session_tallies
+    session.admit_ns, session.admit_timed_ns, session.admit_cpu_ns = 350 * MS, 35 * MS, 31 * MS
     runtime._publish_tallies()
     t = runtime._tallies
     assert t["session_decode_cpu_ms"] == 390.5 and t["session_decode_timed_ms"] == 400.0

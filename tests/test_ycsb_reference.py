@@ -13,7 +13,8 @@ from fantoch_tpu.core.command import Command
 from fantoch_tpu.core.config import Config
 from fantoch_tpu.core.ids import Dot, Rifl
 from fantoch_tpu.core.kvs import KVOp, KVStore
-from fantoch_tpu.run.device_runner import DeviceRuntime, _DeviceClientSession
+from fantoch_tpu.run.device_runner import DeviceRuntime
+from fantoch_tpu.run.device_session import _DeviceClientSession
 from fantoch_tpu.run.rw import Rw
 from tests import ycsb_reference as ref
 from tests.test_device_runner import _CountingWriter, _frames
