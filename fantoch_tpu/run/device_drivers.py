@@ -557,6 +557,11 @@ class _DriverCore(PipelineCore):
         self._state, packed_out, rest = program(
             self._state, self._columns_to_device(staged, sharding)
         )
+        # the copy back follows the program on the device without the
+        # host: a round left in flight has its bytes on the host by the
+        # time its deferred fetch asks for them, and an immediate fetch
+        # finds the copy it would have started itself
+        packed_out.copy_to_host_async()
         self.rounds += S
         return _RoundOutput(packed_out, rest, layout)
 

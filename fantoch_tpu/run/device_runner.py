@@ -103,6 +103,17 @@ from fantoch_tpu.run.device_drivers import (  # noqa: F401
 
 Address = Tuple[str, int]
 
+# The share of one round's rows from which a dispatch is left in flight
+# (``DeviceRuntime._driver_task``).  Read from the dispatch because the two
+# regimes lie a factor of fifty apart there: a throughput round carries
+# 2000-4096 rows of 4096 and its blocking fetch is dead time on both
+# threads, an open-loop round carries about 37 and pays for the overlap
+# with a step of delivery lag and the ingest gate's hold (with every
+# dispatch overlapped the open cells' medians rose by 15-31%: PERF.md
+# section 6, PR 58).  Half a round lies well inside that gap: the edge
+# is not tuned to a cell.
+OVERLAP_MIN_FILL = 0.5
+
 
 class DeviceRuntime:
     """TCP serving front of the device protocol step.
@@ -1065,14 +1076,20 @@ class DeviceRuntime:
             with stages.span("round", round_id) as whole:
                 with stages.span("collect", round_id):
                     batches = self._collect(round_id, tuner.chain)
-                # pipelining pays one round of delivery lag, so engage it
-                # only when another batch is already waiting (throughput
-                # regime); a lone closed-loop command keeps the immediate
-                # sync round.  An outstanding round forces the pipelined
-                # path regardless: its results must come back in order
-                # ahead of this round's.
+                # a dispatch left in flight is fetched when its results
+                # are next wanted (the next dispatch, or the quiet-ring
+                # retire above), not when it was made: the host goes on
+                # to deliver, read and collect while the device runs it
+                # and copies it back, at the price of one step of
+                # delivery lag.  What the dispatch carries says which
+                # regime it is in (OVERLAP_MIN_FILL); a lone closed-loop
+                # command and every part-full round keep the immediate
+                # round.  With a round in flight the path stays on: a
+                # straggler behind it is no reason to block on its fetch.
+                rows = sum(map(len, batches))
                 pipeline = can_pipeline and (
-                    driver.has_outstanding or len(self._submit_queue) > 0
+                    driver.has_outstanding
+                    or rows >= OVERLAP_MIN_FILL * driver.batch_size
                 )
                 # (a chain is one fused device program on Newt, S plain
                 # rounds elsewhere: the driver's own business)

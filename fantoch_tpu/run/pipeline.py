@@ -2,13 +2,20 @@
 
 Every device serving plane pays the same round shape: assemble a batch on
 the host, dispatch one fused device program (async), fetch its outputs
-(blocking), emit results.  On the chip's own host ``enqueue`` + ``fetch``
-take 12-15 ms of a 19-25 ms open-loop round (PERF.md section 5), so the
-only way to keep the device busy is to run dispatch N rounds ahead of
-drain — transfer of round i+1 and the host-side result emit of round
-i-1 overlap with compute of round i, the nonblocking-execution move of
-the GraphBLAS lazy-evaluation line (PAPERS.md) applied to consensus
-serving.
+(blocking), emit results.  The host bounds every cell (the device idles
+87-99% of a saturated capture), and the blocking fetch is host time in
+which nobody works: the step's thread waits for the device and the copy
+back, and the loop's thread has nothing to read while the step holds the
+sockets, 1.2-11.6 ms a round, 4-16% of a saturated window (PERF.md
+section 6, PR 58).  So dispatch may run N rounds ahead of drain: a round
+left in flight is fetched when its results are next wanted, its copy to
+the host follows its program on the device (the drivers' ``_enqueue``
+starts it), and the host delivers, reads and collects meanwhile, the
+nonblocking-execution move of the GraphBLAS lazy-evaluation line
+(PAPERS.md) applied to consensus serving.  The price is a step of
+delivery lag, so who engages it decides by what a dispatch carries
+(``DeviceRuntime._driver_task``, run/device_runner.py): a throughput
+round, not an open loop's.
 
 This module is the one place that machinery lives (the ROADMAP item-5
 refactor seam): drivers implement a ``dispatch(batch) -> token`` /
@@ -358,6 +365,10 @@ class PipelineCore:
         self.dispatched_rows = 0
         self.dispatched_capacity = 0
         self.pipelined_rounds = 0  # rounds dispatched over an in-flight one
+        # dispatches made by ``serve`` calls that ran with the overlap on,
+        # whether or not another round was in flight (the first half of a
+        # closed loop after a quiet-ring retire is deferred all the same)
+        self.overlapped_dispatches = 0
         self.chain_len = 1  # rounds the latest dispatch carried (gauge)
         # the round the spans on the stepping thread belong to: the
         # dispatch number while dispatching, the retired round's number
@@ -462,6 +473,8 @@ class PipelineCore:
             tok = self._dispatch_chain(chain)
             t1 = self.stages.clock()
             self.dispatches += 1
+            if overlap:
+                self.overlapped_dispatches += 1
             self.dispatched_rows += sum(map(len, chain))
             self.dispatched_capacity += rounds * self.batch_size
             self.chain_len = rounds
@@ -616,6 +629,7 @@ class PipelineCore:
             "device_idle_frac": round(idle_frac, 4),
             "device_pipeline_depth": self.pipeline_depth,
             "device_pipelined_rounds": self.pipelined_rounds,
+            "device_overlapped_dispatches": self.overlapped_dispatches,
             "device_seq_epochs": getattr(self, "seq_epochs", 0),
             "device_slot_epochs": getattr(self, "slot_epochs", 0),
         }

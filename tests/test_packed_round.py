@@ -22,13 +22,34 @@ from fantoch_tpu.run.pipeline import StagedColumns, packed_columns, packed_shape
 BATCH = 8
 
 
+@jax.tree_util.register_pytree_node_class
+class _Leaves:
+    """A round's whole output tuple where a driver holds its one packed
+    array: fetched leaf by leaf, and each leaf's copy back started where
+    the driver starts the one (PR 58)."""
+
+    def __init__(self, out):
+        self.out = out
+
+    def copy_to_host_async(self):
+        for leaf in jax.tree_util.tree_leaves(self.out):
+            leaf.copy_to_host_async()
+
+    def tree_flatten(self):
+        return (self.out,), None
+
+    @classmethod
+    def tree_unflatten(cls, _aux, children):
+        return cls(*children)
+
+
 class _Fetched:
     """The layout of a dispatch that packs nothing: the output tuple is
     fetched leaf by leaf and read as it is."""
 
     @staticmethod
     def unpack(fetched):
-        return fetched
+        return fetched.out
 
 
 class _Unpacked:
@@ -47,7 +68,7 @@ class _Unpacked:
             state, out = jitted(state, *(
                 jnp.array(column, dtype=dtype)
                 for column, (_name, _shape, dtype, _fill) in zip(staged, specs)))
-            return state, out, out
+            return state, _Leaves(out), out
 
         return program, None, layout
 

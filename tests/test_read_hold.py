@@ -45,9 +45,9 @@ class _Client:
         self.reader, self.writer = reader, writer
 
     @classmethod
-    async def connect(cls, addr, client_id):
+    async def connect(cls, addr, *client_ids):
         self = cls(*await asyncio.open_connection(*addr))
-        self.writer.write(rw.frame(ClientHi([client_id])))
+        self.writer.write(rw.frame(ClientHi(list(client_ids))))
         assert rw.deserialize(await self.frame()) == ClientHiAck()
         return self
 
@@ -67,12 +67,12 @@ class _Held:
     """A started runtime whose steps wait on the pool thread for the
     test's word: ``step()`` lets exactly one through."""
 
-    def __init__(self, **config):
+    def __init__(self, protocol="epaxos", **config):
         self.addr = ("127.0.0.1", free_port())
         # the ingest gate is off: a dispatch carries what the ring holds
         self.runtime = dr.DeviceRuntime(
             Config(3, 1, shard_count=1, ingest_deadline_ms=0.0, **config), self.addr,
-            batch_size=BATCH, key_buckets=64,
+            protocol=protocol, batch_size=BATCH, key_buckets=64,
         )
         self.entered = threading.Semaphore(0)
         self.go = threading.Semaphore(0)

@@ -227,8 +227,8 @@ TAIL = [
     "device_dispatched_rows", "device_batch_capacity", "dispatch_fill_frac",
     "serving_chain_len", "device_dispatch_ms", "device_drain_ms", "device_fetch_ms",
     "device_busy_ms", "device_span_ms", "device_idle_frac", "device_pipeline_depth",
-    "device_pipelined_rounds", "device_seq_epochs", "device_slot_epochs",
-    "device_held_dispatches",
+    "device_pipelined_rounds", "device_overlapped_dispatches", "device_seq_epochs",
+    "device_slot_epochs", "device_held_dispatches",
     *(f"stage_{name}_{unit}" for name in STAGES for unit in ("ms", "n")),
     *(f"stage_{name}_{unit}" for name in COMPUTING for unit in ("cpu_ms", "timed_ms")),
     "loop_stall_busy_ms", "loop_stall_gil_ms", "loop_stall_runq_ms", "loop_stall_blocked_ms",
