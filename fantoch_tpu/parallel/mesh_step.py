@@ -379,6 +379,17 @@ def _run_end(head: jax.Array) -> jax.Array:
     return jax.lax.cummin(jnp.where(tail, pos, size), reverse=True)
 
 
+def _count_in_run(head: jax.Array, x: jax.Array) -> jax.Array:
+    """How many of ``x`` (bool[n, W], by sorted position) each key run holds
+    up to and with each position."""
+    total = jnp.cumsum(x.astype(jnp.int32), axis=1)
+    # less what the run's first position found before it: totals only
+    # grow, so the latest head's wins a running max
+    return total - jax.lax.cummax(
+        jnp.where(head[None], total - x.astype(jnp.int32), 0), axis=1
+    )
+
+
 def _chain_of_runs(
     perm: jax.Array, head: jax.Array, shape, member: jax.Array | None = None
 ) -> jax.Array:
@@ -1544,22 +1555,14 @@ def _site_proposals(clock_at, perm, head, run_id, propose_at, site_at, fast_quor
     n, work = clock_at.shape
     int_min, int_max = jnp.iinfo(jnp.int32).min, jnp.iinfo(jnp.int32).max
 
-    def count_in_run(x):  # how many of ``x`` the run holds up to here
-        total = jnp.cumsum(x.astype(jnp.int32), axis=1)
-        # less what the run's first position found before it: totals only
-        # grow, so the latest head's wins a running max
-        return total - jax.lax.cummax(
-            jnp.where(head[None], total - x.astype(jnp.int32), 0), axis=1
-        )
-
     replica = jnp.arange(n, dtype=jnp.int32)[:, None]
     own = propose_at[None] & (site_at[None] == replica)  # [n, W]
     member = propose_at[None] & (jnp.mod(replica - site_at[None], n) < fast_quorum)
     other = member & ~own  # a member that is not the coordinator
-    own_so_far = count_in_run(own)
+    own_so_far = _count_in_run(head, own)
     coordinator = jnp.where(own, clock_at + own_so_far, 0).sum(axis=0)  # c(x), [W]
     start = clock_at + own_so_far[:, _run_end(head)]
-    turn = count_in_run(other)
+    turn = _count_in_run(head, other)
     lifted = segmented_running_max(
         run_id, jnp.where(other, coordinator[None] - turn, int_min), axis=-1
     )
@@ -2314,6 +2317,167 @@ class CaesarStepOutput(NamedTuple):
     work_seq: jax.Array  # int32[W]
 
 
+class CaesarSiteStepOutput(NamedTuple):
+    """What the Caesar round with a coordinator at every site gives
+    (``caesar_protocol_step(sites=n)``): :class:`CaesarStepOutput`'s fields,
+    so a drain reads it as it reads that, and the round's tallies."""
+
+    order: jax.Array
+    executed: jax.Array
+    committed: jax.Array
+    fast_path: jax.Array
+    clock: jax.Array
+    slow_paths: jax.Array
+    watermark: jax.Array
+    pending: jax.Array
+    pend_dropped: jax.Array
+    work_src: jax.Array
+    work_seq: jax.Array
+    # int32[5], by the names of CAESAR_SITE_ROUND_TALLIES, then _GAUGES
+    tallies: jax.Array
+
+
+# CaesarSiteStepOutput.tallies, in order: over the rows committed this round,
+# those of whose ring at least one member met a blocker (a higher-timestamp
+# conflict it had seen already: its answer waited for that one's fate), such
+# members summed, the members that rejected, and the sum over the retried of
+# the retry clock less the proposed one; then the gauge, the depth of the
+# recursion that settles the verdicts (passes of ``_caesar_site_answers``'
+# loop; 0 where nothing proposed)
+CAESAR_SITE_ROUND_TALLIES = ("wait_rows", "wait_acks", "reject_acks", "retry_clock_lift")
+CAESAR_SITE_ROUND_GAUGES = ("wait_passes",)
+
+
+def _caesar_site_answers(
+    clock_at, head, run_id, propose_at, carried_at, carried_clock_at,
+    site_at, src_at, seq_at, live_of, fast_quorum,
+):
+    """The answers of a Caesar round with a coordinator at every site, over
+    the working set sorted by key (:func:`_key_runs`: a key's rows one run, in
+    working order).  By sorted position: ``(t0, fast, t1, waited, rejected,
+    occupied, passes)``, the coordinator's proposal, whether every member of
+    the row's ring said ok, the retry clock (the highest report of the ring),
+    how many members met a blocker, how many rejected, every replica's
+    clock for the key once the round's proposals and counter-proposals are
+    made (``int32[n, W]``), and the depth of the recursion.
+
+    ``clock_at``: int32[n, W], every replica's clock for the key at each
+    position; ``propose_at``: the row proposes this round; ``carried_at`` /
+    ``carried_clock_at``: the row was committed by an earlier round, and at
+    what; ``site_at``: the site of its coordinator; ``live_of``: bool[n].
+    The plain reference is ``tests/caesar_sites_reference.py``, rule by rule
+    beside the handlers of ``protocol/caesar.py``; this is what its general
+    rule comes to with every replica's view its own site's rows first, then
+    the others' in working order, and a ring ``(s + j) % n, j < fast_quorum``
+    of at least three:
+
+      * ``T0(x)`` is the coordinator's clock plus its own rows of the key up
+        to ``x``; rows are ranked by ``(T0, dot)`` (a carried, committed row
+        by its clock), ``rank`` below: the two orders of the dominance test
+        are ``rank`` and the position in the run.
+      * ``y`` is a blocker of ``x`` that a member of ``x``'s ring cannot
+        ignore iff ``y`` went fast, stands before ``x`` in the run, ranks
+        higher, and ``x``'s coordinator is outside ``y``'s ring (inside, it
+        met ``x`` first and reported it; every other member of ``y``'s ring
+        met ``y`` first): then every member of ``x``'s ring but its
+        coordinator rejects.  A retried ``y`` is harmless: a member that
+        rejected it reported everything it had met.  That is a running max,
+        inside the run, of the ranks of the fast rows a site has to mind.
+      * the recursion runs along descending ranks: a row is settled once every
+        row it may have to mind is, and a pass settles every row that can be,
+        so the settled set grows only and the loop ends after as many passes
+        as the longest such chain has rows.  A ``lax.while_loop``, as the
+        execution gate's: the depth is the data's (1 with one site or a ring
+        that is everyone; 5 to 7 on a hot key of 2,000 rows from seven
+        coordinators: every link needs a fast row of the next site that
+        stands earlier and ranks higher), a bound would be the working
+        set's size.
+      * a carried, committed row above ``T0(x)`` (its coordinator lags) makes
+        every live member reject.
+      * a replica's counter-proposals are made from the highest rank down, each
+        one above its clock for the key once the whole view is met, so a
+        report is that clock plus the replica's rejections at or above
+        ``x``'s rank: a count in the runs sorted by descending rank.
+    """
+    from fantoch_tpu.ops.table_ops import segmented_running_max
+
+    n, work = clock_at.shape
+    int_max = jnp.iinfo(jnp.int32).max
+    replica = jnp.arange(n, dtype=jnp.int32)[:, None]
+    run_end = _run_end(head)
+    own = propose_at[None] & (site_at[None] == replica)  # [n, W]
+    member = propose_at[None] & (jnp.mod(replica - site_at[None], n) < fast_quorum)
+    own_so_far = _count_in_run(head, own)
+    t0 = jnp.where(own, clock_at + own_so_far, 0).sum(axis=0)  # [W]
+    # every replica meets every row of the round: its clock for the key after
+    # its walk is the highest of its own proposals and everyone's
+    top = jnp.maximum(
+        clock_at + own_so_far[:, run_end], segmented_running_max(run_id, t0)[run_end]
+    )  # [n, W], one value a run
+    stamp = jnp.where(propose_at, t0, jnp.where(carried_at, carried_clock_at, int_max))
+    by_stamp = jnp.lexsort((seq_at, src_at, stamp)).astype(jnp.int32)
+    rank = jnp.zeros((work,), jnp.int32).at[by_stamp].set(
+        jnp.arange(work, dtype=jnp.int32)
+    )
+
+    def run_max(values):  # of ranks or -1, running inside the run
+        return segmented_running_max(run_id, values, axis=-1)
+
+    with jax.named_scope("caesar_wait"):
+        lagging = propose_at & (
+            run_max(jnp.where(carried_at, rank, -1))[run_end] > rank
+        )  # a carried, committed row of the key ranks above
+        # minds[t, y]: a row of site t has to mind y (t outside y's ring)
+        minds = propose_at[None] & (jnp.mod(replica - site_at[None], n) >= fast_quorum)
+        at_site = site_at[None] == replica  # [n, W]: a row reads its site's line
+
+        def settle(state):
+            settled, minded, passes = state
+            fast = settled & propose_at & ~minded & ~lagging
+            seen = run_max(
+                jnp.concatenate(
+                    [
+                        jnp.where(minds & fast[None], rank[None], -1),
+                        jnp.where(minds & ~settled[None], rank[None], -1),
+                    ]
+                )
+            )  # [2n, W]: the highest fast rank so far, the highest unsettled
+            fast_above = jnp.where(at_site, seen[:n], -1).max(axis=0) > rank
+            open_above = jnp.where(at_site, seen[n:], -1).max(axis=0) > rank
+            ready = ~settled & ~open_above
+            return settled | ready, minded | (ready & fast_above), passes + 1
+
+        _, minded, passes = jax.lax.while_loop(
+            lambda state: ~state[0].all(),
+            settle,
+            (~propose_at, jnp.zeros((work,), bool), jnp.int32(0)),
+        )
+        fast = propose_at & ~minded & ~lagging
+        # who met a blocker: a member but the coordinator that has a higher
+        # rank among its own site's rows of the run, or among the rows
+        # before this one; a live member under a lagging coordinator
+        own_top = run_max(jnp.where(own, rank[None], -1))[:, run_end]
+        before = run_max(jnp.where(propose_at, rank, -1))
+        late = lagging[None] & live_of[:, None]
+        met = member & (
+            (~own & (jnp.maximum(own_top, before[None]) > rank[None])) | late
+        )
+    with jax.named_scope("caesar_retry"):
+        # a row that has a fast one to mind is rejected by every member of
+        # its ring but its coordinator; a lagging one by the live members
+        rejects = member & ((minded[None] & ~own) | late)
+        # the runs again, each by descending rank: a replica's rejections so
+        # far are its counter-proposals made
+        _, _, down = jax.lax.sort(
+            (run_id, -rank, jnp.arange(work, dtype=jnp.int32)), num_keys=2
+        )
+        made = _count_in_run(head, rejects[:, down])  # the run's extents are the same
+        so_far = jnp.zeros((n, work), jnp.int32).at[:, down].set(made)
+        t1 = jnp.maximum(jnp.where(rejects, top + so_far, 0).max(axis=0), t0)
+        occupied = top + made[:, run_end]  # ... and its last position has them all
+    return t0, fast, t1, met.sum(axis=0), rejects.sum(axis=0), occupied, passes
+
+
 def init_caesar_state(
     mesh: Mesh,
     num_replicas: int,
@@ -2351,6 +2515,8 @@ def caesar_protocol_step(
     mesh: Mesh,
     num_replicas: int | None = None,
     live_replicas: int | None = None,
+    sites: int | None = None,
+    site_base: int = 1,
 ) -> Tuple[CaesarMeshState, CaesarStepOutput]:
     """One batched Caesar round: timestamp proposal, fast-quorum (3n/4+1)
     agreement, the MRetry counter-proposal as a second masked aggregation
@@ -2370,6 +2536,22 @@ def caesar_protocol_step(
     first); a multi-key row blocked on one bucket holds back every
     higher-(clock, dot) row on its other buckets — the same gate the
     Newt round uses, with commit-ness in place of vote stability.
+
+    ``sites`` (static): ``None`` is the round above, every command numbered
+    by every replica from one view of the round, the fast quorum the first
+    ``fast_quorum`` rows.  ``sites == n`` is the round with a coordinator at
+    every site (one key a command; the plain reference is
+    ``tests/caesar_sites_reference.py``, semantics and departures there): a
+    command's coordinator is the replica at site ``dot_src - site_base``, a
+    replica has its own site's commands before every other's, a member of
+    the command's ring (``(s + j) % n``, ``j < fast_quorum``) that has met a
+    higher-timestamp conflict holds its answer back until that one's fate is
+    known and rejects if it went fast without depending on the command (the
+    wait condition), and the coordinator retries at the highest
+    counter-proposal (:func:`_caesar_site_answers`).  Everything from the
+    commit on is the round above.  Same state, same columns, another program,
+    which also gives :data:`CAESAR_SITE_ROUND_TALLIES`
+    (:class:`CaesarSiteStepOutput`).
     """
     R, key_buckets = state.key_clock.shape
     if num_replicas is None:
@@ -2378,6 +2560,14 @@ def caesar_protocol_step(
         key = key[:, None]
     batch, key_width = key.shape
     assert key_width == state.pend_key.shape[1]
+    assert sites in (None, num_replicas), (
+        "a coordinator at every site: a site a replica"
+    )
+    if sites is not None:
+        assert key_width == 1, (
+            "the Caesar round with a coordinator at every site: one key a "
+            "command, one shard"
+        )
     pend_cap = state.pend_key.shape[0]
     work = pend_cap + batch
     from fantoch_tpu.core.config import Config
@@ -2414,48 +2604,83 @@ def caesar_protocol_step(
         propose = valid & ~already_committed
         real_slot = valid[:, None] & (key_cat != KEY_PAD)
         propose_slot = propose[:, None] & real_slot
-        slot_iota = jnp.arange(work * key_width, dtype=jnp.int32).reshape(
-            work, key_width
-        )
-        key_full = jnp.where(propose_slot, key_cat, key_buckets + slot_iota)
-        safe_key = jnp.minimum(key_full, key_buckets - 1)
-        prior_rows = jnp.where(
-            propose_slot[None], key_clock[:, safe_key], 0
-        )  # [r_blk, W, KW]
-        slot_prop = _segmented_proposal(
-            prior_rows.reshape(replica_blocks, work * key_width),
-            key_full.reshape(work * key_width),
-            work * key_width,
-        ).reshape(replica_blocks, work, key_width)
-        proposal = jnp.where(
-            propose_slot[None], slot_prop, int_min
-        ).max(axis=-1)
-        proposal = jnp.where(propose[None, :], proposal, 0)  # [r_blk, W]
+        if sites is None:
+            slot_iota = jnp.arange(work * key_width, dtype=jnp.int32).reshape(
+                work, key_width
+            )
+            key_full = jnp.where(propose_slot, key_cat, key_buckets + slot_iota)
+            safe_key = jnp.minimum(key_full, key_buckets - 1)
+            prior_rows = jnp.where(
+                propose_slot[None], key_clock[:, safe_key], 0
+            )  # [r_blk, W, KW]
+            slot_prop = _segmented_proposal(
+                prior_rows.reshape(replica_blocks, work * key_width),
+                key_full.reshape(work * key_width),
+                work * key_width,
+            ).reshape(replica_blocks, work, key_width)
+            proposal = jnp.where(
+                propose_slot[None], slot_prop, int_min
+            ).max(axis=-1)
+            proposal = jnp.where(propose[None, :], proposal, 0)  # [r_blk, W]
 
-        # fast path: the whole fast quorum (3n/4 + 1) reports the same
-        # timestamp — everyone said ok to the coordinator's proposal
-        # (caesar.rs MProposeAck ok=true unanimously)
-        row = (
-            jax.lax.axis_index(REPLICA_AXIS) * replica_blocks
-            + jnp.arange(replica_blocks, dtype=jnp.int32)
-        )
-        in_fq = (row < fast_quorum)[:, None]
-        fq_max = jax.lax.pmax(
-            jnp.where(in_fq, proposal, int_min).max(axis=0), REPLICA_AXIS
-        )
-        fq_min = jax.lax.pmin(
-            jnp.where(in_fq, proposal, int_max).min(axis=0), REPLICA_AXIS
-        )
-        fast = (fq_max == fq_min) & propose
+            # fast path: the whole fast quorum (3n/4 + 1) reports the same
+            # timestamp — everyone said ok to the coordinator's proposal
+            # (caesar.rs MProposeAck ok=true unanimously)
+            row = (
+                jax.lax.axis_index(REPLICA_AXIS) * replica_blocks
+                + jnp.arange(replica_blocks, dtype=jnp.int32)
+            )
+            in_fq = (row < fast_quorum)[:, None]
+            fq_max = jax.lax.pmax(
+                jnp.where(in_fq, proposal, int_min).max(axis=0), REPLICA_AXIS
+            )
+            fq_min = jax.lax.pmin(
+                jnp.where(in_fq, proposal, int_max).min(axis=0), REPLICA_AXIS
+            )
+            fast = (fq_max == fq_min) & propose
 
-        # MRetry as a second masked aggregation in the same step: the
-        # counter-proposal clock is the max over every LIVE replica's
-        # proposal, and it commits iff a write quorum (majority) is live
-        # to ack it (caesar.rs:367-405 + MRetryAck counting)
-        live = (row < live_replicas)[:, None]
-        retry_clock = jax.lax.pmax(
-            jnp.where(live, proposal, int_min).max(axis=0), REPLICA_AXIS
-        )
+            # MRetry as a second masked aggregation in the same step: the
+            # counter-proposal clock is the max over every LIVE replica's
+            # proposal, and it commits iff a write quorum (majority) is live
+            # to ack it (caesar.rs:367-405 + MRetryAck counting)
+            live = (row < live_replicas)[:, None]
+            retry_clock = jax.lax.pmax(
+                jnp.where(live, proposal, int_min).max(axis=0), REPLICA_AXIS
+            )
+        else:
+            # a coordinator at every site: the working set sorted by key (a
+            # carried, committed row stays in its key's run and proposes
+            # nothing), every replica's clock at each position, and the
+            # rings' answers
+            row = (
+                jax.lax.axis_index(REPLICA_AXIS) * replica_blocks
+                + jnp.arange(replica_blocks, dtype=jnp.int32)
+            )
+            live = (row < live_replicas)[:, None]
+            perm, head, _ = _key_runs(
+                jnp.where(valid[:, None], key_cat, key_buckets + widx[:, None])
+            )
+            key_at = jnp.minimum(key_cat[perm, 0], key_buckets - 1)
+            clock_at = jax.lax.all_gather(
+                key_clock[:, key_at], REPLICA_AXIS, tiled=True
+            )  # [n, W]; a pad's position reads any entry and proposes nothing
+            propose_at = propose[perm]
+            t0_at, fast_at, t1_at, met_at, rejected_at, occupied_at, passes = (
+                _caesar_site_answers(
+                    clock_at, head, jnp.cumsum(head.astype(jnp.int32)) - 1,
+                    propose_at, already_committed[perm], prior_clock[perm],
+                    jnp.mod(src_f - site_base, num_replicas)[perm],
+                    src_f[perm], seq_f[perm],
+                    jnp.arange(num_replicas) < live_replicas, fast_quorum,
+                )
+            )
+            by_row = jnp.zeros((work, 5), jnp.int32).at[perm].set(
+                jnp.stack(
+                    [t0_at, fast_at.astype(jnp.int32), t1_at, met_at, rejected_at],
+                    axis=-1,
+                )
+            )
+            fq_max, fast, retry_clock = by_row[:, 0], by_row[:, 1] > 0, by_row[:, 2]
         live_count = jax.lax.psum(
             live[:, 0].astype(jnp.int32).sum(), REPLICA_AXIS
         )
@@ -2529,16 +2754,29 @@ def caesar_protocol_step(
         # proposals — uncommitted proposals occupy the index too, which
         # is what keeps later proposals strictly above them
         # (the key-clock add of caesar.rs:786-838)
-        learn = jnp.maximum(
-            jnp.where(
-                committed[None, :, None] & real_slot[None],
-                clock[None, :, None],
-                0,
-            ),
-            jnp.where(propose_slot[None], proposal[..., None], 0),
-        )  # [r_blk, W, KW]
-        upd = jnp.where(live[..., None] & real_slot[None], learn, 0)
-        new_key_clock = key_clock.at[:, real_key].max(upd)
+        if sites is None:
+            learn = jnp.maximum(
+                jnp.where(
+                    committed[None, :, None] & real_slot[None],
+                    clock[None, :, None],
+                    0,
+                ),
+                jnp.where(propose_slot[None], proposal[..., None], 0),
+            )  # [r_blk, W, KW]
+            upd = jnp.where(live[..., None] & real_slot[None], learn, 0)
+            new_key_clock = key_clock.at[:, real_key].max(upd)
+        else:
+            # ... and with a coordinator at every site what a live replica
+            # occupied: the timestamps it met and its counter-proposals
+            local = jnp.where(
+                live & propose_at[None], occupied_at[row], 0
+            )  # [r_blk, W], by sorted position
+            learnt = jnp.where(
+                live & (committed & valid)[None], clock[None], 0
+            )  # [r_blk, W], by row
+            new_key_clock = key_clock.at[
+                :, jnp.concatenate([key_at, real_key[:, 0]])
+            ].max(jnp.concatenate([local, learnt], axis=1))
 
         # pending carry: committed rows first (their timestamps are
         # final — dropping one would have to re-propose at a different
@@ -2563,7 +2801,7 @@ def caesar_protocol_step(
 
         watermark = jnp.where(executed, clock, 0).max()
 
-        return (
+        outputs = (
             new_key_clock,
             new_pend_key, new_pend_src, new_pend_seq, new_pend_clock,
             order, executed, committed, fast, clock,
@@ -2571,6 +2809,21 @@ def caesar_protocol_step(
             jnp.minimum(pending, pend_cap), pend_dropped,
             src_f, seq_f,
         )
+        if sites is None:
+            return outputs
+        # what the wait condition and the retry did to the rows committed
+        # this round (CAESAR_SITE_ROUND_TALLIES), and the recursion's depth
+        met, rejected = by_row[:, 3], by_row[:, 4]
+        tallies = jnp.stack(
+            [
+                (newly_committed & (met > 0)).sum(),
+                jnp.where(newly_committed, met, 0).sum(),
+                jnp.where(newly_committed, rejected, 0).sum(),
+                jnp.where(newly_committed & ~fast, clock - fq_max, 0).sum(),
+                passes,
+            ]
+        ).astype(jnp.int32)
+        return outputs + (tallies,)
 
     specs_in = (
         P(REPLICA_AXIS, None),
@@ -2583,44 +2836,36 @@ def caesar_protocol_step(
         P(), P(), P(), P(), P(),
         P(), P(), P(), P(),
         P(), P(),  # work identity columns
-    )
+    ) + (() if sites is None else (P(),))  # the site round's tallies
     fn = shard_map(
         step, mesh=mesh, in_specs=specs_in, out_specs=specs_out, check_vma=False
     )
-    (
-        kc, pk, ps_, pq, pc,
-        order, executed, committed, fast, clock,
-        slow, watermark, pending, dropped,
-        work_src, work_seq,
-    ) = fn(
+    out = fn(
         state.key_clock,
         state.pend_key, state.pend_src, state.pend_seq, state.pend_clock,
         key, dot_src, dot_seq,
     )
-    return (
-        CaesarMeshState(kc, pk, ps_, pq, pc),
-        CaesarStepOutput(
-            order, executed, committed, fast, clock,
-            slow, watermark, pending, dropped,
-            work_src, work_seq,
-        ),
-    )
+    n_state = len(CaesarMeshState._fields)
+    output = CaesarStepOutput if sites is None else CaesarSiteStepOutput
+    return CaesarMeshState(*out[:n_state]), output(*out[n_state:])
 
 
 def jit_caesar_step(
     mesh: Mesh,
     num_replicas: int | None = None,
     live_replicas: int | None = None,
+    sites: int | None = None,
+    site_base: int = 1,
 ):
-    """jit-compiled Caesar round with donated device-resident state."""
+    """jit-compiled Caesar round with donated device-resident state
+    (``sites``: the round with a coordinator at every site,
+    :func:`caesar_protocol_step`; the program with one is built without the
+    two arguments, as it always was)."""
     import functools
 
+    static = dict(mesh=mesh, num_replicas=num_replicas, live_replicas=live_replicas)
+    if sites is not None:
+        static.update(sites=sites, site_base=site_base)
     return jax.jit(
-        functools.partial(
-            caesar_protocol_step,
-            mesh=mesh,
-            num_replicas=num_replicas,
-            live_replicas=live_replicas,
-        ),
-        donate_argnums=(0,),
+        functools.partial(caesar_protocol_step, **static), donate_argnums=(0,)
     )

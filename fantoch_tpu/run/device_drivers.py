@@ -282,8 +282,11 @@ class _DriverCore(PipelineCore):
         # the mesh and how its packed output reads back (``_program``)
         self._programs: Dict[int, Tuple[Any, Any, PackedOutput]] = {}
         # the sites clients are registered at (``register_site``): a
-        # client that names none is at site 0
+        # client that names none is at site 0; and, where a second site's
+        # hello put the programs with a coordinator at every site in
+        # ``_programs``' place, the programs with one
         self._sites = {0}
+        self._one_site_programs: Dict[int, Tuple[Any, Any, PackedOutput]] = {}
         # the depth-K dispatch/drain pipeline + staging ingest ring +
         # per-dispatch counters (serve/step/flush_pipeline and _staging
         # come from PipelineCore; drivers implement the halves
@@ -356,8 +359,8 @@ class _DriverCore(PipelineCore):
         return self._mesh
 
     # whether the round can have a coordinator at every site (the
-    # dep-commit and the Newt drivers say where); the rounds with one
-    # coordinator serve site 0 alone
+    # dep-commit, the Newt and the Caesar drivers say where); the rounds
+    # with one coordinator serve site 0 alone
     serves_sites = False
 
     @property
@@ -381,10 +384,10 @@ class _DriverCore(PipelineCore):
         if not self.serves_sites:
             raise ValueError(
                 f"clients at site {site}: this round has one coordinator, "
-                "replica 0 (caesar's and fpaxos's always, newt's under "
-                "--shard-count or --device-key-width above 1; a coordinator "
-                "at every site is served under epaxos, atlas, and newt with "
-                "one key a command on one shard)"
+                "replica 0 (fpaxos's always, caesar's under --device-key-width "
+                "above 1, newt's under that or --shard-count above 1; a "
+                "coordinator at every site is served under epaxos and atlas, "
+                "and under newt and caesar with one key a command on one shard)"
             )
         if not 0 <= site < self.num_replicas:
             raise ValueError(
@@ -539,7 +542,7 @@ class _DriverCore(PipelineCore):
     @property
     def precompiled_programs(self) -> int:
         """Programs made ready (gauge)."""
-        return len(self._programs)
+        return len(self._programs) + len(self._one_site_programs)
 
     def _columns_to_device(self, staged: StagedColumns, sharding):
         """The assembled columns of a dispatch, handed to jax as the one
@@ -574,7 +577,10 @@ class _DriverCore(PipelineCore):
     def _assemble_round(self, batch, key_rows, src_row, seq_row) -> None:
         """Fill one round's fixed-size key/src/seq columns in place and
         register its commands under their packed (source, window
-        sequence)."""
+        sequence); with a coordinator at every site the batch's sites
+        take turns (``_sites_in_turn``)."""
+        if len(self._sites) > 1:
+            batch = _sites_in_turn(batch)
         _key_column(batch, key_rows, self.shard_id, self.key_buckets, self.shard_count)
         self._identity_columns(batch, src_row, seq_row)
 
@@ -1257,7 +1263,6 @@ class NewtDeviceDriver(_DriverCore):
         self.num_replicas = num_replicas
         self.site_base = site_base
         self._chain_lengths: List[int] = [1]
-        self._one_site_programs: Dict[int, Tuple[Any, Any, PackedOutput]] = {}
         self.round_tallies = dict.fromkeys(mesh_step.NEWT_SITE_ROUND_TALLIES, 0)
         self._step = mesh_step.jit_newt_step(
             self._mesh, f=f, tiny_quorums=tiny_quorums,
@@ -1388,17 +1393,6 @@ class NewtDeviceDriver(_DriverCore):
                 with self.stages.span("precompile", S):
                     ready[S] = compiled.result()
         self._one_site_programs, self._programs = self._programs, ready
-
-    @property
-    def precompiled_programs(self) -> int:
-        return len(self._programs) + len(self._one_site_programs)
-
-    def _assemble_round(self, batch, key_rows, src_row, seq_row) -> None:
-        """A round's columns, the round of a chain too; with a coordinator
-        at every site the batch's sites take turns (``_sites_in_turn``)."""
-        if len(self._sites) > 1:
-            batch = _sites_in_turn(batch)
-        super()._assemble_round(batch, key_rows, src_row, seq_row)
 
     def _chain_windows_blocked(
         self, batches: Sequence[List[Tuple[Dot, Command]]]
@@ -1568,11 +1562,23 @@ class CaesarDeviceDriver(_DriverCore):
         shard_id: ShardId = 0,
         monitor_execution_order: bool = False,
         mesh=None,
+        site_base: ProcessId = 1,
     ):
         from fantoch_tpu.parallel import mesh_step
 
         self._init_core(shard_id, batch_size, key_buckets, monitor_execution_order)
         self.key_width = key_width
+        # a coordinator at every site: a command's coordinator is its dot's
+        # source, ``site_base + site``; the program with one coordinator
+        # once a second site put the other in its place; what the round
+        # with a coordinator at every site tallies and its gauge
+        # (mesh_step.CAESAR_SITE_ROUND_TALLIES / _GAUGES; 0 while one
+        # coordinator serves)
+        self.num_replicas = num_replicas
+        self.site_base = site_base
+        self._live_replicas = live_replicas
+        self.round_tallies = dict.fromkeys(mesh_step.CAESAR_SITE_ROUND_TALLIES, 0)
+        self.round_gauges = dict.fromkeys(mesh_step.CAESAR_SITE_ROUND_GAUGES, 0)
         self._mesh = (
             mesh
             if mesh is not None
@@ -1590,6 +1596,32 @@ class CaesarDeviceDriver(_DriverCore):
         )
         self._pend_cap = pending_capacity
 
+    # --- a coordinator at every site ---
+
+    @property
+    def serves_sites(self) -> bool:
+        """Whether the round can have a coordinator at every site: with one
+        key a command (``caesar_protocol_step(sites=n)``; a row's several
+        runs are not written, and the round has one shard)."""
+        return self.key_width == 1
+
+    def _make_site_programs(self) -> None:
+        """The round with a coordinator at every site: lowered on the
+        state's shapes and compiled, or loaded, beside the step's thread
+        under a ``precompile`` span (about 8.0 s on an empty cache on a v5e
+        at the cell's shape, 7.4 s of it the compiler's, against a client's
+        30 s wait for its ack; PR 59's chip runs).  The program with one
+        coordinator is kept."""
+        from fantoch_tpu.parallel import mesh_step
+
+        self._step = mesh_step.jit_caesar_step(
+            self._mesh, num_replicas=self.num_replicas,
+            live_replicas=self._live_replicas,
+            sites=self.num_replicas, site_base=self.site_base,
+        )
+        ready = {1: self._precompile(self._step, state=self._state_shapes())}
+        self._one_site_programs, self._programs = self._programs, ready
+
     def _execute(self, _tok, out) -> List[ExecutorResult]:
         """Execute one fetched round's wait-cleared commands in
         (clock, dot) order."""
@@ -1601,6 +1633,17 @@ class CaesarDeviceDriver(_DriverCore):
         self.stable_watermark = max(self.stable_watermark, wm)
         self.slow_paths += int(out.slow_paths)
         self.fast_paths += int(np.asarray(out.fast_path).sum())
+        # what the round with a coordinator at every site adds to the
+        # round's output (mesh_step.CaesarSiteStepOutput): its tallies,
+        # then the recursion's depth, of which the highest is kept
+        counts = getattr(out, "tallies", None)
+        if counts is not None:
+            *sums, passes = counts.tolist()
+            tallies = self.round_tallies
+            for name, count in zip(tallies, sums):
+                tallies[name] += count
+            gauges = self.round_gauges
+            gauges["wait_passes"] = max(gauges["wait_passes"], passes)
 
         return self._drain_and_carry(out, "caesar", "blocked")
 
@@ -1825,14 +1868,13 @@ def driver_for(
     if protocol == "fpaxos":
         # slot-ordered: the round has no key rows
         return PaxosDeviceDriver(config.n, f=config.f, **shared)
-    keyed = dict(shared, key_width=key_width)
+    # the rounds that order by key serve a coordinator at every site
+    # (site ``s``'s is process ``process_id + s``)
+    keyed = dict(shared, key_width=key_width, site_base=process_id)
     if protocol == "caesar":
         return CaesarDeviceDriver(config.n, **keyed)
-    # the two rounds that serve a sharded key axis and a coordinator at
-    # every site (site ``s``'s is process ``process_id + s``)
-    sited = dict(
-        keyed, f=config.f, shard_count=config.shard_count, site_base=process_id
-    )
+    # the two rounds that serve a sharded key axis
+    sited = dict(keyed, f=config.f, shard_count=config.shard_count)
     if protocol == "newt":
         return NewtDeviceDriver(
             config.n, tiny_quorums=config.newt_tiny_quorums, **sited

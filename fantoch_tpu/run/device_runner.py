@@ -597,7 +597,7 @@ class DeviceRuntime:
         "queue_capacity", "device_idle_frac", "device_pipeline_depth",
         "dispatch_fill_frac", "serving_chain_len", "serving_chain", "ingest_target",
         "ingest_rate_per_s", "loop_lag_hwm_ms", "precompiled_programs",
-        "gc_frozen_objects", "sites_registered", "scc_rows_max",
+        "gc_frozen_objects", "sites_registered", "scc_rows_max", "wait_passes",
     })
 
     def telemetry_sample(self):

@@ -157,8 +157,9 @@ def test_a_second_sites_hello_makes_the_second_program_ready_before_its_ack():
     ("newt", {"shard_count": 2}, 1, "one coordinator"),
     ("newt", {"key_width": 2}, 1, "one coordinator"),
     ("newt", {"f": 2}, 5, "the sites are the replicas"),
-    ("caesar", {"n": 7}, 1, "one coordinator"),
-    ("fpaxos", {}, 1, "one coordinator"),
+    ("caesar", {"n": 7, "key_width": 2}, 1, "caesar's under --device-key-width above 1"),
+    ("caesar", {"n": 7}, 7, "the sites are the replicas"),
+    ("fpaxos", {}, 1, "one coordinator, replica 0 (fpaxos's always"),
     ("atlas", {"f": 2}, 5, "the sites are the replicas"),
     ("atlas", {"f": 2, "shard_count": 2}, -1, "the sites are the replicas"),
     ("atlas", {"f": 2, "key_width": 2}, "1", "a site is a replica's number"),
@@ -211,7 +212,7 @@ def test_dots_are_a_coordinators_and_two_sites_never_collide_in_a_registry():
 @pytest.mark.parametrize("build", [
     lambda: NewtDeviceDriver(5, batch_size=8, key_buckets=64, pending_capacity=8, shard_count=2),
     lambda: NewtDeviceDriver(5, batch_size=8, key_buckets=64, pending_capacity=8, key_width=2),
-    lambda: CaesarDeviceDriver(7, batch_size=8, key_buckets=64, pending_capacity=8),
+    lambda: CaesarDeviceDriver(7, batch_size=8, key_buckets=64, pending_capacity=8, key_width=2),
     lambda: PaxosDeviceDriver(5, batch_size=8, pending_capacity=8),
 ])
 def test_a_driver_with_one_coordinator_takes_site_0_and_no_other(build):
@@ -537,3 +538,101 @@ def test_dots_of_two_sites_never_collide_in_the_newt_registry():
     assert len(results) == 12 and driver.executed == 12 and driver.in_flight == 0
     assert driver.fast_paths + driver.slow_paths == 12 and driver.slow_paths > 0
     assert driver.round_tallies["site_clock_spread"] > 0
+
+
+# --- Caesar: the round with a coordinator at every site ---------------------
+
+
+def test_a_caesar_driver_no_site_but_0_registered_at_dispatches_the_parents_program():
+    driver = CaesarDeviceDriver(7, batch_size=8, key_buckets=64, pending_capacity=8)
+    assert driver.serves_sites and driver.site_base == 1
+    assert set(driver._step.__wrapped__.keywords) == {"mesh", "num_replicas", "live_replicas"}
+    batch = [(Dot(1, 1 + at), _put(1, 1 + at, "k")) for at in range(6)]
+    assert len(driver.serve([batch])) == 6 and driver.slow_paths == 0
+    assert driver._programs[1][2].type is mesh_step.CaesarStepOutput
+    one_site = driver._programs[1]
+    assert driver.round_tallies == dict.fromkeys(mesh_step.CAESAR_SITE_ROUND_TALLIES, 0)
+    assert driver.round_gauges == {"wait_passes": 0}
+    driver.register_site(6)
+    assert driver.sites_registered == 2 and driver.precompiled_programs == 2
+    assert driver.stages.n["precompile"] == 2 and driver._one_site_programs[1] is one_site
+    assert driver._step.__wrapped__.keywords["sites"] == 7
+    program, sharding, layout = driver._program(1)
+    assert sharding.shard_shape((3, 8))[0] == len(driver._column_specs())
+    assert layout.type is mesh_step.CaesarSiteStepOutput
+    # one site's stretch after another: the round is given them in turn
+    sources = [1, 1, 1, 7, 7, 2]
+    batch = [(Dot(src, 10 + at), _put(src, 10 + at, "k")) for at, src in enumerate(sources)]
+    _key, src, seq = driver._assemble(batch)
+    assert src[:6].tolist() == [1, 7, 2, 1, 7, 1] and seq[:6].tolist() == [10, 13, 15, 11, 14, 12]
+    driver._cmds.clear()
+
+
+def test_a_caesar_server_takes_hellos_from_seven_sites_and_its_history_passes_the_check():
+    """`--protocol caesar -n 7`: the second site's hello makes the second
+    program ready before its ack, every other site's is acknowledged on it,
+    every client of seven sites is answered, some commands of the one hot key
+    are retried, the wait condition's tallies reach the snapshot, and the
+    answers (every write returns the value it replaced) are one chain a key
+    under `benchmark/check.py`."""
+    import time
+
+    from benchmark.check import check_history
+    from benchmark.generators.kv_loop import MEASURED, NONE_VALUE, OK, PUT, RECORD_FIELDS
+
+    obs.subscribe_recompiles()
+    rows = []
+
+    async def write(rw, client, seq, key):
+        sent = time.monotonic()
+        reply = await _call(rw, Command.from_single(
+            Rifl(client, seq), 0, f"k{key}", KVOp.put(f"{client}:{seq}")))
+        (result,), = reply.cmd_result.results.values()
+        prev = (NONE_VALUE, NONE_VALUE) if result is None else map(int, result.split(":"))
+        ret_client, ret_seq = prev
+        rows.append(dict(client=client, seq=seq, key=key, op=PUT, phase=MEASURED, status=OK,
+                         due=sent, sent=sent, acked=time.monotonic(),
+                         ret_client=ret_client, ret_seq=ret_seq))
+
+    async def go():
+        runtime, port = _runtime("caesar", n=7)
+        await runtime.start()
+        try:
+            driver = runtime.driver
+            assert driver.serves_sites
+            sessions = []  # three clients a site, a connection each
+            for client in range(1, 22):
+                site = (client - 1) // 3
+                rw, writer, ack = await _hello(port, ClientHi([client], site=site))
+                assert isinstance(ack, ClientHiAck)
+                assert driver.stages.n["precompile"] == 1 + (site > 0)
+                sessions.append((rw, writer, client))
+            assert driver.sites_registered == 7 and driver.precompiled_programs == 2
+            compiled = obs.recompile_count() + obs.cache_hit_count()
+            rng = np.random.default_rng(59)
+            for seq in range(1, 13):  # every client at a time in some order, half on key 0
+                await asyncio.gather(*(
+                    write(sessions[at][0], sessions[at][2], seq,
+                          0 if rng.random() < 0.5 else sessions[at][2])
+                    for at in rng.permutation(len(sessions))))
+            assert obs.recompile_count() + obs.cache_hit_count() == compiled  # nothing since
+            assert driver.executed == len(rows) == 7 * 3 * 12 and driver.in_flight == 0
+            runtime._publish_tallies()
+            tallies = runtime._tallies
+            assert tallies["sites_registered"] == 7
+            assert tallies["fast_paths"] + tallies["slow_paths"] == tallies["executed"]
+            assert 0 < tallies["slow_paths"] <= tallies["wait_rows"]
+            assert tallies["wait_acks"] >= tallies["reject_acks"] >= 5 * tallies["slow_paths"]
+            assert tallies["retry_clock_lift"] >= tallies["slow_paths"]
+            assert tallies["wait_passes"] >= 2
+            for _, writer, _ in sessions:
+                writer.close()
+        finally:
+            await runtime.stop()
+
+    asyncio.run(go())
+    records = {name: np.array([row[name] for row in rows], dtype) for name, dtype in RECORD_FIELDS}
+    verdict = check_history(records)
+    assert verdict["correct"], verdict["witnesses"]
+    assert verdict["stats"]["acked_writes"] == len(rows)
+    assert verdict["stats"]["longest_chain"] > 50  # the hot key's

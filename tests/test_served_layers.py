@@ -146,13 +146,14 @@ def test_the_runtime_and_the_session_live_where_the_map_says():
 
 SHARED = {"batch_size": 8, "key_buckets": 64, "pending_capacity": 16, "live_replicas": 3,
           "monitor_execution_order": True, "mesh": None}
-SHARDED = {"f": 1, "shard_count": 2, "site_base": 7, "key_width": 2}
+SITED = {"site_base": 7, "key_width": 2}  # the rounds that order by key
+SHARDED = {"f": 1, "shard_count": 2, **SITED}
 # label -> (class, shard_count of its Config, the keywords beside SHARED)
 FAMILIES = {
     "epaxos": ("DeviceDriver", 2, {**SHARDED, "rule": "epaxos"}),
     "atlas": ("DeviceDriver", 2, {**SHARDED, "rule": "atlas"}),
     "newt": ("NewtDeviceDriver", 2, {**SHARDED, "tiny_quorums": True}),
-    "caesar": ("CaesarDeviceDriver", 1, {"key_width": 2}),
+    "caesar": ("CaesarDeviceDriver", 1, SITED),  # a coordinator at every site since PR 59
     "fpaxos": ("PaxosDeviceDriver", 1, {"f": 1}),
 }
 
@@ -212,7 +213,7 @@ ROUND = {
                "scc_span_rows", "scc_shard_rows", "threshold_short_deps", "split_quorum_rows",
                "threshold_fast_split_rows", "scc_rows_max"],
     "newt": ["site_clock_spread", "clock_ties", "arrival_reordered"],
-    "caesar": [],
+    "caesar": ["wait_rows", "wait_acks", "reject_acks", "retry_clock_lift", "wait_passes"],  # PR 59
     "fpaxos": [],
 }
 STAGES = ["idle_wait", "gate_wait", "collect", "handoff", "step", "assemble", "enqueue", "fetch",
